@@ -6,7 +6,6 @@ re-designed for JAX/XLA: parallelism is a `jax.sharding.Mesh`, ZeRO stages
 are sharding specs, kernels are Pallas/XLA.
 """
 
-from . import _compat  # noqa: F401  (jax.shard_map shim — must run first)
 from .version import __version__, git_hash  # noqa: F401
 from . import comm  # noqa: F401
 from . import module_inject  # noqa: F401

@@ -100,9 +100,6 @@ def init_distributed(
                 )
     except RuntimeError as e:  # already initialized by launcher
         logger.debug(f"jax.distributed.initialize skipped: {e}")
-    from .._compat import install_cpu_collectives
-
-    install_cpu_collectives()
     _INITIALIZED = True
 
 

@@ -127,6 +127,26 @@ class MeshInfo:
         return (self.data_hierarchy[1] if self.data_hierarchy
                 else self.axis_size(DATA_AXIS))
 
+    def manual_axes(self, axes) -> frozenset:
+        """`axis_names` for a `shard_map` whose body is manual over
+        `axes`: those axes plus every other mesh axis of size 1.  A
+        size-1 axis shards nothing, so the program is the same either
+        way, and a region whose automatic axes all have size 1 lowers
+        fully manual.  Partial-manual regions are where jax 0.9's
+        `debug_callback` lowering and XLA:CPU's bf16 all-reduce
+        promotion fail; TPU compiles both forms
+        (tests/test_tpu_compile.py)."""
+        return frozenset(axes) | {a for a, n in self.mesh.shape.items()
+                                  if n == 1}
+
+    def auto_axes(self) -> list:
+        """Mesh axes of size > 1 that the code being traced is NOT
+        manual over: what XLA would have to partition a call across
+        (it cannot partition a Mosaic kernel)."""
+        manual = jax.sharding.get_abstract_mesh().manual_axes
+        return sorted(a for a, n in self.mesh.shape.items()
+                      if n > 1 and a not in manual)
+
     # Reference-parity aliases (pipe/topology.py get_*_parallel_world_size)
     def get_data_parallel_world_size(self) -> int:
         return self.axis_size(DATA_AXIS)

@@ -22,6 +22,13 @@ index tables, which Mosaic turns into plain async block copies:
 Both oracles are vmapped over batch rows by callers; these wrappers
 are shaped identically so `dispatch("moe_dispatch", ...)` drops in
 under the same vmap.
+
+On the chip: NOT selected.  Both kernels move one `(1, D)` row per grid
+step, a block the Pallas TPU lowering refuses (its last two dims must
+divide by 8 and 128), and a gather of arbitrary rows has no 8-row tile
+to move instead: the rewrite is a manual row DMA from HBM.  Until then
+the registry (`MoEDispatchOp.is_compatible`) refuses them by name and
+they run under the interpreter only (tests/test_tpu_compile.py).
 """
 
 from __future__ import annotations
@@ -33,11 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..ops.transformer.flash_attention import compiler_params_cls
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from ..ops import pallas_backend
 
 
 def _clamp(i):
@@ -85,9 +88,9 @@ def sorted_dispatch_pallas(x, eidx, pos, keep, num_experts: int,
         _dispatch_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E * C, D), x.dtype),
-        compiler_params=compiler_params_cls()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL,)),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(src_tok, x)
     return buf.reshape(E, C, D)
 
@@ -141,7 +144,7 @@ def sorted_combine_pallas(expert_out, eidx, gate, pos, keep):
         functools.partial(_combine_kernel, k=k, N=N),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, D), expert_out.dtype),
-        compiler_params=compiler_params_cls()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(src, w, flat)

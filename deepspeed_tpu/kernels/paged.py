@@ -30,10 +30,11 @@ live key to anchor the running max).
 
 TPU-native layout: caches are viewed as `[rows, H * width]` (a free
 reshape) so each gathered tile is a `(block_size, width)` block —
-lane-dim clean when `Dh % 128 == 0`; the registry's auto heuristic
-gates on that plus small T (the q rows unroll over scalar-prefetched
-positions).  Scales ride a `(block_size, 1)` block — sub-lane, fine
-under the interpreter, flagged for Mosaic in docs/tutorials/kernels.md.
+one the chip's compiler tiles only when `width % 128 == 0`, so the
+registry refuses head_dim 64 (every GPT-2 size) and int4 below
+head_dim 256 by name, and gates on small T (the q rows unroll over
+scalar-prefetched positions).  Scales ride the block's whole
+`(block_size, H)` tile and the head's column is selected in-kernel.
 """
 
 from __future__ import annotations
@@ -45,12 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..ops import pallas_backend
 from ..models.generation import NEG_INF
-from ..ops.transformer.flash_attention import compiler_params_cls
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _clamp(i):
@@ -58,7 +55,7 @@ def _clamp(i):
 
 
 def _params():
-    return compiler_params_cls()(
+    return pltpu.CompilerParams(
         dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY))
 
 
@@ -110,9 +107,26 @@ def _decode_nibbles(raw, width, full):
     return jnp.stack([lo, hi], axis=-1).reshape(raw.shape[0], full)
 
 
-def _tile_kv(ref, s_ref, kv_mode, Dh, marker):
+def _f16_bits_to_f32(bits):
+    """uint16 fp16 bit patterns (non-negative: they are scales) -> the
+    fp32 values, in integer ops: the chip loads no fp16 vectors
+    ("Invalid vector type for load")."""
+    b = bits.astype(jnp.uint32)
+    exp, man = (b >> 10) & jnp.uint32(0x1F), b & jnp.uint32(0x3FF)
+    # normals re-bias the exponent (15 -> 127); exponent 31 is inf/nan
+    exp32 = jnp.where(exp == 31, jnp.uint32(255), exp + jnp.uint32(112))
+    normal = jax.lax.bitcast_convert_type((exp32 << 23) | (man << 13),
+                                          jnp.float32)
+    sub = man.astype(jnp.int32).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    return jnp.where(exp == 0, sub, normal)
+
+
+def _tile_kv(ref, s_ref, head, kv_mode, Dh, marker):
     """One gathered cache tile -> fp32 [block_size, Dh], dequantized
-    in-register for quantized caches (the fused dequant)."""
+    in-register for quantized caches (the fused dequant).  The scales
+    arrive as the block's full `(block_size, H)` tile — a one-column
+    block is not one the chip's compiler tiles — and this head's column
+    is picked by a masked lane reduction."""
     raw = ref[...]
     if kv_mode == "dense":
         return raw.astype(jnp.float32)
@@ -120,8 +134,13 @@ def _tile_kv(ref, s_ref, kv_mode, Dh, marker):
         codes = _decode_nibbles(raw, raw.shape[-1], Dh)
     else:
         codes = raw.astype(jnp.int8)
-    vals = codes.astype(jnp.float32) * s_ref[...].astype(jnp.float32)
-    return jnp.where(codes == marker, jnp.float32(jnp.nan), vals)
+    scales = _f16_bits_to_f32(s_ref[...])                    # (bs, H)
+    lane = jax.lax.broadcasted_iota(jnp.int32, scales.shape, 1)
+    scale = jnp.sum(jnp.where(lane == head, scales, 0.0), axis=1,
+                    keepdims=True)                           # (bs, 1)
+    # compared as fp32: the chip has no int8 vector comparison
+    codes = codes.astype(jnp.float32)
+    return jnp.where(codes == marker, jnp.float32(jnp.nan), codes * scale)
 
 
 def _paged_kernel(tbl, qp, q_ref, *rest, scale, bs, W, H, T, Dh,
@@ -134,6 +153,7 @@ def _paged_kernel(tbl, qp, q_ref, *rest, scale, bs, W, H, T, Dh,
     bh = pl.program_id(0)
     a = pl.program_id(1)
     r = jax.lax.div(bh, H)
+    head = jax.lax.rem(bh, H)
 
     @pl.when(a == 0)
     def _init():
@@ -142,7 +162,7 @@ def _paged_kernel(tbl, qp, q_ref, *rest, scale, bs, W, H, T, Dh,
         l_s[...] = jnp.zeros_like(l_s)
 
     q = q_ref[0].astype(jnp.float32) * scale          # (T, Dh)
-    k = _tile_kv(k_ref, ks_ref, kv_mode, Dh, marker)  # (bs, Dh)
+    k = _tile_kv(k_ref, ks_ref, head, kv_mode, Dh, marker)  # (bs, Dh)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     kidx = a * bs + jax.lax.broadcasted_iota(jnp.int32, (T, bs), 1)
@@ -160,7 +180,7 @@ def _paged_kernel(tbl, qp, q_ref, *rest, scale, bs, W, H, T, Dh,
     p = jnp.where(s <= NEG_INF * 0.5, 0.0, p)
     alpha = jnp.exp(m_prev - m_new)
     l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    v = _tile_kv(v_ref, vs_ref, kv_mode, Dh, marker)
+    v = _tile_kv(v_ref, vs_ref, head, kv_mode, Dh, marker)
     acc[...] = acc[...] * alpha + jnp.dot(
         p, v, preferred_element_type=jnp.float32)
     m_s[:, :1] = m_new
@@ -217,11 +237,13 @@ def paged_attention_pallas(q, ck, cv, rows, q_pos, *,
             (bs, width), lambda b, a, t, s: (_clamp(t[b // H, a]),
                                              jax.lax.rem(b, H)))
         scale_spec = pl.BlockSpec(
-            (bs, 1), lambda b, a, t, s: (_clamp(t[b // H, a]),
-                                         jax.lax.rem(b, H)))
+            (bs, H), lambda b, a, t, s: (_clamp(t[b // H, a]), 0))
         kv_specs = [payload_spec, scale_spec, payload_spec, scale_spec]
-        operands = [pk.reshape(pk.shape[0], H * width), sk,
-                    pv.reshape(pv.shape[0], H * width), sv]
+        def bits(scales):  # fp16 -> its bit pattern, a free view
+            return jax.lax.bitcast_convert_type(scales, jnp.uint16)
+
+        operands = [pk.reshape(pk.shape[0], H * width), bits(sk),
+                    pv.reshape(pv.shape[0], H * width), bits(sv)]
 
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, Dh)
 
@@ -246,6 +268,6 @@ def paged_attention_pallas(q, ck, cv, rows, q_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, T, Dh), out_dtype),
         compiler_params=_params(),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(tables, qp, qf, *operands)
     return out.reshape(B, H, T, Dh).transpose(0, 2, 1, 3)

@@ -18,8 +18,8 @@ fine; the arithmetic is what the kernel owns).
 Layout notes (TPU-native): tiles are `_TILE` = 8 block-rows x `block`
 lanes, so `block % 128 == 0` tiles cleanly (the registry's auto
 heuristic gates on it; DEFAULT_BLOCK_SIZE = 256 qualifies).  Scales
-travel through a 128-lane broadcast column — 2 bytes/element of
-sideband, negligible next to the payload.
+travel through a 128-lane fp32 broadcast column, already rounded to
+fp16 values (`_round_to_f16`); the wrappers cast.
 """
 
 from __future__ import annotations
@@ -31,19 +31,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..ops.transformer.flash_attention import compiler_params_cls
+from ..ops import pallas_backend
 from ..runtime.comm.quant import (_F32_MIN_NORMAL, qmax,
                                   validate_block_size)
 
 _TILE = 8  # block-rows per grid program (fp32 sublane tile)
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _params(ndims: int):
-    return compiler_params_cls()(
+    return pltpu.CompilerParams(
         dimension_semantics=(pltpu.PARALLEL,) * ndims)
 
 
@@ -61,6 +57,23 @@ def _pad_rows(a, tile: int):
 # ---------------------------------------------------------------------------
 
 
+def _round_to_f16(x):
+    """The fp32 value of `x.astype(float16)` for finite x >= 0, in
+    integer ops: Mosaic has no f32 -> f16 pack on this chip (`failed to
+    legalize operation 'tpu.pack_subelements'`), so the kernel rounds
+    here and the wrapper's cast to fp16 is then exact."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    # fp16 normals keep 10 of 23 mantissa bits: nearest-even on the 13
+    # dropped ones (a carry into the exponent is the right answer)
+    bits = bits + jnp.uint32(0xFFF) + ((bits >> 13) & jnp.uint32(1))
+    normal = jax.lax.bitcast_convert_type(
+        bits & jnp.uint32(0xFFFFE000), jnp.float32)
+    # below 2**-14 fp16 is subnormal: a fixed quantum of 2**-24
+    sub = jnp.round(x * jnp.float32(2.0 ** 24)) * jnp.float32(2.0 ** -24)
+    out = jnp.where(x < jnp.float32(2.0 ** -14), sub, normal)
+    return jnp.where(out > jnp.float32(65504.0), jnp.float32(jnp.inf), out)
+
+
 def _quant_kernel(x_ref, codes_ref, scales_ref, *, q):
     # the oracle's encode chain, verbatim per tile (quant.py):
     # flush -> finite amax -> fp16 scale -> inv -> round/clip -> marker
@@ -70,12 +83,11 @@ def _quant_kernel(x_ref, codes_ref, scales_ref, *, q):
     finite = jnp.isfinite(blocks)
     amax = jnp.max(jnp.where(finite, jnp.abs(blocks), 0.0),
                    axis=1, keepdims=True)
-    scales = (amax / q).astype(jnp.float16)
-    eff = scales.astype(jnp.float32)
+    eff = _round_to_f16(amax / q)
     inv = jnp.where((eff > 0) & jnp.isfinite(eff), 1.0 / eff, 0.0)
     codes = jnp.clip(jnp.round(blocks * inv), -q, q).astype(jnp.int8)
     codes_ref[...] = jnp.where(finite, codes, jnp.int8(-q - 1))
-    scales_ref[...] = jnp.broadcast_to(scales, scales_ref.shape)
+    scales_ref[...] = jnp.broadcast_to(eff, scales_ref.shape)
 
 
 def quantize_blockwise_pallas(x, block: int, wire: str = "int8"):
@@ -105,13 +117,13 @@ def quantize_blockwise_pallas(x, block: int, wire: str = "int8"):
         ],
         out_shape=[
             jax.ShapeDtypeStruct(blocks.shape, jnp.int8),
-            jax.ShapeDtypeStruct((blocks.shape[0], 128), jnp.float16),
+            jax.ShapeDtypeStruct((blocks.shape[0], 128), jnp.float32),
         ],
         compiler_params=_params(1),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(blocks)
     codes = codes[:nb]
-    scales = scales[:nb, 0]
+    scales = scales[:nb, 0].astype(jnp.float16)
 
     if q == 127:
         return codes, scales
@@ -126,9 +138,11 @@ def quantize_blockwise_pallas(x, block: int, wire: str = "int8"):
 
 
 def _dequant_kernel(codes_ref, scales_ref, out_ref, *, marker):
-    codes = codes_ref[...]
-    vals = codes.astype(jnp.float32) * scales_ref[:, :1]
-    out_ref[...] = jnp.where(codes == marker, jnp.float32(jnp.nan), vals)
+    # compared as fp32: the chip has no int8 vector comparison
+    # ("Target does not support this comparison")
+    codes = codes_ref[...].astype(jnp.float32)
+    out_ref[...] = jnp.where(codes == marker, jnp.float32(jnp.nan),
+                             codes * scales_ref[:, :1])
 
 
 def dequantize_blockwise_pallas(payload, scales, wire: str,
@@ -167,7 +181,7 @@ def dequantize_blockwise_pallas(payload, scales, wire: str,
         out_specs=pl.BlockSpec((_TILE, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(codes.shape, jnp.float32),
         compiler_params=_params(1),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(codes, s128)
     flat = vals[:nb].reshape(lead + (-1,))
     return flat[..., :n_elems]

@@ -15,17 +15,22 @@ with:
   capability probing: Pallas is only *selected* natively on a TPU
   backend, gated per-op by `DS_KERNEL_{NAME}=0` (the `DS_BUILD_*`
   convention from ops/op_builder/builder.py);
-* `auto_supports(...)` — the per-call shape heuristic `impl="auto"`
-  consults (e.g. sparse attention's block%128 / head-dim tiling rule).
+* `auto_supports(...)` — the per-call shape rule: what the chip's
+  compiler takes (tests/test_tpu_compile.py compiles every case for a
+  described v5e) plus each op's own limits (e.g. sparse attention's
+  block%128 / head-dim tiling rule).
 
 Selection contract (`resolve_impl`):
 
-* `"auto"`  — pallas iff the probe AND the shape heuristic pass (an
-  autotuner-recorded winner, keyed per fabric fingerprint, overrides
-  the heuristic — see `record_winner`); otherwise the jnp oracle.
-* `"pallas"` — the kernel, NO silent fallback: off-TPU this raises
-  loudly unless the interpret escape is set (`kernels.interpret=true`
-  in the config, or the call-site `interpret_ok=True` that preserves
+* `"auto"`  — pallas iff the probe AND the shape rule pass, unless the
+  autotuner recorded the oracle as the winner on this fabric (see
+  `record_winner`); otherwise the jnp oracle.  Nothing `auto` selects
+  may be refused by the compiler.
+* `"pallas"` — the kernel, NO silent fallback: on a TPU a call the
+  probe or the shape rule refuses raises with that reason, and the
+  compiler is never asked; off-TPU this raises loudly unless the
+  interpret escape is set (`kernels.interpret=true` in the config, or
+  the call-site `interpret_ok=True` that preserves
   `SparseSelfAttention(impl="pallas")`'s historical run-the-kernel-
   under-the-interpreter semantics).
 * `"jnp"` (alias `"xla"`) — the oracle, unconditionally.
@@ -55,6 +60,7 @@ from typing import Dict, Mapping, Optional, Tuple
 import jax
 
 from ..monitor.counters import COUNTERS
+from ..ops import pallas_backend
 from ..utils.logging import logger
 
 KERNEL_IMPLS = ("auto", "pallas", "jnp")
@@ -63,8 +69,32 @@ KERNEL_IMPLS = ("auto", "pallas", "jnp")
 _IMPL_ALIASES = {"xla": "jnp"}
 
 
+# what jax 0.9's Pallas TPU lowering raises for a block it cannot tile
+_BLOCK_RULE = ("\"The Pallas TPU lowering currently requires that the "
+               "last two dimensions of your block shape are divisible by "
+               "8 and 128 respectively, or be equal to the respective "
+               "dimensions of the overall array\"")
+
+
 def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+    return not pallas_backend.interpret()
+
+
+def _unpartitionable() -> str:
+    """Why a native kernel cannot be traced HERE, or "".  XLA does not
+    partition a Mosaic kernel: under `jit` over a mesh of several
+    devices the call must sit inside a `shard_map` that is manual over
+    every axis of size > 1 (the wire codecs do; training attention gets
+    one from ops/transformer/attention.py)."""
+    from ..comm.mesh import peek_mesh
+
+    info = peek_mesh()
+    auto = info.auto_axes() if info is not None else []
+    if not auto:
+        return ""
+    return (f"the call is traced outside a shard_map over mesh axes "
+            f"{auto}, and \"Mosaic kernels cannot be automatically "
+            f"partitioned\"")
 
 
 # ---------------------------------------------------------------------------
@@ -103,8 +133,10 @@ class KernelOp:
 
     def auto_supports(self, variant: str, info: Optional[Mapping]
                       ) -> Tuple[bool, str]:
-        """Per-call shape heuristic for impl='auto' (info is the call
-        site's shape dict; None = no constraint data, assume yes)."""
+        """Per-call shape rule (info is the call site's shape dict;
+        None = no constraint data, assume yes).  False means the chip's
+        compiler, or the kernel itself, refuses the shape: `auto` takes
+        the oracle and a forced `pallas` raises the reason."""
         return True, ""
 
     def check_variant(self, variant: str) -> None:
@@ -216,7 +248,14 @@ class PagedAttentionOp(KernelOp):
                            f"decode kernel (prefill stays on jnp)")
         d = int(info.get("head_dim", 128))
         if d % 128:
-            return False, f"head_dim {d} not lane-aligned (128)"
+            # every GPT-2 size has head_dim 64
+            return False, (f"head_dim {d} is not a multiple of 128: "
+                           f"{_BLOCK_RULE} refuses the gathered "
+                           f"({bs}, {d}) cache tile")
+        if info.get("kv_mode", "dense") == "int4" and d % 256:
+            return False, (f"int4 KV at head_dim {d} packs rows of "
+                           f"{d // 2} bytes: {_BLOCK_RULE} refuses the "
+                           f"({bs}, {d // 2}) payload tile")
         return True, ""
 
     def pallas(self, variant, *args, **kwargs):
@@ -275,13 +314,20 @@ class MoEDispatchOp(KernelOp):
     VARIANTS = ("dispatch", "combine")
     EXACT = True
 
-    def auto_supports(self, variant, info):
-        if not info:
-            return True, ""
-        d = int(info.get("model_dim", 128))
-        if d % 128:
-            return False, f"model dim {d} not lane-aligned (128)"
-        return True, ""
+    # Both kernels move ONE token row per grid step, and a gather has
+    # no 8-row tile to move instead (kernels/moe_kernels.py): until
+    # they are rewritten they run under the interpreter only, and the
+    # chip gets the jnp scatter/gather.
+
+    def is_compatible(self) -> bool:
+        return False
+
+    def compatibility_message(self) -> str:
+        if self.env_enabled() and _on_tpu():
+            return (f"the kernel moves tokens in one-row blocks "
+                    f"(1, model_dim), which the chip's compiler refuses: "
+                    f"{_BLOCK_RULE}")
+        return super().compatibility_message()
 
     def pallas(self, variant, *args, **kwargs):
         from . import moe_kernels
@@ -484,8 +530,20 @@ def resolve_impl(name: str, variant: str = "default",
         raise ValueError(
             f"kernels.{name}: impl must be one of {KERNEL_IMPLS}, "
             f"got {choice!r}")
+    supported, why = op.auto_supports(variant, info)
+    if supported and _on_tpu():
+        why = _unpartitionable()
+        supported = not why
     if choice == "pallas":
-        if not (op.is_compatible() or interpret_ok or cfg.interpret):
+        if _on_tpu():
+            # native: nothing the chip's compiler refuses may reach it
+            if not op.is_compatible():
+                why = op.compatibility_message()
+            if why:
+                raise RuntimeError(
+                    f"kernels.{name}: impl='pallas' forced but {why}; "
+                    f"use impl='auto' for the jnp oracle")
+        elif not (interpret_ok or cfg.interpret):
             raise RuntimeError(
                 f"kernels.{name}: impl='pallas' forced but "
                 f"{op.compatibility_message()}; use impl='auto' for the "
@@ -494,13 +552,9 @@ def resolve_impl(name: str, variant: str = "default",
         return "pallas"
     if choice == "jnp":
         return "jnp"
-    # auto: an autotuned winner (fabric-matched) overrides the heuristic
-    w = winner_for(name)
-    if w == "jnp":
-        return "jnp"
-    if w == "pallas" and op.is_compatible():
-        return "pallas"
-    if op.is_compatible() and op.auto_supports(variant, info)[0]:
+    # auto: the kernel where it runs natively and the compiler takes the
+    # shape, unless the autotuner measured the oracle faster here
+    if op.is_compatible() and supported and winner_for(name) != "jnp":
         return "pallas"
     return "jnp"
 

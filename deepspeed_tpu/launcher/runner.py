@@ -166,14 +166,16 @@ def _export_env_lines() -> List[str]:
 def _probe_local_slots() -> int:
     """Local device count WITHOUT initializing jax in this process (TPU
     runtime allows one owner process; the trainer child must be it)."""
-    try:
-        out = subprocess.run(
-            [sys.executable, "-c",
-             "import jax; print(jax.local_device_count())"],
-            capture_output=True, text=True, timeout=120)
-        return max(1, int(out.stdout.strip().splitlines()[-1]))
-    except Exception:
-        return 1
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(jax.local_device_count())"],
+        capture_output=True, text=True, timeout=120)
+    if out.returncode != 0:
+        # a child that cannot reach its devices is not "one slot"
+        raise RuntimeError(
+            f"cannot count local devices: the probe exited "
+            f"{out.returncode}: {out.stderr.strip()[-500:]}")
+    return int(out.stdout.strip().splitlines()[-1])
 
 
 def _is_local_host(host: str) -> bool:

@@ -366,22 +366,18 @@ def _softmax_xent_from_hidden(x, w, labels, valid, n_chunks=0,
         impl = "xla"
     if impl == "pallas":
         from ..comm.mesh import peek_mesh
-        from ..ops.transformer.fused_xent import fused_softmax_xent_sum
+        from ..ops.transformer.fused_xent import (fused_softmax_xent_sum,
+                                                  pick_blocks)
 
         info = peek_mesh()
         if info is not None and info.mesh.shape.get("model", 1) > 1:
             raise ValueError(
                 "loss_impl='pallas' is invalid with vocab-parallel TP "
                 "(model axis > 1): the kernel's logsumexp is row-global")
-        # block sizes must divide the shapes; vocab 50304 = 393*128 takes
-        # 384, the padded-to-128 GPT-2 family always has a lane-aligned
-        # divisor
-        br = next((b for b in (256, 128) if N % b == 0), None)
-        bv = next((b for b in (512, 448, 384, 256, 128) if V % b == 0),
-                  None)
-        if br and bv:
+        blocks = pick_blocks(N, V)
+        if blocks:
             return fused_softmax_xent_sum(x, jnp.asarray(w), labels, valid,
-                                          br, bv)
+                                          *blocks)
         from ..utils.logging import logger
 
         logger.warning(f"loss_impl='pallas': shapes N={N}, V={V} have no "

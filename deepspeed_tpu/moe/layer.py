@@ -292,7 +292,7 @@ class MoE:
                            "b1": P(expert_spec, None),
                            "w2": P(expert_spec, None, None),
                            "b2": P(expert_spec, None)}
-        axis_names = set(mesh_info.data_axes)
+        axis_names = mesh_info.manual_axes(mesh_info.data_axes)
         smapped = jax.shard_map(
             body, mesh=mesh_info.mesh,
             in_specs=(P(), expert_in_specs, P(data_spec, None, None),

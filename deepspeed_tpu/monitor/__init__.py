@@ -12,7 +12,7 @@ utils/tensorboard, profiling/flops_profiler) into a single pipeline:
 * `COUNTERS` — process-global comm/dispatch counters threaded through
   the p2p channels, the compiled pipeline executor, the collective
   wrappers, and the hostwire.
-* `report` — renders any run's JSONL back into a BENCH.md-style table
+* `report` — renders any run's JSONL back into a markdown table
   (CLI: tools/run_report.py).
 """
 

@@ -7,7 +7,7 @@ throughput, achieved TFLOPs, loss-scale/overflow bookkeeping, device
 memory stats aggregated over all local devices, and the per-step comm
 counter deltas (monitor/counters.py).  A manifest written at
 construction makes the run self-describing; `tools/run_report.py`
-renders any run dir back into a BENCH.md-style table.
+renders any run dir back into a markdown table.
 
 Sinks: the JSONL stream is primary; an attached `TensorBoardMonitor`
 (utils/tensorboard.py) receives the scalar subset of every event.

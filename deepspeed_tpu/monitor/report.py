@@ -1,4 +1,4 @@
-"""Run-report rendering: JSONL event stream -> BENCH.md-style table.
+"""Run-report rendering: JSONL event stream -> markdown table.
 
 Shared by `tools/run_report.py` (CLI) and the tests; keeps every schema
 assumption in one place next to the writer (monitor.py)."""
@@ -192,7 +192,7 @@ def _fmt_bytes(b):
 
 
 def render_markdown(run: Dict[str, Any]) -> str:
-    """BENCH.md-style report for a loaded run (load_run output)."""
+    """Markdown report for a loaded run (load_run output)."""
     lines = [f"# Run report: `{run['dir']}`", ""]
     man = run.get("manifest")
     if man:

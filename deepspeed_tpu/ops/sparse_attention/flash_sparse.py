@@ -32,14 +32,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import pallas_backend
 from ..transformer.flash_attention import (_compiler_params, _keep_mask,
                                            derive_seed)
 
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def layout_tables(layout: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -159,7 +156,7 @@ def _fwd(q, k, v, tbl, seed, causal, scale, blk, H, rate):
             jax.ShapeDtypeStruct((BH, S, 128), jnp.float32),
         ],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(tbl, seed, q, k, v)
     return out, lse
 
@@ -293,7 +290,7 @@ def _bwd(causal, scale, blk, H, rate, tables, res, dout):
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(fwd_tbl, seed, q, k, v, dout, lse, delta)
 
     dkv_spec = pltpu.PrefetchScalarGridSpec(
@@ -333,7 +330,7 @@ def _bwd(causal, scale, blk, H, rate, tables, res, dout):
             jax.ShapeDtypeStruct((BH, S, D), v.dtype),
         ],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(rev_tbl, seed, q, k, v, dout, lse, delta)
     return dq, dk, dv
 

@@ -14,20 +14,20 @@ Shapes follow [batch, seq, heads, head_dim] (BSHD).
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from .. import pallas_backend
+
 _FLASH_MIN_SEQ = 256  # below this the [S,S] buffer fits easily; XLA wins
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return not pallas_backend.interpret()
 
 
 _AD_TRACER_NAMES = ("JVPTracer", "LinearizeTracer")
@@ -95,6 +95,62 @@ def xla_attention(q, k, v, causal=True, bias=None, dropout_rate=0.0,
     return out.astype(q.dtype)
 
 
+def _flash_per_shard(flash, q, k, v, key_bias, dropout_rng, bh_offset,
+                     **kw):
+    """Call the flash kernel once per shard of the current mesh.
+
+    XLA cannot partition a Mosaic kernel: under `jit` over a mesh of
+    more than one device the native lowering raises "Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map" (first met on four v5e chips, PR 22; the interpreter
+    off-TPU lowers to plain ops and never shows it).  Attention is
+    independent per batch row and per head, so the call is made under a
+    `shard_map` over every mesh axis that is not manual already: batch
+    rows split over the data axes, heads over `model`, everything else
+    replicated.  The dropout hash is keyed by the GLOBAL batch*head
+    index (`bh_offset`), which a head split cannot express, so with
+    dropout active the heads stay whole."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...comm.mesh import MODEL_AXIS, peek_mesh
+
+    def call(q, k, v, key_bias, dropout_rng, bh_offset):
+        return flash(q, k, v, key_bias=key_bias, dropout_rng=dropout_rng,
+                     bh_offset=bh_offset, **kw)
+
+    info = peek_mesh()
+    if info is None or not info.auto_axes():
+        return call(q, k, v, key_bias, dropout_rng, bh_offset)
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    B, H = q.shape[0], q.shape[2]
+    data_axes = tuple(a for a in info.data_axes if a not in manual)
+    if B % math.prod(info.axis_size(a) for a in data_axes):
+        data_axes = ()
+    batch = data_axes or None
+    mp = info.axis_size(MODEL_AXIS)
+    heads = MODEL_AXIS if (mp > 1 and H % mp == 0 and dropout_rng is None
+                           and MODEL_AXIS not in manual) else None
+    qkv = P(batch, None, heads, None)
+    bias = None if key_bias is None else P(
+        batch if key_bias.shape[0] == B else None,
+        *([None] * (key_bias.ndim - 1)))
+
+    def body(q, k, v, key_bias, dropout_rng, bh_offset):
+        if dropout_rng is not None and data_axes:
+            rank = 0
+            for a in data_axes:  # outer-major, the mesh's device order
+                rank = rank * info.axis_size(a) + jax.lax.axis_index(a)
+            bh_offset = bh_offset + rank * (q.shape[0] * q.shape[2])
+        return call(q, k, v, key_bias, dropout_rng, bh_offset)
+
+    return jax.shard_map(
+        body, mesh=info.mesh,
+        in_specs=(qkv, qkv, qkv, bias, P(), P()), out_specs=qkv,
+        axis_names=set(info.mesh.axis_names) - manual,
+        check_vma=False)(q, k, v, key_bias, dropout_rng,
+                         jnp.asarray(bh_offset, jnp.int32))
+
+
 def multihead_attention(q, k, v, causal: bool = True, impl: str = "auto",
                         bias=None, dropout_rate: float = 0.0,
                         dropout_rng=None, train: bool = False,
@@ -137,11 +193,11 @@ def multihead_attention(q, k, v, causal: bool = True, impl: str = "auto",
         bq = block_q or DEFAULT_BLOCK_Q
         bk = block_k or DEFAULT_BLOCK_K
         if S % bq == 0 and k.shape[1] % bk == 0:
-            return flash_attention(
-                q, k, v, causal=causal, scale=scale, block_q=bq, block_k=bk,
-                dropout_rate=dropout_rate if want_dropout else 0.0,
-                dropout_rng=dropout_rng if want_dropout else None,
-                key_bias=key_bias, bh_offset=bh_offset)
+            return _flash_per_shard(
+                flash_attention, q, k, v, key_bias,
+                dropout_rng if want_dropout else None, bh_offset,
+                causal=causal, scale=scale, block_q=bq, block_k=bk,
+                dropout_rate=dropout_rate if want_dropout else 0.0)
         if block_q or block_k:
             # explicit tuning request that cannot tile: say so instead of
             # silently paying the O(S^2) XLA path

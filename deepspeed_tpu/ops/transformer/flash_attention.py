@@ -26,13 +26,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import pallas_backend
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30  # large-negative instead of -inf: keeps masked rows NaN-free
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -93,16 +91,8 @@ def derive_seed(dropout_rate, dropout_rng):
     return jnp.zeros((1,), jnp.int32), 0.0
 
 
-def compiler_params_cls():
-    # jax renamed TPUCompilerParams -> CompilerParams; accept either so
-    # the kernels run across the jax versions the repo supports (shared
-    # by every Pallas kernel in the repo — fix renames HERE only)
-    return (getattr(pltpu, "CompilerParams", None)
-            or getattr(pltpu, "TPUCompilerParams"))
-
-
 def _compiler_params():
-    return compiler_params_cls()(
+    return pltpu.CompilerParams(
         dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL, pltpu.ARBITRARY))
 
 
@@ -215,7 +205,7 @@ def _fwd(q, k, v, seed, kb, causal, scale, bq, bk, rate, n_heads):
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(*operands)
     return out, lse
 
@@ -375,7 +365,7 @@ def _bwd(causal, scale, bq, bk, rate, n_heads, res, dout):
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(*dq_operands)
 
     dkv_specs = [
@@ -411,7 +401,7 @@ def _bwd(causal, scale, bq, bk, rate, n_heads, res, dout):
             pltpu.VMEM((bk, D), jnp.float32),
         ],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(*dkv_operands)
     return dq, dk, dv
 

@@ -29,19 +29,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import pallas_backend
+
 DEFAULT_BLOCK_ROWS = 256
 DEFAULT_BLOCK_V = 512
 NEG_INF = -1e30
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _params():
-    from .flash_attention import compiler_params_cls
-
-    return compiler_params_cls()(
+    return pltpu.CompilerParams(
         dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY))
 
 
@@ -82,6 +78,12 @@ def _fwd_kernel(x_ref, w_ref, lab_ref, lse_ref, ll_ref, m_s, l_s, ll_s, *,
 def _fwd(x, w, labels, br, bv) -> Tuple[jax.Array, jax.Array]:
     N, D = x.shape
     V = w.shape[1]
+    if N % br or V % bv:
+        # the grids are N // br x V // bv, here and in the backward: a
+        # remainder would be dropped without a word
+        raise ValueError(
+            f"fused CE blocks ({br}, {bv}) do not divide N={N}, V={V} "
+            f"(pick_blocks chooses ones that do)")
     nr, nv = N // br, V // bv
     lse, ll = pl.pallas_call(
         functools.partial(_fwd_kernel, bv=bv, nv=nv),
@@ -105,7 +107,7 @@ def _fwd(x, w, labels, br, bv) -> Tuple[jax.Array, jax.Array]:
             pltpu.VMEM((br, 128), jnp.float32),
         ],
         compiler_params=_params(),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(x, w, labels[:, None])
     return lse[:, 0], ll[:, 0]
 
@@ -183,7 +185,7 @@ def _bwd(br, bv, res, g):
         out_shape=jax.ShapeDtypeStruct((N, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((br, D), jnp.float32)],
         compiler_params=_params(),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(x, w, lab, jnp.broadcast_to(lse[:, None], (N, 128)), coef)
 
     dw = pl.pallas_call(
@@ -200,9 +202,19 @@ def _bwd(br, bv, res, g):
         out_shape=jax.ShapeDtypeStruct((D, V), w.dtype),
         scratch_shapes=[pltpu.VMEM((D, bv), jnp.float32)],
         compiler_params=_params(),
-        interpret=_interpret(),
+        interpret=pallas_backend.interpret(),
     )(x, w, lab, jnp.broadcast_to(lse[:, None], (N, 128)), coef)
     return dx, dw, None, None
+
+
+def pick_blocks(n_rows: int, vocab: int):
+    """(block_rows, block_v) that divide the shapes, or None.  Vocab
+    50304 = 393*128 takes 384; the padded-to-128 GPT-2 family always
+    has a lane-aligned divisor."""
+    br = next((b for b in (256, 128) if n_rows % b == 0), None)
+    bv = next((b for b in (512, 448, 384, 256, 128) if vocab % b == 0),
+              None)
+    return (br, bv) if br and bv else None
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))

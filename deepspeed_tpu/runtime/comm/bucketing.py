@@ -27,7 +27,7 @@ recipe (stage2.py:614-745 flatten/reduce machinery, ZeRO §5 of
               locally.  Per-contribution relative error is ≤ 2^-11
               (fp16 mantissa) — tighter than bf16's 2^-8 — and, unlike
               an arithmetic reduce (which XLA upcasts BEFORE the
-              transfer, see BENCH.md round-5 methodology note), gather
+              transfer), gather
               semantics keep the narrow dtype ON the wire.
 * For ZeRO stage >= 2 the bucket reduction lowers to `psum_scatter`
   (reduce-scatter): each dp rank materializes only the bucket shards its
@@ -341,8 +341,7 @@ class BucketPlan:
         hierarchical outer hop: fp16 mantissa + int8 exponent of `x`
         all-gather over `axis`, ldexp-reconstruct and sum locally in
         fp32.  Gather semantics keep the narrow dtypes ON the wire — an
-        arithmetic reduce upcasts before the transfer (BENCH.md round-5
-        methodology note)."""
+        arithmetic reduce upcasts before the transfer."""
         from .compressed_ar import decompose_int8_safe
 
         mantissa, exponent = decompose_int8_safe(x)
@@ -363,7 +362,7 @@ class BucketPlan:
         only for the wire, so the error never compounds across ranks.
         One buffer matters: on latency-bound fabrics a separate scales
         collective would cost a second round-trip and hand the latency
-        win right back (BENCH.md round-11 methodology note)."""
+        win right back."""
         from .quant import quantized_all_gather
 
         per_rank = quantized_all_gather(

@@ -86,7 +86,7 @@ def int8_compressed_allreduce(x, worker_error, server_error, axis):
     """Error-compensated INT8 compressed mean over `axis` — the
     TPU-native compression SURVEY §2.3 recommends in place of bit-packing:
     XLA has no packed-int1 wire format (sign compression rides pmean at
-    full width, measured in BENCH.md), but int8 collectives transmit
+    full width), but int8 collectives transmit
     int8, so this genuinely cuts wire bytes ~4x vs fp32.
 
     Same two-stage structure as the reference's 1-bit backends

@@ -12,7 +12,7 @@ Two things only a host wire can do here:
 
 * a TRUE 1-bit wire format: np.packbits ships 1 bit/element + one fp32
   scale. XLA has no packed-int1 type, so the in-jit sign path travels at
-  full width (measured negative result, BENCH.md "1-bit Adam measured");
+  full width (a measured negative result);
   the reference needed CuPy bit-packing for exactly this
   (deepspeed/runtime/compression/cupy.py) — packbits is its host-side
   twin.

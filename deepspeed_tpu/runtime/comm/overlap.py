@@ -15,7 +15,7 @@ spend most of that in `recv` — idle time the device pipeline runs
 straight through.  On TPU fabrics the same schedule-driven structure
 lets XLA's latency-hiding scheduler do the overlap in-program; on this
 fabric the host exchange IS the overlap mechanism, and the bench
-measures the exposure honestly either way (BENCH.md overlap round).
+measures the exposure honestly either way.
 
 The pieces:
 

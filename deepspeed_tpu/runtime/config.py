@@ -127,7 +127,7 @@ class DeepSpeedCommConfig(DeepSpeedConfigObject):
     loss-mean boundary — right on ICI, where XLA overlaps the per-leaf
     psums with the backward.  `bucketed` concatenates grads into the
     BucketPlan's fused buckets, one collective per bucket — measured 2x+
-    faster on serialization-bound fabrics (BENCH.md grad-wire rounds).
+    faster on the two-process CPU/TCP lane; not measured on a TPU.
     The reference's top-level `fp32_allreduce` key forces wire_dtype to
     fp32 (the engine's `allreduce_always_fp32()` reflects the result).
 

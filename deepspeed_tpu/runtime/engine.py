@@ -16,7 +16,7 @@ the execution model is TPU-native:
   static BucketPlan (runtime/comm/bucketing.py): one fused collective
   per dtype bucket — the reference's `reduce_bucket_size` machinery
   (engine.py:1323-1396, zero/stage2.py:614-745), measured 2x+ faster on
-  serialization-bound fabrics (BENCH.md grad-wire round).
+  the two-process CPU/TCP lane; not measured on a TPU.
 * One jitted `_apply_step` unscales, checks overflow, clips, runs the fused
   optimizer, applies ZeRO sharding constraints, and updates the loss-scale
   state — the skip-on-overflow decision is a branchless select inside the
@@ -252,7 +252,7 @@ class DeepSpeedEngine:
             opt_state = self.optimizer.init(self._params)
             self._opt_state = jax.device_put(
                 opt_state, self.zero_plan.opt_state_shardings(opt_state))
-        self._scaler_state = self.loss_scaler.jit_state()
+        self._scaler_state = self._on_mesh(self.loss_scaler.jit_state())
         self._grad_acc = None  # lazily built zeros, sharded per grad_spec
         self._cached = None    # (loss, grads) from forward awaiting backward
 
@@ -499,6 +499,10 @@ class DeepSpeedEngine:
         return comm.elastic_device_slice(sw * other)
 
     def _build_mesh(self, config, mpu) -> MeshInfo:
+        if isinstance(mpu, MeshInfo):
+            # the caller's own mesh, e.g. over some of the host's devices
+            comm.set_current_mesh(mpu)
+            return mpu
         if isinstance(config, str):
             # file-path configs must drive the mesh/hierarchy exactly
             # like dict configs; a bad path surfaces as DeepSpeedConfig's
@@ -1145,10 +1149,9 @@ class DeepSpeedEngine:
             blockers.append("dp==1 (nothing to gather)")
         for ax in (MODEL_AXIS, PIPE_AXIS, SEQ_AXIS):
             if self.mesh_info.axis_size(ax) > 1:
-                # on legacy jax the shard_map axis_names shim runs FULL
-                # manual, where the gather's data-only specs would
-                # silently replicate TP-sharded leaves to full width —
-                # a memory hazard, not a fallback; pure-DP only
+                # the gather region's specs name the data axes only,
+                # and `MeshInfo.manual_axes` makes a region fully manual
+                # when its other axes have size 1; pure-DP only
                 blockers.append(f"{ax} axis > 1 (mixed-axis meshes keep "
                                 "the full-width gather)")
         if self._offload is not None:
@@ -1658,6 +1661,14 @@ class DeepSpeedEngine:
                        PartitionSpec()),
             axis_names={DATA_AXIS}, check_vma=False)
         return jax.jit(smapped, donate_argnums=(0, 1))
+
+    def _on_mesh(self, tree):
+        """Host-made step state, replicated on the mesh as the step
+        program's own outputs are.  Left as plain arrays, the second
+        call's argument types differ from the first's and the whole
+        step compiles twice (40 s + 29 s at 16 GPT-2 xl layers on a
+        v5e, chip run of PR 22)."""
+        return jax.device_put(tree, self.mesh_info.replicated())
 
     def _zero_grad_acc(self):
         zeros = jax.tree_util.tree_map(
@@ -2904,7 +2915,7 @@ class DeepSpeedEngine:
         (micro_per_gpu * dp_world) per forward, and EVERY process
         assembles the SAME global batch: `device_put(host_value,
         global_sharding)` treats each process's value as the global
-        array (the same-value-everywhere contract, _compat.py), so a
+        array (the same-value-everywhere contract), so a
         process-strided per-shard slice here would hand it W different
         "globals" and silently train on a torn mix of them — found by
         the elastic campaign's cross-width loss-parity pin.  Each
@@ -3231,9 +3242,9 @@ class DeepSpeedEngine:
                     self._infinity.load_state_dict(
                         optim_state["optimizer_state"])
             if model_state.get("loss_scaler") is not None:
-                self._scaler_state = {
+                self._scaler_state = self._on_mesh({
                     k: jnp.asarray(v)
-                    for k, v in model_state["loss_scaler"].items()}
+                    for k, v in model_state["loss_scaler"].items()})
             if load_lr_scheduler_states and self.lr_scheduler is not None \
                     and model_state.get("lr_scheduler") is not None:
                 self.lr_scheduler.load_state_dict(model_state["lr_scheduler"])
@@ -3287,8 +3298,9 @@ class DeepSpeedEngine:
                         lambda x: x.item() if hasattr(x, "item") and
                         getattr(x, "ndim", 1) == 0 else x, hparams))
         if model_state.get("loss_scaler") is not None:
-            self._scaler_state = {
-                k: jnp.asarray(v) for k, v in model_state["loss_scaler"].items()}
+            self._scaler_state = self._on_mesh({
+                k: jnp.asarray(v)
+                for k, v in model_state["loss_scaler"].items()})
         if load_lr_scheduler_states and self.lr_scheduler is not None and \
                 model_state.get("lr_scheduler") is not None:
             self.lr_scheduler.load_state_dict(model_state["lr_scheduler"])

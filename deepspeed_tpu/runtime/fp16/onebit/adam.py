@@ -48,7 +48,7 @@ class OnebitAdam:
         self.eps_inside_sqrt = eps_inside_sqrt
         # wire="int8": quantized all_to_all/allgather instead of sign
         # compression — the variant whose wire bytes XLA actually shrinks
-        # (~4x vs fp32; sign rides pmean at full width — see BENCH.md)
+        # (~4x vs fp32; sign rides pmean at full width)
         self.wire = wire
 
     @property

@@ -1,6 +1,6 @@
 """Pipeline schedule compiler — flat per-rank programs for the 1F1B walk.
 
-BENCH.md round-5 measured the interpreted canonical walk at ~300 µs of
+The interpreted canonical walk spends its time in
 serialized Python per schedule event (schedule-stream regeneration +
 dependency re-simulation + isinstance dispatch + counter/dict/mail
 bookkeeping, every train_batch), 12-16 % of step time on CPU-mesh grains

@@ -261,7 +261,7 @@ class PipelineEngine(DeepSpeedEngine):
             or self._config.pipe_use_p2p_channels))
         # the interpreted per-event walk is the parity oracle and the
         # bring-up executor; the compiled flat program is the default
-        # (BENCH.md round-5: ~300 us of serialized Python per event)
+        # (the walk re-derives every event in serialized Python)
         self._debug_schedule = bool(self._config.pipe_debug_schedule)
         self._pipe_prog = None
         self._bound_cache: Dict[Any, Any] = {}
@@ -278,6 +278,11 @@ class PipelineEngine(DeepSpeedEngine):
     # ------------------------------------------------------------------
     # staged construction
     # ------------------------------------------------------------------
+
+    def _on_mesh(self, tree):
+        # stage programs run on per-stage device groups and take the
+        # loss scale as an operand: it stays an uncommitted array
+        return tree
 
     def _build_stages(self):
         module: PipelineModule = self.module

@@ -206,7 +206,7 @@ def _paged_block(p, cfg, x, ck, cv, write_idx, rows, q_pos,
     attn = registry.dispatch(
         "paged_attention", q, ck, cv, rows, q_pos,
         info={"block_size": block_size, "kv_len": rows.shape[1],
-              "q_len": T, "head_dim": Dh},
+              "q_len": T, "head_dim": Dh, "kv_mode": kv_mode},
         kv_mode=kv_mode, block_size=block_size)
     attn = attn.reshape(B, T, D)
     attn = attn @ p["attn"]["proj"]["w"].astype(h.dtype) + \

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import argparse
 
-from common import print_curve  # noqa: E402  (pins platform)
+from common import print_curve  # noqa: E402
 
 import jax
 import jax.numpy as jnp

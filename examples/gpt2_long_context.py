@@ -15,7 +15,7 @@ from __future__ import annotations
 import argparse
 import time
 
-from common import print_curve, token_batches  # noqa: E402  (pins platform)
+from common import print_curve, token_batches  # noqa: E402
 
 import jax
 import numpy as np

@@ -23,7 +23,7 @@ import socket
 import subprocess
 import sys
 
-from common import print_curve, token_batches  # noqa: E402  (pins platform)
+from common import print_curve, token_batches  # noqa: E402
 
 V, D = 128, 32
 MICRO, M = 4, 4

@@ -29,8 +29,6 @@ def main():
     here = os.path.dirname(os.path.abspath(__file__))
     sys.path.insert(0, here)
     sys.path.insert(0, os.path.join(here, ".."))
-    # import BEFORE jax.process_count(): the _compat gloo-collectives
-    # flag must be set before the CPU client exists
     import deepspeed_tpu
     from simple_model import SimpleModel
 

@@ -25,8 +25,6 @@ def main():
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), ".."))
-    # import BEFORE any jax.device_count()/process_count(): the _compat
-    # gloo-collectives flag must be set before the CPU client exists
     import deepspeed_tpu
     from simple_model import SimpleModel
 

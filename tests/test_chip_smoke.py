@@ -1,0 +1,98 @@
+"""chip_smoke.py, rehearsed off the chip (on-chip-measurement §2, first
+and second rehearsal): its phases at toy size through the functions the
+script is made of, kernels under the Pallas interpreter, the four-chip
+phase on four of the virtual CPU devices — and the plumbing around it:
+the device check that refuses anything but a TPU, and the compile-cache
+helper.  The script itself has no CPU mode.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+import jax
+
+ROOT = os.path.join(os.path.dirname(__file__), "..")
+sys.path.insert(0, ROOT)
+
+import chip_smoke  # noqa: E402
+
+TOY = dict(size="nano", depth=2, seq=128)
+
+
+def test_train_phase_toy():
+    out = chip_smoke.train_phase(**TOY, micro=2, steps=3, on_chip=False)
+    assert out["losses"][-1] < out["losses"][0]
+    assert out["pallas_interpret"] and not out["flash_in_lowered_step"]
+
+
+def test_train_phase_misses_the_flash_kernel_off_chip():
+    with pytest.raises(RuntimeError, match="flash kernel is not in"):
+        chip_smoke.train_phase(**TOY, micro=2, steps=1)
+
+
+def test_serve_phase_toy():
+    serve = dict(block_size=4, max_batch=4, max_seq_len=128,
+                 prefill_chunk=16, num_blocks=1 + 4 * 32)
+    out = chip_smoke.serve_phase(
+        **TOY, serve=serve, prompt_lens=(3, 5, 9, 12, 17, 20, 26, 40),
+        max_new=6, param_dtype=jax.numpy.float32)
+    assert out["requests"] == 8 and all(out["first_token_equals_generate"])
+    assert out["joined_while_decoding"] > 0
+    assert out["token_agreement_share"] == 1.0  # fp32 on CPU: exact
+
+
+def test_kernels_phase_toy():
+    out = chip_smoke.kernels_phase(batch=1, seq=256, heads=2, head_dim=64,
+                                   d_model=64, vocab=1024, paged_heads=2,
+                                   paged_slots=2, paged_width=4,
+                                   on_chip=False)
+    assert [k["kernel"] for k in out["kernels"]] == [
+        "flash_attention_fwd", "flash_attention_bwd", "fused_xent_fwd",
+        "fused_xent_bwd", "paged_attention_dense"]
+
+
+def test_four_chip_phase_on_four_virtual_devices():
+    out = chip_smoke.four_chip_phase(**TOY, micro=1, steps=3, on_chip=False)
+    assert out["optimizer_state_devices"] == [0, 1, 2, 3]
+    assert out["grad_accumulator_devices"] == [0, 1, 2, 3]
+    assert out["collectives_in_step"]
+
+
+def test_script_refuses_a_cpu_backend():
+    """Non-zero exit, and the last line is the failure the contract
+    prescribes: never `"ok": true` without a TPU."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "chip_smoke.py"], cwd=ROOT, env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode != 0
+    last = json.loads(r.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False and last["phase"] == "device"
+    assert "needs a TPU" in last["error"]
+    assert '"ok": true' not in r.stdout
+
+
+@pytest.mark.parametrize("placed", [True, False],
+                         ids=["env_dir", "in_checkout"])
+def test_compile_cache_dir(placed, monkeypatch, tmp_path):
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if placed:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = os.path.join(os.path.abspath(ROOT), ".jax_cache")
+            assert os.path.abspath(enable_compile_cache()) == want
+            assert jax.config.jax_compilation_cache_dir == \
+                enable_compile_cache()
+    finally:
+        # the tests run with the cache off (conftest.py)
+        jax.config.update("jax_compilation_cache_dir", before)

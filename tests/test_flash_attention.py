@@ -346,8 +346,6 @@ def test_dropout_shard_offset_decorrelates_and_matches_global():
     run bit-for-bit; without the offset both batch shards draw the
     IDENTICAL local mask pattern (the correlation this fixes)."""
     from jax.sharding import Mesh, PartitionSpec as P
-    # jax.shard_map: native on current jax; installed by
-    # deepspeed_tpu._compat (with check_vma translation) on older jax
     shard_map = jax.shard_map
 
     B, S, H, D = 2, 256, 2, 64  # batch of 2 -> one row per shard
@@ -377,3 +375,36 @@ def test_dropout_shard_offset_decorrelates_and_matches_global():
     np.testing.assert_array_equal(without[:1], np.asarray(full)[:1])
     # ...but shard 1 reused shard 0's mask pattern instead of its own
     assert not np.array_equal(without[1:], np.asarray(full)[1:])
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.3], ids=["plain", "dropout"])
+def test_kernel_runs_per_shard_on_a_mesh(rate):
+    """On a mesh the dispatcher calls the kernel under a shard_map
+    (a Mosaic kernel cannot be partitioned by XLA): batch over `data`,
+    heads over `model` — whole heads under dropout, whose hash is keyed
+    by the global batch*head index, so the sharded run equals the
+    unsharded one bit for bit either way."""
+    from deepspeed_tpu.comm import mesh as mesh_mod
+    from deepspeed_tpu.comm.mesh import make_mesh
+    from deepspeed_tpu.ops.transformer.attention import multihead_attention
+
+    B, S, H, D = 4, 256, 4, 64
+    q, k, v = _make_qkv(jax.random.PRNGKey(21), B=B, S=S, H=H, D=D)
+    kw = dict(causal=True, impl="pallas", dropout_rate=rate,
+              dropout_rng=jax.random.PRNGKey(5), train=True)
+    mesh_mod._CURRENT_MESH = None
+    want = jax.jit(lambda *a: multihead_attention(*a, **kw))(q, k, v)
+    info = make_mesh(data=4, model=2)
+    sharded = jax.device_put((q, k, v), info.sharding("data"))
+    f = jax.jit(lambda *a: multihead_attention(*a, **kw))
+    assert "shard_map" in str(jax.make_jaxpr(f)(*sharded))
+    np.testing.assert_array_equal(np.asarray(f(*sharded)), np.asarray(want))
+    g = jax.jit(jax.grad(lambda *a: jnp.sum(multihead_attention(*a, **kw)
+                                            ** 2), argnums=(0, 1, 2)))
+    got = g(*sharded)
+    mesh_mod._CURRENT_MESH = None  # a new trace: no mesh, no shard_map
+    g = jax.jit(jax.grad(lambda *a: jnp.sum(multihead_attention(*a, **kw)
+                                            ** 2), argnums=(0, 1, 2)))
+    for a, b in zip(got, g(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)

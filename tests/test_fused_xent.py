@@ -107,3 +107,14 @@ def test_dispatch_rejects_tp_mesh():
     with pytest.raises(ValueError, match="vocab-parallel"):
         gpt_mod._softmax_xent_from_hidden(x, w, labels, valid,
                                           impl="pallas")
+
+
+def test_blocks_that_do_not_divide_are_refused():
+    """GPT-2's padded vocab 50304 = 393 * 128 is not a multiple of the
+    default block of 512: the grid would drop the last 128 columns."""
+    from deepspeed_tpu.ops.transformer.fused_xent import pick_blocks
+
+    assert pick_blocks(4096, 50304) == (256, 384)
+    x, w, labels, valid = _inputs()
+    with pytest.raises(ValueError, match="do not divide"):
+        fused_softmax_xent_sum(x, w[:, :-128], labels, valid, BR, BV)

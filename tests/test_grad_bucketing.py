@@ -200,7 +200,8 @@ def test_split_wire_exponent_range_safety():
 
     out = np.asarray(jax.shard_map(
         local, mesh=info.mesh, in_specs=(P(),), out_specs=P(),
-        axis_names={DATA_AXIS}, check_vma=False)(tree)["g"])
+        axis_names=info.manual_axes({DATA_AXIS}),
+        check_vma=False)(tree)["g"])
     assert out[0] == 0.0, "subnormal must flush, not wrap to ~2^108"
     assert not np.isfinite(out[1]), "2^127 tail must trip overflow"
     np.testing.assert_allclose(out[2], 1.5, rtol=1e-3)

@@ -303,7 +303,7 @@ def test_streamed_checkpoint_mid_accumulation(tmp_path):
 
 
 def test_streamed_zigzag_matches_ring():
-    """Zigzag SP composes with Infinity streaming (VERDICT r4 weak #5):
+    """Zigzag SP composes with Infinity streaming (an earlier review's weak point):
     the streamed boundary applies the layout permutation once
     (stream_embed) and inverts it at the head.  Fast representative:
     raw fp32 GRADIENT parity of one streamed micro step vs the streamed
@@ -356,7 +356,7 @@ def test_streamed_zigzag_trains_like_ring():
 def test_streamed_save_load_ram_bounded(tmp_path):
     """The streaming writer's reason to exist: save/load of NVMe-paged
     masters+moments must stay within a few stream groups of host RAM,
-    NOT materialize the full fp32 state (VERDICT r4 missing #2).  Uses a
+    NOT materialize the full fp32 state (an earlier review's gap).  Uses a
     model big enough (~40 MiB masters + 80 MiB moments) that full
     materialization is unambiguous against sampling noise."""
     import threading

@@ -465,16 +465,42 @@ def _engine_losses(comm, steps=3):
     return losses, d, engine
 
 
-def test_engine_dryrun_sorted_matches_dense_exactly():
+def test_engine_dryrun_sorted_tracks_dense():
+    """Routing is identical between the engines; the losses differ at
+    the one-ulp level from step 1 on, because XLA:CPU (jaxlib 0.9)
+    contracts the dense combine einsum's multiply-add, while the sorted
+    gather-and-sum is plain fp32 (pinned bit for bit against a numpy
+    loop in test_sorted_combine_is_plain_fp32)."""
     dense, _, _ = _engine_losses(None)
     srt, d, _ = _engine_losses({"moe": {"dispatch": "sorted"}})
-    # step-1 loss is EXACT (identical routing + movement up to the loss
-    # mean); later steps track within optimizer-compounded ulps (the
-    # dense einsum's fused multiply-add rounds grads one ulp apart)
-    assert dense[0] == srt[0], (dense, srt)
     for a, b in zip(dense, srt):
         assert abs(a - b) < 1e-5, (dense, srt)
     assert d["moe.dropped_tokens"]["calls"] > 0  # stats flowed
+
+
+def test_sorted_combine_is_plain_fp32():
+    """The sorted layer's output equals round(g1*o1) + round(g2*o2)
+    computed in numpy, element for element: of the two engines it is
+    the dense einsum, not this one, that fuses the multiply-add."""
+    make_mesh(data=8)
+    moe, params, x = _wire_setup()
+    cap = moe.capacity(x.shape[1], train=True)
+    with dsp.moe_wire(dispatch="sorted"):
+        y = np.asarray(jax.jit(
+            lambda p, x: moe(p, x, train=True)[0])(params, x))
+    logits = jnp.einsum("bsd,de->bse", x, params["gate"]["w"])
+    keys = jax.random.split(jax.random.PRNGKey(0), x.shape[0])
+    eidx, gate, pos, keep, _ = jax.vmap(
+        lambda lg, k: moe._route(lg, k, 0.0, cap))(logits, keys)
+    expert_in = jax.vmap(lambda xr, er, pr, kr: dsp.sorted_dispatch_ref(
+        xr, er, pr, kr, 8, cap))(x, eidx, pos, keep)
+    out = np.asarray(moe._expert_ffn(
+        expert_in.transpose(1, 0, 2, 3), params, x.dtype))  # [E,B,C,D]
+    eidx, gate, pos, keep = map(np.asarray, (eidx, gate, pos, keep))
+    ref = np.zeros_like(y)
+    for b, k, s in zip(*np.nonzero(keep)):
+        ref[b, s] += gate[b, k, s] * out[eidx[b, k, s], b, pos[b, k, s]]
+    np.testing.assert_array_equal(y, ref)
 
 
 def test_engine_dryrun_wire_pins_counters_and_loss():

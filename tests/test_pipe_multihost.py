@@ -62,7 +62,7 @@ def test_channel_executor_interleaved():
 
 @pytest.mark.slow
 def test_four_process_pipeline_parity():
-    """4 jax.distributed processes x 4 stages (VERDICT r4 missing #4:
+    """4 jax.distributed processes x 4 stages (an earlier review's gap:
     the channel executor was proven at exactly 2 processes): tied
     embedding spans the full pipeline depth, every process walks the
     same canonical order, all four report identical losses matching the

@@ -627,6 +627,10 @@ def test_watchdog_trips_snapshots_and_rearms(tmp_path):
         time.sleep(0.1)  # one trip per stall: no re-trip without a beat
         assert wd.trips == 1
         assert COUNTERS.delta_since(snap)["watchdog.trips"]["calls"] == 1
+        # the callback runs after the snapshot is written: on a loaded
+        # host that is later than the counter (wait, do not assume)
+        while not trips and time.monotonic() < deadline:
+            time.sleep(0.02)
         assert trips and trips[0]["last_step"] == 7
         trip = rz.read_watchdog_trip(run_dir)
         assert trip is not None and "after step 7" in trip["reason"]

@@ -28,6 +28,7 @@ import logging
 import os
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -355,14 +356,28 @@ def _make_qwz(overlap, gas=1):
     return engine
 
 
+def _prefetch_landed(engine, timeout_s=30.0):
+    """A `qwz.prefetch_hits` event is "the prefetch was ready when the
+    forward asked": a race against the exchange thread by definition.
+    Tests that pin its count wait here first, so that a loaded host
+    cannot turn a hit into an on-time wait."""
+    pre = engine._qwz_prefetch
+    deadline = time.monotonic() + timeout_s
+    while pre is not None and not pre[1].ready:
+        assert time.monotonic() < deadline, "qwZ prefetch never landed"
+        time.sleep(0.001)
+
+
 def _train_qwz(engine, mode, gas, steps=4):
     it = _qwz_batches(steps * gas)
     loss = None
     if mode == "scan":
         for _ in range(steps):
+            _prefetch_landed(engine)
             loss = engine.train_batch(it)
     else:
         for _ in range(steps * gas):
+            _prefetch_landed(engine)
             loss = engine.forward(next(it))
             engine.backward()
             engine.step()

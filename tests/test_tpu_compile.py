@@ -1,0 +1,344 @@
+"""Compile for the chip, without the chip.
+
+The TPU compiler is installed here and compiles for a described
+`v5e:2x2`.  Every registered Pallas kernel variant, at the widths the
+main path runs (GPT-2 xl: d_model 1600, 25 heads of 64, vocab 50304,
+seq 1024), either compiles natively — `tpu_custom_call` in the
+optimized program — or is refused by the kernel registry BY NAME, with
+the message the registry carries for it.  What `auto` selects on the
+chip must compile; nothing else may reach the compiler.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may hold libtpu, and every xdist worker
+imports this file.  Nothing runs on a device here, so nothing in this
+file is a measurement.
+"""
+
+import dataclasses
+from typing import Callable, Optional
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from deepspeed_tpu.kernels import registry
+from deepspeed_tpu.ops import pallas_backend
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without the chip: keep it off around these
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no libtpu, or another process holds it
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def native(monkeypatch):
+    """What the package sees on the chip: kernels lower through Mosaic
+    and the registry's probe says TPU."""
+    monkeypatch.setattr(pallas_backend, "interpret", lambda: False)
+
+
+def _compile_text(fn, shapes, sharding):
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        shapes)
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# -- the cases: Motivation 3's table ----------------------------------------
+
+B, S, H, DH, D, V = 8, 1024, 25, 64, 1600, 50304
+
+
+def _flash_fwd():
+    from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
+
+    qkv = (_sds((B, S, H, DH), jnp.bfloat16),) * 3
+    return (lambda q, k, v: flash_attention(q, k, v, causal=True)), qkv
+
+
+def _flash_bwd():
+    from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
+
+    qkv = (_sds((B, S, H, DH), jnp.bfloat16),) * 3
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32) ** 2)
+
+    return jax.grad(loss, argnums=(0, 1, 2)), qkv
+
+
+def _fused_xent():
+    from deepspeed_tpu.ops.transformer.fused_xent import (
+        fused_softmax_xent_sum, pick_blocks)
+
+    # bf16, the training path's operands.  (At fp32 the chip refused
+    # these blocks for 20.1 MB of its 16 MB scoped VMEM, which this
+    # compile does NOT reproduce; chip_smoke runs fp32 at (256, 128).)
+    n = B * S
+    shapes = (_sds((n, D), jnp.bfloat16), _sds((D, V), jnp.bfloat16),
+              _sds((n,), jnp.int32), _sds((n,), jnp.bool_))
+    assert pick_blocks(n, V) == (256, 384)
+
+    def loss(x, w, labels, valid):
+        return fused_softmax_xent_sum(x, w, labels, valid,
+                                      *pick_blocks(n, V))
+
+    return jax.value_and_grad(loss, argnums=(0, 1)), shapes
+
+
+def _sparse():
+    from deepspeed_tpu.ops.sparse_attention.flash_sparse import \
+        flash_sparse_attention
+
+    nb = S // 128
+    layout = np.tril(np.ones((H, nb, nb), np.int32))
+    qkv = (_sds((2, S, H, DH), jnp.bfloat16),) * 3
+    return (lambda q, k, v: flash_sparse_attention(
+        q, k, v, layout, 128, causal=True)), qkv
+
+
+def _paged(kv_mode, heads, dh, slots=16, bs=16, width=64, nblocks=1025):
+    rows_total = nblocks * bs
+    if kv_mode == "dense":
+        cache = _sds((rows_total, heads, dh), jnp.bfloat16)
+    else:
+        w = dh if kv_mode == "int8" else dh // 2
+        pdt = jnp.int8 if kv_mode == "int8" else jnp.uint8
+        cache = (_sds((rows_total, heads, w), pdt),
+                 _sds((rows_total, heads), jnp.float16))
+    qdt = jnp.bfloat16 if kv_mode == "dense" else jnp.float32
+    shapes = (_sds((slots, 1, heads, dh), qdt), cache, cache,
+              _sds((slots, width * bs), jnp.int32),
+              _sds((slots, 1), jnp.int32))
+    info = {"block_size": bs, "kv_len": width * bs, "q_len": 1,
+            "head_dim": dh, "kv_mode": kv_mode}
+
+    def fn(q, ck, cv, rows, q_pos):
+        return registry.dispatch("paged_attention", q, ck, cv, rows, q_pos,
+                                 info=info, kv_mode=kv_mode, block_size=bs)
+
+    return fn, shapes, info
+
+
+def _codec(variant, wire, n=4 * 1024 * 1024, block=256):
+    info = {"block": block}
+    if variant == "quantize":
+        shapes = (_sds((n,), jnp.float32),)
+
+        def fn(x):
+            return registry.dispatch("quant_codec", x, block, wire,
+                                     variant="quantize", info=info)
+    else:
+        w = block if wire == "int8" else block // 2
+        pdt = jnp.int8 if wire == "int8" else jnp.uint8
+        shapes = (_sds((n // block, w), pdt),
+                  _sds((n // block,), jnp.float16))
+
+        def fn(p, s):
+            return registry.dispatch("quant_codec", p, s, wire, n,
+                                     variant="dequantize", info=info)
+    return fn, shapes, info
+
+
+def _moe(variant, n=8192, d=768, e=8, k=2):
+    cap = 2 * n * k // e
+    info = {"model_dim": d}
+    routing = (_sds((k, n), jnp.int32),)
+    if variant == "dispatch":
+        shapes = (_sds((n, d), jnp.float32),) + routing * 2 + (
+            _sds((k, n), jnp.bool_),)
+
+        def fn(x, eidx, pos, keep):
+            return registry.dispatch("moe_dispatch", x, eidx, pos, keep,
+                                     e, cap, variant="dispatch", info=info)
+    else:
+        shapes = (_sds((e, cap, d), jnp.float32),) + routing + (
+            _sds((k, n), jnp.float32),) + routing + (
+            _sds((k, n), jnp.bool_),)
+
+        def fn(out, eidx, gate, pos, keep):
+            return registry.dispatch("moe_dispatch", out, eidx, gate, pos,
+                                     keep, variant="combine", info=info)
+    return fn, shapes, info
+
+
+@dataclasses.dataclass
+class Case:
+    """`build()` -> (fn, shapes[, info]).  `op` None: the kernel has
+    its own call path (training attention, fused CE) and must compile.
+    `refused`: None when `auto` selects the kernel on the chip and it
+    must compile; else a regex the registry's refusal must match."""
+    name: str
+    build: Callable
+    op: Optional[str] = None
+    variant: str = "default"
+    refused: Optional[str] = None
+
+
+CASES = [
+    Case("flash_fwd_B8_S1024_H25_Dh64_bf16", _flash_fwd),
+    Case("flash_fwd_bwd_B8_S1024_H25_Dh64_bf16", _flash_bwd),
+    Case("fused_xent_fwd_bwd_N8192_D1600_V50304", _fused_xent),
+    Case("flash_sparse_fwd_S1024_H25_Dh64_block128", _sparse),
+    Case("paged_dense_H16_Dh128", lambda: _paged("dense", 16, 128),
+         op="paged_attention"),
+    Case("paged_dense_H25_Dh64", lambda: _paged("dense", 25, 64),
+         op="paged_attention", refused=r"head_dim 64 .*128"),
+    Case("paged_int8_H16_Dh128", lambda: _paged("int8", 16, 128),
+         op="paged_attention"),
+    Case("paged_int8_H25_Dh64", lambda: _paged("int8", 25, 64),
+         op="paged_attention", refused=r"head_dim 64 .*128"),
+    Case("paged_int4_H16_Dh128", lambda: _paged("int4", 16, 128),
+         op="paged_attention", refused=r"int4 .*head_dim 128"),
+    Case("paged_int4_H25_Dh64", lambda: _paged("int4", 25, 64),
+         op="paged_attention", refused=r"head_dim 64 .*128"),
+    Case("codec_quantize_int8_4M_block256",
+         lambda: _codec("quantize", "int8"),
+         op="quant_codec", variant="quantize"),
+    Case("codec_quantize_int4_4M_block256",
+         lambda: _codec("quantize", "int4"),
+         op="quant_codec", variant="quantize"),
+    Case("codec_dequantize_int8_4M_block256",
+         lambda: _codec("dequantize", "int8"),
+         op="quant_codec", variant="dequantize"),
+    Case("codec_dequantize_int4_4M_block256",
+         lambda: _codec("dequantize", "int4"),
+         op="quant_codec", variant="dequantize"),
+    Case("moe_dispatch_N8192_D768_E8", lambda: _moe("dispatch"),
+         op="moe_dispatch", variant="dispatch", refused=r"one-row block"),
+    Case("moe_combine_N8192_D768_E8", lambda: _moe("combine"),
+         op="moe_dispatch", variant="combine", refused=r"one-row block"),
+]
+
+
+@pytest.mark.parametrize("case", CASES, ids=lambda c: c.name)
+def test_kernel_compiles_or_is_refused_by_name(case, one_chip, native):
+    fn, shapes, *rest = case.build()
+    info = rest[0] if rest else None
+    if case.op is not None:
+        chosen = registry.resolve_impl(case.op, case.variant, info=info)
+        assert chosen == ("jnp" if case.refused else "pallas")
+    if case.refused:
+        # forced, the registry says why — the compiler is never asked
+        with pytest.raises(RuntimeError, match=case.refused):
+            registry.resolve_impl(case.op, case.variant, impl="pallas",
+                                  info=info)
+        text = _compile_text(fn, shapes, one_chip)  # auto: the oracle
+        assert "tpu_custom_call" not in text
+        return
+    assert "tpu_custom_call" in _compile_text(fn, shapes, one_chip)
+
+
+# -- the partial-manual regions of ISSUE 22 (Motivation 1 and 2) -------------
+
+
+def _mesh4(topo, shape, names):
+    return Mesh(np.array(topo.devices).reshape(shape), names)
+
+
+@pytest.mark.parametrize("shape,manual", [
+    ((4, 1), {"data"}),            # the form the engine used to build
+    ((4, 1), {"data", "model"}),   # MeshInfo.manual_axes' form
+    ((2, 2), {"data"}),            # a real automatic axis stays automatic
+], ids=["partial_size1", "full", "partial_size2"])
+def test_bf16_psum_region_compiles_for_four_chips(topo, shape, manual):
+    mesh = _mesh4(topo, shape, ("data", "model"))
+
+    def wire(x):
+        return jax.lax.psum(x.astype(jnp.bfloat16),
+                            "data").astype(jnp.float32)
+
+    f = jax.jit(jax.shard_map(wire, mesh=mesh, in_specs=P("data"),
+                              out_specs=P(), axis_names=manual,
+                              check_vma=False))
+    x = jax.ShapeDtypeStruct((8, 1600), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data")))
+    assert "all-reduce" in f.lower(x).compile().as_text()
+
+
+def test_debug_callback_in_manual_region_compiles_for_four_chips(topo):
+    """The MoE wire's byte counters are `jax.debug.callback`s inside
+    the dispatch region (moe/dispatch.py); with the region made fully
+    manual over a (data=4, size-1 rest) mesh they lower for the chip."""
+    mesh = _mesh4(topo, (1, 4, 1, 1), ("pipe", "data", "seq", "model"))
+
+    def body(x):
+        jax.debug.callback(lambda n: None, jnp.sum(x > 0))
+        return jax.lax.all_to_all(x, "data", 0, 0, tiled=True)
+
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                              out_specs=P("data"),
+                              axis_names=set(mesh.axis_names),
+                              check_vma=False))
+    x = jax.ShapeDtypeStruct((16, 768), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data")))
+    assert "all-to-all" in f.lower(x).compile().as_text()
+
+
+def test_flash_in_a_data_parallel_step_compiles_for_four_chips(topo, native):
+    """What stopped ZeRO-2 over four chips in PR 22: under `jit` over a
+    mesh XLA refuses to partition the Mosaic kernel.  The dispatcher
+    now calls it per shard; forward and backward compile with the batch
+    split over data=4."""
+    from deepspeed_tpu.comm.mesh import make_mesh
+    from deepspeed_tpu.ops.transformer.attention import multihead_attention
+
+    info = make_mesh(data=4, devices=topo.devices)
+    qkv = (jax.ShapeDtypeStruct((4, S, H, DH), jnp.bfloat16,
+                                sharding=info.sharding("data")),) * 3
+
+    def loss(q, k, v):
+        return jnp.sum(multihead_attention(q, k, v, causal=True)  # auto
+                       .astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        *qkv).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_registry_refuses_a_kernel_xla_would_have_to_partition(topo, native):
+    """Registry ops have no shard_map of their own: traced over a mesh
+    of several devices outside one, `auto` takes the oracle and a forced
+    kernel raises, where the compiler would."""
+    from deepspeed_tpu.comm.mesh import make_mesh
+
+    _, _, info = _paged("dense", 16, 128)
+    assert registry.resolve_impl("paged_attention", info=info) == "pallas"
+    make_mesh(data=2, model=2, devices=topo.devices)
+    assert registry.resolve_impl("paged_attention", info=info) == "jnp"
+    with pytest.raises(RuntimeError, match="cannot be automatically"):
+        registry.resolve_impl("paged_attention", impl="pallas", info=info)

@@ -2,8 +2,7 @@
 
 Default mode: exit 0 iff the given bench JSON file's last JSON line
 reports a run on real hardware (platform present and not the cpu-smoke
-fallback).  Used by tools/tpu_session.sh (fail-fast after the headline
-bench) and anything else that needs to decide whether an artifact is
+fallback).  For anything that needs to decide whether an artifact is
 trustworthy.
 
 `--min-prefix-hit-rate X` mode: exit 0 iff the artifact's last JSON
@@ -41,7 +40,7 @@ def main() -> int:
             if isinstance(d, dict) and isinstance(d.get("result"), dict):
                 d = d["result"]
         except ValueError:
-            # a JSONL stream (tpu_session.sh): gate the LAST line
+            # a JSONL stream: gate the LAST line
             lines = [l for l in text.splitlines()
                      if l.strip().startswith("{")]
             d = json.loads(lines[-1])

@@ -364,7 +364,7 @@ def run_tcp(nproc=2, steps=6, record=True, scratch=None, timeout=900):
     made = scratch is None
     scratch = scratch or tempfile.mkdtemp(prefix="chaos_tcp_")
     coord = f"127.0.0.1:{_free_port()}"
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # CPU lane, never the chip
     env.pop("XLA_FLAGS", None)
     procs = [
         subprocess.Popen(
@@ -749,7 +749,7 @@ def _overlap_worker(args):
 
 def _launch_overlap_workers(nproc, steps, scratch, phase, timeout):
     coord = f"127.0.0.1:{_free_port()}"
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # CPU lane, never the chip
     env.pop("XLA_FLAGS", None)
     procs = [
         subprocess.Popen(
@@ -1243,7 +1243,7 @@ def _elastic_launcher(args):
         world = args.nproc
     until = args.steps if world >= args.nproc else ELASTIC_TCP_REGROW_AT
     coord = f"127.0.0.1:{_free_port()}" if world > 1 else ""
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # CPU lane, never the chip
     env.pop("XLA_FLAGS", None)
     procs = [
         subprocess.Popen(

@@ -1,6 +1,6 @@
 """Per-event dispatch cost: interpreted schedule walk vs compiled program.
 
-BENCH.md round-5 measured the channel pipeline executor at ~300 us of
+The channel pipeline executor was measured at ~300 us of
 serialized Python per schedule event (12-16% of CPU-mesh step time,
 projected ~150 ms/step at 8 stages x 16 micros).  The compiled executor
 (runtime/pipe/compiler.py) lowers the canonical walk once into a flat

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""Render a telemetry run (monitor/ JSONL event stream) as a BENCH.md-
-style markdown report.
+"""Render a telemetry run (monitor/ JSONL event stream) as a
+markdown report.
 
 Usage:
     python tools/run_report.py runs/my_run            # a run directory

@@ -25,8 +25,7 @@ monitor/artifacts.record_bench_result PLUS a run directory
 
 Campaigns:
 
-* default — the full two-lane Poisson comparison (committed numbers in
-  BENCH.md round-16).
+* default — the full two-lane Poisson comparison.
 * `--spec` — the speculative-decoding campaign: one repetitive-suffix
   greedy Poisson timeline against every (kv_dtype x draft_len) lane
   (accepted tokens/step, tok/s, TTFT/ITL tails per lane; outputs
@@ -419,7 +418,7 @@ def run_spec_campaign(n_requests=32, rate_hz=64.0, seed=0, record=True,
     """The speculative-decoding campaign: ONE repetitive-suffix greedy
     Poisson timeline replayed against every (kv_dtype x draft_len)
     lane, plus the equal-pool-bytes resident-session pair.  Headline
-    claims (BENCH.md): draft=4 buys >= 1.3x tokens/s over draft=0 at
+    claims: draft=4 buys >= 1.3x tokens/s over draft=0 at
     matched kv_dtype with > 1.5 accepted tokens/step on this workload,
     and int8 KV keeps >= 1.5x more sessions concurrently resident than
     bf16 at the SAME pool byte budget.  Output is token-identical
@@ -801,7 +800,7 @@ def run_fleet_campaign(n_requests=64, rate_hz=32.0, seed=0, record=True,
     engine — simultaneously the cold baseline row and the bitwise
     oracle — and (b) a FleetRouter lane per replica count with
     per-replica prefix caches; plus the warm-session vs cold-turn
-    lanes.  Headline claims (BENCH.md): the cache serves > 50% of
+    lanes.  Headline claims: the cache serves > 50% of
     prefill tokens on this workload, warm session turns beat cold
     turns on turn>=2 TTFT p50, and tokens/s scales with replica
     count.  Every cache-on lane is asserted BITWISE identical to the

@@ -373,6 +373,7 @@ def run_training_lane(out_dir, steps=4, nproc=2, timeout_s=600):
         [sys.executable, os.path.abspath(__file__), "--train-worker",
          "--proc-id", str(pid), "--coord", coord, "--nproc", str(nproc),
          "--steps", str(steps), "--out", out_dir],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),  # CPU lane
         stdout=subprocess.DEVNULL if pid else None)
         for pid in range(nproc)]
     for p in procs:
