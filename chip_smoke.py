@@ -331,6 +331,7 @@ def _close(name, shape, got, want, rtol, atol) -> dict:
 def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                   d_model=1600, vocab=50304, paged_heads=16,
                   paged_head_dim=128, paged_slots=16, paged_width=64,
+                  bert_batch=16, bert_seq=512, bert_heads=16,
                   on_chip=True) -> dict:
     """Each kernel `auto` selects on this chip, once, natively, at the
     main path's shapes, against its jnp oracle at tier-1's tolerance
@@ -343,7 +344,8 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
     from deepspeed_tpu.kernels.paged import paged_attention_reference
     from deepspeed_tpu.ops import pallas_backend
     from deepspeed_tpu.ops.transformer.attention import xla_attention
-    from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
+    from deepspeed_tpu.ops.transformer.flash_attention import (
+        _keep_mask, derive_seed, flash_attention)
     from deepspeed_tpu.ops.transformer.fused_xent import \
         fused_softmax_xent_sum
     from deepspeed_tpu.serving.kv_cache import rows_for_tables
@@ -363,14 +365,52 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
             jax.jit(lambda *a: xla_attention(*a, causal=True))(q, k, v),
             rtol=2e-5, atol=2e-5))
 
-        def grads(attn):
+        def grads(attn, **kw):  # of the q, k, v bound when it is called
             return jax.jit(jax.grad(
-                lambda q, k, v: jnp.sum(attn(q, k, v, causal=True) ** 2),
+                lambda q, k, v: jnp.sum(attn(q, k, v, **kw) ** 2),
                 argnums=(0, 1, 2)))(q, k, v)
 
         out.append(_close("flash_attention_bwd", list(shape),
-                          grads(flash_attention), grads(xla_attention),
+                          grads(flash_attention, causal=True),
+                          grads(xla_attention, causal=True),
                           rtol=1e-3, atol=1e-3))
+
+        # the variants no benchmark cell runs: bidirectional, key bias
+        # (BERT's padding mask) and in-kernel dropout at BERT-large's
+        # seq-512 shape, against a plain softmax under the SAME mask
+        # (the kernel's own hash over global indices, whole plane)
+        shape = (bert_batch, bert_seq, bert_heads, head_dim)
+        q, k, v = (jax.random.normal(key[i], shape, jnp.float32)
+                   for i in range(3))
+        lens = np.random.RandomState(SEED).randint(
+            bert_seq // 2, bert_seq + 1, size=bert_batch)
+        bias = jnp.asarray(np.where(
+            np.arange(bert_seq)[None] < lens[:, None], 0.0, -1e30)
+            [:, None, None, :], jnp.float32)
+        rate, rng = 0.1, jax.random.PRNGKey(SEED + 1)
+        seed = derive_seed(rate, rng)[0][0]
+        zero = jnp.int32(0)
+        plane = jax.jit(jax.vmap(lambda bh: _keep_mask(
+            seed, bh, zero, zero, bert_seq, bert_seq, rate)))(
+            jnp.arange(bert_batch * bert_heads, dtype=jnp.int32)).reshape(
+            bert_batch, bert_heads, bert_seq, bert_seq)
+
+        def masked_ref(q, k, v):
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * head_dim ** -0.5
+            p = jax.nn.softmax(s + bias, axis=-1) * plane
+            return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+        def kernel(q, k, v):
+            return flash_attention(q, k, v, causal=False, key_bias=bias,
+                                   dropout_rate=rate, dropout_rng=rng)
+
+        out.append(_close("flash_attention_full_bias_dropout_fwd",
+                          list(shape), jax.jit(kernel)(q, k, v),
+                          jax.jit(masked_ref)(q, k, v),
+                          rtol=2e-5, atol=2e-5))
+        out.append(_close("flash_attention_full_bias_dropout_bwd",
+                          list(shape), grads(kernel), grads(masked_ref),
+                          rtol=2e-3, atol=2e-3))
 
         # fused projection + cross-entropy, forward and backward
         n = batch * seq

@@ -16,15 +16,13 @@ from __future__ import annotations
 from typing import Optional
 
 from ..ops.transformer.attention import xla_attention
-from ..ops.transformer.flash_attention import (DEFAULT_BLOCK_K,
-                                               DEFAULT_BLOCK_Q,
-                                               flash_attention)
+from ..ops.transformer.flash_attention import flash_attention
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            scale: Optional[float] = None,
-                           block_q: int = DEFAULT_BLOCK_Q,
-                           block_k: int = DEFAULT_BLOCK_K,
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None,
                            dropout_rate: float = 0.0, dropout_rng=None):
     return flash_attention(q, k, v, causal=causal, scale=scale,
                            block_q=block_q, block_k=block_k,
@@ -34,8 +32,8 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
 
 def flash_attention_reference(q, k, v, *, causal: bool = True,
                               scale: Optional[float] = None,
-                              block_q: int = DEFAULT_BLOCK_Q,
-                              block_k: int = DEFAULT_BLOCK_K,
+                              block_q: Optional[int] = None,
+                              block_k: Optional[int] = None,
                               dropout_rate: float = 0.0,
                               dropout_rng=None):
     # block sizes are a kernel tuning knob with no oracle meaning —

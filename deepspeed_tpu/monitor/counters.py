@@ -157,7 +157,11 @@ Instrumented sites:
   resolutions that took an op's Pallas path (counted at TRACE time,
   once per jit trace, not per step); `kernel.fallbacks` — resolutions
   that ran the jnp oracle instead (incompatible fabric, declined
-  shape, or an explicit jnp pin).
+  shape, or an explicit jnp pin);
+  `kernel.flash.blocks.<bq>x<bk>.walk<rows>` — the tile schedule the
+  training flash kernels (ops/transformer/flash_attention.py, never
+  through the registry) ran for a shape: score tile and resident
+  K/V rows (counted at TRACE time, per traced call).
 * trace/SLO telemetry (`trace.*` / `slo.*`, monitor/tracing.py;
   rendered by monitor/report.py as the "Tracing" rows of the Serving
   SLO section, excluded from the comm byte table): `trace.events` —

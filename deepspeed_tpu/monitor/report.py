@@ -900,6 +900,11 @@ def render_markdown(run: Dict[str, Any]) -> str:
         if falls:
             lines.append(f"| jnp oracle fallbacks (trace-time) | "
                          f"{falls['calls']:,} |")
+        for k in sorted(kern_counters):
+            if k.startswith("kernel.flash.blocks."):
+                lines.append(f"| flash schedule "
+                             f"{k[len('kernel.flash.blocks.'):]} (trace-time) "
+                             f"| {kern_counters[k]['calls']:,} |")
         lines.append("")
 
     qwz = any_comm.get("qwz.gather")

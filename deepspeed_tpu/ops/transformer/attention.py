@@ -23,7 +23,11 @@ import jax.numpy as jnp
 
 from .. import pallas_backend
 
-_FLASH_MIN_SEQ = 256  # below this the [S,S] buffer fits easily; XLA wins
+# Below this XLA's fused attention wins.  Measured on a v5e, forward +
+# backward of 16k tokens at 25 heads of 64, bf16 (PERF.md §6, PR 27):
+# S 256: XLA 1.02 ms, flash 1.51; S 512: XLA 2.43, flash 1.55; S 1024:
+# XLA 4.69, flash 1.99.  (384 is not measured and stays with XLA.)
+_FLASH_MIN_SEQ = 512
 
 
 def _on_tpu() -> bool:
@@ -187,12 +191,11 @@ def multihead_attention(q, k, v, causal: bool = True, impl: str = "auto",
                       and S >= _FLASH_MIN_SEQ and S % 128 == 0
                       and Sk % 128 == 0 and D in (64, 128, 256))
     if use_pallas:
-        from .flash_attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q,
-                                      flash_attention)
+        from .flash_attention import flash_attention, flash_blocks
 
-        bq = block_q or DEFAULT_BLOCK_Q
-        bk = block_k or DEFAULT_BLOCK_K
-        if S % bq == 0 and k.shape[1] % bk == 0:
+        auto_q, auto_k = flash_blocks(S, Sk)
+        bq, bk = block_q or auto_q, block_k or auto_k
+        if S % bq == 0 and Sk % bk == 0:
             return _flash_per_shard(
                 flash_attention, q, k, v, key_bias,
                 dropout_rng if want_dropout else None, bh_offset,
@@ -205,7 +208,7 @@ def multihead_attention(q, k, v, causal: bool = True, impl: str = "auto",
 
             logger.warning(
                 f"flash blocks ({bq},{bk}) do not divide seq lens "
-                f"({S},{k.shape[1]}); falling back to XLA attention")
+                f"({S},{Sk}); falling back to XLA attention")
     try:
         offset_zero = int(bh_offset) == 0  # any concrete zero is a no-op
     except Exception:  # traced (e.g. axis_index): unknowable at dispatch

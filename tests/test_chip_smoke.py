@@ -49,9 +49,12 @@ def test_kernels_phase_toy():
     out = chip_smoke.kernels_phase(batch=1, seq=256, heads=2, head_dim=64,
                                    d_model=64, vocab=1024, paged_heads=2,
                                    paged_slots=2, paged_width=4,
+                                   bert_batch=2, bert_seq=256, bert_heads=2,
                                    on_chip=False)
     assert [k["kernel"] for k in out["kernels"]] == [
-        "flash_attention_fwd", "flash_attention_bwd", "fused_xent_fwd",
+        "flash_attention_fwd", "flash_attention_bwd",
+        "flash_attention_full_bias_dropout_fwd",
+        "flash_attention_full_bias_dropout_bwd", "fused_xent_fwd",
         "fused_xent_bwd", "paged_attention_dense"]
 
 

@@ -408,3 +408,239 @@ def test_kernel_runs_per_shard_on_a_mesh(rate):
     for a, b in zip(got, g(q, k, v)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the tile schedule: `flash_blocks` and what the kernels do with it
+# ---------------------------------------------------------------------------
+
+import importlib  # noqa: E402
+
+fa = importlib.import_module("deepspeed_tpu.ops.transformer.flash_attention")
+
+
+@pytest.mark.parametrize("S,Sk,D,dtype", [
+    (256, 256, 64, jnp.bfloat16),
+    (512, 512, 64, jnp.bfloat16),
+    (1024, 1024, 64, jnp.bfloat16),
+    (2048, 2048, 128, jnp.bfloat16),
+    (1024, 1024, 128, jnp.float32),
+    (384, 640, 64, jnp.bfloat16),
+    (4096, 4096, 128, jnp.bfloat16),
+    (16384, 16384, 128, jnp.float32),
+], ids=lambda x: getattr(x, "__name__", str(x)))
+def test_flash_blocks_tile_every_shape_inside_the_vmem_budget(
+        S, Sk, D, dtype):
+    """The choice is a function of the call's shape: multiples of 128
+    that divide the lengths, a tile no larger than the one the
+    described-v5e compiles prove, and resident rows inside the budget."""
+    bq, bk = fa.flash_blocks(S, Sk)
+    assert bq % 128 == 0 and bk % 128 == 0
+    assert S % bq == 0 and Sk % bk == 0
+    assert bq * bk <= 512 * 512
+    item = jnp.dtype(dtype).itemsize
+    for n, blk in ((Sk, bk), (S, bq)):
+        rows = fa._resident_rows(n, blk, D, item)
+        assert n % rows == 0 and rows % blk == 0
+        assert rows == blk or 4 * rows * D * item <= fa._RESIDENT_BYTES
+
+
+def test_flash_blocks_replace_the_constants_and_explicit_blocks_hold():
+    """No DEFAULT_BLOCK_* left; a caller's blocks keep their meaning
+    (the schedule each traced call ran is noted in COUNTERS)."""
+    from deepspeed_tpu.monitor.counters import COUNTERS
+
+    assert not hasattr(fa, "DEFAULT_BLOCK_Q")
+    assert not hasattr(fa, "DEFAULT_BLOCK_K")
+    q, k, v = _make_qkv(jax.random.PRNGKey(30), B=1, S=768, H=1, D=64)
+    before = COUNTERS.snapshot()
+    flash_attention(q, k, v, block_q=128, block_k=256)
+    flash_attention(q, k, v, block_q=128, block_k=256)
+    flash_attention(q, k, v)
+    noted = COUNTERS.delta_since(before)
+    auto = "kernel.flash.blocks.%dx%d.walk768" % fa.flash_blocks(768, 768)
+    assert noted["kernel.flash.blocks.128x256.walk768"]["calls"] == 2
+    assert noted[auto]["calls"] == 1
+
+
+def _grads(attn, q, k, v, **kw):
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v, **kw).astype(jnp.float32) ** 2)
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _qkv_sk(rng, B, S, Sk, H, D, dtype):
+    ks = jax.random.split(rng, 3)
+    mk = lambda key, n: (jax.random.normal(key, (B, n, H, D), jnp.float32)
+                         * 0.5).astype(dtype)
+    return mk(ks[0], S), mk(ks[1], Sk), mk(ks[2], Sk)
+
+
+# bf16 inputs are held against the fp32 oracle on the SAME bf16 values:
+# the kernel's products are exact, so what remains is the bf16 rounding
+# of p, ds and the outputs
+_TOL = {jnp.float32: dict(fwd=2e-5, bwd=2e-3),
+        jnp.bfloat16: dict(fwd=2e-2, bwd=6e-2)}
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("S,Sk,D,dtype,bias", [
+    (256, 256, 64, jnp.float32, False),
+    (512, 512, 64, jnp.bfloat16, True),
+    (1024, 1024, 64, jnp.bfloat16, False),
+    (2048, 2048, 64, jnp.float32, False),
+    (512, 512, 128, jnp.float32, True),
+    (1024, 1024, 128, jnp.bfloat16, False),
+    (256, 512, 64, jnp.float32, False),
+    (512, 1024, 128, jnp.bfloat16, True),
+], ids=lambda x: getattr(x, "__name__", str(x)))
+def test_auto_blocks_forward_and_gradients_match_xla(S, Sk, D, dtype, bias,
+                                                     causal):
+    """Whatever `flash_blocks` picks — forward and all three gradients
+    against `xla_attention` (causal rows end-aligned when S != Sk, as
+    the oracle's are)."""
+    q, k, v = _qkv_sk(jax.random.PRNGKey(S + Sk + D), 1, S, Sk, 2, D, dtype)
+    kb = _padding_bias([Sk - 37], Sk) if bias else None
+    f32 = [t.astype(jnp.float32) for t in (q, k, v)]
+    tol = _TOL[dtype]
+    want = xla_attention(*f32, causal=causal, bias=kb)
+    got = flash_attention(q, k, v, causal=causal, key_bias=kb)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               atol=tol["fwd"], rtol=tol["fwd"])
+    g_want = _grads(xla_attention, *f32, causal=causal, bias=kb)
+    g_got = _grads(flash_attention, q, k, v, causal=causal, key_bias=kb)
+    for a, b, name in zip(g_got, g_want, "qkv"):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b),
+            atol=tol["bwd"] * scale, rtol=tol["bwd"],
+            err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("S,D,dtype,causal", [
+    (512, 64, jnp.float32, False),     # the BERT seq-512 walk
+    (1024, 64, jnp.bfloat16, True),
+    (512, 128, jnp.float32, True),
+], ids=lambda x: getattr(x, "__name__", str(x)))
+def test_auto_blocks_dropout_matches_masked_reference(S, D, dtype, causal):
+    """Dropout picks the smaller q block; the mask is a function of
+    global (seed, bh, q, k), so forward and gradients equal the
+    host-reconstructed mask on a plain reference — with a key bias."""
+    B, H, rate = 1, 2, 0.1
+    q, k, v = _qkv_sk(jax.random.PRNGKey(S + D), B, S, S, H, D, dtype)
+    kb = _padding_bias([S - 61], S)
+    rng = jax.random.PRNGKey(46)
+    seed = int(jax.random.randint(rng, (1,), 0, jnp.iinfo(jnp.int32).max,
+                                  dtype=jnp.int32)[0])
+    dmask = jnp.asarray(_host_keep_mask(seed, B * H, S, S, rate)).reshape(
+        B, H, S, S)
+
+    def ref(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (D ** -0.5) + kb
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s,
+                          jnp.finfo(jnp.float32).min)
+        return jnp.einsum("bhqk,bkhd->bqhd",
+                          jax.nn.softmax(s, axis=-1) * dmask, v)
+
+    f32 = [t.astype(jnp.float32) for t in (q, k, v)]
+    kw = dict(causal=causal, key_bias=kb, dropout_rate=rate, dropout_rng=rng)
+    tol = _TOL[dtype]
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, **kw), np.float32),
+        np.asarray(ref(*f32)), atol=tol["fwd"], rtol=tol["fwd"])
+    g_want = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), argnums=(0, 1, 2))(
+        *f32)
+    for a, b, name in zip(_grads(flash_attention, q, k, v, **kw), g_want,
+                          "qkv"):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b),
+            atol=tol["bwd"] * scale, rtol=tol["bwd"],
+            err_msg=f"d{name} mismatch")
+
+
+def test_major_blocks_walk_long_sequences(monkeypatch):
+    """Past `_RESIDENT_BYTES` the K/V (and Q/dO) rows are held a major
+    block at a time, dead causal blocks repeating the nearest live
+    index: same numbers as with everything resident."""
+    q, k, v = _make_qkv(jax.random.PRNGKey(31), B=1, S=1024, H=2, D=64)
+    kw = dict(block_q=128, block_k=128)
+    whole = [flash_attention(q, k, v, causal=c, **kw) for c in (True, False)]
+    g_whole = _grads(flash_attention, q, k, v, causal=True, **kw)
+    # room for 256 rows of two fp32 operands, double-buffered
+    monkeypatch.setattr(fa, "_RESIDENT_BYTES", 4 * 256 * 64 * 4)
+    assert fa._resident_rows(1024, 128, 64, 4) == 256
+    for c, want in zip((True, False), whole):
+        np.testing.assert_allclose(
+            np.asarray(flash_attention(q, k, v, causal=c, **kw)),
+            np.asarray(want), atol=2e-6, rtol=2e-6)
+    for a, b in zip(_grads(flash_attention, q, k, v, causal=True, **kw),
+                    g_whole):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-5)
+
+
+def _kernel_dots(fn, *args):
+    """(lhs dtype, rhs dtype, accumulator dtype) of every matmul inside
+    the Pallas kernels of `fn`'s jaxpr."""
+    found = []
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            if inside and eqn.primitive.name == "dot_general":
+                found.append((eqn.invars[0].aval.dtype,
+                              eqn.invars[1].aval.dtype,
+                              eqn.outvars[0].aval.dtype))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, inside or eqn.primitive.name == "pallas_call")
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+    return found
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+def test_every_product_takes_its_tiles_in_the_input_dtype(dtype):
+    """bf16 tiles into the MXU, fp32 out of it: fails if any of the
+    eight products (2 forward, 3 dq, 4 dk/dv — the mask walk doubles
+    them when causal) is fed an fp32 copy of a bf16 tile.  With fp32
+    inputs the products stay fp32 — the rule reads the operand dtype."""
+    q, k, v = _qkv_sk(jax.random.PRNGKey(32), 1, 256, 256, 1, 64, dtype)
+    rng = jax.random.PRNGKey(1)
+    dots = _kernel_dots(
+        lambda *a: _grads(flash_attention, *a, causal=True,
+                          dropout_rate=0.1, dropout_rng=rng), q, k, v)
+    assert len(dots) >= 9
+    for lhs, rhs, acc in dots:
+        assert lhs == dtype and rhs == dtype, (lhs, rhs)
+        assert acc == jnp.float32
+
+
+def test_fp32_inputs_keep_the_fp32_tolerance_at_the_cell_walk():
+    """S 1024, causal, auto blocks: fp32 in, tier-1's fp32 tolerance out
+    (2e-5 forward, 1e-3 backward) — nothing was traded for the bf16
+    path."""
+    q, k, v = _make_qkv(jax.random.PRNGKey(33), B=1, S=1024, H=2, D=64)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, causal=True)),
+        np.asarray(xla_attention(q, k, v, causal=True)),
+        atol=2e-5, rtol=2e-5)
+    for a, b, name in zip(_grads(flash_attention, q, k, v, causal=True),
+                          _grads(xla_attention, q, k, v, causal=True), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-3,
+                                   rtol=1e-3, err_msg=f"d{name} mismatch")
+
+
+def test_logsumexp_residual_is_compact():
+    """The forward keeps [BH, S] fp32, not a 128-lane-wide copy of it."""
+    q, k, v = _make_qkv(jax.random.PRNGKey(34), B=2, S=512, H=2, D=64)
+    _, vjp = jax.vjp(lambda *a: flash_attention(*a, causal=True), q, k, v)
+    sizes = sorted(x.size for x in jax.tree_util.tree_leaves(vjp)
+                   if getattr(x, "dtype", None) == jnp.float32
+                   and x.ndim == 2)
+    assert (2 * 2) * 512 in sizes
+    assert all(x.size <= q.size for x in jax.tree_util.tree_leaves(vjp)
+               if hasattr(x, "size"))

@@ -89,16 +89,23 @@ def _flash_fwd():
     return (lambda q, k, v: flash_attention(q, k, v, causal=True)), qkv
 
 
-def _flash_bwd():
+def _flash_bwd(b=B, s=S, h=H, dh=DH, causal=True, bias=False, rate=0.0):
+    """Forward + backward at the blocks `flash_blocks` picks: what the
+    VMEM budget of that choice has to hold."""
     from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
 
-    qkv = (_sds((B, S, H, DH), jnp.bfloat16),) * 3
+    shapes = (_sds((b, s, h, dh), jnp.bfloat16),) * 3
+    if bias:  # the BERT padding-mask shape
+        shapes += (_sds((b, 1, 1, s), jnp.float32),)
 
-    def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True)
+    def loss(q, k, v, *kb):
+        kw = {"key_bias": kb[0]} if kb else {}
+        if rate:
+            kw.update(dropout_rate=rate, dropout_rng=jax.random.PRNGKey(0))
+        return jnp.sum(flash_attention(q, k, v, causal=causal, **kw)
                        .astype(jnp.float32) ** 2)
 
-    return jax.grad(loss, argnums=(0, 1, 2)), qkv
+    return jax.grad(loss, argnums=(0, 1, 2)), shapes
 
 
 def _fused_xent():
@@ -212,6 +219,15 @@ class Case:
 CASES = [
     Case("flash_fwd_B8_S1024_H25_Dh64_bf16", _flash_fwd),
     Case("flash_fwd_bwd_B8_S1024_H25_Dh64_bf16", _flash_bwd),
+    # the benchmark cell's call, gpt2-xl-d24.train.seq1024
+    Case("flash_fwd_bwd_B4_S1024_H25_Dh64_bf16_cell",
+         lambda: _flash_bwd(b=4)),
+    Case("flash_fwd_bwd_B1_S4096_H8_Dh128_bf16_long_wide",
+         lambda: _flash_bwd(b=1, s=4096, h=8, dh=128)),
+    # BERT-large at seq 512: bidirectional, key bias, in-kernel dropout
+    Case("flash_fwd_bwd_B16_S512_H16_Dh64_bf16_full_bias_dropout",
+         lambda: _flash_bwd(b=16, s=512, h=16, dh=64, causal=False,
+                            bias=True, rate=0.1)),
     Case("fused_xent_fwd_bwd_N8192_D1600_V50304", _fused_xent),
     Case("flash_sparse_fwd_S1024_H25_Dh64_block128", _sparse),
     Case("paged_dense_H16_Dh128", lambda: _paged("dense", 16, 128),
