@@ -307,6 +307,83 @@ def serve_phase(size=MODEL_SIZE, depth=SERVE_DEPTH, seq=SEQ, serve=None,
 # ---------------------------------------------------------------------------
 
 
+# a small EvaByte (the family's own layer kinds at widths the chip tiles:
+# heads of 128, chunk 16) served in bf16 through windows that close in
+# prefill and in decode.  EVABYTE_GAP bounds how far below the float32
+# reference's best logit a byte the engine chose may lie: bf16 weights,
+# rows and summaries against float32 `highest` (the chip read 0.0022 over
+# 48 positions, top-1 agreement 97.9 %, chip run PR 29; the limit is four
+# times that, and far below what a dropped remote term or a value summary
+# out of place reads at the published widths, 3.4 and 0.44, PERF.md).
+EVABYTE = dict(num_layers=2, num_heads=4, d_model=512, d_ff=1408,
+               window_size=256, chunk_size=16, pool_std=4.0)
+EVABYTE_SERVE = dict(block_size=16, max_batch=3, max_seq_len=1024,
+                     prefill_chunk=128, num_blocks=1 + 3 * (16 + 4),
+                     prefix_cache=False)
+EVABYTE_PROMPTS = (700, 512, 90)
+EVABYTE_GAP = 0.01
+
+
+def evabyte_phase(model=None, serve=None, prompt_lens=EVABYTE_PROMPTS,
+                  max_new=MAX_NEW, gap=EVABYTE_GAP, **over) -> dict:
+    """EvaByte served natively against its plain reference: at every
+    generated position the byte the engine chose must have a reference
+    logit within `gap` of the reference's largest."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.reference import evabyte as reference
+    from deepspeed_tpu.models import EvaByte, EvaByteConfig
+    from deepspeed_tpu.serving import FINISHED, ServeConfig, ServeEngine
+
+    serve = dict(EVABYTE_SERVE if serve is None else serve)
+    over.setdefault("param_dtype", jnp.bfloat16)
+    cfg = EvaByteConfig(**{**(EVABYTE if model is None else model),
+                           "max_seq_len": serve["max_seq_len"], **over})
+    net = EvaByte(cfg)
+    t0 = time.perf_counter()
+    params = jax.jit(net.init)(jax.random.PRNGKey(SEED))
+    engine = ServeEngine(net, params, ServeConfig(**serve))
+    free = engine.kv.free_blocks
+    rs = np.random.RandomState(SEED)
+    reqs = [engine.submit(rs.randint(0, cfg.vocab_size, (n,)).tolist(),
+                          max_new) for n in prompt_lens]
+    engine.run()
+    serve_s = time.perf_counter() - t0
+    if any(r.state != FINISHED or len(r.out) != max_new for r in reqs):
+        raise RuntimeError(f"unfinished: {[r.state for r in reqs]}")
+    if engine.kv.free_blocks != free:
+        raise RuntimeError("the free list is not whole after the run")
+    t0 = time.perf_counter()
+    kw = dict(heads=cfg.num_heads, eps=cfg.rms_norm_eps,
+              theta=cfg.rope_theta, window=cfg.window_size,
+              chunk=cfg.chunk_size, vocab=cfg.vocab_size)
+    worst, agree = 0.0, 0
+    for r in reqs:
+        lg = np.asarray(reference.logits(
+            params, jnp.asarray([r.prompt + r.out]), **kw))[0]
+        rows = lg[len(r.prompt) - 1:len(r.prompt) - 1 + max_new]
+        chosen = np.asarray(r.out)
+        worst = max(worst, float(
+            (rows.max(-1) - rows[np.arange(max_new), chosen]).max()))
+        agree += int((rows.argmax(-1) == chosen).sum())
+    if not worst <= gap:
+        raise RuntimeError(
+            f"a byte the engine chose lies {worst} below the reference's "
+            f"best logit (limit {gap})")
+    return {"phase": "evabyte", "d_model": cfg.d_model,
+            "heads": cfg.num_heads, "depth": cfg.num_layers,
+            "window": cfg.window_size, "chunk": cfg.chunk_size,
+            "weights": str(jnp.dtype(cfg.param_dtype)), **serve,
+            "prompt_lens": list(prompt_lens), "max_new_tokens": max_new,
+            "window_closes": sum((n + max_new - 1) // cfg.window_size
+                                 for n in prompt_lens),
+            "worst_gap_to_top_logit": worst, "gap_limit": gap,
+            "top1_agreement": agree / (len(reqs) * max_new),
+            "seconds_serve": round(serve_s, 1),
+            "seconds_reference": round(time.perf_counter() - t0, 1)}
+
+
 def _max_err(got, want) -> float:
     import jax
 
@@ -565,7 +642,7 @@ def main(argv=None) -> int:
 
     cache_dir = enable_compile_cache()
     phases = ([four_chip_phase] if args.chips == 4
-              else [train_phase, serve_phase, kernels_phase])
+              else [train_phase, serve_phase, evabyte_phase, kernels_phase])
     name = "device"
     t0 = time.perf_counter()
     cached_at_start = cache_entries(cache_dir)

@@ -3,7 +3,7 @@
 quantized-KV dequant fused into the gather.
 
 The jnp oracle (`paged_attention_reference`) is the EXACT expression
-serving/programs.py's `_paged_block` always ran — gather the table's
+serving/layers.py's `_paged_attend` always ran — gather the table's
 rows, dequantize if the cache is quantized, one fp32 einsum/softmax/
 einsum chain under the `q_pos >= k_idx` mask.  Wherever the registry
 picks the oracle (all of tier-1 on CPU) serving output stays
@@ -75,7 +75,7 @@ def kv_read(c, rows, kv_mode: str = "dense"):
 def paged_attention_reference(q, ck, cv, rows, q_pos, *,
                               kv_mode: str = "dense",
                               block_size: int = 0):
-    """The `_paged_block` attention core, verbatim: q [B, T, H, Dh],
+    """The `_paged_attend` attention core (serving/layers.py), verbatim: q [B, T, H, Dh],
     caches addressed by flat rows [B, L], q_pos [B, T] absolute
     positions -> attn [B, T, H, Dh] (at the cache/dequant dtype)."""
     del block_size  # kernel tiling knob; the gather needs only rows

@@ -45,7 +45,7 @@ Config install mirrors moe/dispatch.py's wire config: the engine
 installs the parsed `"kernels"` block process-globally at initialize();
 direct users scope overrides with the `kernel_config(...)` context
 manager.  Implementation modules (`flash`, `quant_codec`,
-`moe_kernels`, `paged`) are imported lazily from the op methods so the
+`moe_kernels`, `paged`, `eva`) are imported lazily from the op methods so the
 registry itself stays import-cycle-free (config validation can name
 the op set without dragging in jax kernels).
 """
@@ -229,7 +229,7 @@ class PagedAttentionOp(KernelOp):
     """Decode-path paged attention (op 1): fused block-table gather +
     online-softmax attention over the PagedKVCache, with the quantized
     KV dequant fused into the gather.  Oracle = the gather/einsum/
-    softmax expression serving/programs.py's `_paged_block` always ran
+    softmax expression serving/layers.py's `_paged_attend` always ran
     (bit-identical serving behaviour wherever the oracle is chosen)."""
 
     NAME = "paged_attention"
@@ -265,6 +265,28 @@ class PagedAttentionOp(KernelOp):
     def oracle(self, variant, *args, **kwargs):
         from . import paged
         return paged.paged_attention_reference(*args, **kwargs)
+
+
+class EvaAttentionOp(KernelOp):
+    """Chunk-summarised attention over the paged cache (kernels/eva.py):
+    a window of exact rows and the summary rows of closed windows in one
+    softmax.  Only the jnp oracle exists; the op is registered so the
+    serving block calls it as it calls every attention core, and a
+    Pallas kernel lands here without a change to its caller."""
+
+    NAME = "eva_attention"
+
+    def is_compatible(self) -> bool:
+        return False
+
+    def compatibility_message(self) -> str:
+        if self.env_enabled() and _on_tpu():
+            return "no Pallas kernel is written for it yet (jnp oracle only)"
+        return super().compatibility_message()
+
+    def oracle(self, variant, *args, **kwargs):
+        from . import eva
+        return eva.eva_attention_reference(*args, **kwargs)
 
 
 class QuantCodecOp(KernelOp):
@@ -344,8 +366,8 @@ class MoEDispatchOp(KernelOp):
 
 KERNEL_OPS: Dict[str, KernelOp] = {
     op.NAME: op for op in (FlashAttentionOp(), SparseAttentionOp(),
-                           PagedAttentionOp(), QuantCodecOp(),
-                           MoEDispatchOp())
+                           PagedAttentionOp(), EvaAttentionOp(),
+                           QuantCodecOp(), MoEDispatchOp())
 }
 
 

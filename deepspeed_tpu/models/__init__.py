@@ -2,7 +2,9 @@
 its model tests drive an external Megatron GPT-2, SURVEY.md §1)."""
 
 from .bert import Bert, BertConfig, bert_config, BERT_SIZES
+from .evabyte import EvaByte, EvaByteConfig
 from .gpt import GPT, GPTConfig, gpt2_config, GPT2_SIZES
+from .layer_spec import LayerSpec
 from .gpt_pipe import gpt_pipeline_module
 from .generation import generate
 from .hf import (bert_config_from_hf, gpt2_config_from_hf,
@@ -11,5 +13,6 @@ from .hf import (bert_config_from_hf, gpt2_config_from_hf,
 __all__ = ["GPT", "GPTConfig", "gpt2_config", "GPT2_SIZES",
            "gpt_pipeline_module",
            "Bert", "BertConfig", "bert_config", "BERT_SIZES",
+           "EvaByte", "EvaByteConfig", "LayerSpec",
            "load_hf_gpt2", "gpt2_config_from_hf",
            "load_hf_bert", "bert_config_from_hf", "generate"]

@@ -432,6 +432,24 @@ class GPT(TrainModule):
         self.config = config
         self.param_specs = self._build_specs()
 
+    def layer_spec(self):
+        """What the serving engine builds its programs from
+        (models/layer_spec.py)."""
+        from .layer_spec import LayerSpec
+
+        cfg = self.config
+        if cfg.num_experts > 1 or cfg.pipeline_stages > 1:
+            raise NotImplementedError(
+                "serving takes a dense GPT: `serving/layers.py` has no "
+                "expert layer (`num_experts` > 1: dropless routing and "
+                "experts in the served block are not built) and takes "
+                "`blocks` as a list with one entry a layer, not stacked "
+                "for pipeline stages (`pipeline_stages` > 1)")
+        return LayerSpec(norm="layernorm", positions="learned",
+                         attention="paged", ffn="gelu_mlp",
+                         head="tied" if cfg.tie_embeddings else "untied",
+                         eps=cfg.layer_norm_eps).validate()
+
     # -- init ----------------------------------------------------------
     def init(self, rng):
         cfg = self.config

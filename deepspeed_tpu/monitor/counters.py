@@ -104,6 +104,16 @@ Instrumented sites:
   `kv.prefix_evictions` — refcount-0 cached blocks reclaimed LRU-first
   by the allocator under pool pressure (distinct from `kv.evictions`,
   which counts FORCED frees of errored requests' live blocks).
+  Summarised windows (a served model with "eva" attention:
+  exact rows for the open window, one summary row a chunk behind it):
+  `kv.summary_rows` — summary rows written (calls = program calls that
+  completed a chunk; bytes = rows); `kv.window_closes` — windows
+  closed mid-request at a step boundary (bytes = exact blocks returned
+  to the free list); `serve.eva.rows_read` — calls = queries decoded,
+  bytes = cache rows they read (the window up to the query plus the
+  visible summary rows); `serve.eva.context_tokens` — bytes = cached
+  length of the same queries, so rows_read / context_tokens is the
+  share of full attention's reads that is left.
   Fleet routing (`router.*`, serving/router.py, rendered as the
   "Fleet router" rows; excluded from the comm byte table like the
   rest of the serving families): `router.dispatches` — requests

@@ -1,11 +1,13 @@
 """deepspeed_tpu.serving — the continuous-batching inference engine.
 
 The headline serving scenario (ROADMAP item 1): a paged, mesh-sharded
-KV cache with block-level prefix caching (`kv_cache.py`), in-flight
-admission with chunked prefill (`scheduler.py`), compiled
-prefill/decode programs built StepBuilder-style (`programs.py`), the
-engine + worker loop with pinned sessions (`engine.py`), and a
-multi-replica fleet router (`router.py`).  Benchmarked by
+KV cache with block-level prefix caching and, for models that keep
+exact keys only for an open window, summary rows under the same
+allocator (`kv_cache.py`), in-flight admission with chunked prefill
+(`scheduler.py`), compiled prefill/decode programs built
+StepBuilder-style from the model's layer spec (`programs.py`,
+`layers.py`), the engine + worker loop with pinned sessions
+(`engine.py`), and a multi-replica fleet router (`router.py`).  Benchmarked by
 `tools/serve_bench.py`; tutorial at docs/tutorials/serving.md.
 """
 

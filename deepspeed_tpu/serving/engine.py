@@ -2,7 +2,9 @@
 
 One `ServeEngine` owns: a `PagedKVCache` (block pool + free list), a
 `Scheduler` (admission + slots), and the jitted {prefill, decode}
-program pair from `ServeProgramBuilder`.  `step()` is the whole serving
+program pair from `ServeProgramBuilder`.  It serves any model that
+hands over a layer spec (`model.layer_spec()`, models/layer_spec.py):
+the GPT family and EvaByte today.  `step()` is the whole serving
 loop body — admit, prefill one chunk round, decode one token for every
 running slot — and everything else (the bench's Poisson arrival thread,
 `generate()`'s synchronous loop, a `ServeWorker` daemon) just drives
@@ -46,6 +48,20 @@ output bitwise-identical with the cache on or off.  Counters:
 `kv.prefix_hits`, `kv.prefix_hit_tokens`, `kv.cow_copies`,
 `kv.session_pins`, `kv.prefix_evictions`.
 
+Summarised windows (a layer spec with "eva" attention): the cache
+keeps exact rows only for a request's open window and one summary row
+per chunk behind it.  The programs write both; the engine does the
+book-keeping at step boundaries: before a call it takes the blocks the
+call will write (`kv.extend`), after it, when a request's cached length
+reaches a multiple of the window, it closes the window (`kv.close_window`:
+the window's exact blocks go back to the free list mid-request, its
+summary rows become visible — the programs read that off the position).
+No program of its own, so nothing compiles after warm-up.  For such a
+model the engine refuses, by name, `prefix_cache=True`, sessions,
+`draft_len > 0`, quantized weights and int8/int4 rows.  Counters:
+`kv.summary_rows`, `kv.window_closes`, `serve.eva.rows_read`,
+`serve.eva.context_tokens`; host span `eva.window_close`.
+
 Speculative decoding (`draft_len > 0`): each decode step becomes a
 verify step — a host-side n-gram drafter proposes up to `draft_len`
 candidates per slot from the request's own emitted tokens, the batched
@@ -69,11 +85,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models.gpt import GPT
 from ..monitor.counters import COUNTERS
 from ..runtime.resilience import fault_point
 from ..utils.logging import logger
-from .kv_cache import PagedKVCache, TRASH_BLOCK
+from .kv_cache import PagedKVCache, TRASH_BLOCK, resolve_kv_dtype
 from .programs import ServeProgramBuilder, ServeSchedule
 from .scheduler import (ADMISSION_POLICIES, ERROR, FINISHED, RUNNING,
                         Request, Scheduler)
@@ -121,8 +136,6 @@ class ServeConfig:
                 f"serving quantized_weights must be False, 'int8' or "
                 f"'int4', got {q!r}")
         if self.kv_dtype is not None:
-            from .kv_cache import resolve_kv_dtype
-
             resolve_kv_dtype(self.kv_dtype)  # raises on typos, loudly
         if int(self.draft_len) < 0:
             raise ValueError(
@@ -166,7 +179,7 @@ class ServeEngine:
     cache.  Single engine thread drives `step()`; `submit()` is safe
     from any thread."""
 
-    def __init__(self, model: GPT, params, config: Optional[ServeConfig]
+    def __init__(self, model, params, config: Optional[ServeConfig]
                  = None, mesh_info=None, programs: Optional[dict] = None,
                  clock=time.monotonic):
         self.model = model
@@ -174,17 +187,47 @@ class ServeEngine:
         self.clock = clock
         cfg = model.config
         c = self.config
+        spec = model.layer_spec()
         self.max_seq_len = int(c.max_seq_len or cfg.max_seq_len)
         if self.max_seq_len > cfg.max_seq_len:
             raise ValueError(
                 f"serving max_seq_len {self.max_seq_len} exceeds the "
                 f"model's positional table ({cfg.max_seq_len})")
         table_width = -(-self.max_seq_len // c.block_size)
+        window_blocks = 0
+        if spec.attention == "eva":
+            if c.prefix_cache:
+                raise NotImplementedError(
+                    "prefix_cache=True over summarised windows: a shared "
+                    "prefix would have to keep a closed window's summary "
+                    "rows AND the open window's exact blocks alive for "
+                    "its followers, and only whole blocks of exact rows "
+                    "are hashed today; pass prefix_cache=False")
+            # the window's exact blocks, then one summary row per chunk
+            window_blocks = -(-spec.window // c.block_size)
+            table_width = window_blocks + -(-table_width // c.block_size)
         if mesh_info is None:
             from ..comm.mesh import peek_mesh
 
             mesh_info = peek_mesh()
         self.mesh_info = mesh_info
+        kv_dtype = cfg.param_dtype if c.kv_dtype is None else c.kv_dtype
+        kv_mode = resolve_kv_dtype(kv_dtype)[0]
+        schedule = ServeSchedule(
+            max_batch=c.max_batch, prefill_chunk=c.prefill_chunk,
+            block_size=c.block_size, num_blocks=c.num_blocks,
+            table_width=table_width, quantized=c.quant_mode,
+            kv_dtype=kv_mode, draft_len=int(c.draft_len),
+            window_blocks=window_blocks)
+        if programs is None:
+            # the builder refuses what a family's programs cannot do
+            # before the pool is laid out
+            programs = ServeProgramBuilder(model, schedule).build()
+        elif programs["schedule"].program_key() != schedule.program_key():
+            raise ValueError(
+                f"prebuilt programs were compiled for "
+                f"{programs['schedule'].describe()!r} but this engine "
+                f"needs {schedule.describe()!r}")
         # the chain-hash salt: anything that changes K/V block CONTENT
         # for the same token ids must key the prefix cache (the kv
         # storage mode is folded in by the cache itself)
@@ -194,10 +237,11 @@ class ServeEngine:
             num_layers=cfg.num_layers, num_heads=cfg.num_heads,
             head_dim=cfg.head_dim, num_blocks=c.num_blocks,
             block_size=c.block_size, table_width=table_width,
-            dtype=(cfg.param_dtype if c.kv_dtype is None else c.kv_dtype),
-            mesh_info=mesh_info, prefix_cache=c.prefix_cache,
+            dtype=kv_dtype, mesh_info=mesh_info,
+            prefix_cache=c.prefix_cache,
             min_match_blocks=c.prefix_min_match_blocks,
-            prefix_salt=prefix_salt)
+            prefix_salt=prefix_salt,
+            window_tokens=window_blocks * c.block_size)
         self.scheduler = Scheduler(self.kv, c.max_batch,
                                    admission=c.admission, clock=clock,
                                    draft_len=int(c.draft_len))
@@ -207,19 +251,6 @@ class ServeEngine:
         if c.prefix_cache:
             self.scheduler.session_lookup = self._session_lookup
             self.scheduler.session_consumed = self._session_consumed
-        schedule = ServeSchedule(
-            max_batch=c.max_batch, prefill_chunk=c.prefill_chunk,
-            block_size=c.block_size, num_blocks=c.num_blocks,
-            table_width=table_width, quantized=c.quant_mode,
-            kv_dtype=(self.kv.quant_wire or "dense"),
-            draft_len=int(c.draft_len))
-        if programs is None:
-            programs = ServeProgramBuilder(model, schedule).build()
-        elif programs["schedule"].program_key() != schedule.program_key():
-            raise ValueError(
-                f"prebuilt programs were compiled for "
-                f"{programs['schedule'].describe()!r} but this engine "
-                f"needs {schedule.describe()!r}")
         self.programs = programs
         self.params = programs["prepare_params"](
             self._place_params(params))
@@ -292,6 +323,13 @@ class ServeEngine:
             raise ValueError(
                 f"top_k must be >= 0 and temperature >= 0, got "
                 f"{top_k}, {temperature}")
+        if session_id is not None and self.kv.windowed:
+            raise NotImplementedError(
+                "sessions over summarised windows: a pin would have to "
+                "hold the open window's exact blocks and every summary "
+                "row of the conversation, and the next turn resume inside "
+                "a window; only whole tables of exact rows are pinned "
+                "today")
         req = Request(prompt=prompt, max_new_tokens=int(max_new_tokens),
                       temperature=float(temperature), top_k=int(top_k),
                       seed=int(seed), eos_token=eos_token,
@@ -528,6 +566,8 @@ class ServeEngine:
         tus0 = tr.now_us() if tr is not None else 0
         tokens = np.zeros((1, C), np.int32)
         tokens[0, :n_valid] = chunk
+        if self.kv.windowed:
+            self._take_blocks(req, pos0, pos0 + n_valid)
         tok, _logits, caches = self.programs["prefill"](
             self.params, self.kv.caches, jnp.asarray(tokens),
             np.int32(req.prefill_pos), np.int32(n_valid),
@@ -536,6 +576,8 @@ class ServeEngine:
         self.kv.caches = caches
         req.prefill_pos += n_valid
         req.cached_len = req.prefill_pos
+        if self.kv.windowed:
+            self._close_full_window(req)
         COUNTERS.add("serve.prefill_chunks", nbytes=n_valid)
         if tr is not None:
             # cached/computed: the prefix-cache outcome per request —
@@ -590,6 +632,17 @@ class ServeEngine:
             return
         tr = self._step_tracer()
         tus0 = tr.now_us() if tr is not None else 0
+        if self.kv.windowed:
+            W, C = self.kv.window_tokens, self.kv.block_size
+            for req in running:
+                p = int(self._positions[req.slot])
+                self._take_blocks(req, p, p + 1)
+                self._tables[req.slot] = req.table
+                # what this query reads: its window up to itself and
+                # the summaries of the windows closed before it
+                COUNTERS.add("serve.eva.rows_read",
+                             nbytes=p % W + 1 + p // W * (W // C))
+                COUNTERS.add("serve.eva.context_tokens", nbytes=p + 1)
         t0 = time.perf_counter()
         toks, caches = self.programs["decode"](
             self.params, self.kv.caches, jnp.asarray(self._tokens),
@@ -615,12 +668,42 @@ class ServeEngine:
             else:
                 self._tokens[slot] = tok
                 self._positions[slot] += 1
+                if self.kv.windowed:
+                    self._close_full_window(req)
         if self._slo is not None:
             self._slo.observe_tokens(len(running))
         if tr is not None:
             tr.add_complete("decode_step", "serve", ts_us=tus0,
                             dur_us=tr.now_us() - tus0, step=self.steps,
                             batch=len(running))
+
+    # -- summarised windows: host book-keeping at step boundaries --------
+
+    def _take_blocks(self, req: Request, start: int, stop: int) -> None:
+        """Before a program writes positions [start, stop): take the
+        exact blocks of those window offsets and the summary blocks of
+        the chunks the call completes (booked at admission)."""
+        req.table = self.kv.extend(req.rid, start, stop)
+        rows = stop // self.kv.block_size - start // self.kv.block_size
+        if rows:
+            COUNTERS.add("kv.summary_rows", nbytes=rows)
+
+    def _close_full_window(self, req: Request) -> None:
+        """After a program wrote up to `req.cached_len`: if that filled
+        the request's window, give its exact blocks back to the free
+        list; its summary rows stay and the next query, in the next
+        window, sees them."""
+        if req.cached_len % self.kv.window_tokens:
+            return
+        tr = self._req_tracer(req)
+        tus0 = tr.now_us() if tr is not None else 0
+        back = self.kv.close_window(req.rid)
+        if req.slot is not None and self._active[req.slot]:
+            self._tables[req.slot] = req.table
+        if tr is not None:
+            tr.add_complete("eva.window_close", "serve", ts_us=tus0,
+                            dur_us=tr.now_us() - tus0, rid=req.rid,
+                            blocks=back, cached=req.cached_len)
 
     def _step_tracer(self):
         """The tracer, iff this engine step's index is sampled in

@@ -1,5 +1,7 @@
 """Paged KV cache: fixed-size blocks, a refcounted free-list allocator,
-per-request block tables, and a block-level prefix cache.
+per-request block tables, a block-level prefix cache, and — for models
+whose attention keeps exact keys only for an open window — a second kind
+of row under the same allocator.
 
 The serving problem the static cache in models/generation.py cannot
 solve: a decode batch whose membership changes every step.  A contiguous
@@ -64,6 +66,22 @@ the token prefix like dense rows, so prefix aliasing stays bitwise at
 int8/int4 too (the chain hash is salted with the storage mode, so a
 dense block is never served to an int8 engine).
 
+Two kinds of row (`window_tokens > 0`, the EVA layer spec): a block is
+either an EXACT block (`block_size` consecutive tokens' K/V of the
+request's open window) or a SUMMARY block (`block_size` consecutive
+summary rows, one row per `block_size` tokens, k~ in the K array and v~
+in the V array).  Both come from the one pool and the one free list; a
+request's table is `[window_blocks | summary_blocks]` entries wide.
+Nothing is handed out at admission: `reserve()` books the request's
+bounded footprint (`blocks_needed`: at most a window of exact blocks
+plus one summary row per `block_size` tokens), `extend()` takes blocks
+from the free list as positions are about to be written, and
+`close_window()` gives a full window's exact blocks back mid-request
+while the summary rows stay.  Booked-but-not-held blocks are `promised`
+and are not free for admission, so `extend()` never fails.  The prefix
+cache, session pins and quantized rows are not offered for such a cache
+(the engine refuses them by name).
+
 Block 0 is the reserved TRASH block: the allocator never hands it out,
 block tables are padded with it, and inactive decode slots write to it —
 so the jitted programs need no branches for "this slot/table entry is
@@ -79,7 +97,9 @@ forced frees — a healthy run keeps it at zero.  The prefix cache adds
 blocks aliased), `kv.prefix_hit_tokens` (bytes = prompt tokens whose
 prefill was skipped), `kv.cow_copies` (bytes = device bytes copied),
 `kv.session_pins` (bytes = blocks pinned) and `kv.prefix_evictions`
-(refcount-0 cached blocks LRU-evicted to serve an allocation).
+(refcount-0 cached blocks LRU-evicted to serve an allocation).  A
+windowed cache adds `kv.window_closes` (calls; bytes = exact blocks
+returned to the free list) and the engine `kv.summary_rows`.
 """
 
 from __future__ import annotations
@@ -169,7 +189,7 @@ class PagedKVCache:
                  num_blocks: int, block_size: int, table_width: int,
                  dtype=jnp.float32, mesh_info=None,
                  prefix_cache: bool = True, min_match_blocks: int = 1,
-                 prefix_salt: str = ""):
+                 prefix_salt: str = "", window_tokens: int = 0):
         if num_blocks < 2:
             raise ValueError(
                 f"num_blocks must be >= 2 (block 0 is the reserved trash "
@@ -187,6 +207,21 @@ class PagedKVCache:
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.table_width = int(table_width)
+        # 0: every token keeps its exact row for the request's life.
+        # > 0: exact rows for the open window only; the table's first
+        # `window_blocks` entries are the window, the rest summary blocks
+        self.window_tokens = int(window_tokens)
+        if self.window_tokens % self.block_size:
+            raise ValueError(
+                f"window_tokens {window_tokens} must be a multiple of "
+                f"block_size {block_size}")
+        self.window_blocks = self.window_tokens // self.block_size
+        if self.windowed and (prefix_cache
+                              or self.window_blocks >= self.table_width):
+            raise ValueError(
+                "a windowed cache takes no prefix cache and needs table "
+                "entries for its summary blocks beyond the window's "
+                f"{self.window_blocks}")
         self.dtype = dtype
         mode, dense_dtype = resolve_kv_dtype(dtype)
         # "int8"/"int4" when blocks are stored quantized, else None
@@ -205,6 +240,8 @@ class PagedKVCache:
         self._owned: Dict[Any, List[int]] = {}
         # holders per block (live requests + session pins); absent = 0
         self._ref: Dict[int, int] = {}
+        # windowed owners: rid -> [table, booked blocks, closed windows]
+        self._booked: Dict[Any, list] = {}
         self.evictions = 0
         # -- prefix cache state ---------------------------------------
         self.prefix_enabled = bool(prefix_cache)
@@ -302,8 +339,33 @@ class PagedKVCache:
     @property
     def free_blocks(self) -> int:
         """Allocatable blocks: the free list plus the refcount-0
-        cached blocks the LRU would evict to serve an allocation."""
-        return len(self._free) + len(self._lru)
+        cached blocks the LRU would evict to serve an allocation, less
+        what windowed requests have booked and not yet taken."""
+        return len(self._free) + len(self._lru) - self.promised_blocks
+
+    @property
+    def windowed(self) -> bool:
+        return self.window_tokens > 0
+
+    @property
+    def promised_blocks(self) -> int:
+        """Blocks booked by `reserve` that their owners do not hold
+        right now (not yet written, or given back at a window close)."""
+        return sum(b[1] - len(self._owned[rid])
+                   for rid, b in self._booked.items())
+
+    @property
+    def token_capacity(self) -> int:
+        """The longest request one table can address."""
+        if not self.windowed:
+            return self.table_width * self.block_size
+        return (self.table_width - self.window_blocks) * self.block_size ** 2
+
+    @property
+    def summary_rows_in_use(self) -> int:
+        """Summary rows live requests attend to: the completed chunks of
+        their closed windows."""
+        return sum(b[2] for b in self._booked.values()) * self.window_blocks
 
     @property
     def cached_blocks(self) -> int:
@@ -311,7 +373,16 @@ class PagedKVCache:
         return len(self._hash_index)
 
     def blocks_needed(self, n_tokens: int) -> int:
-        return -(-int(n_tokens) // self.block_size)
+        """The most blocks a request of `n_tokens` holds at once: every
+        token's exact block, or, windowed, at most a window of exact
+        blocks plus the blocks of one summary row per `block_size`
+        tokens."""
+        exact = -(-int(n_tokens) // self.block_size)
+        if not self.windowed:
+            return exact
+        summary_rows = int(n_tokens) // self.block_size
+        return min(exact, self.window_blocks) + \
+            -(-summary_rows // self.block_size)
 
     def _take_free(self) -> int:
         """Pop one allocatable block, evicting the coldest refcount-0
@@ -364,7 +435,7 @@ class PagedKVCache:
         # empty pool mid-allocation.
         lru_shared = sum(1 for b in set(shared)
                          if self._ref.get(b, 0) == 0)
-        if fresh > len(self._free) + len(self._lru) - lru_shared:
+        if fresh > self.free_blocks - lru_shared:
             return None
         blocks: List[int] = []
         cow_pair = None
@@ -411,6 +482,7 @@ class PagedKVCache:
         actually released (a still-shared block survives its evicted
         holder); natural completion does not."""
         blocks = self._owned.pop(rid, None)
+        self._booked.pop(rid, None)
         if not blocks:
             return 0
         released = 0
@@ -429,6 +501,72 @@ class PagedKVCache:
             self.evictions += released
             COUNTERS.add("kv.evictions", calls=released)
         return len(blocks)
+
+    # -- two kinds of row: windowed requests ---------------------------
+
+    def reserve(self, rid, n_blocks: int) -> Optional[np.ndarray]:
+        """Book `n_blocks` (the request's bounded footprint) without
+        taking any: -> an all-trash table, or None when the pool cannot
+        promise them."""
+        if rid in self._owned:
+            raise ValueError(f"request {rid} already holds blocks")
+        if int(n_blocks) > self.free_blocks:
+            return None
+        table = np.full((self.table_width,), TRASH_BLOCK, np.int32)
+        self._owned[rid] = []
+        self._booked[rid] = [table, int(n_blocks), 0]
+        return table
+
+    def _take_into(self, rid, table, entry: int) -> None:
+        if table[entry] == TRASH_BLOCK:
+            b = self._take_free()
+            self._ref[b] = 1
+            self._owned[rid].append(b)
+            table[entry] = b
+
+    def extend(self, rid, start: int, stop: int) -> np.ndarray:
+        """Before positions [start, stop) are written: take the exact
+        blocks of those window offsets and the summary blocks of the
+        chunks they complete.  Never fails: the blocks were booked."""
+        table = self._booked[rid][0]
+        bs, wb = self.block_size, self.window_blocks
+        for blk in range(int(start) // bs, -(-int(stop) // bs)):
+            self._take_into(rid, table, blk % wb)
+        for chunk in range(int(start) // bs, int(stop) // bs):
+            self._take_into(rid, table, wb + chunk // bs)
+        return table
+
+    def close_window(self, rid) -> int:
+        """The request's open window is full: its exact blocks go back
+        to the free list, its summary rows stay and become visible.
+        -> blocks returned."""
+        booked = self._booked[rid]
+        table = booked[0]
+        mine = self._owned[rid]
+        back = [int(b) for b in table[:self.window_blocks]
+                if b != TRASH_BLOCK]
+        for b in back:
+            self._ref.pop(b, None)
+            mine.remove(b)
+            self._free.append(b)
+        table[:self.window_blocks] = TRASH_BLOCK
+        booked[2] += 1
+        COUNTERS.add("kv.window_closes", nbytes=len(back))
+        return len(back)
+
+    def exact_blocks_of(self, rid) -> List[int]:
+        if rid not in self._booked:
+            return self.blocks_of(rid)
+        table = self._booked[rid][0]
+        return [int(b) for b in table[:self.window_blocks]
+                if b != TRASH_BLOCK]
+
+    def summary_blocks_of(self, rid) -> List[int]:
+        if rid not in self._booked:
+            return []
+        table = self._booked[rid][0]
+        return [int(b) for b in table[self.window_blocks:]
+                if b != TRASH_BLOCK]
 
     # -- prefix cache -------------------------------------------------
 
@@ -570,8 +708,13 @@ class PagedKVCache:
     def describe(self) -> str:
         mode = (self.quant_wire if self.quant_wire
                 else jnp.dtype(self.dense_dtype).name)
+        rows = "exact rows" if not self.windowed else (
+            f"exact rows for a window of {self.window_tokens} tok "
+            f"({self.window_blocks} blocks) + summary rows 1 per "
+            f"{self.block_size} tok "
+            f"({self.table_width - self.window_blocks} blocks)")
         return (f"PagedKVCache(layers={self.num_layers}, "
-                f"blocks={self.num_blocks} x {self.block_size} tok, "
+                f"blocks={self.num_blocks} x {self.block_size} rows, {rows}, "
                 f"table_width={self.table_width}, heads={self.num_heads}, "
                 f"head_dim={self.head_dim}, kv={mode}, "
                 f"prefix_cache={'on' if self.prefix_enabled else 'off'}, "

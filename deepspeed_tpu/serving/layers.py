@@ -1,0 +1,321 @@
+"""Embed, block and head of a served model, assembled from the
+`LayerSpec` the model hands over (`models/layer_spec.py`): which norm,
+positions, attention and FFN a block is made of.  `programs.py` builds
+its prefill, decode and verify programs from these and knows no model by
+name.
+
+What is built today: learned positions with paged attention (the GPT-2
+block: fused QKV with bias, every cached position attended), and rotary
+positions with EVA attention (the EvaByte block: exact rows for the open
+window, one summary row a chunk behind it).  The norm, the FFN and the
+head are free of that choice.
+
+The paged pieces mirror models/generation.py `_block_with_cache` op for
+op (fp32 scores, the same einsum strings, NEG_INF masking, probs cast to
+the cache dtype), so greedy serving output is bit-identical to
+`generate()` when the cache lengths agree — pinned in
+tests/test_serving.py.  Their statements stand in the order the programs
+had them before they moved here: the order of independent operations is
+part of a program's StableHLO, which keys the compilation cache.
+
+Addressing (`Addr`): a program works out once where this call's K/V land
+and what attention reads, and every layer's block uses it.  Paged: flat
+write rows, the flat rows of the whole table, query positions.  EVA: the
+same write rows (inside the open window's blocks), the block table
+`[window blocks | summary blocks]`, the flat rows the summaries of the
+chunks this call completes land in, and — in decode, where a chunk
+completes one token at a time — the rows of the chunk's own block, so
+that its summary pools what the cache holds.  `block_size` equals the
+chunk, so one exact block is one chunk and `block_size` summary rows are
+one summary block.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from ..models.evabyte import (chunk_summaries, matmul32, project_qkv,
+                              rms_norm, silu_gated_ffn)
+from ..models.gpt import layer_norm
+from ..models.layer_spec import LayerSpec
+from .kv_cache import rows_for_tables
+
+BUILT = {("learned", "paged"), ("rope", "eva")}
+
+
+def check_spec(spec: LayerSpec) -> LayerSpec:
+    spec.validate()
+    if (spec.positions, spec.attention) not in BUILT:
+        raise NotImplementedError(
+            f"serving has no block with {spec.positions!r} positions and "
+            f"{spec.attention!r} attention yet; built: {sorted(BUILT)}")
+    return spec
+
+
+class Addr(NamedTuple):
+    write_idx: jax.Array                  # [B*T] flat rows of this call's K/V
+    q_pos: jax.Array                      # [B, T] absolute positions
+    rows: Optional[jax.Array] = None      # paged: [B, L] flat rows attended
+    tables: Optional[jax.Array] = None    # eva: [B, Wt] block tables
+    sum_idx: Optional[jax.Array] = None   # eva: [B*n] flat summary rows
+    chunk_src: Optional[jax.Array] = None  # eva decode: [B, bs] flat rows
+
+
+# -- embedding --------------------------------------------------------------
+
+
+def embed_chunk(spec, params, tokens, abs_pos):
+    """tokens [1, C] at positions abs_pos [C] (prefill) or [R, T] at
+    [R, T] (verify) -> x.  Rows past the position table clamp."""
+    if spec.positions == "rope":
+        return params["wte"][tokens].astype(jnp.float32)
+    # per-row gather, NOT dynamic_slice_in_dim(wpe, pos, C): when the
+    # final chunk's pad rows run past the wpe table, a dynamic slice
+    # CLAMPS its start backwards and shifts the VALID rows onto wrong
+    # positional embeddings (silently breaking the ==generate()
+    # contract); the gather keeps every valid row exact and only pad
+    # rows (overwritten before read / masked) see the clamped last entry
+    wpe_rows = params["wpe"][
+        jnp.clip(abs_pos, 0, params["wpe"].shape[0] - 1)]
+    if abs_pos.ndim == 1:
+        return params["wte"][tokens] + wpe_rows[None]
+    return params["wte"][tokens] + wpe_rows
+
+
+def embed_step(spec, params, tokens, positions):
+    """tokens [R] at positions [R] (decode) -> x [R, 1, D]."""
+    if spec.positions == "rope":
+        return params["wte"][tokens].astype(jnp.float32)[:, None, :]
+    return (params["wte"][tokens] +
+            params["wpe"][positions])[:, None, :]
+
+
+# -- addressing -------------------------------------------------------------
+
+
+def _gather_rows(table, block_size):
+    """Block table [W] -> flat cache row indices [W * block_size]."""
+    return (table[:, None] * block_size +
+            jnp.arange(block_size)[None, :]).reshape(-1)
+
+
+def address_chunk(spec, s, table, pos, abs_pos, n_valid) -> Addr:
+    """One request's prefill chunk at positions abs_pos [C] through its
+    table [W]."""
+    bs, W = s.block_size, s.table_width
+    if spec.attention == "paged":
+        blk_i = abs_pos // bs
+        # positions past the table (pad rows of the final chunk)
+        # write to the trash block, never a neighbour's memory
+        blk = jnp.where(blk_i < W, table[jnp.clip(blk_i, 0, W - 1)], 0)
+        write_idx = blk * bs + abs_pos % bs
+        rows = _gather_rows(table, bs)[None, :]
+        return Addr(write_idx=write_idx, q_pos=abs_pos[None, :], rows=rows)
+    # eva: the chunk lies inside one window (prefill_chunk divides it);
+    # rows past n_valid and the summaries of chunks it does not complete
+    # go to the trash block
+    wb = spec.window // bs
+    off = abs_pos % spec.window
+    valid = jnp.arange(abs_pos.shape[0]) < n_valid
+    write_idx = jnp.where(valid, table[off // bs] * bs + off % bs, off % bs)
+    n_c = abs_pos.shape[0] // spec.chunk
+    c = pos // spec.chunk + jnp.arange(n_c)
+    done = (jnp.arange(n_c) + 1) * spec.chunk <= n_valid
+    sblk = table[jnp.clip(wb + c // bs, 0, W - 1)]
+    sum_idx = jnp.where(done, sblk * bs + c % bs, c % bs)
+    return Addr(write_idx=write_idx, q_pos=abs_pos[None, :],
+                tables=table[None, :], sum_idx=sum_idx)
+
+
+def address_step(spec, s, tables, positions, active) -> Addr:
+    """One token for every slot at positions [R] through tables [R, W].
+    Inactive slots write to the trash block."""
+    bs, W = s.block_size, s.table_width
+    if spec.attention == "paged":
+        blk_i = positions // bs
+        blk = jnp.take_along_axis(
+            tables, jnp.clip(blk_i, 0, W - 1)[:, None], axis=1)[:, 0]
+        write_idx = jnp.where(active, blk * bs + positions % bs, 0)
+        rows = rows_for_tables(tables, bs)
+        return Addr(write_idx=write_idx, q_pos=positions[:, None], rows=rows)
+    wb = spec.window // bs
+    off = positions % spec.window
+    blk = jnp.where(active, jnp.take_along_axis(
+        tables, (off // bs)[:, None], axis=1)[:, 0], 0)
+    write_idx = blk * bs + jnp.where(active, off % bs, 0)
+    # the token at the last offset of a chunk completes it: its summary,
+    # pooled from the chunk's own block, goes to summary row `c`
+    c = positions // spec.chunk
+    done = active & (positions % spec.chunk == spec.chunk - 1)
+    sblk = jnp.take_along_axis(
+        tables, jnp.clip(wb + c // bs, 0, W - 1)[:, None], axis=1)[:, 0]
+    sum_idx = jnp.where(done, sblk * bs + c % bs, 0)
+    chunk_src = blk[:, None] * bs + jnp.arange(bs)[None, :]
+    return Addr(write_idx=write_idx, q_pos=positions[:, None],
+                tables=tables, sum_idx=sum_idx, chunk_src=chunk_src)
+
+
+def address_grid(spec, s, tables, abs_pos, active, n_draft) -> Addr:
+    """T candidate tokens for every slot at abs_pos [R, T] (verify);
+    rows past a slot's `n_draft` candidates write to the trash block."""
+    if spec.attention != "paged":
+        raise NotImplementedError(
+            "the verify program over summarised windows is not built: a "
+            "rejected draft may have completed a chunk, whose summary row "
+            "would have to be rewound with it")
+    bs, W = s.block_size, s.table_width
+    R, T = abs_pos.shape
+    blk_i = abs_pos // bs
+    valid = (active[:, None] &
+             (jnp.arange(T)[None, :] <= n_draft[:, None]) &
+             (blk_i < W))
+    blk = jnp.take_along_axis(tables,
+                              jnp.clip(blk_i, 0, W - 1), axis=1)
+    # rows past a slot's drafts (and inactive slots) write to
+    # the trash block, the decode convention
+    write_idx = jnp.where(valid, blk * bs + abs_pos % bs,
+                          0).reshape(R * T)
+    rows = rows_for_tables(tables, bs)
+    return Addr(write_idx=write_idx, q_pos=abs_pos, rows=rows)
+
+
+# -- the block --------------------------------------------------------------
+
+
+def _kv_write(c, idx, val, kv_mode):
+    """Scatter `val` [N, H, Dh] into cache entry `c` at flat rows
+    `idx`.  Dense: a plain row scatter at the cache's own dtype.
+    Quantized: the rows are quantized through the PR-7 row kernels and
+    BOTH the payload and the per-(row, head) scales scatter at the same
+    indices — the write never touches another row's scale."""
+    if kv_mode == "dense":
+        return c.at[idx].set(val.astype(c.dtype))
+    from ..runtime.comm.quant import quantize_rows
+
+    payload, scales = c
+    codes, s = quantize_rows(val.astype(jnp.float32), kv_mode)
+    return (payload.at[idx].set(codes), scales.at[idx].set(s))
+
+
+def _paged_attend(cfg, p, h, ck, cv, addr, kv_mode, block_size):
+    """Fused QKV with bias, K/V written through the table, causal
+    softmax over every cached row, output projection.  Op-for-op the
+    math of generation._block_with_cache; only the cache addressing
+    differs (scatter/gather through the table instead of
+    dynamic_update_slice on a contiguous cache).  `kv_mode` picks the
+    storage codec: "dense" stores rows at the cache arrays' dtype,
+    "int8"/"int4" stores (payload, scales) pairs dequantized at the
+    gather — the surrounding math is identical either way, so parity
+    pins hold AT MATCHED kv_mode."""
+    B, T, D = h.shape
+    H, Dh = cfg.num_heads, cfg.head_dim
+    qkv = h @ p["qkv"]["w"].astype(h.dtype) + \
+        p["qkv"]["b"].astype(h.dtype)
+    q, k, v = jnp.split(qkv, 3, axis=-1)
+    shape = lambda t: t.reshape(B, T, H, Dh)
+    q, k, v = shape(q), shape(k), shape(v)
+    ck = _kv_write(ck, addr.write_idx, k.reshape(B * T, H, Dh), kv_mode)
+    cv = _kv_write(cv, addr.write_idx, v.reshape(B * T, H, Dh), kv_mode)
+    # attention core through the kernel registry: the jnp oracle
+    # (kernels/paged.py paged_attention_reference) is this block's
+    # pre-registry gather/einsum/softmax chain op-for-op — wherever the
+    # oracle is chosen, serving output is bit-identical; the Pallas
+    # kernel fuses the table gather (+ quantized-KV dequant) into an
+    # online-softmax sweep over cache blocks
+    from ..kernels import registry
+
+    attn = registry.dispatch(
+        "paged_attention", q, ck, cv, addr.rows, addr.q_pos,
+        info={"block_size": block_size, "kv_len": addr.rows.shape[1],
+              "q_len": T, "head_dim": Dh, "kv_mode": kv_mode},
+        kv_mode=kv_mode, block_size=block_size)
+    attn = attn.reshape(B, T, D)
+    attn = attn @ p["proj"]["w"].astype(h.dtype) + \
+        p["proj"]["b"].astype(h.dtype)
+    return attn, ck, cv
+
+
+def _eva_attend(spec, cfg, p, h, ck, cv, addr, block_size):
+    """q, k, v with rotary positions at the cache's dtype; this call's
+    exact rows written into the open window's blocks; the summaries of
+    the chunks this call completes written to their summary rows; then
+    one softmax over the window's rows up to the query and the summary
+    rows of closed windows (kernels/eva.py).  -> float32."""
+    B, T, D = h.shape
+    H, Dh = cfg.num_heads, cfg.head_dim
+    q, k, v = project_qkv(p, h, addr.q_pos, spec.rope_theta, H, ck.dtype)
+    ck = ck.at[addr.write_idx].set(k.reshape(B * T, H, Dh))
+    cv = cv.at[addr.write_idx].set(v.reshape(B * T, H, Dh))
+    if addr.chunk_src is None:   # prefill: whole chunks, as just stored
+        chunks = lambda t: t.reshape(B, T // spec.chunk, spec.chunk, H, Dh)
+        ks, vs = chunk_summaries(chunks(k), chunks(v), p["mu"], p["phi"])
+    else:                        # decode: the chunk's block, as stored
+        ks, vs = chunk_summaries(ck[addr.chunk_src], cv[addr.chunk_src],
+                                 p["mu"], p["phi"])
+    ck = ck.at[addr.sum_idx].set(ks.reshape(-1, H, Dh).astype(ck.dtype))
+    cv = cv.at[addr.sum_idx].set(vs.reshape(-1, H, Dh).astype(cv.dtype))
+    from ..kernels import registry
+
+    attn = registry.dispatch(
+        "eva_attention", q, ck, cv, addr.tables, addr.q_pos,
+        window=spec.window, chunk=spec.chunk, block_size=block_size)
+    return matmul32(attn.reshape(B, T, D), p["o"]), ck, cv
+
+
+def _norm(spec, x, p):
+    if spec.norm == "layernorm":
+        return layer_norm(x, p, spec.eps)
+    return rms_norm(x, p, spec.eps)
+
+
+def _ffn(spec, p, h):
+    if spec.ffn == "silu_gated":
+        return silu_gated_ffn(p, h)
+    h = h @ p["fc1"]["w"].astype(h.dtype) + \
+        p["fc1"]["b"].astype(h.dtype)
+    h = jax.nn.gelu(h, approximate=True)
+    return h @ p["fc2"]["w"].astype(h.dtype) + \
+        p["fc2"]["b"].astype(h.dtype)
+
+
+def block(spec, cfg, p, x, ck, cv, addr, kv_mode="dense", block_size=0):
+    """One pre-norm decoder block over x [B, T, D] through the cache."""
+    h = _norm(spec, x, p["ln1"])
+    if spec.attention == "paged":
+        attn, ck, cv = _paged_attend(cfg, p["attn"], h, ck, cv, addr,
+                                     kv_mode, block_size)
+    else:
+        attn, ck, cv = _eva_attend(spec, cfg, p["attn"], h, ck, cv, addr,
+                                   block_size)
+    x = x + attn
+    h = _norm(spec, x, p["ln2"])
+    return x + _ffn(spec, p["mlp"], h), ck, cv
+
+
+# -- head -------------------------------------------------------------------
+
+
+def final_norm(spec, params, x):
+    return _norm(spec, x, params["ln_f"])
+
+
+def logits(spec, params, x_rows):
+    """[B, D] normed hidden rows -> fp32 logits [B, V] (generation.py's
+    head), or, where the spec says `fp32_logits`, the float32 product
+    at full precision."""
+    w = params["wte"].T if spec.head == "tied" else params["lm_head"]
+    if spec.fp32_logits:
+        return jnp.dot(x_rows, w.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+    return (x_rows @ w.astype(x_rows.dtype)).astype(jnp.float32)
+
+
+def sampled(spec, row_logits):
+    """The logits the next token is drawn from: a multi-head output
+    decodes from its first head."""
+    if not spec.sample_vocab:
+        return row_logits
+    return row_logits[..., :spec.sample_vocab]

@@ -1,5 +1,12 @@
 """Compiled serving programs: ONE jitted prefill and ONE jitted decode,
-built through StepBuilder-style schedule composition.
+built through StepBuilder-style schedule composition, for any model that
+hands over a layer spec.
+
+Embed, block and head come from the model: `model.layer_spec()` says
+which norm, positions, attention and FFN a block is made of
+(`models/layer_spec.py`), and `serving/layers.py` assembles them.  This
+module owns what is common to every family — the schedule, the three
+program shapes, sampling, the qwZ weight store — and names no model.
 
 Like runtime/step_builder.py collapsed the three training step paths
 into one composition engine, serving lowers its two phases into two
@@ -32,11 +39,18 @@ runtime/comm/quant.py's row kernels and dequantize gathered rows to
 fp32 in-program.  The surrounding attention math is shared, so parity
 contracts hold at matched kv_dtype.
 
-The attention math deliberately mirrors models/generation.py
-`_block_with_cache` op for op (fp32 scores, the same einsum strings,
-NEG_INF masking, probs cast to the cache dtype) so greedy serving output
-is bit-identical to `generate()` when the cache lengths agree — pinned
-in tests/test_serving.py.
+A spec with "eva" attention (exact rows for an open window, summary
+rows behind it) runs the same two programs over a table of
+`[window blocks | summary blocks]`; the block also writes the summary
+of every chunk a call completes, so closing a window needs no program
+of its own — it is host book-keeping in the engine.  Its `verify`
+program is not built (`draft_len` must be 0), nor are quantized weights
+or quantized rows for it.
+
+The paged attention math deliberately mirrors models/generation.py
+`_block_with_cache` op for op (serving/layers.py) so greedy serving
+output of a GPT is bit-identical to `generate()` when the cache lengths
+agree — pinned in tests/test_serving.py.
 
 Sampling determinism: the key for the token generated at absolute
 position p is `fold_in(PRNGKey(request.seed), p)` — a pure function of
@@ -58,9 +72,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..models.gpt import GPT, layer_norm
 from ..utils.logging import logger
-from .kv_cache import rows_for_tables
+from . import layers
 
 QUANT_MODES = ("none", "int8", "int4")
 
@@ -84,16 +97,28 @@ class ServeSchedule(NamedTuple):
     quant_block: int = 256
     kv_dtype: str = "dense"        # "dense" | "int8" | "int4"
     draft_len: int = 0             # speculative candidates per verify
+    window_blocks: int = 0         # > 0: the table's first entries are
+    #                                the open window's exact blocks, the
+    #                                rest summary blocks (one row a block
+    #                                of tokens)
 
     def describe(self) -> str:
-        cap = self.table_width * self.block_size
+        if self.window_blocks:
+            summary = self.table_width - self.window_blocks
+            cap = summary * self.block_size ** 2
+            rows = (f"exact rows for a window of {self.window_blocks} "
+                    f"blocks + {summary} blocks of summary rows, 1 per "
+                    f"{self.block_size} tok")
+        else:
+            cap = self.table_width * self.block_size
+            rows = "exact rows"
         q = "" if self.quantized == "none" else f", qwZ={self.quantized}"
         kv = "" if self.kv_dtype == "dense" else f", kv={self.kv_dtype}"
         spec = "" if not self.draft_len else \
             f", spec draft {self.draft_len}"
         return (f"serve schedule: decode[{self.max_batch} slots] + "
                 f"prefill[chunk {self.prefill_chunk}], paged KV "
-                f"{self.num_blocks} x {self.block_size} tok "
+                f"{self.num_blocks} x {self.block_size} rows, {rows} "
                 f"(per-request cap {cap}){q}{kv}{spec}")
 
     def program_key(self):
@@ -131,100 +156,6 @@ def _row_key(seed, position):
     p uses fold_in(PRNGKey(seed), p) — shared by prefill (first token)
     and decode so batch composition can never reach the RNG stream."""
     return jax.random.fold_in(jax.random.PRNGKey(seed), position)
-
-
-# -- paged attention block (mirrors generation._block_with_cache) -----------
-
-
-def _gather_rows(table, block_size):
-    """Block table [W] -> flat cache row indices [W * block_size]."""
-    return (table[:, None] * block_size +
-            jnp.arange(block_size)[None, :]).reshape(-1)
-
-
-def _kv_write(c, idx, val, kv_mode):
-    """Scatter `val` [N, H, Dh] into cache entry `c` at flat rows
-    `idx`.  Dense: a plain row scatter at the cache's own dtype.
-    Quantized: the rows are quantized through the PR-7 row kernels and
-    BOTH the payload and the per-(row, head) scales scatter at the same
-    indices — the write never touches another row's scale."""
-    if kv_mode == "dense":
-        return c.at[idx].set(val.astype(c.dtype))
-    from ..runtime.comm.quant import quantize_rows
-
-    payload, scales = c
-    codes, s = quantize_rows(val.astype(jnp.float32), kv_mode)
-    return (payload.at[idx].set(codes), scales.at[idx].set(s))
-
-
-def _kv_read(c, rows, kv_mode):
-    """Gather cache rows `rows` [B, L] -> [B, L, H, Dh].  Dense reads
-    come back at the cache dtype (the downstream casts mirror
-    generation._block_with_cache); quantized reads dequantize the
-    gathered rows to fp32 in-program.  The single definition lives in
-    kernels/paged.py — it doubles as the paged-attention oracle's
-    gather, which is what keeps the registry's jnp path bit-identical
-    to this program."""
-    from ..kernels.paged import kv_read
-
-    return kv_read(c, rows, kv_mode)
-
-
-def _paged_block(p, cfg, x, ck, cv, write_idx, rows, q_pos,
-                 kv_mode="dense", block_size=0):
-    """One decoder block over x [B, T, D] with paged KV.
-
-    `write_idx` [B*T] flat cache rows this chunk's K/V land in, `rows`
-    [B, L] flat cache rows the attention reads (the gathered block
-    table), `q_pos` [B, T] absolute positions of x's tokens.  Op-for-op
-    the math of generation._block_with_cache; only the cache addressing
-    differs (scatter/gather through the table instead of
-    dynamic_update_slice on a contiguous cache).  `kv_mode` picks the
-    storage codec: "dense" stores rows at the cache arrays' dtype,
-    "int8"/"int4" stores (payload, scales) pairs dequantized at the
-    gather — the surrounding math is identical either way, so parity
-    pins hold AT MATCHED kv_mode.
-    """
-    B, T, D = x.shape
-    H, Dh = cfg.num_heads, cfg.head_dim
-    h = layer_norm(x, p["ln1"], cfg.layer_norm_eps)
-    qkv = h @ p["attn"]["qkv"]["w"].astype(h.dtype) + \
-        p["attn"]["qkv"]["b"].astype(h.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    shape = lambda t: t.reshape(B, T, H, Dh)
-    q, k, v = shape(q), shape(k), shape(v)
-    ck = _kv_write(ck, write_idx, k.reshape(B * T, H, Dh), kv_mode)
-    cv = _kv_write(cv, write_idx, v.reshape(B * T, H, Dh), kv_mode)
-    # attention core through the kernel registry: the jnp oracle
-    # (kernels/paged.py paged_attention_reference) is this block's
-    # pre-registry gather/einsum/softmax chain op-for-op — wherever the
-    # oracle is chosen, serving output is bit-identical; the Pallas
-    # kernel fuses the table gather (+ quantized-KV dequant) into an
-    # online-softmax sweep over cache blocks
-    from ..kernels import registry
-
-    attn = registry.dispatch(
-        "paged_attention", q, ck, cv, rows, q_pos,
-        info={"block_size": block_size, "kv_len": rows.shape[1],
-              "q_len": T, "head_dim": Dh, "kv_mode": kv_mode},
-        kv_mode=kv_mode, block_size=block_size)
-    attn = attn.reshape(B, T, D)
-    attn = attn @ p["attn"]["proj"]["w"].astype(h.dtype) + \
-        p["attn"]["proj"]["b"].astype(h.dtype)
-    x = x + attn
-    h = layer_norm(x, p["ln2"], cfg.layer_norm_eps)
-    h = h @ p["mlp"]["fc1"]["w"].astype(h.dtype) + \
-        p["mlp"]["fc1"]["b"].astype(h.dtype)
-    h = jax.nn.gelu(h, approximate=True)
-    h = h @ p["mlp"]["fc2"]["w"].astype(h.dtype) + \
-        p["mlp"]["fc2"]["b"].astype(h.dtype)
-    return x + h, ck, cv
-
-
-def _proj_logits(cfg, params, x_rows):
-    """[B, D] hidden rows -> fp32 logits [B, V] (generation.py's head)."""
-    w = (params["wte"].T if cfg.tie_embeddings else params["lm_head"])
-    return (x_rows @ w.astype(x_rows.dtype)).astype(jnp.float32)
 
 
 # -- qwZ weight store -------------------------------------------------------
@@ -292,13 +223,9 @@ class ServeProgramBuilder:
     (outputs, caches), caches donated — the engine threads the
     returned arrays back through PagedKVCache.caches."""
 
-    def __init__(self, model: GPT, schedule: ServeSchedule):
+    def __init__(self, model, schedule: ServeSchedule):
         cfg = model.config
-        if cfg.num_experts > 1 or cfg.pipeline_stages > 1:
-            raise NotImplementedError(
-                "the serving engine supports plain dense GPT configs "
-                "(no MoE layers, no pipeline-stacked blocks) — the "
-                "generate() contract")
+        self.spec = layers.check_spec(model.layer_spec())
         if schedule.quantized not in QUANT_MODES:
             raise ValueError(
                 f"serving quantized_weights must be one of {QUANT_MODES}, "
@@ -315,8 +242,47 @@ class ServeProgramBuilder:
             raise ValueError(
                 f"serving draft_len must be >= 0, got "
                 f"{schedule.draft_len}")
+        if self.spec.attention == "eva":
+            self._check_eva(schedule)
         self.model = model
         self.schedule = schedule
+
+    def _check_eva(self, s: ServeSchedule) -> None:
+        """What the summarised-window programs need of a schedule, and
+        what is not built for them."""
+        spec = self.spec
+        if s.block_size != spec.chunk:
+            raise ValueError(
+                f"block_size must equal the model's chunk_size "
+                f"({spec.chunk}) — one exact block is one chunk, "
+                f"{spec.chunk} summary rows one summary block — got "
+                f"{s.block_size}")
+        if spec.window % s.prefill_chunk or s.prefill_chunk % spec.chunk:
+            raise ValueError(
+                f"prefill_chunk must divide the model's window_size "
+                f"({spec.window}) and hold whole chunks of {spec.chunk} "
+                f"(a prefill chunk then lies inside one window), got "
+                f"{s.prefill_chunk}")
+        if s.window_blocks != spec.window // spec.chunk:
+            raise ValueError(
+                f"schedule.window_blocks must be window_size / chunk_size "
+                f"= {spec.window // spec.chunk}, got {s.window_blocks}")
+        if s.draft_len:
+            raise NotImplementedError(
+                "draft_len > 0 over summarised windows: the verify "
+                "program is not built (a rejected draft may have "
+                "completed a chunk, and its summary row would have to be "
+                "rewound with it)")
+        if s.quantized != "none":
+            raise NotImplementedError(
+                "quantized_weights over summarised windows: the qwZ "
+                "store is written for the GPT parameter tree's matmul "
+                "leaves and is not proven on this family's")
+        if s.kv_dtype != "dense":
+            raise NotImplementedError(
+                f"kv_dtype {s.kv_dtype!r} over summarised windows: "
+                f"summary rows are pooled in float32 from the stored "
+                f"rows and have no quantized codec yet")
 
     def build(self) -> dict:
         logger.info(self.schedule.describe())
@@ -347,9 +313,9 @@ class ServeProgramBuilder:
         return dequantize_params(params, s.quantized, s.quant_block)
 
     def _build_prefill(self):
-        cfg = self.model.config
+        cfg, spec = self.model.config, self.spec
         s = self.schedule
-        C, bs, W = s.prefill_chunk, s.block_size, s.table_width
+        C, bs = s.prefill_chunk, s.block_size
 
         @partial(jax.jit, donate_argnums=(1,))
         def prefill(params, caches, tokens, pos, n_valid, table,
@@ -361,43 +327,44 @@ class ServeProgramBuilder:
             FINAL chunk (the engine ignores it otherwise)."""
             params = self._maybe_dequant(params)
             abs_pos = pos + jnp.arange(C)
-            # per-row gather, NOT dynamic_slice_in_dim(wpe, pos, C):
-            # when the final chunk's pad rows run past the wpe table,
-            # a dynamic slice CLAMPS its start backwards and shifts the
-            # VALID rows onto wrong positional embeddings (silently
-            # breaking the ==generate() contract); the gather keeps
-            # every valid row exact and only pad rows (overwritten
-            # before read / masked) see the clamped last entry
-            wpe_rows = params["wpe"][
-                jnp.clip(abs_pos, 0, params["wpe"].shape[0] - 1)]
-            x = params["wte"][tokens] + wpe_rows[None]
-            blk_i = abs_pos // bs
-            # positions past the table (pad rows of the final chunk)
-            # write to the trash block, never a neighbour's memory
-            blk = jnp.where(blk_i < W, table[jnp.clip(blk_i, 0, W - 1)], 0)
-            write_idx = blk * bs + abs_pos % bs
-            rows = _gather_rows(table, bs)[None, :]
-            q_pos = abs_pos[None, :]
+            x = layers.embed_chunk(spec, params, tokens, abs_pos)
+            addr = layers.address_chunk(spec, s, table, pos, abs_pos,
+                                        n_valid)
             new_caches = []
             for bp, (ck, cv) in zip(params["blocks"], caches):
-                x, ck, cv = _paged_block(bp, cfg, x, ck, cv, write_idx,
-                                         rows, q_pos,
+                x, ck, cv = layers.block(spec, cfg, bp, x, ck, cv, addr,
                                          kv_mode=s.kv_dtype,
                                          block_size=bs)
                 new_caches.append((ck, cv))
-            x = layer_norm(x, params["ln_f"], cfg.layer_norm_eps)
+            x = layers.final_norm(spec, params, x)
             last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
-            logits = _proj_logits(cfg, params, last[:, 0, :])  # [1, V]
+            logits = layers.logits(spec, params, last[:, 0, :])  # [1, V]
             key = _row_key(seed, pos + n_valid)
-            tok = sample_token(logits[0], temperature, top_k, key)
+            tok = sample_token(layers.sampled(spec, logits[0]), temperature,
+                               top_k, key)
             return tok, logits[0], new_caches
 
         return prefill
 
+    def step_logits(self, params, caches, tokens, positions, active,
+                    tables):
+        """One decode step up to its logits: (logits [R, V], caches).
+        `decode` is this and per-slot sampling; a test that wants the
+        logits a token was drawn from jits this itself."""
+        cfg, spec, s = self.model.config, self.spec, self.schedule
+        x = layers.embed_step(spec, params, tokens, positions)
+        addr = layers.address_step(spec, s, tables, positions, active)
+        new_caches = []
+        for bp, (ck, cv) in zip(params["blocks"], caches):
+            x, ck, cv = layers.block(spec, cfg, bp, x, ck, cv, addr,
+                                     kv_mode=s.kv_dtype,
+                                     block_size=s.block_size)
+            new_caches.append((ck, cv))
+        x = layers.final_norm(spec, params, x)
+        return layers.logits(spec, params, x[:, -1, :]), new_caches
+
     def _build_decode(self):
-        cfg = self.model.config
-        s = self.schedule
-        bs = s.block_size
+        spec = self.spec
 
         @partial(jax.jit, donate_argnums=(1,))
         def decode(params, caches, tokens, positions, active, tables,
@@ -409,28 +376,11 @@ class ServeProgramBuilder:
             outputs are discarded by the engine — all slot math is
             row-wise, THE batching-invariance contract."""
             params = self._maybe_dequant(params)
-            R = tokens.shape[0]
-            x = (params["wte"][tokens] +
-                 params["wpe"][positions])[:, None, :]       # [R, 1, D]
-            blk_i = positions // bs
-            blk = jnp.take_along_axis(
-                tables, jnp.clip(blk_i, 0, s.table_width - 1)[:, None],
-                axis=1)[:, 0]
-            write_idx = jnp.where(active, blk * bs + positions % bs, 0)
-            rows = rows_for_tables(tables, bs)
-            q_pos = positions[:, None]
-            new_caches = []
-            for bp, (ck, cv) in zip(params["blocks"], caches):
-                x, ck, cv = _paged_block(bp, cfg, x, ck, cv, write_idx,
-                                         rows, q_pos,
-                                         kv_mode=s.kv_dtype,
-                                         block_size=bs)
-                new_caches.append((ck, cv))
-            x = layer_norm(x, params["ln_f"], cfg.layer_norm_eps)
-            logits = _proj_logits(cfg, params, x[:, -1, :])  # [R, V]
+            logits, new_caches = self.step_logits(
+                params, caches, tokens, positions, active, tables)
             keys = jax.vmap(_row_key)(seeds, positions + 1)
-            toks = jax.vmap(sample_token)(logits, temperatures, top_ks,
-                                          keys)
+            toks = jax.vmap(sample_token)(layers.sampled(spec, logits),
+                                          temperatures, top_ks, keys)
             return toks, new_caches
 
         return decode
@@ -449,9 +399,9 @@ class ServeProgramBuilder:
         rows need no undo: the engine simply rewinds its position and
         the stale rows are re-written (same scatter indices) before
         any later query's causal mask can reach them."""
-        cfg = self.model.config
+        cfg, spec = self.model.config, self.spec
         s = self.schedule
-        bs, W = s.block_size, s.table_width
+        bs = s.block_size
         T = int(s.draft_len) + 1
 
         @partial(jax.jit, donate_argnums=(1,))
@@ -467,34 +417,20 @@ class ServeProgramBuilder:
             params = self._maybe_dequant(params)
             R = tokens.shape[0]
             abs_pos = positions[:, None] + jnp.arange(T)[None, :]
-            # per-row gather with a clip, the prefill rule: pad rows
-            # past the wpe table clamp (their writes land in trash and
-            # their samples are discarded by the engine)
-            wpe_rows = params["wpe"][
-                jnp.clip(abs_pos, 0, params["wpe"].shape[0] - 1)]
-            x = params["wte"][tokens] + wpe_rows          # [R, T, D]
-            blk_i = abs_pos // bs
-            valid = (active[:, None] &
-                     (jnp.arange(T)[None, :] <= n_draft[:, None]) &
-                     (blk_i < W))
-            blk = jnp.take_along_axis(tables,
-                                      jnp.clip(blk_i, 0, W - 1), axis=1)
-            # rows past a slot's drafts (and inactive slots) write to
-            # the trash block, the decode convention
-            write_idx = jnp.where(valid, blk * bs + abs_pos % bs,
-                                  0).reshape(R * T)
-            rows = rows_for_tables(tables, bs)
-            q_pos = abs_pos
+            # pad rows past the position table clamp (their writes land
+            # in trash and their samples are discarded by the engine)
+            x = layers.embed_chunk(spec, params, tokens, abs_pos)  # [R, T, D]
+            addr = layers.address_grid(spec, s, tables, abs_pos, active,
+                                       n_draft)
             new_caches = []
             for bp, (ck, cv) in zip(params["blocks"], caches):
-                x, ck, cv = _paged_block(bp, cfg, x, ck, cv, write_idx,
-                                         rows, q_pos,
+                x, ck, cv = layers.block(spec, cfg, bp, x, ck, cv, addr,
                                          kv_mode=s.kv_dtype,
                                          block_size=bs)
                 new_caches.append((ck, cv))
-            x = layer_norm(x, params["ln_f"], cfg.layer_norm_eps)
-            logits = _proj_logits(
-                cfg, params,
+            x = layers.final_norm(spec, params, x)
+            logits = layers.logits(
+                spec, params,
                 x.reshape(R * T, -1)).reshape(R, T, -1)   # [R, T, V]
             keys = jax.vmap(jax.vmap(_row_key, in_axes=(None, 0)))(
                 seeds, abs_pos + 1)

@@ -10,7 +10,13 @@ The scheduling loop the engine drives once per `step()`:
    chain hash is already registered are aliased (one refcount, zero
    fresh blocks) and a request arriving with a live session pin adopts
    the pin's blocks outright — admission charges only what is actually
-   new.  Prefill then starts at the first non-cached position.  The
+   new.  Prefill then starts at the first non-cached position.  Over a
+   windowed cache (`kv.windowed`: exact rows for the open window,
+   summary rows behind it) the budget is the request's BOUNDED
+   footprint — at most a window of exact blocks plus its summary
+   blocks, `kv.blocks_needed` — and admission books it (`kv.reserve`)
+   without taking a block: the engine takes blocks as positions are
+   written and gives a window's blocks back when it closes.  The
    `draft_len` tail matters under speculative decoding: a verify step
    writes up to `draft_len` candidate K/V rows PAST the committed
    length, and without the reservation those rows would spill into the
@@ -35,7 +41,8 @@ The scheduling loop the engine drives once per `step()`:
 
 3. **decode** — every RUNNING slot advances one token.
 
-Requests own their block table for their whole life; finishing
+Requests own their block table for their whole life (over a windowed
+cache its entries change as windows open and close); finishing
 (naturally or shed) drops their references immediately — a block a
 finished request shared with a live holder survives, its private
 blocks return to the pool (registered ones park in the prefix LRU).
@@ -147,8 +154,7 @@ class Scheduler:
                 f"request needs {needed} KV blocks > table width "
                 f"{self.kv.table_width}: prompt {len(req.prompt)} + "
                 f"max_new {req.max_new_tokens} exceeds the engine's "
-                f"{self.kv.table_width * self.kv.block_size}-token "
-                f"per-request capacity")
+                f"{self.kv.token_capacity}-token per-request capacity")
         reserved = self.blocks_reserved(req)
         if reserved > self.kv.capacity_blocks:
             raise ValueError(
@@ -171,7 +177,7 @@ class Scheduler:
         TABLE budget; the prefix cache discounts what admission
         actually charges against the pool."""
         tokens = min(len(req.prompt) + req.max_new_tokens + self.draft_len,
-                     self.kv.table_width * self.kv.block_size)
+                     self.kv.token_capacity)
         return self.kv.blocks_needed(tokens)
 
     # -- engine-thread scheduling -------------------------------------
@@ -182,6 +188,8 @@ class Scheduler:
         block table or None; on success the request's cached offsets
         and registration hashes are set."""
         needed = self.blocks_reserved(req)
+        if self.kv.windowed:
+            return self.kv.reserve(req.rid, needed)
         pin = None
         if req.session_id is not None and self.session_lookup is not None:
             pin = self.session_lookup(req)

@@ -45,6 +45,24 @@ def test_serve_phase_toy():
     assert out["token_agreement_share"] == 1.0  # fp32 on CPU: exact
 
 
+def test_evabyte_phase_toy():
+    model = dict(num_layers=2, num_heads=4, d_model=64, d_ff=176,
+                 window_size=32, chunk_size=4, attn_out_std=0.3)
+    serve = dict(block_size=4, max_batch=3, max_seq_len=128,
+                 prefill_chunk=8, num_blocks=1 + 3 * (8 + 8),
+                 prefix_cache=False)
+    out = chip_smoke.evabyte_phase(
+        model=model, serve=serve, prompt_lens=(70, 64, 9), max_new=8,
+        gap=1e-4, param_dtype=jax.numpy.float32)
+    assert out["window_closes"] == 2 + 2 + 0
+    assert out["worst_gap_to_top_logit"] <= 1e-4
+    assert out["top1_agreement"] == 1.0  # fp32 on CPU: exact
+    with pytest.raises(RuntimeError, match="below the reference"):
+        chip_smoke.evabyte_phase(
+            model=model, serve=serve, prompt_lens=(70,), max_new=8,
+            gap=-1.0, param_dtype=jax.numpy.float32)
+
+
 def test_kernels_phase_toy():
     out = chip_smoke.kernels_phase(batch=1, seq=256, heads=2, head_dim=64,
                                    d_model=64, vocab=1024, paged_heads=2,

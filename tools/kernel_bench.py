@@ -10,7 +10,7 @@ each running BOTH sides of the registry's contract on identical inputs:
   paged_attention     fused block-table gather + online-softmax decode
                       attention over a paged KV pool — dense, int8 and
                       int4 storage (the quantized dequant fused into
-                      the gather) vs `_paged_block`'s jnp expression
+                      the gather) vs `_paged_attend`'s jnp expression
   quant_codec         blockwise int8/int4 quantize + dequantize vs
                       runtime/comm/quant.py (BIT-exact, both wires)
   moe_dispatch        sort-based dispatch (BIT-exact permutation) and
