@@ -230,10 +230,17 @@ SERVE = dict(block_size=16, max_batch=16, max_seq_len=1024,
              prefill_chunk=256, num_blocks=1 + 16 * (512 // 16))
 PROMPT_LENS = (12, 37, 64, 100, 150, 200, 300, 420)  # 300, 420 > chunk
 MAX_NEW = 16
+# bf16 weights and rows move a logit by 0.01-0.03 and the largest two lie
+# 0.17 apart on average (PERF.md, PR 24), so two compiled programs may
+# break a near tie differently: where the served first token is not
+# generate()'s, the model's own logit for it must lie this close to its
+# largest (the benchmark cell's `logit_margin`)
+FIRST_TOKEN_MARGIN = 0.1
 
 
 def serve_phase(size=MODEL_SIZE, depth=SERVE_DEPTH, seq=SEQ, serve=None,
-                prompt_lens=PROMPT_LENS, max_new=MAX_NEW, **over) -> dict:
+                prompt_lens=PROMPT_LENS, max_new=MAX_NEW,
+                margin=FIRST_TOKEN_MARGIN, **over) -> dict:
     import jax
     import jax.numpy as jnp
 
@@ -285,9 +292,18 @@ def serve_phase(size=MODEL_SIZE, depth=SERVE_DEPTH, seq=SEQ, serve=None,
     first_ok = [r.out[0] == w[0] for r, w in zip(reqs, want)]
     agree = sum(a == b for r, w in zip(reqs, want)
                 for a, b in zip(r.out, w))
-    if not all(first_ok):
+    # a first token that differs may only be the other side of a near tie
+    first_gap = [0.0] * len(reqs)
+    for i, (r, p) in enumerate(zip(reqs, prompts)):
+        if not first_ok[i]:
+            last = model.apply(params, np.asarray([p], np.int32))[0, -1]
+            last = np.asarray(last, np.float32)
+            first_gap[i] = float(last.max() - last[r.out[0]])
+    if max(first_gap) > margin:
         raise RuntimeError(
-            f"first generated token differs from generate(): {first_ok}")
+            f"first generated token differs from generate() beyond a "
+            f"near tie (margin {margin}): {first_ok}, the model's logit "
+            f"for it below its largest by {first_gap}")
     return {"phase": "serve", "d_model": cfg.d_model,
             "heads": cfg.num_heads, "depth": depth, "depth_published": 48,
             "weights": str(jnp.dtype(cfg.param_dtype)),
@@ -297,6 +313,7 @@ def serve_phase(size=MODEL_SIZE, depth=SERVE_DEPTH, seq=SEQ, serve=None,
             "joined_while_decoding": joined_while_decoding,
             "engine_steps": engine.steps,
             "first_token_equals_generate": first_ok,
+            "first_token_gap_to_top_logit": first_gap,
             "token_agreement_share": agree / (len(reqs) * max_new),
             "serve_seconds_smoke": round(serve_s, 2),
             "oracle_seconds_smoke": round(oracle_s, 2)}
@@ -406,8 +423,9 @@ def _close(name, shape, got, want, rtol, atol) -> dict:
 
 
 def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
-                  d_model=1600, vocab=50304, paged_heads=16,
-                  paged_head_dim=128, paged_slots=16, paged_width=64,
+                  d_model=1600, vocab=50304,
+                  paged_shapes=((25, 64), (16, 128)),
+                  paged_slots=16, paged_width=64,
                   bert_batch=16, bert_seq=512, bert_heads=16,
                   on_chip=True) -> dict:
     """Each kernel `auto` selects on this chip, once, natively, at the
@@ -425,7 +443,7 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
         _keep_mask, derive_seed, flash_attention)
     from deepspeed_tpu.ops.transformer.fused_xent import \
         fused_softmax_xent_sum
-    from deepspeed_tpu.serving.kv_cache import rows_for_tables
+    from deepspeed_tpu.serving.kv_cache import pool_rows
 
     if on_chip and pallas_backend.interpret():
         raise RuntimeError("kernels would run under the Pallas interpreter")
@@ -516,34 +534,45 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
         out.append(_close("fused_xent_bwd", [n, d_model, vocab],
                           got[1], want[1], rtol=5e-4, atol=1e-6))
 
-        # paged attention, dense KV: no in-tree model has head_dim 128,
-        # so `auto` never picks it for GPT-2; shape from the registry's rule
+        # paged attention, dense KV, one decode step: GPT-2 xl's 25
+        # heads of 64 (a pool row of 1,600 lanes in 1,664) and 16 of 128
+        # (whole tiles), slots of every length from idle to the whole
+        # table, dead table entries at the trash block
         bs, nblocks = 16, 1 + paged_slots * paged_width
-        cache = (nblocks * bs, paged_heads, paged_head_dim)
-        ck = jax.random.normal(key[6], cache, jnp.float32)
-        cv = jax.random.normal(key[7], cache, jnp.float32)
         rs = np.random.RandomState(SEED)
-        tables = jnp.asarray(rs.randint(0, nblocks,
-                                        (paged_slots, paged_width)), jnp.int32)
-        rows = rows_for_tables(tables, bs)
-        pq = jax.random.normal(key[0], (paged_slots, 1, paged_heads,
-                                        paged_head_dim), jnp.float32)
-        q_pos = jnp.asarray(rs.randint(0, paged_width * bs,
-                                       (paged_slots, 1)), jnp.int32)
-        info = {"block_size": bs, "kv_len": paged_width * bs, "q_len": 1,
-                "head_dim": paged_head_dim, "kv_mode": "dense"}
-        chosen = registry.resolve_impl("paged_attention", info=info)
-        if on_chip and chosen != "pallas":
-            raise RuntimeError(
-                f"auto resolved paged attention to {chosen!r} on this chip")
-        got = jax.jit(lambda *a: registry.dispatch(
-            "paged_attention", *a, info=info, kv_mode="dense",
-            block_size=bs))(pq, ck, cv, rows, q_pos)
-        want = jax.jit(lambda *a: paged_attention_reference(
-            *a, kv_mode="dense", block_size=bs))(pq, ck, cv, rows, q_pos)
-        out.append(_close("paged_attention_dense",
-                          [paged_slots, paged_width * bs, paged_heads,
-                           paged_head_dim], got, want, rtol=0, atol=2e-6))
+        held = rs.randint(0, paged_width * bs + 1, (paged_slots,))
+        edge = [0, 1, paged_width * bs][:paged_slots]
+        held[:len(edge)] = edge
+        tables = rs.randint(1, nblocks, (paged_slots, paged_width))
+        tables[np.arange(paged_width)[None, :]
+               >= -(-held // bs)[:, None]] = 0
+        tables = jnp.asarray(tables, jnp.int32)
+        q_pos = jnp.asarray(held[:, None] - 1, jnp.int32)
+        for n, (p_heads, p_dim) in enumerate(paged_shapes):
+            cache = (nblocks * bs, p_heads, p_dim)
+            ck = pool_rows(jax.random.normal(key[6], cache, jnp.float32))
+            cv = pool_rows(jax.random.normal(key[7], cache, jnp.float32))
+            pq = jax.random.normal(key[n], (paged_slots, 1, p_heads, p_dim),
+                                   jnp.float32)
+            info = {"block_size": bs, "table_width": paged_width,
+                    "q_len": 1, "num_heads": p_heads, "head_dim": p_dim,
+                    "kv_mode": "dense", "kv_itemsize": 4}
+            chosen = registry.resolve_impl("paged_attention", info=info)
+            if on_chip and chosen != "pallas":
+                raise RuntimeError(
+                    f"auto resolved paged attention at {p_heads} heads of "
+                    f"{p_dim} to {chosen!r} on this chip")
+            got = jax.jit(lambda *a: registry.dispatch(
+                "paged_attention", *a, info=info, kv_mode="dense",
+                block_size=bs))(pq, ck, cv, tables, q_pos)
+            want = jax.jit(lambda *a: paged_attention_reference(
+                *a, kv_mode="dense", block_size=bs))(pq, ck, cv, tables,
+                                                     q_pos)
+            live = held > 0    # an idle slot's output is discarded
+            out.append(_close(f"paged_attention_dense_H{p_heads}_Dh{p_dim}",
+                              [paged_slots, paged_width * bs, p_heads,
+                               p_dim], got[live], want[live],
+                              rtol=0, atol=2e-6))
     return {"phase": "kernels", "native": not pallas_backend.interpret(),
             "kernels": out}
 

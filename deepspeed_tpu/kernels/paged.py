@@ -1,40 +1,53 @@
-"""Pallas decode-path paged attention (op 1): fused block-table gather
-+ online-softmax attention over the PagedKVCache, with the PR-15
-quantized-KV dequant fused into the gather.
+"""Pallas decode-path paged attention (op 1): a walk of each slot's
+block table over the pool as it lies, with the quantized-KV dequant
+done on the fetched tile.
 
-The jnp oracle (`paged_attention_reference`) is the EXACT expression
-serving/layers.py's `_paged_attend` always ran — gather the table's
-rows, dequantize if the cache is quantized, one fp32 einsum/softmax/
-einsum chain under the `q_pos >= k_idx` mask.  Wherever the registry
-picks the oracle (all of tier-1 on CPU) serving output stays
-bit-identical to the pre-registry code, which is what keeps the
-serving-vs-generate pins green.
+The pool (serving/kv_cache.py) keeps a layer's K and V as
+`[num_blocks * block_size, pool_width(H, Dh)]`: one cache row is one
+row of the array (`H * Dh` lanes, padded to whole 128-lane tiles), so a
+block is `block_size` consecutive rows — one contiguous slab of whole
+tiles, which the decode scatter writes in place and which both
+implementations here read in place.
 
-The kernel removes the materialised `[B, L, H, Dh]` gather: each
-(slot·head) program walks the slot's block table a cache block at a
-time — the table rides scalar prefetch, so the BlockSpec index map
-turns each step into a direct async copy of ONE `[block_size, Dh]`
-cache tile into VMEM (the fused gather), streamed through the same
-online-softmax accumulator as ops/transformer/flash_attention.py.  For
-quantized caches the tile arrives as (codes, scales) and dequantizes
-in-register — int4 nibble decode included — so the HBM read is the
-COMPRESSED cache, the whole point of quantized KV.
+The jnp oracle (`paged_attention_reference`) is the expression
+serving/layers.py's `_paged_attend` ran before there was a kernel:
+gather the rows of every table entry, dequantize if the cache is
+quantized, one fp32 einsum/softmax/einsum chain under the
+`q_pos >= k_idx` mask.  Its cost is the table's whole width whatever a
+slot holds; it stays for prefill (one request's rows), for every
+backend but the TPU (all of tier-1 on CPU, where serving output stays
+bit-identical to `generate()`), and as the kernel's correctness
+contract.
 
-Parity: tolerance-bounded (online-softmax tiling vs one fused softmax),
-the attention-op contract.  Trash/garbage blocks beyond a slot's length
-are killed by the mask in both impls: the oracle's softmax underflows
-their NEG_INF scores to exactly 0, the kernel zeroes fully-masked
-tiles explicitly (`p = where(s <= NEG_INF/2, 0, p)` — the
-flash_attention bias-path guard, since a tile past the horizon has no
-live key to anchor the running max).
+The kernel (`paged_attention_pallas`, `q_len` <= 8: decode and verify)
+runs one program per slot.  The block table and the query positions
+ride scalar prefetch; a slot's length is its last query position + 1,
+and a slot whose positions are negative is idle.  The pool stays in
+HBM: the program copies the slot's LIVE blocks, a tile of several
+blocks at a time, into a double-buffered VMEM tile (one async copy a
+block, the next tile's in flight while this one is multiplied), in a
+loop that ends at the slot's last live block — an idle slot and the
+dead tail of a short one cost no copy and no loop step.
 
-TPU-native layout: caches are viewed as `[rows, H * width]` (a free
-reshape) so each gathered tile is a `(block_size, width)` block —
-one the chip's compiler tiles only when `width % 128 == 0`, so the
-registry refuses head_dim 64 (every GPT-2 size) and int4 below
-head_dim 256 by name, and gates on small T (the q rows unroll over
-scalar-prefetched positions).  Scales ride the block's whole
-`(block_size, H)` tile and the head's column is selected in-kernel.
+All heads share a tile without a reshape: the slot's queries arrive
+block-diagonal, `[T * Hp, H * Dh]` with row (t, h) holding head h's
+`Dh` values in that head's lanes and zeros elsewhere (built outside,
+`Hp` = H rounded up to the sublane tile), so `Qbd @ tile^T` is every
+head's scores `[T * Hp, rows]` in one MXU product — the zero lanes
+contribute exactly 0.  Online softmax along the tile's rows in fp32 (the
+flash_attention accumulator), probabilities cast to the cache dtype,
+`p @ Vtile` accumulated in fp32 as `[T * Hp, H * Dh]`; at the end row
+(t, h) keeps only head h's lanes and the rows of one t sum to the
+output row.  Operands enter the MXU at the cache's dtype, every sum is
+fp32: only the order of sums and the online form of the softmax differ
+from the oracle.
+
+Parity: tolerance-bounded, the attention-op contract.  Rows past a
+slot's length are never fetched; rows of its last block past the query
+are masked (`p = where(mask, ., 0)`), and the V tile is zeroed when a
+program starts so that a masked row multiplies a finite number.  An
+idle slot's output is zeros (the oracle's is the mean of the trash
+block's values; the engine discards both).
 """
 
 from __future__ import annotations
@@ -48,40 +61,47 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..ops import pallas_backend
 from ..models.generation import NEG_INF
+from ..serving.kv_cache import rows_for_tables
+
+# what a program's double-buffered K and V tiles may take of VMEM, and
+# what its accumulator and query block may (the chip's scoped default
+# is 16 MiB; the compiler's own temporaries need the rest)
+_TILE_BYTES = 6 << 20
+_ACC_BYTES = 6 << 20
+# rows a tile aims for: the scores of a tile are `[T * Hp, rows]`, so a
+# multiple of the 128 lanes keeps them dense
+_TILE_ROWS = 256
 
 
-def _clamp(i):
-    return jnp.maximum(i, 0)
-
-
-def _params():
-    return pltpu.CompilerParams(
-        dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY))
-
-
-def kv_read(c, rows, kv_mode: str = "dense"):
+def kv_read(c, rows, num_heads: int, head_dim: int,
+            kv_mode: str = "dense"):
     """Gather cache rows `rows` [B, L] -> [B, L, H, Dh].  Dense reads
     come back at the cache dtype; quantized caches ((payload, scales)
-    pairs) dequantize the gathered rows to fp32.  THE gather the oracle
-    and serving/programs.py share."""
+    pairs) dequantize the gathered rows to fp32."""
+    B, L = rows.shape
     if kv_mode == "dense":
-        return c[rows]
+        return c[rows][..., :num_heads * head_dim].reshape(
+            B, L, num_heads, head_dim)
     from ..runtime.comm.quant import dequantize_rows
 
     payload, scales = c
-    return dequantize_rows(payload[rows], scales[rows], kv_mode)
+    w = head_dim if kv_mode == "int8" else head_dim // 2
+    return dequantize_rows(
+        payload[rows][..., :num_heads * w].reshape(B, L, num_heads, w),
+        scales[rows], kv_mode)
 
 
-def paged_attention_reference(q, ck, cv, rows, q_pos, *,
+def paged_attention_reference(q, ck, cv, tables, q_pos, *,
                               kv_mode: str = "dense",
-                              block_size: int = 0):
-    """The `_paged_attend` attention core (serving/layers.py), verbatim: q [B, T, H, Dh],
-    caches addressed by flat rows [B, L], q_pos [B, T] absolute
-    positions -> attn [B, T, H, Dh] (at the cache/dequant dtype)."""
-    del block_size  # kernel tiling knob; the gather needs only rows
-    Dh = q.shape[-1]
-    keys = kv_read(ck, rows, kv_mode)      # [B, L, H, Dh]
-    vals = kv_read(cv, rows, kv_mode)
+                              block_size: int):
+    """The `_paged_attend` attention core: q [B, T, H, Dh], the pool
+    `[rows, pool_width]` addressed through block tables [B, W], q_pos [B, T]
+    absolute positions -> attn [B, T, H, Dh] (at the cache/dequant
+    dtype)."""
+    H, Dh = q.shape[2:]
+    rows = rows_for_tables(tables, block_size)
+    keys = kv_read(ck, rows, H, Dh, kv_mode)      # [B, L, H, Dh]
+    vals = kv_read(cv, rows, H, Dh, kv_mode)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         keys.astype(jnp.float32)) * (Dh ** -0.5)
     L = rows.shape[1]
@@ -97,8 +117,30 @@ def paged_attention_reference(q, ck, cv, rows, q_pos, *,
 # ---------------------------------------------------------------------------
 
 
-def _decode_nibbles(raw, width, full):
-    """uint8 [rows, width] -> int8 codes [rows, full] (quant.py's
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def score_rows(q_len: int, num_heads: int) -> int:
+    """Rows of a slot's scores: every (query, head) pair, the heads of
+    one query padded to whole sublane tiles at any operand dtype."""
+    return q_len * _round_up(num_heads, 16)
+
+
+def tile_blocks(block_size: int, table_width: int, row_bytes: int,
+                q_len: int, num_heads: int, width: int) -> int:
+    """Blocks a tile holds: about `_TILE_ROWS` rows, no more than the
+    table has, and K and V double-buffered inside `_TILE_BYTES`.
+    0: the shapes do not fit VMEM — not even one block does, or the
+    fp32 accumulator `[score_rows, width]` with the query block."""
+    if score_rows(q_len, num_heads) * width * (4 + 2 * 4) > _ACC_BYTES:
+        return 0
+    fit = _TILE_BYTES // (4 * block_size * row_bytes)
+    return min(max(1, _TILE_ROWS // block_size), table_width, fit)
+
+
+def _decode_nibbles(raw, full):
+    """uint8 [rows, full // 2] -> int8 codes [rows, full] (quant.py's
     low-nibble-first two's-complement decode)."""
     lo = (raw & jnp.uint8(0x0F)).astype(jnp.int8)
     hi = ((raw >> 4) & jnp.uint8(0x0F)).astype(jnp.int8)
@@ -121,153 +163,217 @@ def _f16_bits_to_f32(bits):
     return jnp.where(exp == 0, sub, normal)
 
 
-def _tile_kv(ref, s_ref, head, kv_mode, Dh, marker):
-    """One gathered cache tile -> fp32 [block_size, Dh], dequantized
-    in-register for quantized caches (the fused dequant).  The scales
-    arrive as the block's full `(block_size, H)` tile — a one-column
-    block is not one the chip's compiler tiles — and this head's column
-    is picked by a masked lane reduction."""
-    raw = ref[...]
+def _head_lanes(rows: int, H: int, Dh: int, width: int):
+    """bool [rows, width]: lane belongs to head (row index); rows past
+    H and lanes past H * Dh belong to none."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+    return (lane >= head * Dh) & (lane < (head + 1) * Dh) & (head < H)
+
+
+def _tile_kv(buf, sbuf, slot, kv_mode, H, Dh, marker):
+    """The fetched tile `[rows, H * Dh]` as the MXU takes it: dense as
+    it lies; quantized dequantized here — codes times the (row, head)
+    scale spread over the head's lanes — to fp32."""
+    raw = buf[slot].reshape(-1, buf.shape[-1])
     if kv_mode == "dense":
-        return raw.astype(jnp.float32)
+        return raw
     if kv_mode == "int4":
-        codes = _decode_nibbles(raw, raw.shape[-1], Dh)
+        codes = _decode_nibbles(raw[:, :H * Dh // 2], H * Dh)
     else:
-        codes = raw.astype(jnp.int8)
-    scales = _f16_bits_to_f32(s_ref[...])                    # (bs, H)
-    lane = jax.lax.broadcasted_iota(jnp.int32, scales.shape, 1)
-    scale = jnp.sum(jnp.where(lane == head, scales, 0.0), axis=1,
-                    keepdims=True)                           # (bs, 1)
+        codes = raw[:, :H * Dh].astype(jnp.int8)
+    scales = _f16_bits_to_f32(sbuf[slot].reshape(-1, H))     # (rows, H)
+    spread = jnp.dot(scales,
+                     _head_lanes(H, H, Dh, H * Dh).astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
     # compared as fp32: the chip has no int8 vector comparison
     codes = codes.astype(jnp.float32)
-    return jnp.where(codes == marker, jnp.float32(jnp.nan), codes * scale)
+    return jnp.where(codes == marker, jnp.float32(jnp.nan), codes * spread)
 
 
-def _paged_kernel(tbl, qp, q_ref, *rest, scale, bs, W, H, T, Dh,
-                  kv_mode, marker):
+def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
+                 kv_mode, marker):
     if kv_mode == "dense":
-        k_ref, v_ref, o_ref, acc, m_s, l_s = rest
-        ks_ref = vs_ref = None
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc, m_s, l_s = rest
+        pairs = ((k_hbm, kbuf), (v_hbm, vbuf))
+        ksbuf = vsbuf = None
     else:
-        k_ref, ks_ref, v_ref, vs_ref, o_ref, acc, m_s, l_s = rest
-    bh = pl.program_id(0)
-    a = pl.program_id(1)
-    r = jax.lax.div(bh, H)
-    head = jax.lax.rem(bh, H)
+        (k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref,
+         kbuf, ksbuf, vbuf, vsbuf, sem, acc, m_s, l_s) = rest
+        pairs = ((k_hbm, kbuf), (v_hbm, vbuf),
+                 (ks_hbm, ksbuf), (vs_hbm, vsbuf))
+    b = pl.program_id(0)
+    C, TK = T * Hp, KB * bs
+    # T is tiny (1 decode, draft + 1 verify): the scalar position reads
+    # unroll.  The slot's length is its last query's position + 1
+    last = qp[b, 0]
+    for t in range(1, T):
+        last = jnp.maximum(last, qp[b, t])
+    n_blocks = jnp.clip((last + bs) // bs, 0, W)
+    n_tiles = (n_blocks + KB - 1) // KB
 
-    @pl.when(a == 0)
-    def _init():
+    def tile_copies(tile, slot, go):
+        """`go` (start or wait) every live block of `tile`: one copy a
+        block and array, a block being `bs` whole rows of the pool."""
+        first = tile * KB
+
+        def one(blk, carry):
+            for n, (src, dst) in enumerate(pairs):
+                go(pltpu.make_async_copy(
+                    src.at[tbl[b, blk]], dst.at[slot, blk - first],
+                    sem.at[n, slot]))
+            return carry
+
+        jax.lax.fori_loop(first, jnp.minimum(first + KB, n_blocks), one, 0)
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_tiles > 0)
+    def _walk():
         acc[...] = jnp.zeros_like(acc)
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
+        # a masked row's probability is 0 and must meet a finite value
+        vbuf[...] = jnp.zeros_like(vbuf)
+        if vsbuf is not None:
+            vsbuf[...] = jnp.zeros_like(vsbuf)
+        tile_copies(0, 0, lambda cp: cp.start())
+        row = jax.lax.broadcasted_iota(jnp.int32, (C, TK), 0)
+        qrow = jnp.full((C, TK), -1, jnp.int32)
+        for t in range(T):
+            qrow = jnp.where((row >= t * Hp) & (row < (t + 1) * Hp),
+                             qp[b, t], qrow)
+        q = q_ref[0]                                       # (C, H * Dh)
 
-    q = q_ref[0].astype(jnp.float32) * scale          # (T, Dh)
-    k = _tile_kv(k_ref, ks_ref, head, kv_mode, Dh, marker)  # (bs, Dh)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    kidx = a * bs + jax.lax.broadcasted_iota(jnp.int32, (T, bs), 1)
-    # T is tiny (1 decode, draft+1 verify): unroll the scalar position
-    # reads instead of carrying a [T]-shaped operand through VMEM
-    qpos = jnp.stack([qp[r, t] for t in range(T)])
-    s = jnp.where(qpos[:, None] >= kidx, s, NEG_INF)
+        def body(i, carry):
+            slot = jax.lax.rem(i, 2)
 
-    m_prev = m_s[:, :1]
-    l_prev = l_s[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    # a tile fully past the causal horizon leaves m_new at NEG_INF and
-    # exp(s - m_new) = 1 everywhere — zero it (flash_attention's guard)
-    p = jnp.where(s <= NEG_INF * 0.5, 0.0, p)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    v = _tile_kv(v_ref, vs_ref, head, kv_mode, Dh, marker)
-    acc[...] = acc[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_s[:, :1] = m_new
-    l_s[:, :1] = l_new
+            @pl.when(i + 1 < n_tiles)
+            def _():
+                tile_copies(i + 1, 1 - slot, lambda cp: cp.start())
 
-    @pl.when(a == W - 1)
-    def _finish():
-        l = l_s[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc[...] / safe_l).astype(o_ref.dtype)
+            tile_copies(i, slot, lambda cp: cp.wait())
+            k = _tile_kv(kbuf, ksbuf, slot, kv_mode, H, Dh, marker)
+            dt = jnp.promote_types(q.dtype, k.dtype)
+            s = jax.lax.dot_general(
+                q.astype(dt), k.astype(dt), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (C, TK)
+            kidx = i * TK + jax.lax.broadcasted_iota(jnp.int32, (C, TK), 1)
+            mask = qrow >= kidx
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_s[:, :1]
+            l_prev = l_s[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            v = _tile_kv(vbuf, vsbuf, slot, kv_mode, H, Dh, marker)
+            acc[...] = acc[...] * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
+            l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, body, 0)
+        # row (t, h) keeps head h's lanes; the rows of one t make the
+        # output row
+        own = _head_lanes(Hp, H, Dh, acc.shape[-1])
+        for t in range(T):
+            rows = slice(t * Hp, (t + 1) * Hp)
+            l = l_s[rows, :1]
+            out = acc[rows, :] / jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, t:t + 1, :] = jnp.sum(
+                jnp.where(own, out, 0.0), axis=0,
+                keepdims=True).astype(o_ref.dtype)
 
 
-def paged_attention_pallas(q, ck, cv, rows, q_pos, *,
+def _block_diagonal(q, Hp: int, width: int):
+    """q [B, T, H, Dh] -> [B, T * Hp, width]: row (t, h) holds head
+    h's values in head h's lanes, zeros elsewhere (in the rows that pad
+    H up to Hp and the lanes that pad H * Dh up to the pool's width)."""
+    B, T, H, Dh = q.shape
+    flat = jnp.pad(q.reshape(B, T, 1, H * Dh),
+                   ((0, 0),) * 3 + ((0, width - H * Dh),))
+    own = (jnp.arange(width)[None, :] // Dh) == jnp.arange(Hp)[:, None]
+    return jnp.where(own[None, None], flat, 0).reshape(B, T * Hp, width)
+
+
+def paged_attention_pallas(q, ck, cv, tables, q_pos, *,
                            kv_mode: str = "dense", block_size: int):
     """Drop-in for `paged_attention_reference` (tolerance parity)."""
+    return _walk(q, ck, cv, tables, q_pos, kv_mode=kv_mode,
+                 block_size=int(block_size),
+                 interpret=pallas_backend.interpret())
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("kv_mode", "block_size", "interpret"))
+def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret):
+    """The call, as a function of its own: a program that makes it in
+    every layer traces and lowers the kernel once and calls it."""
     B, T, H, Dh = q.shape
-    L = rows.shape[1]
-    bs = int(block_size)
-    if bs <= 0 or L % bs:
-        raise ValueError(
-            f"paged attention kernel needs rows ([{B}, {L}]) to cover "
-            f"whole cache blocks of {bs}")
-    W = L // bs
-    # the gathered rows ARE table walks (programs.py builds them as
-    # table*bs + arange(bs)); recover the table for scalar prefetch
-    tables = (rows[:, ::bs] // bs).astype(jnp.int32)
-    qp = q_pos.astype(jnp.int32)
+    W = tables.shape[1]
+    bs = block_size
+    HD = H * Dh
+    C = score_rows(T, H)
+    Hp = C // T
 
     if kv_mode == "dense":
         marker = 0
         out_dtype = ck.dtype
-        width = Dh
-
-        def views(c):
-            return (c.reshape(c.shape[0], H * Dh),)
-
-        kv_specs = [
-            pl.BlockSpec((bs, width),
-                         lambda b, a, t, s: (_clamp(t[b // H, a]),
-                                             jax.lax.rem(b, H))),
-        ]
-        operands = [*views(ck), *views(cv)]
-        kv_specs = kv_specs * 2
+        operands = [ck, cv]
+        width = ck.shape[1]  # H * Dh and the lanes that pad a pool row
     else:
         from ..runtime.comm.quant import qmax
 
         marker = -qmax(kv_mode) - 1
         out_dtype = jnp.float32
-        pk, sk = ck
-        pv, sv = cv
-        width = pk.shape[-1]  # Dh (int8) or Dh // 2 (int4 nibbles)
+        (pk, sk), (pv, sv) = ck, cv
 
-        payload_spec = pl.BlockSpec(
-            (bs, width), lambda b, a, t, s: (_clamp(t[b // H, a]),
-                                             jax.lax.rem(b, H)))
-        scale_spec = pl.BlockSpec(
-            (bs, H), lambda b, a, t, s: (_clamp(t[b // H, a]), 0))
-        kv_specs = [payload_spec, scale_spec, payload_spec, scale_spec]
         def bits(scales):  # fp16 -> its bit pattern, a free view
             return jax.lax.bitcast_convert_type(scales, jnp.uint16)
 
-        operands = [pk.reshape(pk.shape[0], H * width), bits(sk),
-                    pv.reshape(pv.shape[0], H * width), bits(sv)]
-
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, Dh)
+        operands = [pk, bits(sk), pv, bits(sv)]
+        width = HD  # the tile as dequantized
+    # a cache row's bytes: K's arrays are half of the operands
+    row_bytes = sum(c.shape[1] * c.dtype.itemsize for c in operands) // 2
+    KB = tile_blocks(bs, W, row_bytes, T, H, width)
+    if KB < 1:
+        raise ValueError(
+            f"paged attention kernel: {T} x {H} score rows of {width} "
+            f"lanes, or one block of {bs} rows of {row_bytes} bytes, "
+            f"do not fit VMEM")
+    bufs = [pltpu.VMEM((2, KB, bs, c.shape[1]), c.dtype) for c in operands]
+    # the pool by blocks: a view, `bs` whole rows being whole tiles
+    operands = [c.reshape(-1, bs, c.shape[-1]) for c in operands]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B * H, W),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, T, Dh), lambda b, a, t, s: (b, 0, 0)),
-            *kv_specs,
+            pl.BlockSpec((1, C, width), lambda b, t, s: (b, 0, 0)),
+            *[pl.BlockSpec(memory_space=pl.ANY)] * len(operands),
         ],
-        out_specs=pl.BlockSpec((1, T, Dh), lambda b, a, t, s: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, T, width), lambda b, t, s: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((T, Dh), jnp.float32),
-            pltpu.VMEM((T, 128), jnp.float32),
-            pltpu.VMEM((T, 128), jnp.float32),
+            *bufs,
+            pltpu.SemaphoreType.DMA((len(operands), 2)),
+            pltpu.VMEM((C, width), jnp.float32),
+            pltpu.VMEM((C, 128), jnp.float32),
+            pltpu.VMEM((C, 128), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=Dh ** -0.5, bs=bs, W=W,
-                          H=H, T=T, Dh=Dh, kv_mode=kv_mode,
+        functools.partial(_walk_kernel, scale=Dh ** -0.5, bs=bs, W=W,
+                          KB=KB, T=T, H=H, Hp=Hp, Dh=Dh, kv_mode=kv_mode,
                           marker=marker),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * H, T, Dh), out_dtype),
-        compiler_params=_params(),
-        interpret=pallas_backend.interpret(),
-    )(tables, qp, qf, *operands)
-    return out.reshape(B, H, T, Dh).transpose(0, 2, 1, 3)
+        out_shape=jax.ShapeDtypeStruct((B, T, width), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.PARALLEL,)),
+        interpret=interpret,
+        name="paged_attention_walk",
+    )(tables.astype(jnp.int32), q_pos.astype(jnp.int32),
+      _block_diagonal(q, Hp, width), *operands)
+    return out[..., :HD].reshape(B, T, H, Dh)

@@ -226,36 +226,50 @@ class SparseAttentionOp(KernelOp):
 
 
 class PagedAttentionOp(KernelOp):
-    """Decode-path paged attention (op 1): fused block-table gather +
-    online-softmax attention over the PagedKVCache, with the quantized
-    KV dequant fused into the gather.  Oracle = the gather/einsum/
-    softmax expression serving/layers.py's `_paged_attend` always ran
-    (bit-identical serving behaviour wherever the oracle is chosen)."""
+    """Decode-path paged attention (op 1): a walk of each slot's live
+    blocks in the PagedKVCache's pool, all heads a tile, online softmax
+    (kernels/paged.py).  Oracle = the gather/einsum/softmax expression
+    serving/layers.py's `_paged_attend` ran before there was a kernel
+    (serving stays bit-identical to `generate()` wherever the oracle is
+    chosen).  The shape rule looks at what the call site can observe —
+    `q_len`, `kv_mode`, `block_size`, the row's width and dtype, the
+    table's width — never at a head size or a model."""
 
     NAME = "paged_attention"
 
     def auto_supports(self, variant, info):
         if not info:
             return True, ""
-        bs = int(info.get("block_size", 0))
-        L = int(info.get("kv_len", bs))
-        if bs <= 0 or L % bs:
-            return False, (f"gathered rows {L} not a whole number of "
-                           f"cache blocks of {bs}")
         t = int(info.get("q_len", 1))
         if t > 8:
-            return False, (f"q_len {t} too large for the unrolled "
-                           f"decode kernel (prefill stays on jnp)")
-        d = int(info.get("head_dim", 128))
-        if d % 128:
-            # every GPT-2 size has head_dim 64
-            return False, (f"head_dim {d} is not a multiple of 128: "
-                           f"{_BLOCK_RULE} refuses the gathered "
-                           f"({bs}, {d}) cache tile")
-        if info.get("kv_mode", "dense") == "int4" and d % 256:
-            return False, (f"int4 KV at head_dim {d} packs rows of "
-                           f"{d // 2} bytes: {_BLOCK_RULE} refuses the "
-                           f"({bs}, {d // 2}) payload tile")
+            return False, (f"q_len {t} is a prefill chunk: the kernel "
+                           f"unrolls over a decode or verify step's "
+                           f"queries (<= 8); prefill reads one request's "
+                           f"rows through the jnp oracle")
+        mode = info.get("kv_mode", "dense")
+        bs = int(info.get("block_size", 0))
+        H = int(info.get("num_heads", 1))
+        if mode != "dense":
+            return False, (f"{mode} rows: the kernel would copy a "
+                           f"block's ({bs}, {H}) tile of scales, and "
+                           f"\"Slice shape along dimension 2 must be "
+                           f"aligned to tiling (128)\"; the oracle "
+                           f"dequantises the gathered rows")
+        item = int(info.get("kv_itemsize", 2))
+        sublanes = 32 // item
+        if bs <= 0 or bs % sublanes:
+            return False, (f"a block of {bs} rows is not whole tiles of "
+                           f"{sublanes} rows at {item} bytes a value, so "
+                           f"it is no slab the kernel can copy")
+        from ..serving.kv_cache import pool_width
+        from .paged import tile_blocks
+
+        width = pool_width(H, int(info.get("head_dim", 128)))
+        if not tile_blocks(bs, int(info.get("table_width", 1)),
+                           width * item, t, H, width):
+            return False, (f"{t} x {H} score rows of {width} lanes, or "
+                           f"one block of {bs} such rows, do not fit the "
+                           f"kernel's VMEM tiles")
         return True, ""
 
     def pallas(self, variant, *args, **kwargs):

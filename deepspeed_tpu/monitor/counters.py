@@ -114,6 +114,12 @@ Instrumented sites:
   visible summary rows); `serve.eva.context_tokens` — bytes = cached
   length of the same queries, so rows_read / context_tokens is the
   share of full attention's reads that is left.
+  Paged attention (every other served model): `serve.paged.rows_walked`
+  — calls = slots decoded, bytes = pool rows their attention reads (a
+  slot's live blocks where the paged kernel runs, the table's whole
+  width where the jnp oracle does); `serve.paged.context_tokens` —
+  bytes = the same slots' cached lengths, so context_tokens /
+  rows_walked is the share of what a step reads that it needs.
   Fleet routing (`router.*`, serving/router.py, rendered as the
   "Fleet router" rows; excluded from the comm byte table like the
   rest of the serving families): `router.dispatches` — requests

@@ -62,6 +62,13 @@ model the engine refuses, by name, `prefix_cache=True`, sessions,
 `kv.summary_rows`, `kv.window_closes`, `serve.eva.rows_read`,
 `serve.eva.context_tokens`; host span `eva.window_close`.
 
+What a paged step reads: `serve.paged.rows_walked` (calls = slots
+decoded, bytes = the pool rows attention reads for them: a slot's live
+blocks where the paged kernel runs, the table's whole width where the
+jnp oracle does — what the kernel registry answers for the decode
+program's shapes, asked once at build) beside
+`serve.paged.context_tokens` (bytes = the same slots' cached lengths).
+
 Speculative decoding (`draft_len > 0`): each decode step becomes a
 verify step — a host-side n-gram drafter proposes up to `draft_len`
 candidates per slot from the request's own emitted tokens, the batched
@@ -252,6 +259,20 @@ class ServeEngine:
             self.scheduler.session_lookup = self._session_lookup
             self.scheduler.session_consumed = self._session_consumed
         self.programs = programs
+        # what a decoded slot's attention reads, for
+        # serve.paged.rows_walked: its live blocks where the registry
+        # picks the kernel for the decode program's shapes, else the
+        # table's whole width
+        self._walks_live_blocks = False
+        if spec.attention == "paged":
+            from ..kernels import registry
+            from .layers import paged_info
+
+            pool = jax.tree_util.tree_leaves(self.kv.caches[0][0])[0]
+            info = paged_info(cfg, schedule, int(c.draft_len) + 1,
+                              pool.dtype)
+            self._walks_live_blocks = registry.resolve_impl(
+                "paged_attention", info=info) == "pallas"
         self.params = programs["prepare_params"](
             self._place_params(params))
         logger.info(f"serving engine up: {schedule.describe()}; "
@@ -643,6 +664,8 @@ class ServeEngine:
                 COUNTERS.add("serve.eva.rows_read",
                              nbytes=p % W + 1 + p // W * (W // C))
                 COUNTERS.add("serve.eva.context_tokens", nbytes=p + 1)
+        else:
+            self._count_rows_walked(running, 1)
         t0 = time.perf_counter()
         toks, caches = self.programs["decode"](
             self.params, self.kv.caches, jnp.asarray(self._tokens),
@@ -676,6 +699,20 @@ class ServeEngine:
             tr.add_complete("decode_step", "serve", ts_us=tus0,
                             dur_us=tr.now_us() - tus0, step=self.steps,
                             batch=len(running))
+
+    def _count_rows_walked(self, running: List[Request],
+                           n_queries: int) -> None:
+        """The pool rows this step's attention reads for the running
+        slots, beside the lengths they hold once its rows are written."""
+        held = self._positions[[r.slot for r in running]].astype(
+            np.int64) + n_queries
+        bs = self.kv.block_size
+        walked = (-(-held // bs) * bs if self._walks_live_blocks
+                  else np.full_like(held, self.kv.table_width * bs))
+        COUNTERS.add("serve.paged.rows_walked", calls=len(running),
+                     nbytes=int(walked.sum()))
+        COUNTERS.add("serve.paged.context_tokens", calls=len(running),
+                     nbytes=int(held.sum()))
 
     # -- summarised windows: host book-keeping at step boundaries --------
 
@@ -792,6 +829,7 @@ class ServeEngine:
                 drafts[req.slot, :len(d)] = d
                 COUNTERS.add("serve.draft_tokens", calls=len(d))
         tokens = np.concatenate([self._tokens[:, None], drafts], axis=1)
+        self._count_rows_walked(running, k + 1)
         t0 = time.perf_counter()
         toks, caches = self.programs["verify"](
             self.params, self.kv.caches, jnp.asarray(tokens),

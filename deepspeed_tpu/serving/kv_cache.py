@@ -40,19 +40,28 @@ reference on a finished request's blocks so a follow-up turn can adopt
 them wholesale (`alloc_from_pin` transfers ownership, no copies) and
 re-prefill only its new tokens.
 
-Device layout: per layer, K and V each live in ONE flat array
-`[num_blocks * block_size, block_size-major]` -> shaped
-`[num_blocks * block_size, H, Dh]`.  The flat first dimension makes both
-program-side accesses a single primitive: the decode write is a batched
-row scatter at `table[pos // bs] * bs + pos % bs`, the attention read a
-row gather of the table's blocks.  On a mesh the head dimension is
-sharded over the `model` axis (the same Megatron TP layout as the
-weights), so each TP rank holds its heads' share of every block and the
-gather/scatter stay local to the row dimension.
+Device layout: per layer, K and V each live in ONE array of cache
+rows, `[num_blocks * block_size, pool_width(H, Dh)]`: a token's H heads
+side by side in one row of `H * Dh` lanes, rounded up to the 128 lanes
+the chip tiles a row in anyway (GPT-2 xl: 1,600 -> 1,664, 4 %; nothing
+where `H * Dh` is a multiple of 128).  A block is then `block_size`
+consecutive rows — one contiguous slab — and the programs use the pool
+as it lies: the decode write is a batched row scatter at
+`table[pos // bs] * bs + pos % bs`, the attention read either a row
+gather of the table's blocks (the oracle) or a copy of each live block
+(kernels/paged.py).  Kept as `[rows, H, Dh]` the chip stores the pool
+rows-minor-most and transposes all of it for every scatter and gather
+(32 ms a call at GPT-2 xl's 513 blocks, PERF.md PR 30).  On a mesh the
+row's lanes are sharded over the `model` axis (the same Megatron TP
+layout as the weights: a rank's columns are its heads), so each TP rank
+holds its heads' share of every block and the gather/scatter stay local
+to the row dimension.  A windowed cache (below) keeps `[rows, H, Dh]`:
+its attention (kernels/eva.py) indexes a chunk's block by head.
 
 Quantized storage (`dtype="int8" | "int4"`): each K/V entry becomes a
-(payload, scales) pair — int8/uint8 codes `[rows, H, Dh | Dh/2]` plus
-one fp16 scale per (row, head) through the PR-7 row kernels
+(payload, scales) pair — int8/uint8 codes `[rows, pool_width(H, Dh |
+Dh/2)]` plus one fp16 scale per (row, head) `[rows, H]` through the
+PR-7 row kernels
 (runtime/comm/quant.py `quantize_rows`).  The scale granularity is one
 row, FINER than one cache block, so a decode scatter-write touches
 exactly its own rows' payload and scales (block-local, no
@@ -145,12 +154,31 @@ def resolve_kv_dtype(dtype):
     return "dense", dtype
 
 
+LANES = 128
+
+
+def pool_width(num_heads: int, width: int) -> int:
+    """Lanes of one pool row: `num_heads * width` values side by side,
+    rounded up to the chip's 128-lane tile — what the row occupies on
+    the device whatever its logical width, and what lets the paged
+    kernel copy a block as whole tiles."""
+    return -(-num_heads * width // LANES) * LANES
+
+
+def pool_rows(val, width: Optional[int] = None):
+    """[N, H, w] -> [N, width]: a token's heads side by side, then the
+    lanes that pad a pool row (`pool_width(H, w)` unless given)."""
+    n, heads, w = val.shape
+    width = pool_width(heads, w) if width is None else width
+    return jnp.pad(val.reshape(n, heads * w),
+                   ((0, 0), (0, width - heads * w)))
+
+
 def rows_for_tables(tables, block_size: int):
     """Block tables [R, W] -> flat cache row indices [R, W * block_size]
-    (row-major walk of each slot's blocks).  THE addressing the serving
-    programs attend through and the paged-attention kernel inverts
-    (`rows[:, ::block_size] // block_size` recovers the table), so the
-    two stay in lockstep by sharing this one definition."""
+    (row-major walk of each slot's blocks): the addressing the paged
+    oracle gathers through, and the walk the paged kernel makes block
+    by block."""
     R, W = tables.shape
     return (tables[:, :, None] * block_size +
             jnp.arange(block_size)[None, None, :]).reshape(R, -1)
@@ -160,15 +188,16 @@ def kv_block_bytes(num_layers: int, num_heads: int, head_dim: int,
                    block_size: int, kv_dtype) -> int:
     """Device bytes ONE block costs across all layers (K and V) — the
     equal-pool-bytes sizing rule serve_bench's resident-sessions lanes
-    ride: int8 stores head_dim payload bytes + 2 scale bytes per
-    (row, head), int4 halves the payload."""
+    ride: a row of `pool_width` values; int8 stores head_dim payload
+    bytes + 2 scale bytes per (row, head), int4 halves the payload."""
     mode, dense = resolve_kv_dtype(kv_dtype)
     if mode == "dense":
-        per_row = num_heads * head_dim * jnp.dtype(dense).itemsize
+        per_row = pool_width(num_heads, head_dim) * \
+            jnp.dtype(dense).itemsize
     elif mode == "int8":
-        per_row = num_heads * (head_dim + 2)
+        per_row = pool_width(num_heads, head_dim) + 2 * num_heads
     else:  # int4: two codes per byte + the fp16 scale
-        per_row = num_heads * (head_dim // 2 + 2)
+        per_row = pool_width(num_heads, head_dim // 2) + 2 * num_heads
     return 2 * num_layers * block_size * per_row
 
 
@@ -176,7 +205,8 @@ class PagedKVCache:
     """Device block pool + host allocator for one serving engine.
 
     `caches` is the functional state the jitted programs thread: a list
-    of (k, v) per layer, each `[num_blocks * block_size, H, Dh]`.  The
+    of (k, v) per layer, each `[num_blocks * block_size, pool_width]`
+    (windowed: `[num_blocks * block_size, H, Dh]`).  The
     engine passes it into a program and stores the returned (donated)
     arrays back; this object owns the allocator book-keeping only.
 
@@ -262,7 +292,11 @@ class PagedKVCache:
 
     def _kv_sharding(self, mesh_info):
         """Heads sharded over the TP `model` axis when a mesh is in
-        scope and divides them; None otherwise (plain local arrays)."""
+        scope and divides them; None otherwise (plain local arrays).
+        For the flat pool that is a split of the row's columns, which
+        falls on head boundaries when the row has no padding lanes (a
+        padded row still shards, and the partitioner moves the lanes
+        that land on another rank than their head's)."""
         if mesh_info is None:
             return None
         from ..comm.mesh import MODEL_AXIS
@@ -277,7 +311,9 @@ class PagedKVCache:
                 f"serving KV cache: model axis {tp} does not divide "
                 f"num_heads {self.num_heads}; cache stays unsharded")
             return None
-        return mesh_info.sharding(None, MODEL_AXIS, None)
+        if self.windowed:
+            return mesh_info.sharding(None, MODEL_AXIS, None)
+        return mesh_info.sharding(None, MODEL_AXIS)
 
     def _scale_kv_sharding(self, mesh_info):
         """Scales are [rows, H] — same head split as the payload."""
@@ -290,7 +326,8 @@ class PagedKVCache:
     def _init_caches(self):
         rows = self.num_blocks * self.block_size
         if self.quant_wire is None:
-            shape = (rows, self.num_heads, self.head_dim)
+            shape = ((rows, self.num_heads, self.head_dim) if self.windowed
+                     else (rows, pool_width(self.num_heads, self.head_dim)))
 
             def mk():
                 z = jnp.zeros(shape, self.dense_dtype)
@@ -304,7 +341,8 @@ class PagedKVCache:
             def mk():
                 # zero payload + zero scale dequantizes to exact zero,
                 # matching the dense cache's zero init
-                payload = jnp.zeros((rows, self.num_heads, width), pdt)
+                payload = jnp.zeros(
+                    (rows, pool_width(self.num_heads, width)), pdt)
                 scales = jnp.zeros((rows, self.num_heads), jnp.float16)
                 if self._sharding is not None:
                     payload = jax.device_put(payload, self._sharding)
@@ -319,8 +357,7 @@ class PagedKVCache:
 
     def bytes_per_block(self) -> int:
         """Device bytes one block costs across all layers (K and V)."""
-        return kv_block_bytes(self.num_layers, self.num_heads,
-                              self.head_dim, self.block_size, self.dtype)
+        return self.nbytes() // self.num_blocks
 
     # -- allocator ----------------------------------------------------
 

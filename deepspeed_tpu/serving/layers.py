@@ -13,14 +13,23 @@ head are free of that choice.
 The paged pieces mirror models/generation.py `_block_with_cache` op for
 op (fp32 scores, the same einsum strings, NEG_INF masking, probs cast to
 the cache dtype), so greedy serving output is bit-identical to
-`generate()` when the cache lengths agree — pinned in
-tests/test_serving.py.  Their statements stand in the order the programs
-had them before they moved here: the order of independent operations is
-part of a program's StableHLO, which keys the compilation cache.
+`generate()` when the cache lengths agree and the attention core is the
+jnp oracle (every backend but the TPU) — pinned in tests/test_serving.py.
+The EVA pieces' statements stand in the order the programs had them
+before they moved here: the order of independent operations is part of a
+program's StableHLO, which keys the compilation cache.
+
+The paged pool is `[rows, pool_width(H, Dh)]` (serving/kv_cache.py): a
+token's heads side by side in one row.  A call's K/V are written as such
+rows by one scatter, in place, and attention reads the pool as it lies —
+through the table's live blocks on the chip at `q_len` <= 8
+(kernels/paged.py: decode and verify), by a gather of the table's rows
+elsewhere and in prefill.
 
 Addressing (`Addr`): a program works out once where this call's K/V land
 and what attention reads, and every layer's block uses it.  Paged: flat
-write rows, the flat rows of the whole table, query positions.  EVA: the
+write rows, the block tables, query positions (negative for a slot that
+is not running: it attends nothing).  EVA: the
 same write rows (inside the open window's blocks), the block table
 `[window blocks | summary blocks]`, the flat rows the summaries of the
 chunks this call completes land in, and — in decode, where a chunk
@@ -41,7 +50,7 @@ from ..models.evabyte import (chunk_summaries, matmul32, project_qkv,
                               rms_norm, silu_gated_ffn)
 from ..models.gpt import layer_norm
 from ..models.layer_spec import LayerSpec
-from .kv_cache import rows_for_tables
+from .kv_cache import pool_rows
 
 BUILT = {("learned", "paged"), ("rope", "eva")}
 
@@ -58,8 +67,7 @@ def check_spec(spec: LayerSpec) -> LayerSpec:
 class Addr(NamedTuple):
     write_idx: jax.Array                  # [B*T] flat rows of this call's K/V
     q_pos: jax.Array                      # [B, T] absolute positions
-    rows: Optional[jax.Array] = None      # paged: [B, L] flat rows attended
-    tables: Optional[jax.Array] = None    # eva: [B, Wt] block tables
+    tables: Optional[jax.Array] = None    # [B, W] block tables
     sum_idx: Optional[jax.Array] = None   # eva: [B*n] flat summary rows
     chunk_src: Optional[jax.Array] = None  # eva decode: [B, bs] flat rows
 
@@ -96,12 +104,6 @@ def embed_step(spec, params, tokens, positions):
 # -- addressing -------------------------------------------------------------
 
 
-def _gather_rows(table, block_size):
-    """Block table [W] -> flat cache row indices [W * block_size]."""
-    return (table[:, None] * block_size +
-            jnp.arange(block_size)[None, :]).reshape(-1)
-
-
 def address_chunk(spec, s, table, pos, abs_pos, n_valid) -> Addr:
     """One request's prefill chunk at positions abs_pos [C] through its
     table [W]."""
@@ -112,8 +114,8 @@ def address_chunk(spec, s, table, pos, abs_pos, n_valid) -> Addr:
         # write to the trash block, never a neighbour's memory
         blk = jnp.where(blk_i < W, table[jnp.clip(blk_i, 0, W - 1)], 0)
         write_idx = blk * bs + abs_pos % bs
-        rows = _gather_rows(table, bs)[None, :]
-        return Addr(write_idx=write_idx, q_pos=abs_pos[None, :], rows=rows)
+        return Addr(write_idx=write_idx, q_pos=abs_pos[None, :],
+                    tables=table[None, :])
     # eva: the chunk lies inside one window (prefill_chunk divides it);
     # rows past n_valid and the summaries of chunks it does not complete
     # go to the trash block
@@ -139,8 +141,8 @@ def address_step(spec, s, tables, positions, active) -> Addr:
         blk = jnp.take_along_axis(
             tables, jnp.clip(blk_i, 0, W - 1)[:, None], axis=1)[:, 0]
         write_idx = jnp.where(active, blk * bs + positions % bs, 0)
-        rows = rows_for_tables(tables, bs)
-        return Addr(write_idx=write_idx, q_pos=positions[:, None], rows=rows)
+        q_pos = jnp.where(active, positions, -1)[:, None]
+        return Addr(write_idx=write_idx, q_pos=q_pos, tables=tables)
     wb = spec.window // bs
     off = positions % spec.window
     blk = jnp.where(active, jnp.take_along_axis(
@@ -178,8 +180,8 @@ def address_grid(spec, s, tables, abs_pos, active, n_draft) -> Addr:
     # the trash block, the decode convention
     write_idx = jnp.where(valid, blk * bs + abs_pos % bs,
                           0).reshape(R * T)
-    rows = rows_for_tables(tables, bs)
-    return Addr(write_idx=write_idx, q_pos=abs_pos, rows=rows)
+    q_pos = jnp.where(active[:, None], abs_pos, -1)
+    return Addr(write_idx=write_idx, q_pos=q_pos, tables=tables)
 
 
 # -- the block --------------------------------------------------------------
@@ -187,29 +189,40 @@ def address_grid(spec, s, tables, abs_pos, active, n_draft) -> Addr:
 
 def _kv_write(c, idx, val, kv_mode):
     """Scatter `val` [N, H, Dh] into cache entry `c` at flat rows
-    `idx`.  Dense: a plain row scatter at the cache's own dtype.
-    Quantized: the rows are quantized through the PR-7 row kernels and
-    BOTH the payload and the per-(row, head) scales scatter at the same
-    indices — the write never touches another row's scale."""
+    `idx`, as whole pool rows.  Dense: a plain row scatter at the
+    cache's own dtype.  Quantized: the rows are quantized through the
+    PR-7 row kernels and BOTH the payload and the per-(row, head) scales
+    scatter at the same indices — the write never touches another row's
+    scale."""
     if kv_mode == "dense":
-        return c.at[idx].set(val.astype(c.dtype))
+        return c.at[idx].set(pool_rows(val.astype(c.dtype), c.shape[1]))
     from ..runtime.comm.quant import quantize_rows
 
     payload, scales = c
     codes, s = quantize_rows(val.astype(jnp.float32), kv_mode)
-    return (payload.at[idx].set(codes), scales.at[idx].set(s))
+    return (payload.at[idx].set(pool_rows(codes, payload.shape[1])),
+            scales.at[idx].set(s))
 
 
-def _paged_attend(cfg, p, h, ck, cv, addr, kv_mode, block_size):
+def paged_info(cfg, s, q_len: int, cache_dtype) -> dict:
+    """What the kernel registry may look at to choose the attention
+    core of a paged program of `s` with `q_len` queries a slot."""
+    return {"block_size": s.block_size, "table_width": s.table_width,
+            "q_len": q_len, "num_heads": cfg.num_heads,
+            "head_dim": cfg.head_dim, "kv_mode": s.kv_dtype,
+            "kv_itemsize": jnp.dtype(cache_dtype).itemsize}
+
+
+def _paged_attend(cfg, p, h, ck, cv, addr, s):
     """Fused QKV with bias, K/V written through the table, causal
-    softmax over every cached row, output projection.  Op-for-op the
-    math of generation._block_with_cache; only the cache addressing
-    differs (scatter/gather through the table instead of
-    dynamic_update_slice on a contiguous cache).  `kv_mode` picks the
-    storage codec: "dense" stores rows at the cache arrays' dtype,
-    "int8"/"int4" stores (payload, scales) pairs dequantized at the
-    gather — the surrounding math is identical either way, so parity
-    pins hold AT MATCHED kv_mode."""
+    softmax over every cached row, output projection.  The math of
+    generation._block_with_cache; only the cache addressing differs
+    (scatter and table walk instead of dynamic_update_slice on a
+    contiguous cache).  `s.kv_dtype` picks the storage codec: "dense"
+    stores rows at the cache arrays' dtype, "int8"/"int4" stores
+    (payload, scales) pairs dequantized at the read — the surrounding
+    math is identical either way, so parity pins hold AT MATCHED
+    kv_dtype."""
     B, T, D = h.shape
     H, Dh = cfg.num_heads, cfg.head_dim
     qkv = h @ p["qkv"]["w"].astype(h.dtype) + \
@@ -217,21 +230,22 @@ def _paged_attend(cfg, p, h, ck, cv, addr, kv_mode, block_size):
     q, k, v = jnp.split(qkv, 3, axis=-1)
     shape = lambda t: t.reshape(B, T, H, Dh)
     q, k, v = shape(q), shape(k), shape(v)
+    kv_mode = s.kv_dtype
     ck = _kv_write(ck, addr.write_idx, k.reshape(B * T, H, Dh), kv_mode)
     cv = _kv_write(cv, addr.write_idx, v.reshape(B * T, H, Dh), kv_mode)
     # attention core through the kernel registry: the jnp oracle
     # (kernels/paged.py paged_attention_reference) is this block's
-    # pre-registry gather/einsum/softmax chain op-for-op — wherever the
-    # oracle is chosen, serving output is bit-identical; the Pallas
-    # kernel fuses the table gather (+ quantized-KV dequant) into an
-    # online-softmax sweep over cache blocks
+    # pre-registry gather/einsum/softmax chain — wherever the oracle is
+    # chosen, serving output is bit-identical to generate(); the Pallas
+    # kernel walks each slot's live blocks in the pool, an online
+    # softmax over tiles of them
     from ..kernels import registry
 
+    cache_dtype = ck.dtype if kv_mode == "dense" else ck[0].dtype
     attn = registry.dispatch(
-        "paged_attention", q, ck, cv, addr.rows, addr.q_pos,
-        info={"block_size": block_size, "kv_len": addr.rows.shape[1],
-              "q_len": T, "head_dim": Dh, "kv_mode": kv_mode},
-        kv_mode=kv_mode, block_size=block_size)
+        "paged_attention", q, ck, cv, addr.tables, addr.q_pos,
+        info=paged_info(cfg, s, T, cache_dtype),
+        kv_mode=kv_mode, block_size=s.block_size)
     attn = attn.reshape(B, T, D)
     attn = attn @ p["proj"]["w"].astype(h.dtype) + \
         p["proj"]["b"].astype(h.dtype)
@@ -281,15 +295,15 @@ def _ffn(spec, p, h):
         p["fc2"]["b"].astype(h.dtype)
 
 
-def block(spec, cfg, p, x, ck, cv, addr, kv_mode="dense", block_size=0):
-    """One pre-norm decoder block over x [B, T, D] through the cache."""
+def block(spec, cfg, p, x, ck, cv, addr, s):
+    """One pre-norm decoder block over x [B, T, D] through the cache of
+    a program of schedule `s`."""
     h = _norm(spec, x, p["ln1"])
     if spec.attention == "paged":
-        attn, ck, cv = _paged_attend(cfg, p["attn"], h, ck, cv, addr,
-                                     kv_mode, block_size)
+        attn, ck, cv = _paged_attend(cfg, p["attn"], h, ck, cv, addr, s)
     else:
         attn, ck, cv = _eva_attend(spec, cfg, p["attn"], h, ck, cv, addr,
-                                   block_size)
+                                   s.block_size)
     x = x + attn
     h = _norm(spec, x, p["ln2"])
     return x + _ffn(spec, p["mlp"], h), ck, cv

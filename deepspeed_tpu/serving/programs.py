@@ -20,10 +20,12 @@ fixed-shape programs built once from a declarative `ServeSchedule`
   keeps a long prompt from stalling the decode batch: the scheduler
   interleaves one chunk per engine step with full decode steps.
 * **decode** — one token for every slot of the packed batch
-  `[max_batch]`: per-slot block-table write + gather-based paged
-  attention + per-slot sampling.  Every operation is row-wise
-  (layernorm, per-row attention gather, per-row matmul dots, per-row
-  RNG), which is the batching-invariance contract tier-1 pins: a
+  `[max_batch]`: per-slot block-table write + paged attention through
+  the table (on a TPU a walk of the slot's live blocks in the pool,
+  kernels/paged.py; elsewhere a gather of the table's rows) + per-slot
+  sampling.  Every operation is row-wise (layernorm, per-slot attention,
+  per-row matmul dots, per-row RNG), which is the batching-invariance
+  contract tier-1 pins: a
   request's tokens do not depend on WHICH other requests share the
   batch, so joining mid-flight is token-identical to decoding alone.
 * **verify** — the speculative-decoding forward: decode at
@@ -315,7 +317,7 @@ class ServeProgramBuilder:
     def _build_prefill(self):
         cfg, spec = self.model.config, self.spec
         s = self.schedule
-        C, bs = s.prefill_chunk, s.block_size
+        C = s.prefill_chunk
 
         @partial(jax.jit, donate_argnums=(1,))
         def prefill(params, caches, tokens, pos, n_valid, table,
@@ -332,9 +334,7 @@ class ServeProgramBuilder:
                                         n_valid)
             new_caches = []
             for bp, (ck, cv) in zip(params["blocks"], caches):
-                x, ck, cv = layers.block(spec, cfg, bp, x, ck, cv, addr,
-                                         kv_mode=s.kv_dtype,
-                                         block_size=bs)
+                x, ck, cv = layers.block(spec, cfg, bp, x, ck, cv, addr, s)
                 new_caches.append((ck, cv))
             x = layers.final_norm(spec, params, x)
             last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
@@ -356,9 +356,7 @@ class ServeProgramBuilder:
         addr = layers.address_step(spec, s, tables, positions, active)
         new_caches = []
         for bp, (ck, cv) in zip(params["blocks"], caches):
-            x, ck, cv = layers.block(spec, cfg, bp, x, ck, cv, addr,
-                                     kv_mode=s.kv_dtype,
-                                     block_size=s.block_size)
+            x, ck, cv = layers.block(spec, cfg, bp, x, ck, cv, addr, s)
             new_caches.append((ck, cv))
         x = layers.final_norm(spec, params, x)
         return layers.logits(spec, params, x[:, -1, :]), new_caches
@@ -401,7 +399,6 @@ class ServeProgramBuilder:
         any later query's causal mask can reach them."""
         cfg, spec = self.model.config, self.spec
         s = self.schedule
-        bs = s.block_size
         T = int(s.draft_len) + 1
 
         @partial(jax.jit, donate_argnums=(1,))
@@ -424,9 +421,7 @@ class ServeProgramBuilder:
                                        n_draft)
             new_caches = []
             for bp, (ck, cv) in zip(params["blocks"], caches):
-                x, ck, cv = layers.block(spec, cfg, bp, x, ck, cv, addr,
-                                         kv_mode=s.kv_dtype,
-                                         block_size=bs)
+                x, ck, cv = layers.block(spec, cfg, bp, x, ck, cv, addr, s)
                 new_caches.append((ck, cv))
             x = layers.final_norm(spec, params, x)
             logits = layers.logits(

@@ -43,6 +43,11 @@ def test_serve_phase_toy():
     assert out["requests"] == 8 and all(out["first_token_equals_generate"])
     assert out["joined_while_decoding"] > 0
     assert out["token_agreement_share"] == 1.0  # fp32 on CPU: exact
+    assert out["first_token_gap_to_top_logit"] == [0.0] * 8
+    with pytest.raises(RuntimeError, match="beyond a near tie"):
+        chip_smoke.serve_phase(
+            **TOY, serve=serve, prompt_lens=(3, 5, 9, 12, 17, 20, 26, 40),
+            max_new=6, margin=-1.0, param_dtype=jax.numpy.float32)
 
 
 def test_evabyte_phase_toy():
@@ -65,15 +70,17 @@ def test_evabyte_phase_toy():
 
 def test_kernels_phase_toy():
     out = chip_smoke.kernels_phase(batch=1, seq=256, heads=2, head_dim=64,
-                                   d_model=64, vocab=1024, paged_heads=2,
-                                   paged_slots=2, paged_width=4,
+                                   d_model=64, vocab=1024,
+                                   paged_shapes=((5, 64), (2, 128)),
+                                   paged_slots=3, paged_width=4,
                                    bert_batch=2, bert_seq=256, bert_heads=2,
                                    on_chip=False)
     assert [k["kernel"] for k in out["kernels"]] == [
         "flash_attention_fwd", "flash_attention_bwd",
         "flash_attention_full_bias_dropout_fwd",
         "flash_attention_full_bias_dropout_bwd", "fused_xent_fwd",
-        "fused_xent_bwd", "paged_attention_dense"]
+        "fused_xent_bwd", "paged_attention_dense_H5_Dh64",
+        "paged_attention_dense_H2_Dh128"]
 
 
 def test_four_chip_phase_on_four_virtual_devices():

@@ -138,49 +138,107 @@ def test_moe_combine_parity_one_ulp():
                                rtol=0, atol=1e-6)
 
 
-def _paged_inputs(kv_mode, R=2, T=1, H=2, Dh=128, bs=4, W=4, seed=0):
+def _paged_inputs(kv_mode, R=2, T=1, H=2, Dh=128, bs=4, W=4, seed=0,
+                  lengths=None, dead_to_trash=False):
+    """A pool `[rows, pool_width(H, Dh)]`, tables [R, W] and query
+    positions [R, T].  `lengths` [R]: what each slot holds once this
+    call's rows are written (0: the slot is idle, its positions -1);
+    None draws them.  `dead_to_trash`: table entries past a slot's
+    length point at the trash block, as the allocator pads them."""
     from deepspeed_tpu.runtime.comm.quant import quantize_rows
-    from deepspeed_tpu.serving.kv_cache import rows_for_tables
+    from deepspeed_tpu.serving.kv_cache import pool_rows
 
     rng = np.random.RandomState(seed)
     nblocks = R * W + 1
-    ck = jnp.asarray(rng.randn(nblocks * bs, H, Dh), jnp.float32)
-    cv = jnp.asarray(rng.randn(nblocks * bs, H, Dh), jnp.float32)
-    if kv_mode != "dense":
-        ck, cv = quantize_rows(ck, kv_mode), quantize_rows(cv, kv_mode)
-    tables = jnp.asarray(rng.randint(0, nblocks, (R, W)), jnp.int32)
-    rows = rows_for_tables(tables, bs)
+
+    def pool():
+        c = jnp.asarray(rng.randn(nblocks * bs, H, Dh), jnp.float32)
+        if kv_mode == "dense":
+            return pool_rows(c)
+        codes, scales = quantize_rows(c, kv_mode)
+        return pool_rows(codes), scales
+
+    ck, cv = pool(), pool()
+    tables = rng.randint(1, nblocks, (R, W)).astype(np.int32)
+    if lengths is None:
+        lengths = rng.randint(T, W * bs + 1, (R,))
+    lengths = np.asarray(lengths)
+    if dead_to_trash:
+        live = -(-lengths // bs)
+        tables[np.arange(W)[None, :] >= live[:, None]] = 0
+    # the queries are a slot's last T positions
+    q_pos = np.where(lengths[:, None] > 0,
+                     lengths[:, None] - T + np.arange(T)[None, :], -1)
     q = jnp.asarray(rng.randn(R, T, H, Dh), jnp.float32)
-    q_pos = jnp.asarray(rng.randint(0, W * bs, (R, T)), jnp.int32)
-    return q, ck, cv, rows, q_pos, bs
+    return (q, ck, cv, jnp.asarray(tables),
+            jnp.asarray(q_pos, jnp.int32), bs)
 
 
-@pytest.mark.parametrize("kv_mode", ["dense", "int8", "int4"])
-@pytest.mark.parametrize("T", [1, 3])
-def test_paged_attention_parity(kv_mode, T):
-    """Fused gather+attention (quantized dequant folded into the
-    gather) vs the verbatim `_paged_block` expression — decode (T=1)
-    and short verify windows (T=3)."""
+def _paged_both(kv_mode, **kw):
     from deepspeed_tpu.kernels.paged import paged_attention_reference
 
-    q, ck, cv, rows, q_pos, bs = _paged_inputs(kv_mode, T=T)
-    ref = paged_attention_reference(q, ck, cv, rows, q_pos,
+    q, ck, cv, tables, q_pos, bs = _paged_inputs(kv_mode, **kw)
+    ref = paged_attention_reference(q, ck, cv, tables, q_pos,
                                     kv_mode=kv_mode, block_size=bs)
     with kernel_config(interpret=True):
-        out = registry.dispatch("paged_attention", q, ck, cv, rows, q_pos,
-                                variant="default", impl="pallas",
+        out = registry.dispatch("paged_attention", q, ck, cv, tables,
+                                q_pos, variant="default", impl="pallas",
                                 kv_mode=kv_mode, block_size=bs)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), atol=2e-6)
+    assert out.shape == ref.shape and out.dtype == ref.dtype
+    return np.asarray(out, np.float32), np.asarray(ref, np.float32), q_pos
 
 
-def test_paged_attention_kernel_rejects_ragged_rows():
-    q, ck, cv, rows, q_pos, bs = _paged_inputs("dense")
-    with kernel_config(interpret=True):
-        with pytest.raises(ValueError, match="whole cache blocks"):
-            registry.dispatch("paged_attention", q, ck, cv,
-                              rows[:, :-1], q_pos, impl="pallas",
-                              kv_mode="dense", block_size=bs)
+# slots of length 1, one full block, one block plus one row, the full
+# table, and an idle slot (bs 4, W 4)
+_EDGE_LENGTHS = [1, 4, 5, 16, 0]
+
+
+def _paged_case_id(v):
+    if not isinstance(v, dict):
+        return str(v)
+    return "_".join(
+        f"{k}{'x'.join(map(str, x)) if isinstance(x, list) else x}"
+        for k, x in v.items()) or "H2_Dh128"
+
+
+@pytest.mark.parametrize("kv_mode,T,shape", [
+    ("dense", 1, {}), ("dense", 3, {}),
+    ("int8", 1, {}), ("int8", 3, {}),
+    ("int4", 1, {}), ("int4", 3, {}),
+    # GPT-2 xl's heads (a row of 1,600 lanes in a pool of 1,664) and
+    # chip_smoke's; decode and a verify step of draft_len + 1 = 4
+    ("dense", 1, dict(H=25, Dh=64)), ("dense", 4, dict(H=25, Dh=64)),
+    ("dense", 1, dict(H=16, Dh=128)), ("dense", 4, dict(H=16, Dh=128)),
+    ("dense", 1, dict(H=25, Dh=64, R=5, lengths=_EDGE_LENGTHS)),
+    ("dense", 1, dict(H=16, Dh=128, R=5, lengths=_EDGE_LENGTHS)),
+    ("dense", 1, dict(H=25, Dh=64, R=5, lengths=_EDGE_LENGTHS,
+                      dead_to_trash=True)),
+    ("dense", 2, dict(H=25, Dh=64, R=5, lengths=[2, 4, 5, 16, 0],
+                      dead_to_trash=True)),
+    ("int8", 1, dict(H=25, Dh=64, R=5, lengths=_EDGE_LENGTHS,
+                     dead_to_trash=True)),
+    # a table wider than one tile of blocks: several steps of the walk
+    ("dense", 1, dict(H=25, Dh=64, R=3, bs=16, W=40,
+                      lengths=[640, 257, 30], dead_to_trash=True)),
+], ids=_paged_case_id)
+def test_paged_attention_parity(kv_mode, T, shape):
+    """The table walk (quantized dequant done on the tile) vs the
+    gather/einsum/softmax expression — decode (T=1) and short verify
+    windows.  An idle slot (positions -1) reads nothing in the kernel
+    and its output is discarded by the engine: not compared."""
+    out, ref, q_pos = _paged_both(kv_mode, T=T, **shape)
+    live = np.asarray(q_pos)[:, -1] >= 0
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-6)
+    assert not out[~live].any()
+
+
+def test_paged_attention_slot_ignores_the_other_slots():
+    """Batching invariance of the kernel: a slot's output is the same
+    bits whatever the other slots hold — long, short or idle."""
+    kw = dict(H=25, Dh=64, R=3, bs=4, W=8, dead_to_trash=True)
+    a, _, _ = _paged_both("dense", lengths=[13, 32, 7], **kw)
+    b, _, _ = _paged_both("dense", lengths=[13, 1, 0], **kw)
+    assert np.array_equal(a[0], b[0])
 
 
 def test_flash_attention_parity():

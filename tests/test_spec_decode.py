@@ -153,14 +153,18 @@ def test_resolve_kv_dtype_aliases_and_typos():
         resolve_kv_dtype("fp8")
 
 
-@pytest.mark.parametrize("kv,per_row", [
-    ("bf16", 4 * 8 * 2),           # H * Dh * itemsize
-    ("fp32", 4 * 8 * 4),
-    ("int8", 4 * (8 + 2)),         # H * (Dh payload + fp16 scale)
-    ("int4", 4 * (8 // 2 + 2)),    # packed payload + fp16 scale
+@pytest.mark.parametrize("heads,dh,kv,per_row", [
+    # a pool row is H * Dh values padded to whole 128-lane tiles
+    (4, 8, "bf16", 128 * 2),
+    (4, 8, "fp32", 128 * 4),
+    (4, 8, "int8", 128 + 4 * 2),       # payload row + H fp16 scales
+    (4, 8, "int4", 128 + 4 * 2),       # packed payload row + scales
+    (25, 64, "bf16", 1664 * 2),        # GPT-2 xl: 1,600 -> 1,664 lanes
+    (16, 128, "bf16", 2048 * 2),       # whole tiles already: no padding
+    (16, 128, "int4", 1024 + 16 * 2),
 ])
-def test_kv_block_bytes_formula(kv, per_row):
-    assert kv_block_bytes(2, 4, 8, BS, kv) == 2 * 2 * BS * per_row
+def test_kv_block_bytes_formula(heads, dh, kv, per_row):
+    assert kv_block_bytes(2, heads, dh, BS, kv) == 2 * 2 * BS * per_row
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8", "int4"])
@@ -177,7 +181,9 @@ def test_quant_cache_zero_init_dequantizes_to_zero():
                          num_blocks=3, block_size=BS, table_width=WIDTH,
                          dtype="int8")
     payload, scales = cache.caches[0][0]
-    y = dequantize_rows(payload, scales, "int8")
+    assert payload.shape == (3 * BS, 128) and scales.shape == (3 * BS, 2)
+    y = dequantize_rows(payload[:, :2 * 8].reshape(-1, 2, 8), scales,
+                        "int8")
     assert (np.asarray(y) == 0.0).all()
 
 

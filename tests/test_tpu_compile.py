@@ -138,25 +138,31 @@ def _sparse():
         q, k, v, layout, 128, causal=True)), qkv
 
 
-def _paged(kv_mode, heads, dh, slots=16, bs=16, width=64, nblocks=1025):
+def _paged(kv_mode, heads, dh, slots=16, bs=16, width=64, nblocks=1025,
+           q_len=1, dtype=jnp.bfloat16):
+    from deepspeed_tpu.serving.kv_cache import pool_width
+
     rows_total = nblocks * bs
     if kv_mode == "dense":
-        cache = _sds((rows_total, heads, dh), jnp.bfloat16)
+        cache = _sds((rows_total, pool_width(heads, dh)), dtype)
+        qdt, item = dtype, jnp.dtype(dtype).itemsize
     else:
         w = dh if kv_mode == "int8" else dh // 2
         pdt = jnp.int8 if kv_mode == "int8" else jnp.uint8
-        cache = (_sds((rows_total, heads, w), pdt),
+        cache = (_sds((rows_total, pool_width(heads, w)), pdt),
                  _sds((rows_total, heads), jnp.float16))
-    qdt = jnp.bfloat16 if kv_mode == "dense" else jnp.float32
-    shapes = (_sds((slots, 1, heads, dh), qdt), cache, cache,
-              _sds((slots, width * bs), jnp.int32),
-              _sds((slots, 1), jnp.int32))
-    info = {"block_size": bs, "kv_len": width * bs, "q_len": 1,
-            "head_dim": dh, "kv_mode": kv_mode}
+        qdt, item = jnp.float32, 1
+    shapes = (_sds((slots, q_len, heads, dh), qdt), cache, cache,
+              _sds((slots, width), jnp.int32),
+              _sds((slots, q_len), jnp.int32))
+    info = {"block_size": bs, "table_width": width, "q_len": q_len,
+            "num_heads": heads, "head_dim": dh, "kv_mode": kv_mode,
+            "kv_itemsize": item}
 
-    def fn(q, ck, cv, rows, q_pos):
-        return registry.dispatch("paged_attention", q, ck, cv, rows, q_pos,
-                                 info=info, kv_mode=kv_mode, block_size=bs)
+    def fn(q, ck, cv, tables, q_pos):
+        return registry.dispatch("paged_attention", q, ck, cv, tables,
+                                 q_pos, info=info, kv_mode=kv_mode,
+                                 block_size=bs)
 
     return fn, shapes, info
 
@@ -230,18 +236,37 @@ CASES = [
                             bias=True, rate=0.1)),
     Case("fused_xent_fwd_bwd_N8192_D1600_V50304", _fused_xent),
     Case("flash_sparse_fwd_S1024_H25_Dh64_block128", _sparse),
+    # one kernel for every head shape: the pool row is what it tiles
     Case("paged_dense_H16_Dh128", lambda: _paged("dense", 16, 128),
          op="paged_attention"),
     Case("paged_dense_H25_Dh64", lambda: _paged("dense", 25, 64),
-         op="paged_attention", refused=r"head_dim 64 .*128"),
-    Case("paged_int8_H16_Dh128", lambda: _paged("int8", 16, 128),
          op="paged_attention"),
+    # the chat cell's decode call (513 blocks) and a verify step of 4
+    Case("paged_dense_H25_Dh64_cell",
+         lambda: _paged("dense", 25, 64, nblocks=513),
+         op="paged_attention"),
+    Case("paged_dense_H25_Dh64_verify4",
+         lambda: _paged("dense", 25, 64, q_len=4), op="paged_attention"),
+    Case("paged_dense_H25_Dh64_fp32",
+         lambda: _paged("dense", 25, 64, dtype=jnp.float32),
+         op="paged_attention"),
+    # a prefill chunk, and blocks that are no whole tiles of bf16 rows
+    Case("paged_dense_H25_Dh64_prefill256",
+         lambda: _paged("dense", 25, 64, slots=1, q_len=256),
+         op="paged_attention", refused=r"q_len 256 is a prefill chunk"),
+    Case("paged_dense_H25_Dh64_block8",
+         lambda: _paged("dense", 25, 64, bs=8),
+         op="paged_attention", refused=r"block of 8 rows is not whole"),
+    # quantized rows: the scales tile of a block is one the chip's
+    # compiler does not copy
+    Case("paged_int8_H16_Dh128", lambda: _paged("int8", 16, 128),
+         op="paged_attention", refused=r"int8 rows: .*\(16, 16\) tile"),
     Case("paged_int8_H25_Dh64", lambda: _paged("int8", 25, 64),
-         op="paged_attention", refused=r"head_dim 64 .*128"),
+         op="paged_attention", refused=r"int8 rows: .*\(16, 25\) tile"),
     Case("paged_int4_H16_Dh128", lambda: _paged("int4", 16, 128),
-         op="paged_attention", refused=r"int4 .*head_dim 128"),
+         op="paged_attention", refused=r"int4 rows: .*\(16, 16\) tile"),
     Case("paged_int4_H25_Dh64", lambda: _paged("int4", 25, 64),
-         op="paged_attention", refused=r"head_dim 64 .*128"),
+         op="paged_attention", refused=r"int4 rows: .*\(16, 25\) tile"),
     Case("codec_quantize_int8_4M_block256",
          lambda: _codec("quantize", "int8"),
          op="quant_codec", variant="quantize"),
@@ -358,3 +383,53 @@ def test_registry_refuses_a_kernel_xla_would_have_to_partition(topo, native):
     assert registry.resolve_impl("paged_attention", info=info) == "jnp"
     with pytest.raises(RuntimeError, match="cannot be automatically"):
         registry.resolve_impl("paged_attention", impl="pallas", info=info)
+
+
+def test_decode_layer_uses_the_pool_as_it_lies(one_chip, native):
+    """One layer of GPT-2 xl's `decode` at the chat cell's shapes (16
+    slots, 513 blocks of 16, a table 64 wide, bf16): the pool enters
+    row-major, is written by a scatter, is read by the kernel, and no
+    operation of the pool's size changes its layout, converts it or
+    gathers the table's width from it — what cost 133 of the parent's
+    140 ms a step with the pool as `[rows, 25, 64]`."""
+    import re
+
+    from deepspeed_tpu.models import GPT, gpt2_config
+    from deepspeed_tpu.serving import ServeProgramBuilder, ServeSchedule
+    from deepspeed_tpu.serving.kv_cache import pool_width
+
+    slots, bs, nblocks, width = 16, 16, 513, 64
+    model = GPT(gpt2_config("xl", num_layers=1, param_dtype=jnp.bfloat16))
+    sched = ServeSchedule(max_batch=slots, prefill_chunk=256, block_size=bs,
+                          num_blocks=nblocks, table_width=width)
+    decode = ServeProgramBuilder(model, sched).build()["decode"]
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda s: on(s.shape, s.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    rows = nblocks * bs
+    pool = on((rows, pool_width(H, DH)), jnp.bfloat16)
+    text = decode.lower(
+        params, [(pool, pool)], on((slots,), jnp.int32),
+        on((slots,), jnp.int32), on((slots,), jnp.bool_),
+        on((slots, width), jnp.int32), on((slots,), jnp.float32),
+        on((slots,), jnp.int32), on((slots,), jnp.uint32),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    by_shape = {}
+    for m in re.finditer(r"= (\w+)\[([\d,]*)\]\{([\d,]*)[^ ]* ([\w\-]+)\(",
+                         text):
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        by_shape.setdefault(dims, set()).add((m.group(4), m.group(3)))
+    pool_ops = by_shape[(rows, pool_width(H, DH))]
+    assert {"parameter", "scatter"} <= {op for op, _ in pool_ops}
+    assert {layout for _, layout in pool_ops} == {"1,0"}  # row-major
+    moved = {"copy", "transpose", "convert", "gather", "reshape"}
+    assert not moved & {op for op, _ in pool_ops}, pool_ops
+    # nothing of the table's whole width (slots x 1,024 rows) is built
+    wide = [d for d in by_shape if d and d[0] in (slots * width * bs,)
+            or d[:2] == (slots, width * bs)]
+    assert not wide, wide

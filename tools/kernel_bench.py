@@ -86,7 +86,7 @@ def make_lanes(small: bool = True):
     from deepspeed_tpu.ops.sparse_attention import DenseSparsityConfig
     from deepspeed_tpu.runtime.comm.quant import (quantize_blockwise_ref,
                                                   quantize_rows)
-    from deepspeed_tpu.serving.kv_cache import rows_for_tables
+    from deepspeed_tpu.serving.kv_cache import pool_rows
 
     rng = np.random.RandomState(0)
     lanes = []
@@ -121,19 +121,23 @@ def make_lanes(small: bool = True):
     cv_f = f32(cache_rows, Hh, Dh)
     tables = jnp.asarray(
         rng.randint(0, nblocks, (R, W)), jnp.int32)
-    rows = rows_for_tables(tables, bs)
     L = W * bs
     q_pos = jnp.asarray(rng.randint(1, L, (R, T)), jnp.int32)
     pq = f32(R, T, Hh, Dh)
     for mode in ("dense", "int8", "int4"):
-        ck = ck_f if mode == "dense" else quantize_rows(ck_f, mode)
-        cv = cv_f if mode == "dense" else quantize_rows(cv_f, mode)
+        if mode == "dense":
+            ck, cv = pool_rows(ck_f), pool_rows(cv_f)
+        else:  # (payload rows, per-(row, head) scales)
+            (ck, sk), (cv, sv) = (quantize_rows(c, mode)
+                                  for c in (ck_f, cv_f))
+            ck, cv = (pool_rows(ck), sk), (pool_rows(cv), sv)
         lanes.append(dict(
             name=f"paged_attention_{mode}", op="paged_attention",
-            variant="default", args=(pq, ck, cv, rows, q_pos),
+            variant="default", args=(pq, ck, cv, tables, q_pos),
             kwargs={"kv_mode": mode, "block_size": bs},
-            info={"block_size": bs, "kv_len": L, "q_len": T,
-                  "head_dim": Dh},
+            info={"block_size": bs, "table_width": W, "q_len": T,
+                  "num_heads": Hh, "head_dim": Dh, "kv_mode": mode,
+                  "kv_itemsize": 4},
             exact=False, tol=1e-5))
 
     # -- quant codec (op 2): both wires, both directions, non-finites -
