@@ -109,7 +109,7 @@ def device_phase(chips: int, cache_dir: str) -> dict:
 
 
 def train_config(micro: int, n_dev: int) -> dict:
-    """The ZeRO-2 bf16 config bench.py trains under."""
+    """ZeRO-2, bf16 compute, fp32 masters, Adam."""
     return {
         "train_batch_size": micro * n_dev,
         "train_micro_batch_size_per_gpu": micro,

@@ -6,15 +6,11 @@ expressions kept as pinned correctness oracles.  See
 docs/tutorials/kernels.md.
 """
 
-from .registry import (KERNEL_IMPLS, KERNEL_OPS, KernelConfig,
-                       clear_winners, dispatch, get_kernel,
-                       get_kernel_config, kernel_config,
-                       parse_kernels_config, probe_report, record_winner,
-                       resolve_impl, set_kernel_config, winner_for)
+from .registry import (KERNEL_IMPLS, KERNEL_OPS, KernelConfig, dispatch,
+                       get_kernel, get_kernel_config, kernel_config,
+                       probe_report, resolve_impl)
 
 __all__ = [
-    "KERNEL_IMPLS", "KERNEL_OPS", "KernelConfig", "clear_winners",
-    "dispatch", "get_kernel", "get_kernel_config", "kernel_config",
-    "parse_kernels_config", "probe_report", "record_winner",
-    "resolve_impl", "set_kernel_config", "winner_for",
+    "KERNEL_IMPLS", "KERNEL_OPS", "KernelConfig", "dispatch", "get_kernel",
+    "get_kernel_config", "kernel_config", "probe_report", "resolve_impl",
 ]

@@ -1,38 +1,46 @@
-"""THE kernel-selection mechanism: op_builder-style registry of Pallas
-hot-loop implementations with jnp correctness oracles.
+"""Which kernel a call runs: the one place that decides, from what the
+call can see.
 
-The paper's pitch — "csrc/transformer + sparse_attention kernels
-reimplemented as Pallas/XLA ops behind op_builder" — lands here.  Every
-hot inner loop that has a Pallas implementation registers a `KernelOp`
-with:
+Every hot inner loop that has a Pallas implementation registers a
+`KernelOp` (the reference's op_builder pattern) with:
 
 * `pallas(...)`   — the Pallas TPU kernel (runs under the Pallas
   interpreter off-TPU, which is how tier-1 pins parity on CPU);
-* `oracle(...)`   — the pre-existing jnp expression, kept bit-for-bit
+* `oracle(...)`   — the plain jnp expression of the same mathematics
   (it IS the correctness contract: exact for the integer codecs and MoE
   permutations, tolerance-bounded for attention);
-* `is_compatible()` / `compatibility_message()` — op_builder-style
-  capability probing: Pallas is only *selected* natively on a TPU
-  backend, gated per-op by `DS_KERNEL_{NAME}=0` (the `DS_BUILD_*`
-  convention from ops/op_builder/builder.py);
-* `auto_supports(...)` — the per-call shape rule: what the chip's
+* `is_compatible()` / `compatibility_message()` — the probe: a TPU
+  backend, and the op's `DS_KERNEL_{NAME}` environment switch not "0"
+  (the `DS_BUILD_*` convention from ops/op_builder/builder.py, and the
+  one override an operator has);
+* `auto_supports(variant, info)` — the per-call shape rule over the
+  `info` dict the call site builds from its operands: what the chip's
   compiler takes (tests/test_tpu_compile.py compiles every case for a
-  described v5e) plus each op's own limits (e.g. sparse attention's
-  block%128 / head-dim tiling rule).
+  described v5e) and where the kernel beats the oracle on the chip.
 
-Selection contract (`resolve_impl`):
+    call site (ops/transformer/attention.py, serving/layers.py,
+               runtime/comm/quant.py, moe/dispatch.py, ops/sparse_attention/)
+       └─> dispatch(op, *args, info=<shape facts of this call>)
+              └─> pallas  iff  TPU backend  and  op.auto_supports(info)
+                               and  partitionable here
+                               and  DS_KERNEL_<OP> != 0
+                  oracle  otherwise
 
-* `"auto"`  — pallas iff the probe AND the shape rule pass, unless the
-  autotuner recorded the oracle as the winner on this fabric (see
-  `record_winner`); otherwise the jnp oracle.  Nothing `auto` selects
-  may be refused by the compiler.
+A forced implementation exists in two forms only: the call-site `impl=`
+(what a model config's `attn_impl`, `SparseSelfAttention(impl=)` carry)
+and the scoped `with kernel_config(...)` that tests open.
+Nothing is installed for the process by an engine, a config file or a
+tuner.
+
+* `"auto"`  — as drawn above.  Nothing `auto` selects may be refused by
+  the compiler.
 * `"pallas"` — the kernel, NO silent fallback: on a TPU a call the
   probe or the shape rule refuses raises with that reason, and the
   compiler is never asked; off-TPU this raises loudly unless the
-  interpret escape is set (`kernels.interpret=true` in the config, or
-  the call-site `interpret_ok=True` that preserves
-  `SparseSelfAttention(impl="pallas")`'s historical run-the-kernel-
-  under-the-interpreter semantics).
+  interpret escape is set (`kernel_config(interpret=True)`, or the
+  call-site `interpret_ok=True` with which training attention and
+  `SparseSelfAttention` keep running a forced kernel under the
+  interpreter).
 * `"jnp"` (alias `"xla"`) — the oracle, unconditionally.
 
 Every `dispatch()` bumps `kernel.dispatches` (pallas chosen) or
@@ -41,13 +49,8 @@ TRACE-time counts — once per compiled program per call site, not per
 execution — so a decode program that retraces shows exactly its
 per-layer dispatch count (docs/tutorials/kernels.md).
 
-Config install mirrors moe/dispatch.py's wire config: the engine
-installs the parsed `"kernels"` block process-globally at initialize();
-direct users scope overrides with the `kernel_config(...)` context
-manager.  Implementation modules (`flash`, `quant_codec`,
-`moe_kernels`, `paged`, `eva`) are imported lazily from the op methods so the
-registry itself stays import-cycle-free (config validation can name
-the op set without dragging in jax kernels).
+Implementation modules are imported lazily from the op methods so the
+registry itself stays import-cycle-free.
 """
 
 from __future__ import annotations
@@ -61,7 +64,6 @@ import jax
 
 from ..monitor.counters import COUNTERS
 from ..ops import pallas_backend
-from ..utils.logging import logger
 
 KERNEL_IMPLS = ("auto", "pallas", "jnp")
 # legacy spelling accepted at call sites (SparseSelfAttention's
@@ -78,23 +80,6 @@ _BLOCK_RULE = ("\"The Pallas TPU lowering currently requires that the "
 
 def _on_tpu() -> bool:
     return not pallas_backend.interpret()
-
-
-def _unpartitionable() -> str:
-    """Why a native kernel cannot be traced HERE, or "".  XLA does not
-    partition a Mosaic kernel: under `jit` over a mesh of several
-    devices the call must sit inside a `shard_map` that is manual over
-    every axis of size > 1 (the wire codecs do; training attention gets
-    one from ops/transformer/attention.py)."""
-    from ..comm.mesh import peek_mesh
-
-    info = peek_mesh()
-    auto = info.auto_axes() if info is not None else []
-    if not auto:
-        return ""
-    return (f"the call is traced outside a shard_map over mesh axes "
-            f"{auto}, and \"Mosaic kernels cannot be automatically "
-            f"partitioned\"")
 
 
 # ---------------------------------------------------------------------------
@@ -139,6 +124,21 @@ class KernelOp:
         the oracle and a forced `pallas` raises the reason."""
         return True, ""
 
+    def unpartitionable(self) -> str:
+        """Why a native kernel cannot be traced HERE, or "".  XLA does
+        not partition a Mosaic kernel: under `jit` over a mesh of several
+        devices the call must sit inside a `shard_map` that is manual
+        over every axis of size > 1 (the wire codecs' calls do)."""
+        from ..comm.mesh import peek_mesh
+
+        info = peek_mesh()
+        auto = info.auto_axes() if info is not None else []
+        if not auto:
+            return ""
+        return (f"the call is traced outside a shard_map over mesh axes "
+                f"{auto}, and \"Mosaic kernels cannot be automatically "
+                f"partitioned\"")
+
     def check_variant(self, variant: str) -> None:
         if variant not in self.VARIANTS:
             raise ValueError(
@@ -152,31 +152,60 @@ class KernelOp:
         raise NotImplementedError
 
 
+# Below this XLA's fused attention wins.  Measured on a v5e, forward +
+# backward of 16k tokens at 25 heads of 64, bf16 (PERF.md §6, PR 27):
+# S 256: XLA 1.02 ms, flash 1.51; S 512: XLA 2.43, flash 1.55; S 1024:
+# XLA 4.69, flash 1.99.  (384 is not measured and stays with XLA.)
+_FLASH_MIN_SEQ = 512
+
+
 class FlashAttentionOp(KernelOp):
-    """Dense causal flash attention blocks (op 4): wraps
-    ops/transformer/flash_attention.flash_attention; oracle is the
-    plain jnp softmax attention it streams."""
+    """Dense attention of a training step, causal or full (op 4):
+    ops/transformer/flash_attention.py's kernels against
+    `xla_attention`, the fp32-softmax einsum chain.  Both take BSHD
+    `[batch, seq, heads, head_dim]`; parity is tolerance-bounded (online
+    softmax over tiles against one fused softmax).  The shape rule reads
+    `ops/transformer/attention.py::flash_info`: the two lengths, the
+    head size and the kind of bias."""
 
     NAME = "flash_attention"
 
     def auto_supports(self, variant, info):
         if not info:
             return True, ""
-        bq = int(info.get("block_q", 128))
-        bk = int(info.get("block_k", 128))
-        s, sk = int(info.get("seq_len", bq)), int(info.get("kv_len", bk))
+        if info.get("bias", "none") == "full":
+            return False, ("the kernel adds a per-key bias [B, 1, 1, Sk] "
+                           "only, and returns no cotangent for it: a full "
+                           "[.., S, Sk] bias, or one being differentiated, "
+                           "goes through XLA")
+        s, sk = int(info["seq_len"]), int(info["kv_len"])
+        if s < _FLASH_MIN_SEQ:
+            return False, (f"seq_len {s} < {_FLASH_MIN_SEQ}: XLA's fused "
+                           f"attention is faster on the chip")
+        d = int(info["head_dim"])
+        if d not in (64, 128, 256):
+            return False, f"head_dim {d} not in (64, 128, 256)"
+        from ..ops.transformer.flash_attention import flash_blocks
+
+        bq, bk = flash_blocks(s, sk)
         if s % bq or sk % bk:
-            return False, (f"seq lens ({s},{sk}) not divisible by "
-                           f"blocks ({bq},{bk})")
+            return False, (f"seq lens ({s},{sk}) are not whole tiles of "
+                           f"({bq},{bk}), the multiples of 128 that "
+                           f"`flash_blocks` cuts them into")
         return True, ""
 
+    def unpartitionable(self) -> str:
+        # `pallas()` calls the kernel once per shard, under a shard_map
+        # of its own over whatever mesh axes are not manual already
+        return ""
+
     def pallas(self, variant, *args, **kwargs):
-        from . import flash
-        return flash.flash_attention_pallas(*args, **kwargs)
+        from ..ops.transformer import attention
+        return attention.flash_per_shard(*args, **kwargs)
 
     def oracle(self, variant, *args, **kwargs):
-        from . import flash
-        return flash.flash_attention_reference(*args, **kwargs)
+        from ..ops.transformer import attention
+        return attention.xla_oracle(*args, **kwargs)
 
 
 class SparseAttentionOp(KernelOp):
@@ -394,80 +423,31 @@ def get_kernel(name: str) -> KernelOp:
 
 
 # ---------------------------------------------------------------------------
-# config (the validated "kernels" block; installed like the moe wire)
+# the scoped override (tests)
 # ---------------------------------------------------------------------------
 
 
 @dataclasses.dataclass(frozen=True)
 class KernelConfig:
-    """Process-global kernel selection.  The default-constructed config
-    is the shipping behaviour: auto-probe per op, counters on, no
-    interpret escape."""
+    """What `with kernel_config(...)` holds while it is open.  The
+    default-constructed config is what every call outside one sees:
+    auto-probe per op, no interpret escape."""
 
-    impl: str = "auto"                 # global default: auto|pallas|jnp
+    impl: str = "auto"                 # default for every op
     ops: Mapping[str, str] = dataclasses.field(default_factory=dict)
     interpret: bool = False            # allow forced pallas off-TPU
-    counters: bool = True
 
     def impl_for(self, name: str) -> str:
         return self.ops.get(name, self.impl)
 
-    def describe(self) -> str:
-        per_op = ", ".join(f"{k}={v}" for k, v in sorted(self.ops.items()))
-        return (f"kernels: impl={self.impl}"
-                + (f", {per_op}" if per_op else "")
-                + (", interpret" if self.interpret else ""))
 
-
-def parse_kernels_config(d) -> KernelConfig:
-    """Validate the `"kernels"` config block -> KernelConfig.  Unknown
-    keys, unknown OP NAMES, and invalid impl values all raise HERE — at
-    config time, naming the valid set, never inside a traced program."""
-    d = d or {}
-    if not isinstance(d, dict):
+def _impl_value(key: str, v) -> str:
+    v = str(v).lower()
+    v = _IMPL_ALIASES.get(v, v)
+    if v not in KERNEL_IMPLS:
         raise ValueError(
-            f"kernels must be an object, got {type(d).__name__}")
-    known = {"impl", "ops", "interpret", "counters"}
-    unknown = set(d) - known
-    if unknown:
-        raise ValueError(
-            f"kernels: unknown key(s) {sorted(unknown)}; expected a "
-            f"subset of {sorted(known)}")
-
-    def impl_value(key, v):
-        v = str(v).lower()
-        v = _IMPL_ALIASES.get(v, v)
-        if v not in KERNEL_IMPLS:
-            raise ValueError(
-                f"kernels.{key} must be one of {KERNEL_IMPLS}, "
-                f"got {v!r}")
-        return v
-
-    impl = impl_value("impl", d.get("impl", "auto"))
-
-    ops_d = d.get("ops", {})
-    if not isinstance(ops_d, dict):
-        raise ValueError(
-            f"kernels.ops must be an object mapping op name -> impl, "
-            f"got {type(ops_d).__name__}")
-    ops = {}
-    for name, v in ops_d.items():
-        if name not in KERNEL_OPS:
-            raise ValueError(
-                f"kernels.ops: unknown op {name!r}; registered ops: "
-                f"{sorted(KERNEL_OPS)}")
-        ops[name] = impl_value(f"ops.{name}", v)
-
-    interpret = d.get("interpret", False)
-    if not isinstance(interpret, bool):
-        raise ValueError(
-            f"kernels.interpret must be a bool, got {interpret!r}")
-    counters = d.get("counters", True)
-    if not isinstance(counters, bool):
-        raise ValueError(
-            f"kernels.counters must be a bool, got {counters!r}")
-    return KernelConfig(impl=impl, ops=ops, interpret=interpret,
-                        counters=counters)
+            f"kernels.{key} must be one of {KERNEL_IMPLS}, got {v!r}")
+    return v
 
 
 _KERNEL_CONFIG = KernelConfig()
@@ -477,74 +457,30 @@ def get_kernel_config() -> KernelConfig:
     return _KERNEL_CONFIG
 
 
-def set_kernel_config(cfg: KernelConfig) -> KernelConfig:
-    """Install `cfg` process-globally; returns the previous config.
-    Like the moe wire config, selection is read at TRACE time — a
-    config swap affects programs traced after it, never cached ones."""
-    global _KERNEL_CONFIG
-    prev = _KERNEL_CONFIG
-    _KERNEL_CONFIG = cfg
-    if cfg != prev:
-        logger.debug(cfg.describe())
-    return prev
-
-
 @contextlib.contextmanager
-def kernel_config(cfg: Optional[KernelConfig] = None, **kwargs):
-    """Scoped kernel config for direct users / tests:
+def kernel_config(impl: str = "auto", ops: Optional[Mapping] = None,
+                  interpret: bool = False):
+    """Scoped override for tests:
     `with kernel_config(impl="jnp"): ...` or
     `with kernel_config(ops={"quant_codec": "pallas"}, interpret=True)`.
-    Keyword form routes through the REAL validator."""
-    if cfg is None:
-        cfg = parse_kernels_config(kwargs)
-    prev = set_kernel_config(cfg)
-    try:
-        yield get_kernel_config()
-    finally:
-        set_kernel_config(prev)
-
-
-# ---------------------------------------------------------------------------
-# autotuner winner table (the `kernel` scope's output)
-# ---------------------------------------------------------------------------
-
-# op name -> {"impl": "pallas"|"jnp", "fingerprint": dict|None}
-_WINNERS: Dict[str, Dict] = {}
-
-
-def record_winner(name: str, impl: str,
-                  fingerprint: Optional[Mapping] = None) -> None:
-    """Install an autotuner-measured per-op choice.  `fingerprint` is a
-    `kernel_fingerprint(...)` dict; at resolution time the winner only
-    applies while its `fabric` section still matches the live fabric —
-    a backend/device change invalidates it (measured-not-assumed, the
-    PR-14 contract)."""
-    get_kernel(name)
-    impl = _IMPL_ALIASES.get(str(impl).lower(), str(impl).lower())
-    if impl not in ("pallas", "jnp"):
+    An unknown op name or impl value raises here, naming the valid set,
+    never inside a traced program.  Selection is read at TRACE time: the
+    scope decides for programs traced inside it, never for cached ones."""
+    global _KERNEL_CONFIG
+    if not isinstance(interpret, bool):
         raise ValueError(
-            f"kernel winner impl must be 'pallas' or 'jnp', got {impl!r}")
-    _WINNERS[name] = {"impl": impl,
-                      "fingerprint": dict(fingerprint) if fingerprint
-                      else None}
-
-
-def clear_winners() -> None:
-    _WINNERS.clear()
-
-
-def winner_for(name: str) -> Optional[str]:
-    """The recorded winner impl for `name`, or None when absent or
-    recorded on a different fabric."""
-    w = _WINNERS.get(name)
-    if w is None:
-        return None
-    fp = w["fingerprint"]
-    if fp is not None:
-        from ..runtime.autotune.fingerprint import fabric_section
-        if fp.get("fabric") != fabric_section():
-            return None
-    return w["impl"]
+            f"kernels.interpret must be a bool, got {interpret!r}")
+    pins = {}
+    for name, v in dict(ops or {}).items():
+        get_kernel(name)
+        pins[name] = _impl_value(f"ops.{name}", v)
+    cfg = KernelConfig(impl=_impl_value("impl", impl), ops=pins,
+                       interpret=interpret)
+    prev, _KERNEL_CONFIG = _KERNEL_CONFIG, cfg
+    try:
+        yield cfg
+    finally:
+        _KERNEL_CONFIG = prev
 
 
 # ---------------------------------------------------------------------------
@@ -568,7 +504,7 @@ def resolve_impl(name: str, variant: str = "default",
             f"got {choice!r}")
     supported, why = op.auto_supports(variant, info)
     if supported and _on_tpu():
-        why = _unpartitionable()
+        why = op.unpartitionable()
         supported = not why
     if choice == "pallas":
         if _on_tpu():
@@ -583,16 +519,14 @@ def resolve_impl(name: str, variant: str = "default",
             raise RuntimeError(
                 f"kernels.{name}: impl='pallas' forced but "
                 f"{op.compatibility_message()}; use impl='auto' for the "
-                f"jnp fallback, or set kernels.interpret=true to run "
-                f"the kernel under the Pallas interpreter (tests/bench)")
+                f"jnp fallback, or `with kernel_config(interpret=True)` "
+                f"to run the kernel under the Pallas interpreter (tests)")
         return "pallas"
     if choice == "jnp":
         return "jnp"
-    # auto: the kernel where it runs natively and the compiler takes the
-    # shape, unless the autotuner measured the oracle faster here
-    if op.is_compatible() and supported and winner_for(name) != "jnp":
-        return "pallas"
-    return "jnp"
+    # auto: the kernel where it runs natively and the shape rule takes
+    # the call
+    return "pallas" if op.is_compatible() and supported else "jnp"
 
 
 def dispatch(name: str, *args, variant: str = "default",
@@ -605,9 +539,8 @@ def dispatch(name: str, *args, variant: str = "default",
     op = get_kernel(name)
     chosen = resolve_impl(name, variant, impl=impl,
                           interpret_ok=interpret_ok, info=info)
-    if get_kernel_config().counters:
-        COUNTERS.add("kernel.dispatches" if chosen == "pallas"
-                     else "kernel.fallbacks")
+    COUNTERS.add("kernel.dispatches" if chosen == "pallas"
+                 else "kernel.fallbacks")
     if chosen == "pallas":
         return op.pallas(variant, *args, **kwargs)
     return op.oracle(variant, *args, **kwargs)

@@ -56,9 +56,10 @@ class GPTConfig:
                                      # HBM; invalid with vocab-parallel TP)
     remat: bool = False              # per-block rematerialisation
     shard_activations: bool = True   # seq/data sharding constraints
-    attn_impl: str = "auto"          # auto|pallas|xla (ops/transformer)
-    flash_block_q: int = 0           # 0 -> kernel default
-    flash_block_k: int = 0
+    attn_impl: str = "auto"          # auto|pallas|xla (kernels/registry)
+    flash_block_q: int = 0           # ring attention's query tile: bounds
+                                     # its score memory (0: untiled); the
+                                     # flash kernel tiles itself
     param_dtype: Any = jnp.float32
     pipeline_stages: int = 1         # >1: stack blocks + pipeline over `pipe`
     pipeline_micro_batches: int = 0  # 0 -> default (= pipe size)
@@ -263,9 +264,7 @@ def gpt_block(x, p, cfg: GPTConfig, rng=None, train=True):
         attn = ulysses_attention(
             split_heads(q), split_heads(kk), split_heads(v),
             multihead_attention, causal=True, impl=cfg.attn_impl,
-            dropout_rate=attn_rate, dropout_rng=r1, train=train,
-            block_q=cfg.flash_block_q or None,
-            block_k=cfg.flash_block_k or None)
+            dropout_rate=attn_rate, dropout_rng=r1, train=train)
     elif cfg.sequence_parallel:
         if cfg.sequence_parallel_impl not in ("ring", "ring_zigzag"):
             raise ValueError(
@@ -292,17 +291,14 @@ def gpt_block(x, p, cfg: GPTConfig, rng=None, train=True):
             split_heads(q), split_heads(kk), split_heads(v), causal=True,
             layout=("zigzag" if cfg.sequence_parallel_impl == "ring_zigzag"
                     else "contiguous"),
-            # same config knob as the flash kernel: bounds per-step score
-            # memory at [B, H, block_q, chunk]
+            # bounds per-step score memory at [B, H, block_q, chunk]
             block_q=cfg.flash_block_q)
     else:
         attn = multihead_attention(split_heads(q), split_heads(kk),
                                    split_heads(v), causal=True,
                                    impl=cfg.attn_impl,
                                    dropout_rate=attn_rate,
-                                   dropout_rng=r1, train=train,
-                                   block_q=cfg.flash_block_q or None,
-                                   block_k=cfg.flash_block_k or None)
+                                   dropout_rng=r1, train=train)
     attn = attn.reshape(B, S, D)
     attn = attn @ p["attn"]["proj"]["w"].astype(h.dtype) + \
         p["attn"]["proj"]["b"].astype(h.dtype)

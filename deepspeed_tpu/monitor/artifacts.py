@@ -2,9 +2,9 @@
 
 Round-5 post-mortem: the on-TPU artifacts that proved a 0.41x regression
 were later deleted from the tree (commit 53f94f7), leaving docs pointing
-at files that no longer exist.  This module gives bench.py (and any
-other tool) ONE write path that always lands results in a committed,
-manifest-indexed directory: `bench_artifacts/runs/<stamp>_<metric>.json`
+at files that no longer exist.  This module gives every tool ONE write
+path that always lands results in a committed, manifest-indexed
+directory: `bench_artifacts/runs/<stamp>_<metric>.json`
 plus an append-only `manifest.jsonl` — deleting a result now requires
 editing the manifest too, which review catches."""
 

@@ -1,5 +1,5 @@
-"""Multi-head attention dispatch — Pallas flash attention on TPU, fused XLA
-elsewhere.
+"""Multi-head attention of a training step: the flash kernel or fused XLA,
+whichever the kernel registry picks for the call.
 
 Reference: the fused CUDA transformer kernel's attention core
 (/root/reference/csrc/transformer/ds_transformer_cuda.cpp:147-295 — QKV
@@ -7,7 +7,8 @@ strided-batch GEMM + softmax kernels + dropout). TPU-native design: one
 flash-attention Pallas kernel (ops/transformer/flash_attention.py) computes
 softmax(QK^T)V in VMEM-resident tiles without materialising the [S, S]
 score matrix; off-TPU (and for shapes the kernel doesn't tile) an XLA
-einsum path that the compiler fuses.
+einsum path that the compiler fuses.  `multihead_attention` says what
+the call looks like (`flash_info`) and `kernels/registry.py` chooses.
 
 Shapes follow [batch, seq, heads, head_dim] (BSHD).
 """
@@ -15,24 +16,10 @@ Shapes follow [batch, seq, heads, head_dim] (BSHD).
 from __future__ import annotations
 
 import math
-from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from .. import pallas_backend
-
-# Below this XLA's fused attention wins.  Measured on a v5e, forward +
-# backward of 16k tokens at 25 heads of 64, bf16 (PERF.md §6, PR 27):
-# S 256: XLA 1.02 ms, flash 1.51; S 512: XLA 2.43, flash 1.55; S 1024:
-# XLA 4.69, flash 1.99.  (384 is not measured and stays with XLA.)
-_FLASH_MIN_SEQ = 512
-
-
-def _on_tpu() -> bool:
-    return not pallas_backend.interpret()
-
 
 _AD_TRACER_NAMES = ("JVPTracer", "LinearizeTracer")
 
@@ -99,9 +86,11 @@ def xla_attention(q, k, v, causal=True, bias=None, dropout_rate=0.0,
     return out.astype(q.dtype)
 
 
-def _flash_per_shard(flash, q, k, v, key_bias, dropout_rng, bh_offset,
-                     **kw):
-    """Call the flash kernel once per shard of the current mesh.
+def flash_per_shard(q, k, v, *, bias=None, dropout_rng=None, bh_offset=0,
+                    **kw):
+    """The registry's `flash_attention` kernel: the flash kernel called
+    once per shard of the current mesh.  `bias` is None or per-key
+    (`flash_info` says "key").
 
     XLA cannot partition a Mosaic kernel: under `jit` over a mesh of
     more than one device the native lowering raises "Mosaic kernels
@@ -117,14 +106,16 @@ def _flash_per_shard(flash, q, k, v, key_bias, dropout_rng, bh_offset,
     from jax.sharding import PartitionSpec as P
 
     from ...comm.mesh import MODEL_AXIS, peek_mesh
+    from .flash_attention import flash_attention
 
     def call(q, k, v, key_bias, dropout_rng, bh_offset):
-        return flash(q, k, v, key_bias=key_bias, dropout_rng=dropout_rng,
-                     bh_offset=bh_offset, **kw)
+        return flash_attention(q, k, v, key_bias=key_bias,
+                               dropout_rng=dropout_rng,
+                               bh_offset=bh_offset, **kw)
 
     info = peek_mesh()
     if info is None or not info.auto_axes():
-        return call(q, k, v, key_bias, dropout_rng, bh_offset)
+        return call(q, k, v, bias, dropout_rng, bh_offset)
     manual = set(jax.sharding.get_abstract_mesh().manual_axes)
     B, H = q.shape[0], q.shape[2]
     data_axes = tuple(a for a in info.data_axes if a not in manual)
@@ -135,9 +126,9 @@ def _flash_per_shard(flash, q, k, v, key_bias, dropout_rng, bh_offset,
     heads = MODEL_AXIS if (mp > 1 and H % mp == 0 and dropout_rng is None
                            and MODEL_AXIS not in manual) else None
     qkv = P(batch, None, heads, None)
-    bias = None if key_bias is None else P(
-        batch if key_bias.shape[0] == B else None,
-        *([None] * (key_bias.ndim - 1)))
+    bias_spec = None if bias is None else P(
+        batch if bias.shape[0] == B else None,
+        *([None] * (bias.ndim - 1)))
 
     def body(q, k, v, key_bias, dropout_rng, bh_offset):
         if dropout_rng is not None and data_axes:
@@ -149,71 +140,21 @@ def _flash_per_shard(flash, q, k, v, key_bias, dropout_rng, bh_offset,
 
     return jax.shard_map(
         body, mesh=info.mesh,
-        in_specs=(qkv, qkv, qkv, bias, P(), P()), out_specs=qkv,
+        in_specs=(qkv, qkv, qkv, bias_spec, P(), P()), out_specs=qkv,
         axis_names=set(info.mesh.axis_names) - manual,
-        check_vma=False)(q, k, v, key_bias, dropout_rng,
+        check_vma=False)(q, k, v, bias, dropout_rng,
                          jnp.asarray(bh_offset, jnp.int32))
 
 
-def multihead_attention(q, k, v, causal: bool = True, impl: str = "auto",
-                        bias=None, dropout_rate: float = 0.0,
-                        dropout_rng=None, train: bool = False,
-                        scale: Optional[float] = None,
-                        block_q: Optional[int] = None,
-                        block_k: Optional[int] = None,
-                        bh_offset=0):
-    """Dispatching attention entry point used by the GPT family and the
-    DeepSpeedTransformerLayer.
-
-    impl: "auto" (pallas on TPU when tileable), "pallas", "xla".
-    The Pallas path applies probability dropout in-kernel (hash-generated
-    tile masks, no [S, S] materialisation) and accepts per-key additive
-    biases ([B, 1, 1, Sk] — the BERT padding-mask shape) in-kernel too;
-    only a full [.., S, Sk] bias (e.g. relative-position) routes to XLA.
-    """
-    B, S, D = q.shape[0], q.shape[1], q.shape[3]
-    Sk = k.shape[1]
-    want_dropout = train and dropout_rate > 0.0 and dropout_rng is not None
-    key_bias = None
-    if bias is not None and getattr(bias, "ndim", 0) == 4 \
-            and bias.shape[1] == 1 and bias.shape[2] == 1 \
-            and bias.shape[3] == Sk and bias.shape[0] in (1, B) \
-            and not _is_ad_tracer(bias):
-        key_bias = bias
-    use_pallas = False
-    if impl == "pallas":
-        # the flash kernel carries per-key biases only; honoring a full
-        # [.., S, Sk] bias wins over the impl request (silently dropping
-        # a mask is numerically wrong)
-        use_pallas = bias is None or key_bias is not None
-    elif impl == "auto":
-        use_pallas = (_on_tpu() and (bias is None or key_bias is not None)
-                      and S >= _FLASH_MIN_SEQ and S % 128 == 0
-                      and Sk % 128 == 0 and D in (64, 128, 256))
-    if use_pallas:
-        from .flash_attention import flash_attention, flash_blocks
-
-        auto_q, auto_k = flash_blocks(S, Sk)
-        bq, bk = block_q or auto_q, block_k or auto_k
-        if S % bq == 0 and Sk % bk == 0:
-            return _flash_per_shard(
-                flash_attention, q, k, v, key_bias,
-                dropout_rng if want_dropout else None, bh_offset,
-                causal=causal, scale=scale, block_q=bq, block_k=bk,
-                dropout_rate=dropout_rate if want_dropout else 0.0)
-        if block_q or block_k:
-            # explicit tuning request that cannot tile: say so instead of
-            # silently paying the O(S^2) XLA path
-            from ...utils.logging import logger
-
-            logger.warning(
-                f"flash blocks ({bq},{bk}) do not divide seq lens "
-                f"({S},{Sk}); falling back to XLA attention")
+def xla_oracle(q, k, v, *, bias=None, dropout_rate=0.0, dropout_rng=None,
+               bh_offset=0, **kw):
+    """The registry's `flash_attention` oracle: `xla_attention` over the
+    whole arrays (XLA partitions it), any bias."""
     try:
         offset_zero = int(bh_offset) == 0  # any concrete zero is a no-op
     except Exception:  # traced (e.g. axis_index): unknowable at dispatch
         offset_zero = False
-    if want_dropout and not offset_zero:
+    if dropout_rng is not None and not offset_zero:
         # the XLA path's dropout has no shard-offset notion — silently
         # dropping it would re-correlate the shard masks the caller is
         # explicitly decorrelating
@@ -223,6 +164,57 @@ def multihead_attention(q, k, v, causal: bool = True, impl: str = "auto",
             "shapes, a full bias, or a differentiated bias) with dropout "
             "active — use impl='pallas' with tileable shapes, or drop "
             "bh_offset")
-    return xla_attention(q, k, v, causal=causal, bias=bias,
-                         dropout_rate=dropout_rate, dropout_rng=dropout_rng,
-                         train=train, scale=scale)
+    return xla_attention(q, k, v, bias=bias, dropout_rate=dropout_rate,
+                         dropout_rng=dropout_rng,
+                         train=dropout_rng is not None, **kw)
+
+
+def flash_info(q, k, bias=None) -> dict:
+    """What the registry's shape rule may look at (kernels/registry.py,
+    `FlashAttentionOp.auto_supports`): the lengths, the head size, and
+    whether the bias is one the kernel can add — per key, [B or 1, 1, 1,
+    Sk], the BERT padding-mask shape, and not being differentiated."""
+    B, Sk = q.shape[0], k.shape[1]
+    kind = "none"
+    if bias is not None:
+        keyed = (getattr(bias, "ndim", 0) == 4
+                 and bias.shape[1] == 1 and bias.shape[2] == 1
+                 and bias.shape[3] == Sk and bias.shape[0] in (1, B)
+                 and not _is_ad_tracer(bias))
+        kind = "key" if keyed else "full"
+    return {"seq_len": q.shape[1], "kv_len": Sk, "head_dim": q.shape[3],
+            "bias": kind}
+
+
+def multihead_attention(q, k, v, causal: bool = True, impl: str = "auto",
+                        bias=None, dropout_rate: float = 0.0,
+                        dropout_rng=None, train: bool = False,
+                        scale: Optional[float] = None,
+                        bh_offset=0):
+    """Attention entry point used by the GPT family and the
+    DeepSpeedTransformerLayer.
+
+    impl: "auto" (the kernel registry's rule for `flash_attention`),
+    "pallas", "xla".
+    The Pallas path applies probability dropout in-kernel (hash-generated
+    tile masks, no [S, S] materialisation) and accepts per-key additive
+    biases ([B, 1, 1, Sk] — the BERT padding-mask shape) in-kernel too;
+    only a full [.., S, Sk] bias (e.g. relative-position) routes to XLA.
+    """
+    from ...kernels import registry
+
+    info = flash_info(q, k, bias)
+    if impl == "auto":
+        impl = None  # the registry's rule, or the scope a test opened
+    if info["bias"] == "full":
+        # the flash kernel carries per-key biases only; honoring a full
+        # [.., S, Sk] bias wins over the impl request (silently dropping
+        # a mask is numerically wrong)
+        impl = "xla"
+    want_dropout = train and dropout_rate > 0.0 and dropout_rng is not None
+    return registry.dispatch(
+        "flash_attention", q, k, v, impl=impl, interpret_ok=True,
+        info=info, causal=causal, scale=scale, bias=bias,
+        dropout_rate=dropout_rate if want_dropout else 0.0,
+        dropout_rng=dropout_rng if want_dropout else None,
+        bh_offset=bh_offset)

@@ -15,9 +15,7 @@ cached, live-retunable artifact:
                   winner cache — a cache probed on a different mesh
                   factorization, dtype config or world size must
                   re-probe loudly, never pin silently
-  cache.py        the persisted winner cache (bench_artifacts/
-                  autotune.json-style single-entry mode for bench.py,
-                  fingerprint-keyed map mode for the engine driver)
+  cache.py        the persisted winner cache, keyed by fingerprint
   driver.py       the generic search driver: budgeted probe loop,
                   failure-tolerant (a probe that OOMs is skipped, never
                   fatal), scorer combining achieved throughput with the

@@ -1,14 +1,8 @@
 """The persisted winner cache.
 
-Two on-disk shapes, one API:
-
-* mode="single" — the historical `bench_artifacts/autotune.json` shape:
-  ONE flat entry `{**winner, "probes": [...], "fingerprint": {...}}`.
-  bench.py keeps writing/reading this exact format through the shared
-  driver, so committed bench artifacts stay comparable across rounds.
-* mode="map" — the engine driver's shape: entries keyed by fingerprint
-  digest, each `{"fingerprint", "winner", "trace", "written_unix"}`, so
-  one file serves many (model, mesh, fabric) combinations.
+Entries are keyed by fingerprint digest, each `{"fingerprint",
+"winner", "trace", "written_unix"}`, so one file serves many (model,
+mesh, fabric) combinations.
 
 Invalidation contract (tested): a lookup whose stored fingerprint
 differs from the caller's NEVER pins the run — it logs WHAT changed
@@ -28,12 +22,8 @@ from .fingerprint import fingerprint_diff
 
 
 class WinnerCache:
-    def __init__(self, path: Optional[str], mode: str = "map"):
-        if mode not in ("map", "single"):
-            raise ValueError(
-                f"WinnerCache mode must be 'map' or 'single', got {mode!r}")
+    def __init__(self, path: Optional[str]):
         self.path = path
-        self.mode = mode
 
     # -- IO ------------------------------------------------------------
 
@@ -74,17 +64,6 @@ class WinnerCache:
         data = self._read()
         if data is None:
             return None
-        if self.mode == "single":
-            stored = data.get("fingerprint")
-            if stored == fingerprint:
-                return data
-            if stored is not None:
-                changed = fingerprint_diff(stored, fingerprint)
-                logger.warning(
-                    "autotune cache: stale fingerprint (changed: "
-                    f"{', '.join(changed) or 'structure'}) — cached winner "
-                    "discarded, re-probing")
-            return None
         digest = fingerprint.get("digest", "")
         entry = (data.get("entries") or {}).get(digest)
         if entry is None:
@@ -116,10 +95,6 @@ class WinnerCache:
     def store(self, fingerprint: Dict[str, Any], winner: Dict[str, Any],
               trace: Optional[List[Dict[str, Any]]] = None) -> None:
         if not self.path:
-            return
-        if self.mode == "single":
-            self._write({**winner, "probes": trace or [],
-                         "fingerprint": fingerprint})
             return
         data = self._read() or {}
         entries = data.get("entries") or {}

@@ -1,13 +1,11 @@
 """The generic search driver: a budgeted, failure-tolerant probe loop.
 
 Deliberately knows nothing about engines — `probe_fn(candidate)` is any
-callable returning a metrics dict, so the same driver serves bench.py's
-model-shape search (candidates are (size, micro, remat) tuples probed
-by building throwaway engines), tools/autotune_bench.py's synthetic
-cost surface, and the engine runtime's live StepBuilder probes.
+callable returning a metrics dict, so the same driver serves
+tools/autotune_bench.py's synthetic cost surface and the engine
+runtime's live StepBuilder probes.
 
-Probe discipline (inherited from bench.py's state machine, now owned
-here once):
+Probe discipline:
 
 * a probe is OPTIONAL: any failure (OOM, lowering error, transport
   fault) records the candidate as failed and moves on — the search
@@ -172,8 +170,8 @@ class SearchDriver:
     @property
     def complete(self) -> bool:
         """True when no probe failed or was budget-skipped — the only
-        state a winner may be CACHED from (bench.py's 'never pin future
-        rounds to a degraded probe' rule, now shared)."""
+        state a winner may be CACHED from (never pin future rounds to
+        a degraded probe)."""
         return all(r.ok for r in self.results)
 
     def trace(self) -> List[Dict[str, Any]]:

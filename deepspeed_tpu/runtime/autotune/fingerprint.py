@@ -44,29 +44,13 @@ def fingerprint_diff(a: Dict[str, Any], b: Dict[str, Any]) -> List[str]:
 
 def fabric_section() -> Dict[str, Any]:
     """The fabric half of a fingerprint: backend, device kind, device
-    count.  Kernel winners are keyed on exactly this — a Pallas-vs-jnp
-    measurement transfers across shapes on the same fabric but never
-    across a backend or device-kind change."""
+    count."""
     import jax
 
     devices = jax.devices()
     return {"backend": jax.default_backend(),
             "device_kind": devices[0].device_kind if devices else "?",
             "devices": len(devices)}
-
-
-def kernel_fingerprint(op: str, shape=None, dtype=None) -> Dict[str, Any]:
-    """Fingerprint one kernel-scope probe: which registered op was
-    measured, the representative shape/dtype it was lapped on, and the
-    fabric.  `registry.winner_for` honours a recorded winner only while
-    the `fabric` section still matches `fabric_section()` — the same
-    stale-loudly contract as the engine/serve winner caches."""
-    return make_fingerprint(
-        kernel={"op": str(op),
-                "shape": list(shape) if shape is not None else None,
-                "dtype": str(dtype) if dtype is not None else None},
-        fabric=fabric_section(),
-    )
 
 
 def _model_section(params) -> Dict[str, Any]:

@@ -156,8 +156,7 @@ class AutotuneRuntime:
         if batch is not None:
             eng._autotune_batch = eng._shard_batch(batch)
         fp = engine_fingerprint(eng)
-        cache = WinnerCache(cache_path or self.config.cache_path,
-                            mode="map")
+        cache = WinnerCache(cache_path or self.config.cache_path)
         if not force:
             hit = cache.lookup(fp)
             if self._consensus.world > 1:

@@ -28,14 +28,6 @@ Candidate scopes:
           serve candidate needs a fresh ServeEngine (the KV pool layout
           and the verify program are compile-time), so tools/serve_bench
           is the probe harness, never the online loop.
-  kernel  per-op Pallas-vs-jnp implementation pins for the kernel
-          registry (deepspeed_tpu.kernels).  The `comm` field carries a
-          "kernels"-block fragment ({"ops": {op: impl}}), validated
-          through the REAL `DeepSpeedKernelsConfig` by
-          `generate_kernel_candidates`; the winning pin is applied by
-          `kernels.registry.record_winner`, keyed to the fabric section
-          of the fingerprint, so a cache hit on a different backend
-          never forces a kernel the probe ran elsewhere.
 
 `safe_numerics`: True when swapping to the candidate preserves the
 repo's bitwise loss contract on this fabric — every wire level fp32
@@ -60,10 +52,6 @@ _KNOB_FIELDS = ("gradient_reduction", "wire_dtype", "wire_dtype_inner",
 _SERVE_KNOB_FIELDS = ("kv_dtype", "draft_len", "prefix_cache",
                       "min_match_blocks", "session_ttl_s")
 
-# the kernel scope's knob view: one synthetic field holding the sorted
-# (op, impl) pin tuple, so distance counts per-op pin differences
-_KERNEL_KNOB_FIELDS = ("kernel_ops",)
-
 
 class Candidate(NamedTuple):
     """One point in the legal config space."""
@@ -72,16 +60,13 @@ class Candidate(NamedTuple):
     comm: Dict            # "comm"-block fragment the engine applies
     #                       ("serving" fragment when scope == "serve")
     stage: int = 0        # ZeRO stage the legality check ran against
-    scope: str = "live"   # "live" | "engine" | "serve" | "kernel"
+    scope: str = "live"   # "live" | "engine" | "serve"
     safe_numerics: bool = True
 
     def knobs(self) -> Dict:
         """Comparable knob view (absent keys normalized) — the
         neighborhood distance and ledger entries read this."""
         c = self.comm
-        if self.scope == "kernel":
-            ops = c.get("ops") or {}
-            return {"kernel_ops": tuple(sorted(ops.items()))}
         if self.scope == "serve":
             spec = c.get("speculative") or {}
             pfx = c.get("prefix_cache") or {}
@@ -109,10 +94,6 @@ class Candidate(NamedTuple):
 
     def describe(self) -> str:
         k = self.knobs()
-        if self.scope == "kernel":
-            pins = ", ".join(f"{op}={impl}"
-                             for op, impl in k["kernel_ops"]) or "auto"
-            return f"{self.name}: {pins}"
         if self.scope == "serve":
             parts = [f"kv {k['kv_dtype']}"]
             if k["draft_len"]:
@@ -144,9 +125,9 @@ _OPTIONAL_KNOBS = ("wire_dtype_inner", "wire_dtype_outer",
 
 
 def _scope_family(c: Candidate) -> str:
-    """Knob-space family: "serve" and "kernel" candidates each live in
-    their own space; "live"/"engine" share the train-side comm space."""
-    return c.scope if c.scope in ("serve", "kernel") else "train"
+    """Knob-space family: "serve" candidates live in their own space;
+    "live"/"engine" share the train-side comm space."""
+    return "serve" if c.scope == "serve" else "train"
 
 
 def knob_distance(a: Candidate, b: Candidate) -> int:
@@ -158,11 +139,6 @@ def knob_distance(a: Candidate, b: Candidate) -> int:
         # spaces — farther apart than any same-family pair can be
         return len(_KNOB_FIELDS) + len(_SERVE_KNOB_FIELDS)
     ka, kb = a.knobs(), b.knobs()
-    if a.scope == "kernel":
-        # one unit per op whose pin differs (absent = "auto")
-        da, db = dict(ka["kernel_ops"]), dict(kb["kernel_ops"])
-        return sum(1 for op in set(da) | set(db)
-                   if da.get(op, "auto") != db.get(op, "auto"))
     if a.scope == "serve":
         return sum(1 for f in _SERVE_KNOB_FIELDS if ka[f] != kb[f])
     dist = 0
@@ -397,48 +373,6 @@ def generate_serve_candidates(
                 out.append(Candidate(
                     name=name, comm=frag, scope="serve",
                     safe_numerics=kv in (None, "fp32", "float32")))
-    return out, rejected
-
-
-def generate_kernel_candidates(
-        op_names: Optional[Sequence[str]] = None,
-        impls: Sequence[str] = ("pallas", "jnp"),
-) -> Tuple[List[Candidate], int]:
-    """Enumerate the kernel-scope candidate set: one candidate per
-    (op, impl) pin over the registered kernel ops, each fragment run
-    through the REAL `DeepSpeedKernelsConfig` validator (the same
-    pruning contract as the comm and serve spaces: a typo'd op name or
-    impl value is rejected and counted, never probed).  `op_names=None`
-    enumerates every registered op; passing an explicit list lets a
-    bench sweep one op's pins — including invalid names, which prune
-    instead of raising, so the `autotune.rejected` counter stays the
-    single source of truth for space drift.
-
-    `safe_numerics` is True only for `quant_codec` pins: the codec's
-    Pallas path is pinned BIT-exact against its jnp oracle (both
-    variants), so swapping its pin preserves the bitwise wire contract.
-    Attention ops and the MoE combine are tolerance-bounded (FMA
-    fusion / reduction-order rounding), so their pins are probe-only
-    for the numerics-pinning online loop."""
-    from ..config import DeepSpeedKernelsConfig, DeepSpeedConfigError
-
-    if op_names is None:
-        from ...kernels.registry import KERNEL_OPS
-
-        op_names = sorted(KERNEL_OPS)
-    out: List[Candidate] = []
-    rejected = 0
-    for op in op_names:
-        for impl in impls:
-            frag = {"ops": {op: impl}}
-            try:
-                DeepSpeedKernelsConfig({"kernels": frag})
-            except (DeepSpeedConfigError, ValueError):
-                rejected += 1
-                continue
-            out.append(Candidate(
-                name=f"kern_{op}_{impl}", comm=frag, scope="kernel",
-                safe_numerics=(op == "quant_codec")))
     return out, rejected
 
 

@@ -1130,7 +1130,10 @@ class SocketExchange(_ExchangeBase):
         join = [self._accept_thread, self._kv_thread] + \
             [c.thread for c in conns] + list(self._aux_threads)
         for t in join:
-            if t is not None and t is not threading.current_thread():
+            # a re-dial worker is tracked before it is started (`ident`
+            # None until then); started later, it sees `_closed` and ends
+            if t is not None and t.ident is not None \
+                    and t is not threading.current_thread():
                 t.join(timeout=_CLOSE_JOIN_S)
         self._log_leaked([t for t in join
                           if t is not threading.current_thread()])

@@ -780,36 +780,6 @@ class DeepSpeedServingConfig(DeepSpeedConfigObject):
         }
 
 
-class DeepSpeedKernelsConfig(DeepSpeedConfigObject):
-    """The Pallas kernel registry's selection block
-    (deepspeed_tpu.kernels — reference analogue: op_builder's
-    DS_BUILD_* extension switches).
-
-    "kernels": {"impl": "auto", "ops": {}, "interpret": false,
-                "counters": true}
-
-    Validation delegates to `kernels.registry.parse_kernels_config` —
-    THE validator the registry's own context manager and the
-    autotuner's "kernel" scope also use, so an unknown op name or impl
-    value fails at config time naming the registered set, never inside
-    a traced program.  The engine installs the parsed `KernelConfig`
-    process-globally at initialize()."""
-
-    def __init__(self, param_dict):
-        super().__init__()
-        from ..kernels.registry import parse_kernels_config
-
-        try:
-            self.config = parse_kernels_config(
-                param_dict.get(c.KERNELS) or {})
-        except ValueError as e:
-            raise DeepSpeedConfigError(str(e))
-        self.impl = self.config.impl
-        self.ops = dict(self.config.ops)
-        self.interpret = self.config.interpret
-        self.counters = self.config.counters
-
-
 def get_fp16_enabled(param_dict):
     return get_scalar_param(param_dict.get(c.FP16, {}), c.FP16_ENABLED,
                             c.FP16_ENABLED_DEFAULT)
@@ -914,6 +884,12 @@ class DeepSpeedConfig(DeepSpeedConfigObject):
     # -- parsing ----------------------------------------------------------
 
     def _initialize_params(self, pd):
+        if "kernels" in pd:
+            raise DeepSpeedConfigError(
+                "unknown config block 'kernels': which kernel a call runs "
+                "is decided per call by deepspeed_tpu/kernels/registry.py "
+                "(a model config's attn_impl, or DS_KERNEL_<OP>=0 in the "
+                "environment, overrides it)")
         self.train_batch_size = get_scalar_param(pd, c.TRAIN_BATCH_SIZE,
                                                  c.TRAIN_BATCH_SIZE_DEFAULT)
         self.train_micro_batch_size_per_gpu = get_scalar_param(
@@ -961,10 +937,6 @@ class DeepSpeedConfig(DeepSpeedConfigObject):
         # dtype + self-speculative decoding — the autotuner's "serve"
         # scope searches this block
         self.serving_config = DeepSpeedServingConfig(pd)
-
-        # Pallas kernel registry selection (deepspeed_tpu.kernels) —
-        # the autotuner's "kernel" scope searches this block
-        self.kernels_config = DeepSpeedKernelsConfig(pd)
 
         # pipeline: use_p2p_channels forces the multi-host channel
         # executor even single-process (the driver's virtual-multichip

@@ -445,25 +445,6 @@ SERVING_FLEET_SESSION_AFFINITY = "session_affinity"
 SERVING_FLEET_SESSION_AFFINITY_DEFAULT = True
 
 #############################################
-# Kernels (deepspeed_tpu.kernels) — the Pallas hot-loop op registry
-# (reference analogue: the op_builder CUDA-extension switches).
-# "kernels": {
-#   "impl": "auto",            # global default: auto|pallas|jnp
-#   "ops": {},                 # per-op override, e.g. {"quant_codec": "pallas"}
-#   "interpret": false,        # let forced pallas run off-TPU (interpreter)
-#   "counters": true           # kernel.dispatches / kernel.fallbacks
-# }
-#############################################
-KERNELS = "kernels"
-KERNELS_IMPL = "impl"
-KERNELS_IMPL_DEFAULT = "auto"
-KERNELS_OPS = "ops"
-KERNELS_INTERPRET = "interpret"
-KERNELS_INTERPRET_DEFAULT = False
-KERNELS_COUNTERS = "counters"
-KERNELS_COUNTERS_DEFAULT = True
-
-#############################################
 # TPU-specific additions (no reference analogue)
 #############################################
 MESH = "mesh"  # {"data": -1, "model": 1, "pipe": 1, "seq": 1}

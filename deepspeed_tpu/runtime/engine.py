@@ -184,15 +184,6 @@ class DeepSpeedEngine:
         if self._config.comm_config.moe != _moe_dispatch.MoEWireConfig():
             log_dist(self._config.comm_config.moe.describe(), ranks=[0])
 
-        # Pallas kernel registry: install the validated "kernels" block
-        # the same way (selection is read at trace time, so this must
-        # precede the first compiled program) — kernels/registry.py
-        from ..kernels import registry as _kernel_registry
-
-        _kernel_registry.set_kernel_config(self._config.kernels_config.config)
-        if self._config.kernels_config.config != _kernel_registry.KernelConfig():
-            log_dist(self._config.kernels_config.config.describe(), ranks=[0])
-
         self.compute_dtype = DTYPES[self._config.precision]
         self.loss_scaler = create_loss_scaler(self._config)
 

@@ -552,7 +552,7 @@ class PagedKVCache:
         table = np.full((self.table_width,), TRASH_BLOCK, np.int32)
         self._owned[rid] = []
         self._booked[rid] = [table, int(n_blocks), 0]
-        return table
+        return table.copy()  # the booked one never leaves: see `extend`
 
     def _take_into(self, rid, table, entry: int) -> None:
         if table[entry] == TRASH_BLOCK:
@@ -564,14 +564,21 @@ class PagedKVCache:
     def extend(self, rid, start: int, stop: int) -> np.ndarray:
         """Before positions [start, stop) are written: take the exact
         blocks of those window offsets and the summary blocks of the
-        chunks they complete.  Never fails: the blocks were booked."""
+        chunks they complete.  Never fails: the blocks were booked.
+
+        -> a copy of the table as it now stands.  The booked table is
+        rewritten in place here and in `close_window`, and a program
+        that was handed it runs after its caller has returned (on the
+        CPU `jnp.asarray` may alias a host array, not copy it): a
+        prefill chunk that filled the window then read the table after
+        `close_window` had trashed it."""
         table = self._booked[rid][0]
         bs, wb = self.block_size, self.window_blocks
         for blk in range(int(start) // bs, -(-int(stop) // bs)):
             self._take_into(rid, table, blk % wb)
         for chunk in range(int(start) // bs, int(stop) // bs):
             self._take_into(rid, table, wb + chunk // bs)
-        return table
+        return table.copy()
 
     def close_window(self, rid) -> int:
         """The request's open window is full: its exact blocks go back

@@ -3,7 +3,7 @@
 The path is part of the cache's key, so it never carries a temporary
 name, a pid or a timestamp: a second run in the same checkout (or with
 the same `JAX_COMPILATION_CACHE_DIR`) finds what the first compiled.
-`chip_smoke.py` and `bench.py` call this before their first JAX use; the
+`chip_smoke.py` calls this before its first JAX use; the
 tests leave the cache off (tests/conftest.py says why).
 """
 

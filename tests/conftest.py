@@ -17,7 +17,7 @@ if "--xla_backend_optimization_level" not in os.environ["XLA_FLAGS"]:
     # the suite is compile-dominated on the 1-core box and every test is
     # a CORRECTNESS check (parity between two programs, both compiled the
     # same way) — O0 cuts wall-clock ~40% with identical pass/fail.
-    # Perf measurements (bench.py, tools/) do NOT go through conftest.
+    # Perf measurements (benchmarks/, tools/) do NOT go through conftest.
     os.environ["XLA_FLAGS"] += " --xla_backend_optimization_level=0"
 os.environ["JAX_PLATFORMS"] = "cpu"  # the tests never touch the chip
 
@@ -47,3 +47,14 @@ def _reset_mesh():
     from deepspeed_tpu.moe import dispatch as moe_dispatch
 
     moe_dispatch.set_wire_config(moe_dispatch.MoEWireConfig())
+
+
+@pytest.fixture
+def native(monkeypatch):
+    """What the package sees on the chip: kernels lower through Mosaic
+    and the kernel registry's probe says TPU.  Nothing can run or be
+    lowered for the CPU under it: a test traces, resolves, or compiles
+    for a described chip (tests/test_tpu_compile.py)."""
+    from deepspeed_tpu.ops import pallas_backend
+
+    monkeypatch.setattr(pallas_backend, "interpret", lambda: False)

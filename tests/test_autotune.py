@@ -189,7 +189,7 @@ def test_engine_fingerprint_stable_and_sensitive():
 
 def test_cache_map_roundtrip_and_loud_invalidation(tmp_path):
     path = str(tmp_path / "cache.json")
-    cache = WinnerCache(path, mode="map")
+    cache = WinnerCache(path)
     fp = make_fingerprint(mesh={"dp": 8}, fabric={"t": "x"})
     cache.store(fp, {"name": "flat_fp32"}, [{"candidate": "flat_fp32"}])
     hit = cache.lookup(fp)
@@ -205,21 +205,6 @@ def test_cache_map_roundtrip_and_loud_invalidation(tmp_path):
     with _Capture() as cap:
         assert cache.lookup(fp) is None
     assert any("unreadable" in m for m in cap.records)
-
-
-def test_cache_single_mode_is_bench_format(tmp_path):
-    path = str(tmp_path / "autotune.json")
-    cache = WinnerCache(path, mode="single")
-    fp = {"candidates": [["small", 8, False]], "seq": 1024,
-          "backend": "cpu"}
-    cache.store(fp, {"size": "small", "micro": 8, "remat": False,
-                     "attn_impl": "auto"}, [{"size": "small"}])
-    raw = json.load(open(path))
-    # the committed bench_artifacts/autotune.json shape, exactly
-    assert set(raw) == {"size", "micro", "remat", "attn_impl", "probes",
-                        "fingerprint"}
-    assert cache.lookup(fp)["micro"] == 8
-    assert cache.lookup({**fp, "seq": 31337}) is None
 
 
 # ---------------------------------------------------------------------------

@@ -170,3 +170,15 @@ def test_bf16_and_fp16_both_enabled_raises():
         DeepSpeedConfig({"train_batch_size": 8,
                          "bf16": {"enabled": True},
                          "fp16": {"enabled": True}}, world_size=1)
+
+
+
+def test_kernels_block_is_rejected_by_name():
+    """The block is gone: which kernel a call runs is no user setting.
+    A config that still carries it is told so, and where the choice
+    lives, instead of being silently ignored."""
+    with pytest.raises(DeepSpeedConfigError, match="'kernels'") as e:
+        DeepSpeedConfig({"train_batch_size": 8,
+                         "kernels": {"impl": "jnp"}}, world_size=1)
+    assert "kernels/registry.py" in str(e.value)
+    assert "DS_KERNEL_" in str(e.value)

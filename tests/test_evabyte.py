@@ -317,6 +317,25 @@ def test_two_kinds_of_row_under_one_allocator():
     assert kv.summary_rows_in_use == 0
 
 
+def test_a_table_handed_to_a_program_is_never_rewritten():
+    """A program reads its table when it RUNS, after the engine's call
+    has returned, and on the CPU `jnp.asarray` may alias the host array:
+    what `extend` hands out must not change under a later `extend` or
+    `close_window` (the prefill chunk that filled a window used to read
+    a table `close_window` had already trashed)."""
+    kv = _kv()
+    kv.reserve("a", kv.blocks_needed(64))
+    first = kv.extend("a", 0, 8)
+    seen = first.copy()
+    second = kv.extend("a", 8, 32)
+    assert np.array_equal(first, seen)
+    assert np.array_equal(second[:2], first[:2]) and (second[:8] != 0).all()
+    seen = second.copy()
+    kv.close_window("a")
+    assert np.array_equal(second, seen)
+    assert (kv.extend("a", 32, 33)[1:8] == 0).all()
+
+
 def test_reserve_refuses_what_the_pool_cannot_promise():
     kv = _kv(num_blocks=20)
     assert kv.reserve("a", 12) is not None

@@ -1,23 +1,20 @@
 """The Pallas kernel registry (deepspeed_tpu/kernels/).
 
-THE acceptance pins, per ISSUE 18:
-
 * every registered op's Pallas kernel matches its jnp oracle ON CPU
   (the kernel runs under the Pallas interpreter there) — BIT-exact for
   the quant codec (both wires, both directions, non-finite markers
   included) and the MoE dispatch permutation; tolerance-bounded for
   attention and the MoE combine (reduction-order / FMA rounding);
-* an unknown op name fails at CONFIG time naming the registered set,
-  never inside a traced program;
+* the choice is a function of what the call can see: backend, the
+  op's shape rule over the call's `info`, the mesh, and the op's
+  `DS_KERNEL_<OP>` environment switch — for every op, training
+  attention included;
+* an unknown op name fails where the scoped override is opened, naming
+  the registered set, never inside a traced program;
 * `impl="pallas"` forced off-TPU raises loudly unless the interpret
   escape is set;
 * `kernel.dispatches` / `kernel.fallbacks` count every resolution;
-* the autotuner's `kernel` scope enumerates per-op pins through the
-  REAL `DeepSpeedKernelsConfig` validator (invalid points pruned and
-  counted, never probed) and its fabric-keyed winner table overrides
-  the auto heuristic only while the fabric still matches;
-* `tools/kernel_bench.py --dry-run` runs every parity lane and records
-  a durable artifact.
+* nothing an engine does changes what a later trace selects.
 """
 
 import io
@@ -29,11 +26,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.kernels import (KERNEL_OPS, KernelConfig, clear_winners,
-                                   get_kernel_config, kernel_config,
-                                   parse_kernels_config, probe_report,
-                                   record_winner, registry, resolve_impl,
-                                   winner_for)
+from deepspeed_tpu.kernels import (KERNEL_OPS, get_kernel_config,
+                                   kernel_config, probe_report, registry,
+                                   resolve_impl)
 from deepspeed_tpu.monitor.counters import COUNTERS
 
 ON_TPU = jax.default_backend() == "tpu"
@@ -242,12 +237,12 @@ def test_paged_attention_slot_ignores_the_other_slots():
 
 
 def test_flash_attention_parity():
-    from deepspeed_tpu.kernels.flash import flash_attention_reference
+    from deepspeed_tpu.ops.transformer.attention import xla_attention
 
     rng = np.random.RandomState(0)
     q, k, v = (jnp.asarray(rng.randn(1, 128, 2, 128), jnp.float32)
                for _ in range(3))
-    ref = flash_attention_reference(q, k, v, causal=True)
+    ref = xla_attention(q, k, v, causal=True)
     with kernel_config(interpret=True):
         out = registry.dispatch("flash_attention", q, k, v,
                                 impl="pallas", causal=True)
@@ -285,19 +280,17 @@ def test_sparse_attention_module_auto_matches_oracle_off_tpu():
 
 def test_unknown_op_raises_at_config_time_naming_valid_set():
     with pytest.raises(ValueError) as e:
-        parse_kernels_config({"ops": {"flash_atention": "pallas"}})
+        with kernel_config(ops={"flash_atention": "pallas"}):
+            pass
     for name in sorted(KERNEL_OPS):
         assert name in str(e.value)
-
-    from deepspeed_tpu.runtime.config import (DeepSpeedConfigError,
-                                              DeepSpeedKernelsConfig)
-
-    with pytest.raises(DeepSpeedConfigError, match="registered ops"):
-        DeepSpeedKernelsConfig({"kernels": {"ops": {"nope": "jnp"}}})
-    with pytest.raises(ValueError, match="unknown key"):
-        parse_kernels_config({"implementation": "pallas"})
     with pytest.raises(ValueError, match="must be one of"):
-        parse_kernels_config({"impl": "triton"})
+        with kernel_config(impl="triton"):
+            pass
+    with pytest.raises(ValueError, match="must be one of"):
+        with kernel_config(ops={"quant_codec": "triton"}):
+            pass
+    assert get_kernel_config() == registry.KernelConfig()
 
 
 def test_dispatch_unknown_op_names_valid_set():
@@ -308,27 +301,6 @@ def test_dispatch_unknown_op_names_valid_set():
         registry.dispatch("quant_codec", 1, variant="encode")
 
 
-def test_full_config_round_trip_and_engine_install():
-    from deepspeed_tpu.runtime.config import DeepSpeedConfig
-
-    cfg = DeepSpeedConfig(
-        {"train_batch_size": 8,
-         "kernels": {"impl": "auto", "ops": {"quant_codec": "jnp"},
-                     "counters": False}}, world_size=1)
-    kc = cfg.kernels_config.config
-    assert kc == KernelConfig(impl="auto", ops={"quant_codec": "jnp"},
-                              counters=False)
-    assert kc.impl_for("quant_codec") == "jnp"
-    assert kc.impl_for("flash_attention") == "auto"
-
-    from deepspeed_tpu.runtime.config import DeepSpeedConfigError
-
-    with pytest.raises(DeepSpeedConfigError):
-        DeepSpeedConfig({"train_batch_size": 8,
-                         "kernels": {"ops": {"bogus": "pallas"}}},
-                        world_size=1)
-
-
 @pytest.mark.skipif(ON_TPU, reason="forced pallas is legal on TPU")
 def test_forced_pallas_off_tpu_raises_without_interpret_escape():
     x = jnp.zeros((256,), jnp.float32)
@@ -336,7 +308,7 @@ def test_forced_pallas_off_tpu_raises_without_interpret_escape():
         with pytest.raises(RuntimeError, match="interpret"):
             registry.dispatch("quant_codec", x, 128, "int8",
                               variant="quantize")
-    # the config-level escape runs the kernel under the interpreter
+    # the scoped escape runs the kernel under the interpreter
     with kernel_config(impl="pallas", interpret=True):
         p, s = registry.dispatch("quant_codec", x, 128, "int8",
                                  variant="quantize")
@@ -347,11 +319,93 @@ def test_forced_pallas_off_tpu_raises_without_interpret_escape():
                         interpret_ok=True) == "pallas"
 
 
-def test_env_switch_disables_native_selection(monkeypatch):
-    monkeypatch.setenv("DS_KERNEL_QUANT_CODEC", "0")
-    op = KERNEL_OPS["quant_codec"]
+def _traces_a_kernel(fn, *shapes) -> bool:
+    return "pallas_call" in str(jax.make_jaxpr(fn)(*shapes))
+
+
+def _training_attention(**kw):
+    from deepspeed_tpu.ops.transformer.attention import multihead_attention
+
+    qkv = (jax.ShapeDtypeStruct((4, 1024, 25, 64), jnp.bfloat16),) * 3
+    return _traces_a_kernel(
+        lambda q, k, v: multihead_attention(q, k, v, causal=True, **kw),
+        *qkv)
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_OPS))
+def test_env_gate_disables_every_op(name, native, monkeypatch):
+    """`DS_KERNEL_<OP>=0` is the one override an operator has, and it
+    holds for every op: `auto` takes the oracle and a forced kernel
+    raises the reason.  For training attention the gate is read where a
+    step reads it, through `multihead_attention`."""
+    op = KERNEL_OPS[name]
+    variant = op.VARIANTS[0]
+    selectable = op.is_compatible()   # eva, moe: no kernel for the chip
+    if name == "flash_attention":
+        assert _training_attention()
+    monkeypatch.setenv(f"DS_KERNEL_{name.upper()}", "0")
     assert not op.is_compatible()
-    assert "DS_KERNEL_QUANT_CODEC=0" in op.compatibility_message()
+    assert f"DS_KERNEL_{name.upper()}=0" in op.compatibility_message()
+    assert resolve_impl(name, variant) == "jnp"
+    with pytest.raises(RuntimeError, match="disabled via DS_KERNEL_"):
+        resolve_impl(name, variant, impl="pallas")
+    if name == "flash_attention":
+        assert not _training_attention()
+    monkeypatch.delenv(f"DS_KERNEL_{name.upper()}")
+    assert op.is_compatible() == selectable
+
+
+def test_training_attention_dispatches_through_registry(native):
+    """`multihead_attention` is a registry call like any other: the
+    trace-time counters move, and the scoped override reaches it."""
+    snap = COUNTERS.snapshot()
+    assert _training_attention()
+    d = COUNTERS.delta_since(snap)
+    assert d.get("kernel.dispatches", {}).get("calls", 0) == 1
+    assert "kernel.fallbacks" not in d
+
+    snap = COUNTERS.snapshot()
+    with kernel_config(ops={"flash_attention": "jnp"}):
+        assert not _training_attention()
+        assert _training_attention(impl="pallas")  # the call site's wins
+    d = COUNTERS.delta_since(snap)
+    assert d.get("kernel.fallbacks", {}).get("calls", 0) == 1
+    assert d.get("kernel.dispatches", {}).get("calls", 0) == 1
+
+
+def test_initialize_installs_no_kernel_state():
+    """An engine built between two traces does not change what the
+    second selects — two `initialize()` calls and a `ServeEngine` in one
+    process leave the registry as they found it."""
+    import deepspeed_tpu
+    from deepspeed_tpu.models import GPT, gpt2_config
+    from deepspeed_tpu.serving import ServeConfig, ServeEngine
+
+    x = jnp.zeros((256,), jnp.float32)
+
+    def selected():
+        snap = COUNTERS.snapshot()
+        with kernel_config(ops={"quant_codec": "pallas"}, interpret=True):
+            inside = get_kernel_config()
+            jax.make_jaxpr(lambda a: registry.dispatch(
+                "quant_codec", a, 128, "int8", variant="quantize"))(x)
+        d = COUNTERS.delta_since(snap)
+        return inside, d.get("kernel.dispatches", {}).get("calls", 0)
+
+    before = selected()
+    base = get_kernel_config()
+    model = GPT(gpt2_config("nano", max_seq_len=32))
+    cfg = {"train_batch_size": 8, "optimizer": {"type": "Adam"},
+           "steps_per_print": 0}
+    for _ in range(2):
+        deepspeed_tpu.initialize(model=model, config=dict(cfg))
+        assert get_kernel_config() == base
+    params = model.init(jax.random.PRNGKey(0))
+    ServeEngine(model, params, ServeConfig(
+        block_size=4, max_batch=2, max_seq_len=32, prefill_chunk=8,
+        num_blocks=17))
+    assert get_kernel_config() == base == registry.KernelConfig()
+    assert selected() == before
 
 
 def test_dispatch_counters_and_off_switch():
@@ -370,13 +424,6 @@ def test_dispatch_counters_and_off_switch():
     d = COUNTERS.delta_since(snap)
     assert d.get("kernel.dispatches", {}).get("calls", 0) == 1
 
-    snap = COUNTERS.snapshot()
-    with kernel_config(impl="jnp", counters=False):
-        registry.dispatch("quant_codec", x, 128, "int8",
-                          variant="quantize")
-    d = COUNTERS.delta_since(snap)
-    assert "kernel.fallbacks" not in d and "kernel.dispatches" not in d
-
 
 def test_kernel_config_context_restores():
     base = get_kernel_config()
@@ -387,94 +434,15 @@ def test_kernel_config_context_restores():
             assert inner.impl_for("moe_dispatch") == "pallas"
         assert get_kernel_config().impl == "jnp"
     assert get_kernel_config() == base
+    # ... on an exception too: nothing for conftest to reset
+    with pytest.raises(RuntimeError, match="boom"):
+        with kernel_config(impl="pallas", interpret=True):
+            raise RuntimeError("boom")
+    assert get_kernel_config() == base
 
 
 # ---------------------------------------------------------------------------
-# autotune kernel scope + winner table
-# ---------------------------------------------------------------------------
-
-
-def test_generate_kernel_candidates_through_real_validator():
-    from deepspeed_tpu.runtime.autotune.space import (
-        generate_kernel_candidates, knob_distance, neighborhood)
-
-    cands, rejected = generate_kernel_candidates()
-    assert rejected == 0
-    assert len(cands) == 2 * len(KERNEL_OPS)
-    names = {c.name for c in cands}
-    assert "kern_quant_codec_pallas" in names
-    for c in cands:
-        assert c.scope == "kernel"
-        # safe only for the bit-exact codec
-        assert c.safe_numerics == (c.name.startswith("kern_quant_codec"))
-
-    # invalid op names / impl values are PRUNED and counted, not raised
-    cands2, rejected2 = generate_kernel_candidates(
-        op_names=["quant_codec", "not_an_op"],
-        impls=("pallas", "jnp", "triton"))
-    assert [c.name for c in cands2] == ["kern_quant_codec_pallas",
-                                        "kern_quant_codec_jnp"]
-    assert rejected2 == 4
-
-    # distance: same op differing pin = 1; different ops = 2 (both
-    # differ from auto); radius-1 neighborhood is the same-op flip
-    a = next(c for c in cands if c.name == "kern_quant_codec_pallas")
-    b = next(c for c in cands if c.name == "kern_quant_codec_jnp")
-    m = next(c for c in cands if c.name == "kern_moe_dispatch_pallas")
-    assert knob_distance(a, b) == 1
-    assert knob_distance(a, m) == 2
-    assert [c.name for c in neighborhood(a, cands, radius=1)] == \
-        ["kern_quant_codec_jnp"]
-    assert "quant_codec=pallas" in a.describe()
-
-
-def test_kernel_scope_disjoint_from_train_and_serve_spaces():
-    from deepspeed_tpu.runtime.autotune.space import (
-        generate_candidates, generate_kernel_candidates,
-        generate_serve_candidates, knob_distance)
-
-    kern = generate_kernel_candidates()[0][0]
-    train = generate_candidates(8)[0][0]
-    serve = generate_serve_candidates(64)[0][0]
-    far = knob_distance(train, serve)
-    assert knob_distance(kern, train) == far
-    assert knob_distance(kern, serve) == far
-    assert far > max(knob_distance(kern, k2)
-                     for k2 in generate_kernel_candidates()[0])
-
-
-def test_winner_table_fabric_keyed():
-    from deepspeed_tpu.runtime.autotune.fingerprint import \
-        kernel_fingerprint
-
-    clear_winners()
-    try:
-        with pytest.raises(ValueError):
-            record_winner("nope", "pallas")
-        with pytest.raises(ValueError):
-            record_winner("quant_codec", "triton")
-
-        fp = kernel_fingerprint("quant_codec", shape=(1024,))
-        record_winner("quant_codec", "jnp", fingerprint=fp)
-        assert winner_for("quant_codec") == "jnp"
-        # a jnp winner pins the oracle even where auto would probe
-        assert resolve_impl("quant_codec", "quantize") == "jnp"
-
-        # same winner recorded on a DIFFERENT fabric no longer applies
-        stale = dict(fp, fabric=dict(fp["fabric"], backend="other"))
-        record_winner("quant_codec", "jnp", fingerprint=stale)
-        assert winner_for("quant_codec") is None
-
-        # a pallas winner never forces the kernel off its fabric
-        record_winner("moe_dispatch", "pallas", fingerprint=fp)
-        expect = "pallas" if ON_TPU else "jnp"
-        assert resolve_impl("moe_dispatch", "dispatch") == expect
-    finally:
-        clear_winners()
-
-
-# ---------------------------------------------------------------------------
-# surfaces: ds_report, probe report, bench dry-run
+# surfaces: ds_report, probe report
 # ---------------------------------------------------------------------------
 
 
@@ -499,32 +467,3 @@ def test_ds_report_kernels_section():
         assert name in text
     if not ON_TPU:
         assert "jnp-fallback" in text
-
-
-def test_kernel_bench_dry_run(tmp_path):
-    import importlib
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "tools"))
-    try:
-        bench = importlib.import_module("kernel_bench")
-    finally:
-        sys.path.pop(0)
-    result = bench.run_dry(str(tmp_path))
-    assert result["unit"] == "parity_lanes" and result["value"] == 11
-    for lane in ("flash_attention", "sparse_attention",
-                 "paged_attention_dense", "paged_attention_int8",
-                 "paged_attention_int4", "quant_codec_quantize_int8",
-                 "quant_codec_dequantize_int4", "moe_dispatch",
-                 "moe_combine"):
-        assert lane in result, lane
-    assert result["quant_codec_quantize_int8"]["parity"] == "bitwise"
-    assert result["moe_combine"]["parity"] == "tolerance"
-    pins = result["counters"]
-    assert pins["forced_pallas"] == {"dispatches": 11, "fallbacks": 0}
-    if not ON_TPU:
-        assert pins["auto"] == {"dispatches": 0, "fallbacks": 11}
-    # the artifact landed through monitor/artifacts.py
-    assert (tmp_path / "manifest.jsonl").exists()
-    assert list(tmp_path.glob("*_kernel_registry_dryrun.json"))

@@ -26,7 +26,6 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from deepspeed_tpu.kernels import registry
-from deepspeed_tpu.ops import pallas_backend
 
 
 @pytest.fixture(scope="module")
@@ -57,13 +56,6 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture
-def native(monkeypatch):
-    """What the package sees on the chip: kernels lower through Mosaic
-    and the registry's probe says TPU."""
-    monkeypatch.setattr(pallas_backend, "interpret", lambda: False)
 
 
 def _compile_text(fn, shapes, sharding):
@@ -211,8 +203,8 @@ def _moe(variant, n=8192, d=768, e=8, k=2):
 
 @dataclasses.dataclass
 class Case:
-    """`build()` -> (fn, shapes[, info]).  `op` None: the kernel has
-    its own call path (training attention, fused CE) and must compile.
+    """`build()` -> (fn, shapes[, info]).  `op` None: the kernel is
+    called directly (the flash kernels, fused CE) and must compile.
     `refused`: None when `auto` selects the kernel on the chip and it
     must compile; else a regex the registry's refusal must match."""
     name: str
@@ -302,6 +294,54 @@ def test_kernel_compiles_or_is_refused_by_name(case, one_chip, native):
         assert "tpu_custom_call" not in text
         return
     assert "tpu_custom_call" in _compile_text(fn, shapes, one_chip)
+
+
+# -- what `auto` picks in each benchmark cell --------------------------------
+
+
+def _train_attention(b, s, h, dh, bias=False):
+    from deepspeed_tpu.ops.transformer.attention import flash_info
+
+    q = _sds((b, s, h, dh), jnp.bfloat16)
+    return "flash_attention", flash_info(
+        q, q, _sds((b, 1, 1, s), jnp.float32) if bias else None)
+
+
+def _serve_attention(q_len, slots):
+    return "paged_attention", _paged("dense", H, DH, slots=slots,
+                                     nblocks=513, q_len=q_len)[2]
+
+
+@pytest.mark.parametrize("cell,call,expect", [
+    # micro 4 x 1024, 25 heads of 64, causal, no bias
+    ("gpt2-xl-d24.train.seq1024", lambda: _train_attention(4, 1024, H, DH),
+     "pallas"),
+    # micro 64 x 128, 16 heads of 64, padding mask: below _FLASH_MIN_SEQ
+    ("bert-large.train.seq128",
+     lambda: _train_attention(64, 128, 16, 64, bias=True), "jnp"),
+    # no cell yet (ROADMAP C2): BERT-large's second phase
+    ("bert-large.seq512", lambda: _train_attention(16, 512, 16, 64,
+                                                   bias=True), "pallas"),
+    # 16 slots, 513 blocks of 16, a table 64 wide, bf16
+    ("gpt2-xl.serve.chat.decode", lambda: _serve_attention(1, 16),
+     "pallas"),
+    ("gpt2-xl.serve.chat.prefill", lambda: _serve_attention(256, 1), "jnp"),
+    ("gpt2-xl.serve.overload.decode", lambda: _serve_attention(1, 16),
+     "pallas"),
+    # the op has no kernel yet (ROADMAP M7)
+    ("evabyte-d16.serve.longdoc.decode", lambda: ("eva_attention", None),
+     "jnp"),
+], ids=lambda v: v if isinstance(v, str) and "." in v else "")
+def test_auto_choice_for_each_benchmark_cell(cell, call, expect, native):
+    """The trace-time half of "the same numbers": at each cell's shapes
+    the registry picks, on the chip and with nothing forced, what the
+    cell was measured with."""
+    from deepspeed_tpu.ops.transformer.flash_attention import flash_blocks
+
+    op, info = call()
+    assert registry.resolve_impl(op, info=info) == expect
+    if op == "flash_attention" and expect == "pallas":
+        assert flash_blocks(info["seq_len"], info["kv_len"]) == (512, 512)
 
 
 # -- the partial-manual regions of ISSUE 22 (Motivation 1 and 2) -------------
@@ -433,3 +473,17 @@ def test_decode_layer_uses_the_pool_as_it_lies(one_chip, native):
     wide = [d for d in by_shape if d and d[0] in (slots * width * bs,)
             or d[:2] == (slots, width * bs)]
     assert not wide, wide
+
+
+
+def test_evabyte_phase_after_the_described_compiles(topo):
+    """This file, then tests/test_chip_smoke.py::test_evabyte_phase_toy,
+    in one process: the order in which the toy EvaByte run chose bytes
+    0.2156 below the reference's best.  What the compiles leave behind
+    is a different heap: a request's table then happened to lie where
+    the CPU backend aliases a host array instead of copying it, and the
+    prefill chunk that filled a window read the table after
+    `close_window` had rewritten it (serving/kv_cache.py `extend`)."""
+    from test_chip_smoke import test_evabyte_phase_toy
+
+    test_evabyte_phase_toy()

@@ -119,7 +119,7 @@ def run_dry(artifact_root: str, seed: int = 0) -> dict:
                           mesh={"dp": 8, "data_outer": 1},
                           fabric={"topology": "synthetic"})
     cache_path = os.path.join(artifact_root, "autotune_dry_cache.json")
-    cache = WinnerCache(cache_path, mode="map")
+    cache = WinnerCache(cache_path)
     cache.store(fp, {"name": best1.candidate.name}, d1.trace())
     hit = cache.lookup(fp)
     assert hit is not None and hit["winner"]["name"] == expected

@@ -186,8 +186,8 @@ def main():
     sys.stdout.write(out)
     if any(p.returncode for p in procs):
         sys.exit(1)
-    # durable artifact under bench_artifacts/runs/ + manifest (the PR-2
-    # rule bench.py follows); the printed JSON stays the primary output
+    # durable artifact under bench_artifacts/runs/ + manifest; the
+    # printed JSON stays the primary output
     try:
         line = next(ln for ln in out.splitlines()
                     if ln.startswith("{") and "metric" in ln)
