@@ -427,6 +427,9 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                   paged_shapes=((25, 64), (16, 128)),
                   paged_slots=16, paged_width=64,
                   bert_batch=16, bert_seq=512, bert_heads=16,
+                  eva_heads=(32, 128), eva_window=2048, eva_chunk=16,
+                  eva_summary_blocks=64,
+                  eva_positions=(0, 100, 2047, 2048, 9000, 12345, 16383, -1),
                   on_chip=True) -> dict:
     """Each kernel `auto` selects on this chip, once, natively, at the
     main path's shapes, against its jnp oracle at tier-1's tolerance
@@ -436,6 +439,8 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
     import jax.numpy as jnp
 
     from deepspeed_tpu.kernels import registry
+    from deepspeed_tpu.kernels.eva import (eva_attention_reference,
+                                           live_blocks)
     from deepspeed_tpu.kernels.paged import paged_attention_reference
     from deepspeed_tpu.ops import pallas_backend
     from deepspeed_tpu.ops.transformer.attention import xla_attention
@@ -573,6 +578,51 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                               [paged_slots, paged_width * bs, p_heads,
                                p_dim], got[live], want[live],
                               rtol=0, atol=2e-6))
+
+    # EVA attention, one decode step at the longdoc cell's shape: bf16
+    # rows, 32 heads of 128, a table of 128 window + 64 summary blocks;
+    # slots from a first byte to the last position the table holds, one
+    # idle; entries outside a slot's live runs at the trash block.  At
+    # the default precision, as the cell runs it: under "highest" Mosaic
+    # refuses the kernel's bf16 products ("Bad lhs type")
+    e_heads, e_dim = eva_heads
+    bs, wb = eva_chunk, eva_window // eva_chunk
+    width = wb + eva_summary_blocks
+    slots = len(eva_positions)
+    nblocks = 1 + slots * width
+    ids = rs.permutation(np.arange(1, nblocks)).reshape(slots, width)
+    pos = np.asarray(eva_positions)
+    n_win, n_sum = live_blocks(np.maximum(pos, 0), eva_window,
+                               eva_chunk, bs)
+    entry = np.arange(width)[None, :]
+    live = (entry < n_win[:, None]) | (
+        (entry >= wb) & (entry < wb + n_sum[:, None]))
+    tables = jnp.asarray(np.where(live & (pos >= 0)[:, None], ids, 0),
+                         jnp.int32)
+    rows = (nblocks * bs, e_heads * e_dim)
+    ck = jax.random.normal(key[6], rows, jnp.bfloat16)
+    cv = jax.random.normal(key[7], rows, jnp.bfloat16)
+    eq = jax.random.normal(key[0], (slots, 1, e_heads, e_dim),
+                           jnp.bfloat16)
+    q_pos = jnp.asarray(pos[:, None], jnp.int32)
+    info = {"block_size": bs, "table_width": width, "q_len": 1,
+            "num_heads": e_heads, "head_dim": e_dim, "kv_mode": "dense",
+            "kv_itemsize": 2, "window": eva_window, "chunk": eva_chunk}
+    chosen = registry.resolve_impl("eva_attention", info=info)
+    if on_chip and chosen != "pallas":
+        raise RuntimeError(
+            f"auto resolved EVA attention at {e_heads} heads of "
+            f"{e_dim} to {chosen!r} on this chip")
+    kw = dict(window=eva_window, chunk=eva_chunk, block_size=bs)
+    got = jax.jit(lambda *a: registry.dispatch(
+        "eva_attention", *a, info=info, **kw))(eq, ck, cv, tables, q_pos)
+    want = jax.jit(lambda *a: eva_attention_reference(*a, **kw))(
+        eq, ck, cv, tables, q_pos)
+    # bf16 operands and probabilities on both sides, float32 sums
+    # in another order
+    out.append(_close(f"eva_attention_bf16_H{e_heads}_Dh{e_dim}",
+                      [slots, width * bs, e_heads, e_dim],
+                      got[pos >= 0], want[pos >= 0], rtol=0, atol=1e-2))
     return {"phase": "kernels", "native": not pallas_backend.interpret(),
             "kernels": out}
 

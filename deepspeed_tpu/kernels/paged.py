@@ -48,6 +48,12 @@ are masked (`p = where(mask, ., 0)`), and the V tile is zeroed when a
 program starts so that a masked row multiplies a finite number.  An
 idle slot's output is zeros (the oracle's is the mean of the trash
 block's values; the engine discards both).
+
+The same walk serves chunk-summarised attention (kernels/eva.py, `_walk`
+with `window` > 0): which table entries are live and which of their rows
+a query sees then follow from the query's position — two runs, the open
+window's blocks and the closed windows' summary blocks — and the output
+is float32.  With `window` 0 the kernel's body is what it was.
 """
 
 from __future__ import annotations
@@ -193,7 +199,7 @@ def _tile_kv(buf, sbuf, slot, kv_mode, H, Dh, marker):
 
 
 def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
-                 kv_mode, marker):
+                 kv_mode, marker, window=0, chunk=0):
     if kv_mode == "dense":
         k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc, m_s, l_s = rest
         pairs = ((k_hbm, kbuf), (v_hbm, vbuf))
@@ -210,7 +216,26 @@ def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
     last = qp[b, 0]
     for t in range(1, T):
         last = jnp.maximum(last, qp[b, t])
-    n_blocks = jnp.clip((last + bs) // bs, 0, W)
+    if not window:
+        n_blocks = jnp.clip((last + bs) // bs, 0, W)
+        entry = lambda blk: blk
+    else:
+        # summarised windows (kernels/eva.py): the live blocks are two
+        # runs of the table, walked as one — the open window's, entries
+        # 0 .. n_win - 1, then the closed windows' summary blocks from
+        # entry window // bs on.  A query sees window rows up to its
+        # offset and summary rows below its count; several queries walk
+        # the longest run of each kind and mask their own
+        offs = [jnp.maximum(qp[b, t], 0) % window for t in range(T)]
+        sums = [jnp.maximum(qp[b, t], 0) // window * (window // chunk)
+                for t in range(T)]
+        n_win = functools.reduce(jnp.maximum, offs) // bs + 1
+        n_sum = jnp.minimum((functools.reduce(jnp.maximum, sums) + bs - 1)
+                            // bs, W - window // bs)
+        sums = [jnp.minimum(n, n_sum * bs) for n in sums]
+        n_blocks = jnp.where(last >= 0, n_win + n_sum, 0)
+        entry = lambda blk: jnp.where(blk < n_win, blk,
+                                      blk - n_win + window // bs)
     n_tiles = (n_blocks + KB - 1) // KB
 
     def tile_copies(tile, slot, go):
@@ -221,7 +246,7 @@ def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
         def one(blk, carry):
             for n, (src, dst) in enumerate(pairs):
                 go(pltpu.make_async_copy(
-                    src.at[tbl[b, blk]], dst.at[slot, blk - first],
+                    src.at[tbl[b, entry(blk)]], dst.at[slot, blk - first],
                     sem.at[n, slot]))
             return carry
 
@@ -240,10 +265,25 @@ def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
             vsbuf[...] = jnp.zeros_like(vsbuf)
         tile_copies(0, 0, lambda cp: cp.start())
         row = jax.lax.broadcasted_iota(jnp.int32, (C, TK), 0)
-        qrow = jnp.full((C, TK), -1, jnp.int32)
-        for t in range(T):
-            qrow = jnp.where((row >= t * Hp) & (row < (t + 1) * Hp),
-                             qp[b, t], qrow)
+
+        def by_query(value):
+            """(C, TK): row (t, h) holds query t's `value(t)`."""
+            out = jnp.full((C, TK), -1, jnp.int32)
+            for t in range(T):
+                out = jnp.where((row >= t * Hp) & (row < (t + 1) * Hp),
+                                value(t), out)
+            return out
+
+        if not window:
+            qrow = by_query(lambda t: qp[b, t])
+            visible = lambda kidx: qrow >= kidx
+        else:
+            qoff = by_query(lambda t: offs[t])
+            qsum = by_query(lambda t: sums[t])
+            # (no select between masks: Mosaic has no i1 `select_n`)
+            visible = lambda kidx: (
+                ((kidx < n_win * bs) & (kidx <= qoff)) |
+                ((kidx >= n_win * bs) & (kidx - n_win * bs < qsum)))
         q = q_ref[0]                                       # (C, H * Dh)
 
         def body(i, carry):
@@ -260,7 +300,7 @@ def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
                 q.astype(dt), k.astype(dt), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # (C, TK)
             kidx = i * TK + jax.lax.broadcasted_iota(jnp.int32, (C, TK), 1)
-            mask = qrow >= kidx
+            mask = visible(kidx)
             s = jnp.where(mask, s, NEG_INF)
             m_prev = m_s[:, :1]
             l_prev = l_s[:, :1]
@@ -308,10 +348,14 @@ def paged_attention_pallas(q, ck, cv, tables, q_pos, *,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("kv_mode", "block_size", "interpret"))
-def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret):
+                   static_argnames=("kv_mode", "block_size", "interpret",
+                                    "window", "chunk"))
+def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret,
+          window=0, chunk=0):
     """The call, as a function of its own: a program that makes it in
-    every layer traces and lowers the kernel once and calls it."""
+    every layer traces and lowers the kernel once and calls it.
+    `window` > 0: the table is `[window blocks | summary blocks]` and
+    the walk is kernels/eva.py's (dense rows, float32 out)."""
     B, T, H, Dh = q.shape
     W = tables.shape[1]
     bs = block_size
@@ -321,7 +365,7 @@ def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret):
 
     if kv_mode == "dense":
         marker = 0
-        out_dtype = ck.dtype
+        out_dtype = jnp.float32 if window else ck.dtype
         operands = [ck, cv]
         width = ck.shape[1]  # H * Dh and the lanes that pad a pool row
     else:
@@ -367,13 +411,13 @@ def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret):
     out = pl.pallas_call(
         functools.partial(_walk_kernel, scale=Dh ** -0.5, bs=bs, W=W,
                           KB=KB, T=T, H=H, Hp=Hp, Dh=Dh, kv_mode=kv_mode,
-                          marker=marker),
+                          marker=marker, window=window, chunk=chunk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, width), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL,)),
         interpret=interpret,
-        name="paged_attention_walk",
+        name="eva_attention_walk" if window else "paged_attention_walk",
     )(tables.astype(jnp.int32), q_pos.astype(jnp.int32),
       _block_diagonal(q, Hp, width), *operands)
     return out[..., :HD].reshape(B, T, H, Dh)

@@ -254,6 +254,42 @@ class SparseAttentionOp(KernelOp):
             dropout_rate=dropout_rate, dropout_rng=dropout_rng)
 
 
+def _walk_supports(info: Mapping) -> Tuple[bool, str]:
+    """What kernels/paged.py's walk of live blocks takes, from what a
+    serving call site observes (serving/layers.py::paged_info)."""
+    t = int(info.get("q_len", 1))
+    if t > 8:
+        return False, (f"q_len {t} is a prefill chunk: the kernel "
+                       f"unrolls over a decode or verify step's "
+                       f"queries (<= 8); prefill reads one request's "
+                       f"rows through the jnp oracle")
+    mode = info.get("kv_mode", "dense")
+    bs = int(info.get("block_size", 0))
+    H = int(info.get("num_heads", 1))
+    if mode != "dense":
+        return False, (f"{mode} rows: the kernel would copy a "
+                       f"block's ({bs}, {H}) tile of scales, and "
+                       f"\"Slice shape along dimension 2 must be "
+                       f"aligned to tiling (128)\"; the oracle "
+                       f"dequantises the gathered rows")
+    item = int(info.get("kv_itemsize", 2))
+    sublanes = 32 // item
+    if bs <= 0 or bs % sublanes:
+        return False, (f"a block of {bs} rows is not whole tiles of "
+                       f"{sublanes} rows at {item} bytes a value, so "
+                       f"it is no slab the kernel can copy")
+    from ..serving.kv_cache import pool_width
+    from .paged import tile_blocks
+
+    width = pool_width(H, int(info.get("head_dim", 128)))
+    if not tile_blocks(bs, int(info.get("table_width", 1)),
+                       width * item, t, H, width):
+        return False, (f"{t} x {H} score rows of {width} lanes, or "
+                       f"one block of {bs} such rows, do not fit the "
+                       f"kernel's VMEM tiles")
+    return True, ""
+
+
 class PagedAttentionOp(KernelOp):
     """Decode-path paged attention (op 1): a walk of each slot's live
     blocks in the PagedKVCache's pool, all heads a tile, online softmax
@@ -267,39 +303,7 @@ class PagedAttentionOp(KernelOp):
     NAME = "paged_attention"
 
     def auto_supports(self, variant, info):
-        if not info:
-            return True, ""
-        t = int(info.get("q_len", 1))
-        if t > 8:
-            return False, (f"q_len {t} is a prefill chunk: the kernel "
-                           f"unrolls over a decode or verify step's "
-                           f"queries (<= 8); prefill reads one request's "
-                           f"rows through the jnp oracle")
-        mode = info.get("kv_mode", "dense")
-        bs = int(info.get("block_size", 0))
-        H = int(info.get("num_heads", 1))
-        if mode != "dense":
-            return False, (f"{mode} rows: the kernel would copy a "
-                           f"block's ({bs}, {H}) tile of scales, and "
-                           f"\"Slice shape along dimension 2 must be "
-                           f"aligned to tiling (128)\"; the oracle "
-                           f"dequantises the gathered rows")
-        item = int(info.get("kv_itemsize", 2))
-        sublanes = 32 // item
-        if bs <= 0 or bs % sublanes:
-            return False, (f"a block of {bs} rows is not whole tiles of "
-                           f"{sublanes} rows at {item} bytes a value, so "
-                           f"it is no slab the kernel can copy")
-        from ..serving.kv_cache import pool_width
-        from .paged import tile_blocks
-
-        width = pool_width(H, int(info.get("head_dim", 128)))
-        if not tile_blocks(bs, int(info.get("table_width", 1)),
-                           width * item, t, H, width):
-            return False, (f"{t} x {H} score rows of {width} lanes, or "
-                           f"one block of {bs} such rows, do not fit the "
-                           f"kernel's VMEM tiles")
-        return True, ""
+        return _walk_supports(info) if info else (True, "")
 
     def pallas(self, variant, *args, **kwargs):
         from . import paged
@@ -313,19 +317,30 @@ class PagedAttentionOp(KernelOp):
 class EvaAttentionOp(KernelOp):
     """Chunk-summarised attention over the paged cache (kernels/eva.py):
     a window of exact rows and the summary rows of closed windows in one
-    softmax.  Only the jnp oracle exists; the op is registered so the
-    serving block calls it as it calls every attention core, and a
-    Pallas kernel lands here without a change to its caller."""
+    softmax.  Pallas = the paged walk over the two runs of table entries
+    a slot's position makes live; oracle = the gather of the whole table
+    under the visibility mask.  The shape rule is the walk's, and that
+    both runs are whole blocks (serving/layers.py::eva_info)."""
 
     NAME = "eva_attention"
 
-    def is_compatible(self) -> bool:
-        return False
+    def auto_supports(self, variant, info):
+        if not info:
+            return True, ""
+        ok, why = _walk_supports(info)
+        if not ok:
+            return ok, why
+        bs, window = int(info["block_size"]), int(info["window"])
+        rows = window // int(info["chunk"])
+        if window % bs or rows % bs:
+            return False, (f"a window of {window} rows and its {rows} "
+                           f"summary rows are not whole blocks of {bs}: "
+                           f"the walk takes a run's blocks whole")
+        return True, ""
 
-    def compatibility_message(self) -> str:
-        if self.env_enabled() and _on_tpu():
-            return "no Pallas kernel is written for it yet (jnp oracle only)"
-        return super().compatibility_message()
+    def pallas(self, variant, *args, **kwargs):
+        from . import eva
+        return eva.eva_attention_pallas(*args, **kwargs)
 
     def oracle(self, variant, *args, **kwargs):
         from . import eva

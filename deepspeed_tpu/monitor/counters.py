@@ -113,7 +113,12 @@ Instrumented sites:
   bytes = cache rows they read (the window up to the query plus the
   visible summary rows); `serve.eva.context_tokens` — bytes = cached
   length of the same queries, so rows_read / context_tokens is the
-  share of full attention's reads that is left.
+  share of full attention's reads that is left;
+  `serve.eva.rows_walked` — calls = the same queries, bytes = pool
+  rows their attention fetches (the blocks the rows read lie in where
+  the EVA kernel runs, every entry of the table where the jnp oracle
+  does), so rows_read / rows_walked is the share of a step's reads
+  that it needs.
   Paged attention (every other served model): `serve.paged.rows_walked`
   — calls = slots decoded, bytes = pool rows their attention reads (a
   slot's live blocks where the paged kernel runs, the table's whole

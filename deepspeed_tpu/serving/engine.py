@@ -60,7 +60,11 @@ No program of its own, so nothing compiles after warm-up.  For such a
 model the engine refuses, by name, `prefix_cache=True`, sessions,
 `draft_len > 0`, quantized weights and int8/int4 rows.  Counters:
 `kv.summary_rows`, `kv.window_closes`, `serve.eva.rows_read`,
-`serve.eva.context_tokens`; host span `eva.window_close`.
+`serve.eva.context_tokens`, `serve.eva.rows_walked` (bytes = the rows
+attention fetches for the queries `rows_read` counts: the live window
+and summary blocks where the registry picks the kernel for the decode
+program's shapes, the table's whole width where it picks the oracle);
+host span `eva.window_close`.
 
 What a paged step reads: `serve.paged.rows_walked` (calls = slots
 decoded, bytes = the pool rows attention reads for them: a slot's live
@@ -92,6 +96,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..kernels.eva import live_blocks
 from ..monitor.counters import COUNTERS
 from ..runtime.resilience import fault_point
 from ..utils.logging import logger
@@ -260,19 +265,19 @@ class ServeEngine:
             self.scheduler.session_consumed = self._session_consumed
         self.programs = programs
         # what a decoded slot's attention reads, for
-        # serve.paged.rows_walked: its live blocks where the registry
-        # picks the kernel for the decode program's shapes, else the
-        # table's whole width
-        self._walks_live_blocks = False
-        if spec.attention == "paged":
-            from ..kernels import registry
-            from .layers import paged_info
+        # serve.{paged,eva}.rows_walked: its live blocks where the
+        # registry picks the kernel for the decode program's shapes,
+        # else the table's whole width
+        from ..kernels import registry
+        from .layers import eva_info, paged_info
 
-            pool = jax.tree_util.tree_leaves(self.kv.caches[0][0])[0]
-            info = paged_info(cfg, schedule, int(c.draft_len) + 1,
-                              pool.dtype)
-            self._walks_live_blocks = registry.resolve_impl(
-                "paged_attention", info=info) == "pallas"
+        pool = jax.tree_util.tree_leaves(self.kv.caches[0][0])[0]
+        q_len = int(c.draft_len) + 1
+        info = (paged_info(cfg, schedule, q_len, pool.dtype)
+                if spec.attention == "paged"
+                else eva_info(spec, cfg, schedule, q_len, pool.dtype))
+        self._walks_live_blocks = registry.resolve_impl(
+            f"{spec.attention}_attention", info=info) == "pallas"
         self.params = programs["prepare_params"](
             self._place_params(params))
         logger.info(f"serving engine up: {schedule.describe()}; "
@@ -660,10 +665,15 @@ class ServeEngine:
                 self._take_blocks(req, p, p + 1)
                 self._tables[req.slot] = req.table
                 # what this query reads: its window up to itself and
-                # the summaries of the windows closed before it
+                # the summaries of the windows closed before it; what
+                # its attention fetches for that: the blocks those rows
+                # lie in, or every entry of the table
                 COUNTERS.add("serve.eva.rows_read",
                              nbytes=p % W + 1 + p // W * (W // C))
                 COUNTERS.add("serve.eva.context_tokens", nbytes=p + 1)
+                COUNTERS.add("serve.eva.rows_walked", nbytes=C * (
+                    sum(live_blocks(p, W, C, C)) if self._walks_live_blocks
+                    else self.kv.table_width))
         else:
             self._count_rows_walked(running, 1)
         t0 = time.perf_counter()

@@ -55,8 +55,13 @@ rows-minor-most and transposes all of it for every scatter and gather
 row's lanes are sharded over the `model` axis (the same Megatron TP
 layout as the weights: a rank's columns are its heads), so each TP rank
 holds its heads' share of every block and the gather/scatter stay local
-to the row dimension.  A windowed cache (below) keeps `[rows, H, Dh]`:
-its attention (kernels/eva.py) indexes a chunk's block by head.
+to the row dimension.  A windowed cache (below) has the same row shape:
+an exact row and a summary row are both `H * Dh` values side by side,
+and its attention copies live blocks from the pool as the paged one
+does (kernels/eva.py).  (Rows of 2 bytes share a tile's sublanes in
+pairs, so a scatter of single rows rewrites its neighbours' words: a
+prefill chunk that is whole blocks writes them as blocks,
+serving/layers.py `_block_write`.)
 
 Quantized storage (`dtype="int8" | "int4"`): each K/V entry becomes a
 (payload, scales) pair — int8/uint8 codes `[rows, pool_width(H, Dh |
@@ -205,10 +210,10 @@ class PagedKVCache:
     """Device block pool + host allocator for one serving engine.
 
     `caches` is the functional state the jitted programs thread: a list
-    of (k, v) per layer, each `[num_blocks * block_size, pool_width]`
-    (windowed: `[num_blocks * block_size, H, Dh]`).  The
-    engine passes it into a program and stores the returned (donated)
-    arrays back; this object owns the allocator book-keeping only.
+    of (k, v) per layer, each `[num_blocks * block_size, pool_width]`.
+    The engine passes it into a program and stores the returned
+    (donated) arrays back; this object owns the allocator book-keeping
+    only.
 
     Owners are opaque hashable keys: the scheduler uses request rids,
     the session store uses `("session", sid)` tuples — both walk the
@@ -311,8 +316,6 @@ class PagedKVCache:
                 f"serving KV cache: model axis {tp} does not divide "
                 f"num_heads {self.num_heads}; cache stays unsharded")
             return None
-        if self.windowed:
-            return mesh_info.sharding(None, MODEL_AXIS, None)
         return mesh_info.sharding(None, MODEL_AXIS)
 
     def _scale_kv_sharding(self, mesh_info):
@@ -326,8 +329,7 @@ class PagedKVCache:
     def _init_caches(self):
         rows = self.num_blocks * self.block_size
         if self.quant_wire is None:
-            shape = ((rows, self.num_heads, self.head_dim) if self.windowed
-                     else (rows, pool_width(self.num_heads, self.head_dim)))
+            shape = (rows, pool_width(self.num_heads, self.head_dim))
 
             def mk():
                 z = jnp.zeros(shape, self.dense_dtype)

@@ -19,22 +19,24 @@ The EVA pieces' statements stand in the order the programs had them
 before they moved here: the order of independent operations is part of a
 program's StableHLO, which keys the compilation cache.
 
-The paged pool is `[rows, pool_width(H, Dh)]` (serving/kv_cache.py): a
-token's heads side by side in one row.  A call's K/V are written as such
-rows by one scatter, in place, and attention reads the pool as it lies —
-through the table's live blocks on the chip at `q_len` <= 8
-(kernels/paged.py: decode and verify), by a gather of the table's rows
-elsewhere and in prefill.
+The pool is `[rows, pool_width(H, Dh)]` (serving/kv_cache.py) for both
+attentions: a token's (or a chunk summary's) heads side by side in one
+row.  A call's K/V are written as such rows by one scatter, in place,
+and attention reads the pool as it lies — through the table's live
+blocks on the chip at `q_len` <= 8 (kernels/paged.py: decode and verify;
+kernels/eva.py: decode), by a gather of the table's rows elsewhere and
+in prefill.
 
 Addressing (`Addr`): a program works out once where this call's K/V land
 and what attention reads, and every layer's block uses it.  Paged: flat
 write rows, the block tables, query positions (negative for a slot that
-is not running: it attends nothing).  EVA: the
-same write rows (inside the open window's blocks), the block table
-`[window blocks | summary blocks]`, the flat rows the summaries of the
-chunks this call completes land in, and — in decode, where a chunk
-completes one token at a time — the rows of the chunk's own block, so
-that its summary pools what the cache holds.  `block_size` equals the
+is not running: it attends nothing).  EVA: the same write rows (inside
+the open window's blocks; a prefill chunk, which is whole blocks, names
+the blocks instead and writes them as slabs) and query positions, the
+block table `[window blocks | summary blocks]`, the flat rows the
+summaries of the chunks this call completes land in, and — in decode,
+where a chunk completes one token at a time — the rows of the chunk's
+own block, so that its summary pools what the cache holds.  `block_size` equals the
 chunk, so one exact block is one chunk and `block_size` summary rows are
 one summary block.
 """
@@ -65,10 +67,11 @@ def check_spec(spec: LayerSpec) -> LayerSpec:
 
 
 class Addr(NamedTuple):
-    write_idx: jax.Array                  # [B*T] flat rows of this call's K/V
+    write_idx: Optional[jax.Array]        # [B*T] flat rows of this call's K/V
     q_pos: jax.Array                      # [B, T] absolute positions
     tables: Optional[jax.Array] = None    # [B, W] block tables
     sum_idx: Optional[jax.Array] = None   # eva: [B*n] flat summary rows
+    write_blk: Optional[jax.Array] = None  # eva prefill: [T/bs] block ids
     chunk_src: Optional[jax.Array] = None  # eva decode: [B, bs] flat rows
 
 
@@ -116,20 +119,25 @@ def address_chunk(spec, s, table, pos, abs_pos, n_valid) -> Addr:
         write_idx = blk * bs + abs_pos % bs
         return Addr(write_idx=write_idx, q_pos=abs_pos[None, :],
                     tables=table[None, :])
-    # eva: the chunk lies inside one window (prefill_chunk divides it);
-    # rows past n_valid and the summaries of chunks it does not complete
-    # go to the trash block
+    # eva: the chunk lies inside one window (prefill_chunk divides it)
+    # and is whole blocks (a block is a chunk of the model's), so its
+    # rows are written a block at a time — slabs of whole tiles, where
+    # a scatter of single bf16 rows rewrites the tile each shares with
+    # its neighbour.  A block with a valid row goes where the table
+    # says, its rows past n_valid with it (masked until decode rewrites
+    # them); blocks wholly past n_valid and the summaries of chunks the
+    # call does not complete go to the trash block
     wb = spec.window // bs
-    off = abs_pos % spec.window
-    valid = jnp.arange(abs_pos.shape[0]) < n_valid
-    write_idx = jnp.where(valid, table[off // bs] * bs + off % bs, off % bs)
     n_c = abs_pos.shape[0] // spec.chunk
+    first = jnp.arange(n_c) * spec.chunk
+    write_blk = jnp.where(first < n_valid,
+                          table[(pos % spec.window + first) // bs], 0)
     c = pos // spec.chunk + jnp.arange(n_c)
-    done = (jnp.arange(n_c) + 1) * spec.chunk <= n_valid
+    done = first + spec.chunk <= n_valid
     sblk = table[jnp.clip(wb + c // bs, 0, W - 1)]
     sum_idx = jnp.where(done, sblk * bs + c % bs, c % bs)
-    return Addr(write_idx=write_idx, q_pos=abs_pos[None, :],
-                tables=table[None, :], sum_idx=sum_idx)
+    return Addr(write_idx=None, q_pos=abs_pos[None, :],
+                tables=table[None, :], sum_idx=sum_idx, write_blk=write_blk)
 
 
 def address_step(spec, s, tables, positions, active) -> Addr:
@@ -156,8 +164,9 @@ def address_step(spec, s, tables, positions, active) -> Addr:
         tables, jnp.clip(wb + c // bs, 0, W - 1)[:, None], axis=1)[:, 0]
     sum_idx = jnp.where(done, sblk * bs + c % bs, 0)
     chunk_src = blk[:, None] * bs + jnp.arange(bs)[None, :]
-    return Addr(write_idx=write_idx, q_pos=positions[:, None],
-                tables=tables, sum_idx=sum_idx, chunk_src=chunk_src)
+    q_pos = jnp.where(active, positions, -1)[:, None]
+    return Addr(write_idx=write_idx, q_pos=q_pos, tables=tables,
+                sum_idx=sum_idx, chunk_src=chunk_src)
 
 
 def address_grid(spec, s, tables, abs_pos, active, n_draft) -> Addr:
@@ -202,6 +211,14 @@ def _kv_write(c, idx, val, kv_mode):
     codes, s = quantize_rows(val.astype(jnp.float32), kv_mode)
     return (payload.at[idx].set(pool_rows(codes, payload.shape[1])),
             scales.at[idx].set(s))
+
+
+def _block_write(c, blk, val, bs: int):
+    """Write `val` [n * bs, H, Dh] into the dense pool `c` as the `n`
+    whole blocks `blk`."""
+    width = c.shape[1]
+    slabs = pool_rows(val.astype(c.dtype), width).reshape(-1, bs, width)
+    return c.reshape(-1, bs, width).at[blk].set(slabs).reshape(c.shape)
 
 
 def paged_info(cfg, s, q_len: int, cache_dtype) -> dict:
@@ -252,30 +269,45 @@ def _paged_attend(cfg, p, h, ck, cv, addr, s):
     return attn, ck, cv
 
 
-def _eva_attend(spec, cfg, p, h, ck, cv, addr, block_size):
+def eva_info(spec, cfg, s, q_len: int, cache_dtype) -> dict:
+    """`paged_info` and the two sizes that say which table entries a
+    position makes live."""
+    return dict(paged_info(cfg, s, q_len, cache_dtype),
+                window=spec.window, chunk=spec.chunk)
+
+
+def _eva_attend(spec, cfg, p, h, ck, cv, addr, s):
     """q, k, v with rotary positions at the cache's dtype; this call's
     exact rows written into the open window's blocks; the summaries of
     the chunks this call completes written to their summary rows; then
     one softmax over the window's rows up to the query and the summary
-    rows of closed windows (kernels/eva.py).  -> float32."""
+    rows of closed windows (kernels/eva.py: the walk of a slot's live
+    blocks on the chip at `q_len` <= 8, the gather of its whole table
+    elsewhere and in prefill).  -> float32."""
     B, T, D = h.shape
     H, Dh = cfg.num_heads, cfg.head_dim
     q, k, v = project_qkv(p, h, addr.q_pos, spec.rope_theta, H, ck.dtype)
-    ck = ck.at[addr.write_idx].set(k.reshape(B * T, H, Dh))
-    cv = cv.at[addr.write_idx].set(v.reshape(B * T, H, Dh))
     if addr.chunk_src is None:   # prefill: whole chunks, as just stored
+        ck = _block_write(ck, addr.write_blk, k.reshape(T, H, Dh),
+                          s.block_size)
+        cv = _block_write(cv, addr.write_blk, v.reshape(T, H, Dh),
+                          s.block_size)
         chunks = lambda t: t.reshape(B, T // spec.chunk, spec.chunk, H, Dh)
         ks, vs = chunk_summaries(chunks(k), chunks(v), p["mu"], p["phi"])
     else:                        # decode: the chunk's block, as stored
-        ks, vs = chunk_summaries(ck[addr.chunk_src], cv[addr.chunk_src],
-                                 p["mu"], p["phi"])
-    ck = ck.at[addr.sum_idx].set(ks.reshape(-1, H, Dh).astype(ck.dtype))
-    cv = cv.at[addr.sum_idx].set(vs.reshape(-1, H, Dh).astype(cv.dtype))
+        ck = _kv_write(ck, addr.write_idx, k.reshape(B * T, H, Dh), "dense")
+        cv = _kv_write(cv, addr.write_idx, v.reshape(B * T, H, Dh), "dense")
+        stored = lambda c: c[addr.chunk_src][..., :H * Dh].reshape(
+            B, -1, H, Dh)
+        ks, vs = chunk_summaries(stored(ck), stored(cv), p["mu"], p["phi"])
+    ck = _kv_write(ck, addr.sum_idx, ks.reshape(-1, H, Dh), "dense")
+    cv = _kv_write(cv, addr.sum_idx, vs.reshape(-1, H, Dh), "dense")
     from ..kernels import registry
 
     attn = registry.dispatch(
         "eva_attention", q, ck, cv, addr.tables, addr.q_pos,
-        window=spec.window, chunk=spec.chunk, block_size=block_size)
+        info=eva_info(spec, cfg, s, T, ck.dtype),
+        window=spec.window, chunk=spec.chunk, block_size=s.block_size)
     return matmul32(attn.reshape(B, T, D), p["o"]), ck, cv
 
 
@@ -302,8 +334,7 @@ def block(spec, cfg, p, x, ck, cv, addr, s):
     if spec.attention == "paged":
         attn, ck, cv = _paged_attend(cfg, p["attn"], h, ck, cv, addr, s)
     else:
-        attn, ck, cv = _eva_attend(spec, cfg, p["attn"], h, ck, cv, addr,
-                                   s.block_size)
+        attn, ck, cv = _eva_attend(spec, cfg, p["attn"], h, ck, cv, addr, s)
     x = x + attn
     h = _norm(spec, x, p["ln2"])
     return x + _ffn(spec, p["mlp"], h), ck, cv

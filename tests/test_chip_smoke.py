@@ -74,13 +74,16 @@ def test_kernels_phase_toy():
                                    paged_shapes=((5, 64), (2, 128)),
                                    paged_slots=3, paged_width=4,
                                    bert_batch=2, bert_seq=256, bert_heads=2,
+                                   eva_heads=(2, 64), eva_window=32,
+                                   eva_chunk=4, eva_summary_blocks=8,
+                                   eva_positions=(0, 31, 32, 100, -1),
                                    on_chip=False)
     assert [k["kernel"] for k in out["kernels"]] == [
         "flash_attention_fwd", "flash_attention_bwd",
         "flash_attention_full_bias_dropout_fwd",
         "flash_attention_full_bias_dropout_bwd", "fused_xent_fwd",
         "fused_xent_bwd", "paged_attention_dense_H5_Dh64",
-        "paged_attention_dense_H2_Dh128"]
+        "paged_attention_dense_H2_Dh128", "eva_attention_bf16_H2_Dh64"]
 
 
 def test_four_chip_phase_on_four_virtual_devices():

@@ -107,6 +107,18 @@ class Probe:
             self.step()
 
 
+def _attention(impl):
+    """The scope in which an engine's programs are traced with the
+    `eva_attention` op forced to the jnp oracle or to the Pallas kernel
+    (under the interpreter here)."""
+    from deepspeed_tpu.kernels import kernel_config
+
+    return kernel_config(ops={"eva_attention": impl}, interpret=True)
+
+
+IMPLS = pytest.mark.parametrize("impl", ["jnp", "pallas"])
+
+
 def _reference_rows(params, req, kw=KW):
     """The reference's logits at the positions that chose req.out."""
     lg = ref.logits(params, jnp.asarray([req.prompt + req.out]), **kw)[0]
@@ -187,19 +199,27 @@ def test_engine_is_plain_attention_when_nothing_is_summarised(kw):
 # -- (b) the engine against the reference, logits at every position ------------
 
 
+@IMPLS
 def test_engine_logits_match_reference_across_window_closes(
-        model_and_params):
+        model_and_params, impl):
     """Six requests over three slots, joining and leaving mid-flight;
     between them windows close inside a prefill run (70: at 32 and 64),
     exactly at the prefill/decode boundary (64) and in mid-decode (33 +
     40 reaches 64; 5 + 70 reaches 32 and 64).  Every sequence but the
-    shortest spans at least 2.5 windows."""
+    shortest spans at least 2.5 windows.  The attention core is the
+    oracle's gather of the whole table, or the kernel's walk of the
+    live blocks: the same logits within the same tolerance, with slots
+    idle, blocks returned at a close and taken again by a neighbour."""
     model, params = model_and_params
     probe = Probe(model, params, _serve())
     eng = probe.engine
     lens = [(70, 30), (64, 24), (33, 47), (5, 75), (96, 9), (31, 2)]
     reqs = [eng.submit(_prompt(n, i), new) for i, (n, new) in enumerate(lens)]
-    probe.run()
+    before = COUNTERS.snapshot()
+    with _attention(impl):
+        probe.run()
+    traced = COUNTERS.delta_since(before)
+    assert ("kernel.dispatches" in traced) == (impl == "pallas")
     assert all(r.state == "finished" for r in reqs)
     assert eng.peak_resident == 3
     for r in reqs:
@@ -234,7 +254,8 @@ def test_a_window_closing_in_prefill_at_the_boundary_and_in_decode_agree(
         np.testing.assert_allclose(other, runs[0], atol=1e-4, rtol=0)
 
 
-def test_engine_bf16_stays_within_its_stated_tolerance():
+@IMPLS
+def test_engine_bf16_stays_within_its_stated_tolerance(impl):
     """bf16 weights and cache against the float32 reference on the same
     (bf16-rounded) weights.  Tolerance 0.005 on logits whose standard
     deviation is 0.10 at this size: every matmul rounds its inputs to 8
@@ -248,7 +269,8 @@ def test_engine_bf16_stays_within_its_stated_tolerance():
     probe = Probe(model, params, _serve())
     reqs = [probe.engine.submit(_prompt(n, i), new)
             for i, (n, new) in enumerate([(70, 30), (33, 47), (64, 23)])]
-    probe.run()
+    with _attention(impl):
+        probe.run()
     worst = 0.0
     for r in reqs:
         got = np.stack(probe.logits[r.rid])[:, :VOCAB]
@@ -357,17 +379,31 @@ def test_windowed_cache_refuses_prefix_cache_and_describes_both_rows():
         1, 2, 8, 8, 4, 4, prefix_cache=False).describe()
 
 
-def test_cache_invariants_hold_at_every_step(model_and_params):
+@IMPLS
+def test_cache_invariants_hold_at_every_step(model_and_params, impl):
+    """Exact and summary rows in the one pool `[rows, pool_width(H,
+    Dh)]`, whichever attention core reads it: blocks come back at a
+    window's close and are handed out again, and the requests that took
+    them decode what a pool of their own gives (a block a neighbour
+    returned holds its stale rows: they are masked, or never walked)."""
+    from deepspeed_tpu.serving.kv_cache import pool_width
+
     model, params = model_and_params
     cfg = _serve(num_blocks=64)
+    lens = [(70, 30), (64, 24), (33, 47), (5, 75), (31, 2)]
+    with _attention(impl):
+        alone = [ServeEngine(model, params, _serve()).generate(
+            [_prompt(n, i)], new)[0] for i, (n, new) in enumerate(lens[:2])]
     eng = ServeEngine(model, params, cfg)
     kv = eng.kv
+    assert all(c.shape == (64 * C, pool_width(4, 16)) == (256, 128)
+               for layer in kv.caches for c in layer)
     free0 = kv.free_blocks
     bound = W // C + cfg.prefill_chunk // C
-    lens = [(70, 30), (64, 24), (33, 47), (5, 75), (31, 2)]
     reqs = [eng.submit(_prompt(n, i), new) for i, (n, new) in enumerate(lens)]
     while eng.has_work():
-        eng.step()
+        with _attention(impl):
+            eng.step()
         live = eng.scheduler.occupied()
         for r in live:
             exact = kv.exact_blocks_of(r.rid)
@@ -387,6 +423,7 @@ def test_cache_invariants_hold_at_every_step(model_and_params):
     assert kv.free_blocks == free0 and kv.promised_blocks == 0
     assert sorted(kv._free) == list(range(1, cfg.num_blocks))
     assert eng.peak_blocks_in_use <= 3 * (W // C + 4)
+    assert [r.out for r in reqs[:2]] == alone
 
 
 def test_admission_is_by_bounded_footprint(model_and_params):
@@ -430,12 +467,46 @@ def test_counters_and_the_window_close_span(model_and_params, tmp_path):
     assert d["serve.eva.rows_read"] == {"calls": 40, "bytes": want}
     assert d["serve.eva.context_tokens"] == {
         "calls": 40, "bytes": sum(range(31, 71))}
+    # the oracle (every backend but the TPU) gathers the whole table
+    assert d["serve.eva.rows_walked"] == {
+        "calls": 40, "bytes": 40 * eng.kv.table_width * C}
     spans = [e for e in rec.last_events()
              if e.get("name") == "eva.window_close"]
     assert [e["args"]["cached"] for e in spans] == [32, 64]
     assert all(e["args"]["blocks"] == 8 and e["ph"] == "X" for e in spans)
     rec.close()
     assert req.state == "finished"
+
+
+def test_rows_walked_counts_live_blocks_where_the_kernel_runs(monkeypatch):
+    """`serve.eva.rows_walked` is what the registry answers for the
+    decode program's shapes, asked once at build: where it picks the
+    kernel (on the chip, blocks of whole tiles), a query's live window
+    blocks plus its live summary blocks, whole."""
+    from deepspeed_tpu.ops import pallas_backend
+
+    window, chunk = 64, 8
+    model = EvaByte(_config(window_size=window, chunk_size=chunk))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
+    serve = _serve(block_size=chunk, prefill_chunk=16, num_blocks=64)
+    with monkeypatch.context() as chip:
+        chip.setattr(pallas_backend, "interpret", lambda: False)
+        eng = ServeEngine(model, params, serve)
+    assert eng._walks_live_blocks
+    assert not ServeEngine(model, params, serve)._walks_live_blocks
+    before = COUNTERS.snapshot()
+    with _attention("pallas"):
+        out = eng.generate([_prompt(60, 0)], 81)[0]   # positions 60..139
+    d = COUNTERS.delta_since(before)
+    blocks = lambda p: p % window // chunk + 1 + \
+        -(-(p // window * (window // chunk)) // chunk)
+    assert d["serve.eva.rows_walked"] == {
+        "calls": 80, "bytes": chunk * sum(blocks(p) for p in range(60, 140))}
+    assert d["serve.eva.rows_read"]["bytes"] <= \
+        d["serve.eva.rows_walked"]["bytes"] < 80 * eng.kv.table_width * chunk
+    with _attention("jnp"):
+        assert out == ServeEngine(model, params, serve).generate(
+            [_prompt(60, 0)], 81)[0]
 
 
 def test_nothing_compiles_after_the_warm_up_call(model_and_params):
@@ -505,7 +576,7 @@ def test_schedule_describes_both_kinds_of_row_and_the_registry_has_the_op(
     ServeProgramBuilder(model, sched)
     with pytest.raises(ValueError, match="window_blocks"):
         ServeProgramBuilder(model, sched._replace(window_blocks=4))
-    assert registry.resolve_impl("eva_attention") == "jnp"
+    assert registry.resolve_impl("eva_attention") == "jnp"   # not a TPU
     with pytest.raises(RuntimeError, match="impl='pallas' forced"):
         registry.resolve_impl("eva_attention", impl="pallas")
     spec = model.layer_spec()

@@ -236,6 +236,136 @@ def test_paged_attention_slot_ignores_the_other_slots():
     assert np.array_equal(a[0], b[0])
 
 
+def _eva_inputs(positions, T=1, H=2, Dh=64, bs=4, window=32, chunk=4,
+                summary_blocks=8, dtype=jnp.float32, seed=0):
+    """A pool `[rows, pool_width(H, Dh)]`, tables `[window blocks |
+    summary blocks]` and query positions [R, T] for slots whose last
+    query stands at `positions` (negative: the slot is not running).
+    Table entries outside the two live runs of a slot point at the
+    trash block, as the allocator leaves them; every block, the trash
+    block too, holds noise."""
+    from deepspeed_tpu.serving.kv_cache import pool_width
+
+    rng = np.random.RandomState(seed)
+    R, wb = len(positions), window // bs
+    W = wb + summary_blocks
+    nblocks = R * W + 1
+    pool = lambda: jnp.asarray(
+        rng.randn(nblocks * bs, pool_width(H, Dh)), dtype)
+    ck, cv = pool(), pool()
+    tables = np.zeros((R, W), np.int32)
+    ids = rng.permutation(np.arange(1, nblocks))
+    for r, p in enumerate(positions):
+        if p < 0:
+            continue
+        n_win = p % window // bs + 1
+        n_sum = -(-(p // window * (window // chunk)) // bs)
+        mine = ids[r * W:(r + 1) * W]
+        tables[r, :n_win] = mine[:n_win]
+        tables[r, wb:wb + n_sum] = mine[wb:wb + n_sum]
+    last = np.asarray(positions)[:, None]
+    q_pos = np.where(last >= 0, last - T + 1 + np.arange(T)[None, :], -1)
+    q = jnp.asarray(rng.randn(R, T, H, Dh), dtype)
+    return (q, ck, cv, jnp.asarray(tables), jnp.asarray(q_pos, jnp.int32),
+            dict(window=window, chunk=chunk, block_size=bs))
+
+
+_EVA_CELL = dict(bs=16, window=2048, chunk=16, summary_blocks=64)
+
+
+def _eva_case_id(v):
+    if isinstance(v, list):
+        return "pos" + "x".join(map(str, v))
+    return _paged_case_id({k: getattr(x, "__name__", x)
+                           for k, x in v.items()}) if v else ""
+
+
+@pytest.mark.parametrize("positions,shape", [
+    # inside the first window: no summary run
+    ([5], {}), ([0], {}), ([31], {}),
+    # the first position of a new window: one row of one window block,
+    # and the summaries of the window just closed
+    ([32], {}), ([64], {}),
+    # 6 summary rows a window: the last summary block is part visible
+    ([24 + 7], dict(window=24)), ([3 * 24], dict(window=24)),
+    # EvaByte's own sizes: the last position a table of 128 + 64 holds,
+    # several tiles of the walk, a tile across the two runs
+    ([16383], _EVA_CELL), ([9000, 2048, 100], _EVA_CELL),
+    # a batch: mid-window, an idle slot, a new window, a first window
+    ([45, -1, 96, 3], {}),
+    ([45, -1, 96, 3], dict(H=4, Dh=32)),        # a row of 128 lanes
+    ([45, -1, 96, 3], dict(H=3, Dh=32)),        # ... padded to 128
+    ([45, -1, 96, 3], dict(dtype=jnp.bfloat16)),
+    # several queries a slot, one pair across a window's close
+    ([45, -1, 97, 3], dict(T=3)), ([33], dict(T=4)),
+], ids=_eva_case_id)
+def test_eva_attention_parity(positions, shape):
+    """The walk of a slot's live window and summary blocks vs the gather
+    of its whole table under the visibility mask.  An idle slot reads
+    nothing in the kernel (zeros; the oracle averages the trash block)
+    and the engine discards both: not compared."""
+    from deepspeed_tpu.kernels.eva import eva_attention_reference
+
+    q, ck, cv, tables, q_pos, kw = _eva_inputs(positions, **shape)
+    ref = eva_attention_reference(q, ck, cv, tables, q_pos, **kw)
+    with kernel_config(interpret=True):
+        out = registry.dispatch("eva_attention", q, ck, cv, tables, q_pos,
+                                impl="pallas", **kw)
+    assert out.shape == ref.shape and out.dtype == ref.dtype == jnp.float32
+    live = np.asarray(positions) >= 0
+    atol = 2e-6 if q.dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
+                               atol=atol)
+    assert not np.asarray(out)[~live].any()
+
+
+def test_eva_attention_walks_only_the_live_blocks():
+    """Entries outside a slot's two live runs are never fetched: with
+    them pointed at blocks of NaN the kernel's output does not change
+    (the oracle gathers them, and a masked NaN is still a NaN in its
+    weighted sum)."""
+    q, ck, cv, tables, q_pos, kw = _eva_inputs([45, -1, 96, 3])
+    poison = ck.shape[0] // kw["block_size"]
+    bad = jnp.full((kw["block_size"], ck.shape[1]), jnp.nan, ck.dtype)
+    ck2, cv2 = jnp.concatenate([ck, bad]), jnp.concatenate([cv, bad])
+    with kernel_config(interpret=True):
+        run = lambda k, v, t: np.asarray(registry.dispatch(
+            "eva_attention", q, k, v, t, q_pos, impl="pallas", **kw))
+        want = run(ck, cv, tables)
+        got = run(ck2, cv2, jnp.where(tables == 0, poison, tables))
+    assert np.array_equal(want, got)
+
+
+_EVA_INFO = dict(q_len=1, block_size=16, table_width=192, window=2048,
+                 chunk=16, num_heads=32, head_dim=128, kv_mode="dense",
+                 kv_itemsize=2)
+
+
+@pytest.mark.parametrize("change,why", [
+    ({}, None),
+    (dict(q_len=2), None),
+    (dict(q_len=8), "8 x 32 score rows of 4096 lanes"),
+    (dict(q_len=1024), "q_len 1024 is a prefill chunk"),
+    (dict(block_size=8, chunk=8), "a block of 8 rows is not whole tiles"),
+    (dict(kv_itemsize=4, block_size=8, chunk=8), None),
+    (dict(kv_mode="int8"), "int8 rows"),
+    (dict(window=2040), "are not whole blocks of 16"),
+    (dict(chunk=256), "its 8 summary rows are not whole blocks"),
+], ids=lambda v: _paged_case_id(v) if isinstance(v, dict) else "")
+def test_eva_attention_shape_rule(change, why, native):
+    """What the call site can see decides (serving/layers.py::eva_info):
+    the decode program of EvaByte's cell takes the kernel on the chip,
+    its prefill and every shape the walk cannot copy take the oracle and
+    say why when the kernel is forced."""
+    info = dict(_EVA_INFO, **change)
+    if why is None:
+        assert resolve_impl("eva_attention", info=info) == "pallas"
+        return
+    assert resolve_impl("eva_attention", info=info) == "jnp"
+    with pytest.raises(RuntimeError, match=why):
+        resolve_impl("eva_attention", impl="pallas", info=info)
+
+
 def test_flash_attention_parity():
     from deepspeed_tpu.ops.transformer.attention import xla_attention
 
@@ -340,7 +470,7 @@ def test_env_gate_disables_every_op(name, native, monkeypatch):
     step reads it, through `multihead_attention`."""
     op = KERNEL_OPS[name]
     variant = op.VARIANTS[0]
-    selectable = op.is_compatible()   # eva, moe: no kernel for the chip
+    selectable = op.is_compatible()   # moe: no kernel for the chip
     if name == "flash_attention":
         assert _training_attention()
     monkeypatch.setenv(f"DS_KERNEL_{name.upper()}", "0")

@@ -159,6 +159,31 @@ def _paged(kv_mode, heads, dh, slots=16, bs=16, width=64, nblocks=1025,
     return fn, shapes, info
 
 
+def _eva(slots=8, heads=32, dh=128, bs=16, window=2048, chunk=16,
+         summary_blocks=64, nblocks=1537, q_len=1, dtype=jnp.bfloat16):
+    """`eva_attention` as EvaByte's serving block calls it: the cell's
+    shapes by default (evabyte-d16.serve.longdoc: 8 slots, 32 heads of
+    128, a table of 128 window + 64 summary blocks, bf16 rows)."""
+    from deepspeed_tpu.serving.kv_cache import pool_width
+
+    width = window // bs + summary_blocks
+    cache = _sds((nblocks * bs, pool_width(heads, dh)), dtype)
+    shapes = (_sds((slots, q_len, heads, dh), dtype), cache, cache,
+              _sds((slots, width), jnp.int32),
+              _sds((slots, q_len), jnp.int32))
+    info = {"block_size": bs, "table_width": width, "q_len": q_len,
+            "num_heads": heads, "head_dim": dh, "kv_mode": "dense",
+            "kv_itemsize": jnp.dtype(dtype).itemsize,
+            "window": window, "chunk": chunk}
+
+    def fn(q, ck, cv, tables, q_pos):
+        return registry.dispatch("eva_attention", q, ck, cv, tables, q_pos,
+                                 info=info, window=window, chunk=chunk,
+                                 block_size=bs)
+
+    return fn, shapes, info
+
+
 def _codec(variant, wire, n=4 * 1024 * 1024, block=256):
     info = {"block": block}
     if variant == "quantize":
@@ -259,6 +284,20 @@ CASES = [
          op="paged_attention", refused=r"int4 rows: .*\(16, 16\) tile"),
     Case("paged_int4_H25_Dh64", lambda: _paged("int4", 25, 64),
          op="paged_attention", refused=r"int4 rows: .*\(16, 25\) tile"),
+    # EvaByte's decode call at the longdoc cell's shapes, and what the
+    # shape rule sends to the oracle
+    Case("eva_H32_Dh128_cell", _eva, op="eva_attention"),
+    Case("eva_H32_Dh128_fp32", lambda: _eva(dtype=jnp.float32, bs=8,
+                                            chunk=8, summary_blocks=256),
+         op="eva_attention"),
+    Case("eva_H32_Dh128_prefill1024",
+         lambda: _eva(slots=1, q_len=1024),
+         op="eva_attention", refused=r"q_len 1024 is a prefill chunk"),
+    Case("eva_H32_Dh128_block8", lambda: _eva(bs=8, chunk=8),
+         op="eva_attention", refused=r"block of 8 rows is not whole"),
+    Case("eva_H32_Dh128_summaries_not_whole_blocks",
+         lambda: _eva(chunk=256),
+         op="eva_attention", refused=r"8 summary rows are not whole"),
     Case("codec_quantize_int8_4M_block256",
          lambda: _codec("quantize", "int8"),
          op="quant_codec", variant="quantize"),
@@ -328,9 +367,11 @@ def _serve_attention(q_len, slots):
     ("gpt2-xl.serve.chat.prefill", lambda: _serve_attention(256, 1), "jnp"),
     ("gpt2-xl.serve.overload.decode", lambda: _serve_attention(1, 16),
      "pallas"),
-    # the op has no kernel yet (ROADMAP M7)
-    ("evabyte-d16.serve.longdoc.decode", lambda: ("eva_attention", None),
-     "jnp"),
+    # 8 slots, 1,537 blocks of 16, a table of 128 + 64 entries, bf16
+    ("evabyte-d16.serve.longdoc.decode",
+     lambda: ("eva_attention", _eva()[2]), "pallas"),
+    ("evabyte-d16.serve.longdoc.prefill",
+     lambda: ("eva_attention", _eva(slots=1, q_len=1024)[2]), "jnp"),
 ], ids=lambda v: v if isinstance(v, str) and "." in v else "")
 def test_auto_choice_for_each_benchmark_cell(cell, call, expect, native):
     """The trace-time half of "the same numbers": at each cell's shapes
@@ -425,6 +466,18 @@ def test_registry_refuses_a_kernel_xla_would_have_to_partition(topo, native):
         registry.resolve_impl("paged_attention", impl="pallas", info=info)
 
 
+def _hlo_by_shape(text):
+    """{dims: {(opcode, layout)}} of an optimised program's text."""
+    import re
+
+    by_shape = {}
+    for m in re.finditer(r"= (\w+)\[([\d,]*)\]\{([\d,]*)[^ ]* ([\w\-]+)\(",
+                         text):
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        by_shape.setdefault(dims, set()).add((m.group(4), m.group(3)))
+    return by_shape
+
+
 def test_decode_layer_uses_the_pool_as_it_lies(one_chip, native):
     """One layer of GPT-2 xl's `decode` at the chat cell's shapes (16
     slots, 513 blocks of 16, a table 64 wide, bf16): the pool enters
@@ -432,8 +485,6 @@ def test_decode_layer_uses_the_pool_as_it_lies(one_chip, native):
     operation of the pool's size changes its layout, converts it or
     gathers the table's width from it — what cost 133 of the parent's
     140 ms a step with the pool as `[rows, 25, 64]`."""
-    import re
-
     from deepspeed_tpu.models import GPT, gpt2_config
     from deepspeed_tpu.serving import ServeProgramBuilder, ServeSchedule
     from deepspeed_tpu.serving.kv_cache import pool_width
@@ -459,11 +510,7 @@ def test_decode_layer_uses_the_pool_as_it_lies(one_chip, native):
         on((slots,), jnp.int32), on((slots,), jnp.uint32),
     ).compile().as_text()
     assert text.count("tpu_custom_call") == 1
-    by_shape = {}
-    for m in re.finditer(r"= (\w+)\[([\d,]*)\]\{([\d,]*)[^ ]* ([\w\-]+)\(",
-                         text):
-        dims = tuple(int(d) for d in m.group(2).split(",") if d)
-        by_shape.setdefault(dims, set()).add((m.group(4), m.group(3)))
+    by_shape = _hlo_by_shape(text)
     pool_ops = by_shape[(rows, pool_width(H, DH))]
     assert {"parameter", "scatter"} <= {op for op, _ in pool_ops}
     assert {layout for _, layout in pool_ops} == {"1,0"}  # row-major
@@ -473,6 +520,59 @@ def test_decode_layer_uses_the_pool_as_it_lies(one_chip, native):
     wide = [d for d in by_shape if d and d[0] in (slots * width * bs,)
             or d[:2] == (slots, width * bs)]
     assert not wide, wide
+
+
+def test_evabyte_decode_walks_the_pool_as_it_lies(one_chip, native):
+    """EvaByte's 16-layer `decode` at the longdoc cell's shapes (8
+    slots, 1,537 blocks of 16, a table of 128 + 64 entries, bf16): one
+    kernel, called in every layer; every pool enters as `[rows, 4096]`
+    row-major, is written by row scatters and is read by the kernel
+    where it lies — no operation of a pool's size moves, converts or
+    gathers it, and nothing of the table's whole width (8 x 3,072 rows,
+    the parent's gather and its float32 copy: 61 of 71 ms a step) is
+    built."""
+    from deepspeed_tpu.models import EvaByte, EvaByteConfig
+    from deepspeed_tpu.serving import ServeProgramBuilder, ServeSchedule
+
+    slots, bs, nblocks, layers = 8, 16, 1537, 16
+    model = EvaByte(EvaByteConfig(max_seq_len=16384, num_layers=layers,
+                                  param_dtype=jnp.bfloat16))
+    width = 2048 // bs + 64
+    sched = ServeSchedule(max_batch=slots, prefill_chunk=1024, block_size=bs,
+                          num_blocks=nblocks, table_width=width,
+                          window_blocks=2048 // bs)
+    decode = ServeProgramBuilder(model, sched).build()["decode"]
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda s: on(s.shape, s.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    rows = nblocks * bs
+    pool = on((rows, 4096), jnp.bfloat16)
+    compiled = decode.lower(
+        params, [(pool, pool)] * layers, on((slots,), jnp.int32),
+        on((slots,), jnp.int32), on((slots,), jnp.bool_),
+        on((slots, width), jnp.int32), on((slots,), jnp.float32),
+        on((slots,), jnp.int32), on((slots,), jnp.uint32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == layers
+    by_shape = _hlo_by_shape(text)
+    pool_ops = by_shape[(rows, 4096)]
+    assert {"parameter", "scatter"} <= {op for op, _ in pool_ops}
+    assert {layout for _, layout in pool_ops} == {"1,0"}  # row-major
+    moved = {"copy", "transpose", "convert", "gather", "reshape"}
+    assert not moved & {op for op, _ in pool_ops}, pool_ops
+    wide = [d for d in by_shape if d and (
+        d[0] == slots * width or d[:2] in ((slots, width),
+                                           (slots, width * bs)))
+        and len(d) > 2]
+    assert not wide, wide
+    # the gathered tables were 1.2 GB of temporaries; a step now needs
+    # what its weights' products do
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
 
