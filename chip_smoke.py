@@ -162,11 +162,9 @@ def lowered_step(engine, batch):
     arguments `engine.forward` dispatches it with."""
     import jax.numpy as jnp
 
-    lr = engine._current_lr()
     args = (engine._params, engine._opt_state, engine._scaler_state,
             engine._shard_batch(batch), engine._next_rng(),
-            None if lr is None else jnp.asarray(lr, jnp.float32),
-            jnp.asarray(1.0, jnp.float32))
+            engine._step_lr(), jnp.asarray(1.0, jnp.float32))
     return engine._step_fns["full"].fn.lower(*args)
 
 

@@ -183,6 +183,15 @@ Instrumented sites:
   training flash kernels (ops/transformer/flash_attention.py, never
   through the registry) ran for a shape: score tile and resident
   K/V rows (counted at TRACE time, per traced call).
+* the training step schedule (runtime/engine.py):
+  `engine.overflow_flag.waits` — calls = hot-path settles
+  (`_resolve_pending_overflow(keep_newest=True)`) that found an
+  overflow flag older than the newest not yet produced and waited for
+  it; bytes slot = integer MICROSECONDS waited (the
+  `input.host_wait_ms` convention).  Reads 0 calls where the loop
+  reads anything of step k-1 after it dispatches step k; a loop that
+  reads nothing is held here, two steps ahead of the device, and
+  counts once a step — that wait costs the device nothing.
 * trace/SLO telemetry (`trace.*` / `slo.*`, monitor/tracing.py;
   rendered by monitor/report.py as the "Tracing" rows of the Serving
   SLO section, excluded from the comm byte table): `trace.events` —
@@ -274,4 +283,5 @@ US_IN_BYTES_COUNTERS = frozenset((
     "kv.dequant_ms",
     "moe.a2a_exposed_ms",
     "autotune.probes",
+    "engine.overflow_flag.waits",
 ))

@@ -235,8 +235,10 @@ def render_markdown(run: Dict[str, Any]) -> str:
                      if k.startswith("ckpt.")}
     # grad_wire.exposed_ms / qwz.prefetch_hits carry µs (the
     # ckpt.stall_ms convention), not wire bytes — they render in the
-    # gradient-wire section below, not the comm byte table
-    _WIRE_TIME_COUNTERS = ("grad_wire.exposed_ms", "qwz.prefetch_hits")
+    # gradient-wire section below, not the comm byte table;
+    # engine.overflow_flag.waits carries µs too (step events only)
+    _WIRE_TIME_COUNTERS = ("grad_wire.exposed_ms", "qwz.prefetch_hits",
+                           "engine.overflow_flag.waits")
     # elastic.* counts world-size transitions (shrinks/regrows), not
     # wire bytes — Resilience rows, like fault.*; serve.*/kv.* carry
     # serving-engine metrics (tokens, µs, block occupancy) and render

@@ -176,8 +176,7 @@ class EngineProber:
         params, opt, scaler = self._copies()
         rng = jax.random.PRNGKey(0)
         theta = jnp.asarray(1.0, jnp.float32)
-        cur_lr = eng._current_lr()
-        lr = None if cur_lr is None else jnp.asarray(cur_lr, jnp.float32)
+        lr = eng._step_lr()  # the form the engine dispatches with
         batch = self.batch
         stacked = None
         if "full_scan" in fns:

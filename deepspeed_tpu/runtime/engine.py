@@ -60,6 +60,7 @@ from .module import TrainModule
 from .comm.bucketing import BucketPlan
 from .pipe.p2p import batch_shardable
 from .progressive_layer_drop import ProgressiveLayerDrop
+from .step_builder import StepLR, select_lr
 from .utils import ThroughputTimer, has_overflow
 from ..utils.timer import SynchronizedWallClockTimer
 from .zero.partition import ZeroShardingPlan
@@ -284,7 +285,8 @@ class DeepSpeedEngine:
                 self._config.tensorboard_job_name)
         self._flops_profiled = False
         self._last_loss = None
-        self._pending_overflow = None
+        self._pending_overflow = []  # (flag, its step), oldest first
+        self._no_overflow = None     # the settled flag, made once
         self._pending_full = None
         self._device_feed = None        # owned-iterator double buffer
         self._user_device_feed = None   # latest user-iterator feed
@@ -384,7 +386,8 @@ class DeepSpeedEngine:
         self.monitor = None
         self._flops_profiled = True
         self._last_loss = None
-        self._pending_overflow = None
+        self._pending_overflow = []  # (flag, its step), oldest first
+        self._no_overflow = None     # the settled flag, made once
         self._pending_full = None
         self._device_feed = None
         self._user_device_feed = None
@@ -1601,6 +1604,7 @@ class DeepSpeedEngine:
                        for k in self._opt_state}
 
         def run(params, opt_state, scaler_state, batch, rng, lr, pld_theta):
+            lr = select_lr(lr)
             loss_scale = scaler_state["cur_scale"]
             cparams = cast(params, compute_dtype)
 
@@ -1714,6 +1718,36 @@ class DeepSpeedEngine:
         if groups and "lr" in groups[0]:
             return float(groups[0]["lr"])
         return None
+
+    def _step_lr(self):
+        """The `lr` argument of the next step program, after the hot
+        path's settle (`_resolve_pending_overflow(keep_newest=True)`).
+        While the previous step's flag is in flight the host holds two
+        candidates: the rate param_groups shows (that step applied) and
+        the rate one scheduler index back (it overflowed; what the
+        roll-back in `_settle_overflow` would set, asked of the
+        scheduler the same way and then undone).  The program selects
+        on the flag (`step_builder.StepLR`), so the step after an
+        overflow runs at exactly the rate a blocking settle gives it.
+        With nothing in flight the flag is a constant false; None
+        (the optimizer's own default) stays None."""
+        cur = self._current_lr()
+        if cur is None:
+            return None
+        back = cur
+        if self._pending_overflow:
+            flag = self._pending_overflow[-1][0]
+            sched = self.lr_scheduler
+            it = getattr(sched, "last_batch_iteration", None)
+            if it is not None:  # else no roll-back happens either
+                sched.step(it - 1)
+                back = self._current_lr()
+                sched.step(it)
+        else:
+            if self._no_overflow is None:  # a transfer, no program
+                self._no_overflow = self._on_mesh(np.zeros((), np.bool_))
+            flag = self._no_overflow
+        return StepLR(jnp.asarray(np.array([cur, back], np.float32)), flag)
 
     # ------------------------------------------------------------------
     # public training API (reference engine.py:959,1040,1201)
@@ -1860,9 +1894,11 @@ class DeepSpeedEngine:
         state immediately (the update is branchless-correct in-device, so
         committing at the boundary's forward is semantically the same step
         the split path applies in step()); step() finishes the host-side
-        bookkeeping. The previous step's deferred overflow flag is settled
-        FIRST so the scheduler lr read below is the rolled-back one."""
-        self._resolve_pending_overflow()
+        bookkeeping.  Flags of steps before the previous one are settled
+        FIRST; the previous step's own flag stays in flight, so this
+        program queues behind the running one, and `_step_lr` hands it
+        both rates that flag decides between."""
+        self._resolve_pending_overflow(keep_newest=True)
         self.tput_timer.start()
         batch = self._shard_batch(batch)
         self._autotune_batch = batch  # probe replay (never donated)
@@ -1870,8 +1906,7 @@ class DeepSpeedEngine:
         theta = jnp.asarray(
             self.progressive_layer_drop.get_theta()
             if self.progressive_layer_drop else 1.0, jnp.float32)
-        cur_lr = self._current_lr()
-        lr = None if cur_lr is None else jnp.asarray(cur_lr, jnp.float32)
+        lr = self._step_lr()
         profiling = self._maybe_profile_flops(batch, rng, theta, lr=lr)
         args = (self._params, self._opt_state, self._scaler_state,
                 batch, rng, lr, theta)
@@ -2085,15 +2120,17 @@ class DeepSpeedEngine:
         return out
 
     def _boundary_step(self):
-        """The split/overlap boundary body: drain, apply, bookkeeping."""
+        """The split/overlap boundary body: drain, apply, bookkeeping.
+        As in `_fused_forward`, the previous boundary's overflow flag
+        stays in flight across the apply dispatch (older ones are
+        settled) and the apply program selects its rate on it."""
         if self._wall_clock_breakdown:
             self.timers("step").start()
         rsp = (self.run_monitor.span("step")
                if self.run_monitor is not None else None)
         self._drain_overlap()
-        self._resolve_pending_overflow()
-        cur_lr = self._current_lr()
-        lr = None if cur_lr is None else jnp.asarray(cur_lr, jnp.float32)
+        self._resolve_pending_overflow(keep_newest=True)
+        lr = self._step_lr()
         (self._params, self._opt_state, self._scaler_state, self._grad_acc,
          overflow, grad_norm, extras) = self._step_fns["apply"](
             self._params, self._opt_state, self._scaler_state,
@@ -2105,9 +2142,9 @@ class DeepSpeedEngine:
         # step, serializing Python dispatch against device compute (the
         # weight update itself is already branchless-correct in-device).
         # Step the scheduler optimistically; _resolve_pending_overflow
-        # rolls it back on the rare overflow step, reading the flag next
-        # boundary when the device has long finished.
-        self._pending_overflow = overflow
+        # rolls it back on the rare overflow step, reading the flag two
+        # boundaries on, when the device has long finished.
+        self._pending_overflow.append((overflow, self.global_steps))
         if self.lr_scheduler is not None:
             self.lr_scheduler.step()
         if self.progressive_layer_drop is not None:
@@ -2125,7 +2162,7 @@ class DeepSpeedEngine:
             # overflow first — else the emitted lr scalar is one scheduler
             # step ahead on an overflowed step. Without a monitor the
             # deferral stands; direct scheduler reads between steps may be
-            # one iteration ahead until the next step()/skipped_steps access.
+            # up to two iterations ahead until skipped_steps is read.
             self._resolve_pending_overflow()
         self._emit_monitor_scalars()
         self.tput_timer.stop(report_speed=False)
@@ -2179,7 +2216,7 @@ class DeepSpeedEngine:
         self._pending_full = None
         self._scaler_state = new_scaler
         self.global_steps += 1
-        self._pending_overflow = overflow
+        self._pending_overflow.append((overflow, self.global_steps))
         if self.lr_scheduler is not None:
             self.lr_scheduler.step()  # optimistic; rolled back on overflow
         if self.progressive_layer_drop is not None:
@@ -2195,23 +2232,50 @@ class DeepSpeedEngine:
         self._queue_step_log()
         self._emit_run_event(grad_norm=_grad_norm, overflow=overflow)
 
-    def _resolve_pending_overflow(self):
-        """Apply the host-side bookkeeping for the PREVIOUS step's overflow
-        flag (deferred to avoid a per-step device sync). The in-device
-        update already skipped the weights and halved the loss scale; here
-        we fix the counters and roll the optimistic scheduler step back."""
-        pending = getattr(self, "_pending_overflow", None)
-        if pending is None:
-            return
-        self._pending_overflow = None
-        if bool(pending):
+    def _resolve_pending_overflow(self, keep_newest=False):
+        """Settle the overflow flags the host has not looked at yet,
+        oldest first (deferred to avoid a per-step device sync).
+
+        Called plainly it settles ALL of them and blocks until the
+        device has produced the last: `skipped_steps`, a checkpoint
+        save, the monitor / `sync_timing` branches and the host-side
+        update paths (offload, Infinity) call it so, and always see
+        settled counters and a scheduler at its true index.
+
+        The hot path (`_fused_forward`, `_boundary_step`,
+        `_scan_train_batch`) passes `keep_newest`: the flag of the step
+        dispatched one call earlier stays in flight — the device has
+        not produced it, and reading it would hold the next dispatch
+        until the running step ends — and only older flags, of steps
+        that finished before the running one began, are settled.  One
+        found not ready (the host ran two steps ahead of the device) is
+        waited for and counted in `engine.overflow_flag.waits`."""
+        pending = self._pending_overflow
+        while len(pending) > (1 if keep_newest else 0):
+            flag, step = pending.pop(0)
+            if keep_newest and not flag.is_ready():
+                t0 = time.perf_counter()
+                flag.block_until_ready()
+                COUNTERS.add("engine.overflow_flag.waits",
+                             int(1e6 * (time.perf_counter() - t0)))
+            self._settle_overflow(flag, step)
+
+    def _settle_overflow(self, flag, step):
+        """One step's overflow outcome on the host.  The in-device
+        update already skipped the weights and halved the loss scale;
+        here we fix the counters and roll the optimistic scheduler step
+        back.  The log names the new scale only where the scaler state
+        the host holds is that step's (nothing newer is in flight)."""
+        if bool(flag):
             self._skipped_steps += 1
             if self.lr_scheduler is not None:
                 it = getattr(self.lr_scheduler, "last_batch_iteration", None)
                 if it is not None:  # step(-1) is valid (init state)
                     self.lr_scheduler.step(it - 1)  # undo optimistic step
-            log_dist(f"overflow: skipped step, new loss scale "
-                     f"{float(self._scaler_state['cur_scale'])}", ranks=[0])
+            scale = "" if self._pending_overflow else (
+                ", new loss scale "
+                f"{float(self._scaler_state['cur_scale'])}")
+            log_dist(f"overflow: skipped step {step}{scale}", ranks=[0])
 
     def _log_timers(self):
         """Windowed wall-clock breakdown (reference engine.py:1239-1284):
@@ -2440,7 +2504,7 @@ class DeepSpeedEngine:
                     self.backward()
                 self.step()
                 return self._last_loss
-        self._resolve_pending_overflow()
+        self._resolve_pending_overflow(keep_newest=True)
         rm = self.run_monitor
         if rm is not None:
             rm.step_start(self.global_steps)
@@ -2461,8 +2525,7 @@ class DeepSpeedEngine:
         theta = jnp.asarray(
             self.progressive_layer_drop.get_theta()
             if self.progressive_layer_drop else 1.0, jnp.float32)
-        cur_lr = self._current_lr()
-        lr = None if cur_lr is None else jnp.asarray(cur_lr, jnp.float32)
+        lr = self._step_lr()
         args = (self._params, self._opt_state, self._scaler_state,
                 stacked, rngs, lr, theta)
         if self._qwz_overlap is not None:
@@ -2884,9 +2947,9 @@ class DeepSpeedEngine:
 
     @property
     def skipped_steps(self):
-        """Resolves the deferred overflow flag first, so callers see
-        settled counters (the deferral is a dispatch optimization, not an
-        API change)."""
+        """Resolves every deferred overflow flag first (blocking on the
+        newest), so callers see settled counters (the deferral is a
+        dispatch optimization, not an API change)."""
         self._resolve_pending_overflow()
         return self._skipped_steps
 
@@ -3244,6 +3307,7 @@ class DeepSpeedEngine:
             self.global_steps = int(model_state.get("global_steps", 0))
             self.global_samples = int(model_state.get("global_samples", 0))
             self._skipped_steps = int(model_state.get("skipped_steps", 0))
+            self._pending_overflow.clear()  # the replaced state's flags
             self.micro_steps = int(model_state.get("micro_steps", 0))
             self.loaded_checkpoint_tag = os.path.basename(ckpt_dir)
             client_state = {k: v for k, v in model_state.items()
@@ -3305,6 +3369,7 @@ class DeepSpeedEngine:
         self.global_steps = int(model_state.get("global_steps", 0))
         self.global_samples = int(model_state.get("global_samples", 0))
         self._skipped_steps = int(model_state.get("skipped_steps", 0))
+        self._pending_overflow.clear()  # flags of the state just replaced
         self.micro_steps = int(model_state.get("micro_steps", 0))
         self._grad_acc = None
         self.loaded_checkpoint_tag = os.path.basename(ckpt_dir)

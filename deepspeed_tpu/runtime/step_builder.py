@@ -53,6 +53,27 @@ from ..utils.logging import log_dist
 from .utils import clip_grad_norm, has_overflow
 
 
+class StepLR(NamedTuple):
+    """The learning rate of a step dispatched while the step before it
+    may still be running.  An overflow in that step rolls the scheduler
+    one index back, so the host cannot know which of two rates this
+    step needs until the flag arrives — and waiting for it would drain
+    the device between steps.  The program is handed both rates and
+    the flag as the device value it already is, and selects, as the
+    update itself does on its own step's flag."""
+
+    rates: jax.Array          # f32[2]: previous step applied, skipped
+    prev_overflow: jax.Array  # bool[]; a constant false once settled
+
+
+def select_lr(lr):
+    """A `StepLR` resolved in the program; a plain scalar or None
+    (the optimizer's own default) passes through."""
+    if isinstance(lr, StepLR):
+        return jnp.where(lr.prev_overflow, lr.rates[1], lr.rates[0])
+    return lr
+
+
 class StepSchedule(NamedTuple):
     """The declarative plan `StepBuilder.build` composes programs from."""
 
@@ -324,6 +345,7 @@ class StepBuilder:
             single body behind BOTH the boundary apply program and the
             fused/scan programs' in-program tail (gas_div folds the
             accumulation count into the unscale denominator)."""
+            lr = select_lr(lr)
             loss_scale = scaler_state["cur_scale"]
             overflow = has_overflow(grads)
             denom = loss_scale * gas_div
