@@ -2,6 +2,7 @@
 its model tests drive an external Megatron GPT-2, SURVEY.md §1)."""
 
 from .bert import Bert, BertConfig, bert_config, BERT_SIZES
+from .deepseek_v2 import DeepSeekV2, DeepSeekV2Config
 from .evabyte import EvaByte, EvaByteConfig
 from .gpt import GPT, GPTConfig, gpt2_config, GPT2_SIZES
 from .layer_spec import LayerSpec
@@ -13,6 +14,7 @@ from .hf import (bert_config_from_hf, gpt2_config_from_hf,
 __all__ = ["GPT", "GPTConfig", "gpt2_config", "GPT2_SIZES",
            "gpt_pipeline_module",
            "Bert", "BertConfig", "bert_config", "BERT_SIZES",
-           "EvaByte", "EvaByteConfig", "LayerSpec",
+           "EvaByte", "EvaByteConfig", "DeepSeekV2", "DeepSeekV2Config",
+           "LayerSpec",
            "load_hf_gpt2", "gpt2_config_from_hf",
            "load_hf_bert", "bert_config_from_hf", "generate"]

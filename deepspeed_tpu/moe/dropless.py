@@ -1,0 +1,103 @@
+"""A dropless routed FFN for programs that serve: every token-expert
+assignment the router makes is computed, at any batch — no capacity, no
+overflow bucket, nothing dropped.  (The trainer's MoE, moe/layer.py, is
+GShard capacity routing; this is what `serving/layers.py` and a served
+model's uncached forward use.)
+
+Routing: the router's product and its softmax over all E experts in
+float32 at full precision, the `top_k` largest taken greedily, their
+weights used as they are (no renormalisation).
+
+Two ways to the same sum y_t = sum_i w_ti E_i(x_t), chosen by
+`routed_experts` from what the call can see (its static shapes):
+
+* `experts_masked`: every expert on every token, weighted 0 where the
+  token did not choose it.  Streams each expert's weights once and does
+  E / top_k times the products, which is free while the call is bound by
+  the weights' bytes: where its assignments cover the experts anyway
+  (T * top_k >= E) and T is under the chip's ridge (`RIDGE_TOKENS`
+  operations a byte) — a decode step of tens of slots.
+* `experts_grouped`: assignments sorted by expert, one grouped product
+  (`lax.ragged_dot`) a matrix over the experts held, the results put
+  back in token order.  top_k products a token: a prefill chunk, or a
+  decode step too small to touch every expert.
+
+An expert is a SiLU-gated FFN; `experts` holds `gate`, `up` [E, D, F]
+and `down` [E, F, D].
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+# tokens a call multiplies each streamed weight with before the products
+# cost more than the bytes: ~240 on a v5e (197 TFLOP/s over 819 GB/s);
+# half of it leaves the masked path bound by bytes with room
+RIDGE_TOKENS = 128
+
+
+def route(h, router, top_k: int):
+    """h [T, D], router [D, E] -> (weights [T, top_k] float32, experts
+    [T, top_k] int32): softmax over E in float32, the top_k largest."""
+    scores = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    weights, idx = jax.lax.top_k(jax.nn.softmax(scores, axis=-1), top_k)
+    return weights, idx.astype(jnp.int32)
+
+
+def experts_touched(idx, live, num_experts: int):
+    """Experts with at least one assignment from a live token: idx
+    [T, top_k], live [T] bool -> int32 scalar."""
+    hit = jnp.zeros((num_experts,), jnp.int32).at[idx.reshape(-1)].max(
+        jnp.repeat(live.astype(jnp.int32), idx.shape[1]))
+    return hit.sum()
+
+
+def _dot32(x, w, dims):
+    return jnp.einsum(dims, x.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
+def experts_masked(x, experts, weights, idx):
+    """Every expert on every token; x [T, D] -> [T, D] float32."""
+    E = experts["gate"].shape[0]
+    # w[t, e]: the token's weight for expert e, 0 where it was not chosen
+    w = jnp.zeros((x.shape[0], E), jnp.float32).at[
+        jnp.arange(x.shape[0])[:, None], idx].add(weights)
+    g = _dot32(x, experts["gate"], "td,edf->etf")
+    u = _dot32(x, experts["up"], "td,edf->etf")
+    out = _dot32(jax.nn.silu(g) * u, experts["down"], "etf,efd->etd")
+    return jnp.einsum("etd,te->td", out, w)
+
+
+def experts_grouped(x, experts, weights, idx):
+    """Assignments sorted by expert, grouped products over the experts
+    held; x [T, D] -> [T, D] float32."""
+    T, k = idx.shape
+    E = experts["gate"].shape[0]
+    flat = idx.reshape(T * k)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+    dt = experts["gate"].dtype
+    xs = x.astype(dt)[order // k]                              # [T*k, D]
+
+    def grouped(a, w):
+        return jax.lax.ragged_dot(a, w, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    h = jax.nn.silu(grouped(xs, experts["gate"])) * \
+        grouped(xs, experts["up"])
+    out = grouped(h.astype(dt), experts["down"])               # [T*k, D]
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
+    return jnp.einsum("tkd,tk->td", out[back].reshape(T, k, -1), weights)
+
+
+def routed_experts(x, experts, weights, idx):
+    """sum_i w_ti E_i(x_t) for x [T, D], by the cheaper of the two ways
+    at this call's shapes."""
+    T, k = idx.shape
+    E = experts["gate"].shape[0]
+    if T * k >= E and T <= RIDGE_TOKENS:
+        return experts_masked(x, experts, weights, idx)
+    return experts_grouped(x, experts, weights, idx)

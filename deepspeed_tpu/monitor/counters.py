@@ -119,7 +119,17 @@ Instrumented sites:
   the EVA kernel runs, every entry of the table where the jnp oracle
   does), so rows_read / rows_walked is the share of a step's reads
   that it needs.
-  Paged attention (every other served model): `serve.paged.rows_walked`
+  Latent rows and routed experts (a served model with "latent"
+  attention and a "routed_experts" FFN): `serve.mla.rows_read` — calls
+  = queries decoded, bytes = latent rows they attend (one a cached
+  token, shared by all heads); `serve.mla.context_tokens` — bytes =
+  the same queries' cached lengths (equal while every cached row is
+  attended); `serve.moe.assignments` — calls = routed-layer calls,
+  bytes = token-expert pairs computed (tokens x top_k, nothing
+  dropped); `serve.moe.experts_touched` — calls = decode steps x
+  routed layers, bytes = experts with at least one active slot's
+  token (counted in the program, read back with the step's tokens).
+  Paged attention (the GPT family): `serve.paged.rows_walked`
   — calls = slots decoded, bytes = pool rows their attention reads (a
   slot's live blocks where the paged kernel runs, the table's whole
   width where the jnp oracle does); `serve.paged.context_tokens` —

@@ -4,7 +4,7 @@ One `ServeEngine` owns: a `PagedKVCache` (block pool + free list), a
 `Scheduler` (admission + slots), and the jitted {prefill, decode}
 program pair from `ServeProgramBuilder`.  It serves any model that
 hands over a layer spec (`model.layer_spec()`, models/layer_spec.py):
-the GPT family and EvaByte today.  `step()` is the whole serving
+the GPT family, EvaByte and DeepSeek-V2 today.  `step()` is the whole serving
 loop body — admit, prefill one chunk round, decode one token for every
 running slot — and everything else (the bench's Poisson arrival thread,
 `generate()`'s synchronous loop, a `ServeWorker` daemon) just drives
@@ -65,6 +65,22 @@ attention fetches for the queries `rows_read` counts: the live window
 and summary blocks where the registry picks the kernel for the decode
 program's shapes, the table's whole width where it picks the oracle);
 host span `eva.window_close`.
+
+Latent rows and routed experts (a layer spec with "latent" attention
+and a "routed_experts" FFN): the cache holds one row a token for all
+heads, under the same allocator and tables as the paged one, and the
+programs pick their attention products from the call's query count.
+For such a model the engine refuses, by name, `prefix_cache=True`,
+sessions, `draft_len > 0`, quantized weights, int8/int4 rows and a mesh
+of more than one device.  Counters: `serve.mla.rows_read` (calls =
+queries decoded, bytes = latent rows they attend),
+`serve.mla.context_tokens` (bytes = the same queries' cached lengths:
+equal to the rows read while every cached row is attended),
+`serve.moe.assignments` (calls = routed-layer calls, bytes =
+token-expert pairs they computed: tokens x top_k, nothing dropped) and
+`serve.moe.experts_touched` (calls = decode steps x routed layers,
+bytes = experts with at least one active slot's token, counted in the
+program and read back with the step's tokens).
 
 What a paged step reads: `serve.paged.rows_walked` (calls = slots
 decoded, bytes = the pool rows attention reads for them: a slot's live
@@ -223,6 +239,25 @@ class ServeEngine:
 
             mesh_info = peek_mesh()
         self.mesh_info = mesh_info
+        if spec.attention == "latent":
+            if c.prefix_cache:
+                raise NotImplementedError(
+                    "prefix_cache=True over latent rows: a shared block "
+                    "would be read by the expanded path in one request's "
+                    "prefill and by the absorbed path in another's decode, "
+                    "and the prefix cache's bitwise pins are not proven "
+                    "across the two; pass prefix_cache=False")
+            if mesh_info is not None and mesh_info.size > 1:
+                raise NotImplementedError(
+                    f"a mesh of {mesh_info.size} devices over latent rows "
+                    f"and routed experts: one row serves every head, so "
+                    f"the head split of the pool does not apply, and an "
+                    f"expert layer that holds a share of the experts is "
+                    f"not built; serve it on one device")
+        # routed-FFN layers, for serve.moe.*
+        self._routed_layers = (cfg.num_layers - spec.dense_layers
+                               if spec.ffn == "routed_experts" else 0)
+        self._top_k = spec.top_k
         kv_dtype = cfg.param_dtype if c.kv_dtype is None else c.kv_dtype
         kv_mode = resolve_kv_dtype(kv_dtype)[0]
         schedule = ServeSchedule(
@@ -253,7 +288,8 @@ class ServeEngine:
             prefix_cache=c.prefix_cache,
             min_match_blocks=c.prefix_min_match_blocks,
             prefix_salt=prefix_salt,
-            window_tokens=window_blocks * c.block_size)
+            window_tokens=window_blocks * c.block_size,
+            latent_width=spec.latent_width)
         self.scheduler = Scheduler(self.kv, c.max_batch,
                                    admission=c.admission, clock=clock,
                                    draft_len=int(c.draft_len))
@@ -276,8 +312,10 @@ class ServeEngine:
         info = (paged_info(cfg, schedule, q_len, pool.dtype)
                 if spec.attention == "paged"
                 else eva_info(spec, cfg, schedule, q_len, pool.dtype))
-        self._walks_live_blocks = registry.resolve_impl(
-            f"{spec.attention}_attention", info=info) == "pallas"
+        # latent rows have no kernel: the table's rows are gathered
+        self._walks_live_blocks = spec.attention != "latent" and \
+            registry.resolve_impl(
+                f"{spec.attention}_attention", info=info) == "pallas"
         self.params = programs["prepare_params"](
             self._place_params(params))
         logger.info(f"serving engine up: {schedule.describe()}; "
@@ -349,6 +387,11 @@ class ServeEngine:
             raise ValueError(
                 f"top_k must be >= 0 and temperature >= 0, got "
                 f"{top_k}, {temperature}")
+        if session_id is not None and self.kv.latent_width:
+            raise NotImplementedError(
+                "sessions over latent rows: a pin keeps rows that decode "
+                "wrote, and the next turn's prefill would expand them "
+                "beside rows of its own; not proven, so not offered")
         if session_id is not None and self.kv.windowed:
             raise NotImplementedError(
                 "sessions over summarised windows: a pin would have to "
@@ -605,6 +648,7 @@ class ServeEngine:
         if self.kv.windowed:
             self._close_full_window(req)
         COUNTERS.add("serve.prefill_chunks", nbytes=n_valid)
+        self._count_assignments(n_valid)
         if tr is not None:
             # cached/computed: the prefix-cache outcome per request —
             # how many prompt tokens this request never prefilled
@@ -674,6 +718,12 @@ class ServeEngine:
                 COUNTERS.add("serve.eva.rows_walked", nbytes=C * (
                     sum(live_blocks(p, W, C, C)) if self._walks_live_blocks
                     else self.kv.table_width))
+        elif self.kv.latent_width:
+            held = self._positions[[r.slot for r in running]].astype(
+                np.int64) + 1
+            for name in ("serve.mla.rows_read", "serve.mla.context_tokens"):
+                COUNTERS.add(name, calls=len(running),
+                             nbytes=int(held.sum()))
         else:
             self._count_rows_walked(running, 1)
         t0 = time.perf_counter()
@@ -687,6 +737,12 @@ class ServeEngine:
         self._record_dequant(t0)
         now = self.clock()
         COUNTERS.add("serve.decode_steps", nbytes=len(running))
+        if self._routed_layers:
+            # behind the slots' tokens: the experts the step touched
+            self._count_assignments(len(running))
+            COUNTERS.add("serve.moe.experts_touched",
+                         calls=self._routed_layers,
+                         nbytes=int(toks[len(self._tokens)]))
         for req in running:
             slot = req.slot
             tok = int(toks[slot])
@@ -709,6 +765,14 @@ class ServeEngine:
             tr.add_complete("decode_step", "serve", ts_us=tus0,
                             dur_us=tr.now_us() - tus0, step=self.steps,
                             batch=len(running))
+
+    def _count_assignments(self, n_tokens: int) -> None:
+        """Token-expert pairs a call over `n_tokens` tokens computes in
+        its routed layers: every one the router makes."""
+        if self._routed_layers:
+            COUNTERS.add(
+                "serve.moe.assignments", calls=self._routed_layers,
+                nbytes=n_tokens * self._top_k * self._routed_layers)
 
     def _count_rows_walked(self, running: List[Request],
                            n_queries: int) -> None:

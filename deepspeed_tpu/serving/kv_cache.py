@@ -96,6 +96,16 @@ and are not free for admission, so `extend()` never fails.  The prefix
 cache, session pins and quantized rows are not offered for such a cache
 (the engine refuses them by name).
 
+One row for all heads (`latent_width > 0`, the latent layer spec): a
+token's cache row is not H keys and H values but one latent of
+`latent_width` values that every head attends ([c_kv | k_rope],
+models/deepseek_v2.py).  A layer's entry is then ONE array
+`[rows, pool_width(1, latent_width)]` (576 -> 640 lanes) and not a
+(K, V) pair; blocks, tables, the free list and admission are the same
+— the allocator counts blocks and never looks at a row's width.  The
+prefix cache, session pins, quantized rows and a mesh are not offered
+for such a cache (the engine refuses them by name).
+
 Block 0 is the reserved TRASH block: the allocator never hands it out,
 block tables are padded with it, and inactive decode slots write to it —
 so the jitted programs need no branches for "this slot/table entry is
@@ -224,7 +234,8 @@ class PagedKVCache:
                  num_blocks: int, block_size: int, table_width: int,
                  dtype=jnp.float32, mesh_info=None,
                  prefix_cache: bool = True, min_match_blocks: int = 1,
-                 prefix_salt: str = "", window_tokens: int = 0):
+                 prefix_salt: str = "", window_tokens: int = 0,
+                 latent_width: int = 0):
         if num_blocks < 2:
             raise ValueError(
                 f"num_blocks must be >= 2 (block 0 is the reserved trash "
@@ -257,8 +268,17 @@ class PagedKVCache:
                 "a windowed cache takes no prefix cache and needs table "
                 "entries for its summary blocks beyond the window's "
                 f"{self.window_blocks}")
+        # > 0: one row of so many values a token for all heads, and a
+        # layer's entry one array, not a (K, V) pair
+        self.latent_width = int(latent_width)
         self.dtype = dtype
         mode, dense_dtype = resolve_kv_dtype(dtype)
+        if self.latent_width and (mode != "dense" or prefix_cache
+                                  or self.windowed or mesh_info is not None
+                                  and mesh_info.size > 1):
+            raise ValueError(
+                "a cache of latent rows is dense, on one device, with no "
+                "prefix cache and no window")
         # "int8"/"int4" when blocks are stored quantized, else None
         self.quant_wire = mode if mode in KV_QUANT_WIRES else None
         self.dense_dtype = dense_dtype
@@ -328,6 +348,10 @@ class PagedKVCache:
 
     def _init_caches(self):
         rows = self.num_blocks * self.block_size
+        if self.latent_width:
+            shape = (rows, pool_width(1, self.latent_width))
+            return [(jnp.zeros(shape, self.dense_dtype),)
+                    for _ in range(self.num_layers)]
         if self.quant_wire is None:
             shape = (rows, pool_width(self.num_heads, self.head_dim))
 
@@ -761,8 +785,11 @@ class PagedKVCache:
             f"({self.table_width - self.window_blocks} blocks)")
         return (f"PagedKVCache(layers={self.num_layers}, "
                 f"blocks={self.num_blocks} x {self.block_size} rows, {rows}, "
-                f"table_width={self.table_width}, heads={self.num_heads}, "
-                f"head_dim={self.head_dim}, kv={mode}, "
+                f"table_width={self.table_width}, " + (
+                    f"one latent row of {self.latent_width}, "
+                    if self.latent_width else
+                    f"heads={self.num_heads}, head_dim={self.head_dim}, ")
+                + f"kv={mode}, "
                 f"prefix_cache={'on' if self.prefix_enabled else 'off'}, "
                 f"sharded={self._sharding is not None}, "
                 f"{self.nbytes() / (1 << 20):.2f} MiB)")

@@ -5,10 +5,14 @@ its prefill, decode and verify programs from these and knows no model by
 name.
 
 What is built today: learned positions with paged attention (the GPT-2
-block: fused QKV with bias, every cached position attended), and rotary
+block: fused QKV with bias, every cached position attended), rotary
 positions with EVA attention (the EvaByte block: exact rows for the open
-window, one summary row a chunk behind it).  The norm, the FFN and the
-head are free of that choice.
+window, one summary row a chunk behind it), and rotary positions over
+part of a head with latent attention (the DeepSeek-V2 block: one latent
+row a token for all heads, expanded to keys and values where many
+queries share the expansion, attended as it lies where a query stands
+alone).  The norm, the FFN — a routed-experts FFN among them, behind
+leading dense layers — and the head are free of that choice.
 
 The paged pieces mirror models/generation.py `_block_with_cache` op for
 op (fp32 scores, the same einsum strings, NEG_INF masking, probs cast to
@@ -19,9 +23,11 @@ The EVA pieces' statements stand in the order the programs had them
 before they moved here: the order of independent operations is part of a
 program's StableHLO, which keys the compilation cache.
 
-The pool is `[rows, pool_width(H, Dh)]` (serving/kv_cache.py) for both
-attentions: a token's (or a chunk summary's) heads side by side in one
-row.  A call's K/V are written as such rows by one scatter, in place,
+The pool is `[rows, pool_width(H, Dh)]` (serving/kv_cache.py) for the
+first two attentions: a token's (or a chunk summary's) heads side by side
+in one row, a K and a V array a layer.  Latent attention has one array a
+layer, `[rows, pool_width(1, latent_width)]`, addressed like the paged
+one.  A call's K/V are written as such rows by one scatter, in place,
 and attention reads the pool as it lies — through the table's live
 blocks on the chip at `q_len` <= 8 (kernels/paged.py: decode and verify;
 kernels/eva.py: decode), by a gather of the table's rows elsewhere and
@@ -48,13 +54,16 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..models.deepseek_v2 import (absorb, attend_absorbed, attend_expanded,
+                                  expert_ffn, latent_project, rms_norm_plain)
 from ..models.evabyte import (chunk_summaries, matmul32, project_qkv,
                               rms_norm, silu_gated_ffn)
 from ..models.gpt import layer_norm
 from ..models.layer_spec import LayerSpec
+from ..moe.dropless import experts_touched
 from .kv_cache import pool_rows
 
-BUILT = {("learned", "paged"), ("rope", "eva")}
+BUILT = {("learned", "paged"), ("rope", "eva"), ("rope", "latent")}
 
 
 def check_spec(spec: LayerSpec) -> LayerSpec:
@@ -111,7 +120,7 @@ def address_chunk(spec, s, table, pos, abs_pos, n_valid) -> Addr:
     """One request's prefill chunk at positions abs_pos [C] through its
     table [W]."""
     bs, W = s.block_size, s.table_width
-    if spec.attention == "paged":
+    if spec.attention != "eva":
         blk_i = abs_pos // bs
         # positions past the table (pad rows of the final chunk)
         # write to the trash block, never a neighbour's memory
@@ -144,7 +153,7 @@ def address_step(spec, s, tables, positions, active) -> Addr:
     """One token for every slot at positions [R] through tables [R, W].
     Inactive slots write to the trash block."""
     bs, W = s.block_size, s.table_width
-    if spec.attention == "paged":
+    if spec.attention != "eva":
         blk_i = positions // bs
         blk = jnp.take_along_axis(
             tables, jnp.clip(blk_i, 0, W - 1)[:, None], axis=1)[:, 0]
@@ -311,14 +320,41 @@ def _eva_attend(spec, cfg, p, h, ck, cv, addr, s):
     return matmul32(attn.reshape(B, T, D), p["o"]), ck, cv
 
 
+def _latent_attend(cfg, p, h, pool, addr, s):
+    """Queries and this call's latent rows ([c after its norm | rotated
+    key], one a token for all heads) at the cache's dtype; the rows
+    written through the table; causal softmax over every cached row of
+    the slot, gathered through its table — the rows expanded through
+    W_kv_b where the call's queries share that product (a prefill
+    chunk), W_kv_b absorbed into query and output where they do not
+    (decode); output projection.  -> float32."""
+    B, T, _ = h.shape
+    q_nope, q_rope, rows = latent_project(cfg, p, h, addr.q_pos, pool.dtype)
+    pool = _kv_write(pool, addr.write_idx, rows.reshape(B * T, 1, -1),
+                     "dense")
+    # the table's blocks as slabs of `block_size` rows, not row by row:
+    # a gather of 1,280-byte rows ran at an eighth of the chip's
+    # bandwidth (PERF.md, PR 37)
+    lanes = pool.shape[1]
+    held = pool.reshape(-1, s.block_size, lanes)[addr.tables].reshape(
+        B, -1, lanes)[..., :rows.shape[-1]]                # [B, L, width]
+    L = held.shape[1]
+    mask = addr.q_pos[:, :, None] >= jnp.arange(L)[None, None, :]
+    attend = attend_absorbed if absorb(cfg, T, L) else attend_expanded
+    out = attend(cfg, p["kv_b"], q_nope, q_rope, held, mask)
+    return matmul32(out, p["o"]), pool
+
+
 def _norm(spec, x, p):
     if spec.norm == "layernorm":
         return layer_norm(x, p, spec.eps)
+    if spec.norm == "rmsnorm":
+        return rms_norm_plain(x, p, spec.eps)
     return rms_norm(x, p, spec.eps)
 
 
 def _ffn(spec, p, h):
-    if spec.ffn == "silu_gated":
+    if spec.ffn != "gelu_mlp":   # a routing model's leading dense layers too
         return silu_gated_ffn(p, h)
     h = h @ p["fc1"]["w"].astype(h.dtype) + \
         p["fc1"]["b"].astype(h.dtype)
@@ -327,17 +363,27 @@ def _ffn(spec, p, h):
         p["fc2"]["b"].astype(h.dtype)
 
 
-def block(spec, cfg, p, x, ck, cv, addr, s):
-    """One pre-norm decoder block over x [B, T, D] through the cache of
-    a program of schedule `s`."""
+def block(spec, cfg, p, x, kv, addr, s, layer: int = 0):
+    """Pre-norm decoder block number `layer` over x [B, T, D] through
+    its entry `kv` of the cache of a program of schedule `s` -> (x, kv,
+    touched): `touched` is None, or, behind a routed FFN, how many of
+    its experts the call's live tokens (`addr.q_pos` >= 0) chose."""
     h = _norm(spec, x, p["ln1"])
     if spec.attention == "paged":
-        attn, ck, cv = _paged_attend(cfg, p["attn"], h, ck, cv, addr, s)
+        attn, *kv = _paged_attend(cfg, p["attn"], h, *kv, addr, s)
+    elif spec.attention == "eva":
+        attn, *kv = _eva_attend(spec, cfg, p["attn"], h, *kv, addr, s)
     else:
-        attn, ck, cv = _eva_attend(spec, cfg, p["attn"], h, ck, cv, addr, s)
+        with jax.named_scope("mla_attend"):
+            attn, *kv = _latent_attend(cfg, p["attn"], h, *kv, addr, s)
     x = x + attn
     h = _norm(spec, x, p["ln2"])
-    return x + _ffn(spec, p["mlp"], h), ck, cv
+    if spec.ffn != "routed_experts" or layer < spec.dense_layers:
+        return x + _ffn(spec, p["mlp"], h), tuple(kv), None
+    y, idx = expert_ffn(cfg, p["mlp"], h)
+    touched = experts_touched(idx, addr.q_pos.reshape(-1) >= 0,
+                              p["mlp"]["router"].shape[1])
+    return x + y, tuple(kv), touched
 
 
 # -- head -------------------------------------------------------------------
@@ -355,6 +401,8 @@ def logits(spec, params, x_rows):
     if spec.fp32_logits:
         return jnp.dot(x_rows, w.astype(jnp.float32),
                        precision=jax.lax.Precision.HIGHEST)
+    if x_rows.dtype != w.dtype:  # float32 rows of a bf16 model: multiply
+        return matmul32(x_rows, w)  # at the head's dtype, do not copy it
     return (x_rows @ w.astype(x_rows.dtype)).astype(jnp.float32)
 
 

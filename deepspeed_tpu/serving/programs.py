@@ -41,6 +41,13 @@ runtime/comm/quant.py's row kernels and dequantize gathered rows to
 fp32 in-program.  The surrounding attention math is shared, so parity
 contracts hold at matched kv_dtype.
 
+A spec with "latent" attention (one row a token for all heads) runs the
+same two programs over the paged table; its block picks the expanded or
+the absorbed products from the call's query count (serving/layers.py).
+Behind a routed-experts FFN the decode program appends to its tokens how
+many experts the step touched.  `verify`, quantized weights and
+quantized rows are not built for it.
+
 A spec with "eva" attention (exact rows for an open window, summary
 rows behind it) runs the same two programs over a table of
 `[window blocks | summary blocks]`; the block also writes the summary
@@ -246,6 +253,8 @@ class ServeProgramBuilder:
                 f"{schedule.draft_len}")
         if self.spec.attention == "eva":
             self._check_eva(schedule)
+        if self.spec.attention == "latent":
+            self._check_latent(schedule)
         self.model = model
         self.schedule = schedule
 
@@ -285,6 +294,28 @@ class ServeProgramBuilder:
                 f"kv_dtype {s.kv_dtype!r} over summarised windows: "
                 f"summary rows are pooled in float32 from the stored "
                 f"rows and have no quantized codec yet")
+
+    @staticmethod
+    def _check_latent(s: ServeSchedule) -> None:
+        """What is not built over latent rows."""
+        if s.draft_len:
+            raise NotImplementedError(
+                "draft_len > 0 over latent rows: the verify program "
+                "scores a few queries a slot, between the shapes the "
+                "expanded and the absorbed path were measured at, and is "
+                "not proven against the reference")
+        if s.quantized != "none":
+            raise NotImplementedError(
+                "quantized_weights over latent rows: the qwZ store is "
+                "written for the GPT parameter tree's matmul leaves and "
+                "is not proven on stacked experts or on W_kv_b, whose "
+                "two halves the decode path multiplies separately")
+        if s.kv_dtype != "dense":
+            raise NotImplementedError(
+                f"kv_dtype {s.kv_dtype!r} over latent rows: the row "
+                f"codecs scale per (row, head), and a latent row has no "
+                f"heads — its norm-ed latent and its rotary key would "
+                f"need scales of their own")
 
     def build(self) -> dict:
         logger.info(self.schedule.describe())
@@ -333,9 +364,9 @@ class ServeProgramBuilder:
             addr = layers.address_chunk(spec, s, table, pos, abs_pos,
                                         n_valid)
             new_caches = []
-            for bp, (ck, cv) in zip(params["blocks"], caches):
-                x, ck, cv = layers.block(spec, cfg, bp, x, ck, cv, addr, s)
-                new_caches.append((ck, cv))
+            for i, (bp, kv) in enumerate(zip(params["blocks"], caches)):
+                x, kv, _ = layers.block(spec, cfg, bp, x, kv, addr, s, i)
+                new_caches.append(kv)
             x = layers.final_norm(spec, params, x)
             last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
             logits = layers.logits(spec, params, last[:, 0, :])  # [1, V]
@@ -348,18 +379,22 @@ class ServeProgramBuilder:
 
     def step_logits(self, params, caches, tokens, positions, active,
                     tables):
-        """One decode step up to its logits: (logits [R, V], caches).
-        `decode` is this and per-slot sampling; a test that wants the
-        logits a token was drawn from jits this itself."""
+        """One decode step up to its logits: (logits [R, V], caches,
+        touched).  `decode` is this and per-slot sampling; a test that
+        wants the logits a token was drawn from jits this itself.
+        `touched` holds, for each layer with a routed FFN, how many of
+        its experts the active slots chose."""
         cfg, spec, s = self.model.config, self.spec, self.schedule
         x = layers.embed_step(spec, params, tokens, positions)
         addr = layers.address_step(spec, s, tables, positions, active)
-        new_caches = []
-        for bp, (ck, cv) in zip(params["blocks"], caches):
-            x, ck, cv = layers.block(spec, cfg, bp, x, ck, cv, addr, s)
-            new_caches.append((ck, cv))
+        new_caches, touched = [], []
+        for i, (bp, kv) in enumerate(zip(params["blocks"], caches)):
+            x, kv, n = layers.block(spec, cfg, bp, x, kv, addr, s, i)
+            new_caches.append(kv)
+            if n is not None:
+                touched.append(n)
         x = layers.final_norm(spec, params, x)
-        return layers.logits(spec, params, x[:, -1, :]), new_caches
+        return layers.logits(spec, params, x[:, -1, :]), new_caches, touched
 
     def _build_decode(self):
         spec = self.spec
@@ -372,13 +407,18 @@ class ServeProgramBuilder:
             length), active [R] bool, tables [R, W], sampling params
             [R].  Inactive slots write to the trash block and their
             outputs are discarded by the engine — all slot math is
-            row-wise, THE batching-invariance contract."""
+            row-wise, THE batching-invariance contract.  Behind routed
+            FFNs the tokens [R] are followed by one more entry, the
+            experts the step touched summed over those layers: it rides
+            the one transfer the engine makes a step."""
             params = self._maybe_dequant(params)
-            logits, new_caches = self.step_logits(
+            logits, new_caches, touched = self.step_logits(
                 params, caches, tokens, positions, active, tables)
             keys = jax.vmap(_row_key)(seeds, positions + 1)
             toks = jax.vmap(sample_token)(layers.sampled(spec, logits),
                                           temperatures, top_ks, keys)
+            if touched:
+                toks = jnp.concatenate([toks, sum(touched)[None]])
             return toks, new_caches
 
         return decode
@@ -420,9 +460,9 @@ class ServeProgramBuilder:
             addr = layers.address_grid(spec, s, tables, abs_pos, active,
                                        n_draft)
             new_caches = []
-            for bp, (ck, cv) in zip(params["blocks"], caches):
-                x, ck, cv = layers.block(spec, cfg, bp, x, ck, cv, addr, s)
-                new_caches.append((ck, cv))
+            for i, (bp, kv) in enumerate(zip(params["blocks"], caches)):
+                x, kv, _ = layers.block(spec, cfg, bp, x, kv, addr, s, i)
+                new_caches.append(kv)
             x = layers.final_norm(spec, params, x)
             logits = layers.logits(
                 spec, params,

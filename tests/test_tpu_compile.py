@@ -576,6 +576,64 @@ def test_evabyte_decode_walks_the_pool_as_it_lies(one_chip, native):
 
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_deepseek_cell_programs_compile_inside_one_chip(program, one_chip):
+    """`deepseek-v2-lite-d9.serve.chatgen.decode` / `.prefill` at the
+    cell's shapes (9 layers at published widths in bf16, 32 slots, 8,193
+    blocks of 16 latent rows, a table of 256 entries, chunk 512): the
+    registry is not asked — latent attention and the routed FFN are
+    `jax.numpy` — so `decode` holds no kernel at all and the only custom
+    calls in `prefill` are XLA's own grouped products (`lax.ragged_dot`
+    over the 64 experts); each pool enters as `[rows, 640]`, one array a
+    layer; and weights, pool and temporaries fit the chip's 15.75 GB
+    with the room the check's 1.68 GB of reference logits needs."""
+    from deepspeed_tpu.models import DeepSeekV2, DeepSeekV2Config
+    from deepspeed_tpu.serving import ServeProgramBuilder, ServeSchedule
+
+    slots, bs, nblocks, layers, chunk = 32, 16, 8193, 9, 512
+    model = DeepSeekV2(DeepSeekV2Config(num_layers=layers,
+                                        param_dtype=jnp.bfloat16))
+    width = 4096 // bs
+    sched = ServeSchedule(max_batch=slots, prefill_chunk=chunk,
+                          block_size=bs, num_blocks=nblocks,
+                          table_width=width)
+    progs = ServeProgramBuilder(model, sched).build()
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda s: on(s.shape, s.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    held = sum(s.size * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    assert abs(held - 10.36e9) < 0.01e9
+    caches = [(on((nblocks * bs, 640), jnp.bfloat16),)] * layers
+    if program == "decode":
+        args = (on((slots,), jnp.int32), on((slots,), jnp.int32),
+                on((slots,), jnp.bool_), on((slots, width), jnp.int32),
+                on((slots,), jnp.float32), on((slots,), jnp.int32),
+                on((slots,), jnp.uint32))
+    else:
+        args = (on((1, chunk), jnp.int32), on((), jnp.int32),
+                on((), jnp.int32), on((width,), jnp.int32),
+                on((), jnp.float32), on((), jnp.int32), on((), jnp.uint32))
+    compiled = progs[program].lower(params, caches, *args).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    if program == "decode":
+        assert not calls
+    else:
+        assert calls and all("ragged" in ln for ln in calls)
+    pools = _hlo_by_shape(text)[(nblocks * bs, 640)]
+    assert {layout for _, layout in pools} == {"1,0"}  # row-major
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + \
+        m.output_size_in_bytes - m.alias_size_in_bytes
+    assert m.temp_size_in_bytes < 512 << 20
+    assert total < 15.75e9 - 1.68e9 - 1.0e9, total
+
+
 def test_evabyte_phase_after_the_described_compiles(topo):
     """This file, then tests/test_chip_smoke.py::test_evabyte_phase_toy,
     in one process: the order in which the toy EvaByte run chose bytes
