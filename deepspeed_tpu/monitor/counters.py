@@ -70,6 +70,10 @@ Instrumented sites:
   decoded (prefill first tokens included); `serve.decode_steps` —
   decode dispatches (bytes = active slots, so bytes/calls is the mean
   batch occupancy continuous batching exists to maximize);
+  `serve.decode_ahead` — calls = decode steps launched, bytes = those
+  launched while the step before was still unread;
+  `serve.decode_ahead.dropped` — lane-steps computed for a request
+  that had already ended (an `eos_token` found one step late);
   `serve.prefill_chunks` — chunked-prefill dispatches (bytes = prompt
   tokens); `serve.ttft_ms` — time-to-first-token (integer MICROSECONDS
   in the bytes slot, the ckpt.stall_ms convention; one call per first

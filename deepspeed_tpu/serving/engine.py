@@ -10,6 +10,44 @@ running slot — and everything else (the bench's Poisson arrival thread,
 `generate()`'s synchronous loop, a `ServeWorker` daemon) just drives
 `step()`.
 
+The loop runs one decode step ahead of what it has read.  One
+iteration, in order: (1) shed if asked, admit; (2) launch a prefill
+chunk for a request still in its prompt — a prompt's last chunk samples
+the first token, `seat` puts it into the slot's entry of the token
+vector on the device, and the request is running; (3) launch decode
+step i for every running request whose answer is not yet wholly
+computed: each slot's last token is step i-1's sample as it lies on the
+device (or `seat`'s), positions were advanced by step i-1 itself, and
+`active`, `tables`, `temperatures`, `top_ks`, `seeds` are the arrays
+step i-1 was handed unless a request joined, left, crossed a block or
+closed a window since — then a COPY of the engine's own rows is
+uploaded, so nothing a launched program reads is ever rewritten
+(`_SlotState`); (4) only now the one blocking read: step i-1's tokens,
+and the host's half of that step — append to `req.out`, stamp, finish,
+free — while step i runs; (5) the first token of this iteration's
+prompt-ending chunk, read as soon as the chunk has run, with step i
+queued behind it.  Decided AHEAD, from positions alone and in launch
+order: a request whose `max_new_tokens` the launched step completes is
+not in the next step; a window the launched step fills is closed and
+its blocks may be taken by whatever is launched next (the device runs
+programs in launch order, so a freed block is only written by a program
+queued after the one that last read it).  Found ONE STEP LATE, because
+only the tokens say it: with `eos_token` set, a request that ends at
+step i has already ridden step i+1 — that lane's token is dropped
+(`serve.decode_ahead.dropped`), its one extra row lies beyond the rows
+of its answer in a block it still held at the launch, and finishing
+(prefix registration, session pin, blocks back) happens when the host
+reads step i.  Outputs are those of a loop that reads every step before
+it launches the next, token for token.  A step after which nothing is
+left to launch is read at once; `run()`, `generate()`, a shed, a dead
+worker and `close()` read (or wait out) what is in flight before they
+report.  `draft_len > 0` keeps the serial loop — drafting reads the
+tokens — and that, with whether a request set `eos_token`, is all the
+loop adapts to: no option.  Counters: `serve.decode_ahead` (calls =
+decode steps launched, bytes = those launched while the step before was
+still unread) and `serve.decode_ahead.dropped` (calls = lane-steps
+computed for a request that had already ended).
+
 Resilience contract (the PR-8 machinery, applied to serving):
 
 * `fault_point` sites `serve.step` / `serve.admit` / `serve.prefill` /
@@ -80,7 +118,8 @@ equal to the rows read while every cached row is attended),
 token-expert pairs they computed: tokens x top_k, nothing dropped) and
 `serve.moe.experts_touched` (calls = decode steps x routed layers,
 bytes = experts with at least one active slot's token, counted in the
-program and read back with the step's tokens).
+program and read back with the step's tokens, one step after the
+launch).
 
 What a paged step reads: `serve.paged.rows_walked` (calls = slots
 decoded, bytes = the pool rows attention reads for them: a slot's live
@@ -102,10 +141,11 @@ at dense KV) — speculation changes WHEN tokens arrive, never WHICH.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import threading
 import time
-from typing import Any, List, Optional, Sequence
+from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -200,6 +240,65 @@ class SessionPin:
     cached_len: int
     blocks: int
     expires: float
+
+
+@dataclasses.dataclass
+class _First:
+    """A prompt's first token: sampled by `prefill`, not yet read."""
+
+    req: Request
+    tok: Any                          # device scalar
+
+
+@dataclasses.dataclass
+class _Step:
+    """A decode step: launched, its tokens not yet read."""
+
+    toks: Any                         # device [R] (+ 1 behind routed FFNs)
+    lanes: List[Tuple[Request, int]]  # (request, slot) as launched
+    t0: float                         # perf_counter at the launch
+    tus0: int                         # the tracer's clock at the launch
+    index: int                        # engine.steps at the launch
+
+
+class _SlotState:
+    """The packed decode-batch state, one row a slot, under the names
+    `decode` takes it by.  `host` is the engine's own copy, which it
+    rewrites at will; a program is only ever handed `on_device()`: an
+    upload of a COPY of each row set that changed since its last upload
+    (on the CPU `jnp.asarray` may alias the host array, and on any
+    backend a transfer may still be under way when the call returns),
+    else the array the step before was handed.  So nothing a launched
+    program reads is rewritten, and a step that no request joins or
+    leaves uploads nothing."""
+
+    def __init__(self, slots: int, table_width: int):
+        self.host = {
+            "positions": np.zeros((slots,), np.int32),
+            "active": np.zeros((slots,), bool),
+            "tables": np.full((slots, table_width), TRASH_BLOCK, np.int32),
+            "temperatures": np.zeros((slots,), np.float32),
+            "top_ks": np.zeros((slots,), np.int32),
+            "seeds": np.zeros((slots,), np.uint32)}
+        self._dev = {}
+        self._stale = set(self.host)
+
+    def set(self, slot: int, **rows) -> None:
+        for name, row in rows.items():
+            self.host[name][slot] = row
+        self._stale.update(rows)
+
+    def on_device(self) -> tuple:
+        for name in self._stale:
+            self._dev[name] = jnp.asarray(self.host[name].copy())
+        self._stale.clear()
+        return tuple(self._dev[name] for name in self.host)
+
+    def advanced(self, slots: List[int], positions) -> None:
+        """A launched step moved `slots` on by one; `positions` is what
+        it returned for the step after it."""
+        self.host["positions"][slots] += 1
+        self._dev["positions"] = positions
 
 
 class ServeEngine:
@@ -320,15 +419,15 @@ class ServeEngine:
             self._place_params(params))
         logger.info(f"serving engine up: {schedule.describe()}; "
                     f"{self.kv.describe()}")
-        # packed decode-batch state (one row per slot)
-        R, W = c.max_batch, table_width
-        self._tokens = np.zeros((R,), np.int32)
-        self._positions = np.zeros((R,), np.int32)
-        self._active = np.zeros((R,), bool)
-        self._tables = np.full((R, W), TRASH_BLOCK, np.int32)
-        self._temps = np.zeros((R,), np.float32)
-        self._topks = np.zeros((R,), np.int32)
-        self._seeds = np.zeros((R,), np.uint32)
+        # packed decode-batch state (one row per slot).  Each slot's
+        # last token lies on the device where decode runs ahead of what
+        # the host has read, on the host where drafting reads it
+        self._slots = _SlotState(c.max_batch, table_width)
+        self._serial = int(c.draft_len) > 0
+        self._tokens = (np.zeros if self._serial else jnp.zeros)(
+            (c.max_batch,), np.int32)
+        # launched and not yet read, in launch order
+        self._unread: "collections.deque" = collections.deque()
         self.steps = 0
         self.peak_blocks_in_use = 0
         self.peak_resident = 0        # max concurrent block-holding reqs
@@ -536,13 +635,12 @@ class ServeEngine:
         if reason is None:
             return False
         self._shed_reason = None
+        # what is in flight is read first: once a victim's blocks are
+        # handed back, no program launched for it is still to run
+        self._settle()
         victims = self.scheduler.occupied()
         for req in victims:
-            slot = req.slot
-            self.scheduler.finish(req, ERROR, error=reason)
-            if slot is not None:
-                self._active[slot] = False
-                self._tables[slot] = TRASH_BLOCK
+            self._retire(req, ERROR, error=reason)
         if victims:
             COUNTERS.add("serve.shed", calls=len(victims))
             if self._slo is not None:
@@ -556,10 +654,20 @@ class ServeEngine:
                 f"{self.scheduler.n_waiting} waiting proceed")
         return bool(victims)
 
+    def _retire(self, req: Request, state: str,
+                error: Optional[str] = None) -> None:
+        """Terminal transition of a request that holds a slot: slot and
+        blocks go back now, and no later step decodes for the slot."""
+        slot = req.slot
+        self.scheduler.finish(req, state, error=error)
+        if slot is not None:
+            self._slots.set(slot, active=False, tables=TRASH_BLOCK)
+
     # -- the serving loop body ----------------------------------------
 
     def step(self) -> bool:
-        """One engine iteration: admit -> prefill chunk round -> decode.
+        """One engine iteration: admit -> prefill chunk round -> launch
+        the next decode step -> read what was launched before it.
         Returns True when any work was done (callers idle otherwise)."""
         fault_point("serve.step")
         self._check_shed()
@@ -573,7 +681,7 @@ class ServeEngine:
             # depth AFTER admission = backlog the cache/slots could not
             # absorb this step, the saturation signal SLO windows want
             self._slo.observe_queue_depth(self.scheduler.n_waiting)
-        did = False
+        did = bool(self._unread)
         for req in self.scheduler.prefilling()[
                 :self.config.max_prefill_chunks_per_step]:
             fault_point("serve.prefill")
@@ -581,13 +689,23 @@ class ServeEngine:
                 return True
             self._prefill_chunk(req)
             did = True
-        running = self.scheduler.running()
-        if running:
+        ahead = None
+        lanes = self._lanes()
+        if lanes:
             fault_point("serve.decode")
             if self._check_shed():
                 return True
-            self._decode_step(running)
+            if self._serial:
+                self._verify_step(lanes)
+            else:
+                ahead = self._launch_decode(lanes)
             did = True
+        # the one blocking read: the step before, while `ahead` runs
+        self._settle(keep=ahead)
+        if ahead is not None and not self._lanes() and not \
+                self.scheduler.prefilling() and not self.scheduler.n_waiting:
+            # nothing is left to launch behind it: wait for it now
+            self._settle()
         if did:
             self.steps += 1
             self.kv.sample_occupancy()
@@ -599,12 +717,20 @@ class ServeEngine:
                 self._slo.tick()
         return did
 
+    def _lanes(self) -> List[Request]:
+        """The running requests the next decode step decodes for: not
+        those whose last token a launched step already computes."""
+        active = self._slots.host["active"]
+        return [r for r in self.scheduler.running() if active[r.slot]]
+
     def has_work(self) -> bool:
-        return self.scheduler.has_work() or self._shed_reason is not None
+        return (self.scheduler.has_work() or bool(self._unread)
+                or self._shed_reason is not None)
 
     def run(self) -> None:
-        """Drive step() until every submitted request is terminal."""
-        while self.scheduler.has_work():
+        """Drive step() until every submitted request is terminal and
+        nothing launched is unread."""
+        while self.has_work():
             self.step()
 
     def generate(self, prompts: Sequence[Sequence[int]],
@@ -669,45 +795,45 @@ class ServeEngine:
         if req.block_hashes:
             start = -(-req.prefix_cached_tokens // self.kv.block_size)
             self.kv.register_prefix(req.rid, req.block_hashes, start)
-        # the program sampled the request's FIRST token
-        first = int(tok)
-        now = self.clock()
-        req.t_first_token = now
-        req.token_times.append(now)
-        req.out.append(first)
-        COUNTERS.add("serve.tokens")
-        COUNTERS.add("serve.ttft_ms", nbytes=int(req.ttft_s * 1e6))
-        if self._slo is not None:
-            self._slo.observe_ttft(req.ttft_s)
-        if tr is not None:
-            tr.instant("first_token", "serve", rid=req.rid,
-                       ttft_ms=round(req.ttft_s * 1e3, 3))
-        if self._is_finished(req, first):
-            self._finish(req)
-            return
+        # the program sampled the request's FIRST token: it joins the
+        # decode batch from the device, and is read behind the step
+        # launched next (at once where drafting needs it on the host)
         req.state = RUNNING
-        slot = req.slot
-        self._tokens[slot] = first
-        # the first decode step writes this token's K/V at position P
-        self._positions[slot] = len(req.prompt)
-        self._active[slot] = True
-        self._tables[slot] = req.table
-        self._temps[slot] = req.temperature
-        self._topks[slot] = req.top_k
-        self._seeds[slot] = np.uint32(req.seed)
+        rides = req.max_new_tokens > 1
+        self._slots.set(
+            req.slot, active=rides, tables=req.table,
+            # the first decode step writes this token's K/V at position P
+            positions=len(req.prompt), temperatures=req.temperature,
+            top_ks=req.top_k, seeds=np.uint32(req.seed))
+        self._unread.append(_First(req, tok))
+        if self._serial:
+            self._settle()
+        elif rides:
+            self._tokens = self.programs["seat"](
+                self._tokens, np.int32(req.slot), tok)
 
-    def _decode_step(self, running: List[Request]) -> None:
-        if int(self.config.draft_len) > 0:
-            self._verify_step(running)
-            return
-        tr = self._step_tracer()
-        tus0 = tr.now_us() if tr is not None else 0
+    def _launch_decode(self, lanes: List[Request]) -> _Step:
+        """Launch one decode step for `lanes` from the state as it lies
+        on the device — each slot's last token is the step before's
+        sample, or `seat`'s — and decide from positions alone, in
+        launch order, what the step after it will see: who has had its
+        last token computed, which window this step fills."""
+        tr = self._tracer
+        step = _Step(toks=None, lanes=[(r, r.slot) for r in lanes],
+                     t0=time.perf_counter(),
+                     tus0=tr.now_us() if tr is not None else 0,
+                     index=self.steps)
+        state = self._slots
+        positions = state.host["positions"]
+        slots = [r.slot for r in lanes]
         if self.kv.windowed:
             W, C = self.kv.window_tokens, self.kv.block_size
-            for req in running:
-                p = int(self._positions[req.slot])
+            tables = state.host["tables"]
+            for req in lanes:
+                p = int(positions[req.slot])
                 self._take_blocks(req, p, p + 1)
-                self._tables[req.slot] = req.table
+                if not np.array_equal(tables[req.slot], req.table):
+                    state.set(req.slot, tables=req.table)
                 # what this query reads: its window up to itself and
                 # the summaries of the windows closed before it; what
                 # its attention fetches for that: the blocks those rows
@@ -719,52 +845,92 @@ class ServeEngine:
                     sum(live_blocks(p, W, C, C)) if self._walks_live_blocks
                     else self.kv.table_width))
         elif self.kv.latent_width:
-            held = self._positions[[r.slot for r in running]].astype(
-                np.int64) + 1
+            held = positions[slots].astype(np.int64) + 1
             for name in ("serve.mla.rows_read", "serve.mla.context_tokens"):
-                COUNTERS.add(name, calls=len(running),
-                             nbytes=int(held.sum()))
+                COUNTERS.add(name, calls=len(lanes), nbytes=int(held.sum()))
         else:
-            self._count_rows_walked(running, 1)
-        t0 = time.perf_counter()
-        toks, caches = self.programs["decode"](
-            self.params, self.kv.caches, jnp.asarray(self._tokens),
-            jnp.asarray(self._positions), jnp.asarray(self._active),
-            jnp.asarray(self._tables), jnp.asarray(self._temps),
-            jnp.asarray(self._topks), jnp.asarray(self._seeds))
-        self.kv.caches = caches
-        toks = np.asarray(toks)
-        self._record_dequant(t0)
+            self._count_rows_walked(lanes, 1)
+        COUNTERS.add("serve.decode_ahead", nbytes=int(
+            any(isinstance(u, _Step) for u in self._unread)))
+        step.toks, self.kv.caches, (self._tokens, moved) = \
+            self.programs["decode"](self.params, self.kv.caches,
+                                    self._tokens, *state.on_device())
+        state.advanced(slots, moved)
+        for req in lanes:
+            req.cached_len += 1
+            if req.cached_len >= len(req.prompt) + req.max_new_tokens - 1:
+                state.set(req.slot, active=False)
+            elif self.kv.windowed:
+                self._close_full_window(req)
+        self._unread.append(step)
+        return step
+
+    def _settle(self, keep: Optional[_Step] = None) -> None:
+        """Read, in launch order, everything launched and not yet read
+        — up to `keep`, the step just launched, which stays in flight."""
+        while self._unread and self._unread[0] is not keep:
+            item = self._unread.popleft()
+            if isinstance(item, _First):
+                self._read_first(item)
+            else:
+                self._read_step(item)
+
+    def _read_first(self, item: _First) -> None:
+        req = item.req
+        if req.done:                    # shed before its token was read
+            return
+        first = int(item.tok)
         now = self.clock()
-        COUNTERS.add("serve.decode_steps", nbytes=len(running))
+        req.t_first_token = now
+        req.token_times.append(now)
+        req.out.append(first)
+        COUNTERS.add("serve.tokens")
+        COUNTERS.add("serve.ttft_ms", nbytes=int(req.ttft_s * 1e6))
+        if self._slo is not None:
+            self._slo.observe_ttft(req.ttft_s)
+        tr = self._req_tracer(req)
+        if tr is not None:
+            tr.instant("first_token", "serve", rid=req.rid,
+                       ttft_ms=round(req.ttft_s * 1e3, 3))
+        if self._serial:
+            self._tokens[req.slot] = first
+        if self._is_finished(req, first):
+            self._finish(req)
+
+    def _read_step(self, step: _Step) -> None:
+        """The host's half of a decode step, while the step after it
+        runs: append, stamp, finish, free.  A request that ended at the
+        step before (its `eos_token`, found one step late) rode this
+        one too: that lane's token is dropped."""
+        toks = np.asarray(step.toks)
+        self._record_dequant(step.t0)
+        now = self.clock()
+        COUNTERS.add("serve.decode_steps", nbytes=len(step.lanes))
         if self._routed_layers:
             # behind the slots' tokens: the experts the step touched
-            self._count_assignments(len(running))
+            self._count_assignments(len(step.lanes))
             COUNTERS.add("serve.moe.experts_touched",
                          calls=self._routed_layers,
-                         nbytes=int(toks[len(self._tokens)]))
-        for req in running:
-            slot = req.slot
+                         nbytes=int(toks[self.config.max_batch]))
+        emitted = 0
+        for req, slot in step.lanes:
+            if req.done:
+                COUNTERS.add("serve.decode_ahead.dropped")
+                continue
             tok = int(toks[slot])
             req.out.append(tok)
             req.token_times.append(now)
-            req.cached_len += 1
+            emitted += 1
             COUNTERS.add("serve.tokens")
             if self._is_finished(req, tok):
                 self._finish(req)
-                self._active[slot] = False
-                self._tables[slot] = TRASH_BLOCK
-            else:
-                self._tokens[slot] = tok
-                self._positions[slot] += 1
-                if self.kv.windowed:
-                    self._close_full_window(req)
         if self._slo is not None:
-            self._slo.observe_tokens(len(running))
-        if tr is not None:
-            tr.add_complete("decode_step", "serve", ts_us=tus0,
-                            dur_us=tr.now_us() - tus0, step=self.steps,
-                            batch=len(running))
+            self._slo.observe_tokens(emitted)
+        tr = self._tracer
+        if tr is not None and tr.sampled(f"step:{step.index}"):
+            tr.add_complete("decode_step", "serve", ts_us=step.tus0,
+                            dur_us=tr.now_us() - step.tus0, step=step.index,
+                            batch=len(step.lanes))
 
     def _count_assignments(self, n_tokens: int) -> None:
         """Token-expert pairs a call over `n_tokens` tokens computes in
@@ -778,8 +944,8 @@ class ServeEngine:
                            n_queries: int) -> None:
         """The pool rows this step's attention reads for the running
         slots, beside the lengths they hold once its rows are written."""
-        held = self._positions[[r.slot for r in running]].astype(
-            np.int64) + n_queries
+        held = self._slots.host["positions"][
+            [r.slot for r in running]].astype(np.int64) + n_queries
         bs = self.kv.block_size
         walked = (-(-held // bs) * bs if self._walks_live_blocks
                   else np.full_like(held, self.kv.table_width * bs))
@@ -800,17 +966,18 @@ class ServeEngine:
             COUNTERS.add("kv.summary_rows", nbytes=rows)
 
     def _close_full_window(self, req: Request) -> None:
-        """After a program wrote up to `req.cached_len`: if that filled
-        the request's window, give its exact blocks back to the free
-        list; its summary rows stay and the next query, in the next
-        window, sees them."""
+        """After a program that writes up to `req.cached_len` was
+        launched: if that fills the request's window, give its exact
+        blocks back to the free list; its summary rows stay and the
+        next query, in the next window, sees them.  Whatever takes the
+        blocks next is launched behind the program that last read them.
+        The slot's row of the decode state keeps them until the
+        request's next step takes its table anew, as every step does."""
         if req.cached_len % self.kv.window_tokens:
             return
         tr = self._req_tracer(req)
         tus0 = tr.now_us() if tr is not None else 0
         back = self.kv.close_window(req.rid)
-        if req.slot is not None and self._active[req.slot]:
-            self._tables[req.slot] = req.table
         if tr is not None:
             tr.add_complete("eva.window_close", "serve", ts_us=tus0,
                             dur_us=tr.now_us() - tus0, rid=req.rid,
@@ -828,8 +995,9 @@ class ServeEngine:
         """`kv.dequant_ms` (µs-in-bytes): wall time of decode-family
         dispatches against a QUANTIZED cache — the in-program
         dequantize is XLA-fused into the attention gather, so the
-        honest measurement is the whole dispatch; A/B against the
-        dense-kv lane of the same bench isolates the dequant cost."""
+        honest measurement is the whole dispatch, launch to tokens
+        read; A/B against the dense-kv lane of the same bench isolates
+        the dequant cost."""
         if self.kv.quant_wire:
             COUNTERS.add("kv.dequant_ms",
                          nbytes=int((time.perf_counter() - t0) * 1e6))
@@ -846,7 +1014,7 @@ class ServeEngine:
         writes candidate K/V at positions P+1..P+k, and every one of
         those rows must be backed by a real block."""
         c = self.config
-        P = int(self._positions[req.slot])
+        P = int(self._slots.host["positions"][req.slot])
         alloc_rows = len(self.kv.blocks_of(req.rid)) * self.kv.block_size
         k = min(int(c.draft_len),
                 req.max_new_tokens - len(req.out) - 1,
@@ -892,6 +1060,7 @@ class ServeEngine:
         query's causal mask can attend them — no scatter undo."""
         R = self.config.max_batch
         k = int(self.config.draft_len)
+        state = self._slots.host
         tr = self._step_tracer()
         tus0 = tr.now_us() if tr is not None else 0
         drafts = np.zeros((R, k), np.int32)
@@ -907,10 +1076,9 @@ class ServeEngine:
         t0 = time.perf_counter()
         toks, caches = self.programs["verify"](
             self.params, self.kv.caches, jnp.asarray(tokens),
-            jnp.asarray(self._positions), jnp.asarray(n_draft),
-            jnp.asarray(self._active), jnp.asarray(self._tables),
-            jnp.asarray(self._temps), jnp.asarray(self._topks),
-            jnp.asarray(self._seeds))
+            jnp.asarray(state["positions"]), jnp.asarray(n_draft),
+            *(jnp.asarray(state[name]) for name in (
+                "active", "tables", "temperatures", "top_ks", "seeds")))
         self.kv.caches = caches
         toks = np.asarray(toks)                     # [R, draft_len + 1]
         self._record_dequant(t0)
@@ -947,11 +1115,9 @@ class ServeEngine:
             tot_emitted += emitted
             if finished:
                 self._finish(req)
-                self._active[slot] = False
-                self._tables[slot] = TRASH_BLOCK
             else:
                 self._tokens[slot] = int(toks[slot, emitted - 1])
-                self._positions[slot] += emitted
+                state["positions"][slot] += emitted
         if self._slo is not None:
             self._slo.observe_tokens(tot_emitted)
             self._slo.observe_accept(tot_accepted, int(n_draft.sum()))
@@ -973,9 +1139,12 @@ class ServeEngine:
         if tr is not None:
             tr.instant("finish", "serve", rid=req.rid,
                        tokens=len(req.out))
+        # the rows that hold the answer: where the end was found one step
+        # late, a launched step wrote one more, and it lies beyond them
+        req.cached_len = len(req.prompt) + len(req.out) - 1
         if req.session_id is not None and self.config.prefix_cache:
             self._pin_session(req)
-        self.scheduler.finish(req, FINISHED)
+        self._retire(req, FINISHED)
 
     # -- watchdog / worker integration ---------------------------------
 
@@ -999,15 +1168,36 @@ class ServeEngine:
             lambda: [t for t in (self._worker,)
                      if t is not None and t.is_alive()])
 
+    def abort(self, error: str) -> None:
+        """Make every request that is not terminal end in state 'error'
+        — waiting, prefilling, running — with its blocks handed back,
+        after what is in flight for them has run and been read.  Where
+        that read fails too (the device, under a loop that died of it)
+        the failure is logged and what was in flight is dropped."""
+        try:
+            self._settle()
+        except Exception as e:  # noqa: BLE001 — must still release
+            logger.error(f"serving: reading what was in flight failed "
+                         f"({type(e).__name__}: {e}); dropped")
+            self._unread.clear()
+        self.scheduler.abort_waiting(error)
+        for req in self.scheduler.occupied():
+            self._retire(req, ERROR, error=error)
+
     def close(self) -> None:
+        """Release sessions, stop the worker, read what is in flight and
+        end every request that is not terminal in state 'error'."""
         for sid in list(self._sessions):
             self.release_session(sid)
-        if self._worker is not None:
-            self._worker.stop()
-            self._worker = None
-        if self._watchdog is not None:
-            self._watchdog.unregister_threads("serving")
-            self._watchdog = None
+        try:
+            if self._worker is not None:
+                self._worker.stop()
+                self._worker = None
+        finally:
+            self.abort("engine closed")
+            if self._watchdog is not None:
+                self._watchdog.unregister_threads("serving")
+                self._watchdog = None
 
 
 class ServeWorker(threading.Thread):
@@ -1042,11 +1232,7 @@ class ServeWorker(threading.Thread):
         except BaseException as e:  # noqa: BLE001 — reported, not hidden
             self.error = e
             logger.error(f"serving worker died: {type(e).__name__}: {e}")
-            eng.request_shed(f"serving worker died: {e}")
-            for req in eng.scheduler.requests:
-                if not req.done:
-                    eng.scheduler.finish(req, ERROR,
-                                         error=f"worker died: {e}")
+            eng.abort(f"worker died: {e}")
 
     def stop(self, timeout: float = 10.0) -> None:
         self._halt.set()

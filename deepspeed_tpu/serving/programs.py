@@ -28,6 +28,11 @@ fixed-shape programs built once from a declarative `ServeSchedule`
   contract tier-1 pins: a
   request's tokens do not depend on WHICH other requests share the
   batch, so joining mid-flight is token-identical to decoding alone.
+  Beside its tokens and caches it returns what the NEXT step takes as
+  `tokens` and `positions` (the slots' samples, the positions advanced
+  for the active slots), so the engine launches that step from arrays
+  that never leave the device; **seat** writes a prompt's first token
+  (prefill's sample) into that vector at the request's slot.
 * **verify** — the speculative-decoding forward: decode at
   `draft_len + 1` tokens per slot, scoring a slot's drafted candidates
   in ONE dispatch.  Position-keyed sampling at every row makes the
@@ -167,6 +172,14 @@ def _row_key(seed, position):
     return jax.random.fold_in(jax.random.PRNGKey(seed), position)
 
 
+@jax.jit
+def seat(tokens, slot, tok):
+    """A request joins the decode batch: its first token, sampled by
+    `prefill` and still on the device, becomes its slot's entry of the
+    vector the next `decode` takes as `tokens`."""
+    return tokens.at[slot].set(tok)
+
+
 # -- qwZ weight store -------------------------------------------------------
 
 
@@ -230,7 +243,8 @@ class ServeProgramBuilder:
     """Builds the jitted {prefill, decode} pair for one (model,
     schedule).  Programs are pure: (params, caches, batch state) ->
     (outputs, caches), caches donated — the engine threads the
-    returned arrays back through PagedKVCache.caches."""
+    returned arrays back through PagedKVCache.caches.  Nothing else is
+    donated: a slot-state array is handed to step after step."""
 
     def __init__(self, model, schedule: ServeSchedule):
         cfg = model.config
@@ -322,6 +336,7 @@ class ServeProgramBuilder:
         return {"schedule": self.schedule,
                 "prefill": self._build_prefill(),
                 "decode": self._build_decode(),
+                "seat": seat,
                 "verify": self._build_verify(),
                 "prepare_params": self._prepare_params}
 
@@ -407,19 +422,24 @@ class ServeProgramBuilder:
             length), active [R] bool, tables [R, W], sampling params
             [R].  Inactive slots write to the trash block and their
             outputs are discarded by the engine — all slot math is
-            row-wise, THE batching-invariance contract.  Behind routed
-            FFNs the tokens [R] are followed by one more entry, the
-            experts the step touched summed over those layers: it rides
-            the one transfer the engine makes a step."""
+            row-wise, THE batching-invariance contract.  Returns (what
+            the host reads, caches, what the next step takes): behind
+            routed FFNs the tokens [R] the host reads are followed by
+            one more entry, the experts the step touched summed over
+            those layers — it rides the one transfer the engine makes a
+            step; the next step's (tokens [R], positions [R]) are the
+            samples as they lie and the positions moved on by one for
+            the active slots."""
             params = self._maybe_dequant(params)
             logits, new_caches, touched = self.step_logits(
                 params, caches, tokens, positions, active, tables)
             keys = jax.vmap(_row_key)(seeds, positions + 1)
             toks = jax.vmap(sample_token)(layers.sampled(spec, logits),
                                           temperatures, top_ks, keys)
+            ahead = (toks, positions + active.astype(positions.dtype))
             if touched:
                 toks = jnp.concatenate([toks, sum(touched)[None]])
-            return toks, new_caches
+            return toks, new_caches, ahead
 
         return decode
 

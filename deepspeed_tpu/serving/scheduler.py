@@ -87,7 +87,8 @@ class Request:
     slot: Optional[int] = None
     table = None                      # np.int32 [table_width]
     prefill_pos: int = 0              # tokens already prefilled
-    cached_len: int = 0               # cache positions written (real)
+    cached_len: int = 0               # cache positions written, or being
+    #                                   written by a launched program
     prefix_cached_tokens: int = 0     # prompt tokens skipped at admit
     block_hashes: List[bytes] = field(default_factory=list)
     # timestamps (engine clock)
@@ -293,6 +294,14 @@ class Scheduler:
             self.slots[req.slot] = None
             req.slot = None
         self.kv.free(req.rid, evicted=(state == ERROR))
+
+    def abort_waiting(self, error: str) -> None:
+        """Everything still queued leaves the queue in state 'error'
+        (the engine is closing, or its loop died)."""
+        with self._lock:
+            waiting, self._waiting = self._waiting, []
+        for req in waiting:
+            self.finish(req, ERROR, error=error)
 
     def has_work(self) -> bool:
         with self._lock:

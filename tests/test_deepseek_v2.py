@@ -316,18 +316,51 @@ def test_counters_of_a_decode_step():
     assert steps * 2 * 3 <= touched["bytes"] <= steps * 2 * 6
 
 
+def test_experts_touched_is_read_one_step_late_and_loses_nothing():
+    """The entry behind a step's tokens reaches the host with them, one
+    step after the launch: when step k is launched the counter holds
+    steps 1..k-2, and at the end it holds every step's entry."""
+    model, params = _model()
+    eng = ServeEngine(model, params, _serve())
+    decode, R, layers = eng.programs["decode"], 3, 2
+    name = "serve.moe.experts_touched"
+    launched, counted = [], []
+
+    def recording(*args):
+        out = decode(*args)
+        launched.append(out[0])
+        counted.append(COUNTERS.totals().get(name, {"calls": 0})["calls"])
+        return out
+
+    eng.programs = dict(eng.programs, decode=recording)
+    before = COUNTERS.snapshot()
+    base = COUNTERS.totals().get(name, {"calls": 0})["calls"]
+    reqs = [eng.submit(_prompt(8, 1), 9), eng.submit(_prompt(21, 2), 4)]
+    eng.run()
+    d = COUNTERS.delta_since(before)
+    assert [len(r.out) for r in reqs] == [9, 4] and len(launched) >= 8
+    assert counted == [base + max(k - 1, 0) * layers
+                       for k in range(len(launched))]
+    assert d[name] == {"calls": len(launched) * layers,
+                       "bytes": sum(int(o[R]) for o in launched)}
+    assert d["serve.decode_ahead"] == {"calls": len(launched),
+                                       "bytes": len(launched) - 1}
+
+
 def test_decode_appends_experts_touched_to_its_tokens():
     model, params = _model()
     eng = ServeEngine(model, params, _serve())
     R, W = 3, eng.kv.table_width
     active = jnp.asarray([True, False, False])
-    out, _ = eng.programs["decode"](
+    out, _, (toks, moved) = eng.programs["decode"](
         eng.params, eng.kv.caches, jnp.zeros((R,), jnp.int32),
         jnp.zeros((R,), jnp.int32), active, jnp.zeros((R, W), jnp.int32),
         jnp.zeros((R,), jnp.float32), jnp.zeros((R,), jnp.int32),
         jnp.zeros((R,), jnp.uint32))
     # one live token: top 3 experts in each of the 2 routed layers
     assert out.shape == (R + 1,) and int(out[R]) == 2 * TOPK
+    # what the next step takes: the samples alone, the live slot moved on
+    assert np.array_equal(toks, out[:R]) and moved.tolist() == [1, 0, 0]
 
 
 # -- the row under the one allocator -------------------------------------------

@@ -82,11 +82,15 @@ class Probe:
                           *sampling):
             lg = np.asarray(step(params, caches, tokens, positions, active,
                                  tables)[0])
+            live = np.asarray(active)
             for req in eng.scheduler.running():
                 # a request whose prefill ended in this very step
-                # decodes in it too: its first logits are the prefill's
-                self.logits.setdefault(req.rid, [self._last_prefill]) \
-                    .append(lg[req.slot])
+                # decodes in it too: its first logits are the prefill's;
+                # one whose last token the step before computes is still
+                # running, unread, and is not decoded for
+                if live[req.slot]:
+                    self.logits.setdefault(req.rid, [self._last_prefill]) \
+                        .append(lg[req.slot])
             return decode(params, caches, tokens, positions, active, tables,
                           *sampling)
 
@@ -405,7 +409,11 @@ def test_cache_invariants_hold_at_every_step(model_and_params, impl):
         with _attention(impl):
             eng.step()
         live = eng.scheduler.occupied()
-        for r in live:
+        # a request whose last step is launched and unread opens no
+        # further window: it gives everything back when it is read
+        ahead = [r for r in live if r.cached_len <
+                 len(r.prompt) + r.max_new_tokens - 1]
+        for r in ahead:
             exact = kv.exact_blocks_of(r.rid)
             assert len(exact) <= bound
             # a closed window's blocks are back before the next step:
@@ -415,7 +423,9 @@ def test_cache_invariants_hold_at_every_step(model_and_params, impl):
                 -(-(r.cached_len // C) // C)
             assert len(set(exact) & set(kv._free)) == 0
         assert kv.summary_rows_in_use == sum(
-            r.cached_len // W * (W // C) for r in live)
+            r.cached_len // W * (W // C) for r in ahead) + sum(
+            (r.cached_len - 1) // W * (W // C) for r in live
+            if r not in ahead)
         assert kv.blocks_in_use == sum(
             len(kv.blocks_of(r.rid)) for r in live)
         assert kv.free_blocks >= 0
@@ -424,6 +434,39 @@ def test_cache_invariants_hold_at_every_step(model_and_params, impl):
     assert sorted(kv._free) == list(range(1, cfg.num_blocks))
     assert eng.peak_blocks_in_use <= 3 * (W // C + 4)
     assert [r.out for r in reqs[:2]] == alone
+
+
+def test_a_window_closes_under_a_step_in_flight_and_its_blocks_are_retaken(
+        model_and_params):
+    """The decode step that fills a request's window is launched, the
+    window's blocks go back to the free list from positions alone, and
+    the step is still unread when a second request's first chunk takes
+    them: that chunk is queued behind the step that last read them, so
+    both requests get what they get served alone."""
+    model, params = model_and_params
+    lens = [(29, 20), (12, 30)]
+    alone = [ServeEngine(model, params, _serve()).generate(
+        [_prompt(n, i)], new)[0] for i, (n, new) in enumerate(lens)]
+    eng = ServeEngine(model, params, _serve())
+    kv = eng.kv
+    before = COUNTERS.snapshot()
+    a = eng.submit(_prompt(29, 0), 20)
+    held = []
+    while a.cached_len < W:
+        held = kv.exact_blocks_of(a.rid)
+        eng.step()
+    # rows 29..31 were decoded for; the last of those steps is unread
+    assert len(eng._unread) == 1 and len(a.out) == 3
+    assert len(held) == W // C and not kv.exact_blocks_of(a.rid)
+    assert COUNTERS.delta_since(before)["kv.window_closes"] == {
+        "calls": 1, "bytes": W // C}
+    b = eng.submit(_prompt(12, 1), 30)
+    eng.step()                              # b's first chunk of 8 rows
+    taken = kv.exact_blocks_of(b.rid)
+    assert len(taken) == 2 and set(taken) <= set(held)
+    eng.run()
+    assert [a.out, b.out] == alone
+    assert kv.blocks_in_use == 0 and kv.promised_blocks == 0
 
 
 def test_admission_is_by_bounded_footprint(model_and_params):

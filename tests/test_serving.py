@@ -318,6 +318,188 @@ def test_static_admission_policy_blocks_until_batch_drains(
     assert r_late.out == _alone(model_and_params, programs, prompts[1], 4)
 
 
+# -- the loop, one decode step ahead of what the host has read --------------
+
+
+@pytest.mark.parametrize("kw", [{}, dict(temperature=0.8, top_k=5)],
+                         ids=["greedy", "sampled"])
+def test_one_step_ahead_is_token_identical_to_generate_alone(
+        model_and_params, programs, kw):
+    """Six requests over four slots with answers of different lengths:
+    they join while others decode, leave at different steps, and the
+    later ones sit in slots and blocks the earlier ones gave back.  Each
+    gets, token for token, what `generate()` gives it alone, while nearly
+    every decode step was launched before the one before it was read."""
+    prompts = _prompts(seed=43, lens=(5, 9, 3, 12, 7, 4))
+    news = (9, 3, 12, 2, 6, 1)
+    oracle = [_alone(model_and_params, programs, p, n,
+                     seeds=[70 + i], **kw)
+              for i, (p, n) in enumerate(zip(prompts, news))]
+    eng = _engine(model_and_params, programs)
+    snap = COUNTERS.snapshot()
+    reqs = []
+    for i, (p, n) in enumerate(zip(prompts, news)):
+        reqs.append(eng.submit(p, n, seed=70 + i, **kw))
+        eng.step()
+        if i % 2:
+            eng.step()
+    eng.run()
+    d = COUNTERS.delta_since(snap)
+    assert [r.out for r in reqs] == oracle
+    assert all(r.state == FINISHED for r in reqs)
+    assert eng.kv.blocks_in_use == 0 and not eng._unread
+    ahead = d["serve.decode_ahead"]
+    assert ahead["bytes"] >= ahead["calls"] - 2 > 0, ahead
+    # an end by max_new_tokens is decided ahead: nothing is decoded for a
+    # request whose last token a launched step already computes
+    assert d["serve.decode_steps"]["bytes"] == sum(n - 1 for n in news)
+    assert "serve.decode_ahead.dropped" not in d
+
+
+def test_eos_is_found_one_step_late_and_costs_one_lane_step(
+        model_and_params, programs):
+    """One slot samples its `eos_token` while two others run: the step
+    launched before the host saw it decodes that slot once more.  That
+    token is dropped, nothing follows the EOS in `out`, the request's
+    blocks are free once the host has read the EOS, and a request
+    admitted into them decodes exactly."""
+    prompts = _prompts(seed=13)
+    kw = dict(temperature=0.9, top_k=6)
+    full = _alone(model_and_params, programs, prompts[1], 8, seeds=[42],
+                  **kw)
+    stop_at = next(i for i in range(1, 7) if full[i] not in full[:i])
+    eng = _engine(model_and_params, programs, num_blocks=21)
+    snap = COUNTERS.snapshot()
+    others = [eng.submit(prompts[0], 14), eng.submit(prompts[3], 14)]
+    r = eng.submit(prompts[1], 8, seed=42, eos_token=full[stop_at], **kw)
+    mine = None
+    while not r.done:
+        eng.step()
+        mine = set(eng.kv.blocks_of(r.rid)) or mine
+    assert r.out == full[:stop_at + 1] and r.state == FINISHED
+    assert not eng.kv.blocks_of(r.rid)
+    assert not any(o.done for o in others)
+    late = eng.submit(prompts[2], 6)
+    eng.step()                      # admitted while the others decode
+    assert set(eng.kv.blocks_of(late.rid)) & mine
+    eng.run()
+    d = COUNTERS.delta_since(snap)
+    assert d["serve.decode_ahead.dropped"] == {"calls": 1, "bytes": 0}, d
+    assert late.out == _alone(model_and_params, programs, prompts[2], 6)
+    assert [o.out for o in others] == [
+        _alone(model_and_params, programs, p, 14)
+        for p in (prompts[0], prompts[3])]
+    assert eng.kv.blocks_in_use == 0
+
+
+def test_slot_state_handed_to_a_launched_step_is_never_rewritten(
+        model_and_params, programs):
+    """A program reads its arguments when it RUNS, and on the CPU
+    `jnp.asarray` may alias a host array: every array of slot state a
+    decode step was launched with must still hold, after the run, what
+    it held at the launch — whatever joined, left or moved on since.
+    And a step that nobody joins or leaves uploads nothing: it is handed
+    the arrays the step before was, and that step's own results."""
+    eng = _engine(model_and_params, programs)
+    decode = eng.programs["decode"]
+    launches = []
+
+    def recording(params, caches, *state):
+        out = decode(params, caches, *state)
+        launches.append((state, [np.array(a) for a in state], out[2]))
+        return out
+
+    eng.programs = dict(eng.programs, decode=recording)
+    prompts = _prompts(seed=47)
+    eng.submit(prompts[0], 12)
+    eng.step()
+    eng.submit(prompts[1], 3)
+    for _ in range(4):
+        eng.step()
+    eng.submit(prompts[2], 5)
+    eng.run()
+    assert len(launches) >= 11
+    for state, seen, _ in launches:
+        for handed, at_launch in zip(state, seen):
+            assert np.array_equal(np.asarray(handed), at_launch)
+    steady = 0
+    for (a, _, ahead), (b, _, _) in zip(launches, launches[1:]):
+        if all(x is y for x, y in zip(a[3:], b[3:])):
+            # nobody joined or left: tokens and positions are the step
+            # before's results, the rest the very arrays it was handed
+            assert b[0] is ahead[0] and b[1] is ahead[1]
+            steady += 1
+    assert steady >= 5
+
+
+@pytest.mark.parametrize("how", ["shed", "worker_death", "close"])
+def test_nothing_in_flight_outlives_its_requests(model_and_params, programs,
+                                                 how):
+    """A shed, a dead worker and `close()` meet the engine with a decode
+    step launched and unread and a request still waiting: afterwards no
+    request is non-terminal, no block is booked, nothing is unread."""
+    from deepspeed_tpu.serving import ServeWorker
+
+    prompts = _prompts(seed=53, lens=(5, 9, 6))
+    eng = _engine(model_and_params, programs, num_blocks=13)
+    reqs = [eng.submit(p, 12) for p in prompts]
+    for _ in range(3):
+        eng.step()
+    assert len(eng._unread) == 1 and eng.kv.blocks_in_use > 0
+    assert reqs[2].state == WAITING
+    if how == "shed":
+        eng.request_shed("test wedge")
+        eng.step()
+        assert [r.state for r in reqs[:2]] == [ERROR, ERROR]
+        assert not eng._unread or reqs[2].state != WAITING
+        eng.run()
+        assert reqs[2].state == FINISHED
+        assert reqs[2].out == _alone(model_and_params, programs,
+                                     prompts[2], 12)
+    elif how == "worker_death":
+        real, calls = eng.step, []
+
+        def step_then_die():
+            calls.append(1)
+            if len(calls) > 2:
+                raise RuntimeError("injected engine failure")
+            return real()
+
+        eng.step = step_then_die
+        w = ServeWorker(eng)
+        w.start()
+        w.join(timeout=30.0)
+        assert not w.is_alive()
+        assert all(r.state == ERROR and "injected" in r.error for r in reqs)
+        with pytest.raises(RuntimeError, match="injected engine failure"):
+            w.stop()
+    else:
+        eng.close()
+        assert all(r.state == ERROR and r.error == "engine closed"
+                   for r in reqs)
+    assert all(r.done for r in reqs)
+    assert eng.kv.blocks_in_use == 0 and not eng._unread
+    assert not eng.has_work()
+
+
+def test_drafting_keeps_the_serial_loop(model_and_params):
+    """`draft_len` > 0 reads a step's tokens before it proposes the next
+    step's candidates: nothing is ever left unread by `step()`, no step
+    counts as launched ahead, and the output is the plain engine's."""
+    model, params = model_and_params
+    prompts = _prompts(seed=59)
+    plain = ServeEngine(model, params, _cfg()).generate(prompts[:2], 10)
+    eng = ServeEngine(model, params, _cfg(draft_len=2))
+    snap = COUNTERS.snapshot()
+    reqs = [eng.submit(p, 10) for p in prompts[:2]]
+    while eng.has_work():
+        eng.step()
+        assert not eng._unread
+    d = COUNTERS.delta_since(snap)
+    assert [r.out for r in reqs] == plain
+    assert "serve.decode_ahead" not in d and d["serve.decode_steps"]["calls"]
+
+
 # -- counters ---------------------------------------------------------------
 
 
@@ -343,6 +525,24 @@ def test_serving_counters_pinned_exactly(model_and_params, programs):
     # decode, which finishes + frees before the sample -> [2, 0]
     assert d["kv.blocks_in_use"] == {"calls": 2, "bytes": 2}, d
     assert "kv.evictions" not in d and "serve.shed" not in d
+    # the first decode step starts the stretch, the second was launched
+    # while the first was unread; nothing ran for a request already ended
+    assert d["serve.decode_ahead"] == {"calls": 2, "bytes": 1}, d
+    assert "serve.decode_ahead.dropped" not in d
+
+    # a fixed script of arrivals: r1 joins at r0's third decode step
+    # and leaves one step later; four steps, all but the first ahead
+    snap = COUNTERS.snapshot()
+    r0 = eng.submit(prompt, 5)
+    eng.step()
+    eng.step()
+    r1 = eng.submit(prompt[:3], 2)
+    eng.run()
+    d = COUNTERS.delta_since(snap)
+    assert (len(r0.out), len(r1.out)) == (5, 2)
+    assert d["serve.decode_ahead"] == {"calls": 4, "bytes": 3}, d
+    assert d["serve.decode_steps"] == {"calls": 4, "bytes": 4 + 1}, d
+    assert "serve.decode_ahead.dropped" not in d
 
 
 # -- chaos: wedged decode -> watchdog trip -> shed --------------------------
