@@ -26,6 +26,7 @@ import json
 import os
 import re
 import sys
+import tempfile
 import time
 import traceback
 
@@ -234,6 +235,9 @@ MAX_NEW = 16
 # generate()'s, the model's own logit for it must lie this close to its
 # largest (the benchmark cell's `logit_margin`)
 FIRST_TOKEN_MARGIN = 0.1
+# what a recorder attached to the engine has to have seen of its thread
+SERVE_PHASES = ("serve.admit", "serve.prefill.launch", "serve.decode.launch",
+                "serve.read", "serve.bookkeep")
 
 
 def serve_phase(size=MODEL_SIZE, depth=SERVE_DEPTH, seq=SEQ, serve=None,
@@ -244,6 +248,7 @@ def serve_phase(size=MODEL_SIZE, depth=SERVE_DEPTH, seq=SEQ, serve=None,
 
     from deepspeed_tpu.models import GPT
     from deepspeed_tpu.models.generation import generate
+    from deepspeed_tpu.monitor.tracing import TraceRecorder
     from deepspeed_tpu.serving import FINISHED, ServeConfig, ServeEngine
 
     serve = dict(SERVE if serve is None else serve)
@@ -259,18 +264,29 @@ def serve_phase(size=MODEL_SIZE, depth=SERVE_DEPTH, seq=SEQ, serve=None,
     if not any(n > serve["prefill_chunk"] for n in prompt_lens):
         raise RuntimeError("no prompt is longer than prefill_chunk")
 
-    # requests join while others decode: three waves, steps in between
+    # requests join while others decode: three waves, steps in between;
+    # a recorder listens to what the engine's thread says it is doing
     waves = [prompts[:3], prompts[3:6], prompts[6:]]
     reqs, joined_while_decoding = [], 0
-    for wave in waves:
-        decoding = len(engine.scheduler.running())
-        for p in wave:
-            reqs.append(engine.submit(p, max_new))
-            joined_while_decoding += decoding > 0
-        for _ in range(4):
-            engine.step()
-    engine.run()
+    with tempfile.TemporaryDirectory() as spans:
+        recorder = TraceRecorder(spans, buffer_events=1 << 14)
+        engine.attach_tracing(tracer=recorder)
+        try:
+            for wave in waves:
+                decoding = len(engine.scheduler.running())
+                for p in wave:
+                    reqs.append(engine.submit(p, max_new))
+                    joined_while_decoding += decoding > 0
+                for _ in range(4):
+                    engine.step()
+            engine.run()
+        finally:
+            recorder.close()
+        said = {e["name"] for e in recorder.last_events()}
     serve_s = time.perf_counter() - t0
+    if not said >= set(SERVE_PHASES):  # the sites fire under this backend
+        raise RuntimeError(f"the engine's thread never said "
+                           f"{sorted(set(SERVE_PHASES) - said)}")
     for r in reqs:
         if r.state != FINISHED or len(r.out) != max_new:
             raise RuntimeError(

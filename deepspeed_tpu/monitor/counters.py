@@ -91,7 +91,8 @@ Instrumented sites:
   of extra tokens speculation bought, so accepted/decode_steps is the
   bonus tokens-per-step and accepted/draft is the acceptance rate);
   `kv.dequant_ms` — µs-in-bytes (the ckpt.stall_ms convention): wall
-  time of decode-family dispatches against a QUANTIZED kv cache (XLA
+  time of the serial loop's `verify` dispatches, launch to tokens read,
+  against a QUANTIZED kv cache; the loop that runs ahead records none (XLA
   fuses the row dequant into the attention gather, so the cost is only
   isolable by A/B against a dense lane — serve_bench does exactly
   that); zero when kv_dtype is dense.  Prefix caching + sessions
@@ -126,9 +127,8 @@ Instrumented sites:
   Latent rows and routed experts (a served model with "latent"
   attention and a "routed_experts" FFN): `serve.mla.rows_read` — calls
   = queries decoded, bytes = latent rows they attend (one a cached
-  token, shared by all heads); `serve.mla.context_tokens` — bytes =
-  the same queries' cached lengths (equal while every cached row is
-  attended); `serve.moe.assignments` — calls = routed-layer calls,
+  token, shared by all heads: every cached row of the request);
+  `serve.moe.assignments` — calls = routed-layer calls,
   bytes = token-expert pairs computed (tokens x top_k, nothing
   dropped); `serve.moe.experts_touched` — calls = decode steps x
   routed layers, bytes = experts with at least one active slot's
@@ -136,9 +136,7 @@ Instrumented sites:
   Paged attention (the GPT family): `serve.paged.rows_walked`
   — calls = slots decoded, bytes = pool rows their attention reads (a
   slot's live blocks where the paged kernel runs, the table's whole
-  width where the jnp oracle does); `serve.paged.context_tokens` —
-  bytes = the same slots' cached lengths, so context_tokens /
-  rows_walked is the share of what a step reads that it needs.
+  width where the jnp oracle does).
   Fleet routing (`router.*`, serving/router.py, rendered as the
   "Fleet router" rows; excluded from the comm byte table like the
   rest of the serving families): `router.dispatches` — requests

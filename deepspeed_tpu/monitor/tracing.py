@@ -31,6 +31,14 @@ bytes/calls): `trace.events` (calls=events recorded, bytes=bytes
 written), `trace.dropped` (calls=events the byte cap rejected),
 `slo.windows` (calls=slo events emitted).
 
+`phase` is the one call site an engine's host thread uses to say what it
+is doing: it opens a `jax.profiler.TraceAnnotation`, so any profiler
+capture (`monitor.TraceWindow`, the benchmark's `--trace 1`) shows the
+interval on the host plane of the same `.xplane.pb` as the device's
+operations, and hands the same interval to a recorder when one is given.
+The serving loop's `serve.*` and the training dispatch's `train.*` phases
+go through it (docs/tutorials/tracing.md lists them).
+
 `ServingSLO` rides the same clock: a sliding window over request
 lifecycle observations (TTFT, emitted tokens, queue depth, speculative
 accepts, sheds) emitting periodic `slo` monitor events; the p50/p99
@@ -47,6 +55,8 @@ import threading
 import time
 import zlib
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from .counters import COUNTERS
 
@@ -98,6 +108,41 @@ class _SpanCtx:
                                dur_us=self._rec.now_us() - t0,
                                **self._args)
         return False
+
+
+class _RecordedPhase(_SpanCtx):
+    """A `phase` with a recorder: the annotation is open for exactly the
+    interval the recorder's event covers."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann: str, rec: "TraceRecorder", name: str,
+                 cat: str, args: Dict[str, Any]):
+        self._ann = TraceAnnotation(ann)
+        super().__init__(rec, name, cat, args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = self._rec.now_us()
+        return self
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        return self._ann.__exit__(*exc)
+
+
+def phase(name: str, recorder: Optional["TraceRecorder"] = None,
+          cat: str = "serve", span: Optional[str] = None, **args):
+    """What an engine's host thread is doing from here to the end of the
+    `with`, to two sinks from one call site: a profiler annotation
+    `name`, always (0.5 µs where no profiler session runs), and, when
+    `recorder` is given — the caller passes it only where the step or
+    request is sampled — the same interval as a complete event named
+    `span` (default `name`) in the recorder.  Host walls only: a phase
+    never waits for a device value its body did not wait for."""
+    if recorder is None:
+        return TraceAnnotation(name)
+    return _RecordedPhase(name, recorder, span or name, cat, args)
 
 
 class TraceRecorder:

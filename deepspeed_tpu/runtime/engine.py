@@ -44,6 +44,7 @@ from .. import comm
 from ..comm.mesh import (DATA_AXIS, MODEL_AXIS, PIPE_AXIS, SEQ_AXIS,
                          MeshInfo)
 from ..monitor.counters import COUNTERS
+from ..monitor.tracing import phase
 from ..ops.adam import DeepSpeedCPUAdam, FusedAdam
 from ..ops.lamb import FusedLamb
 from ..utils.logging import log_dist, logger
@@ -1897,16 +1898,22 @@ class DeepSpeedEngine:
         bookkeeping.  Flags of steps before the previous one are settled
         FIRST; the previous step's own flag stays in flight, so this
         program queues behind the running one, and `_step_lr` hands it
-        both rates that flag decides between."""
-        self._resolve_pending_overflow(keep_newest=True)
+        both rates that flag decides between.  The host's three
+        stretches before the program is in flight are `train.*` phases
+        (`monitor.tracing.phase`); the third, `train.launch`, is the
+        step function's own call."""
+        tr, at = self._dispatch_tracer(), self.global_steps + 1
+        with phase("train.settle_flag", tr, "train", step=at):
+            self._resolve_pending_overflow(keep_newest=True)
         self.tput_timer.start()
-        batch = self._shard_batch(batch)
-        self._autotune_batch = batch  # probe replay (never donated)
-        rng = rng if rng is not None else self._next_rng()
-        theta = jnp.asarray(
-            self.progressive_layer_drop.get_theta()
-            if self.progressive_layer_drop else 1.0, jnp.float32)
-        lr = self._step_lr()
+        with phase("train.inputs", tr, "train", step=at):
+            batch = self._shard_batch(batch)
+            self._autotune_batch = batch  # probe replay (never donated)
+            rng = rng if rng is not None else self._next_rng()
+            theta = jnp.asarray(
+                self.progressive_layer_drop.get_theta()
+                if self.progressive_layer_drop else 1.0, jnp.float32)
+            lr = self._step_lr()
         profiling = self._maybe_profile_flops(batch, rng, theta, lr=lr)
         args = (self._params, self._opt_state, self._scaler_state,
                 batch, rng, lr, theta)
@@ -2129,8 +2136,11 @@ class DeepSpeedEngine:
         rsp = (self.run_monitor.span("step")
                if self.run_monitor is not None else None)
         self._drain_overlap()
-        self._resolve_pending_overflow(keep_newest=True)
-        lr = self._step_lr()
+        tr, at = self._dispatch_tracer(), self.global_steps + 1
+        with phase("train.settle_flag", tr, "train", step=at):
+            self._resolve_pending_overflow(keep_newest=True)
+        with phase("train.inputs", tr, "train", step=at):
+            lr = self._step_lr()
         (self._params, self._opt_state, self._scaler_state, self._grad_acc,
          overflow, grad_norm, extras) = self._step_fns["apply"](
             self._params, self._opt_state, self._scaler_state,
@@ -2504,28 +2514,33 @@ class DeepSpeedEngine:
                     self.backward()
                 self.step()
                 return self._last_loss
-        self._resolve_pending_overflow(keep_newest=True)
+        tr, at = self._dispatch_tracer(), self.global_steps + 1
+        with phase("train.settle_flag", tr, "train", step=at):
+            self._resolve_pending_overflow(keep_newest=True)
         rm = self.run_monitor
         if rm is not None:
             rm.step_start(self.global_steps)
         self.tput_timer.start()
-        stacked = self._shard_batch_stacked(stacked)
-        if self._autotuner is not None:
-            # probe replay stash: one micro slice (the prober re-stacks
-            # to whatever gas the probed composition needs).  Unlike
-            # the other forward paths' zero-cost reference stash, this
-            # slice is a per-leaf device dispatch — autotuned runs only.
-            self._autotune_batch = jax.tree_util.tree_map(
-                lambda x: x[0], stacked)
-        # ONE split dispatch for the whole global batch (a python loop of
-        # _next_rng() costs gas separate jax.random.split dispatches):
-        # key state folds forward once, per-micro keys peel off the rest
-        keys = jax.random.split(self._rng_key, gas + 1)
-        self._rng_key, rngs = keys[0], keys[1:]
-        theta = jnp.asarray(
-            self.progressive_layer_drop.get_theta()
-            if self.progressive_layer_drop else 1.0, jnp.float32)
-        lr = self._step_lr()
+        with phase("train.inputs", tr, "train", step=at):
+            stacked = self._shard_batch_stacked(stacked)
+            if self._autotuner is not None:
+                # probe replay stash: one micro slice (the prober
+                # re-stacks to whatever gas the probed composition
+                # needs).  Unlike the other forward paths' zero-cost
+                # reference stash, this slice is a per-leaf device
+                # dispatch — autotuned runs only.
+                self._autotune_batch = jax.tree_util.tree_map(
+                    lambda x: x[0], stacked)
+            # ONE split dispatch for the whole global batch (a python
+            # loop of _next_rng() costs gas separate jax.random.split
+            # dispatches): key state folds forward once, per-micro keys
+            # peel off the rest
+            keys = jax.random.split(self._rng_key, gas + 1)
+            self._rng_key, rngs = keys[0], keys[1:]
+            theta = jnp.asarray(
+                self.progressive_layer_drop.get_theta()
+                if self.progressive_layer_drop else 1.0, jnp.float32)
+            lr = self._step_lr()
         args = (self._params, self._opt_state, self._scaler_state,
                 stacked, rngs, lr, theta)
         if self._qwz_overlap is not None:

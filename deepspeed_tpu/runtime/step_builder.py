@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..monitor.counters import COUNTERS
+from ..monitor.tracing import phase
 from ..utils.logging import log_dist
 from .utils import clip_grad_norm, has_overflow
 
@@ -103,7 +104,9 @@ class CountedFn:
 
     `trace`: an optional zero-arg callable returning (recorder, step)
     when the in-flight step is sampled, else None — each dispatch then
-    lands as a `dispatch.<name>` span on the trace timeline.  Dispatch
+    lands as a `dispatch.<name>` span on the trace timeline.  Every
+    dispatch is a `train.launch` phase (`monitor.tracing.phase`), so a
+    profiler capture shows it beside the device's operations.  Dispatch
     wall only (programs run async): the span bounds the host-side
     enqueue, not device execution."""
 
@@ -119,9 +122,9 @@ class CountedFn:
         if self._account is not None:
             self._account()
         tr = self._trace() if self._trace is not None else None
-        if tr is None:
-            return self.fn(*args)
-        with tr[0].span(f"dispatch.{self._name}", "train", step=tr[1]):
+        with (phase("train.launch") if tr is None else
+              phase("train.launch", tr[0], "train",
+                    span=f"dispatch.{self._name}", step=tr[1])):
             return self.fn(*args)
 
 

@@ -48,6 +48,16 @@ decode steps launched, bytes = those launched while the step before was
 still unread) and `serve.decode_ahead.dropped` (calls = lane-steps
 computed for a request that had already ended).
 
+What the engine's thread is doing, phase by phase (`monitor.tracing.
+phase`: a profiler annotation always, the same interval in the attached
+recorder where the engine step is sampled; disjoint, in this order in an
+iteration): `serve.idle` (the worker, nothing submitted and nothing
+unread), `serve.admit` (1), `serve.prefill.launch` (2),
+`serve.decode.launch` with the uploads of (3) inside it as
+`serve.decode.upload`, `serve.read` (the host blocked on a launched
+program's tokens) and `serve.bookkeep` (the host's half of (4) and (5)
+once they are there); the serial loop adds `serve.draft`.
+
 Resilience contract (the PR-8 machinery, applied to serving):
 
 * `fault_point` sites `serve.step` / `serve.admit` / `serve.prefill` /
@@ -73,7 +83,9 @@ Speculative decoding adds `serve.draft_tokens` (candidates proposed),
 `serve.accepted_tokens` (drafts accepted AND emitted — the
 acceptance-rate numerator; accepted/decode_steps is the extra
 tokens/step speculation bought), and `kv.dequant_ms` (µs-in-bytes:
-decode-family dispatch wall time against a QUANTIZED cache).
+wall time of the serial loop's `verify` dispatches, launch to tokens
+read, against a QUANTIZED cache; the loop that runs ahead records none
+— launch to read spans the step before there).
 
 Prefix caching + pinned sessions (PR 19): admission aliases the
 request's already-cached full prompt blocks (serving/kv_cache.py chain
@@ -111,9 +123,7 @@ programs pick their attention products from the call's query count.
 For such a model the engine refuses, by name, `prefix_cache=True`,
 sessions, `draft_len > 0`, quantized weights, int8/int4 rows and a mesh
 of more than one device.  Counters: `serve.mla.rows_read` (calls =
-queries decoded, bytes = latent rows they attend),
-`serve.mla.context_tokens` (bytes = the same queries' cached lengths:
-equal to the rows read while every cached row is attended),
+queries decoded, bytes = latent rows they attend: every cached row),
 `serve.moe.assignments` (calls = routed-layer calls, bytes =
 token-expert pairs they computed: tokens x top_k, nothing dropped) and
 `serve.moe.experts_touched` (calls = decode steps x routed layers,
@@ -125,8 +135,7 @@ What a paged step reads: `serve.paged.rows_walked` (calls = slots
 decoded, bytes = the pool rows attention reads for them: a slot's live
 blocks where the paged kernel runs, the table's whole width where the
 jnp oracle does — what the kernel registry answers for the decode
-program's shapes, asked once at build) beside
-`serve.paged.context_tokens` (bytes = the same slots' cached lengths).
+program's shapes, asked once at build).
 
 Speculative decoding (`draft_len > 0`): each decode step becomes a
 verify step — a host-side n-gram drafter proposes up to `draft_len`
@@ -154,6 +163,7 @@ import jax.numpy as jnp
 
 from ..kernels.eva import live_blocks
 from ..monitor.counters import COUNTERS
+from ..monitor.tracing import phase
 from ..runtime.resilience import fault_point
 from ..utils.logging import logger
 from .kv_cache import PagedKVCache, TRASH_BLOCK, resolve_kv_dtype
@@ -256,7 +266,6 @@ class _Step:
 
     toks: Any                         # device [R] (+ 1 behind routed FFNs)
     lanes: List[Tuple[Request, int]]  # (request, slot) as launched
-    t0: float                         # perf_counter at the launch
     tus0: int                         # the tracer's clock at the launch
     index: int                        # engine.steps at the launch
 
@@ -599,9 +608,15 @@ class ServeEngine:
         `prefill_chunk` spans, a `first_token` instant, per-step
         `decode_step`/`verify_step` spans with batch occupancy and
         draft accept counts, `finish`/`shed` instants — all cat
-        "serve"); request-scoped events are sampled per rid, step
-        spans per engine step, so a loaded engine stays within the
-        recorder's byte budget.  The SLO aggregator is fed UNSAMPLED
+        "serve") and the `serve.*` phases of every iteration;
+        request-scoped events are sampled per rid, step spans and
+        phases per engine step, so a loaded engine stays within the
+        recorder's byte budget.  A `decode_step` runs from the step's
+        launch to the end of its book-keeping one iteration later
+        (under the next launch); its `rids` are the requests its
+        tokens went to and `stamp_us` the instant they were stamped
+        (`Request.token_times`), on the recorder's clock, as on
+        `first_token`.  The SLO aggregator is fed UNSAMPLED
         (TTFT, tokens, queue depth, accept rate, sheds) and ticked at
         every step boundary so its windows never have sampling holes.
         When a watchdog is attached (before or after this call) the
@@ -674,9 +689,11 @@ class ServeEngine:
         if self._watchdog is not None:
             self._watchdog.beat(self.steps)
         fault_point("serve.admit")
-        self._expire_sessions()
-        self.scheduler.admit()
-        self._session_pressure_release()
+        tr = self._step_tracer()
+        with phase("serve.admit", tr):
+            self._expire_sessions()
+            self.scheduler.admit()
+            self._session_pressure_release()
         if self._slo is not None:
             # depth AFTER admission = backlog the cache/slots could not
             # absorb this step, the saturation signal SLO windows want
@@ -687,7 +704,10 @@ class ServeEngine:
             fault_point("serve.prefill")
             if self._check_shed():
                 return True
-            self._prefill_chunk(req)
+            with phase("serve.prefill.launch", tr):
+                self._prefill_chunk(req)
+            if self._serial:    # drafting reads the first token
+                self._settle()
             did = True
         ahead = None
         lanes = self._lanes()
@@ -698,7 +718,8 @@ class ServeEngine:
             if self._serial:
                 self._verify_step(lanes)
             else:
-                ahead = self._launch_decode(lanes)
+                with phase("serve.decode.launch", tr):
+                    ahead = self._launch_decode(lanes)
             did = True
         # the one blocking read: the step before, while `ahead` runs
         self._settle(keep=ahead)
@@ -806,9 +827,7 @@ class ServeEngine:
             positions=len(req.prompt), temperatures=req.temperature,
             top_ks=req.top_k, seeds=np.uint32(req.seed))
         self._unread.append(_First(req, tok))
-        if self._serial:
-            self._settle()
-        elif rides:
+        if rides and not self._serial:
             self._tokens = self.programs["seat"](
                 self._tokens, np.int32(req.slot), tok)
 
@@ -820,7 +839,6 @@ class ServeEngine:
         last token computed, which window this step fills."""
         tr = self._tracer
         step = _Step(toks=None, lanes=[(r, r.slot) for r in lanes],
-                     t0=time.perf_counter(),
                      tus0=tr.now_us() if tr is not None else 0,
                      index=self.steps)
         state = self._slots
@@ -846,15 +864,17 @@ class ServeEngine:
                     else self.kv.table_width))
         elif self.kv.latent_width:
             held = positions[slots].astype(np.int64) + 1
-            for name in ("serve.mla.rows_read", "serve.mla.context_tokens"):
-                COUNTERS.add(name, calls=len(lanes), nbytes=int(held.sum()))
+            COUNTERS.add("serve.mla.rows_read", calls=len(lanes),
+                         nbytes=int(held.sum()))
         else:
             self._count_rows_walked(lanes, 1)
         COUNTERS.add("serve.decode_ahead", nbytes=int(
             any(isinstance(u, _Step) for u in self._unread)))
+        with phase("serve.decode.upload", self._step_tracer()):
+            rows = state.on_device()
         step.toks, self.kv.caches, (self._tokens, moved) = \
             self.programs["decode"](self.params, self.kv.caches,
-                                    self._tokens, *state.on_device())
+                                    self._tokens, *rows)
         state.advanced(slots, moved)
         for req in lanes:
             req.cached_len += 1
@@ -879,58 +899,69 @@ class ServeEngine:
         req = item.req
         if req.done:                    # shed before its token was read
             return
-        first = int(item.tok)
-        now = self.clock()
-        req.t_first_token = now
-        req.token_times.append(now)
-        req.out.append(first)
-        COUNTERS.add("serve.tokens")
-        COUNTERS.add("serve.ttft_ms", nbytes=int(req.ttft_s * 1e6))
-        if self._slo is not None:
-            self._slo.observe_ttft(req.ttft_s)
-        tr = self._req_tracer(req)
-        if tr is not None:
-            tr.instant("first_token", "serve", rid=req.rid,
-                       ttft_ms=round(req.ttft_s * 1e3, 3))
-        if self._serial:
-            self._tokens[req.slot] = first
-        if self._is_finished(req, first):
-            self._finish(req)
+        phases = self._step_tracer()
+        with phase("serve.read", phases):
+            first = int(item.tok)
+        with phase("serve.bookkeep", phases):
+            tr = self._req_tracer(req)
+            now = self.clock()
+            stamp_us = tr.now_us() if tr is not None else 0
+            req.t_first_token = now
+            req.token_times.append(now)
+            req.out.append(first)
+            COUNTERS.add("serve.tokens")
+            COUNTERS.add("serve.ttft_ms", nbytes=int(req.ttft_s * 1e6))
+            if self._slo is not None:
+                self._slo.observe_ttft(req.ttft_s)
+            if tr is not None:
+                tr.instant("first_token", "serve", rid=req.rid,
+                           ttft_ms=round(req.ttft_s * 1e3, 3),
+                           stamp_us=stamp_us)
+            if self._serial:
+                self._tokens[req.slot] = first
+            if self._is_finished(req, first):
+                self._finish(req)
 
     def _read_step(self, step: _Step) -> None:
         """The host's half of a decode step, while the step after it
         runs: append, stamp, finish, free.  A request that ended at the
         step before (its `eos_token`, found one step late) rode this
         one too: that lane's token is dropped."""
-        toks = np.asarray(step.toks)
-        self._record_dequant(step.t0)
-        now = self.clock()
-        COUNTERS.add("serve.decode_steps", nbytes=len(step.lanes))
-        if self._routed_layers:
-            # behind the slots' tokens: the experts the step touched
-            self._count_assignments(len(step.lanes))
-            COUNTERS.add("serve.moe.experts_touched",
-                         calls=self._routed_layers,
-                         nbytes=int(toks[self.config.max_batch]))
-        emitted = 0
-        for req, slot in step.lanes:
-            if req.done:
-                COUNTERS.add("serve.decode_ahead.dropped")
-                continue
-            tok = int(toks[slot])
-            req.out.append(tok)
-            req.token_times.append(now)
-            emitted += 1
-            COUNTERS.add("serve.tokens")
-            if self._is_finished(req, tok):
-                self._finish(req)
-        if self._slo is not None:
-            self._slo.observe_tokens(emitted)
-        tr = self._tracer
-        if tr is not None and tr.sampled(f"step:{step.index}"):
-            tr.add_complete("decode_step", "serve", ts_us=step.tus0,
-                            dur_us=tr.now_us() - step.tus0, step=step.index,
-                            batch=len(step.lanes))
+        phases = self._step_tracer()
+        with phase("serve.read", phases):
+            toks = np.asarray(step.toks)
+        with phase("serve.bookkeep", phases):
+            tr = self._tracer
+            if tr is not None and not tr.sampled(f"step:{step.index}"):
+                tr = None
+            now = self.clock()
+            stamp_us = tr.now_us() if tr is not None else 0
+            COUNTERS.add("serve.decode_steps", nbytes=len(step.lanes))
+            if self._routed_layers:
+                # behind the slots' tokens: the experts the step touched
+                self._count_assignments(len(step.lanes))
+                COUNTERS.add("serve.moe.experts_touched",
+                             calls=self._routed_layers,
+                             nbytes=int(toks[self.config.max_batch]))
+            rids = []
+            for req, slot in step.lanes:
+                if req.done:
+                    COUNTERS.add("serve.decode_ahead.dropped")
+                    continue
+                tok = int(toks[slot])
+                req.out.append(tok)
+                req.token_times.append(now)
+                rids.append(req.rid)
+                COUNTERS.add("serve.tokens")
+                if self._is_finished(req, tok):
+                    self._finish(req)
+            if self._slo is not None:
+                self._slo.observe_tokens(len(rids))
+            if tr is not None:
+                tr.add_complete("decode_step", "serve", ts_us=step.tus0,
+                                dur_us=tr.now_us() - step.tus0,
+                                step=step.index, batch=len(step.lanes),
+                                rids=rids, stamp_us=stamp_us)
 
     def _count_assignments(self, n_tokens: int) -> None:
         """Token-expert pairs a call over `n_tokens` tokens computes in
@@ -943,7 +974,7 @@ class ServeEngine:
     def _count_rows_walked(self, running: List[Request],
                            n_queries: int) -> None:
         """The pool rows this step's attention reads for the running
-        slots, beside the lengths they hold once its rows are written."""
+        slots."""
         held = self._slots.host["positions"][
             [r.slot for r in running]].astype(np.int64) + n_queries
         bs = self.kv.block_size
@@ -951,8 +982,6 @@ class ServeEngine:
                   else np.full_like(held, self.kv.table_width * bs))
         COUNTERS.add("serve.paged.rows_walked", calls=len(running),
                      nbytes=int(walked.sum()))
-        COUNTERS.add("serve.paged.context_tokens", calls=len(running),
-                     nbytes=int(held.sum()))
 
     # -- summarised windows: host book-keeping at step boundaries --------
 
@@ -992,12 +1021,13 @@ class ServeEngine:
         return None
 
     def _record_dequant(self, t0: float) -> None:
-        """`kv.dequant_ms` (µs-in-bytes): wall time of decode-family
-        dispatches against a QUANTIZED cache — the in-program
-        dequantize is XLA-fused into the attention gather, so the
-        honest measurement is the whole dispatch, launch to tokens
-        read; A/B against the dense-kv lane of the same bench isolates
-        the dequant cost."""
+        """`kv.dequant_ms` (µs-in-bytes): wall time of the serial
+        loop's `verify` dispatches against a QUANTIZED cache — the
+        in-program dequantize is XLA-fused into the attention gather,
+        so the honest measurement is the whole dispatch, launch to
+        tokens read; A/B against the dense-kv lane of the same bench
+        isolates the dequant cost.  The loop that runs ahead records
+        none: there launch to read spans the step before too."""
         if self.kv.quant_wire:
             COUNTERS.add("kv.dequant_ms",
                          nbytes=int((time.perf_counter() - t0) * 1e6))
@@ -1063,25 +1093,38 @@ class ServeEngine:
         state = self._slots.host
         tr = self._step_tracer()
         tus0 = tr.now_us() if tr is not None else 0
-        drafts = np.zeros((R, k), np.int32)
-        n_draft = np.zeros((R,), np.int32)
-        for req in running:
-            d = self._propose_draft(req)
-            n_draft[req.slot] = len(d)
-            if d:
-                drafts[req.slot, :len(d)] = d
-                COUNTERS.add("serve.draft_tokens", calls=len(d))
-        tokens = np.concatenate([self._tokens[:, None], drafts], axis=1)
-        self._count_rows_walked(running, k + 1)
-        t0 = time.perf_counter()
-        toks, caches = self.programs["verify"](
-            self.params, self.kv.caches, jnp.asarray(tokens),
-            jnp.asarray(state["positions"]), jnp.asarray(n_draft),
-            *(jnp.asarray(state[name]) for name in (
-                "active", "tables", "temperatures", "top_ks", "seeds")))
-        self.kv.caches = caches
-        toks = np.asarray(toks)                     # [R, draft_len + 1]
-        self._record_dequant(t0)
+        with phase("serve.draft", tr):
+            drafts = np.zeros((R, k), np.int32)
+            n_draft = np.zeros((R,), np.int32)
+            for req in running:
+                d = self._propose_draft(req)
+                n_draft[req.slot] = len(d)
+                if d:
+                    drafts[req.slot, :len(d)] = d
+                    COUNTERS.add("serve.draft_tokens", calls=len(d))
+        with phase("serve.decode.launch", tr):
+            tokens = np.concatenate([self._tokens[:, None], drafts], axis=1)
+            self._count_rows_walked(running, k + 1)
+            t0 = time.perf_counter()
+            toks, caches = self.programs["verify"](
+                self.params, self.kv.caches, jnp.asarray(tokens),
+                jnp.asarray(state["positions"]), jnp.asarray(n_draft),
+                *(jnp.asarray(state[name]) for name in (
+                    "active", "tables", "temperatures", "top_ks", "seeds")))
+            self.kv.caches = caches
+        with phase("serve.read", tr):
+            toks = np.asarray(toks)                 # [R, draft_len + 1]
+        with phase("serve.bookkeep", tr):
+            self._record_dequant(t0)
+            self._emit_verified(running, toks, drafts, n_draft, tr, tus0)
+
+    def _emit_verified(self, running: List[Request], toks, drafts, n_draft,
+                       tr, tus0: int) -> None:
+        """The host's half of a verify step: per slot, accept while
+        draft i matches the target's sample for the same position; the
+        first sample past the matching prefix is the bonus (all
+        accepted) or the correction (a draft rejected)."""
+        state = self._slots.host
         now = self.clock()
         COUNTERS.add("serve.decode_steps", nbytes=len(running))
         tot_emitted = 0
@@ -1089,9 +1132,6 @@ class ServeEngine:
         for req in running:
             slot = req.slot
             nd = int(n_draft[slot])
-            # accept while draft i matches the target's sample for the
-            # same position; the first sample past the matching prefix
-            # is the bonus (nd == m) or correction (draft rejected)
             m = 0
             while m < nd and int(drafts[slot, m]) == int(toks[slot, m]):
                 m += 1
@@ -1225,10 +1265,11 @@ class ServeWorker(threading.Thread):
                     # a quiet traffic period never trips it.  A truly
                     # wedged step blocks THIS thread inside step(), so
                     # the idle beat can never mask a real hang.
-                    if eng._watchdog is not None:
-                        eng._watchdog.beat(eng.steps)
-                    eng._wake.wait(self.idle_wait_s)
-                    eng._wake.clear()
+                    with phase("serve.idle", eng._step_tracer()):
+                        if eng._watchdog is not None:
+                            eng._watchdog.beat(eng.steps)
+                        eng._wake.wait(self.idle_wait_s)
+                        eng._wake.clear()
         except BaseException as e:  # noqa: BLE001 — reported, not hidden
             self.error = e
             logger.error(f"serving worker died: {type(e).__name__}: {e}")

@@ -545,6 +545,160 @@ def test_serving_counters_pinned_exactly(model_and_params, programs):
     assert "serve.decode_ahead.dropped" not in d
 
 
+# -- phases: what the engine's thread says it is doing ----------------------
+
+LEAVES = ("serve.idle", "serve.admit", "serve.prefill.launch",
+          "serve.draft", "serve.decode.launch", "serve.read",
+          "serve.bookkeep")
+# one iteration of step(): the loop that runs ahead, then the serial one
+ITERATION = {
+    False: r"admit (prefill\.launch )?(decode\.launch )?(read bookkeep )*",
+    True: r"admit (prefill\.launch (read bookkeep )?)?"
+          r"(draft decode\.launch read bookkeep )?"}
+
+
+def _recorded(tmp_path, **kw):
+    from deepspeed_tpu.monitor.tracing import TraceRecorder
+
+    return TraceRecorder(str(tmp_path), buffer_events=1 << 16,
+                         flush_interval_s=10, **kw)
+
+
+def _check_phases(events, serial):
+    """Leaf phases never overlap and follow step()'s order; the uploads
+    lie inside the launch that makes them."""
+    import re
+
+    spans = [e for e in events if e["name"].startswith("serve.")
+             and e["ph"] == "X"]
+    leaves = sorted((e for e in spans if e["name"] in LEAVES),
+                    key=lambda e: e["ts"])
+    assert {e["name"] for e in spans} <= set(LEAVES) | {"serve.decode.upload"}
+    for a, b in zip(leaves, leaves[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"], (a, b)
+    order = "".join(e["name"][len("serve."):] + " " for e in leaves)
+    assert re.fullmatch(f"((idle )|({ITERATION[serial]}))*", order), order
+    launches = [e for e in leaves if e["name"] == "serve.decode.launch"]
+    for up in (e for e in spans if e["name"] == "serve.decode.upload"):
+        assert any(la["ts"] <= up["ts"] and up["ts"] + up["dur"]
+                   <= la["ts"] + la["dur"] for la in launches), up
+    return leaves
+
+
+@pytest.mark.parametrize("draft_len", [0, 2], ids=["ahead", "serial"])
+def test_phases_of_an_iteration_are_disjoint_and_in_order(
+        model_and_params, programs, tmp_path, draft_len):
+    """With a recorder attached every iteration of `step()` lands as
+    leaf phases that do not overlap and come in the loop's order, under
+    a worker with requests joining and leaving; `serve.idle` begins only
+    once nothing is submitted and nothing is unread."""
+    import time
+
+    from deepspeed_tpu.serving import ServeWorker
+
+    eng = _engine(model_and_params, None if draft_len else programs,
+                  draft_len=draft_len)
+    rec = _recorded(tmp_path)
+    eng.attach_tracing(tracer=rec)
+    prompts = _prompts(seed=71, lens=(5, 9, 3, 12, 7))
+    reqs = [eng.submit(prompts[0], 24)]     # keeps the engine busy
+    worker = ServeWorker(eng)
+    worker.start()
+    try:
+        for p, n in zip(prompts[1:], (3, 6, 2, 5)):
+            while len(reqs[0].out) < 2 * len(reqs):
+                time.sleep(0.001)
+            reqs.append(eng.submit(p, n))   # joins; the short ones leave
+        deadline = time.time() + 60
+        while eng.has_work() and time.time() < deadline:
+            time.sleep(0.001)
+        time.sleep(0.02)                    # a few idle waits
+    finally:
+        worker.stop()
+    assert all(r.state == FINISHED for r in reqs)
+    events = rec.last_events()
+    rec.close()
+    leaves = _check_phases(events, serial=bool(draft_len))
+    seen = {e["name"] for e in leaves}
+    assert seen == set(LEAVES) - ({"serve.draft"} if not draft_len else set())
+    # work was there from before the worker started until the last
+    # request finished: no idle phase begins before that
+    done = max(e["ts"] for e in events if e["name"] == "finish")
+    idle = [e for e in leaves if e["name"] == "serve.idle"]
+    assert idle and all(e["ts"] >= done for e in idle)
+
+
+def test_decode_step_rids_reproduce_token_times_gaps(model_and_params,
+                                                     programs, tmp_path):
+    """`first_token` and `decode_step` carry who was stamped and when,
+    on the recorder's clock: with the engine and the recorder on one
+    clock the gaps between a request's stamps are the gaps of its
+    `token_times`, exactly — an EOS found one step late included (its
+    dropped lane is in no `rids`)."""
+    ticks = iter(range(1, 1 << 20))
+    clock = lambda: float(next(ticks))      # whole seconds: exact in us
+    model, params = model_and_params
+    prompts = _prompts(seed=73, lens=(5, 9, 3, 12))
+    kw = dict(temperature=0.9, top_k=6, seed=42)
+    full = _alone(model_and_params, programs, prompts[3], 8, seeds=[42],
+                  temperature=0.9, top_k=6)
+    stop_at = next(i for i in range(1, 7) if full[i] not in full[:i])
+    eng = ServeEngine(model, params, _cfg(), programs=programs, clock=clock)
+    rec = _recorded(tmp_path, clock=clock)
+    eng.attach_tracing(tracer=rec)
+    snap = COUNTERS.snapshot()
+    reqs = []
+    for p, n in zip(prompts[:3], (9, 3, 12)):
+        reqs.append(eng.submit(p, n))
+        eng.step()
+    reqs.append(eng.submit(prompts[3], 8, eos_token=full[stop_at], **kw))
+    eng.run()
+    events = rec.last_events()
+    rec.close()
+    stamps = {r.rid: [] for r in reqs}
+    for e in events:
+        if e["name"] == "first_token":
+            stamps[e["args"]["rid"]].append(e["args"]["stamp_us"])
+        elif e["name"] == "decode_step":
+            assert len(e["args"]["rids"]) <= e["args"]["batch"]
+            assert e["ts"] <= e["args"]["stamp_us"] <= e["ts"] + e["dur"]
+            for rid in e["args"]["rids"]:
+                stamps[rid].append(e["args"]["stamp_us"])
+    assert reqs[3].out == full[:stop_at + 1]    # ended by its EOS
+    assert COUNTERS.delta_since(snap)["serve.decode_ahead.dropped"][
+        "calls"] == 1
+    for r in reqs:
+        assert len(stamps[r.rid]) == len(r.token_times) == len(r.out)
+        assert [(b - a) / 1e6 for a, b in zip(stamps[r.rid],
+                                              stamps[r.rid][1:])] == \
+            [b - a for a, b in zip(r.token_times, r.token_times[1:])]
+
+
+def test_programs_lower_the_same_with_and_without_a_recorder(
+        model_and_params, tmp_path):
+    """Phases are host annotations: `prefill`, `decode` and `seat` lower
+    to the same text whether or not a recorder is attached."""
+
+    def lowered(recorder):
+        eng = _engine(model_and_params)     # its own programs
+        if recorder is not None:
+            eng.attach_tracing(tracer=recorder)
+        texts = {}
+        for name in ("prefill", "decode", "seat"):
+            def capture(*args, _fn=eng.programs[name], _name=name):
+                texts.setdefault(_name, _fn.lower(*args).as_text())
+                return _fn(*args)
+            eng.programs[name] = capture
+        eng.generate(_prompts(seed=79, lens=(5, 9)), 4)
+        return texts
+
+    rec = _recorded(tmp_path)
+    with_rec, without = lowered(rec), lowered(None)
+    rec.close()
+    assert set(with_rec) == {"prefill", "decode", "seat"}
+    assert with_rec == without
+
+
 # -- chaos: wedged decode -> watchdog trip -> shed --------------------------
 
 

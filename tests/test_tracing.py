@@ -251,6 +251,46 @@ def test_training_timeline_content(tmp_path):
     assert tot and tot["calls"] > 0 and tot["bytes"] > 0
 
 
+def test_training_phases_and_the_lowered_step(tmp_path):
+    """The host's three stretches before a fused step is in flight land
+    in the recorder in order, `train.launch` under its old name; the
+    step lowers to the same text with tracing on and off."""
+
+    def lowered(job, tracing):
+        engine, *_ = ds.initialize(model=SimpleModel(),
+                                   config=engine_cfg(tmp_path, job, tracing))
+        counted, texts = engine._step_fns["full"], []
+
+        class Capture:
+            fn = counted.fn
+
+            def __call__(self, *args):
+                texts.append(counted.fn.lower(*args).as_text())
+                return counted(*args)
+
+        engine._step_fns["full"] = Capture()
+        for b in random_batches(2):
+            engine.forward(b)
+            engine.backward()
+            engine.step()
+        engine.finalize_monitoring()
+        return texts
+
+    traced = lowered("on", {"enabled": True, "flush_interval_s": 0.1})
+    assert traced == lowered("off", None) and len(traced) == 2
+    [path] = trace_files(tmp_path, "on")
+    events = read_trace_file(path)[0][0][1]
+    for step in (1, 2):
+        mine = sorted((e for e in events if e["ph"] == "X"
+                       and e.get("args", {}).get("step") == step
+                       and e["cat"] == "train"), key=lambda e: e["ts"])
+        assert [e["name"] for e in mine] == [
+            "train.settle_flag", "train.inputs", "dispatch.full"]
+        for a, b in zip(mine, mine[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"]
+    assert not any(e["name"] == "train.launch" for e in events)
+
+
 def test_training_sampling_thins_whole_steps(tmp_path):
     train_losses(tmp_path, "s",
                  tracing={"enabled": True, "sample_rate": 0.5,
@@ -368,6 +408,63 @@ def test_serving_traced_lifecycle_token_identical(tmp_path):
     snap = slo_events[-1]
     assert snap["requests"] == len(prompts)
     assert snap["ttft_ms"]["n"] == len(prompts)
+
+
+@pytest.mark.parametrize("family", ["gpt", "evabyte"])
+def test_worker_driven_run_is_token_identical_with_a_recorder(tmp_path,
+                                                              family):
+    """A worker-driven run — requests join while others decode and leave
+    at different steps; for EvaByte a window closes in mid-decode — gives
+    the same tokens with a recorder at sampling 1 as with none, and the
+    recorder saw every phase of the loop."""
+    import time
+
+    from deepspeed_tpu.serving import ServeEngine, ServeWorker
+
+    if family == "gpt":
+        model, params, cfg = _serve_fixture()
+        rs = np.random.RandomState(5)
+        jobs = [(rs.randint(0, 64, (n,)).tolist(), new)
+                for n, new in ((5, 20), (9, 3), (3, 7), (12, 2))]
+    else:
+        from tests import test_evabyte as eva
+
+        model = eva.EvaByte(eva._config())
+        params = jax.jit(model.init)(jax.random.PRNGKey(0))
+        cfg = eva._serve
+        # 29 + 20 crosses the window of 32 three decode steps in
+        jobs = [(eva._prompt(29, 0), 20), (eva._prompt(12, 1), 6),
+                (eva._prompt(7, 2), 3)]
+
+    def drive(recorder):
+        eng = ServeEngine(model, params, cfg())
+        if recorder is not None:
+            eng.attach_tracing(tracer=recorder)
+        reqs = [eng.submit(*jobs[0])]
+        worker = ServeWorker(eng)
+        worker.start()
+        try:
+            for job in jobs[1:]:
+                while len(reqs[0].out) < 2 * len(reqs):
+                    time.sleep(0.001)
+                reqs.append(eng.submit(*job))
+            deadline = time.time() + 120
+            while eng.has_work() and time.time() < deadline:
+                time.sleep(0.001)
+        finally:
+            worker.stop()
+        assert all(r.state == "finished" for r in reqs)
+        return [r.out for r in reqs]
+
+    rec = TraceRecorder(str(tmp_path), buffer_events=1 << 16,
+                        flush_interval_s=10)
+    traced = drive(rec)
+    names = {e["name"] for e in rec.last_events()}
+    rec.close()
+    assert traced == drive(None), "a recorder must not perturb the tokens"
+    assert {"serve.admit", "serve.prefill.launch", "serve.decode.launch",
+            "serve.decode.upload", "serve.read", "serve.bookkeep"} <= names
+    assert ("eva.window_close" in names) == (family == "evabyte")
 
 
 def test_watchdog_snapshot_ships_trace_tail(tmp_path):
