@@ -74,6 +74,9 @@ Instrumented sites:
   launched while the step before was still unread;
   `serve.decode_ahead.dropped` — lane-steps computed for a request
   that had already ended (an `eos_token` found one step late);
+  `serve.sample.greedy_steps` — calls = decode steps launched, bytes =
+  those in which no live slot had a temperature above 0, so that the
+  program's sampling tail was the argmax alone;
   `serve.prefill_chunks` — chunked-prefill dispatches (bytes = prompt
   tokens); `serve.ttft_ms` — time-to-first-token (integer MICROSECONDS
   in the bytes slot, the ckpt.stall_ms convention; one call per first

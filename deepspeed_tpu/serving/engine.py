@@ -870,6 +870,10 @@ class ServeEngine:
             self._count_rows_walked(lanes, 1)
         COUNTERS.add("serve.decode_ahead", nbytes=int(
             any(isinstance(u, _Step) for u in self._unread)))
+        # the predicate `decode` picks its sampling tail by, from the
+        # rows it is handed
+        COUNTERS.add("serve.sample.greedy_steps", nbytes=int(not np.any(
+            state.host["active"] & (state.host["temperatures"] > 0))))
         with phase("serve.decode.upload", self._step_tracer()):
             rows = state.on_device()
         step.toks, self.kv.caches, (self._tokens, moved) = \

@@ -70,6 +70,12 @@ Sampling determinism: the key for the token generated at absolute
 position p is `fold_in(PRNGKey(request.seed), p)` — a pure function of
 the request, never of the batch composition or the step count, so
 sampled output is identical-under-seed across batch join/leave too.
+What a call's sampling tail computes follows from what its live rows
+ask for (`sample_rows`): the argmax alone where none has a temperature
+above 0 — the keys, the scaled logits and the noise over [rows, V]
+stand inside a conditional — and the k-th largest value, selected and
+never sorted for, only where a sampling row sets `top_k`.  A row's
+token is the same on every path it can take.
 
 qwZ weights (`quantized="int8"|"int4"`): weights are stored blockwise
 quantized (runtime/comm/quant.py, the PR-7 kernels) and dequantized at
@@ -146,30 +152,89 @@ class ServeSchedule(NamedTuple):
 # -- sampling ---------------------------------------------------------------
 
 
-def sample_token(logits, temperature, top_k, key):
-    """One row: greedy at temperature 0, else temperature + optional
-    top-k truncation, sampled with the caller's key.  `top_k`/
-    `temperature` are per-request ARRAYS (not static), so one compiled
-    program serves every request mix."""
-    greedy = jnp.argmax(logits, axis=-1)
-    v = logits.shape[-1]
-    t = jnp.where(temperature > 0, temperature, 1.0)
-    scaled = logits.astype(jnp.float32) / t
-    # dynamic top-k: value-threshold against the k-th largest logit
-    # (ties at the threshold survive, the HF semantics generation.py
-    # documents); top_k <= 0 disables the filter
-    sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
-    kth = sorted_desc[jnp.clip(top_k, 1, v) - 1]
-    filtered = jnp.where((top_k > 0) & (scaled < kth), -jnp.inf, scaled)
-    sampled = jax.random.categorical(key, filtered, axis=-1)
-    return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
-
-
 def _row_key(seed, position):
     """THE sampling-key rule: the token generated at absolute position
     p uses fold_in(PRNGKey(seed), p) — shared by prefill (first token)
     and decode so batch composition can never reach the RNG stream."""
     return jax.random.fold_in(jax.random.PRNGKey(seed), position)
+
+
+def _kth_largest(x, k):
+    """The k-th largest value of every row of float32 `x` [N, V], `k`
+    [N] in 1..V, exactly and without a sort: float32 bit patterns, the
+    magnitude bits flipped under a set sign, order as int32 the way the
+    floats do (-inf lowest, -0.0 under +0.0 — `jnp.sort`'s total
+    order), so the answer is the largest t with `count(row >= t) >= k`,
+    built from the sign bit down: 32 compare-and-count passes over
+    [N, V] whatever k is."""
+    def image(bits):                 # its own inverse
+        return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+    keys = image(jax.lax.bitcast_convert_type(x, jnp.int32))
+
+    def reaches(t):
+        return jnp.sum(keys >= t[:, None], axis=-1, dtype=jnp.int32) >= k
+
+    zero = jnp.zeros(k.shape, jnp.int32)
+    t = jnp.where(reaches(zero), zero, jnp.iinfo(jnp.int32).min)
+
+    def lower_bit(i, t):
+        up = t | (jnp.int32(1 << 30) >> i)
+        return jnp.where(reaches(up), up, t)
+
+    t = jax.lax.fori_loop(0, 31, lower_bit, t)
+    return jax.lax.bitcast_convert_type(image(t), jnp.float32)
+
+
+def top_k_filter(scaled, top_ks):
+    """`scaled` [N, V] float32 with everything under a row's k-th
+    largest value at -inf (ties at the threshold survive, the HF
+    semantics generation.py documents); `top_ks` [N] <= 0 leaves a row
+    as it is, >= V keeps everything."""
+    k = jnp.clip(top_ks, 1, scaled.shape[-1])
+    kth = _kth_largest(scaled, k)[:, None]
+    return jnp.where((top_ks > 0)[:, None] & (scaled < kth), -jnp.inf,
+                     scaled)
+
+
+def sample_rows(logits, temperatures, top_ks, live, keys):
+    """One token a row of `logits` [N, V]: greedy at temperature 0,
+    else temperature + optional top-k truncation, drawn with the row's
+    key.  `temperatures` / `top_ks` [N] are per-request ARRAYS (not
+    static), so one compiled program serves every request mix — and the
+    tail does only the work the CALL's rows ask for: where no `live`
+    row samples it is the argmax alone (`lax.cond` over the whole call:
+    no scaled copy, no keys, no noise over [N, V]); where one samples
+    and no live sampling row sets `top_k`, the draw without the
+    threshold; the threshold only where one does.  `live` [N] marks the
+    rows whose token anybody reads — a freed slot keeps the temperature
+    of the request that left it.  `keys` is called inside the sampling
+    branch and returns the rows' keys [N].  A greedy row is the same
+    argmax on every path, so its token does not depend on what its
+    neighbours asked for; neither does a sampled live row's: a
+    threshold skipped is one that no live sampling row set."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    samples = live & (temperatures > 0)
+
+    def draw():
+        t = jnp.where(temperatures > 0, temperatures, 1.0)
+        scaled = logits.astype(jnp.float32) / t[:, None]
+        filtered = jax.lax.cond(
+            jnp.any(samples & (top_ks > 0)),
+            lambda s: top_k_filter(s, top_ks), lambda s: s, scaled)
+        drawn = jax.vmap(partial(jax.random.categorical, axis=-1))(
+            keys(), filtered)
+        return jnp.where(temperatures > 0, drawn.astype(jnp.int32), greedy)
+
+    return jax.lax.cond(jnp.any(samples), draw, lambda: greedy)
+
+
+def sample_token(logits, temperature, top_k, key):
+    """One row of `sample_rows`, with the caller's key."""
+    return sample_rows(
+        logits[None], jnp.asarray(temperature)[None],
+        jnp.asarray(top_k)[None], jnp.ones((1,), bool),
+        lambda: key[None])[0]
 
 
 @jax.jit
@@ -385,9 +450,10 @@ class ServeProgramBuilder:
             x = layers.final_norm(spec, params, x)
             last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
             logits = layers.logits(spec, params, last[:, 0, :])  # [1, V]
-            key = _row_key(seed, pos + n_valid)
-            tok = sample_token(layers.sampled(spec, logits[0]), temperature,
-                               top_k, key)
+            tok = sample_rows(
+                layers.sampled(spec, logits), temperature[None],
+                top_k[None], jnp.ones((1,), bool),
+                lambda: _row_key(seed, pos + n_valid)[None])[0]
             return tok, logits[0], new_caches
 
         return prefill
@@ -433,9 +499,9 @@ class ServeProgramBuilder:
             params = self._maybe_dequant(params)
             logits, new_caches, touched = self.step_logits(
                 params, caches, tokens, positions, active, tables)
-            keys = jax.vmap(_row_key)(seeds, positions + 1)
-            toks = jax.vmap(sample_token)(layers.sampled(spec, logits),
-                                          temperatures, top_ks, keys)
+            toks = sample_rows(
+                layers.sampled(spec, logits), temperatures, top_ks, active,
+                lambda: jax.vmap(_row_key)(seeds, positions + 1))
             ahead = (toks, positions + active.astype(positions.dtype))
             if touched:
                 toks = jnp.concatenate([toks, sum(touched)[None]])
@@ -485,13 +551,15 @@ class ServeProgramBuilder:
                 new_caches.append(kv)
             x = layers.final_norm(spec, params, x)
             logits = layers.logits(
-                spec, params,
-                x.reshape(R * T, -1)).reshape(R, T, -1)   # [R, T, V]
-            keys = jax.vmap(jax.vmap(_row_key, in_axes=(None, 0)))(
-                seeds, abs_pos + 1)
-            toks = jax.vmap(jax.vmap(
-                sample_token, in_axes=(0, None, None, 0)))(
-                logits, temperatures, top_ks, keys)
-            return toks, new_caches
+                spec, params, x.reshape(R * T, -1))       # [R * T, V]
+
+            def rows(a):             # a slot's value at each of its T rows
+                return jnp.repeat(a, T)
+
+            toks = sample_rows(
+                logits, rows(temperatures), rows(top_ks), rows(active),
+                lambda: jax.vmap(_row_key)(rows(seeds),
+                                           abs_pos.reshape(-1) + 1))
+            return toks.reshape(R, T), new_caches
 
         return verify

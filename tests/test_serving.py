@@ -22,6 +22,9 @@ from deepspeed_tpu.monitor.counters import COUNTERS
 from deepspeed_tpu.serving import (ERROR, FINISHED, TRASH_BLOCK, ServeConfig,
                                    ServeEngine, ServeProgramBuilder,
                                    ServeSchedule, WAITING)
+from deepspeed_tpu.serving import programs as programs_mod
+from deepspeed_tpu.serving.programs import (_kth_largest, sample_rows,
+                                            top_k_filter)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 
@@ -498,6 +501,242 @@ def test_drafting_keeps_the_serial_loop(model_and_params):
     d = COUNTERS.delta_since(snap)
     assert [r.out for r in reqs] == plain
     assert "serve.decode_ahead" not in d and d["serve.decode_steps"]["calls"]
+
+
+# -- the sampling tail: only the work the call's rows ask for ---------------
+
+
+def _sorted_rule(logits, temperature, top_k, key):
+    """The rule the programs sampled by until they stopped sorting: one
+    row, a full descending sort for the k-th largest value.  Kept here
+    as the oracle: (token, filtered logits)."""
+    greedy = jnp.argmax(logits, axis=-1)
+    v = logits.shape[-1]
+    t = jnp.where(temperature > 0, temperature, 1.0)
+    scaled = logits.astype(jnp.float32) / t
+    sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
+    kth = sorted_desc[jnp.clip(top_k, 1, v) - 1]
+    filtered = jnp.where((top_k > 0) & (scaled < kth), -jnp.inf, scaled)
+    sampled = jax.random.categorical(key, filtered, axis=-1)
+    return (jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32),
+            filtered)
+
+
+def _sorted_rows(logits, temperatures, top_ks, live, keys):
+    """`sample_rows` as it was: every row sorts, whatever it asks for."""
+    return jax.vmap(_sorted_rule)(logits, temperatures, top_ks, keys())[0]
+
+
+def _mixed_call(vocab, dtype, seed):
+    """A call's rows, one of each kind: greedy, and sampled with
+    `top_k` 0 / 1 / 40 / V / above V — over logits on a grid of halves,
+    so that the k-th value is shared by several columns, the top few
+    columns exactly equal, a run of -inf in every row and one row with
+    fewer finite values than its `top_k`."""
+    rs = np.random.RandomState(seed)
+    top_ks = np.array([0, 0, 1, 40, vocab, vocab + 7, 40, 3, 5], np.int32)
+    temps = np.array([0, .7, .7, 1.3, .9, .7, 0, 2.5, .7], np.float32)
+    n = len(top_ks)
+    logits = np.round(rs.randn(n, vocab) * 3) / 2
+    logits[:, rs.permutation(vocab)[:4]] = 6.0          # tied at the top
+    logits[:, rs.permutation(vocab)[:vocab // 5]] = -np.inf
+    logits[-1, rs.permutation(vocab)[:vocab - 3]] = -np.inf
+    logits[1] += rs.randn(vocab) * 1e-3                 # and one row untied
+    keys = jax.vmap(jax.random.PRNGKey)(
+        jnp.asarray(rs.randint(0, 2 ** 31 - 1, n), jnp.uint32))
+    return (jnp.asarray(logits, dtype), jnp.asarray(temps),
+            jnp.asarray(top_ks), keys)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("vocab", [64, 200, 1031])
+def test_sampling_tail_draws_what_the_sorted_rule_draws(vocab, dtype, seed):
+    """Every row of a mixed call gets the sorted rule's token, and a
+    row that sets `top_k` the sorted rule's filtered logits bit for bit
+    — ties at the threshold, -inf, `top_k` at and above V, a vocabulary
+    that is no multiple of 128."""
+    logits, temps, top_ks, keys = _mixed_call(vocab, dtype, seed)
+    live = jnp.ones(temps.shape, bool)
+    want_tok, want_filtered = jax.vmap(_sorted_rule)(logits, temps, top_ks,
+                                                     keys)
+    got = jax.jit(sample_rows, static_argnums=4)(
+        logits, temps, top_ks, live, lambda: keys)
+    assert np.array_equal(np.asarray(got), np.asarray(want_tok))
+    # the draws must actually vary, or equal tokens would say nothing
+    assert len(set(np.asarray(got)[np.asarray(temps) > 0].tolist())) > 1
+    t = jnp.where(temps > 0, temps, 1.0)
+    scaled = logits.astype(jnp.float32) / t[:, None]
+    got_filtered = jax.jit(top_k_filter)(scaled, top_ks)
+    assert np.array_equal(np.asarray(got_filtered).view(np.uint32),
+                          np.asarray(want_filtered).view(np.uint32))
+    cut = np.isneginf(np.asarray(got_filtered)) & ~np.isneginf(
+        np.asarray(scaled))
+    assert cut[3].any() and not cut[[0, 1, 4, 5]].any()
+
+
+def test_kth_largest_is_the_sorts_own_entry():
+    """The selection against the sort it replaces, value by value: every
+    k of a row that holds both zeros, both infinities and repeats (the
+    two zeros compare equal, and a backend may sort them either way)."""
+    row = np.array([0.0, -0.0, np.inf, -np.inf, 1e-30, -1e-30, 1.5, 1.5,
+                    -1.5, 3e38, -3e38, 1.0000001, 1.0, -np.inf, 0.0],
+                   np.float32)
+    v = len(row)
+    x = jnp.asarray(np.tile(row, (v, 1)))
+    got = jax.jit(_kth_largest)(x, jnp.arange(1, v + 1, dtype=jnp.int32))
+    want = jnp.sort(jnp.asarray(row))[::-1]
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def _tail_taken(temps, top_ks, live, logits=None):
+    """(tokens, whether the sampling branch RAN) for one call."""
+    n = len(temps)
+    if logits is None:
+        logits = jnp.asarray(np.random.RandomState(5).randn(n, VOCAB),
+                             jnp.float32)
+    ran = []
+
+    def keys():
+        jax.debug.callback(lambda: ran.append(1))
+        return jax.vmap(jax.random.PRNGKey)(jnp.arange(n, dtype=jnp.uint32))
+
+    toks = jax.jit(sample_rows, static_argnums=4)(
+        logits, jnp.asarray(temps, jnp.float32),
+        jnp.asarray(top_ks, jnp.int32), jnp.asarray(live, bool), keys)
+    jax.effects_barrier()
+    return np.asarray(toks), bool(ran), logits
+
+
+@pytest.mark.parametrize("temps,live,samples", [
+    ((0, 0, 0), (1, 1, 1), False),
+    ((0, .8, 0), (1, 0, 1), False),     # a freed slot's stale temperature
+    ((.8, .8, .8), (0, 0, 0), False),
+    ((0, .8, 0), (1, 1, 1), True),
+    ((0, .8, .8), (0, 0, 1), True),
+], ids=["greedy", "stale", "all-stale", "one-samples", "last-samples"])
+def test_no_live_sampling_row_no_sampling_tail(temps, live, samples):
+    """The sampling branch runs iff a LIVE row has `temperature` > 0; a
+    call that stays on the greedy tail returns every row's argmax."""
+    toks, ran, logits = _tail_taken(temps, (0, 4, 0), live)
+    assert ran == samples
+    if not samples:
+        assert toks.tolist() == np.argmax(np.asarray(logits), -1).tolist()
+
+
+@pytest.mark.parametrize("neighbours", [
+    dict(temps=(0, 0, 0), top_ks=(0, 0, 0)),
+    dict(temps=(0, .8, 1.2), top_ks=(0, 0, 0)),
+    dict(temps=(0, .8, 1.2), top_ks=(0, 5, 0)),
+    dict(temps=(0, .8, 1.2), top_ks=(3, 5, 70)),
+], ids=["greedy", "sampling", "one-top-k", "all-top-k"])
+def test_a_rows_token_does_not_depend_on_its_neighbours_tail(neighbours):
+    """Row 0 (greedy) and row 3 (sampled, no `top_k`) beside neighbours
+    that take the call through each of the three tails."""
+    temps = neighbours["temps"] + (0.9,)
+    top_ks = neighbours["top_ks"] + (0,)
+    toks, _, logits = _tail_taken(temps, top_ks, (1, 1, 1, 1))
+    assert toks[0] == int(jnp.argmax(logits[0]))
+    # row 3 alone, with the key `_tail_taken` gives its index
+    want = _sorted_rule(logits[3], jnp.float32(0.9), jnp.int32(0),
+                        jax.random.PRNGKey(jnp.uint32(3)))[0]
+    assert toks[3] == int(want)
+
+
+@pytest.mark.parametrize("top_k", [0, 5, VOCAB + 3])
+@pytest.mark.parametrize("draft_len", [0, 2], ids=["decode", "verify"])
+def test_programs_draw_the_sorted_rules_tokens(model_and_params, monkeypatch,
+                                               draft_len, top_k):
+    """`prefill`, `decode` and `verify` built around the sorted rule and
+    built as they are give the same answers at `temperature` > 0, with a
+    greedy request decoding beside the sampled ones."""
+    model, params = model_and_params
+    prompts = _prompts(seed=61)
+
+    def answers():
+        eng = ServeEngine(model, params, _cfg(draft_len=draft_len))
+        reqs = [eng.submit(p, 9, seed=300 + i,
+                           temperature=0.0 if i == 2 else 0.6 + i / 4,
+                           top_k=top_k if i % 2 else 0)
+                for i, p in enumerate(prompts)]
+        eng.run()
+        return [r.out for r in reqs]
+
+    monkeypatch.setattr(programs_mod, "sample_rows", _sorted_rows)
+    want = answers()
+    monkeypatch.undo()
+    got = answers()
+    assert got == want
+    assert any(len(set(o)) > 2 for o in got)
+
+
+def _outside_conds(jaxpr):
+    """Names of the primitives a program runs whatever its `cond`s
+    decide: its own and its nested calls', not the branches'."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name == "cond":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _outside_conds(sub)
+    return names
+
+
+def test_decode_sorts_nothing_and_draws_only_inside_a_branch(
+        model_and_params):
+    """The lowered `decode` holds no sort at all, and what the sampling
+    tail costs — keys, noise bits — stands inside a conditional."""
+    eng = _engine(model_and_params)
+    seen = {}
+
+    def capture(*args, _fn=eng.programs["decode"]):
+        if not seen:
+            seen["text"] = _fn.lower(*args).as_text()
+            seen["jaxpr"] = jax.make_jaxpr(_fn)(*args).jaxpr
+        return _fn(*args)
+
+    eng.programs["decode"] = capture
+    eng.generate(_prompts(seed=83, lens=(5,)), 3)
+    assert "stablehlo.sort" not in seen["text"]
+    assert "top_k" not in seen["text"]
+    assert "stablehlo.case" in seen["text"] or "stablehlo.if" in seen["text"]
+    always = set(_outside_conds(seen["jaxpr"]))
+    assert "cond" in always and "argmax" in always
+    assert not always & {"sort", "top_k", "random_bits", "threefry2x32",
+                         "random_wrap", "random_fold_in", "random_seed"}
+
+
+def test_greedy_steps_counter_follows_the_live_rows(model_and_params,
+                                                    programs):
+    """`serve.sample.greedy_steps`: calls = decode steps launched, bytes
+    = those in which no live slot samples — every step of a greedy run,
+    none while a sampled request is seated, and every step again once it
+    has left its slot (and its temperature) behind."""
+    prompts = _prompts(seed=89)
+    eng = _engine(model_and_params, programs)
+    snap = COUNTERS.snapshot()
+    eng.generate(prompts[:2], 5)
+    d = COUNTERS.delta_since(snap)
+    assert d["serve.sample.greedy_steps"] == {
+        "calls": d["serve.decode_ahead"]["calls"],
+        "bytes": d["serve.decode_ahead"]["calls"]}, d
+
+    snap = COUNTERS.snapshot()
+    greedy = eng.submit(prompts[0], 12)
+    sampled = eng.submit(prompts[1], 4, temperature=0.8, top_k=5, seed=3)
+    eng.step()
+    slot = sampled.slot
+    eng.run()
+    d = COUNTERS.delta_since(snap)
+    assert (len(greedy.out), len(sampled.out)) == (12, 4)
+    # 11 decode steps; the sampled request rides the first 3 of them and
+    # its slot keeps temperature 0.8, inactive, through the other 8
+    assert eng._slots.host["temperatures"][slot] > 0
+    assert not eng._slots.host["active"][slot]
+    assert d["serve.sample.greedy_steps"] == {"calls": 11, "bytes": 8}, d
+    assert greedy.out == _alone(model_and_params, programs, prompts[0], 12)
 
 
 # -- counters ---------------------------------------------------------------
