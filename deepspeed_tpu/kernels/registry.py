@@ -298,12 +298,22 @@ class PagedAttentionOp(KernelOp):
     (serving stays bit-identical to `generate()` wherever the oracle is
     chosen).  The shape rule looks at what the call site can observe —
     `q_len`, `kv_mode`, `block_size`, the row's width and dtype, the
-    table's width — never at a head size or a model."""
+    table's width, whether the row has fewer K/V heads than the call
+    has query heads — never at a head size or a model."""
 
     NAME = "paged_attention"
 
     def auto_supports(self, variant, info):
-        return _walk_supports(info) if info else (True, "")
+        if not info:
+            return True, ""
+        H, kv = int(info.get("num_heads", 1)), int(info.get("kv_heads", 0))
+        if kv and kv != H:
+            return False, (f"grouped rows: {H} query heads on {kv} K/V "
+                           f"heads a row, and the walk takes one query "
+                           f"head a K/V head (a tile of the row's heads "
+                           f"against as many queries); the gather in "
+                           f"jax.numpy reads them")
+        return _walk_supports(info)
 
     def pallas(self, variant, *args, **kwargs):
         from . import paged

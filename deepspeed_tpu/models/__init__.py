@@ -2,6 +2,7 @@
 its model tests drive an external Megatron GPT-2, SURVEY.md §1)."""
 
 from .bert import Bert, BertConfig, bert_config, BERT_SIZES
+from .cohere2_moe import Cohere2Moe, Cohere2MoeConfig
 from .deepseek_v2 import DeepSeekV2, DeepSeekV2Config
 from .evabyte import EvaByte, EvaByteConfig
 from .gpt import GPT, GPTConfig, gpt2_config, GPT2_SIZES
@@ -15,6 +16,7 @@ __all__ = ["GPT", "GPTConfig", "gpt2_config", "GPT2_SIZES",
            "gpt_pipeline_module",
            "Bert", "BertConfig", "bert_config", "BERT_SIZES",
            "EvaByte", "EvaByteConfig", "DeepSeekV2", "DeepSeekV2Config",
+           "Cohere2Moe", "Cohere2MoeConfig",
            "LayerSpec",
            "load_hf_gpt2", "gpt2_config_from_hf",
            "load_hf_bert", "bert_config_from_hf", "generate"]

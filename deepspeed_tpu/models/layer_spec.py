@@ -16,24 +16,32 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
-NORMS = ("layernorm", "rmsnorm_unit_offset", "rmsnorm")
-POSITIONS = ("learned", "rope")
-ATTENTIONS = ("paged", "eva", "latent")
+NORMS = ("layernorm", "rmsnorm_unit_offset", "rmsnorm", "layernorm_gain")
+POSITIONS = ("learned", "rope", "per_layer")
+ATTENTIONS = ("paged", "eva", "latent", "grouped")
 FFNS = ("gelu_mlp", "silu_gated", "routed_experts")
 HEADS = ("tied", "untied")
+RESIDUALS = ("sequential", "parallel")
+SCORINGS = ("softmax", "sigmoid")
+SHARED = ("sum", "average")
 
 
 class LayerSpec(NamedTuple):
-    """One kind of layer, repeated `num_layers` times; the first
-    `dense_layers` of them may keep a plain gated FFN where the others
-    route.
+    """One kind of layer, repeated `num_layers` times — or, with
+    "grouped" attention, a pattern of layers repeated: `layer_windows`
+    and `layer_positions` give layer l its window and its positions at
+    index l mod the pattern's length.  The first `dense_layers` layers
+    may keep a plain gated FFN where the others route.
 
     norm       "layernorm" (scale, bias) | "rmsnorm_unit_offset" (the
-               scale is 1 + g) | "rmsnorm" (the scale is the gain w)
+               scale is 1 + g) | "rmsnorm" (the scale is the gain w) |
+               "layernorm_gain" (mean subtracted, a gain and no bias)
     positions  "learned" (a `wpe` table added to the embedding) | "rope"
                (rotary on q and k: over the whole head, or, with latent
                attention, over the rotary part of it, with the model's
-               YaRN frequencies)
+               YaRN frequencies) | "per_layer" (`layer_positions` says
+               of each layer "rope" — over the whole head, interleaved
+               pairing — or "none": no positions at all)
     attention  "paged": causal softmax over every cached position; the
                cache holds one exact K/V row a token for the request's
                whole life.  "eva": exact rows for the open window of
@@ -45,12 +53,27 @@ class LayerSpec(NamedTuple):
                expands a request's rows to per-head keys and values,
                decode absorbs the expansion into the query and the
                output (models/deepseek_v2.py).
+               "grouped": causal softmax over exact rows of `kv_heads`
+               keys and values a token, query head n reading K/V head
+               n // (num_heads / kv_heads); a layer whose entry of
+               `layer_windows` is w > 0 attends the last w positions
+               only (the query's own among them) and its rows may live
+               in a ring of `window + prefill_chunk` rows a request
+               (serving/kv_cache.py's group "window"); 0 is every
+               cached position (models/cohere2_moe.py).
     ffn        "gelu_mlp" (fc1, tanh GELU, fc2, biases) | "silu_gated"
-               | "routed_experts" (a float32 softmax router, the top k
-               experts a token unrenormalised, every assignment
-               computed — moe/dropless.py — plus shared experts; the
-               first `dense_layers` layers are "silu_gated")
+               | "routed_experts" (a float32 router — `scoring`
+               "softmax" over all experts or "sigmoid" of each — the top
+               k experts a token, their weights as they are or, with
+               `renormalize`, over their sum; every assignment computed
+               — moe/dropless.py — among the `experts_held` experts from
+               `first_expert` on that this chip holds (0: all); plus
+               shared experts, their outputs summed or, with `shared`
+               "average", their mean; the first `dense_layers` layers
+               are "silu_gated")
     head       "tied" (wte transposed) | "untied" (`lm_head`)
+    residual   "sequential" (x + attn(norm1 x), then + ffn(norm2 of
+               that)) | "parallel" (one norm: x + attn(h) + ffn(h))
     eps        the norm's epsilon
     """
 
@@ -71,11 +94,39 @@ class LayerSpec(NamedTuple):
     top_k: int = 0           # routed_experts: experts a token chooses
     dense_layers: int = 0    # routed_experts: leading layers that keep
     #                          a plain gated FFN
+    kv_heads: int = 0        # grouped: K/V heads of a cache row
+    layer_windows: tuple = ()    # grouped: the pattern's windows (0: full)
+    layer_positions: tuple = ()  # per_layer: the pattern's "rope" | "none"
+    residual: str = "sequential"
+    scoring: str = "softmax"     # routed_experts: the router's scores
+    renormalize: bool = False    # routed_experts: weights over their sum
+    shared: str = "sum"          # routed_experts: the shared experts
+    experts_held: int = 0        # routed_experts: experts held here (0: all)
+    first_expert: int = 0        # routed_experts: the first one held
+
+    def window_of(self, layer: int) -> int:
+        """The window of layer `layer` (0: every cached position)."""
+        if not self.layer_windows:
+            return 0
+        return self.layer_windows[layer % len(self.layer_windows)]
+
+    def rotates(self, layer: int) -> bool:
+        """Whether layer `layer` of a "per_layer" spec rotates q and k."""
+        return self.layer_positions[
+            layer % len(self.layer_positions)] == "rope"
+
+    @property
+    def held(self):
+        """None, or (first expert held, experts held) of a share."""
+        if not self.experts_held:
+            return None
+        return self.first_expert, self.experts_held
 
     def validate(self) -> "LayerSpec":
         for value, kinds in ((self.norm, NORMS), (self.positions, POSITIONS),
                              (self.attention, ATTENTIONS), (self.ffn, FFNS),
-                             (self.head, HEADS)):
+                             (self.head, HEADS), (self.residual, RESIDUALS),
+                             (self.scoring, SCORINGS), (self.shared, SHARED)):
             if value not in kinds:
                 raise ValueError(
                     f"layer spec: {value!r} is not one of {kinds}")
@@ -97,4 +148,36 @@ class LayerSpec(NamedTuple):
                 f"names the top_k experts a token chooses and may keep "
                 f"leading dense_layers (got {self.ffn!r}, top_k "
                 f"{self.top_k}, dense_layers {self.dense_layers})")
+        if (self.attention == "grouped") != (self.kv_heads > 0) or (
+                self.layer_windows and self.attention != "grouped"):
+            raise ValueError(
+                f"layer spec: grouped attention, and nothing else, names "
+                f"the kv_heads of its row and may give layer_windows (got "
+                f"{self.attention!r}, kv_heads {self.kv_heads}, "
+                f"layer_windows {self.layer_windows})")
+        if any(int(w) < 0 for w in self.layer_windows):
+            raise ValueError(
+                f"layer spec: a layer's window is 0 (full) or a count of "
+                f"positions, got {self.layer_windows}")
+        if (self.positions == "per_layer") != bool(self.layer_positions) \
+                or any(p not in ("rope", "none")
+                       for p in self.layer_positions):
+            raise ValueError(
+                f"layer spec: per_layer positions, and nothing else, say "
+                f"\"rope\" or \"none\" of each layer of the pattern (got "
+                f"{self.positions!r}, {self.layer_positions})")
+        routed_only = (self.scoring != "softmax" or self.renormalize
+                       or self.shared != "sum" or self.experts_held
+                       or self.first_expert)
+        if routed_only and self.ffn != "routed_experts":
+            raise ValueError(
+                f"layer spec: scoring, renormalize, shared, experts_held "
+                f"and first_expert describe a routed_experts FFN (got "
+                f"{self.ffn!r})")
+        if self.experts_held < 0 or self.first_expert < 0 or (
+                self.first_expert and not self.experts_held):
+            raise ValueError(
+                f"layer spec: a share of the experts is experts_held > 0 "
+                f"experts from first_expert >= 0 on (got "
+                f"{self.experts_held}, {self.first_expert})")
         return self

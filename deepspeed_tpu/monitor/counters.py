@@ -136,6 +136,17 @@ Instrumented sites:
   dropped); `serve.moe.experts_touched` — calls = decode steps x
   routed layers, bytes = experts with at least one active slot's
   token (counted in the program, read back with the step's tokens).
+  Grouped rows over two groups of layers (a served model with
+  "grouped" attention and sliding layers): `serve.window.rows_read` —
+  calls = queries decoded, bytes = rows one attends in ONE sliding
+  layer (min(cached, window)); `serve.attn.rows_read` — the same summed
+  over all the layers (every cached row in a full one), both from
+  positions on the host; `kv.ring_wraps` — calls = requests that ended
+  with more rows than a ring holds, bytes = blocks the ring saved them
+  in the window group.  Behind a share of the experts
+  `serve.moe.experts_touched` counts among the experts held and
+  `serve.moe.assignments` is not emitted (only the program knows how
+  many of a call's assignments it held).
   Paged attention (the GPT family): `serve.paged.rows_walked`
   — calls = slots decoded, bytes = pool rows their attention reads (a
   slot's live blocks where the paged kernel runs, the table's whole

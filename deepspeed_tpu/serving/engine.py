@@ -4,7 +4,7 @@ One `ServeEngine` owns: a `PagedKVCache` (block pool + free list), a
 `Scheduler` (admission + slots), and the jitted {prefill, decode}
 program pair from `ServeProgramBuilder`.  It serves any model that
 hands over a layer spec (`model.layer_spec()`, models/layer_spec.py):
-the GPT family, EvaByte and DeepSeek-V2 today.  `step()` is the whole serving
+the GPT family, EvaByte, DeepSeek-V2 and Command A+ today.  `step()` is the whole serving
 loop body — admit, prefill one chunk round, decode one token for every
 running slot — and everything else (the bench's Poisson arrival thread,
 `generate()`'s synchronous loop, a `ServeWorker` daemon) just drives
@@ -131,6 +131,27 @@ bytes = experts with at least one active slot's token, counted in the
 program and read back with the step's tokens, one step after the
 launch).
 
+Grouped rows over two groups of layers (a layer spec with "grouped"
+attention some of whose layers have a window): the full layers' rows are
+handed out at admission as the paged ones are, and `num_blocks` is
+theirs; the sliding layers' rows live in a group of their own, a ring of
+`window + prefill_chunk` rows a request (whole blocks), which the engine
+sizes for `max_batch` requests so that it never runs dry — no option —
+and takes from as positions are first written (`kv.extend`, before a
+prefill chunk and before a decode step that enters a block).  Where the
+ring would be no shorter than `max_seq_len` the cache is one group and
+the window is a mask.  For such a model the engine refuses, by name,
+`prefix_cache=True`, sessions, `draft_len > 0`, quantized weights,
+int8/int4 rows and a mesh of more than one device.  Counters, from
+positions on the host: `serve.window.rows_read` (calls = queries
+decoded, bytes = rows one of them attends in ONE sliding layer:
+min(cached, window)), `serve.attn.rows_read` (the same summed over all
+the layers), `kv.ring_wraps` (calls = requests that ended with more rows
+than a ring, bytes = the blocks the ring saved each in the window
+group); behind a share of the experts `serve.moe.experts_touched`
+counts among those held, and `serve.moe.assignments` is not emitted
+(only the program knows how many of a call's assignments it held).
+
 What a paged step reads: `serve.paged.rows_walked` (calls = slots
 decoded, bytes = the pool rows attention reads for them: a slot's live
 blocks where the paged kernel runs, the table's whole width where the
@@ -153,6 +174,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import threading
+from functools import partial
 import time
 from typing import Any, List, Optional, Sequence, Tuple
 
@@ -233,6 +255,13 @@ class ServeConfig:
     @property
     def quant_mode(self) -> str:
         return self.quantized_weights if self.quantized_weights else "none"
+
+
+def ring_blocks_for(window: int, prefill_chunk: int, block_size: int) -> int:
+    """Blocks of a sliding layer's ring: the window and one prefill
+    chunk — the chunk's rows are written before its oldest query has
+    attended the window behind it — in whole blocks."""
+    return -(-(window + prefill_chunk) // block_size)
 
 
 @dataclasses.dataclass
@@ -347,6 +376,31 @@ class ServeEngine:
 
             mesh_info = peek_mesh()
         self.mesh_info = mesh_info
+        ring_blocks, ring_layers = 0, ()
+        # grouped rows: the rows ONE sliding layer reads at most a query
+        self._window = max(spec.layer_windows, default=0)
+        if spec.attention == "grouped":
+            if c.prefix_cache:
+                raise NotImplementedError(
+                    "prefix_cache=True over grouped rows with sliding "
+                    "layers: a shared prefix's rows in a ring are "
+                    "overwritten as its first holder goes on, so only the "
+                    "full layers' blocks could be shared; pass "
+                    "prefix_cache=False")
+            if mesh_info is not None and mesh_info.size > 1:
+                raise NotImplementedError(
+                    f"a mesh of {mesh_info.size} devices over grouped rows "
+                    f"and a share of the experts: the all-to-all between "
+                    f"the chips that share a layer is not built; serve "
+                    f"one chip's share on one device")
+            # the sliding layers' ring: the window and one prefill chunk,
+            # whole blocks; one group where that is no less than the table
+            ring = ring_blocks_for(self._window, c.prefill_chunk,
+                                   c.block_size)
+            if self._window and ring < table_width:
+                ring_blocks = ring
+                ring_layers = [i for i in range(cfg.num_layers)
+                               if spec.window_of(i)]
         if spec.attention == "latent":
             if c.prefix_cache:
                 raise NotImplementedError(
@@ -366,6 +420,9 @@ class ServeEngine:
         self._routed_layers = (cfg.num_layers - spec.dense_layers
                                if spec.ffn == "routed_experts" else 0)
         self._top_k = spec.top_k
+        self._held_share = spec.held is not None
+        self._sliding_layers = sum(
+            1 for i in range(cfg.num_layers) if spec.window_of(i))
         kv_dtype = cfg.param_dtype if c.kv_dtype is None else c.kv_dtype
         kv_mode = resolve_kv_dtype(kv_dtype)[0]
         schedule = ServeSchedule(
@@ -373,7 +430,7 @@ class ServeEngine:
             block_size=c.block_size, num_blocks=c.num_blocks,
             table_width=table_width, quantized=c.quant_mode,
             kv_dtype=kv_mode, draft_len=int(c.draft_len),
-            window_blocks=window_blocks)
+            window_blocks=window_blocks, ring_blocks=ring_blocks)
         if programs is None:
             # the builder refuses what a family's programs cannot do
             # before the pool is laid out
@@ -389,7 +446,8 @@ class ServeEngine:
         prefix_salt = (f"{cfg.num_layers}|{cfg.num_heads}|{cfg.head_dim}|"
                        f"{cfg.vocab_size}|{cfg.max_seq_len}|{c.quant_mode}")
         self.kv = PagedKVCache(
-            num_layers=cfg.num_layers, num_heads=cfg.num_heads,
+            num_layers=cfg.num_layers,
+            num_heads=spec.kv_heads or cfg.num_heads,
             head_dim=cfg.head_dim, num_blocks=c.num_blocks,
             block_size=c.block_size, table_width=table_width,
             dtype=kv_dtype, mesh_info=mesh_info,
@@ -397,7 +455,9 @@ class ServeEngine:
             min_match_blocks=c.prefix_min_match_blocks,
             prefix_salt=prefix_salt,
             window_tokens=window_blocks * c.block_size,
-            latent_width=spec.latent_width)
+            latent_width=spec.latent_width,
+            ring_tokens=ring_blocks * c.block_size,
+            ring_layers=ring_layers, max_requests=c.max_batch)
         self.scheduler = Scheduler(self.kv, c.max_batch,
                                    admission=c.admission, clock=clock,
                                    draft_len=int(c.draft_len))
@@ -413,17 +473,19 @@ class ServeEngine:
         # registry picks the kernel for the decode program's shapes,
         # else the table's whole width
         from ..kernels import registry
-        from .layers import eva_info, paged_info
+        from .layers import eva_info, grouped_info, paged_info
 
         pool = jax.tree_util.tree_leaves(self.kv.caches[0][0])[0]
         q_len = int(c.draft_len) + 1
-        info = (paged_info(cfg, schedule, q_len, pool.dtype)
-                if spec.attention == "paged"
-                else eva_info(spec, cfg, schedule, q_len, pool.dtype))
-        # latent rows have no kernel: the table's rows are gathered
-        self._walks_live_blocks = spec.attention != "latent" and \
-            registry.resolve_impl(
-                f"{spec.attention}_attention", info=info) == "pallas"
+        op = "eva_attention" if spec.attention == "eva" \
+            else "paged_attention"
+        info = {"paged": paged_info, "grouped": partial(grouped_info, spec),
+                "eva": partial(eva_info, spec)}.get(spec.attention)
+        # latent rows have no kernel: the table's rows are gathered; so
+        # are grouped rows, which the registry refuses the walk for
+        self._walks_live_blocks = info is not None and \
+            registry.resolve_impl(op, info=info(
+                cfg, schedule, q_len, pool.dtype)) == "pallas"
         self.params = programs["prepare_params"](
             self._place_params(params))
         logger.info(f"serving engine up: {schedule.describe()}; "
@@ -431,7 +493,7 @@ class ServeEngine:
         # packed decode-batch state (one row per slot).  Each slot's
         # last token lies on the device where decode runs ahead of what
         # the host has read, on the host where drafting reads it
-        self._slots = _SlotState(c.max_batch, table_width)
+        self._slots = _SlotState(c.max_batch, table_width + ring_blocks)
         self._serial = int(c.draft_len) > 0
         self._tokens = (np.zeros if self._serial else jnp.zeros)(
             (c.max_batch,), np.int32)
@@ -500,6 +562,12 @@ class ServeEngine:
                 "sessions over latent rows: a pin keeps rows that decode "
                 "wrote, and the next turn's prefill would expand them "
                 "beside rows of its own; not proven, so not offered")
+        if session_id is not None and self.kv.ring_blocks:
+            raise NotImplementedError(
+                "sessions over grouped rows with sliding layers: a pin "
+                "would have to keep the ring's rows as the last turn left "
+                "them and the next turn resume inside it; only whole "
+                "tables of exact rows are pinned today")
         if session_id is not None and self.kv.windowed:
             raise NotImplementedError(
                 "sessions over summarised windows: a pin would have to "
@@ -674,6 +742,11 @@ class ServeEngine:
         """Terminal transition of a request that holds a slot: slot and
         blocks go back now, and no later step decodes for the slot."""
         slot = req.slot
+        if self.kv.ring_blocks:
+            saved = -(-req.cached_len // self.kv.block_size) \
+                - self.kv.ring_blocks
+            if saved > 0:
+                COUNTERS.add("kv.ring_wraps", nbytes=saved)
         self.scheduler.finish(req, state, error=error)
         if slot is not None:
             self._slots.set(slot, active=False, tables=TRASH_BLOCK)
@@ -784,6 +857,8 @@ class ServeEngine:
         tokens[0, :n_valid] = chunk
         if self.kv.windowed:
             self._take_blocks(req, pos0, pos0 + n_valid)
+        elif self.kv.ring_blocks:
+            req.table = self.kv.extend(req.rid, pos0, pos0 + n_valid)
         tok, _logits, caches = self.programs["prefill"](
             self.params, self.kv.caches, jnp.asarray(tokens),
             np.int32(req.prefill_pos), np.int32(n_valid),
@@ -866,6 +941,8 @@ class ServeEngine:
             held = positions[slots].astype(np.int64) + 1
             COUNTERS.add("serve.mla.rows_read", calls=len(lanes),
                          nbytes=int(held.sum()))
+        elif self._window:
+            self._count_grouped_rows(lanes)
         else:
             self._count_rows_walked(lanes, 1)
         COUNTERS.add("serve.decode_ahead", nbytes=int(
@@ -969,11 +1046,35 @@ class ServeEngine:
 
     def _count_assignments(self, n_tokens: int) -> None:
         """Token-expert pairs a call over `n_tokens` tokens computes in
-        its routed layers: every one the router makes."""
-        if self._routed_layers:
+        its routed layers: every one the router makes.  Of a share of
+        the experts only the program knows how many it held: nothing
+        is counted."""
+        if self._routed_layers and not self._held_share:
             COUNTERS.add(
                 "serve.moe.assignments", calls=self._routed_layers,
                 nbytes=n_tokens * self._top_k * self._routed_layers)
+
+    def _count_grouped_rows(self, lanes: List[Request]) -> None:
+        """Before a decode step over grouped rows: the blocks of the
+        window group a slot's position enters, and what its query
+        attends — the window's rows in a sliding layer, every cached row
+        in a full one."""
+        state = self._slots
+        positions = state.host["positions"]
+        bs, ring = self.kv.block_size, self.kv.ring_tokens
+        for req in lanes:
+            p = int(positions[req.slot])
+            if p < ring and p % bs == 0:
+                req.table = self.kv.extend(req.rid, p, p + 1)
+                state.set(req.slot, tables=req.table)
+        held = positions[[r.slot for r in lanes]].astype(np.int64) + 1
+        in_window = int(np.minimum(held, self._window).sum())
+        full_layers = self.model.config.num_layers - self._sliding_layers
+        COUNTERS.add("serve.window.rows_read", calls=len(lanes),
+                     nbytes=in_window)
+        COUNTERS.add("serve.attn.rows_read", calls=len(lanes),
+                     nbytes=self._sliding_layers * in_window
+                     + full_layers * int(held.sum()))
 
     def _count_rows_walked(self, running: List[Request],
                            n_queries: int) -> None:

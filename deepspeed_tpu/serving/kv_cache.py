@@ -1,7 +1,9 @@
 """Paged KV cache: fixed-size blocks, a refcounted free-list allocator,
-per-request block tables, a block-level prefix cache, and — for models
+per-request block tables, a block-level prefix cache, — for models
 whose attention keeps exact keys only for an open window — a second kind
-of row under the same allocator.
+of row under the same allocator, and — for models some of whose layers
+attend a sliding window — a second GROUP of layers with rows, free list
+and table run of its own.
 
 The serving problem the static cache in models/generation.py cannot
 solve: a decode batch whose membership changes every step.  A contiguous
@@ -106,6 +108,30 @@ models/deepseek_v2.py).  A layer's entry is then ONE array
 prefix cache, session pins, quantized rows and a mesh are not offered
 for such a cache (the engine refuses them by name).
 
+Two groups of layers (`ring_tokens > 0`, a layer spec with "grouped"
+attention whose `layer_windows` name sliding layers): a layer that
+attends the last `window` positions needs at most `ring = window +
+prefill_chunk` rows a request, a full layer all of them, so one block id
+can no longer name a slab in every layer.  Group `full` (the layers not
+in `ring_layers`) is the cache described above: `num_blocks` blocks, the
+free list, `alloc` at admission for the request's whole life.  Group
+`window` (`ring_layers`) has arrays, a free list and a trash block of
+its own: `max_requests * ring_blocks + 1` blocks, so it never runs dry
+and admission need not ask it; a request's run of it, `ring_blocks`
+table entries BEHIND its `table_width` full entries (one table
+`[full | window]` a request, one `tables` array a decode step), is
+addressed by position modulo the ring — the row of position p is row
+`p % ring` of the run — and `extend` takes a run's blocks from the
+group's free list as positions are first written, never more than
+`ring_blocks`.  A prefill chunk writes its `prefill_chunk` rows before
+its queries attend, and the chunk's oldest query still needs the
+`window - 1` positions before it: hence the ring's margin of one chunk.
+Which position a ring row holds is the programs' arithmetic
+(serving/layers.py), not the allocator's.  `free` returns both groups'
+blocks.  The prefix cache, session pins, quantized rows and a mesh are
+not offered for such a cache (the engine refuses them by name); a model
+with one kind of layer is one group and sees none of this.
+
 Block 0 is the reserved TRASH block: the allocator never hands it out,
 block tables are padded with it, and inactive decode slots write to it —
 so the jitted programs need no branches for "this slot/table entry is
@@ -123,7 +149,8 @@ prefill was skipped), `kv.cow_copies` (bytes = device bytes copied),
 `kv.session_pins` (bytes = blocks pinned) and `kv.prefix_evictions`
 (refcount-0 cached blocks LRU-evicted to serve an allocation).  A
 windowed cache adds `kv.window_closes` (calls; bytes = exact blocks
-returned to the free list) and the engine `kv.summary_rows`.
+returned to the free list) and the engine `kv.summary_rows`; over two
+groups the engine adds `kv.ring_wraps`.
 """
 
 from __future__ import annotations
@@ -235,7 +262,8 @@ class PagedKVCache:
                  dtype=jnp.float32, mesh_info=None,
                  prefix_cache: bool = True, min_match_blocks: int = 1,
                  prefix_salt: str = "", window_tokens: int = 0,
-                 latent_width: int = 0):
+                 latent_width: int = 0, ring_tokens: int = 0,
+                 ring_layers: Sequence[int] = (), max_requests: int = 0):
         if num_blocks < 2:
             raise ValueError(
                 f"num_blocks must be >= 2 (block 0 is the reserved trash "
@@ -279,6 +307,27 @@ class PagedKVCache:
             raise ValueError(
                 "a cache of latent rows is dense, on one device, with no "
                 "prefix cache and no window")
+        # > 0: the layers `ring_layers` are group `window`: rows, free
+        # list and trash block of their own, `ring_blocks` table entries
+        # a request behind its `table_width` full ones
+        self.ring_tokens = int(ring_tokens)
+        self.ring_layers = frozenset(int(i) for i in ring_layers)
+        if self.ring_tokens % self.block_size or \
+                bool(self.ring_tokens) != bool(self.ring_layers) or \
+                (self.ring_tokens and int(max_requests) < 1):
+            raise ValueError(
+                f"a ring of {ring_tokens} rows must be whole blocks of "
+                f"{block_size}, for some layers ({sorted(self.ring_layers)}) "
+                f"and max_requests >= 1 requests ({max_requests})")
+        self.ring_blocks = self.ring_tokens // self.block_size
+        self.ring_pool_blocks = int(max_requests) * self.ring_blocks + 1
+        if self.ring_blocks and (
+                mode != "dense" or prefix_cache or self.windowed
+                or self.latent_width
+                or mesh_info is not None and mesh_info.size > 1):
+            raise ValueError(
+                "a cache of two groups of layers is dense, on one device, "
+                "with no prefix cache and one kind of row")
         # "int8"/"int4" when blocks are stored quantized, else None
         self.quant_wire = mode if mode in KV_QUANT_WIRES else None
         self.dense_dtype = dense_dtype
@@ -297,6 +346,12 @@ class PagedKVCache:
         self._ref: Dict[int, int] = {}
         # windowed owners: rid -> [table, booked blocks, closed windows]
         self._booked: Dict[Any, list] = {}
+        # group `window`: its free list (block 0 its trash block) and
+        # rid -> the request's table [full | window], rewritten in place
+        self._ring_free: List[int] = list(
+            range(self.ring_pool_blocks - 1, 0, -1)) \
+            if self.ring_blocks else []
+        self._ring: Dict[Any, np.ndarray] = {}
         self.evictions = 0
         # -- prefix cache state ---------------------------------------
         self.prefix_enabled = bool(prefix_cache)
@@ -352,6 +407,16 @@ class PagedKVCache:
             shape = (rows, pool_width(1, self.latent_width))
             return [(jnp.zeros(shape, self.dense_dtype),)
                     for _ in range(self.num_layers)]
+        if self.ring_blocks:
+            width = pool_width(self.num_heads, self.head_dim)
+            ring_rows = self.ring_pool_blocks * self.block_size
+
+            def pair(i):
+                shape = (ring_rows if i in self.ring_layers else rows, width)
+                return (jnp.zeros(shape, self.dense_dtype),
+                        jnp.zeros(shape, self.dense_dtype))
+
+            return [pair(i) for i in range(self.num_layers)]
         if self.quant_wire is None:
             shape = (rows, pool_width(self.num_heads, self.head_dim))
 
@@ -529,8 +594,12 @@ class PagedKVCache:
         self._owned[rid] = blocks
         if cow_pair is not None:
             self._cow_copy(*cow_pair)
-        table = np.full((self.table_width,), TRASH_BLOCK, np.int32)
+        table = np.full((self.table_width + self.ring_blocks,),
+                        TRASH_BLOCK, np.int32)
         table[:n_blocks] = blocks
+        if self.ring_blocks:
+            self._ring[rid] = table
+            return table.copy()  # the booked one never leaves: `extend`
         return table
 
     def blocks_of(self, rid) -> List[int]:
@@ -546,6 +615,10 @@ class PagedKVCache:
         holder); natural completion does not."""
         blocks = self._owned.pop(rid, None)
         self._booked.pop(rid, None)
+        ring = self._ring.pop(rid, None)
+        if ring is not None:
+            self._ring_free.extend(
+                int(b) for b in ring[self.table_width:] if b != TRASH_BLOCK)
         if not blocks:
             return 0
         released = 0
@@ -597,7 +670,21 @@ class PagedKVCache:
         that was handed it runs after its caller has returned (on the
         CPU `jnp.asarray` may alias a host array, not copy it): a
         prefill chunk that filled the window then read the table after
-        `close_window` had trashed it."""
+        `close_window` had trashed it.
+
+        Over two groups of layers: take the blocks of group `window`
+        that hold rows `p % ring` of those positions, where the
+        request's run does not have them yet; group `full` was handed
+        out whole at admission."""
+        if self.ring_blocks:
+            table = self._ring[rid]
+            bs, rb = self.block_size, self.ring_blocks
+            last = min(-(-int(stop) // bs), int(start) // bs + rb)
+            for blk in range(int(start) // bs, last):
+                entry = self.table_width + blk % rb
+                if table[entry] == TRASH_BLOCK:
+                    table[entry] = self._ring_free.pop()
+            return table.copy()
         table = self._booked[rid][0]
         bs, wb = self.block_size, self.window_blocks
         for blk in range(int(start) // bs, -(-int(stop) // bs)):
@@ -623,6 +710,19 @@ class PagedKVCache:
         booked[2] += 1
         COUNTERS.add("kv.window_closes", nbytes=len(back))
         return len(back)
+
+    def ring_blocks_of(self, rid) -> List[int]:
+        """The blocks of group `window` the request holds."""
+        table = self._ring.get(rid)
+        if table is None:
+            return []
+        return [int(b) for b in table[self.table_width:]
+                if b != TRASH_BLOCK]
+
+    @property
+    def ring_blocks_in_use(self) -> int:
+        """Blocks of group `window` with a holder."""
+        return self.ring_pool_blocks - 1 - len(self._ring_free)
 
     def exact_blocks_of(self, rid) -> List[int]:
         if rid not in self._booked:
@@ -783,6 +883,12 @@ class PagedKVCache:
             f"({self.window_blocks} blocks) + summary rows 1 per "
             f"{self.block_size} tok "
             f"({self.table_width - self.window_blocks} blocks)")
+        if self.ring_blocks:
+            rows += (f" in {self.num_layers - len(self.ring_layers)} full "
+                     f"layer(s); {len(self.ring_layers)} layer(s) with a "
+                     f"window in a ring of {self.ring_tokens} rows a "
+                     f"request ({self.ring_pool_blocks} blocks of their "
+                     f"own)")
         return (f"PagedKVCache(layers={self.num_layers}, "
                 f"blocks={self.num_blocks} x {self.block_size} rows, {rows}, "
                 f"table_width={self.table_width}, " + (

@@ -11,8 +11,17 @@ window, one summary row a chunk behind it), and rotary positions over
 part of a head with latent attention (the DeepSeek-V2 block: one latent
 row a token for all heads, expanded to keys and values where many
 queries share the expansion, attended as it lies where a query stands
-alone).  The norm, the FFN — a routed-experts FFN among them, behind
-leading dense layers — and the head are free of that choice.
+alone), and positions given layer by layer with grouped attention (the
+Command A+ block: `kv_heads` keys and values a row for `num_heads`
+queries, query head n on K/V head n // (num_heads / kv_heads); sliding
+layers rotate q and k with interleaved pairing and attend a window,
+full layers have no positions and attend every cached row; one norm and
+x + attn(h) + ffn(h), the "parallel" residual, whose routed FFN reads
+the spec: the router's scoring, whether the chosen weights are
+renormalised, how the shared experts combine and which experts this
+chip holds).  The norm,
+the FFN — a routed-experts FFN among them, behind leading dense layers —
+and the head are free of that choice.
 
 The paged pieces mirror models/generation.py `_block_with_cache` op for
 op (fp32 scores, the same einsum strings, NEG_INF masking, probs cast to
@@ -32,6 +41,19 @@ and attention reads the pool as it lies — through the table's live
 blocks on the chip at `q_len` <= 8 (kernels/paged.py: decode and verify;
 kernels/eva.py: decode), by a gather of the table's rows elsewhere and
 in prefill.
+
+Grouped rows over two groups of layers (`s.ring_blocks > 0`,
+serving/kv_cache.py): a full layer's arrays are addressed through the
+table's first `table_width` entries as the paged ones are; a sliding
+layer's, arrays of their own, through the `ring_blocks` entries behind
+them, a ring: position p lies in row `p % ring` of the run.  The position
+a ring row j holds follows from the newest position written: the
+largest p <= newest with p % ring = j; a query at p_q attends row j iff
+that position is >= 0, <= p_q and > p_q - window.  With `ring_blocks`
+0 (the ring would be no shorter than the table) sliding layers lie in
+the one group and mask the window on the whole table.  No kernel: the
+walk of kernels/paged.py takes one query head a K/V head, and the
+registry sends grouped rows to `jax.numpy` (`grouped_info`).
 
 Addressing (`Addr`): a program works out once where this call's K/V land
 and what attention reads, and every layer's block uses it.  Paged: flat
@@ -54,6 +76,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..models import cohere2_moe
 from ..models.deepseek_v2 import (absorb, attend_absorbed, attend_expanded,
                                   expert_ffn, latent_project, rms_norm_plain)
 from ..models.evabyte import (chunk_summaries, matmul32, project_qkv,
@@ -63,7 +86,8 @@ from ..models.layer_spec import LayerSpec
 from ..moe.dropless import experts_touched
 from .kv_cache import pool_rows
 
-BUILT = {("learned", "paged"), ("rope", "eva"), ("rope", "latent")}
+BUILT = {("learned", "paged"), ("rope", "eva"), ("rope", "latent"),
+         ("per_layer", "grouped")}
 
 
 def check_spec(spec: LayerSpec) -> LayerSpec:
@@ -72,6 +96,25 @@ def check_spec(spec: LayerSpec) -> LayerSpec:
         raise NotImplementedError(
             f"serving has no block with {spec.positions!r} positions and "
             f"{spec.attention!r} attention yet; built: {sorted(BUILT)}")
+    # grouped rows and the parallel block are built for each other, in
+    # front of a routed FFN that reads the spec; the sequential block's
+    # routed FFN is models/deepseek_v2.py's one kind
+    parallel = spec.residual == "parallel"
+    if (spec.attention == "grouped") != parallel or (
+            parallel and spec.ffn != "routed_experts"):
+        raise NotImplementedError(
+            f"serving builds the parallel block over grouped attention "
+            f"and a routed_experts FFN, and grouped attention nowhere "
+            f"else; got a {spec.residual!r} residual with "
+            f"{spec.attention!r} attention and a {spec.ffn!r} FFN")
+    if not parallel and (spec.scoring != "softmax" or spec.renormalize
+                         or spec.shared != "sum" or spec.held):
+        raise NotImplementedError(
+            f"the sequential block's routed FFN scores by softmax, uses "
+            f"the weights as they are, sums its shared experts and holds "
+            f"every expert; got scoring {spec.scoring!r}, renormalize "
+            f"{spec.renormalize}, shared {spec.shared!r}, held "
+            f"{spec.held}: the parallel block reads them")
     return spec
 
 
@@ -82,6 +125,11 @@ class Addr(NamedTuple):
     sum_idx: Optional[jax.Array] = None   # eva: [B*n] flat summary rows
     write_blk: Optional[jax.Array] = None  # eva prefill: [T/bs] block ids
     chunk_src: Optional[jax.Array] = None  # eva decode: [B, bs] flat rows
+    ring_idx: Optional[jax.Array] = None   # ring: [B*T] flat rows, group
+    #                                        `window`'s arrays
+    ring_tables: Optional[jax.Array] = None  # ring: [B, ring blocks]
+    ring_newest: Optional[jax.Array] = None  # ring: [B] newest position
+    #                                          the call writes
 
 
 # -- embedding --------------------------------------------------------------
@@ -90,7 +138,7 @@ class Addr(NamedTuple):
 def embed_chunk(spec, params, tokens, abs_pos):
     """tokens [1, C] at positions abs_pos [C] (prefill) or [R, T] at
     [R, T] (verify) -> x.  Rows past the position table clamp."""
-    if spec.positions == "rope":
+    if spec.positions != "learned":
         return params["wte"][tokens].astype(jnp.float32)
     # per-row gather, NOT dynamic_slice_in_dim(wpe, pos, C): when the
     # final chunk's pad rows run past the wpe table, a dynamic slice
@@ -107,7 +155,7 @@ def embed_chunk(spec, params, tokens, abs_pos):
 
 def embed_step(spec, params, tokens, positions):
     """tokens [R] at positions [R] (decode) -> x [R, 1, D]."""
-    if spec.positions == "rope":
+    if spec.positions != "learned":
         return params["wte"][tokens].astype(jnp.float32)[:, None, :]
     return (params["wte"][tokens] +
             params["wpe"][positions])[:, None, :]
@@ -120,6 +168,9 @@ def address_chunk(spec, s, table, pos, abs_pos, n_valid) -> Addr:
     """One request's prefill chunk at positions abs_pos [C] through its
     table [W]."""
     bs, W = s.block_size, s.table_width
+    if spec.attention == "grouped":
+        return _address_grouped(s, table[None, :], abs_pos[None, :],
+                                abs_pos[None, -1])
     if spec.attention != "eva":
         blk_i = abs_pos // bs
         # positions past the table (pad rows of the final chunk)
@@ -153,6 +204,9 @@ def address_step(spec, s, tables, positions, active) -> Addr:
     """One token for every slot at positions [R] through tables [R, W].
     Inactive slots write to the trash block."""
     bs, W = s.block_size, s.table_width
+    if spec.attention == "grouped":
+        return _address_grouped(
+            s, tables, jnp.where(active, positions, -1)[:, None], positions)
     if spec.attention != "eva":
         blk_i = positions // bs
         blk = jnp.take_along_axis(
@@ -176,6 +230,29 @@ def address_step(spec, s, tables, positions, active) -> Addr:
     q_pos = jnp.where(active, positions, -1)[:, None]
     return Addr(write_idx=write_idx, q_pos=q_pos, tables=tables,
                 sum_idx=sum_idx, chunk_src=chunk_src)
+
+
+def _address_grouped(s, tables, pos, newest) -> Addr:
+    """Grouped rows: tables [B, table_width (+ ring_blocks)], the
+    positions [B, T] this call writes and attends from (negative, or past
+    the table: the trash block, and nothing attended) and the newest
+    position it writes [B], which says what a ring's rows hold."""
+    bs, W = s.block_size, s.table_width
+    B, T = pos.shape
+
+    def rows(run, blk_i, ok):
+        blk = jnp.take_along_axis(
+            run, jnp.clip(blk_i, 0, run.shape[1] - 1), axis=1)
+        return jnp.where(ok, blk * bs + pos % bs, 0).reshape(B * T)
+
+    live = pos >= 0
+    write_idx = rows(tables[:, :W], pos // bs, live & (pos // bs < W))
+    if not s.ring_blocks:
+        return Addr(write_idx=write_idx, q_pos=pos, tables=tables)
+    ring = s.ring_blocks * bs
+    return Addr(write_idx=write_idx, q_pos=pos, tables=tables[:, :W],
+                ring_idx=rows(tables[:, W:], pos % ring // bs, live),
+                ring_tables=tables[:, W:], ring_newest=newest)
 
 
 def address_grid(spec, s, tables, abs_pos, active, n_draft) -> Addr:
@@ -345,9 +422,73 @@ def _latent_attend(cfg, p, h, pool, addr, s):
     return matmul32(out, p["o"]), pool
 
 
+def grouped_info(spec, cfg, s, q_len: int, cache_dtype) -> dict:
+    """`paged_info` over rows of `kv_heads` heads: what the registry is
+    asked, and refuses the walk for."""
+    return dict(paged_info(cfg, s, q_len, cache_dtype),
+                kv_heads=spec.kv_heads)
+
+
+def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
+    """q of `num_heads` heads and this call's rows of `kv_heads` keys and
+    values at the cache's dtype, rotated where the layer rotates; the
+    rows written — into the request's ring where the layer has a window
+    and the cache two groups, through the table else; softmax over the
+    rows the layer lets a query see, gathered a block at a time; output
+    projection.  -> float32."""
+    B, T, _ = h.shape
+    KV, Dh = spec.kv_heads, cfg.head_dim
+    window = spec.window_of(layer)
+    q, k, v = cohere2_moe.project_grouped(
+        cfg, p, h, addr.q_pos, spec.rotates(layer), ck.dtype)
+    ringed = window > 0 and addr.ring_idx is not None
+    idx, tables = (addr.ring_idx, addr.ring_tables) if ringed \
+        else (addr.write_idx, addr.tables)
+    ck = _kv_write(ck, idx, k.reshape(B * T, KV, Dh), "dense")
+    cv = _kv_write(cv, idx, v.reshape(B * T, KV, Dh), "dense")
+    lanes = ck.shape[1]
+    held = lambda c: c.reshape(-1, s.block_size, lanes)[tables].reshape(
+        B, -1, lanes)[..., :KV * Dh].reshape(B, -1, KV, Dh)
+    keys, vals = held(ck), held(cv)
+    L = keys.shape[1]
+    at = jnp.arange(L)[None, :]
+    if ringed:      # the position ring row j holds, from the newest written
+        newest = addr.ring_newest[:, None]
+        at = newest - (newest - at) % L                      # [B, L]
+    out = cohere2_moe.attend_grouped(
+        q, keys, vals, _visible(at, addr.q_pos, window))
+    return matmul32(out, p["o"]), ck, cv
+
+
+def _visible(at, q_pos, window: int):
+    """Which rows a query sees: rows holding positions `at` [B | 1, L]
+    (negative: nothing yet) for queries at q_pos [B, T] -> [B, T, L]:
+    causal, and inside the window where the layer has one."""
+    at, q_pos = at[:, None, :], q_pos[:, :, None]
+    mask = (at <= q_pos) & (at >= 0)
+    if window:
+        mask &= at > q_pos - window
+    return mask
+
+
+def _parallel_block(spec, cfg, p, x, kv, addr, s, layer: int):
+    """One norm, attention and the routed FFN the spec describes on the
+    same h, both added to x: -> (x, kv, touched)."""
+    h = _norm(spec, x, p["ln1"])
+    with jax.named_scope("swa_attend" if spec.window_of(layer)
+                         else "full_attend"):
+        attn, *kv = _grouped_attend(spec, cfg, p["attn"], h, *kv, addr, s,
+                                    layer)
+    y, touched = cohere2_moe.expert_ffn(
+        spec, cfg, p["mlp"], h, live=addr.q_pos.reshape(-1) >= 0)
+    return x + attn + y, tuple(kv), touched
+
+
 def _norm(spec, x, p):
     if spec.norm == "layernorm":
         return layer_norm(x, p, spec.eps)
+    if spec.norm == "layernorm_gain":
+        return cohere2_moe.layer_norm_gain(x, p, spec.eps)
     if spec.norm == "rmsnorm":
         return rms_norm_plain(x, p, spec.eps)
     return rms_norm(x, p, spec.eps)
@@ -367,7 +508,10 @@ def block(spec, cfg, p, x, kv, addr, s, layer: int = 0):
     """Pre-norm decoder block number `layer` over x [B, T, D] through
     its entry `kv` of the cache of a program of schedule `s` -> (x, kv,
     touched): `touched` is None, or, behind a routed FFN, how many of
-    its experts the call's live tokens (`addr.q_pos` >= 0) chose."""
+    its experts the call's live tokens (`addr.q_pos` >= 0) chose
+    (behind a share of the experts: of those held)."""
+    if spec.residual == "parallel":
+        return _parallel_block(spec, cfg, p, x, kv, addr, s, layer)
     h = _norm(spec, x, p["ln1"])
     if spec.attention == "paged":
         attn, *kv = _paged_attend(cfg, p["attn"], h, *kv, addr, s)

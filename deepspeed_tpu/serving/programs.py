@@ -53,6 +53,15 @@ Behind a routed-experts FFN the decode program appends to its tokens how
 many experts the step touched.  `verify`, quantized weights and
 quantized rows are not built for it.
 
+A spec with "grouped" attention (rows of `kv_heads` keys and values,
+layers with a window beside full ones) runs the same two programs; where
+the cache has two groups of layers (`ring_blocks > 0`) a request's table
+is `[table_width full entries | ring_blocks entries of the window
+group]` and the sliding layers' blocks address their ring by position
+(serving/layers.py).  Behind a share of the experts the number decode
+appends to its tokens counts the experts touched among those held.
+`verify`, quantized weights and quantized rows are not built for it.
+
 A spec with "eva" attention (exact rows for an open window, summary
 rows behind it) runs the same two programs over a table of
 `[window blocks | summary blocks]`; the block also writes the summary
@@ -121,6 +130,9 @@ class ServeSchedule(NamedTuple):
     #                                the open window's exact blocks, the
     #                                rest summary blocks (one row a block
     #                                of tokens)
+    ring_blocks: int = 0           # > 0: behind the table's `table_width`
+    #                                entries, so many of the window
+    #                                group's: a ring of rows a request
 
     def describe(self) -> str:
         if self.window_blocks:
@@ -132,6 +144,9 @@ class ServeSchedule(NamedTuple):
         else:
             cap = self.table_width * self.block_size
             rows = "exact rows"
+            if self.ring_blocks:
+                rows += (f", layers with a window in a ring of "
+                         f"{self.ring_blocks} blocks a request")
         q = "" if self.quantized == "none" else f", qwZ={self.quantized}"
         kv = "" if self.kv_dtype == "dense" else f", kv={self.kv_dtype}"
         spec = "" if not self.draft_len else \
@@ -334,6 +349,8 @@ class ServeProgramBuilder:
             self._check_eva(schedule)
         if self.spec.attention == "latent":
             self._check_latent(schedule)
+        if self.spec.attention == "grouped":
+            self._check_grouped(schedule)
         self.model = model
         self.schedule = schedule
 
@@ -373,6 +390,35 @@ class ServeProgramBuilder:
                 f"kv_dtype {s.kv_dtype!r} over summarised windows: "
                 f"summary rows are pooled in float32 from the stored "
                 f"rows and have no quantized codec yet")
+
+    def _check_grouped(self, s: ServeSchedule) -> None:
+        """What the programs over grouped rows need of a schedule, and
+        what is not built for them."""
+        window = max(self.spec.layer_windows, default=0)
+        ring = s.ring_blocks * s.block_size
+        if s.ring_blocks and (not window
+                              or ring < window + s.prefill_chunk):
+            raise ValueError(
+                f"a ring of {ring} rows needs layers with a window and "
+                f"must hold it ({window}) and one prefill chunk "
+                f"({s.prefill_chunk}): the chunk's rows are written "
+                f"before its oldest query has attended the window's")
+        if s.draft_len:
+            raise NotImplementedError(
+                "draft_len > 0 over grouped rows: a rejected draft's rows "
+                "would have overwritten the oldest rows of a ring, which "
+                "the rewound query still attends; the verify program is "
+                "not built for them")
+        if s.quantized != "none":
+            raise NotImplementedError(
+                "quantized_weights over grouped rows: the qwZ store is "
+                "written for the GPT parameter tree's matmul leaves and "
+                "is not proven on stacked experts")
+        if s.kv_dtype != "dense":
+            raise NotImplementedError(
+                f"kv_dtype {s.kv_dtype!r} over grouped rows: the row "
+                f"codecs are read by the paged gather, and the grouped "
+                f"gather over a ring is not built for their scales")
 
     @staticmethod
     def _check_latent(s: ServeSchedule) -> None:

@@ -16,7 +16,12 @@ The scheduling loop the engine drives once per `step()`:
    footprint — at most a window of exact blocks plus its summary
    blocks, `kv.blocks_needed` — and admission books it (`kv.reserve`)
    without taking a block: the engine takes blocks as positions are
-   written and gives a window's blocks back when it closes.  The
+   written and gives a window's blocks back when it closes.  Over two
+   groups of layers (`kv.ring_blocks`: sliding layers in a ring of
+   their own) admission books the full layers' footprint as ever —
+   `num_blocks` is theirs — and never asks the window group, which holds
+   a ring for every slot; the table it gets is `[full | ring]` entries
+   wide.  The
    `draft_len` tail matters under speculative decoding: a verify step
    writes up to `draft_len` candidate K/V rows PAST the committed
    length, and without the reservation those rows would spill into the

@@ -1,0 +1,753 @@
+"""Command A+ through the serving engine: sliding layers with rotary
+positions beside full layers without, grouped K/V rows, two groups of
+layers with a ring, the parallel block, sigmoid routing and a share of
+the experts — against the plain reference
+(`benchmarks/reference/cohere2_moe.py`) on seeded weights at toy widths:
+8 layers (two periods), hidden 64, 8 query heads on 2 K/V heads of 16,
+window 32, 8 experts top 4 of width 32, 2 shared, vocabulary 128.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmarks.reference import cohere2_moe as ref
+from deepspeed_tpu.kernels import registry
+from deepspeed_tpu.models import Cohere2Moe, Cohere2MoeConfig, LayerSpec
+from deepspeed_tpu.models import cohere2_moe as c2
+from deepspeed_tpu.moe import dropless
+from deepspeed_tpu.monitor.counters import COUNTERS
+from deepspeed_tpu.serving import (PagedKVCache, ServeConfig, ServeEngine,
+                                   ServeProgramBuilder, ServeSchedule)
+from deepspeed_tpu.serving import layers as serving_layers
+from deepspeed_tpu.serving.kv_cache import TRASH_BLOCK, pool_width
+
+VOCAB, LAYERS, HEADS, KV, DH, WINDOW, EXPERTS, TOPK, SHARED = \
+    128, 8, 8, 2, 16, 32, 8, 4, 2
+BS, CHUNK = 8, 16
+RING = WINDOW + CHUNK
+
+
+def _config(**kw):
+    base = dict(vocab_size=VOCAB, max_seq_len=256, num_layers=LAYERS,
+                num_heads=HEADS, kv_heads=KV, head_dim=DH, d_model=64,
+                d_expert=32, num_experts=EXPERTS, top_k=TOPK,
+                num_shared=SHARED, window=WINDOW, init_std=0.2)
+    base.update(kw)
+    return Cohere2MoeConfig(**base)
+
+
+def _kw(cfg):
+    return dict(heads=cfg.num_heads, kv_heads=cfg.kv_heads, top_k=cfg.top_k,
+                shared=cfg.num_shared, first_expert=cfg.first_expert,
+                windows=tuple(cfg.window_of(i)
+                              for i in range(cfg.num_layers)),
+                eps=cfg.layer_norm_eps, theta=cfg.rope_theta)
+
+
+def _serve(**kw):
+    base = dict(block_size=BS, num_blocks=120, max_batch=3,
+                prefill_chunk=CHUNK, max_seq_len=256, prefix_cache=False)
+    base.update(kw)
+    return ServeConfig(**base)
+
+
+def _model(dtype=jnp.float32, **kw):
+    model = Cohere2Moe(_config(param_dtype=dtype, **kw))
+    return model, jax.jit(model.init)(jax.random.PRNGKey(0))
+
+
+def _prompt(n, seed=0):
+    return np.random.RandomState(seed).randint(0, VOCAB, (n,)).tolist()
+
+
+# float32: the largest difference.  bf16 (inputs of every product rounded
+# to 8 bits of mantissa, through eight layers of width 64, on logits of
+# standard deviation 1.5): the mean difference — where rounding moves a
+# token's fourth and fifth expert across each other single logits move by
+# several tenths (0.05-0.09 a request through the engine, whose cache
+# rows are rounded too)
+TOL = {"float32": 3e-4, "bfloat16": 0.12}
+
+
+def _differ(got, want, dtype):
+    d = np.abs(np.asarray(got, np.float32) - want)
+    return d.max() if dtype == "float32" else d.mean()
+
+
+# -- the uncached forward against the reference -------------------------------
+
+
+@pytest.mark.parametrize("held", [None, (2, 4)])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_forward_matches_the_plain_reference(dtype, held):
+    share = {} if held is None else dict(first_expert=held[0],
+                                         experts_held=held[1])
+    model, params = _model(jnp.dtype(dtype), **share)
+    assert params["blocks"][0]["mlp"]["experts"]["gate"].shape[0] == \
+        (EXPERTS if held is None else held[1])
+    tokens = jnp.asarray(
+        np.random.RandomState(1).randint(0, VOCAB, (2, 5 * WINDOW)))
+    want = np.asarray(ref.logits(params, tokens, **_kw(model.config)))
+    got = np.asarray(jax.jit(model.apply)(params, tokens))
+    assert want.std() > 1.0
+    assert _differ(got, want, dtype) < TOL[dtype]
+
+
+def test_reference_is_independent_of_the_model_under_test():
+    import inspect
+
+    src = inspect.getsource(ref)
+    assert "deepspeed_tpu" not in src.split('"""', 2)[2]
+    assert 'HIGHEST = "highest"' in src
+
+
+def test_reference_blocks_change_nothing(monkeypatch):
+    """Queries, tokens and vocabulary in blocks of any size: the same
+    logits to float32 rounding."""
+    model, params = _model()
+    tokens = jnp.asarray([_prompt(48, 3)])
+    want = np.asarray(ref.logits(params, tokens, **_kw(model.config)))
+    for name, size in (("QUERY_BLOCK", 4), ("TOKEN_BLOCK", 6),
+                       ("HEAD_BLOCK", 32)):
+        monkeypatch.setattr(ref, name, size)
+    jax.clear_caches()
+    got = np.asarray(ref.logits(params, tokens, **_kw(model.config)))
+    jax.clear_caches()
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+# -- positions, rows, routing: by hand ------------------------------------------
+
+
+def test_interleaved_rope_by_hand():
+    """Dims 2i and 2i + 1 turn together by p theta^(-2i/dh): head size 4
+    at position 3, theta 100 -> angles 3 and 0.3."""
+    x = jnp.asarray([1.0, 2.0, 3.0, 4.0]).reshape(1, 1, 1, 4)
+    got = np.asarray(c2.rope_interleaved(x, jnp.asarray([[3]]), 100.0))[0, 0, 0]
+    a, b = 3.0, 3.0 * 100.0 ** -0.5
+    want = [np.cos(a) - 2 * np.sin(a), 2 * np.cos(a) + np.sin(a),
+            3 * np.cos(b) - 4 * np.sin(b), 4 * np.cos(b) + 3 * np.sin(b)]
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    # the half-split pairing (EvaByte's, DeepSeek's) is another rotation
+    from deepspeed_tpu.models.evabyte import rope
+
+    assert np.abs(np.asarray(rope(x, jnp.asarray([[3]]), 100.0))[0, 0, 0]
+                  - want).max() > 0.5
+    # and the reference's own
+    np.testing.assert_allclose(
+        np.asarray(ref._rope_gptj(x[0], jnp.asarray([3]), 100.0))[0, 0],
+        want, rtol=1e-6)
+
+
+def test_grouped_rows_and_which_head_reads_which():
+    assert pool_width(2, 16) == 128 and pool_width(8, 128) == 1024
+    # 4 query heads on 2 K/V heads: K/V head 0 has the key, K/V head 1
+    # only zeros, so query heads 0, 1 see the value and 2, 3 do not
+    q = jnp.ones((1, 1, 4, 4))
+    k = jnp.zeros((1, 2, 2, 4)).at[0, 1, 0].set(10.0)
+    v = jnp.zeros((1, 2, 2, 4)).at[0, 1, 0].set(1.0).at[0, 1, 1].set(-1.0)
+    out = np.asarray(c2.attend_grouped(
+        q, k, v, jnp.ones((1, 1, 2), bool))).reshape(4, 4)
+    np.testing.assert_allclose(out[:2], 1.0, atol=1e-6)   # n // 2 == 0
+    np.testing.assert_allclose(out[2:], -0.5, atol=1e-6)  # n // 2 == 1
+
+
+def test_attention_a_kv_head_at_a_time_is_the_same(monkeypatch):
+    key = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(key[0], (2, 6, HEADS, DH))
+    k = jax.random.normal(key[1], (2, 20, KV, DH))
+    v = jax.random.normal(key[2], (2, 20, KV, DH))
+    mask = jnp.arange(20)[None, None, :] <= jnp.arange(6)[None, :, None] + 14
+    mask = jnp.broadcast_to(mask, (2, 6, 20))
+    once = np.asarray(c2.attend_grouped(q, k, v, mask))
+    monkeypatch.setattr(c2, "SCORE_BYTES", 0)
+    np.testing.assert_allclose(
+        np.asarray(c2.attend_grouped(q, k, v, mask)), once, atol=1e-6)
+
+
+def test_sigmoid_top_k_renormalised_by_hand():
+    # h = e_0, so the router's logits are its first row
+    logits = np.asarray([2.0, -1.0, 0.5, 3.0, 0.0, -2.0])
+    router = jnp.zeros((4, 6)).at[0].set(logits)
+    h = jnp.asarray([[1.0, 0.0, 0.0, 0.0]])
+    s = 1 / (1 + np.exp(-logits))
+    w, idx = dropless.route(h, router, 3, scoring="sigmoid",
+                            renormalize=True)
+    assert idx.tolist() == [[3, 0, 2]]
+    np.testing.assert_allclose(np.asarray(w)[0],
+                               s[[3, 0, 2]] / s[[3, 0, 2]].sum(), rtol=1e-6)
+    raw, _ = dropless.route(h, router, 3, scoring="sigmoid")
+    np.testing.assert_allclose(np.asarray(raw)[0], s[[3, 0, 2]], rtol=1e-6)
+    # the softmax router of the other family is untouched by the option
+    soft, idx = dropless.route(h, router, 3)
+    e = np.exp(logits - logits.max())
+    np.testing.assert_allclose(np.asarray(soft)[0], (e / e.sum())[[3, 0, 2]],
+                               rtol=1e-6)
+
+
+def test_ring_position_arithmetic():
+    """The position ring row j holds: the largest p <= newest with
+    p % ring = j — at newest = ring - 1 (no wrap yet), ring (row 0
+    rewritten) and ring + chunk."""
+    ring = RING
+
+    def held(newest):
+        j = jnp.arange(ring)
+        return np.asarray(newest - (newest - j) % ring)
+
+    assert held(ring - 1).tolist() == list(range(ring))
+    assert held(ring).tolist() == [ring] + list(range(1, ring))
+    wrapped = held(ring + CHUNK)
+    assert wrapped[:CHUNK + 1].tolist() == list(range(ring, ring + CHUNK + 1))
+    assert wrapped[CHUNK + 1:].tolist() == list(range(CHUNK + 1, ring))
+    # before the ring is full the rows past the newest hold nothing
+    assert (held(5)[6:] < 0).all() and held(5)[:6].tolist() == list(range(6))
+
+
+# -- a share of the experts ------------------------------------------------------
+
+
+def _experts(key, e, d=64, f=32):
+    k = jax.random.split(key, 3)
+    return {"gate": jax.random.normal(k[0], (e, d, f)) * 0.2,
+            "up": jax.random.normal(k[1], (e, d, f)) * 0.2,
+            "down": jax.random.normal(k[2], (e, f, d)) * 0.2}
+
+
+def _share(experts, first, count):
+    return {n: w[first:first + count] for n, w in experts.items()}
+
+
+SCENES = {
+    # (tokens, the held share, the router's first row)
+    "a_held_subset": (12, (2, 4), None),
+    # the router never sends anything to expert 3: held and empty
+    "an_empty_held_expert": (12, (2, 4), {3: -9.0}),
+    # every token's choices lie among experts 0..3: nothing held of them
+    "every_assignment_elsewhere": (12, (4, 4),
+                                   {0: 9.0, 1: 9.0, 2: 9.0, 3: 9.0}),
+    "batch_1": (1, (0, 2), None),
+}
+
+
+@pytest.mark.parametrize("scene", list(SCENES))
+def test_masked_and_grouped_ways_agree_on_a_share(scene):
+    n, (first, count), push = SCENES[scene]
+    x = jax.random.normal(jax.random.PRNGKey(7), (n, 64))
+    router = jax.random.normal(jax.random.PRNGKey(8), (64, EXPERTS)) * 0.1
+    if push:
+        x = x.at[:, 0].set(30.0)
+        router = router.at[0].set(0.0)
+        for e, bias in push.items():
+            router = router.at[0, e].set(bias)
+    weights, idx = dropless.route(x, router, TOPK, scoring="sigmoid",
+                                  renormalize=True)
+    np.testing.assert_allclose(np.asarray(weights.sum(-1)), 1.0, rtol=1e-6)
+    experts = _experts(jax.random.PRNGKey(9), EXPERTS)
+    w, local, held = dropless.held_assignments(weights, idx, first, count)
+    if scene == "every_assignment_elsewhere":
+        assert not np.asarray(held).any()
+    if scene == "an_empty_held_expert":
+        assert 3 not in np.asarray(idx)
+    mine = _share(experts, first, count)
+    masked = np.asarray(jax.jit(dropless.experts_masked)(x, mine, w, local))
+    grouped = np.asarray(jax.jit(dropless.experts_grouped)(
+        x, mine, w, local, held))
+    # every expert on every token, the weights of those elsewhere dropped
+    dense = jnp.zeros((n, EXPERTS)).at[jnp.arange(n)[:, None], idx].add(
+        weights)[:, first:first + count]
+    want = np.asarray(dropless.experts_masked(
+        x, mine, dense, jnp.broadcast_to(jnp.arange(count), (n, count))))
+    tol = 2e-6 * max(np.abs(want).max(), 1.0)
+    np.testing.assert_allclose(masked, want, atol=tol)
+    np.testing.assert_allclose(grouped, want, atol=tol)
+    if scene == "every_assignment_elsewhere":
+        assert not masked.any() and not grouped.any()
+
+
+def test_the_way_is_chosen_from_the_assignments_held():
+    picked = []
+    real = dropless.experts_masked, dropless.experts_grouped
+    try:
+        dropless.experts_masked = lambda *a: picked.append("masked")
+        dropless.experts_grouped = lambda *a: picked.append("grouped")
+        ex = {"gate": jnp.zeros((16, 8, 4))}     # 16 of 128 held, top 8
+        for tokens in (1, 15, 16, 128, 129, 512):
+            dropless.routed_experts(None, ex, None,
+                                    jnp.zeros((tokens, 8), jnp.int32),
+                                    total=128)
+    finally:
+        dropless.experts_masked, dropless.experts_grouped = real
+    # 16 tokens' 128 assignments leave one for each of the 16 held, as 16
+    # tokens' would cover all 128: counted on the 16 held alone (T k >=
+    # 16) two tokens would already stream every expert
+    assert picked == ["grouped", "grouped", "masked", "masked", "grouped",
+                      "grouped"]
+
+
+def test_the_shares_add_up():
+    """The routed parts that the E / held shares give, with the shared
+    experts counted once, are what the uncut reference gives for the
+    layer's FFN; and so through the model's own routed FFN."""
+    model, params = _model()
+    cfg, p = model.config, params["blocks"][1]["mlp"]
+    h = jax.random.normal(jax.random.PRNGKey(4), (24, 64))
+    whole = np.asarray(ref._ffn(h, p, top_k=TOPK, shared=SHARED,
+                                first_expert=0))
+    shared = np.asarray(ref._ffn(
+        h, dict(p, experts=_share(p["experts"], 0, 0)), top_k=TOPK,
+        shared=SHARED, first_expert=0))
+    count = 2
+    parts, mine = [], []
+    for first in range(0, EXPERTS, count):
+        share = dict(p, experts=_share(p["experts"], first, count))
+        parts.append(np.asarray(ref._ffn(
+            h, share, top_k=TOPK, shared=SHARED, first_expert=first))
+            - shared)
+        held = Cohere2MoeConfig(**dict(
+            vars(cfg), experts_held=count, first_expert=first))
+        mine.append(np.asarray(c2.expert_ffn(
+            Cohere2Moe(held).layer_spec(), held, share, h)[0]) - shared)
+    assert np.abs(whole - shared).std() > 0.05
+    np.testing.assert_allclose(sum(parts) + shared, whole, atol=2e-5)
+    np.testing.assert_allclose(sum(mine) + shared, whole, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(c2.expert_ffn(model.layer_spec(), cfg, p, h)[0]), whole,
+        atol=2e-5)
+
+
+def test_shared_experts_are_averaged_not_summed():
+    model, params = _model()
+    p = params["blocks"][0]["mlp"]
+    h = jax.random.normal(jax.random.PRNGKey(5), (6, 64))
+    none = dict(p, experts=_share(p["experts"], 0, 0))
+    mean = np.asarray(ref._ffn(h, none, top_k=TOPK, shared=SHARED,
+                               first_expert=0))
+    width = p["shared"]["gate"].shape[1] // SHARED
+    each = [np.asarray(ref._gated(
+        h, p["shared"]["gate"][:, i * width:(i + 1) * width],
+        p["shared"]["up"][:, i * width:(i + 1) * width],
+        p["shared"]["down"][i * width:(i + 1) * width]))
+        for i in range(SHARED)]
+    np.testing.assert_allclose(mean, sum(each) / SHARED, atol=1e-5)
+
+
+def test_the_other_familys_routed_output_is_unchanged_bitwise():
+    """`route` and `routed_experts` as DeepSeek-V2 calls them — no
+    option given — against the expressions they were before this model
+    came: softmax, top k as it is, both ways over all the experts."""
+    x = jax.random.normal(jax.random.PRNGKey(7), (12, 64))
+    router = jax.random.normal(jax.random.PRNGKey(8), (64, EXPERTS))
+    experts = _experts(jax.random.PRNGKey(9), EXPERTS)
+    scores = jnp.dot(x, router, precision=jax.lax.Precision.HIGHEST)
+    w0, i0 = jax.lax.top_k(jax.nn.softmax(scores, axis=-1), 3)
+    w, idx = dropless.route(x, router, 3)
+    assert np.array_equal(w, w0) and np.array_equal(idx, i0)
+    for fn in (dropless.experts_masked, dropless.experts_grouped):
+        plain = jax.jit(fn).lower(x, experts, w, idx).as_text()
+        via = jax.jit(lambda *a: dropless.routed_experts(*a)).lower(
+            x, experts, w, idx).as_text()
+        if fn is dropless.experts_masked:       # 12 x 3 >= 8: the masked way
+            assert plain.split("{", 1)[1] == via.split("{", 1)[1]
+    from deepspeed_tpu.models import DeepSeekV2, DeepSeekV2Config
+
+    m = DeepSeekV2(DeepSeekV2Config(
+        vocab_size=64, max_seq_len=32, num_layers=2, num_heads=2, d_model=32,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
+        v_head_dim=8, d_ff=32, num_experts=4, top_k=2, d_expert=16,
+        yarn=None))
+    text = jax.jit(m.apply).lower(
+        jax.eval_shape(m.init, jax.random.PRNGKey(0)),
+        jax.ShapeDtypeStruct((1, 8), jnp.int32)).as_text()
+    assert "logistic" not in text.replace("silu", "")  # no sigmoid router
+
+
+# -- through the engine --------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_prefill_then_decode_matches_the_reference_forward(dtype):
+    """Five requests through three slots (a slot is reused), from under
+    one block to 5 x the window; one prompt ends a row short of the ring,
+    so that its decode steps wrap it, two wrap it in prefill — the ring
+    (56 rows) is no multiple of the chunk (24), so a chunk straddles the
+    wrap: at every generated position the logits the engine drew from
+    are the reference's full forward's."""
+    from test_evabyte import Probe
+
+    model, params = _model(jnp.dtype(dtype), first_expert=2, experts_held=4)
+    probe = Probe(model, params, _serve(prefill_chunk=24))
+    eng = probe.engine
+    ring = eng.kv.ring_tokens
+    assert ring == WINDOW + 24 and ring % 24
+    lengths = [5, 17, ring - 1, 5 * WINDOW, 3 * WINDOW + 5]
+    reqs = [eng.submit(_prompt(n, i), 10) for i, n in enumerate(lengths)]
+    probe.run()
+    assert [r.state for r in reqs] == ["finished"] * 5
+    assert eng.kv.blocks_in_use == 0 and eng.kv.ring_blocks_in_use == 0
+    for r in reqs:
+        lg = np.asarray(ref.logits(
+            params, jnp.asarray([r.prompt + r.out]), **_kw(model.config)))[0]
+        first = len(r.prompt) - 1
+        want = lg[first:first + len(r.out)]
+        got = np.stack(probe.logits[r.rid])[:len(r.out)]
+        assert _differ(got, want, dtype) < TOL[dtype], r.rid
+        chosen = want[np.arange(len(r.out)), r.out]
+        assert (want.max(-1) - chosen).mean() <= (0 if dtype == "float32"
+                                                  else TOL[dtype])
+
+
+def _drive(model, params, sched, kv, prompt, n_decode):
+    """One request by hand through a builder's programs over `kv`:
+    prefill chunk by chunk, then decode steps -> every logits row."""
+    progs = ServeProgramBuilder(model, sched).build()
+    step = jax.jit(ServeProgramBuilder(model, sched).step_logits)
+    C = sched.prefill_chunk
+    n_blocks = -(-(len(prompt) + n_decode) // sched.block_size)
+    table = kv.alloc("r", n_blocks)
+    rows, caches = [], kv.caches
+    zero = (np.float32(0), np.int32(0), np.uint32(0))
+    for pos in range(0, len(prompt), C):
+        chunk = prompt[pos:pos + C]
+        toks = np.zeros((1, C), np.int32)
+        toks[0, :len(chunk)] = chunk
+        if kv.ring_blocks:
+            table = kv.extend("r", pos, pos + len(chunk))
+        tok, lg, caches = progs["prefill"](
+            params, caches, jnp.asarray(toks), np.int32(pos),
+            np.int32(len(chunk)), jnp.asarray(table), *zero)
+    rows.append(np.asarray(lg))
+    tok = int(tok)
+    for p in range(len(prompt), len(prompt) + n_decode):
+        if kv.ring_blocks:
+            table = kv.extend("r", p, p + 1)
+        lg, caches, _ = step(params, caches, jnp.asarray([tok], jnp.int32),
+                             jnp.asarray([p], jnp.int32),
+                             jnp.asarray([True]), jnp.asarray(table[None]))
+        rows.append(np.asarray(lg[0]))
+        tok = int(np.argmax(lg[0]))
+    return np.stack(rows)
+
+
+def test_one_group_with_a_mask_equals_two_groups_with_a_ring():
+    """The same request through the same programs: every layer in the
+    one group, the window a mask on the whole table, against the sliding
+    layers in a ring of their own.  The softmax sees the same keys in
+    another order of rows, so equal to float32 rounding through eight
+    layers (a few parts in a million of the largest logit)."""
+    model, params = _model()
+    W = 256 // BS
+    base = dict(max_batch=1, prefill_chunk=CHUNK, block_size=BS,
+                num_blocks=40, table_width=W)
+    cache = dict(num_layers=LAYERS, num_heads=KV, head_dim=DH, num_blocks=40,
+                 block_size=BS, table_width=W, prefix_cache=False)
+    prompt = _prompt(3 * WINDOW + 5, 11)
+    one = _drive(model, params, ServeSchedule(**base),
+                 PagedKVCache(**cache), prompt, 2 * CHUNK)
+    sliding = [i for i in range(LAYERS) if model.config.window_of(i)]
+    two = _drive(model, params,
+                 ServeSchedule(ring_blocks=RING // BS, **base),
+                 PagedKVCache(ring_tokens=RING, ring_layers=sliding,
+                              max_requests=1, **cache), prompt, 2 * CHUNK)
+    assert one.std() > 1.0
+    np.testing.assert_allclose(two, one, atol=4e-6 * np.abs(one).max())
+    want = np.asarray(ref.logits(
+        params, jnp.asarray([prompt]), **_kw(model.config)))[0, -1]
+    np.testing.assert_allclose(one[0], want, atol=TOL["float32"])
+
+
+def test_a_ring_without_the_chunks_margin_is_refused():
+    model, _ = _model()
+    sched = ServeSchedule(max_batch=1, prefill_chunk=CHUNK, block_size=BS,
+                          num_blocks=40, table_width=32,
+                          ring_blocks=WINDOW // BS)
+    with pytest.raises(ValueError, match="one prefill chunk"):
+        ServeProgramBuilder(model, sched)
+
+
+def test_a_request_decodes_the_same_alone_and_in_a_batch():
+    model, params = _model()
+    alone = ServeEngine(model, params, _serve()).generate(
+        [_prompt(2 * WINDOW, 3)], 12)[0]
+    eng = ServeEngine(model, params, _serve())
+    outs = eng.generate([_prompt(7, 1), _prompt(2 * WINDOW, 3),
+                         _prompt(RING + 3, 2)], 12)
+    assert outs[1] == alone
+
+
+def test_short_contexts_are_one_group():
+    """Where the ring would be no shorter than the table the cache is
+    one group, the window a mask — and the tokens are the same."""
+    model, params = _model()
+    small = ServeEngine(model, params,
+                        _serve(max_seq_len=RING, num_blocks=40))
+    assert small.kv.ring_blocks == 0 and len(
+        {c[0].shape for c in small.kv.caches}) == 1
+    big = ServeEngine(model, params, _serve())
+    assert big.kv.ring_blocks == RING // BS
+    prompts = [_prompt(WINDOW + 3, 4), _prompt(9, 5)]
+    assert small.generate(prompts, 5) == big.generate(prompts, 5)
+
+
+def test_counters_of_a_decode_step():
+    model, params = _model(first_expert=0, experts_held=4)
+    eng = ServeEngine(model, params, _serve())
+    before = COUNTERS.snapshot()
+    lengths = (8, 2 * WINDOW + 1)
+    eng.generate([_prompt(n, i) for i, n in enumerate(lengths)], 6)
+    d = COUNTERS.delta_since(before)
+    # 2 requests x 5 decode steps; a query at position p attends
+    # min(p + 1, window) rows in each of 6 sliding layers, p + 1 in 2 full
+    held = [n + i + 1 for n in lengths for i in range(5)]
+    in_window = sum(min(h, WINDOW) for h in held)
+    assert d["serve.window.rows_read"] == {"calls": 10, "bytes": in_window}
+    assert d["serve.attn.rows_read"] == {
+        "calls": 10, "bytes": 6 * in_window + 2 * sum(held)}
+    # the long request ends with 2 x 32 + 6 rows: 9 blocks where the
+    # ring holds 6
+    assert d["kv.ring_wraps"] == {"calls": 1, "bytes": 3}
+    steps = d["serve.decode_steps"]["calls"]
+    touched = d["serve.moe.experts_touched"]
+    assert touched["calls"] == steps * LAYERS
+    # among the 4 held of 8: a live token's 4 choices hold 0..4 of them,
+    # and how many of a call's assignments were held only the program
+    # knows: they are not counted
+    assert 0 < touched["bytes"] <= 10 * TOPK * LAYERS
+    assert "serve.moe.assignments" not in d
+    assert "serve.paged.rows_walked" not in d
+
+
+def test_decode_appends_the_held_experts_touched_to_its_tokens():
+    model, params = _model(first_expert=0, experts_held=EXPERTS // 2)
+    eng = ServeEngine(model, params, _serve())
+    R, W = 3, eng.kv.table_width + eng.kv.ring_blocks
+    out, _, (toks, moved) = eng.programs["decode"](
+        eng.params, eng.kv.caches, jnp.zeros((R,), jnp.int32),
+        jnp.zeros((R,), jnp.int32), jnp.asarray([True, False, False]),
+        jnp.zeros((R, W), jnp.int32), jnp.zeros((R,), jnp.float32),
+        jnp.zeros((R,), jnp.int32), jnp.zeros((R,), jnp.uint32))
+    # one live token: each of its held assignments touches one expert
+    assert out.shape == (R + 1,) and 0 < int(out[R]) <= TOPK * LAYERS
+    assert np.array_equal(toks, out[:R]) and moved.tolist() == [1, 0, 0]
+
+
+# -- two groups of layers under the allocator ------------------------------------
+
+
+def test_two_groups_under_the_allocator():
+    kv = PagedKVCache(num_layers=4, num_heads=KV, head_dim=DH, num_blocks=9,
+                      block_size=BS, table_width=6, dtype=jnp.bfloat16,
+                      prefix_cache=False, ring_tokens=24,
+                      ring_layers=[0, 1, 2], max_requests=2)
+    assert [c[0].shape for c in kv.caches] == [(56, 128)] * 3 + [(72, 128)]
+    a = kv.alloc("a", 5)
+    assert a.shape == (9,) and (a[5:] == TRASH_BLOCK).all()
+    assert TRASH_BLOCK not in a[:5] and kv.ring_blocks_in_use == 0
+    a = kv.extend("a", 0, 10)              # positions 0..9: two ring blocks
+    assert (a[6:8] != TRASH_BLOCK).all() and a[8] == TRASH_BLOCK
+    assert np.array_equal(kv.extend("a", 3, 12), a)     # nothing new
+    a = kv.extend("a", 16, 17)
+    assert sorted(kv.ring_blocks_of("a")) == sorted(a[6:].tolist())
+    held = set(kv.ring_blocks_of("a"))
+    for p in range(17, 40):                # round and round: never more
+        assert set(kv.ring_blocks_of("a")) == held
+        assert np.array_equal(kv.extend("a", p, p + 1), a)
+    assert kv.alloc("b", 4) is None        # group full: 8 blocks, 5 held
+    b = kv.extend("b", 0, 24) if kv.alloc("b", 3) is not None else None
+    assert kv.ring_blocks_in_use == 6 and not held & set(b[6:].tolist())
+    assert TRASH_BLOCK not in b[6:]
+    kv.free("a")
+    assert kv.ring_blocks_in_use == 3 and kv.blocks_in_use == 3
+    c = kv.alloc("c", 5)
+    c = kv.extend("c", 40, 48)             # a chunk at ring rows 16..23
+    assert set(kv.ring_blocks_of("c")) <= held and c[8] != TRASH_BLOCK
+    kv.free("b"), kv.free("c")
+    assert kv.ring_blocks_in_use == kv.blocks_in_use == 0
+
+
+def test_two_groups_refuse_what_they_cannot_hold():
+    base = dict(num_layers=2, num_heads=KV, head_dim=DH, num_blocks=9,
+                block_size=BS, table_width=6, dtype=jnp.bfloat16,
+                prefix_cache=False, ring_tokens=24, ring_layers=[0],
+                max_requests=2)
+    for kw, match in (({"dtype": "int8"}, "two groups"),
+                      ({"prefix_cache": True}, "two groups"),
+                      ({"ring_tokens": 20}, "whole blocks"),
+                      ({"ring_layers": []}, "for some layers"),
+                      ({"max_requests": 0}, "max_requests")):
+        with pytest.raises(ValueError, match=match):
+            PagedKVCache(**dict(base, **kw))
+
+
+def test_engine_never_holds_more_than_the_ring_and_frees_both_groups():
+    model, params = _model()
+    eng = ServeEngine(model, params, _serve())
+    most = []
+    reqs = [eng.submit(_prompt(n, i), 8)
+            for i, n in enumerate([RING - 2, 4 * WINDOW, 6])]
+    while eng.has_work():
+        eng.step()
+        most.append(max([len(eng.kv.ring_blocks_of(r.rid)) for r in reqs]))
+    assert max(most) == RING // BS
+    assert eng.kv.ring_blocks_in_use == eng.kv.blocks_in_use == 0
+    first = [r.out for r in reqs]
+    # a second wave through the freed blocks of both groups: the same
+    # tokens, and nothing but inactive slots' rows in the trash blocks
+    again = eng.generate([r.prompt for r in reqs], 8)
+    assert again == first
+
+
+# -- refused, by name ----------------------------------------------------------
+
+
+@pytest.mark.parametrize("serve,match", [
+    (dict(prefix_cache=True), "prefix_cache=True over grouped rows"),
+    (dict(draft_len=2), "draft_len > 0 over grouped rows"),
+    (dict(kv_dtype="int8"), "kv_dtype 'int8' over grouped rows"),
+    (dict(kv_dtype="int4"), "kv_dtype 'int4' over grouped rows"),
+    (dict(quantized_weights="int8"), "quantized_weights over grouped rows"),
+])
+def test_engine_refuses_by_name(serve, match):
+    model, params = _model()
+    with pytest.raises(NotImplementedError, match=match):
+        ServeEngine(model, params, _serve(**serve))
+
+
+def test_engine_refuses_sessions_and_a_mesh_by_name():
+    from deepspeed_tpu.comm import make_mesh
+
+    model, params = _model()
+    eng = ServeEngine(model, params, _serve())
+    with pytest.raises(NotImplementedError,
+                       match="sessions over grouped rows"):
+        eng.submit(_prompt(5), 4, session_id="s")
+    with pytest.raises(NotImplementedError,
+                       match="a mesh of 2 devices over grouped rows"):
+        ServeEngine(model, params, _serve(),
+                    mesh_info=make_mesh(model=2, data=1,
+                                        devices=jax.devices()[:2]))
+
+
+def test_the_registry_sends_grouped_rows_to_jax_numpy():
+    model, params = _model()
+    eng = ServeEngine(model, params, _serve())
+    spec, sched = model.layer_spec(), eng.programs["schedule"]
+    info = serving_layers.grouped_info(spec, model.config, sched, 1,
+                                       jnp.bfloat16)
+    assert info["kv_heads"] == KV and info["num_heads"] == HEADS
+    ok, why = registry.get_kernel("paged_attention").auto_supports(
+        None, info)
+    assert not ok and "grouped rows" in why
+    same = dict(info, kv_heads=HEADS)         # one query head a K/V head
+    assert "grouped rows" not in registry.get_kernel(
+        "paged_attention").auto_supports(None, same)[1]
+    assert not eng._walks_live_blocks
+
+
+# -- the layer spec -------------------------------------------------------------
+
+
+def test_layer_spec_of_the_new_kinds():
+    model, _ = _model(first_expert=2, experts_held=4)
+    spec = model.layer_spec()
+    assert (spec.norm, spec.positions, spec.attention, spec.ffn, spec.head,
+            spec.residual) == ("layernorm_gain", "per_layer", "grouped",
+                               "routed_experts", "tied", "parallel")
+    assert spec.layer_windows == (WINDOW, WINDOW, WINDOW, 0)
+    assert spec.layer_positions == ("rope", "rope", "rope", "none")
+    assert [spec.window_of(i) for i in (0, 3, 6, 7)] == [WINDOW, 0, WINDOW, 0]
+    assert spec.rotates(4) and not spec.rotates(7)
+    assert (spec.kv_heads, spec.top_k, spec.scoring, spec.renormalize,
+            spec.shared, spec.held) == (KV, TOPK, "sigmoid", True,
+                                        "average", (2, 4))
+    assert serving_layers.check_spec(spec) == spec
+
+
+@pytest.mark.parametrize("change,match", [
+    (dict(kv_heads=0), "kv_heads"),
+    (dict(attention="paged"), "kv_heads"),
+    (dict(layer_windows=(4, -1)), "window is 0"),
+    (dict(layer_positions=()), "per_layer positions"),
+    (dict(layer_positions=("rope", "alibi")), "per_layer positions"),
+    (dict(positions="rope"), "per_layer positions"),
+    (dict(residual="post"), "is not one of"),
+    (dict(scoring="tanh"), "is not one of"),
+    (dict(shared="max"), "is not one of"),
+    (dict(norm="layernorm_nobias"), "is not one of"),
+    (dict(first_expert=2, experts_held=0), "a share of the experts"),
+    (dict(ffn="silu_gated", top_k=0), "describe a routed_experts FFN"),
+])
+def test_layer_spec_validate_refuses(change, match):
+    good = Cohere2Moe(_config(experts_held=4)).layer_spec()
+    with pytest.raises(ValueError, match=match):
+        good._replace(**change).validate()
+
+
+@pytest.mark.parametrize("change", [
+    dict(scoring="softmax"), dict(renormalize=False), dict(shared="sum"),
+    dict(scoring="softmax", renormalize=False, shared="sum"),
+    dict(experts_held=2, first_expert=4), dict(norm="rmsnorm"),
+])
+def test_the_parallel_block_computes_what_the_spec_says(change):
+    """Scoring, renormalisation, the shared experts' combine and the
+    share held are the spec's to say: the routed FFN against the sum
+    written out."""
+    model, params = _model()
+    spec = serving_layers.check_spec(
+        model.layer_spec()._replace(**change))
+    p = params["blocks"][2]["mlp"]
+    first, count = spec.held or (0, EXPERTS)
+    e = {k: v[first:first + count] for k, v in p["experts"].items()}
+    h = jax.random.normal(jax.random.PRNGKey(7), (10, 64))
+    got = c2.expert_ffn(spec, model.config, dict(p, experts=e), h)[0]
+    logits = h @ p["router"]
+    s = jax.nn.softmax(logits, -1) if spec.scoring == "softmax" \
+        else jax.nn.sigmoid(logits)
+    w, idx = jax.lax.top_k(s, TOPK)
+    if spec.renormalize:
+        w = w / w.sum(-1, keepdims=True)
+    gated = lambda g, u, d: (jax.nn.silu(h @ g) * (h @ u)) @ d
+    each = jnp.stack([gated(e["gate"][i], e["up"][i], e["down"][i])
+                      for i in range(count)])                # [count, T, D]
+    weight = jnp.zeros((10, EXPERTS)).at[
+        jnp.arange(10)[:, None], idx].set(w)[:, first:first + count]
+    shared = gated(p["shared"]["gate"], p["shared"]["up"],
+                   p["shared"]["down"])
+    want = jnp.einsum("etd,te->td", each, weight) + \
+        (shared / SHARED if spec.shared == "average" else shared)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    if change != dict(norm="rmsnorm"):   # and the field decides something
+        base = c2.expert_ffn(model.layer_spec(), model.config, p, h)[0]
+        assert np.abs(np.asarray(base - got)).max() > 1e-3
+
+
+@pytest.mark.parametrize("change,match", [
+    (dict(residual="sequential"), "parallel block over grouped"),
+    (dict(ffn="silu_gated", top_k=0, scoring="softmax", renormalize=False,
+          shared="sum"), "parallel block over grouped"),
+])
+def test_serving_refuses_blocks_it_has_not_built(change, match):
+    good = Cohere2Moe(_config()).layer_spec()
+    with pytest.raises(NotImplementedError, match=match):
+        serving_layers.check_spec(good._replace(**change))
+    gpt = LayerSpec(norm="layernorm", positions="learned", attention="paged",
+                    ffn="gelu_mlp", head="tied", eps=1e-5)
+    assert serving_layers.check_spec(gpt) == gpt
+    with pytest.raises(NotImplementedError, match=match):
+        serving_layers.check_spec(gpt._replace(residual="parallel"))
+
+
+@pytest.mark.parametrize("change", [
+    dict(scoring="sigmoid"), dict(renormalize=True),
+    dict(shared="average"), dict(experts_held=2),
+])
+def test_the_sequential_blocks_routed_ffn_is_one_kind(change):
+    routed = LayerSpec(norm="rmsnorm", positions="rope", attention="latent",
+                       ffn="routed_experts", head="untied", eps=1e-6,
+                       latent_width=24, top_k=2).validate()
+    assert serving_layers.check_spec(routed) == routed
+    with pytest.raises(NotImplementedError, match="sequential block"):
+        serving_layers.check_spec(routed._replace(**change).validate())

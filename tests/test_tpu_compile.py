@@ -634,6 +634,83 @@ def test_deepseek_cell_programs_compile_inside_one_chip(program, one_chip):
     assert total < 15.75e9 - 1.68e9 - 1.0e9, total
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
+                                                              one_chip):
+    """`command-a-plus-d4.serve.mixedlen.decode` / `.prefill` at the
+    cell's shapes (4 layers at published widths in bf16 with 16 of 128
+    experts and an eighth of the vocabulary, 16 slots, 16,385 blocks of
+    16 rows for the full layer and 16 x 288 + 1 for each sliding one, a
+    table of 1,024 + 288 entries, chunk 512): asked for grouped rows the
+    registry answers `oracle` by name, so `decode` holds no kernel at all
+    and the only custom calls in `prefill` are XLA's own grouped products
+    (`lax.ragged_dot` over the 16 held experts); every pool enters as
+    `[rows, 1024]`, a K and a V a layer; and weights, pools and
+    temporaries fit the chip's 15.75 GB with the room the check's 2.15 GB
+    of reference logits needs."""
+    from deepspeed_tpu.models import Cohere2Moe, Cohere2MoeConfig
+    from deepspeed_tpu.serving import ServeProgramBuilder, ServeSchedule
+    from deepspeed_tpu.serving.layers import grouped_info
+
+    slots, bs, nblocks, layers, chunk = 16, 16, 16385, 4, 512
+    model = Cohere2Moe(Cohere2MoeConfig(
+        vocab_size=32768, max_seq_len=16384, num_layers=layers,
+        experts_held=16, param_dtype=jnp.bfloat16))
+    width, ring = 16384 // bs, (4096 + chunk) // bs
+    sched = ServeSchedule(max_batch=slots, prefill_chunk=chunk,
+                          block_size=bs, num_blocks=nblocks,
+                          table_width=width, ring_blocks=ring)
+    info = grouped_info(model.layer_spec(), model.config, sched, 1,
+                        jnp.bfloat16)
+    supported, why = registry.get_kernel("paged_attention").auto_supports(
+        "default", info)
+    assert not supported and "grouped rows" in why
+    # (with a K/V head a query head the rule would be the walk's own)
+    assert "grouped rows" not in registry.get_kernel(
+        "paged_attention").auto_supports(
+            "default", dict(info, kv_heads=info["num_heads"]))[1]
+    progs = ServeProgramBuilder(model, sched).build()
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda s: on(s.shape, s.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    held = sum(s.size * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    assert abs(held - 9.47e9) < 0.01e9
+    full = on((nblocks * bs, 1024), jnp.bfloat16)
+    window = on(((slots * ring + 1) * bs, 1024), jnp.bfloat16)
+    caches = [(window, window)] * 3 + [(full, full)]
+    pools = sum(2 * c[0].shape[0] * 1024 * 2 for c in caches)
+    assert abs(pools - 1.98e9) < 0.01e9
+    if program == "decode":
+        args = (on((slots,), jnp.int32), on((slots,), jnp.int32),
+                on((slots,), jnp.bool_), on((slots, width + ring), jnp.int32),
+                on((slots,), jnp.float32), on((slots,), jnp.int32),
+                on((slots,), jnp.uint32))
+    else:
+        args = (on((1, chunk), jnp.int32), on((), jnp.int32),
+                on((), jnp.int32), on((width + ring,), jnp.int32),
+                on((), jnp.float32), on((), jnp.int32), on((), jnp.uint32))
+    compiled = progs[program].lower(params, caches, *args).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    if program == "decode":
+        assert not calls
+    else:
+        assert calls and all("ragged" in ln for ln in calls)
+    for rows in (nblocks * bs, (slots * ring + 1) * bs):
+        layouts = {layout for _, layout in _hlo_by_shape(text)[(rows, 1024)]}
+        assert layouts == {"1,0"}  # row-major
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + \
+        m.output_size_in_bytes - m.alias_size_in_bytes
+    assert m.temp_size_in_bytes < 1536 << 20
+    assert total < 15.75e9 - 2.15e9 - 0.5e9, total
+
+
 def test_evabyte_phase_after_the_described_compiles(topo):
     """This file, then tests/test_chip_smoke.py::test_evabyte_phase_toy,
     in one process: the order in which the toy EvaByte run chose bytes
