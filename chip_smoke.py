@@ -444,6 +444,7 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                   eva_heads=(32, 128), eva_window=2048, eva_chunk=16,
                   eva_summary_blocks=64,
                   eva_positions=(0, 100, 2047, 2048, 9000, 12345, 16383, -1),
+                  routed_shape=(32, 64, 2048, 1408), routed_live=12,
                   on_chip=True) -> dict:
     """Each kernel `auto` selects on this chip, once, natively, at the
     main path's shapes, against its jnp oracle at tier-1's tolerance
@@ -637,6 +638,38 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
     out.append(_close(f"eva_attention_bf16_H{e_heads}_Dh{e_dim}",
                       [slots, width * bs, e_heads, e_dim],
                       got[pos >= 0], want[pos >= 0], rtol=0, atol=1e-2))
+
+    # the routed product of a decode step at the chatgen cell's shape:
+    # 32 slots of which 12 are live, 6 of 64 bf16 experts a slot, the
+    # dead slots' rows left as they are.  At the default precision too
+    from deepspeed_tpu.moe import dropless
+
+    T, E, D, F = routed_shape
+    mk = lambda k, shape: (jax.random.normal(k, shape, jnp.float32)
+                           * D ** -0.5).astype(jnp.bfloat16)
+    experts = {"gate": mk(key[1], (E, D, F)), "up": mk(key[2], (E, D, F)),
+               "down": mk(key[3], (E, F, D))}
+    x = jax.random.normal(key[4], (T, D), jnp.float32)
+    weights, idx = dropless.route(
+        x, jax.random.normal(key[5], (D, E), jnp.float32) * D ** -0.5, 6)
+    alive = jnp.arange(T) < routed_live
+    way = dropless.routed_way(T, 6, experts)
+    if on_chip and way != "touched":
+        raise RuntimeError(
+            f"a call of {T} rows over {E} experts of {D} x {F} takes the "
+            f"{way} way on this chip")
+    got = jax.jit(dropless.experts_touched_only)(x, experts, weights, idx,
+                                                 alive)
+    want = jax.jit(dropless.experts_masked)(
+        x, experts, jnp.where(alive[:, None], weights, 0.0), idx)
+    # the kernel's weighted sum is float32 on the VPU; where XLA makes
+    # the oracle's an MXU product it enters as bf16 at this precision
+    out.append(_close(
+        f"touched_experts_bf16_T{T}_E{E}", [T, E, D, F], got, want, rtol=0,
+        atol=1e-2 * float(jnp.abs(want).max())))
+    if int(dropless.experts_touched(idx, alive, E)) in (0, E) or \
+            np.asarray(got)[routed_live:].any():
+        raise RuntimeError("the routed check's dead slots touched experts")
     return {"phase": "kernels", "native": not pallas_backend.interpret(),
             "kernels": out}
 

@@ -1,4 +1,7 @@
-"""Pallas sort-based MoE dispatch/combine (op 3, moe/dispatch.py).
+"""Pallas MoE kernels: the routed product of a call of few rows over the
+experts its live rows touched (`touched_experts`, moe/dropless.py — at
+the end of this file, and the one the chip runs), and the sort-based
+dispatch/combine of the trainer's layer (op 3, moe/dispatch.py):
 
 The jnp oracles move tokens with a scatter-add (`sorted_dispatch_ref`)
 and a gated gather (`sorted_combine_ref`).  On TPU the scatter lowers
@@ -148,3 +151,110 @@ def sorted_combine_pallas(expert_out, eidx, gate, pos, keep):
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=pallas_backend.interpret(),
     )(src, w, flat)
+
+
+# ---------------------------------------------------------------------------
+# touched experts: the routed product of a call of few rows
+# ---------------------------------------------------------------------------
+
+# what the three weight tiles a grid step reads may take of VMEM, both
+# pipeline buffers counted; the kernel asks the compiler for this much
+# and `_TOUCHED_REST` for x, the weights, the accumulator and its own
+# temporaries (a v5e's VMEM is 128 MiB, its scoped default 16)
+_TOUCHED_TILE_BYTES = 48 << 20
+_TOUCHED_REST = 16 << 20
+
+
+def touched_tile(model_dim: int, expert_dim: int, itemsize: int) -> int:
+    """The columns of an expert's `gate` and `up` (rows of its `down`)
+    a grid step takes: the largest divisor of `expert_dim` in whole
+    128-lane tiles — or all of it — whose three tiles, double-buffered,
+    fit `_TOUCHED_TILE_BYTES`; 0 where none does."""
+    fits = lambda tf: 6 * model_dim * tf * itemsize <= _TOUCHED_TILE_BYTES
+    if fits(expert_dim):
+        return expert_dim
+    return max((tf for tf in range(128, expert_dim, 128)
+                if expert_dim % tf == 0 and fits(tf)), default=0)
+
+
+def _touched_kernel(ids_ref, n_ref, x_ref, w_ref, gate_ref, up_ref,
+                    down_ref, o_ref):
+    j, f = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((j == 0) & (f == 0))
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(j < n_ref[0])
+    def _expert():
+        # experts_weighted's roundings: operands at the weights' dtype,
+        # float32 sums, the gated product rounded before `down`
+        x = x_ref[...]
+        g = jnp.dot(x, gate_ref[...], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, up_ref[...], preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        out = jnp.dot(h, down_ref[...], preferred_element_type=jnp.float32)
+        w = w_ref[...]                                    # (T, E)
+        mine = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1) == ids_ref[j]
+        o_ref[...] += out * jnp.sum(jnp.where(mine, w, 0.0), axis=1,
+                                    keepdims=True)
+
+
+def touched_experts_pallas(x, experts, w, ids, n):
+    """Drop-in for `moe/dropless.py::experts_weighted` where `ids` [E]
+    lists the `n` experts with a weight in w [T, E] (`touched_list`):
+    x [T, D] -> [T, D] float32, tolerance parity (the sum over experts
+    and over an expert's column tiles is taken in another order)."""
+    return _touched(x.astype(experts["gate"].dtype), experts["gate"],
+                    experts["up"], experts["down"], w, ids,
+                    jnp.reshape(n, (1,)),
+                    interpret=pallas_backend.interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _touched(x, gate, up, down, w, ids, n, *, interpret):
+    """The call, as a function of its own (a program that makes it in
+    every layer lowers the kernel once).  Grid (place j in the list,
+    column tile f); the weight tiles' index maps read `ids[j]`, and
+    from place `n` on stay on the last tile read, so that the pipeline
+    copies nothing more; the output block is the float32 accumulator."""
+    T, D = x.shape
+    E, _, F = gate.shape
+    tf = touched_tile(D, F, gate.dtype.itemsize)
+    nf = F // tf
+    rows = -(-T // 16) * 16            # whole tiles of bf16 rows
+    if rows != T:
+        x = jnp.pad(x, ((0, rows - T), (0, 0)))
+        w = jnp.pad(w, ((0, rows - T), (0, 0)))
+
+    def tile(j, f, ids, n):      # (expert, column tile) of a grid step
+        return ids[j], jnp.where(j < n[0], f, nf - 1)
+
+    def cols_of(*step):
+        expert, t = tile(*step)
+        return expert, 0, t
+
+    whole = lambda j, f, ids, n: (0, 0)
+    cols = pl.BlockSpec((None, D, tf), cols_of)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(E, nf),
+        in_specs=[
+            pl.BlockSpec((rows, D), whole),
+            pl.BlockSpec((rows, E), whole),
+            cols, cols,
+            pl.BlockSpec((None, tf, D), lambda *step: (*tile(*step), 0)),
+        ],
+        out_specs=pl.BlockSpec((rows, D), whole),
+    )
+    out = pl.pallas_call(
+        _touched_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY),
+            vmem_limit_bytes=_TOUCHED_TILE_BYTES + _TOUCHED_REST),
+        name="touched_experts",
+        interpret=interpret,
+    )(ids, n, x, w, gate, up, down)
+    return out[:T]

@@ -19,7 +19,8 @@ Every hot inner loop that has a Pallas implementation registers a
   described v5e) and where the kernel beats the oracle on the chip.
 
     call site (ops/transformer/attention.py, serving/layers.py,
-               runtime/comm/quant.py, moe/dispatch.py, ops/sparse_attention/)
+               runtime/comm/quant.py, moe/dispatch.py, moe/dropless.py,
+               ops/sparse_attention/)
        └─> dispatch(op, *args, info=<shape facts of this call>)
               └─> pallas  iff  TPU backend  and  op.auto_supports(info)
                                and  partitionable here
@@ -432,10 +433,54 @@ class MoEDispatchOp(KernelOp):
         return moe_dispatch.sorted_combine_ref(*args, **kwargs)
 
 
+class TouchedExpertsOp(KernelOp):
+    """The routed FFN of a call of few rows (moe/dropless.py): every
+    expert that a live row's assignment chose, on every row, under the
+    call's combine weights.  Pallas = a walk of the touched list that
+    reads only those experts' matrices (kernels/moe_kernels.py); oracle
+    = `experts_weighted`, every expert held under the same weights.  The
+    shape rule looks at the call's rows and the experts' three sizes
+    (`moe/dropless.py::touched_info`)."""
+
+    NAME = "touched_experts"
+
+    def auto_supports(self, variant, info):
+        if not info:
+            return True, ""
+        from ..moe.dropless import RIDGE_TOKENS
+        from .moe_kernels import touched_tile
+
+        t = int(info["tokens"])
+        if t > RIDGE_TOKENS:
+            return False, (f"{t} rows are over the ridge "
+                           f"({RIDGE_TOKENS}): every expert's products "
+                           f"on every row cost more than the bytes they "
+                           f"save, and the grouped products do top_k a "
+                           f"row")
+        D, F = int(info["model_dim"]), int(info["expert_dim"])
+        if D % 128:
+            return False, (f"rows of {D} values are not whole 128-lane "
+                           f"tiles")
+        if not touched_tile(D, F, int(info["itemsize"])):
+            return False, (f"no whole-tile share of an expert's {F} "
+                           f"columns divides them and fits the kernel's "
+                           f"VMEM at {D} rows")
+        return True, ""
+
+    def pallas(self, variant, *args, **kwargs):
+        from . import moe_kernels
+        return moe_kernels.touched_experts_pallas(*args, **kwargs)
+
+    def oracle(self, variant, x, experts, w, ids, n):
+        from ..moe import dropless
+        return dropless.experts_weighted(x, experts, w)
+
+
 KERNEL_OPS: Dict[str, KernelOp] = {
     op.NAME: op for op in (FlashAttentionOp(), SparseAttentionOp(),
                            PagedAttentionOp(), EvaAttentionOp(),
-                           QuantCodecOp(), MoEDispatchOp())
+                           QuantCodecOp(), MoEDispatchOp(),
+                           TouchedExpertsOp())
 }
 
 

@@ -183,8 +183,8 @@ def expert_ffn(spec, cfg, p, h, live=None):
     sum where `spec.renormalize`, the held experts' weighted sum
     (`spec.held`: a share of `cfg.num_experts`, or all) plus the shared
     experts' sum or, with `spec.shared` "average", their mean; None, or
-    with `live` [tokens] how many of the experts held the live tokens
-    touched, int32)."""
+    with `live` [tokens] — the tokens whose sum anyone reads — how many
+    of the experts held the live tokens touched, int32)."""
     flat = h.reshape(-1, h.shape[-1])
     with jax.named_scope("moe_route"):
         weights, idx = route(flat, p["router"], spec.top_k,
@@ -196,7 +196,7 @@ def expert_ffn(spec, cfg, p, h, live=None):
             count = spec.held[1]
     with jax.named_scope("moe_experts"):
         y = routed_experts(flat, p["experts"], weights, idx,
-                           total=cfg.num_experts, held=held)
+                           total=cfg.num_experts, held=held, live=live)
     with jax.named_scope("moe_shared"):
         shared = silu_gated_ffn(p["shared"], flat)
         y = y + (shared / cfg.num_shared if spec.shared == "average"

@@ -250,14 +250,15 @@ def absorb(cfg, n_queries: int, n_rows: int) -> bool:
     return absorbed < expanded
 
 
-def expert_ffn(cfg, p, h):
+def expert_ffn(cfg, p, h, live=None):
     """h [..., D] float32 -> the routed experts' weighted sum plus the
-    shared experts, float32, and the experts chosen [tokens, top_k]."""
+    shared experts, float32, and the experts chosen [tokens, top_k].
+    `live` [tokens]: the tokens whose sum anyone reads (None: all)."""
     flat = h.reshape(-1, h.shape[-1])
     with jax.named_scope("moe_route"):
         weights, idx = route(flat, p["router"], cfg.top_k)
     with jax.named_scope("moe_experts"):
-        y = routed_experts(flat, p["experts"], weights, idx)
+        y = routed_experts(flat, p["experts"], weights, idx, live=live)
     with jax.named_scope("moe_shared"):
         y = y + silu_gated_ffn(p["shared"], flat)
     return y.reshape(h.shape), idx

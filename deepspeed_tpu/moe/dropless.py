@@ -16,8 +16,9 @@ renumbers the chosen experts to the ones this chip holds and gives the
 others weight 0, so an assignment to an expert that lies elsewhere adds
 nothing here — what the other chips would add is theirs to add.
 
-Two ways to the same sum y_t = sum_i w_ti E_i(x_t), chosen by
-`routed_experts` from what the call can see (its static shapes):
+Three ways to the same sum y_t = sum_i w_ti E_i(x_t), chosen by
+`routed_experts` from what the call can see (its static shapes and its
+backend):
 
 * `experts_masked`: every expert on every token, weighted 0 where the
   token did not choose it.  Streams each expert's weights once and does
@@ -26,11 +27,22 @@ Two ways to the same sum y_t = sum_i w_ti E_i(x_t), chosen by
   (T * top_k >= E, counted over all E: of a share `count / E` of the
   assignments are held, `T * top_k * count / E >= count`) and T is
   under the chip's ridge (`RIDGE_TOKENS` operations a byte) — a decode
-  step of tens of slots.
+  step of tens of slots.  It is also the oracle of the third way.
+* `experts_touched_only` (on a TPU): every TOUCHED expert on every
+  token — the masked sum over the experts that an assignment of a live
+  token chose here, by a list the program computes (`touched_list`) and
+  a kernel that walks it (kernels/moe_kernels.py): an expert no live
+  token chose is never read from HBM.  Never more bytes than the masked
+  way and the same products an expert read, so any call under the ridge
+  takes it, whatever its assignments cover: a decode step of 32 slots
+  of which 12 are live streams the ~40 of 64 experts those chose, and
+  the slots that are not live (`live` [T] False: their hidden rows are
+  whatever the slot last held) touch nothing.
 * `experts_grouped`: assignments sorted by expert, one grouped product
   (`lax.ragged_dot`) a matrix over the experts held, the results put
-  back in token order.  top_k products a token: a prefill chunk, or a
-  decode step too small to touch every expert.
+  back in token order.  top_k products a token: a prefill chunk (any
+  call over the ridge), or off a TPU a decode step too small to touch
+  every expert.
 
 An expert is a SiLU-gated FFN; `experts` holds `gate`, `up` [E, D, F]
 and `down` [E, F, D].
@@ -73,15 +85,28 @@ def held_assignments(weights, idx, first: int, count: int):
             held)
 
 
-def experts_touched(idx, live, num_experts: int, held=None):
-    """Experts with at least one assignment from a live token: idx
-    [T, top_k], live [T] bool -> int32 scalar; with `held` [T, top_k],
-    among the assignments held."""
+def touched_list(idx, live, num_experts: int, held=None):
+    """The experts with at least one assignment from a live token: idx
+    [T, top_k], live [T] bool -> (ids [num_experts] int32: the touched
+    experts in ascending order, the tail padded by repeating the last
+    touched one — zeros where none is —, their count int32); with
+    `held` [T, top_k], among the assignments held."""
     hit, flat = jnp.zeros((num_experts,), jnp.int32), idx.reshape(-1)
     live = jnp.repeat(live.astype(jnp.int32), idx.shape[1])
     if held is not None:
         live = live * held.reshape(-1).astype(jnp.int32)
-    return hit.at[flat].max(live).sum()
+    hit = hit.at[flat].max(live)
+    n, at = hit.sum(), jnp.arange(num_experts, dtype=jnp.int32)
+    # touched expert e goes to place (touched experts below e)
+    ids = jnp.zeros_like(at).at[
+        jnp.where(hit > 0, jnp.cumsum(hit) - 1, num_experts)].set(
+            at, mode="drop")
+    return jnp.where(at < n, ids, ids[jnp.maximum(n - 1, 0)]), n
+
+
+def experts_touched(idx, live, num_experts: int, held=None):
+    """How many experts `touched_list` lists: int32 scalar."""
+    return touched_list(idx, live, num_experts, held)[1]
 
 
 def _dot32(x, w, dims):
@@ -89,16 +114,28 @@ def _dot32(x, w, dims):
                       preferred_element_type=jnp.float32)
 
 
-def experts_masked(x, experts, weights, idx):
-    """Every expert on every token; x [T, D] -> [T, D] float32."""
-    E = experts["gate"].shape[0]
-    # w[t, e]: the token's weight for expert e, 0 where it was not chosen
-    w = jnp.zeros((x.shape[0], E), jnp.float32).at[
-        jnp.arange(x.shape[0])[:, None], idx].add(weights)
+def combine_weights(weights, idx, num_experts: int):
+    """w [T, num_experts] float32: the token's weight for expert e, 0
+    where it was not chosen."""
+    T = idx.shape[0]
+    return jnp.zeros((T, num_experts), jnp.float32).at[
+        jnp.arange(T)[:, None], idx].add(weights)
+
+
+def experts_weighted(x, experts, w):
+    """Every expert on every token under the weights w [T, E] of
+    `combine_weights`; x [T, D] -> [T, D] float32."""
     g = _dot32(x, experts["gate"], "td,edf->etf")
     u = _dot32(x, experts["up"], "td,edf->etf")
     out = _dot32(jax.nn.silu(g) * u, experts["down"], "etf,efd->etd")
     return jnp.einsum("etd,te->td", out, w)
+
+
+def experts_masked(x, experts, weights, idx):
+    """Every expert on every token, weighted 0 where the token did not
+    choose it; x [T, D] -> [T, D] float32."""
+    return experts_weighted(
+        x, experts, combine_weights(weights, idx, experts["gate"].shape[0]))
 
 
 def experts_grouped(x, experts, weights, idx, held=None):
@@ -129,15 +166,58 @@ def experts_grouped(x, experts, weights, idx, held=None):
     return jnp.einsum("tkd,tk->td", out[back].reshape(T, k, -1), weights)
 
 
-def routed_experts(x, experts, weights, idx, total=None, held=None):
-    """sum_i w_ti E_i(x_t) for x [T, D], by the cheaper of the two ways
-    at this call's shapes.  `total` is the number of experts the router
-    chose among where `experts` is a share of them, and `held`
-    [T, top_k] the assignments of the share (`held_assignments`)."""
-    T, k = idx.shape
+def touched_info(tokens: int, experts) -> dict:
+    """What the kernel registry may look at to choose the routed product
+    of a call of `tokens` rows over `experts`."""
+    E, D, F = experts["gate"].shape
+    return {"tokens": tokens, "num_experts": E, "model_dim": D,
+            "expert_dim": F,
+            "itemsize": jnp.dtype(experts["gate"].dtype).itemsize}
+
+
+def experts_touched_only(x, experts, weights, idx, live=None, held=None):
+    """Every touched expert on every token, through the registry's
+    `touched_experts` op; x [T, D] -> [T, D] float32.  An assignment of
+    a token that is not `live` [T] weighs 0 and touches nothing."""
+    from ..kernels import registry
+
     E = experts["gate"].shape[0]
-    total = E if total is None else total
+    if live is None:
+        live = jnp.ones((x.shape[0],), bool)
+    ids, n = touched_list(idx, live, E, held)
+    w = combine_weights(jnp.where(live[:, None], weights, 0.0), idx, E)
+    return registry.dispatch("touched_experts", x, experts, w, ids, n,
+                             info=touched_info(x.shape[0], experts))
+
+
+def routed_way(tokens: int, top_k: int, experts, total=None) -> str:
+    """Which of the three ways `routed_experts` takes for a call of
+    `tokens` rows of `top_k` assignments over `experts` (arrays or their
+    shapes), a share of `total`: "touched", "masked" or "grouped"."""
+    from ..kernels import registry
+
+    total = experts["gate"].shape[0] if total is None else total
+    # on a TPU, under the ridge: never more bytes than the masked way
+    if registry.resolve_impl("touched_experts",
+                             info=touched_info(tokens, experts)) == "pallas":
+        return "touched"
     # the assignments expected here, T * k * E / total, cover the E held
-    if T * k >= total and T <= RIDGE_TOKENS:
+    if tokens * top_k >= total and tokens <= RIDGE_TOKENS:
+        return "masked"
+    return "grouped"
+
+
+def routed_experts(x, experts, weights, idx, total=None, held=None,
+                   live=None):
+    """sum_i w_ti E_i(x_t) for x [T, D], by the cheapest of the three
+    ways at this call's shapes and backend (`routed_way`).  `total` is
+    the number of experts the router chose among where `experts` is a
+    share of them, `held` [T, top_k] the assignments of the share
+    (`held_assignments`) and `live` [T] the tokens whose sum anyone
+    reads (all of them where it is None)."""
+    way = routed_way(*idx.shape, experts, total)
+    if way == "touched":
+        return experts_touched_only(x, experts, weights, idx, live, held)
+    if way == "masked":
         return experts_masked(x, experts, weights, idx)
     return experts_grouped(x, experts, weights, idx, held)

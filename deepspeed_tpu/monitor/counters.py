@@ -135,7 +135,11 @@ Instrumented sites:
   bytes = token-expert pairs computed (tokens x top_k, nothing
   dropped); `serve.moe.experts_touched` — calls = decode steps x
   routed layers, bytes = experts with at least one active slot's
-  token (counted in the program, read back with the step's tokens).
+  token (counted in the program, read back with the step's tokens);
+  `serve.moe.experts_streamed` — the same calls, bytes = experts whose
+  weights the step's routed product read: the touched ones where it
+  follows the touched list or sorts by expert, every expert held where
+  it masks (`moe/dropless.py::routed_way`, asked once at build).
   Grouped rows over two groups of layers (a served model with
   "grouped" attention and sliding layers): `serve.window.rows_read` —
   calls = queries decoded, bytes = rows one attends in ONE sliding
@@ -144,9 +148,9 @@ Instrumented sites:
   positions on the host; `kv.ring_wraps` — calls = requests that ended
   with more rows than a ring holds, bytes = blocks the ring saved them
   in the window group.  Behind a share of the experts
-  `serve.moe.experts_touched` counts among the experts held and
-  `serve.moe.assignments` is not emitted (only the program knows how
-  many of a call's assignments it held).
+  `serve.moe.experts_touched` and `serve.moe.experts_streamed` count
+  among the experts held and `serve.moe.assignments` is not emitted
+  (only the program knows how many of a call's assignments it held).
   Paged attention (the GPT family): `serve.paged.rows_walked`
   — calls = slots decoded, bytes = pool rows their attention reads (a
   slot's live blocks where the paged kernel runs, the table's whole

@@ -129,7 +129,11 @@ token-expert pairs they computed: tokens x top_k, nothing dropped) and
 `serve.moe.experts_touched` (calls = decode steps x routed layers,
 bytes = experts with at least one active slot's token, counted in the
 program and read back with the step's tokens, one step after the
-launch).
+launch) and `serve.moe.experts_streamed` (the same calls, bytes =
+experts whose weights the step's routed product read: the touched ones
+where it follows the touched list — a TPU — or sorts by expert, every
+one held where it masks; `moe/dropless.py::routed_way`, asked once at
+build, and no read of its own).
 
 Grouped rows over two groups of layers (a layer spec with "grouped"
 attention some of whose layers have a window): the full layers' rows are
@@ -421,6 +425,17 @@ class ServeEngine:
                                if spec.ffn == "routed_experts" else 0)
         self._top_k = spec.top_k
         self._held_share = spec.held is not None
+        # what a decode step's routed product streams of a layer's
+        # experts, for serve.moe.experts_streamed: None where it follows
+        # the touched list, the experts held where every one is read
+        self._streams_all = None
+        if self._routed_layers:
+            from ..moe.dropless import routed_way
+
+            experts = params["blocks"][spec.dense_layers]["mlp"]["experts"]
+            if routed_way(c.max_batch * (int(c.draft_len) + 1), spec.top_k,
+                          experts, cfg.num_experts) == "masked":
+                self._streams_all = experts["gate"].shape[0]
         self._sliding_layers = sum(
             1 for i in range(cfg.num_layers) if spec.window_of(i))
         kv_dtype = cfg.param_dtype if c.kv_dtype is None else c.kv_dtype
@@ -1021,9 +1036,13 @@ class ServeEngine:
             if self._routed_layers:
                 # behind the slots' tokens: the experts the step touched
                 self._count_assignments(len(step.lanes))
+                touched = int(toks[self.config.max_batch])
                 COUNTERS.add("serve.moe.experts_touched",
+                             calls=self._routed_layers, nbytes=touched)
+                COUNTERS.add("serve.moe.experts_streamed",
                              calls=self._routed_layers,
-                             nbytes=int(toks[self.config.max_batch]))
+                             nbytes=touched if self._streams_all is None
+                             else self._streams_all * self._routed_layers)
             rids = []
             for req, slot in step.lanes:
                 if req.done:

@@ -524,9 +524,9 @@ def block(spec, cfg, p, x, kv, addr, s, layer: int = 0):
     h = _norm(spec, x, p["ln2"])
     if spec.ffn != "routed_experts" or layer < spec.dense_layers:
         return x + _ffn(spec, p["mlp"], h), tuple(kv), None
-    y, idx = expert_ffn(cfg, p["mlp"], h)
-    touched = experts_touched(idx, addr.q_pos.reshape(-1) >= 0,
-                              p["mlp"]["router"].shape[1])
+    live = addr.q_pos.reshape(-1) >= 0
+    y, idx = expert_ffn(cfg, p["mlp"], h, live)
+    touched = experts_touched(idx, live, p["mlp"]["router"].shape[1])
     return x + y, tuple(kv), touched
 
 

@@ -288,6 +288,22 @@ def test_the_way_is_chosen_from_the_assignments_held():
                       "grouped"]
 
 
+def test_on_a_tpu_a_share_under_the_ridge_follows_the_touched_list(native):
+    """The chip-side half, at the `mixedlen` cell's expert shapes (16 of
+    128 held, 8 a token): any call of up to 128 rows reads the held
+    experts its live rows touched — one token's 8 assignments hold one
+    expert on average, and none is read for the other 15 — and a
+    prefill chunk keeps the grouped products."""
+    ex = {"gate": jax.ShapeDtypeStruct((16, 4096, 4096), jnp.bfloat16)}
+    assert [dropless.routed_way(t, 8, ex, 128)
+            for t in (1, 15, 16, 128, 129, 512)] == \
+        ["touched"] * 4 + ["grouped"] * 2
+    # widths the kernel cannot tile: the choice off a TPU
+    small = {"gate": jax.ShapeDtypeStruct((16, 64, 32), jnp.float32)}
+    assert [dropless.routed_way(t, 8, small, 128) for t in (15, 16, 129)] \
+        == ["grouped", "masked", "grouped"]
+
+
 def test_the_shares_add_up():
     """The routed parts that the E / held shares give, with the shared
     experts counted once, are what the uncut reference gives for the
@@ -516,6 +532,10 @@ def test_counters_of_a_decode_step():
     # and how many of a call's assignments were held only the program
     # knows: they are not counted
     assert 0 < touched["bytes"] <= 10 * TOPK * LAYERS
+    # off a TPU 3 slots x top 4 of 8 leave one for each of the 4 held:
+    # the masked way, which reads all 4 in every layer
+    assert d["serve.moe.experts_streamed"] == {
+        "calls": steps * LAYERS, "bytes": steps * LAYERS * 4}
     assert "serve.moe.assignments" not in d
     assert "serve.paged.rows_walked" not in d
 

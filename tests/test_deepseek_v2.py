@@ -230,6 +230,37 @@ def test_the_way_is_chosen_from_the_calls_shapes():
                       "grouped", "grouped"]
 
 
+def test_on_a_tpu_every_call_under_the_ridge_follows_the_touched_list(
+        native):
+    """The chip-side half of the chooser, at the `chatgen` cell's expert
+    shapes: whatever its assignments cover, a call of up to 128 rows
+    reads only the experts its live rows touched; a prefill chunk keeps
+    the grouped products; `DS_KERNEL_TOUCHED_EXPERTS=0` gives the two
+    old ways back."""
+    ex = {"gate": jax.ShapeDtypeStruct((64, 2048, 1408), jnp.bfloat16)}
+    ways = lambda: [dropless.routed_way(t, 6, ex)
+                    for t in (1, 10, 11, 32, 128, 129, 512)]
+    assert ways() == ["touched"] * 5 + ["grouped"] * 2
+    picked = []
+    real = dropless.experts_touched_only
+    try:
+        dropless.experts_touched_only = \
+            lambda *a: picked.append([None if v is None else v.shape
+                                      for v in a[3:]])
+        dropless.routed_experts(None, ex, None, jnp.zeros((32, 6), jnp.int32),
+                                live=jnp.ones((32,), bool))
+    finally:
+        dropless.experts_touched_only = real
+    assert picked == [[(32, 6), (32,), None]]     # idx, live, held
+    import os
+    os.environ["DS_KERNEL_TOUCHED_EXPERTS"] = "0"
+    try:
+        assert ways() == ["grouped", "grouped", "masked", "masked",
+                          "masked", "grouped", "grouped"]
+    finally:
+        del os.environ["DS_KERNEL_TOUCHED_EXPERTS"]
+
+
 def test_routed_ffn_equals_the_all_experts_masked_reference():
     model, params = _model()
     p = params["blocks"][1]
@@ -313,6 +344,31 @@ def test_counters_of_a_decode_step():
     assert steps > 5 and touched["calls"] == steps * 2
     # one or two live tokens a step choose 3..6 different experts a layer
     assert steps * 2 * 3 <= touched["bytes"] <= steps * 2 * 6
+    # off a TPU 3 slots x top 3 cover the 8 experts: the masked way,
+    # which reads every expert of both layers whatever was touched
+    assert d["serve.moe.experts_streamed"] == {
+        "calls": steps * 2, "bytes": steps * 2 * EXPERTS}
+
+
+@pytest.mark.parametrize("slots,way", [(1, "grouped"), (3, "masked")])
+def test_experts_streamed_is_what_the_chosen_way_reads(slots, way):
+    """`serve.moe.experts_streamed` against `experts_touched`: equal
+    where the step's routed product follows what was touched (here the
+    grouped way; on a TPU the touched list), the experts held where it
+    masks — from the number already read back, or a constant."""
+    model, params = _model()
+    experts = params["blocks"][1]["mlp"]["experts"]
+    assert dropless.routed_way(slots, TOPK, experts) == way
+    eng = ServeEngine(model, params, _serve(max_batch=slots))
+    before = COUNTERS.snapshot()
+    eng.generate([_prompt(8, 1)], 4)
+    d = COUNTERS.delta_since(before)
+    touched = d["serve.moe.experts_touched"]
+    streamed = d["serve.moe.experts_streamed"]
+    assert streamed["calls"] == touched["calls"] == 3 * 2
+    assert streamed["bytes"] == (touched["bytes"] if way == "grouped"
+                                 else 3 * 2 * EXPERTS)
+    assert touched["bytes"] == 3 * 2 * TOPK      # one live token a step
 
 
 def test_experts_touched_is_read_one_step_late_and_loses_nothing():

@@ -449,6 +449,187 @@ def test_forced_pallas_off_tpu_raises_without_interpret_escape():
                         interpret_ok=True) == "pallas"
 
 
+# -- the routed product of a call of few rows (moe/dropless.py) ---------------
+
+
+def _routed_inputs(T, E, k, live, share=None, dtype=jnp.float32, D=128,
+                   F=256, scoring="softmax", push=None, seed=0):
+    """A call of T rows over E held experts: (x, experts, weights, idx,
+    held, live).  `share` = (first, total): the E held are a share of
+    `total` the router chose among; `push` {expert: score} steers every
+    row's choices."""
+    from deepspeed_tpu.moe import dropless
+
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    mk = lambda key, shape: (jax.random.normal(key, shape) * 0.1
+                             ).astype(dtype)
+    experts = {"gate": mk(ks[0], (E, D, F)), "up": mk(ks[1], (E, D, F)),
+               "down": mk(ks[2], (E, F, D))}
+    first, total = share or (0, E)
+    x = jax.random.normal(ks[3], (T, D))
+    router = jax.random.normal(ks[4], (D, total)) * 0.1
+    if push:
+        x = x.at[:, 0].set(30.0)
+        router = router.at[0].set(0.0)
+        for e, score in push.items():
+            router = router.at[0, e].set(score)
+    weights, idx = dropless.route(x, router, k, scoring=scoring,
+                                  renormalize=scoring == "sigmoid")
+    held = None
+    if share:
+        weights, idx, held = dropless.held_assignments(weights, idx, first, E)
+    return x, experts, weights, idx, held, jnp.asarray(live)
+
+
+_ROUTED_SCENES = {
+    # 32 rows of 6 over 16 experts, all live: every expert touched
+    "all_experts_touched": dict(T=32, E=16, k=6, live=[True] * 32,
+                                touched=16),
+    # two live rows of 2: a few
+    "a_few_touched": dict(T=16, E=16, k=2, live=[True, True] + [False] * 14,
+                          touched=(2, 4)),
+    "none_touched": dict(T=16, E=8, k=2, live=[False] * 16, touched=0),
+    # the live row is steered to experts 0 and 1; the dead rows' own
+    # choices cover the rest and must touch nothing
+    "dead_rows_would_touch_more": dict(
+        T=16, E=8, k=2, live=[True] + [False] * 15, touched=(1, 2)),
+    # Command A+'s routing at toy size: 16 of 128 held, 8 a row by
+    # renormalised sigmoid scores, 5 of 16 rows live
+    "a_held_share": dict(T=16, E=16, k=8, share=(32, 128),
+                         scoring="sigmoid", live=[True] * 5 + [False] * 11,
+                         touched=(1, 16)),
+    # nothing a live row chose is held here
+    "a_held_share_none_here": dict(
+        T=16, E=4, k=2, share=(4, 8), scoring="sigmoid",
+        push={0: 9.0, 1: 9.0}, live=[True] * 16, touched=0),
+    "bf16_T32": dict(T=32, E=8, k=2, dtype=jnp.bfloat16,
+                     live=[True] * 12 + [False] * 20, touched=(2, 8)),
+    "bf16_T16_column_tiles": dict(T=16, E=8, k=2, dtype=jnp.bfloat16,
+                                  live=[True] * 5 + [False] * 11,
+                                  touched=(2, 8),
+                                  tile_bytes=6 * 128 * 128 * 2),
+    # rows that are no whole tile: padded inside the call
+    "T3": dict(T=3, E=8, k=2, live=[True, False, True], touched=(2, 4)),
+}
+
+
+@pytest.mark.parametrize("scene", list(_ROUTED_SCENES))
+def test_touched_experts_parity(scene, monkeypatch):
+    """The walk of the touched list vs `experts_masked` with the dead
+    rows' weights zeroed: the same sum, whatever is touched."""
+    from deepspeed_tpu.kernels import moe_kernels
+    from deepspeed_tpu.moe import dropless
+
+    kw = dict(_ROUTED_SCENES[scene])
+    touched, tile_bytes = kw.pop("touched"), kw.pop("tile_bytes", None)
+    x, experts, weights, idx, held, live = _routed_inputs(**kw)
+    E = experts["gate"].shape[0]
+    if tile_bytes:
+        monkeypatch.setattr(moe_kernels, "_TOUCHED_TILE_BYTES", tile_bytes)
+        assert moe_kernels.touched_tile(128, 256, 2) == 128
+    if "dead_rows" in scene:
+        all_live = int(dropless.experts_touched(
+            idx, jnp.ones_like(live), E, held))
+        assert all_live > 2
+    n = int(dropless.experts_touched(idx, live, E, held))
+    lo, hi = touched if isinstance(touched, tuple) else (touched, touched)
+    assert lo <= n <= hi, n
+    want = dropless.experts_masked(
+        x, experts, jnp.where(live[:, None], weights, 0.0), idx)
+    with kernel_config(ops={"touched_experts": "pallas"}, interpret=True):
+        got = registry.dispatch(
+            "touched_experts", x, experts,
+            dropless.combine_weights(
+                jnp.where(live[:, None], weights, 0.0), idx, E),
+            *dropless.touched_list(idx, live, E, held))
+        via = dropless.experts_touched_only(x, experts, weights, idx, live,
+                                            held)
+    assert got.shape == want.shape and got.dtype == want.dtype == jnp.float32
+    assert np.array_equal(np.asarray(got), np.asarray(via))
+    # bf16: a float32 sum taken in another order rounds a gated product
+    # to the neighbouring bf16 now and then
+    tol = 2e-6 if experts["gate"].dtype == jnp.float32 else 1e-3
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=tol * max(np.abs(want).max(), 1.0))
+    assert not np.asarray(got)[~np.asarray(live)].any()
+    if n == 0:
+        assert not np.asarray(got).any()
+
+
+@pytest.mark.parametrize("scene", ["a_few_touched", "a_held_share",
+                                   "none_touched", "all_experts_touched"])
+def test_touched_list_is_what_experts_touched_counts(scene):
+    """One computation feeds the product and the counter: the list's
+    count is `experts_touched`, its first `n` entries are the touched
+    experts in ascending order and its tail repeats the last."""
+    from deepspeed_tpu.moe import dropless
+
+    kw = dict(_ROUTED_SCENES[scene])
+    kw.pop("touched")
+    _, experts, _, idx, held, live = _routed_inputs(**kw)
+    E = experts["gate"].shape[0]
+    ids, n = dropless.touched_list(idx, live, E, held)
+    ids, n = np.asarray(ids), int(n)
+    assert n == int(dropless.experts_touched(idx, live, E, held))
+    on = np.asarray(live)[:, None] & (
+        np.ones(idx.shape, bool) if held is None else np.asarray(held))
+    want = sorted(set(np.asarray(idx)[on].tolist()))
+    assert ids[:n].tolist() == want and len(ids) == E
+    assert (ids[n:] == (want[-1] if want else 0)).all()
+
+
+def test_touched_experts_reads_only_the_touched_experts():
+    """An expert no live row chose is never fetched: with its matrices
+    NaN the kernel's output does not change (the oracle multiplies them
+    by a weight of 0, and 0 x NaN is NaN)."""
+    from deepspeed_tpu.moe import dropless
+
+    kw = dict(_ROUTED_SCENES["a_few_touched"])
+    kw.pop("touched")
+    x, experts, weights, idx, held, live = _routed_inputs(**kw)
+    E = experts["gate"].shape[0]
+    ids, n = dropless.touched_list(idx, live, E, held)
+    untouched = np.setdiff1d(np.arange(E), np.asarray(ids)[:int(n)])
+    assert len(untouched) >= E - 4
+    poisoned = {k: v.at[untouched].set(jnp.nan) for k, v in experts.items()}
+    with kernel_config(ops={"touched_experts": "pallas"}, interpret=True):
+        run = lambda ex: np.asarray(dropless.experts_touched_only(
+            x, ex, weights, idx, live, held))
+        want, got = run(experts), run(poisoned)
+    assert np.isfinite(got).all() and np.array_equal(want, got)
+    assert np.isnan(np.asarray(dropless.experts_masked(
+        x, poisoned, jnp.where(live[:, None], weights, 0.0), idx))).any()
+
+
+_TOUCHED_INFO = dict(tokens=32, num_experts=64, model_dim=2048,
+                     expert_dim=1408, itemsize=2)
+
+
+@pytest.mark.parametrize("change,why", [
+    ({}, None),                                    # chatgen's decode
+    (dict(tokens=16, num_experts=16, model_dim=4096, expert_dim=4096),
+     None),                                        # mixedlen's decode
+    (dict(tokens=1), None),
+    (dict(tokens=128), None),
+    (dict(tokens=129), "129 rows are over the ridge"),
+    (dict(tokens=512), "512 rows are over the ridge"),
+    (dict(model_dim=64, expert_dim=32), "rows of 64 values"),
+    (dict(model_dim=65536, expert_dim=1408), "fits the kernel's VMEM"),
+], ids=lambda v: _paged_case_id(v) if isinstance(v, dict) else "")
+def test_touched_experts_shape_rule(change, why, native):
+    """What the call can see decides (moe/dropless.py::touched_info):
+    on the chip a decode step of either routed family takes the kernel,
+    a prefill chunk and widths the kernel cannot tile do not, and say
+    why when it is forced."""
+    info = dict(_TOUCHED_INFO, **change)
+    if why is None:
+        assert resolve_impl("touched_experts", info=info) == "pallas"
+        return
+    assert resolve_impl("touched_experts", info=info) == "jnp"
+    with pytest.raises(RuntimeError, match=why):
+        resolve_impl("touched_experts", impl="pallas", info=info)
+
+
 def _traces_a_kernel(fn, *shapes) -> bool:
     return "pallas_call" in str(jax.make_jaxpr(fn)(*shapes))
 

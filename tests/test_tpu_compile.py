@@ -226,6 +226,22 @@ def _moe(variant, n=8192, d=768, e=8, k=2):
     return fn, shapes, info
 
 
+def _touched(tokens, e, d, f, dtype=jnp.bfloat16):
+    from deepspeed_tpu.moe.dropless import touched_info
+
+    experts = {"gate": _sds((e, d, f), dtype), "up": _sds((e, d, f), dtype),
+               "down": _sds((e, f, d), dtype)}
+    info = touched_info(tokens, experts)
+    shapes = (_sds((tokens, d), jnp.float32), experts,
+              _sds((tokens, e), jnp.float32), _sds((e,), jnp.int32),
+              _sds((), jnp.int32))
+
+    def fn(x, experts, w, ids, n):
+        return registry.dispatch("touched_experts", x, experts, w, ids, n,
+                                 info=info)
+    return fn, shapes, info
+
+
 @dataclasses.dataclass
 class Case:
     """`build()` -> (fn, shapes[, info]).  `op` None: the kernel is
@@ -310,6 +326,19 @@ CASES = [
     Case("codec_dequantize_int4_4M_block256",
          lambda: _codec("dequantize", "int4"),
          op="quant_codec", variant="dequantize"),
+    # the routed product of a decode step at the two MoE cells' shapes
+    # (chatgen: an expert's 1,408 columns as one tile; mixedlen: tiles
+    # of 1,024), and what the shape rule sends elsewhere
+    Case("touched_experts_T32_E64_D2048_F1408_chatgen",
+         lambda: _touched(32, 64, 2048, 1408), op="touched_experts"),
+    Case("touched_experts_T16_E16_D4096_F4096_mixedlen",
+         lambda: _touched(16, 16, 4096, 4096), op="touched_experts"),
+    Case("touched_experts_T4_E8_D1024_F512_fp32",
+         lambda: _touched(4, 8, 1024, 512, jnp.float32),
+         op="touched_experts"),
+    Case("touched_experts_T512_prefill",
+         lambda: _touched(512, 16, 1024, 512),
+         op="touched_experts", refused=r"512 rows are over the ridge"),
     Case("moe_dispatch_N8192_D768_E8", lambda: _moe("dispatch"),
          op="moe_dispatch", variant="dispatch", refused=r"one-row block"),
     Case("moe_combine_N8192_D768_E8", lambda: _moe("combine"),
@@ -577,16 +606,18 @@ def test_evabyte_decode_walks_the_pool_as_it_lies(one_chip, native):
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_deepseek_cell_programs_compile_inside_one_chip(program, one_chip):
+def test_deepseek_cell_programs_compile_inside_one_chip(program, one_chip,
+                                                        native):
     """`deepseek-v2-lite-d9.serve.chatgen.decode` / `.prefill` at the
     cell's shapes (9 layers at published widths in bf16, 32 slots, 8,193
-    blocks of 16 latent rows, a table of 256 entries, chunk 512): the
-    registry is not asked — latent attention and the routed FFN are
-    `jax.numpy` — so `decode` holds no kernel at all and the only custom
-    calls in `prefill` are XLA's own grouped products (`lax.ragged_dot`
-    over the 64 experts); each pool enters as `[rows, 640]`, one array a
-    layer; and weights, pool and temporaries fit the chip's 15.75 GB
-    with the room the check's 1.68 GB of reference logits needs."""
+    blocks of 16 latent rows, a table of 256 entries, chunk 512), as the
+    chip traces them: latent attention is `jax.numpy`, so `decode`'s only
+    custom calls are the 8 routed layers' `touched_experts` kernels (32
+    rows are under the ridge) and the only ones in `prefill` are XLA's
+    own grouped products (`lax.ragged_dot` over the 64 experts: 512 rows
+    are over it); each pool enters as `[rows, 640]`, one array a layer;
+    and weights, pool and temporaries fit the chip's 15.75 GB with the
+    room the check's 1.68 GB of reference logits needs."""
     from deepspeed_tpu.models import DeepSeekV2, DeepSeekV2Config
     from deepspeed_tpu.serving import ServeProgramBuilder, ServeSchedule
 
@@ -622,7 +653,8 @@ def test_deepseek_cell_programs_compile_inside_one_chip(program, one_chip):
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     if program == "decode":
-        assert not calls
+        assert len(calls) == layers - 1
+        assert all("touched_experts" in ln for ln in calls)
     else:
         assert calls and all("ragged" in ln for ln in calls)
     pools = _hlo_by_shape(text)[(nblocks * bs, 640)]
@@ -636,14 +668,16 @@ def test_deepseek_cell_programs_compile_inside_one_chip(program, one_chip):
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
-                                                              one_chip):
+                                                              one_chip,
+                                                              native):
     """`command-a-plus-d4.serve.mixedlen.decode` / `.prefill` at the
     cell's shapes (4 layers at published widths in bf16 with 16 of 128
     experts and an eighth of the vocabulary, 16 slots, 16,385 blocks of
     16 rows for the full layer and 16 x 288 + 1 for each sliding one, a
-    table of 1,024 + 288 entries, chunk 512): asked for grouped rows the
-    registry answers `oracle` by name, so `decode` holds no kernel at all
-    and the only custom calls in `prefill` are XLA's own grouped products
+    table of 1,024 + 288 entries, chunk 512), as the chip traces them:
+    asked for grouped rows the registry answers `oracle` by name, so
+    `decode`'s only custom calls are the 4 layers' `touched_experts`
+    kernels and the only ones in `prefill` are XLA's own grouped products
     (`lax.ragged_dot` over the 16 held experts); every pool enters as
     `[rows, 1024]`, a K and a V a layer; and weights, pools and
     temporaries fit the chip's 15.75 GB with the room the check's 2.15 GB
@@ -698,7 +732,8 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     if program == "decode":
-        assert not calls
+        assert len(calls) == layers
+        assert all("touched_experts" in ln for ln in calls)
     else:
         assert calls and all("ragged" in ln for ln in calls)
     for rows in (nblocks * bs, (slots * ring + 1) * bs):
