@@ -147,31 +147,32 @@ def project_grouped(cfg, p, h, positions, rotate: bool, dtype):
     return q.astype(dtype), k.astype(dtype), v.astype(dtype)
 
 
-def _attend_heads(q, k, v, mask):
+def _attend_heads(q, k, v, mask, scale):
     """q [B, T, n, G, Dh] on k, v [B, L, n, Dh] under mask [B, T, L] ->
     [B, T, n, G, Dh] float32: query head (n, g) reads K/V head n."""
     scores = jnp.einsum("bqngd,bknd->bngqk", q, k,
-                        preferred_element_type=jnp.float32) * \
-        q.shape[-1] ** -0.5
+                        preferred_element_type=jnp.float32) * scale
     probs = jax.nn.softmax(
         jnp.where(mask[:, None, None, :, :], scores, NEG_INF), axis=-1)
     return jnp.einsum("bngqk,bknd->bqngd", probs.astype(v.dtype), v,
                       preferred_element_type=jnp.float32)
 
 
-def attend_grouped(q, k, v, mask):
+def attend_grouped(q, k, v, mask, scale=None):
     """Softmax attention of q [B, T, H, Dh] over k, v [B, L, KV, Dh]
-    under mask [B, T, L], query head n on K/V head n // (H / KV) ->
-    [B, T, H * Dh] float32.  All heads' scores at once where they are
-    small, a K/V head's at a time where they are not (`SCORE_BYTES`)."""
+    under mask [B, T, L], query head n on K/V head n // (H / KV), the
+    scores times `scale` (None: Dh ** -0.5) -> [B, T, H * Dh] float32.
+    All heads' scores at once where they are small, a K/V head's at a
+    time where they are not (`SCORE_BYTES`)."""
     B, T, H, Dh = q.shape
     L, KV = k.shape[1], k.shape[2]
     q = q.reshape(B, T, KV, H // KV, Dh)
+    scale = Dh ** -0.5 if scale is None else scale
     if 4 * B * H * T * L <= SCORE_BYTES:
-        return _attend_heads(q, k, v, mask).reshape(B, T, H * Dh)
+        return _attend_heads(q, k, v, mask, scale).reshape(B, T, H * Dh)
     out = jax.lax.map(
         lambda a: _attend_heads(a[0][:, :, None], a[1][:, :, None],
-                                a[2][:, :, None], mask)[:, :, 0],
+                                a[2][:, :, None], mask, scale)[:, :, 0],
         (jnp.moveaxis(q, 2, 0), jnp.moveaxis(k, 2, 0),
          jnp.moveaxis(v, 2, 0)))                    # [KV, B, T, G, Dh]
     return jnp.moveaxis(out, 0, 2).reshape(B, T, H * Dh)

@@ -17,21 +17,24 @@ from __future__ import annotations
 from typing import NamedTuple
 
 NORMS = ("layernorm", "rmsnorm_unit_offset", "rmsnorm", "layernorm_gain")
-POSITIONS = ("learned", "rope", "per_layer")
+POSITIONS = ("learned", "rope", "per_layer", "none")
 ATTENTIONS = ("paged", "eva", "latent", "grouped")
 FFNS = ("gelu_mlp", "silu_gated", "routed_experts")
 HEADS = ("tied", "untied")
 RESIDUALS = ("sequential", "parallel")
 SCORINGS = ("softmax", "sigmoid")
 SHARED = ("sum", "average")
+MIXERS = ("attention", "ssm")
 
 
 class LayerSpec(NamedTuple):
     """One kind of layer, repeated `num_layers` times — or, with
     "grouped" attention, a pattern of layers repeated: `layer_windows`
     and `layer_positions` give layer l its window and its positions at
-    index l mod the pattern's length.  The first `dense_layers` layers
-    may keep a plain gated FFN where the others route.
+    index l mod the pattern's length, and `layer_mixers` says which of
+    them mix tokens by attention and which by a state-space recurrence.
+    The first `dense_layers` layers may keep a plain gated FFN where the
+    others route.
 
     norm       "layernorm" (scale, bias) | "rmsnorm_unit_offset" (the
                scale is 1 + g) | "rmsnorm" (the scale is the gain w) |
@@ -61,6 +64,18 @@ class LayerSpec(NamedTuple):
                in a ring of `window + prefill_chunk` rows a request
                (serving/kv_cache.py's group "window"); 0 is every
                cached position (models/cohere2_moe.py).
+    mixers     `layer_mixers` (empty: every layer attends) gives layer
+               l, at index l mod its length, "attention" (the kind
+               above) or "ssm": a Mamba-2 mixer (models/
+               granite_hybrid.py) of `ssm_heads` heads of
+               `ssm_head_dim` over one group of `ssm_state` state
+               values, behind a causal depthwise convolution of
+               `ssm_conv` taps.  Such a layer owns no cache rows: it
+               keeps, a request, one float32 state [ssm_heads,
+               ssm_head_dim, ssm_state] and the convolution's last
+               `ssm_conv - 1` inputs — a prefill chunk scans from the
+               state and leaves it behind, `ssm_chunk` positions at a
+               time; a decode step moves it on by one token.
     ffn        "gelu_mlp" (fc1, tanh GELU, fc2, biases) | "silu_gated"
                | "routed_experts" (a float32 router — `scoring`
                "softmax" over all experts or "sigmoid" of each — the top
@@ -75,6 +90,11 @@ class LayerSpec(NamedTuple):
     residual   "sequential" (x + attn(norm1 x), then + ffn(norm2 of
                that)) | "parallel" (one norm: x + attn(h) + ffn(h))
     eps        the norm's epsilon
+    scalars    `embed_scale` multiplies the embedding, `residual_scale`
+               every branch before it is added to the stream,
+               `logit_divisor` divides the logits, and `attn_scale`
+               (0: head_dim ** -0.5) multiplies grouped attention's
+               scores.
     """
 
     norm: str
@@ -103,6 +123,16 @@ class LayerSpec(NamedTuple):
     shared: str = "sum"          # routed_experts: the shared experts
     experts_held: int = 0        # routed_experts: experts held here (0: all)
     first_expert: int = 0        # routed_experts: the first one held
+    layer_mixers: tuple = ()     # the pattern's "attention" | "ssm"
+    ssm_heads: int = 0           # ssm: heads of the recurrence
+    ssm_head_dim: int = 0        # ssm: values a head
+    ssm_state: int = 0           # ssm: state values (B and C's width)
+    ssm_conv: int = 0            # ssm: taps of the causal convolution
+    ssm_chunk: int = 0           # ssm: positions the scan takes at once
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    attn_scale: float = 0.0      # grouped: 0 is head_dim ** -0.5
+    logit_divisor: float = 1.0
 
     def window_of(self, layer: int) -> int:
         """The window of layer `layer` (0: every cached position)."""
@@ -112,8 +142,31 @@ class LayerSpec(NamedTuple):
 
     def rotates(self, layer: int) -> bool:
         """Whether layer `layer` of a "per_layer" spec rotates q and k."""
-        return self.layer_positions[
+        return bool(self.layer_positions) and self.layer_positions[
             layer % len(self.layer_positions)] == "rope"
+
+    def mixer_of(self, layer: int) -> str:
+        """"attention" or "ssm": how layer `layer` mixes tokens."""
+        if not self.layer_mixers:
+            return "attention"
+        return self.layer_mixers[layer % len(self.layer_mixers)]
+
+    @property
+    def has_state(self) -> bool:
+        """Whether some layer keeps a state a request beside (or in
+        place of) cache rows."""
+        return "ssm" in self.layer_mixers
+
+    def state_layers(self, num_layers: int) -> tuple:
+        """The layers of `num_layers` that keep a state a request and
+        no cache rows."""
+        return tuple(i for i in range(num_layers)
+                     if self.mixer_of(i) == "ssm")
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels of the convolution: the heads' values, B and C."""
+        return self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_state
 
     @property
     def held(self):
@@ -180,4 +233,26 @@ class LayerSpec(NamedTuple):
                 f"layer spec: a share of the experts is experts_held > 0 "
                 f"experts from first_expert >= 0 on (got "
                 f"{self.experts_held}, {self.first_expert})")
+        sizes = (self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                 self.ssm_conv, self.ssm_chunk)
+        if any(m not in MIXERS for m in self.layer_mixers) or (
+                "ssm" in self.layer_mixers) != all(n > 0 for n in sizes) \
+                or ("ssm" not in self.layer_mixers and any(sizes)) \
+                or self.ssm_conv == 1:
+            raise ValueError(
+                f"layer spec: layer_mixers says \"attention\" or \"ssm\" "
+                f"of each layer of the pattern, and a pattern with ssm "
+                f"layers, and nothing else, names ssm_heads, ssm_head_dim, "
+                f"ssm_state, ssm_conv >= 2 and ssm_chunk (got "
+                f"{self.layer_mixers}, {sizes})")
+        if min(self.embed_scale, self.residual_scale,
+               self.logit_divisor) <= 0 or self.attn_scale < 0 or (
+                self.attn_scale and self.attention != "grouped"):
+            raise ValueError(
+                f"layer spec: embed_scale, residual_scale and "
+                f"logit_divisor are positive, and attn_scale (0: "
+                f"head_dim ** -0.5) is grouped attention's (got "
+                f"{self.embed_scale}, {self.residual_scale}, "
+                f"{self.logit_divisor}, {self.attn_scale} with "
+                f"{self.attention!r} attention)")
         return self

@@ -151,6 +151,18 @@ Instrumented sites:
   `serve.moe.experts_touched` and `serve.moe.experts_streamed` count
   among the experts held and `serve.moe.assignments` is not emitted
   (only the program knows how many of a call's assignments it held).
+  A state beside rows (a served model some of whose layers mix tokens
+  by a state-space recurrence and keep a fixed state a slot, no rows),
+  all from what the host knows: `serve.ssm.state_bytes` — calls =
+  decode steps, bytes = state (the float32 state and the convolution's
+  kept inputs, every such layer) the step's program reads and writes as
+  it is built: every slot's, twice; `serve.ssm.slots_live` — calls =
+  decode steps, bytes = running slots x layers with a state (times a
+  layer's bytes a slot: what the live slots need); `serve.ssm.
+  prefill_tokens` — calls = prefill chunks, bytes = valid tokens
+  scanned; `serve.ssm.state_resets` — calls = slots zeroed on the
+  device as a request is seated (serving/kv_cache.py `reset_state`);
+  `serve.attn.rows_read` as above over the attention layers alone.
   Paged attention (the GPT family): `serve.paged.rows_walked`
   — calls = slots decoded, bytes = pool rows their attention reads (a
   slot's live blocks where the paged kernel runs, the table's whole

@@ -4,7 +4,8 @@ One `ServeEngine` owns: a `PagedKVCache` (block pool + free list), a
 `Scheduler` (admission + slots), and the jitted {prefill, decode}
 program pair from `ServeProgramBuilder`.  It serves any model that
 hands over a layer spec (`model.layer_spec()`, models/layer_spec.py):
-the GPT family, EvaByte, DeepSeek-V2 and Command A+ today.  `step()` is the whole serving
+the GPT family, EvaByte, DeepSeek-V2, Command A+ and Granite 4.0-H
+today.  `step()` is the whole serving
 loop body — admit, prefill one chunk round, decode one token for every
 running slot — and everything else (the bench's Poisson arrival thread,
 `generate()`'s synchronous loop, a `ServeWorker` daemon) just drives
@@ -52,8 +53,9 @@ What the engine's thread is doing, phase by phase (`monitor.tracing.
 phase`: a profiler annotation always, the same interval in the attached
 recorder where the engine step is sampled; disjoint, in this order in an
 iteration): `serve.idle` (the worker, nothing submitted and nothing
-unread), `serve.admit` (1), `serve.prefill.launch` (2),
-`serve.decode.launch` with the uploads of (3) inside it as
+unread), `serve.admit` (1), `serve.prefill.launch` (2; inside it, where
+layers keep a state a slot, `serve.state.reset` before a request's
+first chunk), `serve.decode.launch` with the uploads of (3) inside it as
 `serve.decode.upload`, `serve.read` (the host blocked on a launched
 program's tokens) and `serve.bookkeep` (the host's half of (4) and (5)
 once they are there); the serial loop adds `serve.draft`.
@@ -155,6 +157,32 @@ than a ring, bytes = the blocks the ring saved each in the window
 group); behind a share of the experts `serve.moe.experts_touched`
 counts among those held, and `serve.moe.assignments` is not emitted
 (only the program knows how many of a call's assignments it held).
+
+A state beside rows (a layer spec some of whose layers mix tokens by a
+state-space recurrence, grouped attention without positions in the
+others): those layers own no cache rows — `num_blocks`, the tables and
+admission count the attention layers alone — but a float32 state and
+the convolution's last inputs a SLOT, held for all `max_batch` slots
+whatever is seated: the state, not the rows, sizes `max_batch`.  When a
+request is seated, before its first prefill chunk, the engine zeroes its
+slot's entries on the device (`kv.reset_state`, phase
+`serve.state.reset`): launched behind whatever step still decodes for
+the slot's last tenant, so it holds under the loop that runs ahead; a
+decode step hands a slot that is not running its state back as it found
+it, so a request between its prefill chunks is safe from the steps that
+run meanwhile.  `prefill` learns the slot from one more entry behind the
+request's table.  For such a model the engine refuses, by name,
+`prefix_cache=True` and sessions (a shared or resumed prefix would need
+the state as it stood at the prefix's end, and nothing stores one),
+`draft_len > 0` (a rejected draft would have to rewind the state),
+quantized weights, int8/int4 rows and a mesh of more than one device.
+Counters, from what the host knows: `serve.ssm.state_bytes` (calls =
+decode steps, bytes = state the step's program reads and writes: every
+slot's, twice, as the program is built), `serve.ssm.slots_live` (calls
+= decode steps, bytes = running slots x layers with a state),
+`serve.ssm.prefill_tokens` (calls = prefill chunks, bytes = valid
+tokens scanned), `serve.ssm.state_resets` (calls = slots zeroed) and
+`serve.attn.rows_read` over the attention layers.
 
 What a paged step reads: `serve.paged.rows_walked` (calls = slots
 decoded, bytes = the pool rows attention reads for them: a slot's live
@@ -383,7 +411,23 @@ class ServeEngine:
         ring_blocks, ring_layers = 0, ()
         # grouped rows: the rows ONE sliding layer reads at most a query
         self._window = max(spec.layer_windows, default=0)
-        if spec.attention == "grouped":
+        # layers that keep a state a slot and no rows
+        self._state_layers = spec.state_layers(cfg.num_layers)
+        if self._state_layers:
+            if c.prefix_cache:
+                raise NotImplementedError(
+                    "prefix_cache=True over layers with a state: a request "
+                    "that shares a prefix's blocks would also need the "
+                    "state as it stood at the prefix's last block "
+                    "boundary, and nothing stores such a snapshot; pass "
+                    "prefix_cache=False")
+            if mesh_info is not None and mesh_info.size > 1:
+                raise NotImplementedError(
+                    f"a mesh of {mesh_info.size} devices over layers with "
+                    f"a state: the split of the state's heads and of the "
+                    f"convolution's channels over the model axis is not "
+                    f"built; serve it on one device")
+        elif spec.attention == "grouped":
             if c.prefix_cache:
                 raise NotImplementedError(
                     "prefix_cache=True over grouped rows with sliding "
@@ -472,7 +516,14 @@ class ServeEngine:
             window_tokens=window_blocks * c.block_size,
             latent_width=spec.latent_width,
             ring_tokens=ring_blocks * c.block_size,
-            ring_layers=ring_layers, max_requests=c.max_batch)
+            ring_layers=ring_layers, max_requests=c.max_batch,
+            state_layers=self._state_layers,
+            state_shapes=(((spec.ssm_heads, spec.ssm_head_dim,
+                            spec.ssm_state), jnp.float32),
+                          ((spec.ssm_conv - 1, spec.ssm_conv_width), None))
+            if self._state_layers else ())
+        # what a decode step reads and writes of it: every slot's
+        self._state_step_bytes = 2 * self.kv.state_nbytes()
         self.scheduler = Scheduler(self.kv, c.max_batch,
                                    admission=c.admission, clock=clock,
                                    draft_len=int(c.draft_len))
@@ -490,7 +541,9 @@ class ServeEngine:
         from ..kernels import registry
         from .layers import eva_info, grouped_info, paged_info
 
-        pool = jax.tree_util.tree_leaves(self.kv.caches[0][0])[0]
+        with_rows = next(i for i in range(cfg.num_layers)
+                         if i not in self._state_layers)
+        pool = jax.tree_util.tree_leaves(self.kv.caches[with_rows][0])[0]
         q_len = int(c.draft_len) + 1
         op = "eva_attention" if spec.attention == "eva" \
             else "paged_attention"
@@ -577,6 +630,12 @@ class ServeEngine:
                 "sessions over latent rows: a pin keeps rows that decode "
                 "wrote, and the next turn's prefill would expand them "
                 "beside rows of its own; not proven, so not offered")
+        if session_id is not None and self._state_layers:
+            raise NotImplementedError(
+                "sessions over layers with a state: a pin would have to "
+                "keep the state as the last turn left it beside the rows, "
+                "and a slot's state is zeroed when the next request is "
+                "seated; nothing stores a snapshot")
         if session_id is not None and self.kv.ring_blocks:
             raise NotImplementedError(
                 "sessions over grouped rows with sliding layers: a pin "
@@ -874,10 +933,18 @@ class ServeEngine:
             self._take_blocks(req, pos0, pos0 + n_valid)
         elif self.kv.ring_blocks:
             req.table = self.kv.extend(req.rid, pos0, pos0 + n_valid)
+        table = req.table
+        if self._state_layers:
+            if pos0 == 0:   # seated: the slot's last tenant's state goes
+                with phase("serve.state.reset", self._step_tracer()):
+                    self.kv.reset_state(req.slot)
+            # behind the table's entries: where the request's state lies
+            table = np.append(table, np.int32(req.slot))
+            COUNTERS.add("serve.ssm.prefill_tokens", nbytes=n_valid)
         tok, _logits, caches = self.programs["prefill"](
             self.params, self.kv.caches, jnp.asarray(tokens),
             np.int32(req.prefill_pos), np.int32(n_valid),
-            jnp.asarray(req.table), np.float32(req.temperature),
+            jnp.asarray(table), np.float32(req.temperature),
             np.int32(req.top_k), np.uint32(req.seed))
         self.kv.caches = caches
         req.prefill_pos += n_valid
@@ -956,10 +1023,16 @@ class ServeEngine:
             held = positions[slots].astype(np.int64) + 1
             COUNTERS.add("serve.mla.rows_read", calls=len(lanes),
                          nbytes=int(held.sum()))
-        elif self._window:
+        elif self._window or self._state_layers:
             self._count_grouped_rows(lanes)
         else:
             self._count_rows_walked(lanes, 1)
+        if self._state_layers:
+            # the program steps every slot's state, running or not
+            COUNTERS.add("serve.ssm.state_bytes",
+                         nbytes=self._state_step_bytes)
+            COUNTERS.add("serve.ssm.slots_live",
+                         nbytes=len(lanes) * len(self._state_layers))
         COUNTERS.add("serve.decode_ahead", nbytes=int(
             any(isinstance(u, _Step) for u in self._unread)))
         # the predicate `decode` picks its sampling tail by, from the
@@ -1088,9 +1161,11 @@ class ServeEngine:
                 state.set(req.slot, tables=req.table)
         held = positions[[r.slot for r in lanes]].astype(np.int64) + 1
         in_window = int(np.minimum(held, self._window).sum())
-        full_layers = self.model.config.num_layers - self._sliding_layers
-        COUNTERS.add("serve.window.rows_read", calls=len(lanes),
-                     nbytes=in_window)
+        full_layers = self.model.config.num_layers - self._sliding_layers \
+            - len(self._state_layers)
+        if self._window:
+            COUNTERS.add("serve.window.rows_read", calls=len(lanes),
+                         nbytes=in_window)
         COUNTERS.add("serve.attn.rows_read", calls=len(lanes),
                      nbytes=self._sliding_layers * in_window
                      + full_layers * int(held.sum()))

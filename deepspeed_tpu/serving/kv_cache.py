@@ -132,6 +132,24 @@ blocks.  The prefix cache, session pins, quantized rows and a mesh are
 not offered for such a cache (the engine refuses them by name); a model
 with one kind of layer is one group and sees none of this.
 
+A group of layers with a state and no rows (`state_layers`, a layer
+spec some of whose layers mix tokens by a state-space recurrence): such
+a layer's entry of `caches` is not rows of a pool but arrays BY SLOT,
+`state_shapes` says which — for a Mamba-2 layer a float32 state
+`[max_requests, heads, head_dim, state]` and the convolution's last
+inputs `[max_requests, taps - 1, conv_width]` at the cache's dtype.  No
+blocks, no table entries, no free list: a request's share is its slot's,
+fixed whatever its length, so the pool, `num_blocks`, `bytes_per_block`
+and admission count the other layers only, and with `max_batch` slots
+the state is what a deployment sizes (`state_nbytes`).  `free` leaves
+it as it lies — the loop runs a step ahead, and a step launched for the
+slot's last tenant may still be to run; `reset_state(slot)` zeroes a
+slot's entries on the device, in launch order behind whatever was
+launched before it, and the engine calls it when it seats a request,
+before its first prefill chunk.  The prefix cache, session pins,
+quantized rows and a mesh are not offered for such a cache (the engine
+refuses them by name).
+
 Block 0 is the reserved TRASH block: the allocator never hands it out,
 block tables are padded with it, and inactive decode slots write to it —
 so the jitted programs need no branches for "this slot/table entry is
@@ -263,7 +281,9 @@ class PagedKVCache:
                  prefix_cache: bool = True, min_match_blocks: int = 1,
                  prefix_salt: str = "", window_tokens: int = 0,
                  latent_width: int = 0, ring_tokens: int = 0,
-                 ring_layers: Sequence[int] = (), max_requests: int = 0):
+                 ring_layers: Sequence[int] = (), max_requests: int = 0,
+                 state_layers: Sequence[int] = (),
+                 state_shapes: Sequence[tuple] = ()):
         if num_blocks < 2:
             raise ValueError(
                 f"num_blocks must be >= 2 (block 0 is the reserved trash "
@@ -328,6 +348,27 @@ class PagedKVCache:
             raise ValueError(
                 "a cache of two groups of layers is dense, on one device, "
                 "with no prefix cache and one kind of row")
+        # the layers `state_layers` hold no rows: arrays by slot, of
+        # `state_shapes` ((shape a slot, dtype or None: the cache's), ...)
+        self.state_layers = frozenset(int(i) for i in state_layers)
+        self._state_order = tuple(sorted(self.state_layers))
+        self.state_shapes = tuple(state_shapes)
+        self.max_requests = int(max_requests)
+        if bool(self.state_layers) != bool(self.state_shapes) or (
+                self.state_layers and self.max_requests < 1):
+            raise ValueError(
+                f"layers with a state ({sorted(self.state_layers)}) need "
+                f"the shapes of what a slot keeps ({self.state_shapes}) "
+                f"and max_requests >= 1 slots ({max_requests})")
+        if self.state_layers and (
+                mode != "dense" or prefix_cache or self.windowed
+                or self.latent_width or self.ring_blocks
+                or mesh_info is not None and mesh_info.size > 1):
+            raise ValueError(
+                "a cache with a group of layers that keep a state a slot "
+                "is dense, on one device, with no prefix cache, one kind "
+                "of row and no ring")
+        self._reset_fn = None                     # lazy jitted slot zeroing
         # "int8"/"int4" when blocks are stored quantized, else None
         self.quant_wire = mode if mode in KV_QUANT_WIRES else None
         self.dense_dtype = dense_dtype
@@ -403,6 +444,19 @@ class PagedKVCache:
 
     def _init_caches(self):
         rows = self.num_blocks * self.block_size
+        if self.state_layers:
+            shape = (rows, pool_width(self.num_heads, self.head_dim))
+
+            def entry(i):
+                if i not in self.state_layers:
+                    return (jnp.zeros(shape, self.dense_dtype),
+                            jnp.zeros(shape, self.dense_dtype))
+                return tuple(
+                    jnp.zeros((self.max_requests,) + tuple(a_slot),
+                              dtype or self.dense_dtype)
+                    for a_slot, dtype in self.state_shapes)
+
+            return [entry(i) for i in range(self.num_layers)]
         if self.latent_width:
             shape = (rows, pool_width(1, self.latent_width))
             return [(jnp.zeros(shape, self.dense_dtype),)
@@ -446,9 +500,31 @@ class PagedKVCache:
         return sum(int(a.size) * a.dtype.itemsize
                    for a in jax.tree_util.tree_leaves(self.caches))
 
+    def state_nbytes(self) -> int:
+        """Device bytes of the layers that keep a state a slot: all
+        slots', whatever is seated."""
+        return sum(int(a.size) * a.dtype.itemsize
+                   for i in self.state_layers for a in self.caches[i])
+
     def bytes_per_block(self) -> int:
-        """Device bytes one block costs across all layers (K and V)."""
-        return self.nbytes() // self.num_blocks
+        """Device bytes one block costs across all layers with rows (K
+        and V)."""
+        return (self.nbytes() - self.state_nbytes()) // self.num_blocks
+
+    def reset_state(self, slot: int) -> None:
+        """Zero slot `slot`'s entries of every layer with a state, on
+        the device and in place: behind every program already launched,
+        before any launched after."""
+        if self._reset_fn is None:
+            self._reset_fn = jax.jit(
+                lambda states, slot: jax.tree_util.tree_map(
+                    lambda a: a.at[slot].set(0), states),
+                donate_argnums=(0,))
+        states = self._reset_fn([self.caches[i] for i in self._state_order],
+                                np.int32(slot))
+        for i, entry in zip(self._state_order, states):
+            self.caches[i] = entry
+        COUNTERS.add("serve.ssm.state_resets")
 
     # -- allocator ----------------------------------------------------
 
@@ -889,6 +965,11 @@ class PagedKVCache:
                      f"window in a ring of {self.ring_tokens} rows a "
                      f"request ({self.ring_pool_blocks} blocks of their "
                      f"own)")
+        if self.state_layers:
+            rows += (f" in {self.num_layers - len(self.state_layers)} "
+                     f"layer(s); {len(self.state_layers)} layer(s) with no "
+                     f"rows and a state a slot, {self.max_requests} slots "
+                     f"({self.state_nbytes() / (1 << 20):.2f} MiB)")
         return (f"PagedKVCache(layers={self.num_layers}, "
                 f"blocks={self.num_blocks} x {self.block_size} rows, {rows}, "
                 f"table_width={self.table_width}, " + (

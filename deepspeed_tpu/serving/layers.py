@@ -19,7 +19,10 @@ full layers have no positions and attend every cached row; one norm and
 x + attn(h) + ffn(h), the "parallel" residual, whose routed FFN reads
 the spec: the router's scoring, whether the chosen weights are
 renormalised, how the shared experts combine and which experts this
-chip holds).  The norm,
+chip holds), and no positions at all with grouped attention in some
+layers and a state-space mixer in the others (the Granite 4.0-H block:
+sequential, both branches scaled; `spec.layer_mixers` says which layer
+has which; below).  The norm,
 the FFN — a routed-experts FFN among them, behind leading dense layers —
 and the head are free of that choice.
 
@@ -55,6 +58,19 @@ the one group and mask the window on the whole table.  No kernel: the
 walk of kernels/paged.py takes one query head a K/V head, and the
 registry sends grouped rows to `jax.numpy` (`grouped_info`).
 
+Layers with a state and no rows (`spec.mixer_of(layer) == "ssm"`,
+models/granite_hybrid.py): such a layer's entry of the cache is not rows
+of a pool but two arrays BY SLOT — a float32 state `[slots, heads,
+head_dim, state]` and the convolution's last inputs `[slots, taps - 1,
+conv_width]` — and its block reads and writes the entries of the call's
+sequences: a prefill chunk its request's one (`Addr.slot`, which rides
+behind the request's table), a decode step every slot's.  The
+block moves both on by the call's valid positions (`Addr.n_valid`: a
+chunk's valid tokens; 1 for a running slot, 0 for any other, whose
+entries a step therefore hands back as it found them) and no term
+crosses slots.  Nothing here zeroes a state: the engine does, when a
+request is seated (serving/kv_cache.py `reset_state`).
+
 Addressing (`Addr`): a program works out once where this call's K/V land
 and what attention reads, and every layer's block uses it.  Paged: flat
 write rows, the block tables, query positions (negative for a slot that
@@ -77,6 +93,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import cohere2_moe
+from ..models.granite_hybrid import ssm_mix
 from ..models.deepseek_v2 import (absorb, attend_absorbed, attend_expanded,
                                   expert_ffn, latent_project, rms_norm_plain)
 from ..models.evabyte import (chunk_summaries, matmul32, project_qkv,
@@ -87,7 +104,7 @@ from ..moe.dropless import experts_touched
 from .kv_cache import pool_rows
 
 BUILT = {("learned", "paged"), ("rope", "eva"), ("rope", "latent"),
-         ("per_layer", "grouped")}
+         ("per_layer", "grouped"), ("none", "grouped")}
 
 
 def check_spec(spec: LayerSpec) -> LayerSpec:
@@ -100,13 +117,32 @@ def check_spec(spec: LayerSpec) -> LayerSpec:
     # front of a routed FFN that reads the spec; the sequential block's
     # routed FFN is models/deepseek_v2.py's one kind
     parallel = spec.residual == "parallel"
-    if (spec.attention == "grouped") != parallel or (
+    hybrid = spec.positions == "none"
+    if (spec.attention == "grouped") != (parallel or hybrid) or (
             parallel and spec.ffn != "routed_experts"):
         raise NotImplementedError(
             f"serving builds the parallel block over grouped attention "
-            f"and a routed_experts FFN, and grouped attention nowhere "
-            f"else; got a {spec.residual!r} residual with "
-            f"{spec.attention!r} attention and a {spec.ffn!r} FFN")
+            f"and a routed_experts FFN, and grouped attention elsewhere "
+            f"only in a model without positions; got a {spec.residual!r} "
+            f"residual with {spec.attention!r} attention, "
+            f"{spec.positions!r} positions and a {spec.ffn!r} FFN")
+    if hybrid and (parallel or spec.ffn != "silu_gated" or spec.layer_windows
+                   or spec.norm != "rmsnorm"):
+        raise NotImplementedError(
+            f"a model without positions is built as the sequential block "
+            f"with RMSNorm, a silu_gated FFN and no window; got a "
+            f"{spec.residual!r} residual, a {spec.norm!r} norm, a "
+            f"{spec.ffn!r} FFN and layer_windows {spec.layer_windows}")
+    scaled = (spec.embed_scale, spec.residual_scale, spec.logit_divisor,
+              spec.attn_scale) != (1.0, 1.0, 1.0, 0.0)
+    if (spec.has_state or scaled) and not hybrid:
+        raise NotImplementedError(
+            f"layers with a state-space mixer and the stream's scalars "
+            f"are built in the sequential block without positions; got "
+            f"{spec.positions!r} positions with layer_mixers "
+            f"{spec.layer_mixers} and scalars {spec.embed_scale}, "
+            f"{spec.residual_scale}, {spec.attn_scale}, "
+            f"{spec.logit_divisor}")
     if not parallel and (spec.scoring != "softmax" or spec.renormalize
                          or spec.shared != "sum" or spec.held):
         raise NotImplementedError(
@@ -130,16 +166,25 @@ class Addr(NamedTuple):
     ring_tables: Optional[jax.Array] = None  # ring: [B, ring blocks]
     ring_newest: Optional[jax.Array] = None  # ring: [B] newest position
     #                                          the call writes
+    slot: Optional[jax.Array] = None       # state: the one sequence's slot
+    #                                        (None: sequence b is slot b)
+    n_valid: Optional[jax.Array] = None    # state: [B] real positions of T
 
 
 # -- embedding --------------------------------------------------------------
+
+
+def _scaled(x, by: float):
+    """x times a scalar of the spec; x itself where that is 1."""
+    return x if by == 1.0 else x * by
 
 
 def embed_chunk(spec, params, tokens, abs_pos):
     """tokens [1, C] at positions abs_pos [C] (prefill) or [R, T] at
     [R, T] (verify) -> x.  Rows past the position table clamp."""
     if spec.positions != "learned":
-        return params["wte"][tokens].astype(jnp.float32)
+        return _scaled(params["wte"][tokens].astype(jnp.float32),
+                       spec.embed_scale)
     # per-row gather, NOT dynamic_slice_in_dim(wpe, pos, C): when the
     # final chunk's pad rows run past the wpe table, a dynamic slice
     # CLAMPS its start backwards and shifts the VALID rows onto wrong
@@ -156,7 +201,8 @@ def embed_chunk(spec, params, tokens, abs_pos):
 def embed_step(spec, params, tokens, positions):
     """tokens [R] at positions [R] (decode) -> x [R, 1, D]."""
     if spec.positions != "learned":
-        return params["wte"][tokens].astype(jnp.float32)[:, None, :]
+        return _scaled(params["wte"][tokens].astype(jnp.float32),
+                       spec.embed_scale)[:, None, :]
     return (params["wte"][tokens] +
             params["wpe"][positions])[:, None, :]
 
@@ -168,6 +214,10 @@ def address_chunk(spec, s, table, pos, abs_pos, n_valid) -> Addr:
     """One request's prefill chunk at positions abs_pos [C] through its
     table [W]."""
     bs, W = s.block_size, s.table_width
+    if spec.has_state:      # behind the table's entries: the slot
+        return _address_grouped(
+            s, table[None, :-1], abs_pos[None, :], abs_pos[None, -1]
+        )._replace(slot=table[-1], n_valid=n_valid[None])
     if spec.attention == "grouped":
         return _address_grouped(s, table[None, :], abs_pos[None, :],
                                 abs_pos[None, -1])
@@ -205,8 +255,11 @@ def address_step(spec, s, tables, positions, active) -> Addr:
     Inactive slots write to the trash block."""
     bs, W = s.block_size, s.table_width
     if spec.attention == "grouped":
-        return _address_grouped(
+        addr = _address_grouped(
             s, tables, jnp.where(active, positions, -1)[:, None], positions)
+        if not spec.has_state:
+            return addr
+        return addr._replace(n_valid=active.astype(jnp.int32))
     if spec.attention != "eva":
         blk_i = positions // bs
         blk = jnp.take_along_axis(
@@ -258,6 +311,11 @@ def _address_grouped(s, tables, pos, newest) -> Addr:
 def address_grid(spec, s, tables, abs_pos, active, n_draft) -> Addr:
     """T candidate tokens for every slot at abs_pos [R, T] (verify);
     rows past a slot's `n_draft` candidates write to the trash block."""
+    if spec.has_state:
+        raise NotImplementedError(
+            "the verify program over layers with a state is not built: a "
+            "rejected draft would have moved the state on, and nothing "
+            "keeps the state it would have to be rewound to")
     if spec.attention != "paged":
         raise NotImplementedError(
             "the verify program over summarised windows is not built: a "
@@ -456,8 +514,25 @@ def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
         newest = addr.ring_newest[:, None]
         at = newest - (newest - at) % L                      # [B, L]
     out = cohere2_moe.attend_grouped(
-        q, keys, vals, _visible(at, addr.q_pos, window))
+        q, keys, vals, _visible(at, addr.q_pos, window),
+        scale=spec.attn_scale or None)
     return matmul32(out, p["o"]), ck, cv
+
+
+def _ssm_mix(spec, p, h, state, conv, addr):
+    """The state-space mixer over the call's sequences: their entries of
+    the layer's state and convolution inputs — slot `addr.slot`'s for
+    the one sequence of a prefill chunk, every slot's in a decode step —
+    moved on by `addr.n_valid` positions each and written back in place.
+    -> float32."""
+    if addr.slot is None:
+        return ssm_mix(spec, p, h, state, conv, addr.n_valid)
+    take = lambda a: jax.lax.dynamic_slice_in_dim(a, addr.slot, 1)
+    put = lambda a, new: jax.lax.dynamic_update_slice_in_dim(
+        a, new, addr.slot, 0)
+    out, new_state, new_conv = ssm_mix(spec, p, h, take(state), take(conv),
+                                       addr.n_valid)
+    return out, put(state, new_state), put(conv, new_conv)
 
 
 def _visible(at, q_pos, window: int):
@@ -513,17 +588,25 @@ def block(spec, cfg, p, x, kv, addr, s, layer: int = 0):
     if spec.residual == "parallel":
         return _parallel_block(spec, cfg, p, x, kv, addr, s, layer)
     h = _norm(spec, x, p["ln1"])
-    if spec.attention == "paged":
+    if spec.mixer_of(layer) == "ssm":
+        with jax.named_scope("ssm.step" if h.shape[1] == 1 else "ssm.scan"):
+            attn, *kv = _ssm_mix(spec, p["ssm"], h, *kv, addr)
+    elif spec.attention == "grouped":
+        with jax.named_scope("full_attend"):
+            attn, *kv = _grouped_attend(spec, cfg, p["attn"], h, *kv, addr,
+                                        s, layer)
+    elif spec.attention == "paged":
         attn, *kv = _paged_attend(cfg, p["attn"], h, *kv, addr, s)
     elif spec.attention == "eva":
         attn, *kv = _eva_attend(spec, cfg, p["attn"], h, *kv, addr, s)
     else:
         with jax.named_scope("mla_attend"):
             attn, *kv = _latent_attend(cfg, p["attn"], h, *kv, addr, s)
-    x = x + attn
+    x = x + _scaled(attn, spec.residual_scale)
     h = _norm(spec, x, p["ln2"])
     if spec.ffn != "routed_experts" or layer < spec.dense_layers:
-        return x + _ffn(spec, p["mlp"], h), tuple(kv), None
+        return (x + _scaled(_ffn(spec, p["mlp"], h), spec.residual_scale),
+                tuple(kv), None)
     live = addr.q_pos.reshape(-1) >= 0
     y, idx = expert_ffn(cfg, p["mlp"], h, live)
     touched = experts_touched(idx, live, p["mlp"]["router"].shape[1])
@@ -543,11 +626,13 @@ def logits(spec, params, x_rows):
     at full precision."""
     w = params["wte"].T if spec.head == "tied" else params["lm_head"]
     if spec.fp32_logits:
-        return jnp.dot(x_rows, w.astype(jnp.float32),
-                       precision=jax.lax.Precision.HIGHEST)
-    if x_rows.dtype != w.dtype:  # float32 rows of a bf16 model: multiply
-        return matmul32(x_rows, w)  # at the head's dtype, do not copy it
-    return (x_rows @ w.astype(x_rows.dtype)).astype(jnp.float32)
+        out = jnp.dot(x_rows, w.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+    elif x_rows.dtype != w.dtype:  # float32 rows of a bf16 model: multiply
+        out = matmul32(x_rows, w)  # at the head's dtype, do not copy it
+    else:
+        out = (x_rows @ w.astype(x_rows.dtype)).astype(jnp.float32)
+    return out if spec.logit_divisor == 1.0 else out / spec.logit_divisor
 
 
 def sampled(spec, row_logits):

@@ -62,6 +62,17 @@ group]` and the sliding layers' blocks address their ring by position
 appends to its tokens counts the experts touched among those held.
 `verify`, quantized weights and quantized rows are not built for it.
 
+A spec some of whose layers mix tokens by a state-space recurrence
+(`layer_mixers`; grouped attention without positions in the others)
+runs the same two programs: such a layer's entry of `caches` is its
+state and its convolution's last inputs BY SLOT, donated and written in
+place with the rows; `prefill` is told its request's slot by one more
+entry behind the table's, `decode` steps every slot's state and leaves a
+slot that is not running as it found it (serving/layers.py).  Zeroing a
+slot for a new request is the cache's (`PagedKVCache.reset_state`), not
+a program built here.  `verify`, quantized weights and quantized rows
+are not built for it.
+
 A spec with "eva" attention (exact rows for an open window, summary
 rows behind it) runs the same two programs over a table of
 `[window blocks | summary blocks]`; the block also writes the summary
@@ -349,6 +360,8 @@ class ServeProgramBuilder:
             self._check_eva(schedule)
         if self.spec.attention == "latent":
             self._check_latent(schedule)
+        if self.spec.has_state:
+            self._check_state(schedule)
         if self.spec.attention == "grouped":
             self._check_grouped(schedule)
         self.model = model
@@ -419,6 +432,34 @@ class ServeProgramBuilder:
                 f"kv_dtype {s.kv_dtype!r} over grouped rows: the row "
                 f"codecs are read by the paged gather, and the grouped "
                 f"gather over a ring is not built for their scales")
+
+    def _check_state(self, s: ServeSchedule) -> None:
+        """What the programs over layers with a state need of a
+        schedule, and what is not built for them."""
+        if s.draft_len:
+            raise NotImplementedError(
+                "draft_len > 0 over layers with a state: a rejected draft "
+                "has moved the state on, and nothing keeps the state it "
+                "would have to be rewound to; the verify program is not "
+                "built for them")
+        if s.quantized != "none":
+            raise NotImplementedError(
+                "quantized_weights over layers with a state: the qwZ store "
+                "is written for the GPT parameter tree's matmul leaves and "
+                "would quantize the convolution's taps with them; not "
+                "proven, so not offered")
+        if s.kv_dtype != "dense":
+            raise NotImplementedError(
+                f"kv_dtype {s.kv_dtype!r} over layers with a state: the "
+                f"row codecs are read by the paged gather, and a state "
+                f"kept in fewer bits accumulates its rounding at every "
+                f"token; neither is built")
+        chunk = self.spec.ssm_chunk
+        if s.prefill_chunk > chunk and s.prefill_chunk % chunk:
+            raise ValueError(
+                f"prefill_chunk must be at most the model's scan chunk "
+                f"({chunk}) or whole chunks of it (the scan takes a prefill "
+                f"chunk {chunk} positions at a time), got {s.prefill_chunk}")
 
     @staticmethod
     def _check_latent(s: ServeSchedule) -> None:

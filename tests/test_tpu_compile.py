@@ -746,6 +746,73 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     assert total < 15.75e9 - 2.15e9 - 0.5e9, total
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_granite_hybrid_cell_programs_compile_inside_one_chip(program,
+                                                              one_chip,
+                                                              native):
+    """`granite-4.0-h-micro.serve.chatrate.decode` / `.prefill` at the
+    cell's shapes (all 40 layers at published widths in bf16, 64 slots,
+    8,193 blocks of 16 rows for the 4 attention layers, a float32 state
+    `[64, 64, 64, 128]` and `[64, 3, 4352]` convolution inputs for each
+    of the 36 state-space layers, chunk 512): no custom call (grouped
+    rows go to `jax.numpy`, the scan is `jax.numpy`), every state enters
+    and leaves under its own shape — updated in place, not copied beside
+    itself — and weights, rows, state and temporaries fit the chip's
+    15.75 GB with room for the check's 0.82 GB of reference logits and
+    its float32 layer."""
+    from deepspeed_tpu.models import GraniteHybrid, GraniteHybridConfig
+    from deepspeed_tpu.serving import ServeProgramBuilder, ServeSchedule
+
+    slots, bs, nblocks, chunk, seq = 64, 16, 8193, 512, 2048
+    model = GraniteHybrid(GraniteHybridConfig(
+        max_seq_len=seq, param_dtype=jnp.bfloat16))
+    spec, cfg = model.layer_spec(), model.config
+    width = seq // bs
+    sched = ServeSchedule(max_batch=slots, prefill_chunk=chunk,
+                          block_size=bs, num_blocks=nblocks,
+                          table_width=width)
+    progs = ServeProgramBuilder(model, sched).build()
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda s: on(s.shape, s.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    held = sum(s.size * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    assert abs(held - 6.383e9) < 0.001e9
+    rows = on((nblocks * bs, 512), jnp.bfloat16)
+    state = (on((slots, 64, 64, 128), jnp.float32),
+             on((slots, 3, 4352), jnp.bfloat16))
+    caches = [state if spec.mixer_of(i) == "ssm" else (rows, rows)
+              for i in range(cfg.num_layers)]
+    nbytes = lambda c: sum(a.size * a.dtype.itemsize for a in c)
+    assert abs(sum(nbytes(c) for c in caches if c is state) - 4.89e9) < 0.01e9
+    assert abs(sum(nbytes(c) for c in caches if c is not state)
+               - 1.07e9) < 0.01e9
+    if program == "decode":
+        args = (on((slots,), jnp.int32), on((slots,), jnp.int32),
+                on((slots,), jnp.bool_), on((slots, width), jnp.int32),
+                on((slots,), jnp.float32), on((slots,), jnp.int32),
+                on((slots,), jnp.uint32))
+    else:   # behind the table's entries: the slot
+        args = (on((1, chunk), jnp.int32), on((), jnp.int32),
+                on((), jnp.int32), on((width + 1,), jnp.int32),
+                on((), jnp.float32), on((), jnp.int32), on((), jnp.uint32))
+    compiled = progs[program].lower(params, caches, *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    m = compiled.memory_analysis()
+    # all 72 state arrays and 8 pools are donated and aliased
+    assert m.alias_size_in_bytes > 5.9e9
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + \
+        m.output_size_in_bytes - m.alias_size_in_bytes
+    print(program, "temp", m.temp_size_in_bytes / 1e9, "total", total / 1e9)
+    assert m.temp_size_in_bytes < 1024 << 20
+    assert total < 15.75e9 - 0.82e9 - 1.0e9, total
+
+
 def test_evabyte_phase_after_the_described_compiles(topo):
     """This file, then tests/test_chip_smoke.py::test_evabyte_phase_toy,
     in one process: the order in which the toy EvaByte run chose bytes
