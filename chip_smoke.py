@@ -445,6 +445,7 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                   eva_summary_blocks=64,
                   eva_positions=(0, 100, 2047, 2048, 9000, 12345, 16383, -1),
                   routed_shape=(32, 64, 2048, 1408), routed_live=12,
+                  ssm_shape=(64, 64, 64, 128), ssm_live=37,
                   on_chip=True) -> dict:
     """Each kernel `auto` selects on this chip, once, natively, at the
     main path's shapes, against its jnp oracle at tier-1's tolerance
@@ -670,6 +671,40 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
     if int(dropless.experts_touched(idx, alive, E)) in (0, E) or \
             np.asarray(got)[routed_live:].any():
         raise RuntimeError("the routed check's dead slots touched experts")
+
+    # the state-space recurrence of a decode step at the chatrate cell's
+    # shape: 64 slots of which 37 run, scattered; the others' state
+    # comes back bit for bit and their y is zeros
+    from deepspeed_tpu.kernels.ssm import live_slots, ssm_step_info
+    from deepspeed_tpu.models.granite_hybrid import ssm_step
+
+    B, H, P, N = ssm_shape
+    runs = jnp.zeros((B,), bool).at[
+        jax.random.permutation(key[6], B)[:ssm_live]].set(True)
+    sx, sB, sC = (jax.random.normal(k, shape, jnp.float32)
+                  for k, shape in zip(key[:3], ((B, H, P), (B, N), (B, N))))
+    dt = jax.random.uniform(key[3], (B, H), minval=0.001, maxval=0.1) * runs[
+        :, None]
+    A = -jax.random.uniform(key[4], (H,), minval=1.0, maxval=16.0)
+    state = jax.random.normal(key[5], (B, H, P, N), jnp.float32)
+    info = ssm_step_info(state)
+    chosen = registry.resolve_impl("ssm_step", info=info)
+    if on_chip and chosen != "pallas":
+        raise RuntimeError(f"auto resolved the recurrence over a state of "
+                           f"{ssm_shape} to {chosen!r} on this chip")
+    got = jax.jit(lambda *a: registry.dispatch("ssm_step", *a, info=info))(
+        sx, sB, sC, dt, A, state, *live_slots(runs))
+    want = jax.jit(ssm_step)(sx, sB, sC, dt, A, state)
+    out.append(_close(f"ssm_step_B{B}_H{H}_P{P}_N{N}_live{ssm_live}",
+                      list(ssm_shape), jax.tree_util.tree_map(
+                          lambda a: a[runs], got),
+                      jax.tree_util.tree_map(lambda a: a[runs], want),
+                      rtol=1e-5, atol=1e-4))
+    rest = ~np.asarray(runs)
+    if chosen == "pallas" and (np.asarray(got[0])[rest].any() or not
+                               np.array_equal(np.asarray(got[1])[rest],
+                                              np.asarray(state)[rest])):
+        raise RuntimeError("the recurrence touched a slot that does not run")
     return {"phase": "kernels", "native": not pallas_backend.interpret(),
             "kernels": out}
 

@@ -20,7 +20,7 @@ Every hot inner loop that has a Pallas implementation registers a
 
     call site (ops/transformer/attention.py, serving/layers.py,
                runtime/comm/quant.py, moe/dispatch.py, moe/dropless.py,
-               ops/sparse_attention/)
+               models/granite_hybrid.py, ops/sparse_attention/)
        └─> dispatch(op, *args, info=<shape facts of this call>)
               └─> pallas  iff  TPU backend  and  op.auto_supports(info)
                                and  partitionable here
@@ -476,11 +476,52 @@ class TouchedExpertsOp(KernelOp):
         return dropless.experts_weighted(x, experts, w)
 
 
+class SsmStepOp(KernelOp):
+    """The Mamba-2 recurrence of a decode step (models/granite_hybrid.py
+    `ssm_mix`, one token a slot).  Pallas = a walk of the step's live
+    slots that reads and writes only their state, in place
+    (kernels/ssm.py); oracle = `ssm_step`, every slot's state through
+    the same expression, a slot that is not live under dt = 0.  The
+    shape rule looks at the state's four sizes (`kernels/ssm.py::
+    ssm_step_info`)."""
+
+    NAME = "ssm_step"
+
+    def auto_supports(self, variant, info):
+        if not info:
+            return True, ""
+        from .ssm import head_tile
+
+        P, N, item = (int(info[k]) for k in ("head_dim", "state",
+                                             "itemsize"))
+        if item != 4 or P % 8 or N % 128:
+            return False, (f"a head's state of {P} x {N} values of {item} "
+                           f"bytes is not whole (8, 128) tiles of float32")
+        th = head_tile(int(info["heads"]), P, N)
+        if not th:
+            return False, (f"one head's state of {P} x {N} float32, read "
+                           f"and written and double-buffered, does not fit "
+                           f"the kernel's VMEM")
+        if th * P % 128:
+            return False, (f"the {th} heads of {P} rows a block that fit "
+                           f"the kernel's VMEM are not whole tiles of 128 "
+                           f"rows")
+        return True, ""
+
+    def pallas(self, variant, *args, **kwargs):
+        from . import ssm
+        return ssm.ssm_step_pallas(*args, **kwargs)
+
+    def oracle(self, variant, x, Bm, Cm, dt, A, state, ids, n):
+        from ..models.granite_hybrid import ssm_step
+        return ssm_step(x, Bm, Cm, dt, A, state)
+
+
 KERNEL_OPS: Dict[str, KernelOp] = {
     op.NAME: op for op in (FlashAttentionOp(), SparseAttentionOp(),
                            PagedAttentionOp(), EvaAttentionOp(),
                            QuantCodecOp(), MoEDispatchOp(),
-                           TouchedExpertsOp())
+                           TouchedExpertsOp(), SsmStepOp())
 }
 
 

@@ -32,7 +32,9 @@ Decays, D_t and the state are float32.  Such a layer keeps, for a
 request, H and the convolution's last K - 1 inputs and nothing else:
 `ssm_mix` takes both and hands both back, moved on by the call's valid
 positions — by the recurrence itself where the call is one token
-(`ssm_step`: decode), by the chunked form of it where it is many
+(`ssm_step`: decode; on a TPU the kernel of kernels/ssm.py, which steps
+the sequences with a valid position and touches no other's state), by
+the chunked form of it where it is many
 (`ssm_scan`: a prefill chunk, `ssm_chunk` positions at a time — inside a
 chunk the scores C_t . B_s under the cumulative decay, plus the incoming
 state decayed to each position; the outgoing state the incoming one
@@ -166,12 +168,16 @@ def ssm_scan(x, Bm, Cm, dt, A, state, chunk: int):
     return jnp.moveaxis(y, 0, 1).reshape(B, T, H, P), state
 
 
-def ssm_mix(spec, p, h, state, conv, n_valid):
+def ssm_mix(spec, p, h, state, conv, n_valid, live=None):
     """The Mamba-2 mixer over h [B, T, D] (normed) from a request's
     `state` [B, H, P, N] float32 and the convolution's last inputs
     `conv` [B, K - 1, conv_width]; `n_valid` [B]: how many of the T
     positions are real.  -> (out [B, T, D] float32, state, conv), both
-    moved on by the valid positions and by nothing else."""
+    moved on by the valid positions and by nothing else.  Where T is 1
+    the recurrence goes through the kernel registry's `ssm_step`, which
+    may walk `live` — `kernels/ssm.py::live_slots(n_valid)`, worked out
+    here unless the caller has it for all its layers — and leave every
+    other sequence's state where it lies."""
     B, T, _ = h.shape
     H, P, N = spec.ssm_heads, spec.ssm_head_dim, spec.ssm_state
     K, d_in = spec.ssm_conv, spec.ssm_heads * spec.ssm_head_dim
@@ -189,12 +195,18 @@ def ssm_mix(spec, p, h, state, conv, n_valid):
         s, n, K - 1, axis=0))(seq, n_valid).astype(conv.dtype)
     x, Bm, Cm = jnp.split(c, [d_in, d_in + N], axis=-1)
     x = x.reshape(B, T, H, P)
-    live = jnp.arange(T)[None, :] < n_valid[:, None]
-    dt = jnp.where(live[..., None], jax.nn.softplus(
+    valid = jnp.arange(T)[None, :] < n_valid[:, None]
+    dt = jnp.where(valid[..., None], jax.nn.softplus(
         dt + p["dt_bias"].astype(jnp.float32)), 0.0)
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
     if T == 1:
-        y, state = ssm_step(x[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], A, state)
+        from ..kernels import registry
+        from ..kernels.ssm import live_slots, ssm_step_info
+
+        y, state = registry.dispatch(
+            "ssm_step", x[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], A, state,
+            *(live_slots(n_valid) if live is None else live),
+            info=ssm_step_info(state))
         y = y[:, None]
     else:
         y, state = ssm_scan(x, Bm, Cm, dt, A, state, min(spec.ssm_chunk, T))

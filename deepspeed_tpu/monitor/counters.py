@@ -156,7 +156,10 @@ Instrumented sites:
   all from what the host knows: `serve.ssm.state_bytes` — calls =
   decode steps, bytes = state (the float32 state and the convolution's
   kept inputs, every such layer) the step's program reads and writes as
-  it is built: every slot's, twice; `serve.ssm.slots_live` — calls =
+  it is built, which the engine asks `kernels/registry.py` once: where
+  the recurrence is the `ssm_step` kernel, the running slots' float32
+  state twice and every slot's kept inputs twice; where it is the
+  oracle, every slot's of both, twice; `serve.ssm.slots_live` — calls =
   decode steps, bytes = running slots x layers with a state (times a
   layer's bytes a slot: what the live slots need); `serve.ssm.
   prefill_tokens` — calls = prefill chunks, bytes = valid tokens

@@ -177,8 +177,12 @@ the state as it stood at the prefix's end, and nothing stores one),
 `draft_len > 0` (a rejected draft would have to rewind the state),
 quantized weights, int8/int4 rows and a mesh of more than one device.
 Counters, from what the host knows: `serve.ssm.state_bytes` (calls =
-decode steps, bytes = state the step's program reads and writes: every
-slot's, twice, as the program is built), `serve.ssm.slots_live` (calls
+decode steps, bytes = state the step's program reads and writes, as
+the program is built — decided once, from what `kernels/registry.py`
+answers for the decode program's shapes: where the recurrence is the
+`ssm_step` kernel, the float32 state of the RUNNING slots twice and
+every slot's convolution inputs twice; where it is the oracle, every
+slot's of both, twice), `serve.ssm.slots_live` (calls
 = decode steps, bytes = running slots x layers with a state),
 `serve.ssm.prefill_tokens` (calls = prefill chunks, bytes = valid
 tokens scanned), `serve.ssm.state_resets` (calls = slots zeroed) and
@@ -522,8 +526,22 @@ class ServeEngine:
                             spec.ssm_state), jnp.float32),
                           ((spec.ssm_conv - 1, spec.ssm_conv_width), None))
             if self._state_layers else ())
-        # what a decode step reads and writes of it: every slot's
+        # what a decode step reads and writes of it: every slot's — less,
+        # where the registry answers that the recurrence walks the live
+        # slots (asked once, for the decode program's shapes), the
+        # float32 state `_state_dead_bytes` of each slot that is not
+        # running; the convolution's inputs stay every slot's
         self._state_step_bytes = 2 * self.kv.state_nbytes()
+        self._state_dead_bytes = 0
+        if self._state_layers:
+            from ..kernels import registry
+            from ..kernels.ssm import ssm_step_info
+
+            states = [self.kv.caches[i][0] for i in self._state_layers]
+            if registry.resolve_impl(
+                    "ssm_step", info=ssm_step_info(states[0])) == "pallas":
+                self._state_dead_bytes = 2 * sum(
+                    a.nbytes for a in states) // c.max_batch
         self.scheduler = Scheduler(self.kv, c.max_batch,
                                    admission=c.admission, clock=clock,
                                    draft_len=int(c.draft_len))
@@ -1028,9 +1046,11 @@ class ServeEngine:
         else:
             self._count_rows_walked(lanes, 1)
         if self._state_layers:
-            # the program steps every slot's state, running or not
+            # what the program as built streams: every slot's state,
+            # running or not, or the running slots' alone
             COUNTERS.add("serve.ssm.state_bytes",
-                         nbytes=self._state_step_bytes)
+                         nbytes=self._state_step_bytes - self._state_dead_bytes
+                         * (self.config.max_batch - len(lanes)))
             COUNTERS.add("serve.ssm.slots_live",
                          nbytes=len(lanes) * len(self._state_layers))
         COUNTERS.add("serve.decode_ahead", nbytes=int(

@@ -68,8 +68,12 @@ behind the request's table), a decode step every slot's.  The
 block moves both on by the call's valid positions (`Addr.n_valid`: a
 chunk's valid tokens; 1 for a running slot, 0 for any other, whose
 entries a step therefore hands back as it found them) and no term
-crosses slots.  Nothing here zeroes a state: the engine does, when a
-request is seated (serving/kv_cache.py `reset_state`).
+crosses slots.  A decode step also lists its running slots once
+(`Addr.live`, kernels/ssm.py `live_slots`), for every such layer: where
+the registry picks the `ssm_step` kernel the recurrence walks that list
+and a slot that is not on it has its state neither read nor written.
+Nothing here zeroes a state: the engine does, when a request is seated
+(serving/kv_cache.py `reset_state`).
 
 Addressing (`Addr`): a program works out once where this call's K/V land
 and what attention reads, and every layer's block uses it.  Paged: flat
@@ -93,6 +97,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import cohere2_moe
+from ..kernels.ssm import live_slots
 from ..models.granite_hybrid import ssm_mix
 from ..models.deepseek_v2 import (absorb, attend_absorbed, attend_expanded,
                                   expert_ffn, latent_project, rms_norm_plain)
@@ -169,6 +174,8 @@ class Addr(NamedTuple):
     slot: Optional[jax.Array] = None       # state: the one sequence's slot
     #                                        (None: sequence b is slot b)
     n_valid: Optional[jax.Array] = None    # state: [B] real positions of T
+    live: Optional[tuple] = None           # state, decode: (the running
+    #                                        slots [B], their count)
 
 
 # -- embedding --------------------------------------------------------------
@@ -259,7 +266,9 @@ def address_step(spec, s, tables, positions, active) -> Addr:
             s, tables, jnp.where(active, positions, -1)[:, None], positions)
         if not spec.has_state:
             return addr
-        return addr._replace(n_valid=active.astype(jnp.int32))
+        # the live list, once for every state-space layer of the step
+        return addr._replace(n_valid=active.astype(jnp.int32),
+                             live=live_slots(active))
     if spec.attention != "eva":
         blk_i = positions // bs
         blk = jnp.take_along_axis(
@@ -526,7 +535,7 @@ def _ssm_mix(spec, p, h, state, conv, addr):
     moved on by `addr.n_valid` positions each and written back in place.
     -> float32."""
     if addr.slot is None:
-        return ssm_mix(spec, p, h, state, conv, addr.n_valid)
+        return ssm_mix(spec, p, h, state, conv, addr.n_valid, addr.live)
     take = lambda a: jax.lax.dynamic_slice_in_dim(a, addr.slot, 1)
     put = lambda a, new: jax.lax.dynamic_update_slice_in_dim(
         a, new, addr.slot, 0)

@@ -78,14 +78,15 @@ def test_kernels_phase_toy():
                                    eva_chunk=4, eva_summary_blocks=8,
                                    eva_positions=(0, 31, 32, 100, -1),
                                    routed_shape=(16, 16, 128, 256),
-                                   routed_live=2, on_chip=False)
+                                   routed_live=2, ssm_shape=(6, 4, 8, 16),
+                                   ssm_live=3, on_chip=False)
     assert [k["kernel"] for k in out["kernels"]] == [
         "flash_attention_fwd", "flash_attention_bwd",
         "flash_attention_full_bias_dropout_fwd",
         "flash_attention_full_bias_dropout_bwd", "fused_xent_fwd",
         "fused_xent_bwd", "paged_attention_dense_H5_Dh64",
         "paged_attention_dense_H2_Dh128", "eva_attention_bf16_H2_Dh64",
-        "touched_experts_bf16_T16_E16"]
+        "touched_experts_bf16_T16_E16", "ssm_step_B6_H4_P8_N16_live3"]
 
 
 def test_four_chip_phase_on_four_virtual_devices():

@@ -67,8 +67,11 @@ _BUILT = {}
 def _engine(model, params, **kw):
     """An engine on programs built once for each (model config, serve
     config): the weights are arguments, so engines share them."""
+    from deepspeed_tpu.kernels import get_kernel_config
+
     serve = _serve(**kw)
-    key = (repr(model.config), repr(serve))
+    # a program keeps the kernels it was traced with
+    key = (repr(model.config), repr(serve), repr(get_kernel_config()))
     eng = ServeEngine(model, params, serve, programs=_BUILT.get(key))
     _BUILT[key] = eng.programs
     return eng
@@ -254,6 +257,79 @@ def test_a_decode_step_has_no_term_across_slots():
         np.testing.assert_array_equal(c1[slot], c2[slot])
 
 
+# -- the recurrence over the live slots (kernels/ssm.py) ----------------------
+
+
+def _forced():
+    """The registry's own override: the `ssm_step` kernel, under the
+    Pallas interpreter."""
+    from deepspeed_tpu.kernels import kernel_config
+
+    return kernel_config(ops={"ssm_step": "pallas"}, interpret=True)
+
+
+@pytest.mark.parametrize("live", [
+    (0, 0, 0, 0, 0, 0), (0, 0, 0, 1, 0, 0), (1, 1, 1, 1, 1, 1),
+    (1, 0, 1, 1, 0, 1), (0, 1, 0, 0, 1, 0), (0, 0, 0, 0, 0, 1)],
+    ids=["none", "one", "all", "scattered", "two", "last"])
+@pytest.mark.parametrize("tiles", [1, 2])
+def test_the_kernel_steps_the_live_slots_and_no_others(live, tiles,
+                                                       monkeypatch):
+    """Against `ssm_step` under dt = 0 for a slot that is not live: the
+    live slots' state and y to float32 tolerance, every other slot's
+    state the input's bit for bit and its y zeros — with a slot's state
+    as one block, and as two tiles of heads."""
+    from deepspeed_tpu.kernels import registry, ssm
+
+    if tiles > 1:
+        monkeypatch.setattr(ssm, "_STATE_BLOCK_BYTES",
+                            4 * (SH // tiles) * SP * SN * 4)
+    assert ssm.head_tile(SH, SP, SN) == SH // tiles
+    x, Bm, Cm, dt, A, state = _mixer_inputs(1, B=6)
+    x, Bm, Cm, dt = (a[:, 0] for a in (x, Bm, Cm, dt))
+    on = np.asarray(live, bool)
+    dt = dt * jnp.asarray(on, jnp.float32)[:, None]
+    ids, n = ssm.live_slots(jnp.asarray(live))
+    assert int(n) == on.sum()
+    assert list(np.asarray(ids)[:on.sum()]) == list(np.flatnonzero(on))
+    assert set(np.asarray(ids)[on.sum():]) <= {
+        int(np.flatnonzero(on)[-1]) if on.any() else 0}
+    want_y, want_state = gh.ssm_step(x, Bm, Cm, dt, A, state)
+    with _forced():
+        y, got = jax.jit(lambda *a: registry.dispatch(
+            "ssm_step", *a, info=ssm.ssm_step_info(state)))(
+                x, Bm, Cm, dt, A, state, ids, n)
+    y, got = np.asarray(y), np.asarray(got)
+    np.testing.assert_array_equal(got[~on], np.asarray(state)[~on])
+    np.testing.assert_array_equal(y[~on], 0.0)
+    np.testing.assert_allclose(got[on], np.asarray(want_state)[on],
+                               atol=1e-6, rtol=1e-6)
+    np.testing.assert_allclose(y[on], np.asarray(want_y)[on], atol=1e-5,
+                               rtol=1e-5)
+
+
+def test_the_kernel_is_chosen_by_what_the_call_shows():
+    """Off a TPU, and for a state that is not whole float32 tiles, the
+    recurrence is `ssm_step` itself; forced, the registry says why."""
+    from deepspeed_tpu.kernels import registry
+    from deepspeed_tpu.kernels.ssm import ssm_step_info
+
+    cell = ssm_step_info(jax.ShapeDtypeStruct((64, 64, 64, 128),
+                                              jnp.float32))
+    toy = ssm_step_info(jax.ShapeDtypeStruct((3, SH, SP, SN), jnp.float32))
+    op = registry.get_kernel("ssm_step")
+    assert op.auto_supports("default", cell) == (True, "")
+    assert not op.auto_supports("default", toy)[0]
+    assert registry.resolve_impl("ssm_step", info=cell) == "jnp"   # the CPU
+    with pytest.raises(RuntimeError, match="backend is 'cpu'"):
+        registry.resolve_impl("ssm_step", impl="pallas", info=cell)
+
+
+def test_a_decode_step_has_no_term_across_slots_through_the_kernel():
+    with _forced():
+        test_a_decode_step_has_no_term_across_slots()
+
+
 # -- through the programs -----------------------------------------------------
 
 
@@ -379,6 +455,11 @@ def test_prefill_then_decode_matches_the_reference_forward(dtype):
                                                   else TOL[dtype])
 
 
+def test_prefill_then_decode_matches_the_reference_forward_through_the_kernel():
+    with _forced():
+        test_prefill_then_decode_matches_the_reference_forward("float32")
+
+
 def _alone(model, params, prompt, n, **kw):
     return _engine(model, params, **kw).generate([prompt], n)[0]
 
@@ -421,6 +502,11 @@ def test_a_slots_last_tenant_does_not_leak(monkeypatch):
     assert d["serve.decode_ahead.dropped"]["calls"] >= 1
     monkeypatch.setattr(PagedKVCache, "reset_state", lambda self, slot: None)
     assert serve_all()[0] != want
+
+
+def test_a_slots_last_tenant_does_not_leak_through_the_kernel(monkeypatch):
+    with _forced():
+        test_a_slots_last_tenant_does_not_leak(monkeypatch)
 
 
 def test_the_cache_holds_a_state_a_slot_beside_rows():
@@ -467,20 +553,32 @@ def test_a_cache_with_a_state_refuses_what_it_cannot_hold():
             PagedKVCache(**dict(base, **change))
 
 
-def test_counters_of_the_state_space_layers():
+@pytest.mark.parametrize("way", ["oracle", "kernel"])
+def test_counters_of_the_state_space_layers(way):
+    import contextlib
+
     model, params = _model()
-    eng = ServeEngine(model, params, _serve())
-    before = COUNTERS.snapshot()
-    lengths = (8, 19)
-    eng.generate([_prompt(n, i) for i, n in enumerate(lengths)], 6)
+    with _forced() if way == "kernel" else contextlib.nullcontext():
+        eng = ServeEngine(model, params, _serve())
+        before = COUNTERS.snapshot()
+        lengths = (8, 19)
+        eng.generate([_prompt(n, i) for i, n in enumerate(lengths)], 6)
     d = COUNTERS.delta_since(before)
     steps = d["serve.decode_steps"]["calls"]
     assert d["serve.ssm.state_resets"] == {"calls": 2, "bytes": 0}
     # chunks of 8: one, and three
     assert d["serve.ssm.prefill_tokens"] == {"calls": 4, "bytes": 27}
-    # every step streams every slot's state, in and out, live or not
+    # as the program is built.  The oracle: every step streams every
+    # slot's state, in and out, live or not.  The kernel: the float32
+    # state of the running slots (10 over the steps, of 3 a step) and
+    # every slot's convolution inputs — any other slot's state, in 4
+    # layers, is never touched
+    every = 2 * eng.kv.state_nbytes()
+    dead = 2 * len(STATE_LAYERS) * SH * SP * SN * 4 if way == "kernel" else 0
     assert d["serve.ssm.state_bytes"] == {
-        "calls": steps, "bytes": steps * 2 * eng.kv.state_nbytes()}
+        "calls": steps, "bytes": steps * every - (3 * steps - 10) * dead}
+    assert d["kernel.dispatches" if way == "kernel" else "kernel.fallbacks"][
+        "calls"] >= len(STATE_LAYERS)
     # 2 requests x 5 decode steps x 4 layers with a state
     assert d["serve.ssm.slots_live"] == {"calls": steps, "bytes": 10 * 4}
     # the 2 attention layers' rows: every cached position

@@ -242,6 +242,24 @@ def _touched(tokens, e, d, f, dtype=jnp.bfloat16):
     return fn, shapes, info
 
 
+def _ssm_step(slots=64, heads=64, p=64, n=128, dtype=jnp.float32):
+    """`ssm_step` as `ssm_mix` calls it in a decode step: the Granite
+    cell's shapes by default (64 slots, a float32 state of 64 heads x 64
+    x 128 a slot)."""
+    from deepspeed_tpu.kernels.ssm import ssm_step_info
+
+    state = _sds((slots, heads, p, n), dtype)
+    info = ssm_step_info(state)
+    f32 = lambda *shape: _sds(shape, jnp.float32)
+    shapes = (f32(slots, heads, p), f32(slots, n), f32(slots, n),
+              f32(slots, heads), f32(heads), state,
+              _sds((slots,), jnp.int32), _sds((), jnp.int32))
+
+    def fn(*args):
+        return registry.dispatch("ssm_step", *args, info=info)
+    return fn, shapes, info
+
+
 @dataclasses.dataclass
 class Case:
     """`build()` -> (fn, shapes[, info]).  `op` None: the kernel is
@@ -339,6 +357,17 @@ CASES = [
     Case("touched_experts_T512_prefill",
          lambda: _touched(512, 16, 1024, 512),
          op="touched_experts", refused=r"512 rows are over the ridge"),
+    # the recurrence of a decode step at the Granite cell's shapes (a
+    # slot's 2 MB of state as one block), a state of 16 MB a slot in
+    # tiles of 16 heads, and what the shape rule sends to the oracle
+    Case("ssm_step_B64_H64_P64_N128_chatrate", _ssm_step, op="ssm_step"),
+    Case("ssm_step_B8_H128_P128_N256_head_tiles",
+         lambda: _ssm_step(8, 128, 128, 256), op="ssm_step"),
+    Case("ssm_step_B64_H4_P8_N16_toy", lambda: _ssm_step(64, 4, 8, 16),
+         op="ssm_step", refused=r"8 x 16 values of 4 bytes is not whole"),
+    Case("ssm_step_B64_H64_P64_N128_bf16_state",
+         lambda: _ssm_step(dtype=jnp.bfloat16),
+         op="ssm_step", refused=r"64 x 128 values of 2 bytes is not whole"),
     Case("moe_dispatch_N8192_D768_E8", lambda: _moe("dispatch"),
          op="moe_dispatch", variant="dispatch", refused=r"one-row block"),
     Case("moe_combine_N8192_D768_E8", lambda: _moe("combine"),
@@ -754,12 +783,15 @@ def test_granite_hybrid_cell_programs_compile_inside_one_chip(program,
     cell's shapes (all 40 layers at published widths in bf16, 64 slots,
     8,193 blocks of 16 rows for the 4 attention layers, a float32 state
     `[64, 64, 64, 128]` and `[64, 3, 4352]` convolution inputs for each
-    of the 36 state-space layers, chunk 512): no custom call (grouped
-    rows go to `jax.numpy`, the scan is `jax.numpy`), every state enters
-    and leaves under its own shape — updated in place, not copied beside
-    itself — and weights, rows, state and temporaries fit the chip's
-    15.75 GB with room for the check's 0.82 GB of reference logits and
-    its float32 layer."""
+    of the 36 state-space layers, chunk 512): `decode`'s only custom
+    calls are the 36 state-space layers' `ssm_step_live` kernels,
+    `prefill` has none (grouped rows go to `jax.numpy`, the scan is
+    `jax.numpy`), every state enters and leaves under its own shape —
+    updated in place, not copied beside itself: the kernel's state is
+    aliased input to output, and no temporary is as large as one layer's
+    — and weights, rows, state and temporaries fit the chip's 15.75 GB
+    with room for the check's 0.82 GB of reference logits and its
+    float32 layer."""
     from deepspeed_tpu.models import GraniteHybrid, GraniteHybridConfig
     from deepspeed_tpu.serving import ServeProgramBuilder, ServeSchedule
 
@@ -802,7 +834,21 @@ def test_granite_hybrid_cell_programs_compile_inside_one_chip(program,
                 on((), jnp.float32), on((), jnp.int32), on((), jnp.uint32))
     compiled = progs[program].lower(params, caches, *args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    if program == "decode":
+        assert len(calls) == 36
+        assert all("ssm_step_live" in ln for ln in calls)
+        # a layer's state enters, goes through the kernel and leaves:
+        # nothing copies it, nothing else of its size is built
+        # (the kernel takes it as [slots, 32, 128, 128]: the same bytes)
+        by_shape = _hlo_by_shape(text)
+        state_ops = {op for shape in ((slots, 64, 64, 128),
+                                      (slots, 32, 128, 128))
+                     for op, _ in by_shape[shape]}
+        assert state_ops <= {"parameter", "custom-call", "get-tuple-element",
+                             "bitcast"}, state_ops
+    else:
+        assert not calls
     m = compiled.memory_analysis()
     # all 72 state arrays and 8 pools are donated and aliased
     assert m.alias_size_in_bytes > 5.9e9
