@@ -444,6 +444,8 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                   eva_heads=(32, 128), eva_window=2048, eva_chunk=16,
                   eva_summary_blocks=64,
                   eva_positions=(0, 100, 2047, 2048, 9000, 12345, 16383, -1),
+                  grouped_shapes=((64, 32, 8, 64, 128, 1 / 64),
+                                  (16, 128, 8, 128, 1024, None)),
                   routed_shape=(32, 64, 2048, 1408), routed_live=12,
                   ssm_shape=(64, 64, 64, 128), ssm_live=37,
                   on_chip=True) -> dict:
@@ -639,6 +641,48 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
     out.append(_close(f"eva_attention_bf16_H{e_heads}_Dh{e_dim}",
                       [slots, width * bs, e_heads, e_dim],
                       got[pos >= 0], want[pos >= 0], rtol=0, atol=1e-2))
+
+    # attention over grouped rows, one decode step of a full layer at
+    # the chatrate cell's shape (64 slots, 32 query heads on 8 K/V heads
+    # of 64, a table of 128 blocks, scores times 1/64) and at mixedlen's
+    # (16 slots, 128 on 8 of 128, 1,024 blocks): bf16 rows, slots from
+    # idle to the whole table, dead entries at the trash block.  At the
+    # default precision too
+    from deepspeed_tpu.serving.layers import grouped_attention_reference
+
+    for slots, g_heads, g_kv, g_dim, width, scale in grouped_shapes:
+        bs, nblocks = 16, 1 + slots * width
+        held = rs.randint(0, width * bs + 1, (slots,))
+        edge = [0, 1, width * bs, bs, bs + 1][:slots]
+        held[:len(edge)] = edge
+        tables = rs.permutation(np.arange(1, nblocks)).reshape(slots, width)
+        tables[np.arange(width)[None, :] >= -(-held // bs)[:, None]] = 0
+        tables = jnp.asarray(tables, jnp.int32)
+        q_pos = jnp.asarray(held[:, None] - 1, jnp.int32)
+        rows = (nblocks * bs, g_kv, g_dim)
+        ck = pool_rows(jax.random.normal(key[6], rows, jnp.bfloat16))
+        cv = pool_rows(jax.random.normal(key[7], rows, jnp.bfloat16))
+        gq = jax.random.normal(key[0], (slots, 1, g_heads, g_dim),
+                               jnp.bfloat16)
+        info = {"block_size": bs, "table_width": width, "q_len": 1,
+                "num_heads": g_heads, "kv_heads": g_kv, "head_dim": g_dim,
+                "kv_mode": "dense", "kv_itemsize": 2, "window": 0,
+                "ring": False}
+        chosen = registry.resolve_impl("grouped_attention", info=info)
+        if on_chip and chosen != "pallas":
+            raise RuntimeError(
+                f"auto resolved grouped attention at {g_heads} heads on "
+                f"{g_kv} of {g_dim} to {chosen!r} on this chip")
+        kw = dict(kv_heads=g_kv, block_size=bs, scale=scale)
+        got = jax.jit(lambda *a: registry.dispatch(
+            "grouped_attention", *a, info=info, **kw))(gq, ck, cv, tables,
+                                                       q_pos)
+        want = jax.jit(lambda *a: grouped_attention_reference(*a, **kw))(
+            gq, ck, cv, tables, q_pos)
+        out.append(_close(
+            f"grouped_attention_bf16_H{g_heads}_KV{g_kv}_Dh{g_dim}",
+            [slots, width * bs, g_heads, g_dim], got[held > 0],
+            want[held > 0], rtol=0, atol=1e-2))
 
     # the routed product of a decode step at the chatgen cell's shape:
     # 32 slots of which 12 are live, 6 of 64 bf16 experts a slot, the

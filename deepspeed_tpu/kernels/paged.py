@@ -54,6 +54,22 @@ with `window` > 0): which table entries are live and which of their rows
 a query sees then follow from the query's position — two runs, the open
 window's blocks and the closed windows' summary blocks — and the output
 is float32.  With `window` 0 the kernel's body is what it was.
+
+And it serves grouped rows (`grouped_attention_pallas`; serving/layers.py
+`_grouped_attend`, a full layer's decode step): a cache row holds
+`kv_heads` heads, each read by `G = H / kv_heads` query heads, and the
+call says so by its shapes.  The tile is the row as it lies,
+`pool_width(kv_heads, Dh)` lanes; score row (t, h) carries query head
+h's `Dh` values in the lanes of K/V head `h // G`, so the one product
+still gives every head's scores, `G` rows a K/V head.  Rows that share
+lanes cannot sum into one output row: the program writes its `[T * Hp,
+lanes]` accumulator out, normalised, in float32, and the caller keeps
+each score row's own `Dh` lanes (`_own_lanes`) -> `[B, T, H, Dh]`.  The
+softmax scale is the caller's (Granite's `attention_multiplier` is not
+`Dh ** -0.5`).  Liveness is the paged walk's own — one causal run from
+the table's first entry; a window's lower bound and a ring's modular
+rows are not cases of it (kernels/registry.py refuses them by what
+`grouped_info` says).  At `G` = 1 nothing of this is traced.
 """
 
 from __future__ import annotations
@@ -199,7 +215,7 @@ def _tile_kv(buf, sbuf, slot, kv_mode, H, Dh, marker):
 
 
 def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
-                 kv_mode, marker, window=0, chunk=0):
+                 kv_mode, marker, window=0, chunk=0, G=1):
     if kv_mode == "dense":
         k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc, m_s, l_s = rest
         pairs = ((k_hbm, kbuf), (v_hbm, vbuf))
@@ -316,6 +332,14 @@ def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
             return carry
 
         jax.lax.fori_loop(0, n_tiles, body, 0)
+        if G > 1:
+            # grouped rows: G score rows share a K/V head's lanes, so the
+            # rows of one t do not sum; each leaves whole and the caller
+            # keeps its own lanes (`_own_lanes`)
+            l = l_s[:, :1]
+            o_ref[0] = (acc[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+                o_ref.dtype)
+            return
         # row (t, h) keeps head h's lanes; the rows of one t make the
         # output row
         own = _head_lanes(Hp, H, Dh, acc.shape[-1])
@@ -328,15 +352,34 @@ def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
                 keepdims=True).astype(o_ref.dtype)
 
 
-def _block_diagonal(q, Hp: int, width: int):
+def _block_diagonal(q, Hp: int, width: int, G: int = 1):
     """q [B, T, H, Dh] -> [B, T * Hp, width]: row (t, h) holds head
-    h's values in head h's lanes, zeros elsewhere (in the rows that pad
-    H up to Hp and the lanes that pad H * Dh up to the pool's width)."""
+    h's values in the lanes of the K/V head it reads, h // G, zeros
+    elsewhere (in the rows that pad H up to Hp and the lanes that pad a
+    row's heads up to the pool's width)."""
     B, T, H, Dh = q.shape
-    flat = jnp.pad(q.reshape(B, T, 1, H * Dh),
-                   ((0, 0),) * 3 + ((0, width - H * Dh),))
-    own = (jnp.arange(width)[None, :] // Dh) == jnp.arange(Hp)[:, None]
-    return jnp.where(own[None, None], flat, 0).reshape(B, T * Hp, width)
+    if G == 1:   # as it stood: the G = 1 callers' programs keep their bytes
+        flat = jnp.pad(q.reshape(B, T, 1, H * Dh),
+                       ((0, 0),) * 3 + ((0, width - H * Dh),))
+        own = (jnp.arange(width)[None, :] // Dh) == jnp.arange(Hp)[:, None]
+        return jnp.where(own[None, None], flat, 0).reshape(B, T * Hp, width)
+    KV = H // G
+    # each query head's values under every K/V head, then its own kept
+    rows = jnp.pad(jnp.tile(q, (1, 1, 1, KV)),
+                   ((0, 0), (0, 0), (0, Hp - H), (0, width - KV * Dh)))
+    own = (jnp.arange(width)[None, :] // Dh) == \
+        (jnp.arange(Hp)[:, None] // G)
+    return jnp.where(own[None, None], rows, 0).reshape(B, T * Hp, width)
+
+
+def _own_lanes(out, T: int, H: int, G: int, Dh: int):
+    """The grouped walk's rows [B, T * Hp, width] -> [B, T, H, Dh]: score
+    row (t, h) keeps the lanes of K/V head h // G."""
+    B, C, width = out.shape
+    rows = out.reshape(B, T, C // T, width)
+    return jnp.concatenate(
+        [rows[:, :, n * G:(n + 1) * G, n * Dh:(n + 1) * Dh]
+         for n in range(H // G)], axis=2)
 
 
 def paged_attention_pallas(q, ck, cv, tables, q_pos, *,
@@ -347,25 +390,51 @@ def paged_attention_pallas(q, ck, cv, tables, q_pos, *,
                  interpret=pallas_backend.interpret())
 
 
+def grouped_attention_pallas(q, ck, cv, tables, q_pos, *, kv_heads: int,
+                             block_size: int, scale=None, window: int = 0,
+                             newest=None):
+    """Drop-in for serving/layers.py's `grouped_attention_reference`
+    where a layer attends its whole table causally (tolerance parity):
+    the walk at `G` = q's heads / `kv_heads` -> [B, T, H * Dh] float32."""
+    if window or newest is not None:
+        raise ValueError(
+            "paged attention kernel: the walk reads one causal run of a "
+            "table from its first entry; a window's lower bound and a "
+            "ring's modular rows are liveness rules it does not have")
+    B, T, H, Dh = q.shape
+    return _walk(q, ck, cv, tables, q_pos, kv_mode="dense",
+                 block_size=int(block_size), kv_heads=int(kv_heads),
+                 scale=None if scale is None else float(scale),
+                 interpret=pallas_backend.interpret()).reshape(B, T, H * Dh)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("kv_mode", "block_size", "interpret",
-                                    "window", "chunk"))
+                                    "window", "chunk", "kv_heads", "scale"))
 def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret,
-          window=0, chunk=0):
+          window=0, chunk=0, kv_heads=None, scale=None):
     """The call, as a function of its own: a program that makes it in
     every layer traces and lowers the kernel once and calls it.
     `window` > 0: the table is `[window blocks | summary blocks]` and
-    the walk is kernels/eva.py's (dense rows, float32 out)."""
+    the walk is kernels/eva.py's (dense rows, float32 out).  `kv_heads`
+    fewer than q's heads: grouped rows (dense, float32 out), a tile of
+    the row's heads serving `G` = H / kv_heads query heads a key.
+    `scale`: the softmax's, `Dh ** -0.5` unless given."""
     B, T, H, Dh = q.shape
     W = tables.shape[1]
     bs = block_size
-    HD = H * Dh
+    G = H // (kv_heads or H)
+    if G * (kv_heads or H) != H:
+        raise ValueError(
+            f"paged attention kernel: {H} query heads are not whole "
+            f"groups on {kv_heads} K/V heads a row")
+    HD = H // G * Dh
     C = score_rows(T, H)
     Hp = C // T
 
     if kv_mode == "dense":
         marker = 0
-        out_dtype = jnp.float32 if window else ck.dtype
+        out_dtype = jnp.float32 if window or G > 1 else ck.dtype
         operands = [ck, cv]
         width = ck.shape[1]  # H * Dh and the lanes that pad a pool row
     else:
@@ -399,7 +468,8 @@ def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret,
             pl.BlockSpec((1, C, width), lambda b, t, s: (b, 0, 0)),
             *[pl.BlockSpec(memory_space=pl.ANY)] * len(operands),
         ],
-        out_specs=pl.BlockSpec((1, T, width), lambda b, t, s: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, C if G > 1 else T, width),
+                               lambda b, t, s: (b, 0, 0)),
         scratch_shapes=[
             *bufs,
             pltpu.SemaphoreType.DMA((len(operands), 2)),
@@ -409,15 +479,20 @@ def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_walk_kernel, scale=Dh ** -0.5, bs=bs, W=W,
-                          KB=KB, T=T, H=H, Hp=Hp, Dh=Dh, kv_mode=kv_mode,
-                          marker=marker, window=window, chunk=chunk),
+        functools.partial(_walk_kernel,
+                          scale=Dh ** -0.5 if scale is None else scale,
+                          bs=bs, W=W, KB=KB, T=T, H=H, Hp=Hp, Dh=Dh,
+                          kv_mode=kv_mode, marker=marker, window=window,
+                          chunk=chunk, G=G),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, width), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((B, C if G > 1 else T, width),
+                                       out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL,)),
         interpret=interpret,
         name="eva_attention_walk" if window else "paged_attention_walk",
     )(tables.astype(jnp.int32), q_pos.astype(jnp.int32),
-      _block_diagonal(q, Hp, width), *operands)
+      _block_diagonal(q, Hp, width, G), *operands)
+    if G > 1:
+        return _own_lanes(out, T, H, G, Dh)
     return out[..., :HD].reshape(B, T, H, Dh)

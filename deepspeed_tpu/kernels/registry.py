@@ -257,7 +257,9 @@ class SparseAttentionOp(KernelOp):
 
 def _walk_supports(info: Mapping) -> Tuple[bool, str]:
     """What kernels/paged.py's walk of live blocks takes, from what a
-    serving call site observes (serving/layers.py::paged_info)."""
+    serving call site observes (serving/layers.py::paged_info): the
+    tile is `q_len` x `num_heads` score rows of a cache row's lanes —
+    the row's `kv_heads` heads where it has fewer than the queries."""
     t = int(info.get("q_len", 1))
     if t > 8:
         return False, (f"q_len {t} is a prefill chunk: the kernel "
@@ -282,7 +284,8 @@ def _walk_supports(info: Mapping) -> Tuple[bool, str]:
     from ..serving.kv_cache import pool_width
     from .paged import tile_blocks
 
-    width = pool_width(H, int(info.get("head_dim", 128)))
+    width = pool_width(int(info.get("kv_heads", H)),
+                       int(info.get("head_dim", 128)))
     if not tile_blocks(bs, int(info.get("table_width", 1)),
                        width * item, t, H, width):
         return False, (f"{t} x {H} score rows of {width} lanes, or "
@@ -299,21 +302,17 @@ class PagedAttentionOp(KernelOp):
     (serving stays bit-identical to `generate()` wherever the oracle is
     chosen).  The shape rule looks at what the call site can observe —
     `q_len`, `kv_mode`, `block_size`, the row's width and dtype, the
-    table's width, whether the row has fewer K/V heads than the call
-    has query heads — never at a head size or a model."""
+    table's width — never at a head size or a model.  A row whose
+    `kv_heads` heads each serve several query heads takes the same walk
+    at a GROUPED tile — the row's heads as they lie, every (query, query
+    head) pair a score row in the lanes of the K/V head it reads — as
+    `grouped_attention` below, whose oracle is another expression."""
 
     NAME = "paged_attention"
 
     def auto_supports(self, variant, info):
         if not info:
             return True, ""
-        H, kv = int(info.get("num_heads", 1)), int(info.get("kv_heads", 0))
-        if kv and kv != H:
-            return False, (f"grouped rows: {H} query heads on {kv} K/V "
-                           f"heads a row, and the walk takes one query "
-                           f"head a K/V head (a tile of the row's heads "
-                           f"against as many queries); the gather in "
-                           f"jax.numpy reads them")
         return _walk_supports(info)
 
     def pallas(self, variant, *args, **kwargs):
@@ -323,6 +322,46 @@ class PagedAttentionOp(KernelOp):
     def oracle(self, variant, *args, **kwargs):
         from . import paged
         return paged.paged_attention_reference(*args, **kwargs)
+
+
+class GroupedAttentionOp(KernelOp):
+    """Attention over paged rows of `kv_heads` heads that each serve
+    `num_heads / kv_heads` query heads (serving/layers.py
+    `_grouped_attend`).  Pallas = the paged walk at a grouped tile: the
+    row's heads as they lie, `q_len` x `num_heads` score rows whose
+    queries sit in the lanes of the K/V head they read (kernels/paged.py
+    `grouped_attention_pallas`).  Oracle = the gather of every table
+    entry and `attend_grouped` under the layer's visibility mask, the
+    expression the layer ran before there was a kernel
+    (`grouped_attention_reference`).  The shape rule is the walk's at
+    that tile, and that the layer's rows are one causal run of the
+    table: a window or a ring (`grouped_info`) keeps the gather."""
+
+    NAME = "grouped_attention"
+
+    def auto_supports(self, variant, info):
+        if not info:
+            return True, ""
+        if info.get("ring"):
+            return False, ("the rows are a ring (position p in row p "
+                           "modulo the ring): the walk reads one run of a "
+                           "table from its first entry and has no modular "
+                           "run until the runs are data (ROADMAP D11)")
+        window = int(info.get("window", 0))
+        if window:
+            return False, (f"a window of {window} rows: the walk's one "
+                           f"liveness rule is causal from the table's first "
+                           f"entry, with no lower bound until the runs are "
+                           f"data (ROADMAP D11)")
+        return _walk_supports(info)
+
+    def pallas(self, variant, *args, **kwargs):
+        from . import paged
+        return paged.grouped_attention_pallas(*args, **kwargs)
+
+    def oracle(self, variant, *args, **kwargs):
+        from ..serving import layers
+        return layers.grouped_attention_reference(*args, **kwargs)
 
 
 class EvaAttentionOp(KernelOp):
@@ -519,7 +558,8 @@ class SsmStepOp(KernelOp):
 
 KERNEL_OPS: Dict[str, KernelOp] = {
     op.NAME: op for op in (FlashAttentionOp(), SparseAttentionOp(),
-                           PagedAttentionOp(), EvaAttentionOp(),
+                           PagedAttentionOp(), GroupedAttentionOp(),
+                           EvaAttentionOp(),
                            QuantCodecOp(), MoEDispatchOp(),
                            TouchedExpertsOp(), SsmStepOp())
 }

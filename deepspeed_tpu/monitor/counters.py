@@ -144,8 +144,14 @@ Instrumented sites:
   "grouped" attention and sliding layers): `serve.window.rows_read` —
   calls = queries decoded, bytes = rows one attends in ONE sliding
   layer (min(cached, window)); `serve.attn.rows_read` — the same summed
-  over all the layers (every cached row in a full one), both from
-  positions on the host; `kv.ring_wraps` — calls = requests that ended
+  over all the layers (every cached row in a full one);
+  `serve.attn.rows_walked` — calls = slots decoded, bytes = pool rows
+  the step's attention FETCHES for them over the same layers (a slot's
+  cached length rounded up to a block in a layer whose decode call
+  resolves to the walk of live blocks, the table's — or the ring's —
+  whole width in a layer that gathers; `kernels/registry.py`, asked
+  once for each kind of layer at build), all three from positions on
+  the host; `kv.ring_wraps` — calls = requests that ended
   with more rows than a ring holds, bytes = blocks the ring saved them
   in the window group.  Behind a share of the experts
   `serve.moe.experts_touched` and `serve.moe.experts_streamed` count
@@ -165,7 +171,8 @@ Instrumented sites:
   prefill_tokens` — calls = prefill chunks, bytes = valid tokens
   scanned; `serve.ssm.state_resets` — calls = slots zeroed on the
   device as a request is seated (serving/kv_cache.py `reset_state`);
-  `serve.attn.rows_read` as above over the attention layers alone.
+  `serve.attn.rows_read` and `serve.attn.rows_walked` as above over
+  the attention layers alone.
   Paged attention (the GPT family): `serve.paged.rows_walked`
   — calls = slots decoded, bytes = pool rows their attention reads (a
   slot's live blocks where the paged kernel runs, the table's whole

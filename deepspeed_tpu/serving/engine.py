@@ -152,7 +152,12 @@ int8/int4 rows and a mesh of more than one device.  Counters, from
 positions on the host: `serve.window.rows_read` (calls = queries
 decoded, bytes = rows one of them attends in ONE sliding layer:
 min(cached, window)), `serve.attn.rows_read` (the same summed over all
-the layers), `kv.ring_wraps` (calls = requests that ended with more rows
+the layers), `serve.attn.rows_walked` (calls = slots decoded, bytes =
+the pool rows the layers fetch for that: a slot's cached length rounded
+up to a block in a layer whose decode call the registry resolves to the
+walk of live blocks, its run's whole width — the table's, or the ring's
+— in a layer that gathers; asked once for each kind of layer at build),
+`kv.ring_wraps` (calls = requests that ended with more rows
 than a ring, bytes = the blocks the ring saved each in the window
 group); behind a share of the experts `serve.moe.experts_touched`
 counts among those held, and `serve.moe.assignments` is not emitted
@@ -186,7 +191,8 @@ slot's of both, twice), `serve.ssm.slots_live` (calls
 = decode steps, bytes = running slots x layers with a state),
 `serve.ssm.prefill_tokens` (calls = prefill chunks, bytes = valid
 tokens scanned), `serve.ssm.state_resets` (calls = slots zeroed) and
-`serve.attn.rows_read` over the attention layers.
+`serve.attn.rows_read` and `serve.attn.rows_walked` over the attention
+layers.
 
 What a paged step reads: `serve.paged.rows_walked` (calls = slots
 decoded, bytes = the pool rows attention reads for them: a slot's live
@@ -210,7 +216,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import threading
-from functools import partial
 import time
 from typing import Any, List, Optional, Sequence, Tuple
 
@@ -553,7 +558,7 @@ class ServeEngine:
             self.scheduler.session_consumed = self._session_consumed
         self.programs = programs
         # what a decoded slot's attention reads, for
-        # serve.{paged,eva}.rows_walked: its live blocks where the
+        # serve.{paged,eva,attn}.rows_walked: its live blocks where the
         # registry picks the kernel for the decode program's shapes,
         # else the table's whole width
         from ..kernels import registry
@@ -563,15 +568,24 @@ class ServeEngine:
                          if i not in self._state_layers)
         pool = jax.tree_util.tree_leaves(self.kv.caches[with_rows][0])[0]
         q_len = int(c.draft_len) + 1
-        op = "eva_attention" if spec.attention == "eva" \
-            else "paged_attention"
-        info = {"paged": paged_info, "grouped": partial(grouped_info, spec),
-                "eva": partial(eva_info, spec)}.get(spec.attention)
-        # latent rows have no kernel: the table's rows are gathered; so
-        # are grouped rows, which the registry refuses the walk for
-        self._walks_live_blocks = info is not None and \
-            registry.resolve_impl(op, info=info(
-                cfg, schedule, q_len, pool.dtype)) == "pallas"
+        walks = lambda op, info: registry.resolve_impl(
+            op, info=info) == "pallas"
+        # latent rows have no kernel: the table's rows are gathered
+        self._walks_live_blocks = False
+        if spec.attention == "grouped":
+            # a full layer and a sliding one are asked apart: a sliding
+            # layer's rows are a ring, or the table under a window
+            ask = lambda window: walks("grouped_attention", grouped_info(
+                spec, cfg, schedule, q_len, pool.dtype, window,
+                bool(window and ring_blocks)))
+            self._walks_live_blocks = ask(0)
+            self._sliding_walks = bool(self._window) and ask(self._window)
+        elif spec.attention == "paged":
+            self._walks_live_blocks = walks("paged_attention", paged_info(
+                cfg, schedule, q_len, pool.dtype))
+        elif spec.attention == "eva":
+            self._walks_live_blocks = walks("eva_attention", eva_info(
+                spec, cfg, schedule, q_len, pool.dtype))
         self.params = programs["prepare_params"](
             self._place_params(params))
         logger.info(f"serving engine up: {schedule.describe()}; "
@@ -1189,6 +1203,20 @@ class ServeEngine:
         COUNTERS.add("serve.attn.rows_read", calls=len(lanes),
                      nbytes=self._sliding_layers * in_window
                      + full_layers * int(held.sum()))
+        # what the layers FETCH for that: a slot's live blocks where the
+        # layer's decode is the walk, every entry of its run — the table,
+        # or the ring — where it gathers
+        table = self.kv.table_width * bs
+
+        def fetched(walks: bool, run: int) -> int:
+            return int(np.minimum(-(-held // bs) * bs, run).sum()) if walks \
+                else run * len(lanes)
+
+        COUNTERS.add("serve.attn.rows_walked", calls=len(lanes),
+                     nbytes=full_layers * fetched(self._walks_live_blocks,
+                                                  table)
+                     + self._sliding_layers * fetched(self._sliding_walks,
+                                                      ring or table))
 
     def _count_rows_walked(self, running: List[Request],
                            n_queries: int) -> None:

@@ -54,9 +54,12 @@ a ring row j holds follows from the newest position written: the
 largest p <= newest with p % ring = j; a query at p_q attends row j iff
 that position is >= 0, <= p_q and > p_q - window.  With `ring_blocks`
 0 (the ring would be no shorter than the table) sliding layers lie in
-the one group and mask the window on the whole table.  No kernel: the
-walk of kernels/paged.py takes one query head a K/V head, and the
-registry sends grouped rows to `jax.numpy` (`grouped_info`).
+the one group and mask the window on the whole table.  A full layer's
+decode step walks each slot's live blocks (kernels/paged.py, a tile of
+the row's `kv_heads` heads serving `num_heads / kv_heads` query heads a
+key) where the registry picks the kernel; a ring and a window are
+liveness rules the walk does not have, say so in `grouped_info`, and are
+gathered in `jax.numpy` (`grouped_attention_reference`).
 
 Layers with a state and no rows (`spec.mixer_of(layer) == "ssm"`,
 models/granite_hybrid.py): such a layer's entry of the cache is not rows
@@ -489,11 +492,37 @@ def _latent_attend(cfg, p, h, pool, addr, s):
     return matmul32(out, p["o"]), pool
 
 
-def grouped_info(spec, cfg, s, q_len: int, cache_dtype) -> dict:
-    """`paged_info` over rows of `kv_heads` heads: what the registry is
-    asked, and refuses the walk for."""
+def grouped_info(spec, cfg, s, q_len: int, cache_dtype, window: int = 0,
+                 ringed: bool = False) -> dict:
+    """`paged_info` over rows of `kv_heads` heads, and what the layer's
+    rows are beside one causal run of the table: a `window` (0: none),
+    a `ring`."""
     return dict(paged_info(cfg, s, q_len, cache_dtype),
-                kv_heads=spec.kv_heads)
+                kv_heads=spec.kv_heads, window=window, ring=ringed)
+
+
+def grouped_attention_reference(q, ck, cv, tables, q_pos, *, kv_heads: int,
+                                block_size: int, scale=None,
+                                window: int = 0, newest=None):
+    """The `_grouped_attend` attention core: q [B, T, H, Dh] over the
+    rows of `kv_heads` heads that `tables` [B, W] address in the pool,
+    gathered a block at a time — every entry, whatever a slot holds —
+    under what a query at q_pos [B, T] may see: causal, inside `window`
+    where there is one; `newest` [B]: the rows are a ring, and row j
+    holds the largest position <= newest that is j modulo the ring.
+    -> [B, T, H * Dh] float32."""
+    B, Dh = q.shape[0], q.shape[3]
+    lanes = ck.shape[1]
+    held = lambda c: c.reshape(-1, block_size, lanes)[tables].reshape(
+        B, -1, lanes)[..., :kv_heads * Dh].reshape(B, -1, kv_heads, Dh)
+    keys, vals = held(ck), held(cv)
+    L = keys.shape[1]
+    at = jnp.arange(L)[None, :]
+    if newest is not None:
+        newest = newest[:, None]
+        at = newest - (newest - at) % L                      # [B, L]
+    return cohere2_moe.attend_grouped(
+        q, keys, vals, _visible(at, q_pos, window), scale=scale)
 
 
 def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
@@ -501,7 +530,10 @@ def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
     values at the cache's dtype, rotated where the layer rotates; the
     rows written — into the request's ring where the layer has a window
     and the cache two groups, through the table else; softmax over the
-    rows the layer lets a query see, gathered a block at a time; output
+    rows the layer lets a query see (through the kernel registry: where
+    the layer attends its whole table causally, the walk of each slot's
+    live blocks on the chip at `q_len` <= 8; the gather of every table
+    entry elsewhere, in prefill, under a window and over a ring); output
     projection.  -> float32."""
     B, T, _ = h.shape
     KV, Dh = spec.kv_heads, cfg.head_dim
@@ -513,18 +545,14 @@ def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
         else (addr.write_idx, addr.tables)
     ck = _kv_write(ck, idx, k.reshape(B * T, KV, Dh), "dense")
     cv = _kv_write(cv, idx, v.reshape(B * T, KV, Dh), "dense")
-    lanes = ck.shape[1]
-    held = lambda c: c.reshape(-1, s.block_size, lanes)[tables].reshape(
-        B, -1, lanes)[..., :KV * Dh].reshape(B, -1, KV, Dh)
-    keys, vals = held(ck), held(cv)
-    L = keys.shape[1]
-    at = jnp.arange(L)[None, :]
-    if ringed:      # the position ring row j holds, from the newest written
-        newest = addr.ring_newest[:, None]
-        at = newest - (newest - at) % L                      # [B, L]
-    out = cohere2_moe.attend_grouped(
-        q, keys, vals, _visible(at, addr.q_pos, window),
-        scale=spec.attn_scale or None)
+    from ..kernels import registry
+
+    out = registry.dispatch(
+        "grouped_attention", q, ck, cv, tables, addr.q_pos,
+        info=grouped_info(spec, cfg, s, T, ck.dtype, window, ringed),
+        kv_heads=KV, block_size=s.block_size,
+        scale=spec.attn_scale or None, window=window,
+        newest=addr.ring_newest if ringed else None)
     return matmul32(out, p["o"]), ck, cv
 
 

@@ -58,3 +58,24 @@ def native(monkeypatch):
     from deepspeed_tpu.ops import pallas_backend
 
     monkeypatch.setattr(pallas_backend, "interpret", lambda: False)
+
+
+@pytest.fixture
+def chip_rule(monkeypatch):
+    """`with chip_rule(op):` — the kernel registry answers for `op` as it
+    does on the chip (its shape rule decides, nothing forced) while the
+    kernel it picks runs under the Pallas interpreter; every other op
+    takes its oracle.  An engine built and run inside it serves through
+    the kernel exactly where the chip's would."""
+    import contextlib
+
+    from deepspeed_tpu.kernels import kernel_config, registry
+
+    @contextlib.contextmanager
+    def scope(op: str):
+        with monkeypatch.context() as chip, \
+                kernel_config(impl="jnp", ops={op: "auto"}):
+            chip.setattr(registry, "_on_tpu", lambda: True)
+            yield
+
+    return scope

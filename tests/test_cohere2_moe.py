@@ -651,20 +651,76 @@ def test_engine_refuses_sessions_and_a_mesh_by_name():
                                         devices=jax.devices()[:2]))
 
 
-def test_the_registry_sends_grouped_rows_to_jax_numpy():
+def test_the_registry_is_asked_for_each_kind_of_layer(native):
+    """What `_grouped_attend` hands the registry says what the layer's
+    rows are: on the chip a full layer's decode call takes the walk, a
+    ring and a window on the table keep the gather and name what the walk
+    lacks, and so does a prefill chunk."""
     model, params = _model()
-    eng = ServeEngine(model, params, _serve())
-    spec, sched = model.layer_spec(), eng.programs["schedule"]
-    info = serving_layers.grouped_info(spec, model.config, sched, 1,
-                                       jnp.bfloat16)
+    spec, cfg = model.layer_spec(), model.config
+    sched = ServeSchedule(max_batch=3, prefill_chunk=CHUNK, block_size=BS,
+                          num_blocks=120, table_width=256 // BS,
+                          ring_blocks=RING // BS)
+    info = serving_layers.grouped_info(spec, cfg, sched, 1, jnp.float32)
     assert info["kv_heads"] == KV and info["num_heads"] == HEADS
-    ok, why = registry.get_kernel("paged_attention").auto_supports(
-        None, info)
-    assert not ok and "grouped rows" in why
-    same = dict(info, kv_heads=HEADS)         # one query head a K/V head
-    assert "grouped rows" not in registry.get_kernel(
-        "paged_attention").auto_supports(None, same)[1]
-    assert not eng._walks_live_blocks
+    assert (info["window"], info["ring"]) == (0, False)
+    ask = lambda *a: registry.resolve_impl(
+        "grouped_attention", info=serving_layers.grouped_info(
+            spec, cfg, sched, *a))
+    assert ask(1, jnp.float32) == "pallas"
+    assert ask(1, jnp.float32, WINDOW, True) == "jnp"
+    assert ask(1, jnp.float32, WINDOW, False) == "jnp"
+    assert ask(CHUNK, jnp.float32) == "jnp"
+    assert ask(1, jnp.bfloat16) == "jnp"     # blocks of 8 rows of bf16
+    for kind, why in (((WINDOW, True), "a ring"), ((WINDOW, False),
+                                                   "a window of 32 rows")):
+        with pytest.raises(RuntimeError, match=f"{why}.*ROADMAP D11"):
+            registry.resolve_impl(
+                "grouped_attention", impl="pallas",
+                info=serving_layers.grouped_info(spec, cfg, sched, 1,
+                                                 jnp.float32, *kind))
+
+
+@pytest.mark.parametrize("way,ringed", [("oracle", True), ("kernel", True),
+                                        ("kernel", False)])
+def test_rows_walked_is_what_each_kind_of_layer_fetches(way, ringed,
+                                                        chip_rule):
+    """`serve.attn.rows_walked`: a full layer fetches a slot's cached
+    length rounded up to a block where its decode is the walk and the
+    table's whole width where it is the gather; a sliding layer always
+    gathers — its ring, or (one group: the ring would be no shorter than
+    the table) the table under the window.  The kernel serves the
+    oracle's tokens."""
+    import contextlib
+
+    model, params = _model()
+    serve = _serve() if ringed else _serve(max_seq_len=RING)
+    lengths = (8, 2 * WINDOW + 1) if ringed else (8, 21)
+    prompts = [_prompt(n, i) for i, n in enumerate(lengths)]
+    with chip_rule("grouped_attention") if way == "kernel" \
+            else contextlib.nullcontext():
+        eng = ServeEngine(model, params, serve)
+        before = COUNTERS.snapshot()
+        out = eng.generate(prompts, 6)
+    d = COUNTERS.delta_since(before)
+    assert bool(eng.kv.ring_blocks) == ringed
+    assert eng._walks_live_blocks == (way == "kernel")
+    assert not eng._sliding_walks
+    held = [n + i + 1 for n in lengths for i in range(5)]
+    table = eng.kv.table_width * BS
+    full = sum(-(-h // BS) * BS for h in held) if way == "kernel" \
+        else 10 * table
+    assert d["serve.attn.rows_walked"] == {
+        "calls": 10,
+        "bytes": 2 * full + 6 * 10 * (RING if ringed else table)}
+    assert d["serve.attn.rows_read"]["bytes"] <= \
+        d["serve.attn.rows_walked"]["bytes"]
+    if way == "kernel":
+        # decode's two full layers, and no other call, took the kernel
+        assert d["kernel.dispatches"]["calls"] == 2
+        assert out == ServeEngine(model, params, serve).generate(prompts, 6)
+    else:
+        assert "kernel.dispatches" not in d
 
 
 # -- the layer spec -------------------------------------------------------------

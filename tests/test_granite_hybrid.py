@@ -7,6 +7,8 @@ hidden 32, 4 query heads on 2 K/V heads of 8, 4 state-space heads of 8
 over a state of 16, convolution 4, scan chunk 4, vocabulary 97.
 """
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -555,7 +557,6 @@ def test_a_cache_with_a_state_refuses_what_it_cannot_hold():
 
 @pytest.mark.parametrize("way", ["oracle", "kernel"])
 def test_counters_of_the_state_space_layers(way):
-    import contextlib
 
     model, params = _model()
     with _forced() if way == "kernel" else contextlib.nullcontext():
@@ -586,6 +587,44 @@ def test_counters_of_the_state_space_layers(way):
     assert d["serve.attn.rows_read"] == {"calls": 10, "bytes": 2 * sum(held)}
     assert "serve.window.rows_read" not in d
     assert "serve.paged.rows_walked" not in d
+
+
+@pytest.mark.parametrize("way", ["oracle", "kernel"])
+def test_rows_walked_is_what_the_attention_layers_fetch(way, chip_rule):
+    """`serve.attn.rows_walked` beside `serve.attn.rows_read`: where the
+    registry answers the oracle for the decode program's shapes (every
+    backend but the TPU) each attention layer gathers the table's whole
+    width for every running slot; where it answers the walk, a slot's
+    cached length rounded up to a block — and the tokens served are the
+    oracle's."""
+    model, params = _model()
+    # blocks that are whole tiles of float32, a chunk no verify step is
+    serve = _serve(block_size=8, num_blocks=32, prefill_chunk=16)
+    lengths, bs = (8, 19), 8
+    prompts = [_prompt(n, i) for i, n in enumerate(lengths)]
+    with chip_rule("grouped_attention") if way == "kernel" \
+            else contextlib.nullcontext():
+        eng = ServeEngine(model, params, serve)
+        before = COUNTERS.snapshot()
+        out = eng.generate(prompts, 6)
+    d = COUNTERS.delta_since(before)
+    assert eng._walks_live_blocks == (way == "kernel")
+    attention = LAYERS - len(STATE_LAYERS)
+    held = [n + i + 1 for n in lengths for i in range(5)]
+    assert d["serve.attn.rows_read"] == {
+        "calls": 10, "bytes": attention * sum(held)}
+    fetched = sum(-(-h // bs) * bs for h in held) if way == "kernel" \
+        else 10 * eng.kv.table_width * bs
+    assert d["serve.attn.rows_walked"] == {
+        "calls": 10, "bytes": attention * fetched}
+    assert d["serve.attn.rows_read"]["bytes"] <= \
+        d["serve.attn.rows_walked"]["bytes"]
+    if way == "kernel":
+        # decode's attention layers, and no other call, took the kernel
+        assert d["kernel.dispatches"]["calls"] == attention
+        assert out == ServeEngine(model, params, serve).generate(prompts, 6)
+    else:
+        assert "kernel.dispatches" not in d
 
 
 def test_seating_is_a_phase_of_the_loop(tmp_path):

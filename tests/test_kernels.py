@@ -134,12 +134,13 @@ def test_moe_combine_parity_one_ulp():
 
 
 def _paged_inputs(kv_mode, R=2, T=1, H=2, Dh=128, bs=4, W=4, seed=0,
-                  lengths=None, dead_to_trash=False):
-    """A pool `[rows, pool_width(H, Dh)]`, tables [R, W] and query
+                  lengths=None, dead_to_trash=False, KV=None):
+    """A pool `[rows, pool_width(KV or H, Dh)]`, tables [R, W] and query
     positions [R, T].  `lengths` [R]: what each slot holds once this
     call's rows are written (0: the slot is idle, its positions -1);
     None draws them.  `dead_to_trash`: table entries past a slot's
-    length point at the trash block, as the allocator pads them."""
+    length point at the trash block, as the allocator pads them.  `KV`:
+    the rows hold that many heads, fewer than the H of the queries."""
     from deepspeed_tpu.runtime.comm.quant import quantize_rows
     from deepspeed_tpu.serving.kv_cache import pool_rows
 
@@ -147,7 +148,7 @@ def _paged_inputs(kv_mode, R=2, T=1, H=2, Dh=128, bs=4, W=4, seed=0,
     nblocks = R * W + 1
 
     def pool():
-        c = jnp.asarray(rng.randn(nblocks * bs, H, Dh), jnp.float32)
+        c = jnp.asarray(rng.randn(nblocks * bs, KV or H, Dh), jnp.float32)
         if kv_mode == "dense":
             return pool_rows(c)
         codes, scales = quantize_rows(c, kv_mode)
@@ -169,18 +170,29 @@ def _paged_inputs(kv_mode, R=2, T=1, H=2, Dh=128, bs=4, W=4, seed=0,
             jnp.asarray(q_pos, jnp.int32), bs)
 
 
-def _paged_both(kv_mode, **kw):
+def _paged_both(kv_mode, scale=None, **kw):
+    """(kernel, oracle, q_pos) of one call: `paged_attention`, or with
+    `KV` heads a row `grouped_attention` — the walk at a grouped tile
+    against the gather `_grouped_attend` ran before there was one."""
     from deepspeed_tpu.kernels.paged import paged_attention_reference
+    from deepspeed_tpu.serving.layers import grouped_attention_reference
 
     q, ck, cv, tables, q_pos, bs = _paged_inputs(kv_mode, **kw)
-    ref = paged_attention_reference(q, ck, cv, tables, q_pos,
-                                    kv_mode=kv_mode, block_size=bs)
+    if kw.get("KV"):
+        op = "grouped_attention"
+        args = dict(kv_heads=kw["KV"], block_size=bs, scale=scale)
+        ref = grouped_attention_reference(q, ck, cv, tables, q_pos, **args)
+        assert ref.dtype == jnp.float32
+    else:
+        op, args = "paged_attention", dict(kv_mode=kv_mode, block_size=bs)
+        ref = paged_attention_reference(q, ck, cv, tables, q_pos, **args)
     with kernel_config(interpret=True):
-        out = registry.dispatch("paged_attention", q, ck, cv, tables,
-                                q_pos, variant="default", impl="pallas",
-                                kv_mode=kv_mode, block_size=bs)
+        out = registry.dispatch(op, q, ck, cv, tables, q_pos,
+                                variant="default", impl="pallas", **args)
     assert out.shape == ref.shape and out.dtype == ref.dtype
-    return np.asarray(out, np.float32), np.asarray(ref, np.float32), q_pos
+    shape = q.shape if kw.get("KV") else out.shape
+    return (np.asarray(out, np.float32).reshape(shape),
+            np.asarray(ref, np.float32).reshape(shape), q_pos)
 
 
 # slots of length 1, one full block, one block plus one row, the full
@@ -188,12 +200,21 @@ def _paged_both(kv_mode, **kw):
 _EDGE_LENGTHS = [1, 4, 5, 16, 0]
 
 
+# the same on and beside the edges of blocks of 16 in a table of 20:
+# several tiles of the walk, the last entry full
+_EDGE_LENGTHS_16 = [1, 16, 17, 255, 257, 320, 0]
+# Granite 4.0-H's rows (32 query heads on 8 K/V heads of 64, scores
+# times 1/64) and Command A+'s (128 on 8 of 128)
+_GRANITE = dict(H=32, KV=8, Dh=64, scale=1 / 64)
+_COMMAND_A = dict(H=128, KV=8, Dh=128)
+
+
 def _paged_case_id(v):
     if not isinstance(v, dict):
         return str(v)
     return "_".join(
         f"{k}{'x'.join(map(str, x)) if isinstance(x, list) else x}"
-        for k, x in v.items()) or "H2_Dh128"
+        for k, x in v.items()).replace("0.015625", "64th") or "H2_Dh128"
 
 
 @pytest.mark.parametrize("kv_mode,T,shape", [
@@ -215,6 +236,22 @@ def _paged_case_id(v):
     # a table wider than one tile of blocks: several steps of the walk
     ("dense", 1, dict(H=25, Dh=64, R=3, bs=16, W=40,
                       lengths=[640, 257, 30], dead_to_trash=True)),
+    # grouped rows, a tile of the row's K/V heads serving G query heads
+    # a key: idle slots, one row, a block's edges, the full table
+    ("dense", 1, dict(_GRANITE, R=5, lengths=_EDGE_LENGTHS)),
+    ("dense", 1, dict(_GRANITE, R=5, lengths=_EDGE_LENGTHS,
+                      dead_to_trash=True)),
+    ("dense", 1, dict(_GRANITE, R=7, bs=16, W=20,
+                      lengths=_EDGE_LENGTHS_16, dead_to_trash=True)),
+    ("dense", 1, dict(_COMMAND_A, R=5, lengths=_EDGE_LENGTHS)),
+    ("dense", 1, dict(_COMMAND_A, R=7, bs=16, W=20,
+                      lengths=_EDGE_LENGTHS_16, dead_to_trash=True)),
+    # a verify step's queries, and rows narrower than a lane tile (the
+    # toy models of tests/test_granite_hybrid.py, test_cohere2_moe.py)
+    ("dense", 3, dict(_GRANITE, R=5, lengths=[3, 4, 5, 16, 0],
+                      dead_to_trash=True)),
+    ("dense", 1, dict(H=8, KV=2, Dh=16, R=5, lengths=_EDGE_LENGTHS)),
+    ("dense", 2, dict(H=4, KV=2, Dh=16, R=5, lengths=[2, 4, 5, 16, 0])),
 ], ids=_paged_case_id)
 def test_paged_attention_parity(kv_mode, T, shape):
     """The table walk (quantized dequant done on the tile) vs the
@@ -227,13 +264,90 @@ def test_paged_attention_parity(kv_mode, T, shape):
     assert not out[~live].any()
 
 
-def test_paged_attention_slot_ignores_the_other_slots():
+@pytest.mark.parametrize("shape", [dict(H=25, Dh=64), _GRANITE,
+                                   _COMMAND_A], ids=_paged_case_id)
+def test_paged_attention_slot_ignores_the_other_slots(shape):
     """Batching invariance of the kernel: a slot's output is the same
     bits whatever the other slots hold — long, short or idle."""
-    kw = dict(H=25, Dh=64, R=3, bs=4, W=8, dead_to_trash=True)
+    kw = dict(shape, R=3, bs=4, W=8, dead_to_trash=True)
     a, _, _ = _paged_both("dense", lengths=[13, 32, 7], **kw)
     b, _, _ = _paged_both("dense", lengths=[13, 1, 0], **kw)
     assert np.array_equal(a[0], b[0])
+
+
+def test_grouped_attention_kernel_refuses_a_window_and_a_ring():
+    """Forced onto rows it has no liveness rule for, the kernel says so
+    and computes nothing."""
+    q, ck, cv, tables, q_pos, bs = _paged_inputs("dense", **{
+        k: v for k, v in _GRANITE.items() if k != "scale"})
+    for kw in (dict(window=8), dict(newest=q_pos[:, 0])):
+        with kernel_config(interpret=True), \
+                pytest.raises(ValueError, match="one causal run"):
+            registry.dispatch("grouped_attention", q, ck, cv, tables, q_pos,
+                              impl="pallas", kv_heads=8, block_size=bs, **kw)
+
+
+def test_grouped_oracle_is_the_expression_the_layer_ran():
+    """`grouped_attention` through the registry off the chip IS
+    `attend_grouped` over the gathered table under `_visible`, bit for
+    bit — under a window and over a ring too."""
+    from deepspeed_tpu.models.cohere2_moe import attend_grouped
+    from deepspeed_tpu.serving.layers import _visible
+
+    q, ck, cv, tables, q_pos, bs = _paged_inputs(
+        "dense", H=8, KV=2, Dh=16, R=3, bs=4, W=4, lengths=[16, 7, 0])
+    L, lanes = 16, ck.shape[1]
+    held = lambda c: c.reshape(-1, bs, lanes)[tables].reshape(
+        3, -1, lanes)[..., :32].reshape(3, -1, 2, 16)
+    at = jnp.arange(L)[None, :]
+    for window, newest in ((0, None), (5, None), (5, q_pos[:, 0] + 20)):
+        rows = at if newest is None else \
+            newest[:, None] - (newest[:, None] - at) % L
+        want = attend_grouped(q, held(ck), held(cv),
+                              _visible(rows, q_pos, window), scale=0.3)
+        got = registry.dispatch(
+            "grouped_attention", q, ck, cv, tables, q_pos,
+            info={"ring": newest is not None, "window": window},
+            kv_heads=2, block_size=bs, scale=0.3, window=window,
+            newest=newest)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+_GRANITE_INFO = dict(block_size=16, table_width=128, q_len=1, num_heads=32,
+                     kv_heads=8, head_dim=64, kv_mode="dense",
+                     kv_itemsize=2, window=0, ring=False)
+
+
+@pytest.mark.parametrize("change,why", [
+    ({}, None),
+    (dict(q_len=4), None),
+    # Command A+'s full layer: 128 score rows of 1,024 lanes
+    (dict(num_heads=128, head_dim=128, table_width=1024), None),
+    (dict(q_len=512), "q_len 512 is a prefill chunk"),
+    (dict(window=4096), "a window of 4096 rows.*ROADMAP D11"),
+    (dict(window=4096, ring=True), "the rows are a ring.*ROADMAP D11"),
+    (dict(block_size=8), "a block of 8 rows is not whole tiles"),
+    (dict(kv_mode="int8"), "int8 rows"),
+    (dict(q_len=8, num_heads=128, head_dim=128),
+     "8 x 128 score rows of 1024 lanes"),
+], ids=lambda v: _paged_case_id(v) if isinstance(v, dict) else "")
+def test_grouped_attention_shape_rule(change, why, native):
+    """What the call site can see decides (serving/layers.py::
+    grouped_info): a full layer's decode call takes the walk on the chip
+    at the grouped tile; prefill, a window, a ring and every shape the
+    walk cannot copy take the gather and say what is missing when the
+    kernel is forced."""
+    info = dict(_GRANITE_INFO, **change)
+    if why is None:
+        assert resolve_impl("grouped_attention", info=info) == "pallas"
+        return
+    assert resolve_impl("grouped_attention", info=info) == "jnp"
+    with pytest.raises(RuntimeError, match=why):
+        resolve_impl("grouped_attention", impl="pallas", info=info)
+
+
+def test_grouped_attention_is_the_oracle_off_the_chip():
+    assert resolve_impl("grouped_attention", info=_GRANITE_INFO) == "jnp"
 
 
 def _eva_inputs(positions, T=1, H=2, Dh=64, bs=4, window=32, chunk=4,

@@ -159,6 +159,42 @@ def _paged(kv_mode, heads, dh, slots=16, bs=16, width=64, nblocks=1025,
     return fn, shapes, info
 
 
+def _grouped(slots=64, heads=32, kv=8, dh=64, bs=16, width=128,
+             nblocks=8193, q_len=1, scale=1 / 64, window=0, ring=False,
+             dtype=jnp.bfloat16):
+    """`grouped_attention` as a grouped layer's serving block calls it:
+    Granite 4.0-H Micro's cell by default (64 slots, 32 query heads on 8
+    K/V heads of 64, 8,193 blocks of 16 rows, a table of 128 entries,
+    bf16 rows, scores times 1/64)."""
+    from deepspeed_tpu.serving.kv_cache import pool_width
+
+    cache = _sds((nblocks * bs, pool_width(kv, dh)), dtype)
+    shapes = (_sds((slots, q_len, heads, dh), dtype), cache, cache,
+              _sds((slots, width), jnp.int32),
+              _sds((slots, q_len), jnp.int32))
+    info = {"block_size": bs, "table_width": width, "q_len": q_len,
+            "num_heads": heads, "head_dim": dh, "kv_mode": "dense",
+            "kv_itemsize": jnp.dtype(dtype).itemsize, "kv_heads": kv,
+            "window": window, "ring": ring}
+    if ring:
+        shapes += (_sds((slots,), jnp.int32),)
+
+    def fn(q, ck, cv, tables, q_pos, newest=None):
+        return registry.dispatch("grouped_attention", q, ck, cv, tables,
+                                 q_pos, info=info, kv_heads=kv,
+                                 block_size=bs, scale=scale, window=window,
+                                 newest=newest)
+
+    return fn, shapes, info
+
+
+def _command_a_full(**kw):
+    """Command A+'s full layer in its cell: 16 slots, 128 query heads on
+    8 K/V heads of 128, 16,385 blocks, a table of 1,024 entries."""
+    return _grouped(**dict(dict(slots=16, heads=128, dh=128, width=1024,
+                                nblocks=16385, scale=None), **kw))
+
+
 def _eva(slots=8, heads=32, dh=128, bs=16, window=2048, chunk=16,
          summary_blocks=64, nblocks=1537, q_len=1, dtype=jnp.bfloat16):
     """`eva_attention` as EvaByte's serving block calls it: the cell's
@@ -332,6 +368,25 @@ CASES = [
     Case("eva_H32_Dh128_summaries_not_whole_blocks",
          lambda: _eva(chunk=256),
          op="eva_attention", refused=r"8 summary rows are not whole"),
+    # grouped rows: a full layer's decode call at the two cells' tiles
+    # (32 x 512 and 128 x 1,024), a verify step, and what keeps the
+    # gather — prefill, a window on the table, a ring
+    Case("grouped_H32_KV8_Dh64_chatrate", _grouped, op="grouped_attention"),
+    Case("grouped_H128_KV8_Dh128_mixedlen_full", _command_a_full,
+         op="grouped_attention"),
+    Case("grouped_H32_KV8_Dh64_verify4", lambda: _grouped(q_len=4),
+         op="grouped_attention"),
+    Case("grouped_H32_KV8_Dh64_prefill512",
+         lambda: _grouped(slots=1, q_len=512),
+         op="grouped_attention", refused=r"q_len 512 is a prefill chunk"),
+    Case("grouped_H128_KV8_Dh128_window_on_the_table",
+         lambda: _command_a_full(window=4096),
+         op="grouped_attention",
+         refused=r"a window of 4096 rows: .*no lower bound.*D11"),
+    Case("grouped_H128_KV8_Dh128_mixedlen_ring",
+         lambda: _command_a_full(window=4096, ring=True, width=288,
+                                 nblocks=16 * 288 + 1),
+         op="grouped_attention", refused=r"rows are a ring .*D11"),
     Case("codec_quantize_int8_4M_block256",
          lambda: _codec("quantize", "int8"),
          op="quant_codec", variant="quantize"),
@@ -430,6 +485,17 @@ def _serve_attention(q_len, slots):
      lambda: ("eva_attention", _eva()[2]), "pallas"),
     ("evabyte-d16.serve.longdoc.prefill",
      lambda: ("eva_attention", _eva(slots=1, q_len=1024)[2]), "jnp"),
+    # 64 slots, 8,193 blocks of 16, a table of 128 entries, bf16
+    ("granite-4.0-h-micro.serve.chatrate.decode",
+     lambda: ("grouped_attention", _grouped()[2]), "pallas"),
+    ("granite-4.0-h-micro.serve.chatrate.prefill",
+     lambda: ("grouped_attention", _grouped(slots=1, q_len=512)[2]), "jnp"),
+    # 16 slots; the full layer's table of 1,024, the sliding ones' rings
+    ("command-a-plus-d4.serve.mixedlen.decode.full",
+     lambda: ("grouped_attention", _command_a_full()[2]), "pallas"),
+    ("command-a-plus-d4.serve.mixedlen.decode.sliding",
+     lambda: ("grouped_attention",
+              _command_a_full(window=4096, ring=True, width=288)[2]), "jnp"),
 ], ids=lambda v: v if isinstance(v, str) and "." in v else "")
 def test_auto_choice_for_each_benchmark_cell(cell, call, expect, native):
     """The trace-time half of "the same numbers": at each cell's shapes
@@ -704,9 +770,12 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     experts and an eighth of the vocabulary, 16 slots, 16,385 blocks of
     16 rows for the full layer and 16 x 288 + 1 for each sliding one, a
     table of 1,024 + 288 entries, chunk 512), as the chip traces them:
-    asked for grouped rows the registry answers `oracle` by name, so
-    `decode`'s only custom calls are the 4 layers' `touched_experts`
-    kernels and the only ones in `prefill` are XLA's own grouped products
+    the registry answers the walk for the full layer's decode call and
+    the gather for the three rings and for prefill, so `decode`'s custom
+    calls are the 4 layers' `touched_experts` kernels and the full
+    layer's one walk — no slot's whole table of 16,384 rows is laid out
+    as `[16, 16384, 8, 128]` — and the only ones in `prefill` are XLA's
+    own grouped products
     (`lax.ragged_dot` over the 16 held experts); every pool enters as
     `[rows, 1024]`, a K and a V a layer; and weights, pools and
     temporaries fit the chip's 15.75 GB with the room the check's 2.15 GB
@@ -723,15 +792,12 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     sched = ServeSchedule(max_batch=slots, prefill_chunk=chunk,
                           block_size=bs, num_blocks=nblocks,
                           table_width=width, ring_blocks=ring)
-    info = grouped_info(model.layer_spec(), model.config, sched, 1,
-                        jnp.bfloat16)
-    supported, why = registry.get_kernel("paged_attention").auto_supports(
-        "default", info)
-    assert not supported and "grouped rows" in why
-    # (with a K/V head a query head the rule would be the walk's own)
-    assert "grouped rows" not in registry.get_kernel(
-        "paged_attention").auto_supports(
-            "default", dict(info, kv_heads=info["num_heads"]))[1]
+    spec = model.layer_spec()
+    ask = lambda q_len, *kind: registry.resolve_impl(
+        "grouped_attention", info=grouped_info(
+            spec, model.config, sched, q_len, jnp.bfloat16, *kind))
+    assert (ask(1), ask(1, 4096, True), ask(chunk)) == ("pallas", "jnp",
+                                                        "jnp")
     progs = ServeProgramBuilder(model, sched).build()
 
     def on(shape, dtype):
@@ -761,8 +827,10 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     if program == "decode":
-        assert len(calls) == layers
-        assert all("touched_experts" in ln for ln in calls)
+        assert len(calls) == layers + 1
+        assert sum("touched_experts" in ln for ln in calls) == layers
+        assert sum("paged_attention_walk" in ln for ln in calls) == 1
+        assert (slots, 16384, 8, 128) not in _hlo_by_shape(text)
     else:
         assert calls and all("ragged" in ln for ln in calls)
     for rows in (nblocks * bs, (slots * ring + 1) * bs):
@@ -783,9 +851,11 @@ def test_granite_hybrid_cell_programs_compile_inside_one_chip(program,
     cell's shapes (all 40 layers at published widths in bf16, 64 slots,
     8,193 blocks of 16 rows for the 4 attention layers, a float32 state
     `[64, 64, 64, 128]` and `[64, 3, 4352]` convolution inputs for each
-    of the 36 state-space layers, chunk 512): `decode`'s only custom
-    calls are the 36 state-space layers' `ssm_step_live` kernels,
-    `prefill` has none (grouped rows go to `jax.numpy`, the scan is
+    of the 36 state-space layers, chunk 512): `decode`'s custom calls
+    are the 36 state-space layers' `ssm_step_live` kernels and the 4
+    attention layers' walks of the live blocks — no slot's whole table is
+    re-laid as `[64, 2048, 8, 64]` — `prefill` has none (a chunk's
+    grouped rows go to `jax.numpy`, the scan is
     `jax.numpy`), every state enters and leaves under its own shape —
     updated in place, not copied beside itself: the kernel's state is
     aliased input to output, and no temporary is as large as one layer's
@@ -836,8 +906,10 @@ def test_granite_hybrid_cell_programs_compile_inside_one_chip(program,
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     if program == "decode":
-        assert len(calls) == 36
-        assert all("ssm_step_live" in ln for ln in calls)
+        assert len(calls) == 40
+        assert sum("ssm_step_live" in ln for ln in calls) == 36
+        assert sum("paged_attention_walk" in ln for ln in calls) == 4
+        assert (slots, seq, 8, 64) not in _hlo_by_shape(text)
         # a layer's state enters, goes through the kernel and leaves:
         # nothing copies it, nothing else of its size is built
         # (the kernel takes it as [slots, 32, 128, 128]: the same bytes)
