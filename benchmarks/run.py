@@ -14,8 +14,9 @@ This file knows no cell, model or metric by name.  It finds them from
 It refuses to run (exit code 2, no result line) unless JAX finds TPUs, as
 many as the cell asks for, of a kind listed in peaks.json: there is no CPU
 mode and no default peak.  The last line of stdout is the one JSON object
-of the contract; everything else (MFU, compiles in the window, how late
-the generator ran) is printed on earlier lines as `# <json>`.
+of the contract, with what the check compared beside its limits under
+`check`, its last key (and as stderr's last line); everything else (MFU,
+how late the generator ran) is printed on earlier lines as `# <json>`.
 """
 
 from __future__ import annotations
@@ -165,11 +166,6 @@ def main(argv=None) -> int:
     run = plugin("runners", cell.workload["runner"]).run(cell)
     for line in run.notes:
         note(**line)
-    if not run.correct:  # the check's own numbers, where a log's tail shows
-        print("benchmarks/run.py: incorrect: " + json.dumps(
-            [run.notes[-1]] + [{k: n[k]} for n in run.notes
-                               for k in n if k == "compiles_in_window"]),
-            file=sys.stderr)
 
     trace = None
     if cell.trace:
@@ -184,6 +180,14 @@ def main(argv=None) -> int:
               "device": device_block(cell, run, trace)}
     if trace is not None:
         result["breakdown"] = trace.breakdown()
+    # what the check compared, each number beside its limit (a runner's
+    # last note), and the compiles the window held: the line's last key
+    # and stderr's last line
+    result["check"] = dict(run.notes[-1], **{
+        "compiles_in_window": n["compiles_in_window"]
+        for n in run.notes[:-1] if "compiles_in_window" in n})
+    print("benchmarks/run.py: " + ("compared" if run.correct else "incorrect")
+          + ": " + json.dumps(result["check"]), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -126,7 +126,9 @@ def test_the_observer_stamps_tokens_by_its_own_clock():
     obs.stop()
     first, second, third = obs.times[0]
     assert 0.05 <= first - t0 < 0.07 and second == third
-    assert 0.05 <= second - first < 0.07
+    # a stamp is late by up to a tick and its look, so the second may follow
+    # the first by that much under the 50 ms the tokens lay apart
+    assert 0.05 - 2 * obs.tick <= second - first < 0.07
     assert obs.finished[0] >= third and obs.late_max < 0.05
 
 

@@ -199,11 +199,11 @@ def test_the_configuration_file_keeps_every_published_size():
 
 
 def test_the_cell_is_in_the_benchmark_with_its_metrics():
+    """Looked up by name: what other cells and metrics the benchmark
+    holds, and in which order, is not this test's."""
     with open(harness.BENCH + "/../BENCHMARK.json") as f:
         bm = json.load(f)
-    assert len(bm["workloads"]) == 7
     entry = next(w for w in bm["workloads"] if w["name"] == CELL)
-    assert entry == bm["workloads"][-1]
     assert entry["chips"] == 1 and len(entry["why"]) <= 200
     assert sum(w["chips"] == 4 for w in bm["workloads"]) == 0
     cell = harness.load_json("workloads", CELL + ".json")
@@ -221,13 +221,11 @@ def test_the_cell_is_in_the_benchmark_with_its_metrics():
     assert mix["prompt_tokens"] == [256, 15360]
     assert mix["output_tokens"] == [64, 512]
     assert mix["shape_seed"] == 20260930 and mix["max_total_tokens"] == 16384
-    mine = [m["name"] for m in bm["per_layer"]
-            if CELL in m.get("workloads", [])]
-    assert len(mine) == 17 and mine[-3:] == [
-        "window_rows_per_query.serve", "attn_rows_per_query.serve",
-        "swa_moe_decode_hbm_roofline.serve"]
-    for m in bm["per_layer"][-3:]:
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_itl_p95_ms"
+    by_name = {m["name"]: m for m in bm["per_layer"]}
+    for name in ("window_rows_per_query.serve", "attn_rows_per_query.serve",
+                 "swa_moe_decode_hbm_roofline.serve"):
+        assert CELL in by_name[name]["workloads"], name
+        assert by_name[name]["moves"] == "serve_itl_p95_ms"
     # the first-token tail is not admissible at 40 requests (PERF.md
     # section 2, PR 44): the cell reports the other two
     for name, listed in (("serve_ttft_p95_ms", False),

@@ -61,9 +61,16 @@ def test_training_on_a_mesh_of_four(tmp_path):
 
 
 @pytest.mark.parametrize("trace", [False, True])
-def test_serving_cell_runs_and_matches_its_reference(tmp_path, trace):
+def test_serving_cell_runs_and_matches_its_reference(tmp_path, monkeypatch,
+                                                     trace):
     from benchmarks.runners import serve
 
+    # a toy step on the CPU takes ~0.6 ms, under the observer's 5 ms tick:
+    # a request's tokens would be stamped at one look and the gaps' 95th
+    # percentile could read 0.  Here the observer looks every 0.5 ms, so
+    # the step is longer than the tick, as it is in every cell on the chip
+    monkeypatch.setattr(serve.TokenObserver.__init__, "__defaults__",
+                        (0.0005,))
     run = serve.run(toy.cell(toy.GPT, toy.SERVE, toy.CHAT, tmp=tmp_path,
                              seconds=1.5, trace=trace))
     load, check = run.notes
@@ -75,6 +82,8 @@ def test_serving_cell_runs_and_matches_its_reference(tmp_path, trace):
     for name in ("serve_ttft_p95_ms", "serve_itl_p95_ms",
                  "serve_tokens_per_s", "setup_s"):
         assert run.end_to_end[name] > 0, name
+    assert 0 < load["itl_ms_median"] <= run.end_to_end["serve_itl_p95_ms"] \
+        <= load["itl_ms_max"]
     assert run.counters["serve.decode_steps"]["calls"] > 0
     if trace:
         waits = [e for e in run.program_spans if e["name"] == "queue_wait"]
@@ -121,8 +130,12 @@ def test_the_command_prints_the_contract_line(tmp_path, monkeypatch, capsys,
     assert ("run.py: incorrect: " in captured.err) == (rtol == 0.0)
     assert rc == 0 and all(ln.startswith("# ") for ln in lines[:-1])
     last = json.loads(lines[-1])
-    assert set(last) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+    assert list(last) == ["correct", "attempted", "failed", "metrics",
+                          "device", "check"]
+    assert last["check"]["rtol"] == rtol and "compiles_in_window" in \
+        last["check"]
+    assert json.loads(captured.err.strip().splitlines()[-1].split(
+        ": ", 2)[2]) == last["check"]
     want = {m["name"] for m in bm["end_to_end"]
             if entry["name"] in m.get("workloads", [entry["name"]])}
     assert set(last["metrics"]) == want and "setup_s" in want
