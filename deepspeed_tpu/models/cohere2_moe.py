@@ -178,19 +178,25 @@ def attend_grouped(q, k, v, mask, scale=None):
     return jnp.moveaxis(out, 0, 2).reshape(B, T, H * Dh)
 
 
-def expert_ffn(spec, cfg, p, h, live=None):
-    """h [..., D] float32 -> (the routed FFN the layer spec describes,
-    float32: the router's `spec.scoring`, the chosen weights over their
-    sum where `spec.renormalize`, the held experts' weighted sum
-    (`spec.held`: a share of `cfg.num_experts`, or all) plus the shared
-    experts' sum or, with `spec.shared` "average", their mean; None, or
-    with `live` [tokens] — the tokens whose sum anyone reads — how many
-    of the experts held the live tokens touched, int32)."""
-    flat = h.reshape(-1, h.shape[-1])
+def routed_ffn(spec, cfg, p, flat, live=None):
+    """flat [T, D] float32 -> (y [T, D] float32, experts [T, top_k],
+    count, held): the routed FFN the layer spec describes — the router's
+    `spec.scoring`, the chosen weights over their sum where
+    `spec.renormalize` (chosen by the scores plus the layer's
+    `select_bias` where `spec.select_bias`, times `spec.route_scale`),
+    the held experts' weighted sum (`spec.held`: a share of
+    `cfg.num_experts`, or all) plus the shared experts' sum or, with
+    `spec.shared` "average", their mean — and what `experts_touched`
+    counts the touched experts from: the experts chosen, numbered among
+    the `count` held, and which assignments are `held` (None: all).
+    `live` [T]: the tokens whose sum anyone reads (None: all)."""
     with jax.named_scope("moe_route"):
         weights, idx = route(flat, p["router"], spec.top_k,
                              scoring=spec.scoring,
-                             renormalize=spec.renormalize)
+                             renormalize=spec.renormalize,
+                             select_bias=p["select_bias"]
+                             if spec.select_bias else None,
+                             scale=spec.route_scale)
         held, count = None, cfg.num_experts
         if spec.held is not None:
             weights, idx, held = held_assignments(weights, idx, *spec.held)
@@ -202,6 +208,15 @@ def expert_ffn(spec, cfg, p, h, live=None):
         shared = silu_gated_ffn(p["shared"], flat)
         y = y + (shared / cfg.num_shared if spec.shared == "average"
                  else shared)
+    return y, idx, count, held
+
+
+def expert_ffn(spec, cfg, p, h, live=None):
+    """h [..., D] float32 -> (`routed_ffn` of its tokens, float32; None,
+    or with `live` [tokens] how many of the experts held the live tokens
+    touched, int32)."""
+    y, idx, count, held = routed_ffn(spec, cfg, p,
+                                     h.reshape(-1, h.shape[-1]), live)
     touched = None if live is None else \
         experts_touched(idx, live, count, held)
     return y.reshape(h.shape), touched

@@ -25,6 +25,17 @@ positions cover the `rope` dims only, half-split pairing, with YaRN's
 blended frequencies (`yarn_inv_freq`) and cos, sin times
 m(mscale) / m(mscale_all_dim), m(s) = 0.1 s ln(factor) + 1.
 
+The pieces also serve the families built from them
+(models/glm_moe_dsa.py): a config with `q_lora_rank` > 0 takes its
+queries through a low-rank path, c_q = RMSNorm(h W_q_a), q = c_q W_q_b
+(`latent_project(..., with_cq=True)` hands c_q on: an indexer reads
+it), and one with `rope_interleave` pairs dims 2i and 2i + 1 where this
+model pairs i and i + dr/2.  A learned selection of the rows a query
+attends reaches `attend_expanded` and `attend_absorbed` as what they
+already take: the rows (all of a sequence's, or the chosen ones
+gathered) and a mask [B, T, K] that is False for a row the query did
+not choose.
+
 FFN: the first `first_k_dense` layers a SiLU-gated FFN; the others a
 float32 softmax router over `num_experts`, the `top_k` largest kept
 unrenormalised, every assignment computed (moe/dropless.py), plus the
@@ -82,6 +93,8 @@ class DeepSeekV2Config:
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     yarn: Optional[Yarn] = Yarn(40.0, 4096, 32.0, 1.0, 0.707, 0.707)
+    q_lora_rank: int = 0             # > 0: queries through c_q of this rank
+    rope_interleave: bool = False    # rotary pairs (2i, 2i + 1)
     # seeded weights only: every matrix N(0, init_std); the router's own
     # scale decides how sharply it picks (at init_std a softmax over the
     # experts is flat)
@@ -155,10 +168,11 @@ def yarn_inv_freq(dim: int, theta: float, yarn: Optional[Yarn]):
     return plain * (1.0 - ramp) + plain / yarn.factor * ramp
 
 
-def rope_part(x, positions, theta, yarn):
+def rope_part(x, positions, theta, yarn, interleave: bool = False):
     """Rotary positions over all of x's last axis (the rotary part of a
-    head), half-split pairing.  x [..., T, (H,) dr] with positions
-    [..., T] -> float32; `x.ndim - positions.ndim` trailing axes ride."""
+    head), half-split pairing — or, with `interleave`, dims 2i and
+    2i + 1 together.  x [..., T, (H,) dr] with positions [..., T] ->
+    float32; `x.ndim - positions.ndim` trailing axes ride."""
     dr = x.shape[-1]
     ang = positions.astype(jnp.float32)[..., None] * \
         yarn_inv_freq(dr, theta, yarn)
@@ -168,24 +182,41 @@ def rope_part(x, positions, theta, yarn):
     for _ in range(x.ndim - positions.ndim - 1):
         cos, sin = cos[..., None, :], sin[..., None, :]
     x32 = x.astype(jnp.float32)
+    if interleave:
+        a, b = x32[..., 0::2], x32[..., 1::2]
+        return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                         axis=-1).reshape(x.shape)
     a, b = x32[..., :dr // 2], x32[..., dr // 2:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
-def latent_project(cfg, p, h, positions, dtype):
+def latent_project(cfg, p, h, positions, dtype, with_cq: bool = False):
     """h [B, T, D] at positions [B, T] -> (q_nope [B, T, H, nope],
     q_rope [B, T, H, rope] rotated, rows [B, T, rank + rope]: the
-    latent c after its norm beside the rotated key), at `dtype`."""
+    latent c after its norm beside the rotated key), at `dtype`; with
+    `with_cq` a fourth: the queries' own latent c_q [B, T, q_lora_rank]
+    after its norm, float32."""
     B, T, _ = h.shape
     nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    q = matmul32(h, p["q"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
-    q_rope = rope_part(q[..., nope:], positions, cfg.rope_theta, cfg.yarn)
+    pair = cfg.rope_interleave
+    c_q = None
+    if cfg.q_lora_rank:
+        c_q = rms_norm_plain(matmul32(h, p["q_a"]), p["q_norm"],
+                             cfg.rms_norm_eps)
+        q = matmul32(c_q, p["q_b"])
+    else:
+        q = matmul32(h, p["q"])
+    q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
+    q_rope = rope_part(q[..., nope:], positions, cfg.rope_theta, cfg.yarn,
+                       pair)
     ckr = matmul32(h, p["kv_a"])
     c = rms_norm_plain(ckr[..., :rank], p["kv_norm"], cfg.rms_norm_eps)
-    k_r = rope_part(ckr[..., rank:], positions, cfg.rope_theta, cfg.yarn)
+    k_r = rope_part(ckr[..., rank:], positions, cfg.rope_theta, cfg.yarn,
+                    pair)
     rows = jnp.concatenate([c, k_r], axis=-1)
-    return (q[..., :nope].astype(dtype), q_rope.astype(dtype),
-            rows.astype(dtype))
+    out = (q[..., :nope].astype(dtype), q_rope.astype(dtype),
+           rows.astype(dtype))
+    return out + (c_q,) if with_cq else out
 
 
 def _softmax_over_rows(scores, mask):

@@ -24,6 +24,33 @@ from .gpt import GPT, layer_norm
 NEG_INF = -1e30
 
 
+def kth_largest(x, k):
+    """The k-th largest value of every row of float32 `x` [N, V], `k`
+    [N] in 1..V, exactly and without a sort: float32 bit patterns, the
+    magnitude bits flipped under a set sign, order as int32 the way the
+    floats do (-inf lowest, -0.0 under +0.0 — `jnp.sort`'s total
+    order), so the answer is the largest t with `count(row >= t) >= k`,
+    built from the sign bit down: 32 compare-and-count passes over
+    [N, V] whatever k is."""
+    def image(bits):                 # its own inverse
+        return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+    keys = image(jax.lax.bitcast_convert_type(x, jnp.int32))
+
+    def reaches(t):
+        return jnp.sum(keys >= t[:, None], axis=-1, dtype=jnp.int32) >= k
+
+    zero = jnp.zeros(k.shape, jnp.int32)
+    t = jnp.where(reaches(zero), zero, jnp.iinfo(jnp.int32).min)
+
+    def lower_bit(i, t):
+        up = t | (jnp.int32(1 << 30) >> i)
+        return jnp.where(reaches(up), up, t)
+
+    t = jax.lax.fori_loop(0, 31, lower_bit, t)
+    return jax.lax.bitcast_convert_type(image(t), jnp.float32)
+
+
 def _split_qkv(h, qkv_p, B, T, H, Dh):
     qkv = h @ qkv_p["w"].astype(h.dtype) + qkv_p["b"].astype(h.dtype)
     q, k, v = jnp.split(qkv, 3, axis=-1)

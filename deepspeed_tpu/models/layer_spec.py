@@ -64,6 +64,18 @@ class LayerSpec(NamedTuple):
                in a ring of `window + prefill_chunk` rows a request
                (serving/kv_cache.py's group "window"); 0 is every
                cached position (models/cohere2_moe.py).
+    indexers   `layer_indexers` (latent attention only; empty: every
+               cached row is attended) says of layer l, at index l mod
+               its length, "full" — the layer computes a learned
+               selection: an indexer of `index_heads` heads of
+               `index_width` scores the call's queries against ONE
+               cached index key a token (a second row of `index_width`
+               values the layer owns beside its latent row), and a
+               query attends the `index_topk` rows with the largest
+               scores (every row while it has no more than that) — or
+               "shared": no indexer and no index keys, the layer attends
+               the selection of the nearest "full" layer before it, made
+               in the same call (models/glm_moe_dsa.py).
     mixers     `layer_mixers` (empty: every layer attends) gives layer
                l, at index l mod its length, "attention" (the kind
                above) or "ssm": a Mamba-2 mixer (models/
@@ -81,7 +93,10 @@ class LayerSpec(NamedTuple):
                "softmax" over all experts or "sigmoid" of each — the top
                k experts a token, their weights as they are or, with
                `renormalize`, over their sum; every assignment computed
-               — moe/dropless.py — among the `experts_held` experts from
+               (with `select_bias` the k are chosen by the scores plus a
+               bias a layer holds, which does not weigh; `route_scale`
+               multiplies the weights last) — moe/dropless.py — among
+               the `experts_held` experts from
                `first_expert` on that this chip holds (0: all); plus
                shared experts, their outputs summed or, with `shared`
                "average", their mean; the first `dense_layers` layers
@@ -133,6 +148,12 @@ class LayerSpec(NamedTuple):
     residual_scale: float = 1.0
     attn_scale: float = 0.0      # grouped: 0 is head_dim ** -0.5
     logit_divisor: float = 1.0
+    layer_indexers: tuple = ()   # latent: the pattern's "full" | "shared"
+    index_topk: int = 0          # indexers: rows a query attends at most
+    index_heads: int = 0         # indexers: heads of the indexer
+    index_width: int = 0         # indexers: values of a cached index key
+    select_bias: bool = False    # routed_experts: a bias chooses the top k
+    route_scale: float = 1.0     # routed_experts: times the weights
 
     def window_of(self, layer: int) -> int:
         """The window of layer `layer` (0: every cached position)."""
@@ -150,6 +171,19 @@ class LayerSpec(NamedTuple):
         if not self.layer_mixers:
             return "attention"
         return self.layer_mixers[layer % len(self.layer_mixers)]
+
+    def indexer_of(self, layer: int):
+        """None (every cached row is attended), or "full" | "shared":
+        whether layer `layer` selects the rows its queries attend or
+        takes the selection of the "full" layer before it."""
+        if not self.layer_indexers:
+            return None
+        return self.layer_indexers[layer % len(self.layer_indexers)]
+
+    def index_layers(self, num_layers: int) -> tuple:
+        """The layers of `num_layers` that own index-key rows."""
+        return tuple(i for i in range(num_layers)
+                     if self.indexer_of(i) == "full")
 
     @property
     def has_state(self) -> bool:
@@ -221,12 +255,27 @@ class LayerSpec(NamedTuple):
                 f"{self.positions!r}, {self.layer_positions})")
         routed_only = (self.scoring != "softmax" or self.renormalize
                        or self.shared != "sum" or self.experts_held
-                       or self.first_expert)
+                       or self.first_expert or self.select_bias
+                       or self.route_scale != 1.0)
         if routed_only and self.ffn != "routed_experts":
             raise ValueError(
-                f"layer spec: scoring, renormalize, shared, experts_held "
-                f"and first_expert describe a routed_experts FFN (got "
-                f"{self.ffn!r})")
+                f"layer spec: scoring, renormalize, shared, experts_held, "
+                f"first_expert, select_bias and route_scale describe a "
+                f"routed_experts FFN (got {self.ffn!r})")
+        sizes = (self.index_topk, self.index_heads, self.index_width)
+        if any(k not in ("full", "shared") for k in self.layer_indexers) \
+                or bool(self.layer_indexers) != all(n > 0 for n in sizes) \
+                or (not self.layer_indexers and any(sizes)) \
+                or (self.layer_indexers
+                    and (self.attention != "latent"
+                         or self.layer_indexers[0] != "full")):
+            raise ValueError(
+                f"layer spec: layer_indexers says \"full\" or \"shared\" "
+                f"of each layer of a latent-attention pattern, a \"full\" "
+                f"one first, and with it, and with nothing else, go "
+                f"index_topk, index_heads and index_width (got "
+                f"{self.layer_indexers} with {self.attention!r} attention, "
+                f"{sizes})")
         if self.experts_held < 0 or self.first_expert < 0 or (
                 self.first_expert and not self.experts_held):
             raise ValueError(

@@ -7,7 +7,10 @@ model's uncached forward use.)
 Routing: the router's product in float32 at full precision and its
 scores over all E experts — their softmax, or the sigmoid of each
 (`scoring`) — the `top_k` largest taken greedily, their weights used as
-they are or, with `renormalize`, divided by their sum.
+they are or, with `renormalize`, divided by their sum.  A selection bias
+(`select_bias` [E], the `noaux_tc` routing of GLM-5.2) is added to the
+scores only to CHOOSE the `top_k`: the weights are the unbiased scores
+of the chosen, and `scale` multiplies them last.
 
 A share of the experts (`held` = (first, count), what expert parallelism
 gives one chip): the router keeps its E outputs and its `top_k` a token,
@@ -60,17 +63,26 @@ RIDGE_TOKENS = 128
 
 
 def route(h, router, top_k: int, scoring: str = "softmax",
-          renormalize: bool = False):
+          renormalize: bool = False, select_bias=None, scale: float = 1.0):
     """h [T, D], router [D, E] -> (weights [T, top_k] float32, experts
-    [T, top_k] int32): the scores over E in float32, the top_k largest,
-    as they are or over their sum."""
+    [T, top_k] int32): the scores over E in float32, the top_k largest —
+    of the scores plus `select_bias` [E] where one is given, which
+    chooses and does not weigh — as they are or over their sum, times
+    `scale`."""
     scores = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.softmax(scores, axis=-1) if scoring == "softmax" \
         else jax.nn.sigmoid(scores)
-    weights, idx = jax.lax.top_k(scores, top_k)
+    if select_bias is None:
+        weights, idx = jax.lax.top_k(scores, top_k)
+    else:
+        _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32),
+                               top_k)
+        weights = jnp.take_along_axis(scores, idx, axis=-1)
     if renormalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, idx.astype(jnp.int32)
 
 

@@ -137,6 +137,23 @@ where it follows the touched list — a TPU — or sorts by expert, every
 one held where it masks; `moe/dropless.py::routed_way`, asked once at
 build, and no read of its own).
 
+A learned selection of the latent rows (a layer spec whose
+`layer_indexers` mark layers "full" or "shared", models/glm_moe_dsa.py):
+the "full" layers keep one index key a token beside the latent row,
+under the same tables and allocator (`kv.index_layers`), and every
+refusal above holds.  Counters, from positions on the host like
+`serve.mla.rows_read`, which this family does not emit (its queries do
+not attend every cached row): `serve.sparse.keys_scored` (calls =
+queries decoded x "full" layers, bytes = index keys they score: every
+cached one), `serve.sparse.rows_selected` (calls = queries decoded x
+layers, bytes = rows the chosen sets hold: min(cached, index_topk) a
+layer), `serve.sparse.rows_fetched` (the same calls, bytes = latent rows
+the decode program gathers for them: its list's `index_topk` rows a
+slot a layer, chosen or not) and `serve.sparse.selections_shared`
+(calls = layer-calls that attended a selection made by an earlier layer
+of the same program call: the "shared" layers of every decode step and
+prefill chunk).
+
 Grouped rows over two groups of layers (a layer spec with "grouped"
 attention some of whose layers have a window): the full layers' rows are
 handed out at admission as the paged ones are, and `num_blocks` is
@@ -473,6 +490,13 @@ class ServeEngine:
                     f"the head split of the pool does not apply, and an "
                     f"expert layer that holds a share of the experts is "
                     f"not built; serve it on one device")
+        # layers that choose the rows their queries attend, and those
+        # that take the choice over, for serve.sparse.*
+        self._index_layers = spec.index_layers(cfg.num_layers)
+        self._index_shared = (cfg.num_layers - len(self._index_layers)
+                              if self._index_layers else 0)
+        self._index_topk = min(spec.index_topk,
+                               table_width * c.block_size)
         # routed-FFN layers, for serve.moe.*
         self._routed_layers = (cfg.num_layers - spec.dense_layers
                                if spec.ffn == "routed_experts" else 0)
@@ -526,6 +550,7 @@ class ServeEngine:
             latent_width=spec.latent_width,
             ring_tokens=ring_blocks * c.block_size,
             ring_layers=ring_layers, max_requests=c.max_batch,
+            index_layers=self._index_layers, index_width=spec.index_width,
             state_layers=self._state_layers,
             state_shapes=(((spec.ssm_heads, spec.ssm_head_dim,
                             spec.ssm_state), jnp.float32),
@@ -984,6 +1009,9 @@ class ServeEngine:
         if self.kv.windowed:
             self._close_full_window(req)
         COUNTERS.add("serve.prefill_chunks", nbytes=n_valid)
+        if self._index_shared:
+            COUNTERS.add("serve.sparse.selections_shared",
+                         calls=self._index_shared)
         self._count_assignments(n_valid)
         if tr is not None:
             # cached/computed: the prefix-cache outcome per request —
@@ -1051,6 +1079,19 @@ class ServeEngine:
                 COUNTERS.add("serve.eva.rows_walked", nbytes=C * (
                     sum(live_blocks(p, W, C, C)) if self._walks_live_blocks
                     else self.kv.table_width))
+        elif self._index_layers:
+            held = positions[slots].astype(np.int64) + 1
+            full, every = len(self._index_layers), self.kv.num_layers
+            COUNTERS.add("serve.sparse.keys_scored", calls=len(lanes) * full,
+                         nbytes=int(held.sum()) * full)
+            COUNTERS.add("serve.sparse.rows_selected",
+                         calls=len(lanes) * every, nbytes=every * int(
+                             np.minimum(held, self._index_topk).sum()))
+            COUNTERS.add("serve.sparse.rows_fetched",
+                         calls=len(lanes) * every,
+                         nbytes=len(lanes) * every * self._index_topk)
+            COUNTERS.add("serve.sparse.selections_shared",
+                         calls=self._index_shared)
         elif self.kv.latent_width:
             held = positions[slots].astype(np.int64) + 1
             COUNTERS.add("serve.mla.rows_read", calls=len(lanes),

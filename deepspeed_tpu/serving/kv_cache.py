@@ -132,6 +132,18 @@ blocks.  The prefix cache, session pins, quantized rows and a mesh are
 not offered for such a cache (the engine refuses them by name); a model
 with one kind of layer is one group and sees none of this.
 
+A second row in SOME layers (`index_layers`, `index_width` > 0, beside
+latent rows: a layer spec whose `layer_indexers` mark layers "full",
+models/glm_moe_dsa.py): such a layer's entry is a pair — the latent
+rows and, under the same block ids, an array of index keys `[rows,
+pool_width(1, index_width)]`, one key a token that the layer's indexer
+scores a query against to choose the rows it attends.  The layers
+marked "shared" own no such rows: their entry is the one array.  One
+block id names a slab in every array, so tables, the free list and
+admission do not change, and `nbytes` / `bytes_per_block` count the
+second array where it exists (`index_nbytes`).  What the latent cache
+refuses it refuses too.
+
 A group of layers with a state and no rows (`state_layers`, a layer
 spec some of whose layers mix tokens by a state-space recurrence): such
 a layer's entry of `caches` is not rows of a pool but arrays BY SLOT,
@@ -283,7 +295,8 @@ class PagedKVCache:
                  latent_width: int = 0, ring_tokens: int = 0,
                  ring_layers: Sequence[int] = (), max_requests: int = 0,
                  state_layers: Sequence[int] = (),
-                 state_shapes: Sequence[tuple] = ()):
+                 state_shapes: Sequence[tuple] = (),
+                 index_layers: Sequence[int] = (), index_width: int = 0):
         if num_blocks < 2:
             raise ValueError(
                 f"num_blocks must be >= 2 (block 0 is the reserved trash "
@@ -327,6 +340,15 @@ class PagedKVCache:
             raise ValueError(
                 "a cache of latent rows is dense, on one device, with no "
                 "prefix cache and no window")
+        # the layers `index_layers` keep a second row a token beside the
+        # latent one: an index key of `index_width` values
+        self.index_layers = frozenset(int(i) for i in index_layers)
+        self.index_width = int(index_width)
+        if bool(self.index_layers) != bool(self.index_width) or (
+                self.index_layers and not self.latent_width):
+            raise ValueError(
+                f"index keys of {index_width} values are kept beside "
+                f"latent rows, in some layers ({sorted(self.index_layers)})")
         # > 0: the layers `ring_layers` are group `window`: rows, free
         # list and trash block of their own, `ring_blocks` table entries
         # a request behind its `table_width` full ones
@@ -459,8 +481,11 @@ class PagedKVCache:
             return [entry(i) for i in range(self.num_layers)]
         if self.latent_width:
             shape = (rows, pool_width(1, self.latent_width))
-            return [(jnp.zeros(shape, self.dense_dtype),)
-                    for _ in range(self.num_layers)]
+            keys = (rows, pool_width(1, self.index_width))
+            return [(jnp.zeros(shape, self.dense_dtype),) + (
+                (jnp.zeros(keys, self.dense_dtype),)
+                if i in self.index_layers else ())
+                for i in range(self.num_layers)]
         if self.ring_blocks:
             width = pool_width(self.num_heads, self.head_dim)
             ring_rows = self.ring_pool_blocks * self.block_size
@@ -505,6 +530,13 @@ class PagedKVCache:
         slots', whatever is seated."""
         return sum(int(a.size) * a.dtype.itemsize
                    for i in self.state_layers for a in self.caches[i])
+
+    def index_nbytes(self) -> int:
+        """Device bytes of the index keys: the second array of the
+        layers that select the rows their queries attend."""
+        return sum(int(self.caches[i][1].size)
+                   * self.caches[i][1].dtype.itemsize
+                   for i in self.index_layers)
 
     def bytes_per_block(self) -> int:
         """Device bytes one block costs across all layers with rows (K
@@ -976,6 +1008,10 @@ class PagedKVCache:
                     f"one latent row of {self.latent_width}, "
                     if self.latent_width else
                     f"heads={self.num_heads}, head_dim={self.head_dim}, ")
+                + (f"index keys of {self.index_width} in layers "
+                   f"{sorted(self.index_layers)} "
+                   f"({self.index_nbytes() / (1 << 20):.2f} MiB), "
+                   if self.index_layers else "")
                 + f"kv={mode}, "
                 f"prefix_cache={'on' if self.prefix_enabled else 'off'}, "
                 f"sharded={self._sharding is not None}, "

@@ -16,15 +16,19 @@ Command A+ block: `kv_heads` keys and values a row for `num_heads`
 queries, query head n on K/V head n // (num_heads / kv_heads); sliding
 layers rotate q and k with interleaved pairing and attend a window,
 full layers have no positions and attend every cached row; one norm and
-x + attn(h) + ffn(h), the "parallel" residual, whose routed FFN reads
-the spec: the router's scoring, whether the chosen weights are
-renormalised, how the shared experts combine and which experts this
-chip holds), and no positions at all with grouped attention in some
+x + attn(h) + ffn(h), the "parallel" residual), and no positions at all with grouped attention in some
 layers and a state-space mixer in the others (the Granite 4.0-H block:
 sequential, both branches scaled; `spec.layer_mixers` says which layer
-has which; below).  The norm,
-the FFN — a routed-experts FFN among them, behind leading dense layers —
-and the head are free of that choice.
+has which; below), and latent attention over a learned selection
+of the cached rows (the GLM-5.2 block: `spec.layer_indexers` says which
+layers score and choose — they keep a second row a token, an index key
+— and which take the choice of the layer before them; below).  The norm,
+the FFN and the head are free of that choice.  A routed-experts FFN,
+behind leading dense layers, is one function in either block and reads
+the spec (models/cohere2_moe.py `routed_ffn`): the router's scoring,
+whether the chosen weights are renormalised, a bias that chooses and a
+factor on the weights, which experts this chip holds, and — in the
+parallel block alone — how the shared experts combine.
 
 The paged pieces mirror models/generation.py `_block_with_cache` op for
 op (fp32 scores, the same einsum strings, NEG_INF masking, probs cast to
@@ -78,6 +82,14 @@ and a slot that is not on it has its state neither read nor written.
 Nothing here zeroes a state: the engine does, when a request is seated
 (serving/kv_cache.py `reset_state`).
 
+A selection that travels (`spec.layer_indexers`, serving/sparse.py,
+imported when such a block is first built): `block` takes and returns
+`sel`, what the nearest "full" layer before it chose for this call's
+queries — a list of pool rows a slot in a decode step, a mask over the
+table's positions in a prefill chunk — and `blocks` threads it through
+a program's layers.  A "full" layer's cache entry is (latent rows, index
+keys), a "shared" layer's the latent rows alone.
+
 Addressing (`Addr`): a program works out once where this call's K/V land
 and what attention reads, and every layer's block uses it.  Paged: flat
 write rows, the block tables, query positions (negative for a slot that
@@ -103,7 +115,7 @@ from ..models import cohere2_moe
 from ..kernels.ssm import live_slots
 from ..models.granite_hybrid import ssm_mix
 from ..models.deepseek_v2 import (absorb, attend_absorbed, attend_expanded,
-                                  expert_ffn, latent_project, rms_norm_plain)
+                                  latent_project, rms_norm_plain)
 from ..models.evabyte import (chunk_summaries, matmul32, project_qkv,
                               rms_norm, silu_gated_ffn)
 from ..models.gpt import layer_norm
@@ -121,9 +133,8 @@ def check_spec(spec: LayerSpec) -> LayerSpec:
         raise NotImplementedError(
             f"serving has no block with {spec.positions!r} positions and "
             f"{spec.attention!r} attention yet; built: {sorted(BUILT)}")
-    # grouped rows and the parallel block are built for each other, in
-    # front of a routed FFN that reads the spec; the sequential block's
-    # routed FFN is models/deepseek_v2.py's one kind
+    # grouped rows and the parallel block are built for each other; a
+    # routed FFN reads the spec in either block
     parallel = spec.residual == "parallel"
     hybrid = spec.positions == "none"
     if (spec.attention == "grouped") != (parallel or hybrid) or (
@@ -151,14 +162,18 @@ def check_spec(spec: LayerSpec) -> LayerSpec:
             f"{spec.layer_mixers} and scalars {spec.embed_scale}, "
             f"{spec.residual_scale}, {spec.attn_scale}, "
             f"{spec.logit_divisor}")
-    if not parallel and (spec.scoring != "softmax" or spec.renormalize
-                         or spec.shared != "sum" or spec.held):
+    if not parallel and spec.shared != "sum":
         raise NotImplementedError(
-            f"the sequential block's routed FFN scores by softmax, uses "
-            f"the weights as they are, sums its shared experts and holds "
-            f"every expert; got scoring {spec.scoring!r}, renormalize "
-            f"{spec.renormalize}, shared {spec.shared!r}, held "
-            f"{spec.held}: the parallel block reads them")
+            f"the sequential block's routed FFN sums its shared experts "
+            f"(its models' configurations do not count them); got shared "
+            f"{spec.shared!r}: the parallel block reads it")
+    if spec.layer_indexers and (spec.positions != "rope"
+                                or spec.ffn != "routed_experts"):
+        raise NotImplementedError(
+            f"a learned selection of rows is built in the sequential "
+            f"block with rotary positions, latent rows and a "
+            f"routed_experts FFN; got {spec.positions!r} positions and a "
+            f"{spec.ffn!r} FFN")
     return spec
 
 
@@ -616,16 +631,36 @@ def _ffn(spec, p, h):
         p["fc2"]["b"].astype(h.dtype)
 
 
-def block(spec, cfg, p, x, kv, addr, s, layer: int = 0):
+def blocks(spec, cfg, params, x, caches, addr, s):
+    """Every layer's `block` in turn over x -> (x, the new cache
+    entries, `touched` of the layers that have one), a selection that a
+    layer makes handed to the layers behind it."""
+    new_caches, touched, sel = [], [], None
+    for i, (bp, kv) in enumerate(zip(params["blocks"], caches)):
+        x, kv, n, sel = block(spec, cfg, bp, x, kv, addr, s, i, sel)
+        new_caches.append(kv)
+        if n is not None:
+            touched.append(n)
+    return x, new_caches, touched
+
+
+def block(spec, cfg, p, x, kv, addr, s, layer: int = 0, sel=None):
     """Pre-norm decoder block number `layer` over x [B, T, D] through
     its entry `kv` of the cache of a program of schedule `s` -> (x, kv,
-    touched): `touched` is None, or, behind a routed FFN, how many of
-    its experts the call's live tokens (`addr.q_pos` >= 0) chose
-    (behind a share of the experts: of those held)."""
+    touched, sel): `touched` is None, or, behind a routed FFN, how many
+    of its experts the call's live tokens (`addr.q_pos` >= 0) chose
+    (behind a share of the experts: of those held); `sel` is the
+    selection of rows the layer attended where the spec has layers that
+    choose (None elsewhere), for the layers behind it."""
     if spec.residual == "parallel":
-        return _parallel_block(spec, cfg, p, x, kv, addr, s, layer)
+        return _parallel_block(spec, cfg, p, x, kv, addr, s, layer) + (None,)
     h = _norm(spec, x, p["ln1"])
-    if spec.mixer_of(layer) == "ssm":
+    if spec.layer_indexers:
+        from .sparse import sparse_latent_attend
+
+        attn, kv, sel = sparse_latent_attend(
+            spec, cfg, p["attn"], h, kv, addr, s, layer, sel, _kv_write)
+    elif spec.mixer_of(layer) == "ssm":
         with jax.named_scope("ssm.step" if h.shape[1] == 1 else "ssm.scan"):
             attn, *kv = _ssm_mix(spec, p["ssm"], h, *kv, addr)
     elif spec.attention == "grouped":
@@ -643,11 +678,15 @@ def block(spec, cfg, p, x, kv, addr, s, layer: int = 0):
     h = _norm(spec, x, p["ln2"])
     if spec.ffn != "routed_experts" or layer < spec.dense_layers:
         return (x + _scaled(_ffn(spec, p["mlp"], h), spec.residual_scale),
-                tuple(kv), None)
+                tuple(kv), None, sel)
     live = addr.q_pos.reshape(-1) >= 0
-    y, idx = expert_ffn(cfg, p["mlp"], h, live)
-    touched = experts_touched(idx, live, p["mlp"]["router"].shape[1])
-    return x + y, tuple(kv), touched
+    y, idx, count, held = cohere2_moe.routed_ffn(
+        spec, cfg, p["mlp"], h.reshape(-1, h.shape[-1]), live)
+    # reshaped before the count, as this block's programs have it (the
+    # order of independent operations is part of their StableHLO)
+    y = y.reshape(h.shape)
+    touched = experts_touched(idx, live, count, held)
+    return x + y, tuple(kv), touched, sel
 
 
 # -- head -------------------------------------------------------------------

@@ -51,7 +51,10 @@ same two programs over the paged table; its block picks the expanded or
 the absorbed products from the call's query count (serving/layers.py).
 Behind a routed-experts FFN the decode program appends to its tokens how
 many experts the step touched.  `verify`, quantized weights and
-quantized rows are not built for it.
+quantized rows are not built for it.  Where the spec's `layer_indexers`
+mark layers that choose the rows a query attends, `layers.blocks` hands
+each such layer's selection to the layers behind it inside the one
+program (serving/sparse.py); nothing else here knows of it.
 
 A spec with "grouped" attention (rows of `kv_heads` keys and values,
 layers with a window beside full ones) runs the same two programs; where
@@ -112,6 +115,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..models.generation import kth_largest
 from ..utils.logging import logger
 from . import layers
 
@@ -185,40 +189,13 @@ def _row_key(seed, position):
     return jax.random.fold_in(jax.random.PRNGKey(seed), position)
 
 
-def _kth_largest(x, k):
-    """The k-th largest value of every row of float32 `x` [N, V], `k`
-    [N] in 1..V, exactly and without a sort: float32 bit patterns, the
-    magnitude bits flipped under a set sign, order as int32 the way the
-    floats do (-inf lowest, -0.0 under +0.0 — `jnp.sort`'s total
-    order), so the answer is the largest t with `count(row >= t) >= k`,
-    built from the sign bit down: 32 compare-and-count passes over
-    [N, V] whatever k is."""
-    def image(bits):                 # its own inverse
-        return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
-
-    keys = image(jax.lax.bitcast_convert_type(x, jnp.int32))
-
-    def reaches(t):
-        return jnp.sum(keys >= t[:, None], axis=-1, dtype=jnp.int32) >= k
-
-    zero = jnp.zeros(k.shape, jnp.int32)
-    t = jnp.where(reaches(zero), zero, jnp.iinfo(jnp.int32).min)
-
-    def lower_bit(i, t):
-        up = t | (jnp.int32(1 << 30) >> i)
-        return jnp.where(reaches(up), up, t)
-
-    t = jax.lax.fori_loop(0, 31, lower_bit, t)
-    return jax.lax.bitcast_convert_type(image(t), jnp.float32)
-
-
 def top_k_filter(scaled, top_ks):
     """`scaled` [N, V] float32 with everything under a row's k-th
     largest value at -inf (ties at the threshold survive, the HF
     semantics generation.py documents); `top_ks` [N] <= 0 leaves a row
     as it is, >= V keeps everything."""
     k = jnp.clip(top_ks, 1, scaled.shape[-1])
-    kth = _kth_largest(scaled, k)[:, None]
+    kth = kth_largest(scaled, k)[:, None]
     return jnp.where((top_ks > 0)[:, None] & (scaled < kth), -jnp.inf,
                      scaled)
 
@@ -530,10 +507,8 @@ class ServeProgramBuilder:
             x = layers.embed_chunk(spec, params, tokens, abs_pos)
             addr = layers.address_chunk(spec, s, table, pos, abs_pos,
                                         n_valid)
-            new_caches = []
-            for i, (bp, kv) in enumerate(zip(params["blocks"], caches)):
-                x, kv, _ = layers.block(spec, cfg, bp, x, kv, addr, s, i)
-                new_caches.append(kv)
+            x, new_caches, _ = layers.blocks(spec, cfg, params, x, caches,
+                                             addr, s)
             x = layers.final_norm(spec, params, x)
             last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
             logits = layers.logits(spec, params, last[:, 0, :])  # [1, V]
@@ -555,12 +530,8 @@ class ServeProgramBuilder:
         cfg, spec, s = self.model.config, self.spec, self.schedule
         x = layers.embed_step(spec, params, tokens, positions)
         addr = layers.address_step(spec, s, tables, positions, active)
-        new_caches, touched = [], []
-        for i, (bp, kv) in enumerate(zip(params["blocks"], caches)):
-            x, kv, n = layers.block(spec, cfg, bp, x, kv, addr, s, i)
-            new_caches.append(kv)
-            if n is not None:
-                touched.append(n)
+        x, new_caches, touched = layers.blocks(spec, cfg, params, x, caches,
+                                               addr, s)
         x = layers.final_norm(spec, params, x)
         return layers.logits(spec, params, x[:, -1, :]), new_caches, touched
 
@@ -632,10 +603,8 @@ class ServeProgramBuilder:
             x = layers.embed_chunk(spec, params, tokens, abs_pos)  # [R, T, D]
             addr = layers.address_grid(spec, s, tables, abs_pos, active,
                                        n_draft)
-            new_caches = []
-            for i, (bp, kv) in enumerate(zip(params["blocks"], caches)):
-                x, kv, _ = layers.block(spec, cfg, bp, x, kv, addr, s, i)
-                new_caches.append(kv)
+            x, new_caches, _ = layers.blocks(spec, cfg, params, x, caches,
+                                             addr, s)
             x = layers.final_norm(spec, params, x)
             logits = layers.logits(
                 spec, params, x.reshape(R * T, -1))       # [R * T, V]

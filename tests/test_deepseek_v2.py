@@ -316,6 +316,40 @@ def test_prefill_then_decode_matches_the_reference_forward(dtype):
                                                   else TOL[dtype])
 
 
+@pytest.mark.parametrize("change", [
+    dict(scoring="sigmoid"), dict(renormalize=True), dict(experts_held=2),
+    dict(select_bias=True), dict(route_scale=2.5)])
+def test_the_sequential_blocks_routed_ffn_reads_the_spec(change, monkeypatch):
+    """One routed FFN for both blocks (models/cohere2_moe.py
+    `expert_ffn`): with a field of the spec changed, the engine's logits
+    are the uncached forward's under that spec, and not the plain
+    spec's."""
+    from deepspeed_tpu.models import cohere2_moe
+    from test_evabyte import Probe
+
+    class Respecced(DeepSeekV2):
+        def layer_spec(self):
+            return super().layer_spec()._replace(**change).validate()
+
+    model = Respecced(_config())
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
+    for i, bp in enumerate(params["blocks"][model.config.first_k_dense:]):
+        bp["mlp"]["select_bias"] = jax.random.normal(
+            jax.random.PRNGKey(i), (EXPERTS,)) * 0.5
+    probe = Probe(model, params, _serve())
+    req = probe.engine.submit(_prompt(21, 4), 6)
+    probe.run()
+    got = np.stack(probe.logits[req.rid])[:len(req.out)]
+    toks, first = jnp.asarray([req.prompt + req.out]), len(req.prompt) - 1
+    plain = np.asarray(model.apply(params, toks))[0, first:first + len(got)]
+    spec = model.layer_spec()
+    monkeypatch.setattr(dsv2, "expert_ffn", lambda c, p, h:
+                        cohere2_moe.expert_ffn(spec, c, p, h))
+    want = np.asarray(model.apply(params, toks))[0, first:first + len(got)]
+    assert np.abs(got - want).max() < TOL["float32"]
+    assert np.abs(got - plain).max() > 1e-2
+
+
 def test_a_request_decodes_the_same_alone_and_in_a_batch():
     model, params = _model()
     alone = ServeEngine(model, params, _serve()).generate([_prompt(19, 3)],

@@ -17,14 +17,13 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models import GPT, gpt2_config
-from deepspeed_tpu.models.generation import generate
+from deepspeed_tpu.models.generation import generate, kth_largest
 from deepspeed_tpu.monitor.counters import COUNTERS
 from deepspeed_tpu.serving import (ERROR, FINISHED, TRASH_BLOCK, ServeConfig,
                                    ServeEngine, ServeProgramBuilder,
                                    ServeSchedule, WAITING)
 from deepspeed_tpu.serving import programs as programs_mod
-from deepspeed_tpu.serving.programs import (_kth_largest, sample_rows,
-                                            top_k_filter)
+from deepspeed_tpu.serving.programs import sample_rows, top_k_filter
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 
@@ -585,7 +584,7 @@ def test_kth_largest_is_the_sorts_own_entry():
                    np.float32)
     v = len(row)
     x = jnp.asarray(np.tile(row, (v, 1)))
-    got = jax.jit(_kth_largest)(x, jnp.arange(1, v + 1, dtype=jnp.int32))
+    got = jax.jit(kth_largest)(x, jnp.arange(1, v + 1, dtype=jnp.int32))
     want = jnp.sort(jnp.asarray(row))[::-1]
     assert np.array_equal(np.asarray(got), np.asarray(want))
 

@@ -762,6 +762,74 @@ def test_deepseek_cell_programs_compile_inside_one_chip(program, one_chip,
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_glm_dsa_cell_programs_compile_inside_one_chip(program, one_chip,
+                                                       native):
+    """`glm-5.2-d5.serve.longctx.decode` / `.prefill` at the cell's
+    shapes (5 layers at published widths in bf16, 16 of 256 experts,
+    8 slots, 12,289 blocks of 16 rows, a table of 1,536 entries, chunk
+    512), as the chip traces them: the selection and the attention over
+    it are `jax.numpy` (a stable sort over the table's width in decode, a
+    walk of 1,024-position tiles in prefill), so `decode`'s only
+    custom calls are the 4 routed layers' `touched_experts` kernels,
+    `prefill`'s XLA's grouped products; a "full"
+    layer's entry is (latent rows [rows, 640], index keys [rows, 128]),
+    a "shared" layer's the latent rows alone; and weights, pools and
+    temporaries leave the room the check's 1.9 GB of reference logits
+    and its float32 pieces need."""
+    from benchmarks import harness
+    from deepspeed_tpu.serving import ServeProgramBuilder, ServeSchedule
+
+    config = harness.load_json("configs", "glm-5.2-d5.json")
+    slots, bs, nblocks, chunk, seq = 8, 16, 12289, 512, 24576
+    model = harness.plugin("models", "glm_moe_dsa").build(
+        config, seq_len=seq, n_dev=1, param_dtype="bfloat16")
+    layers, width = model.config.num_layers, seq // bs
+    sched = ServeSchedule(max_batch=slots, prefill_chunk=chunk,
+                          block_size=bs, num_blocks=nblocks,
+                          table_width=width)
+    progs = ServeProgramBuilder(model, sched).build()
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda s: on(s.shape, s.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    held = sum(s.size * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    assert abs(held - 7.763e9) < 0.01e9
+    rows, keys = on((nblocks * bs, 640), jnp.bfloat16), \
+        on((nblocks * bs, 128), jnp.bfloat16)
+    caches = [(rows, keys) if kind == "full" else (rows,)
+              for kind in model.config.indexer_types]
+    assert [len(c) for c in caches] == [2, 1, 1, 1, 2]
+    if program == "decode":
+        args = (on((slots,), jnp.int32), on((slots,), jnp.int32),
+                on((slots,), jnp.bool_), on((slots, width), jnp.int32),
+                on((slots,), jnp.float32), on((slots,), jnp.int32),
+                on((slots,), jnp.uint32))
+    else:
+        args = (on((1, chunk), jnp.int32), on((), jnp.int32),
+                on((), jnp.int32), on((width,), jnp.int32),
+                on((), jnp.float32), on((), jnp.int32), on((), jnp.uint32))
+    compiled = progs[program].lower(params, caches, *args).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    if program == "decode":
+        assert sum("touched_experts" in ln for ln in calls) == layers - 1
+    else:
+        assert calls and all("ragged" in ln for ln in calls)
+    pools = _hlo_by_shape(text)[(nblocks * bs, 640)]
+    assert {layout for _, layout in pools} == {"1,0"}  # row-major
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + \
+        m.output_size_in_bytes - m.alias_size_in_bytes
+    print(program, "temp", m.temp_size_in_bytes / 1e9, "total", total / 1e9)
+    assert m.temp_size_in_bytes < 1536 << 20
+    assert total < 15.75e9 - 1.9e9 - 2.5e9, total
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
                                                               one_chip,
                                                               native):
