@@ -446,6 +446,7 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                   eva_positions=(0, 100, 2047, 2048, 9000, 12345, 16383, -1),
                   grouped_shapes=((64, 32, 8, 64, 128, 1 / 64),
                                   (16, 128, 8, 128, 1024, None)),
+                  latent_shape=(32, 16, 512, 64, 256),
                   routed_shape=(32, 64, 2048, 1408), routed_live=12,
                   ssm_shape=(64, 64, 64, 128), ssm_live=37,
                   on_chip=True) -> dict:
@@ -650,15 +651,21 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
     # default precision too
     from deepspeed_tpu.serving.layers import grouped_attention_reference
 
-    for slots, g_heads, g_kv, g_dim, width, scale in grouped_shapes:
-        bs, nblocks = 16, 1 + slots * width
+    def held_tables(slots, width, bs=16):
+        """Slots from idle to the whole table, dead entries at the trash
+        block: -> (rows held [slots], tables, q_pos, blocks in the pool)."""
+        nblocks = 1 + slots * width
         held = rs.randint(0, width * bs + 1, (slots,))
         edge = [0, 1, width * bs, bs, bs + 1][:slots]
         held[:len(edge)] = edge
         tables = rs.permutation(np.arange(1, nblocks)).reshape(slots, width)
         tables[np.arange(width)[None, :] >= -(-held // bs)[:, None]] = 0
-        tables = jnp.asarray(tables, jnp.int32)
-        q_pos = jnp.asarray(held[:, None] - 1, jnp.int32)
+        return (held, jnp.asarray(tables, jnp.int32),
+                jnp.asarray(held[:, None] - 1, jnp.int32), nblocks)
+
+    for slots, g_heads, g_kv, g_dim, width, scale in grouped_shapes:
+        bs = 16
+        held, tables, q_pos, nblocks = held_tables(slots, width)
         rows = (nblocks * bs, g_kv, g_dim)
         ck = pool_rows(jax.random.normal(key[6], rows, jnp.bfloat16))
         cv = pool_rows(jax.random.normal(key[7], rows, jnp.bfloat16))
@@ -683,6 +690,37 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
             f"grouped_attention_bf16_H{g_heads}_KV{g_kv}_Dh{g_dim}",
             [slots, width * bs, g_heads, g_dim], got[held > 0],
             want[held > 0], rtol=0, atol=1e-2))
+
+    # attention over latent rows, one decode step at the chatgen cell's
+    # shape (32 slots, 16 heads' absorbed queries over rows of 512 + 64
+    # values in 640 lanes, a table of 256 blocks): one array that is key
+    # and value, bf16, the same slots and the default precision
+    from deepspeed_tpu.serving.layers import latent_attention_reference
+
+    slots, l_heads, rank, rope, width = latent_shape
+    bs = 16
+    held, tables, q_pos, nblocks = held_tables(slots, width)
+    pool = pool_rows(jax.random.normal(
+        key[6], (nblocks * bs, 1, rank + rope), jnp.bfloat16))
+    lq = jax.random.normal(key[0], (slots, 1, l_heads, rank + rope),
+                           jnp.bfloat16)
+    info = {"block_size": bs, "table_width": width, "q_len": 1,
+            "num_heads": l_heads, "kv_heads": 1, "head_dim": rank + rope,
+            "kv_mode": "dense", "kv_itemsize": 2}
+    chosen = registry.resolve_impl("latent_attention", info=info)
+    if on_chip and chosen != "pallas":
+        raise RuntimeError(
+            f"auto resolved latent attention at {l_heads} heads over rows "
+            f"of {rank} + {rope} to {chosen!r} on this chip")
+    kw = dict(block_size=bs, rank=rank, scale=(rank + rope) ** -0.5)
+    got = jax.jit(lambda *a: registry.dispatch(
+        "latent_attention", *a, info=info, **kw))(lq, pool, tables, q_pos)
+    want = jax.jit(lambda *a: latent_attention_reference(*a, **kw))(
+        lq, pool, tables, q_pos)
+    out.append(_close(
+        f"latent_attention_bf16_H{l_heads}_W{rank + rope}",
+        [slots, width * bs, l_heads, rank + rope], got[held > 0],
+        want[held > 0], rtol=0, atol=1e-2))
 
     # the routed product of a decode step at the chatgen cell's shape:
     # 32 slots of which 12 are live, 6 of 64 bf16 experts a slot, the

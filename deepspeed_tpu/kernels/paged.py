@@ -70,6 +70,15 @@ softmax scale is the caller's (Granite's `attention_multiplier` is not
 the table's first entry; a window's lower bound and a ring's modular
 rows are not cases of it (kernels/registry.py refuses them by what
 `grouped_info` says).  At `G` = 1 nothing of this is traced.
+
+And latent rows (`latent_attention_pallas`; serving/layers.py
+`_latent_attend`, a decode step's absorbed products): a cache row is ONE
+array a layer, key and value at once — [latent c | rotated key], one
+"K/V head" read by every query head — which is the grouped tile at
+`kv_heads` = 1, `G` = the query heads.  Score row (t, h) is head h's
+absorbed query over the row's lanes; the weighted sum runs over the SAME
+tile, so the call hands the kernel one operand and a block is copied
+once; the caller keeps the accumulator's first `rank` lanes, the value.
 """
 
 from __future__ import annotations
@@ -216,7 +225,13 @@ def _tile_kv(buf, sbuf, slot, kv_mode, H, Dh, marker):
 
 def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
                  kv_mode, marker, window=0, chunk=0, G=1):
-    if kv_mode == "dense":
+    if kv_mode == "dense" and len(rest) == 7:
+        # one array is key and value (a latent row): the one tile is
+        # multiplied twice and a block copied once
+        k_hbm, o_ref, kbuf, sem, acc, m_s, l_s = rest
+        vbuf, pairs = kbuf, ((k_hbm, kbuf),)
+        ksbuf = vsbuf = None
+    elif kv_mode == "dense":
         k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc, m_s, l_s = rest
         pairs = ((k_hbm, kbuf), (v_hbm, vbuf))
         ksbuf = vsbuf = None
@@ -408,6 +423,18 @@ def grouped_attention_pallas(q, ck, cv, tables, q_pos, *, kv_heads: int,
                  interpret=pallas_backend.interpret()).reshape(B, T, H * Dh)
 
 
+def latent_attention_pallas(q_row, pool, tables, q_pos, *, block_size: int,
+                            rank: int, scale: float):
+    """Drop-in for serving/layers.py's `latent_attention_reference`
+    (tolerance parity): the walk at `kv_heads` = 1 over the one array
+    that is key and value -> [B, H, T, rank] float32, the accumulator's
+    first `rank` lanes."""
+    out = _walk(q_row, pool, None, tables, q_pos, kv_mode="dense",
+                block_size=int(block_size), kv_heads=1, scale=float(scale),
+                interpret=pallas_backend.interpret())
+    return out[..., :rank].transpose(0, 2, 1, 3)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("kv_mode", "block_size", "interpret",
                                     "window", "chunk", "kv_heads", "scale"))
@@ -419,6 +446,7 @@ def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret,
     the walk is kernels/eva.py's (dense rows, float32 out).  `kv_heads`
     fewer than q's heads: grouped rows (dense, float32 out), a tile of
     the row's heads serving `G` = H / kv_heads query heads a key.
+    `cv` None: `ck` is key and value at once (a latent row), one operand.
     `scale`: the softmax's, `Dh ** -0.5` unless given."""
     B, T, H, Dh = q.shape
     W = tables.shape[1]
@@ -435,7 +463,7 @@ def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret,
     if kv_mode == "dense":
         marker = 0
         out_dtype = jnp.float32 if window or G > 1 else ck.dtype
-        operands = [ck, cv]
+        operands = [ck] if cv is None else [ck, cv]
         width = ck.shape[1]  # H * Dh and the lanes that pad a pool row
     else:
         from ..runtime.comm.quant import qmax
@@ -449,8 +477,10 @@ def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret,
 
         operands = [pk, bits(sk), pv, bits(sv)]
         width = HD  # the tile as dequantized
-    # a cache row's bytes: K's arrays are half of the operands
-    row_bytes = sum(c.shape[1] * c.dtype.itemsize for c in operands) // 2
+    # a cache row's bytes: K's arrays are half of the operands, or the
+    # one array that is key and value
+    row_bytes = sum(c.shape[1] * c.dtype.itemsize
+                    for c in operands) // min(len(operands), 2)
     KB = tile_blocks(bs, W, row_bytes, T, H, width)
     if KB < 1:
         raise ValueError(

@@ -306,7 +306,9 @@ class PagedAttentionOp(KernelOp):
     `kv_heads` heads each serve several query heads takes the same walk
     at a GROUPED tile — the row's heads as they lie, every (query, query
     head) pair a score row in the lanes of the K/V head it reads — as
-    `grouped_attention` below, whose oracle is another expression."""
+    `grouped_attention` below, whose oracle is another expression; a
+    latent row, key and value at once, is that tile at one K/V head
+    (`latent_attention`)."""
 
     NAME = "paged_attention"
 
@@ -362,6 +364,33 @@ class GroupedAttentionOp(KernelOp):
     def oracle(self, variant, *args, **kwargs):
         from ..serving import layers
         return layers.grouped_attention_reference(*args, **kwargs)
+
+
+class LatentAttentionOp(KernelOp):
+    """Absorbed attention over paged latent rows — one array a layer that
+    is key and value at once, one "K/V head" a row for every query head
+    (serving/layers.py `_latent_attend`, a decode step).  Pallas = the
+    paged walk at `kv_heads` = 1 with one operand: a block is copied
+    once, scored and summed over as the one tile (kernels/paged.py
+    `latent_attention_pallas`).  Oracle = the gather of every table entry
+    and `attend_rows` under the causal mask, the expression the layer ran
+    before there was a kernel (`latent_attention_reference`).  The shape
+    rule is the walk's at that tile (`latent_info`)."""
+
+    NAME = "latent_attention"
+
+    def auto_supports(self, variant, info):
+        if not info:
+            return True, ""
+        return _walk_supports(info)
+
+    def pallas(self, variant, *args, **kwargs):
+        from . import paged
+        return paged.latent_attention_pallas(*args, **kwargs)
+
+    def oracle(self, variant, *args, **kwargs):
+        from ..serving import layers
+        return layers.latent_attention_reference(*args, **kwargs)
 
 
 class EvaAttentionOp(KernelOp):
@@ -559,7 +588,7 @@ class SsmStepOp(KernelOp):
 KERNEL_OPS: Dict[str, KernelOp] = {
     op.NAME: op for op in (FlashAttentionOp(), SparseAttentionOp(),
                            PagedAttentionOp(), GroupedAttentionOp(),
-                           EvaAttentionOp(),
+                           LatentAttentionOp(), EvaAttentionOp(),
                            QuantCodecOp(), MoEDispatchOp(),
                            TouchedExpertsOp(), SsmStepOp())
 }

@@ -18,7 +18,10 @@ from rows to attention, equal in exact arithmetic:
   q_rope.k_r) s, o_lat = sum p c, o = o_lat W_UV: attention over the rows
   as they lie, all heads on one row as in multi-query attention.  Cheap
   where a query stands alone: decode.  `absorb` decides from the counts
-  of queries and rows.
+  of queries and rows.  It is `absorbed_attention` (the two W_kv_b
+  products) around `attend_rows` (scores, softmax and weighted sum over
+  rows handed in); a cached decode step puts a kernel that reads the
+  rows where they lie in `attend_rows`' place (serving/layers.py).
 
 s = (nope + rope)^-1/2, times m(mscale_all_dim)^2 under YaRN.  Rotary
 positions cover the `rope` dims only, half-split pairing, with YaRN's
@@ -244,25 +247,42 @@ def attend_expanded(cfg, kv_b, q_nope, q_rope, rows, mask):
     return out.reshape(B, -1, H * cfg.v_head_dim)
 
 
-def attend_absorbed(cfg, kv_b, q_nope, q_rope, rows, mask):
-    """W_UK absorbed into the query and W_UV into the output: attention
-    over the rows as they lie.  Same arguments and result as
-    `attend_expanded`."""
-    B, K, _ = rows.shape
+def attend_rows(q_row, rows, mask, rank: int, scale: float):
+    """Attention over latent rows as they lie: q_row [B, T, H, width]
+    (a head's absorbed query beside its rotated part), rows [B, K,
+    width], mask [B, T, K] -> [B, H, T, rank] float32, the weighted sum
+    of the rows' first `rank` values (the latent c)."""
+    scores = jnp.einsum("bqhw,bkw->bhqk", q_row, rows,
+                        preferred_element_type=jnp.float32)
+    probs = _softmax_over_rows(scores * scale, mask)
+    return jnp.einsum("bhqk,bkr->bhqr", probs.astype(rows.dtype),
+                      rows[..., :rank], preferred_element_type=jnp.float32)
+
+
+def absorbed_attention(cfg, kv_b, q_nope, q_rope, dtype, attend):
+    """W_UK absorbed into the query and W_UV into the output, around
+    `attend`: the queries as rows-shaped [B, T, H, rank + rope] at
+    `dtype` -> [B, H, T, rank] float32 (`attend_rows`, or a kernel that
+    reads the rows where they lie).  -> [B, T, H * v] float32."""
+    B = q_nope.shape[0]
     H, nope, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
     w = kv_b.reshape(rank, H, nope + cfg.v_head_dim)
     q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope.astype(w.dtype),
                        w[..., :nope], preferred_element_type=jnp.float32)
-    q_row = jnp.concatenate([q_lat.astype(rows.dtype), q_rope], axis=-1)
-    scores = jnp.einsum("bqhw,bkw->bhqk", q_row, rows,
-                        preferred_element_type=jnp.float32)
-    probs = _softmax_over_rows(
-        scores * softmax_scale(cfg.head_dim, cfg.yarn), mask)
-    o_lat = jnp.einsum("bhqk,bkr->bhqr", probs.astype(rows.dtype),
-                       rows[..., :rank], preferred_element_type=jnp.float32)
+    o_lat = attend(jnp.concatenate([q_lat.astype(dtype), q_rope], axis=-1))
     out = jnp.einsum("bhqr,rhd->bqhd", o_lat.astype(w.dtype), w[..., nope:],
                      preferred_element_type=jnp.float32)
     return out.reshape(B, -1, H * cfg.v_head_dim)
+
+
+def attend_absorbed(cfg, kv_b, q_nope, q_rope, rows, mask):
+    """W_UK absorbed into the query and W_UV into the output: attention
+    over the rows as they lie.  Same arguments and result as
+    `attend_expanded`."""
+    return absorbed_attention(
+        cfg, kv_b, q_nope, q_rope, rows.dtype,
+        lambda q_row: attend_rows(q_row, rows, mask, cfg.kv_lora_rank,
+                                  softmax_scale(cfg.head_dim, cfg.yarn)))
 
 
 def absorb(cfg, n_queries: int, n_rows: int) -> bool:

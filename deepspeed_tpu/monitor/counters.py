@@ -131,6 +131,10 @@ Instrumented sites:
   attention and a "routed_experts" FFN): `serve.mla.rows_read` — calls
   = queries decoded, bytes = latent rows they attend (one a cached
   token, shared by all heads: every cached row of the request);
+  `serve.mla.rows_walked` — the same calls, bytes = latent rows the
+  step's attention fetches for them in a layer (the cached length
+  rounded up to a block where the decode call is the walk of live
+  blocks, the table's whole width where it gathers);
   `serve.moe.assignments` — calls = routed-layer calls,
   bytes = token-expert pairs computed (tokens x top_k, nothing
   dropped); `serve.moe.experts_touched` — calls = decode steps x

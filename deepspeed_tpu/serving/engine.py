@@ -124,8 +124,16 @@ heads, under the same allocator and tables as the paged one, and the
 programs pick their attention products from the call's query count.
 For such a model the engine refuses, by name, `prefix_cache=True`,
 sessions, `draft_len > 0`, quantized weights, int8/int4 rows and a mesh
-of more than one device.  Counters: `serve.mla.rows_read` (calls =
-queries decoded, bytes = latent rows they attend: every cached row),
+of more than one device.  A decode step's absorbed products read each
+running slot's live blocks where they lie (kernels/paged.py, the walk at
+one K/V head a row and one operand) where the registry picks the kernel
+for the decode program's shapes; a prefill chunk, and every backend but
+the TPU, gathers the table's rows.  Counters: `serve.mla.rows_read`
+(calls = queries decoded, bytes = latent rows they attend: every cached
+row), `serve.mla.rows_walked` (the same calls, bytes = latent rows the
+step FETCHES for them: a slot's cached length rounded up to a block
+where its decode call is the walk, the table's whole width where it
+gathers),
 `serve.moe.assignments` (calls = routed-layer calls, bytes =
 token-expert pairs they computed: tokens x top_k, nothing dropped) and
 `serve.moe.experts_touched` (calls = decode steps x routed layers,
@@ -583,11 +591,11 @@ class ServeEngine:
             self.scheduler.session_consumed = self._session_consumed
         self.programs = programs
         # what a decoded slot's attention reads, for
-        # serve.{paged,eva,attn}.rows_walked: its live blocks where the
+        # serve.{paged,eva,attn,mla}.rows_walked: its live blocks where the
         # registry picks the kernel for the decode program's shapes,
         # else the table's whole width
         from ..kernels import registry
-        from .layers import eva_info, grouped_info, paged_info
+        from .layers import eva_info, grouped_info, latent_info, paged_info
 
         with_rows = next(i for i in range(cfg.num_layers)
                          if i not in self._state_layers)
@@ -595,9 +603,12 @@ class ServeEngine:
         q_len = int(c.draft_len) + 1
         walks = lambda op, info: registry.resolve_impl(
             op, info=info) == "pallas"
-        # latent rows have no kernel: the table's rows are gathered
+        # (a learned selection gathers the rows it chose: no walk)
         self._walks_live_blocks = False
-        if spec.attention == "grouped":
+        if spec.attention == "latent" and not self._index_layers:
+            self._walks_live_blocks = walks("latent_attention", latent_info(
+                cfg, schedule, q_len, pool.dtype, spec.latent_width))
+        elif spec.attention == "grouped":
             # a full layer and a sliding one are asked apart: a sliding
             # layer's rows are a ring, or the table under a window
             ask = lambda window: walks("grouped_attention", grouped_info(
@@ -1096,6 +1107,7 @@ class ServeEngine:
             held = positions[slots].astype(np.int64) + 1
             COUNTERS.add("serve.mla.rows_read", calls=len(lanes),
                          nbytes=int(held.sum()))
+            self._count_rows_walked(lanes, 1, "serve.mla.rows_walked")
         elif self._window or self._state_layers:
             self._count_grouped_rows(lanes)
         else:
@@ -1259,17 +1271,17 @@ class ServeEngine:
                      + self._sliding_layers * fetched(self._sliding_walks,
                                                       ring or table))
 
-    def _count_rows_walked(self, running: List[Request],
-                           n_queries: int) -> None:
-        """The pool rows this step's attention reads for the running
-        slots."""
+    def _count_rows_walked(self, running: List[Request], n_queries: int,
+                           name: str = "serve.paged.rows_walked") -> None:
+        """The pool rows this step's attention FETCHES for the running
+        slots: their live blocks where the decode call is the walk, the
+        table's whole width where it gathers."""
         held = self._slots.host["positions"][
             [r.slot for r in running]].astype(np.int64) + n_queries
         bs = self.kv.block_size
         walked = (-(-held // bs) * bs if self._walks_live_blocks
                   else np.full_like(held, self.kv.table_width * bs))
-        COUNTERS.add("serve.paged.rows_walked", calls=len(running),
-                     nbytes=int(walked.sum()))
+        COUNTERS.add(name, calls=len(running), nbytes=int(walked.sum()))
 
     # -- summarised windows: host book-keeping at step boundaries --------
 

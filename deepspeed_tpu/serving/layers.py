@@ -114,8 +114,10 @@ import jax.numpy as jnp
 from ..models import cohere2_moe
 from ..kernels.ssm import live_slots
 from ..models.granite_hybrid import ssm_mix
-from ..models.deepseek_v2 import (absorb, attend_absorbed, attend_expanded,
-                                  latent_project, rms_norm_plain)
+from ..models.deepseek_v2 import (absorb, absorbed_attention,
+                                  attend_expanded, attend_rows,
+                                  latent_project, rms_norm_plain,
+                                  softmax_scale)
 from ..models.evabyte import (chunk_summaries, matmul32, project_qkv,
                               rms_norm, silu_gated_ffn)
 from ..models.gpt import layer_norm
@@ -482,28 +484,66 @@ def _eva_attend(spec, cfg, p, h, ck, cv, addr, s):
     return matmul32(attn.reshape(B, T, D), p["o"]), ck, cv
 
 
+def latent_info(cfg, s, q_len: int, cache_dtype, width: int) -> dict:
+    """`paged_info` over latent rows: one array a layer that is key and
+    value at once, one "K/V head" of `width` values (the latent c beside
+    the rotated key) that every query head reads."""
+    return dict(paged_info(cfg, s, q_len, cache_dtype),
+                kv_heads=1, head_dim=width)
+
+
+def _table_rows(pool, tables, block_size: int, width: int):
+    """Every entry of `tables` [B, W], whatever a slot holds, as slabs of
+    `block_size` rows, not row by row: a gather of 1,280-byte rows ran at
+    an eighth of the chip's bandwidth (PERF.md, PR 37).  -> [B, L, width]"""
+    lanes = pool.shape[1]
+    return pool.reshape(-1, block_size, lanes)[tables].reshape(
+        tables.shape[0], -1, lanes)[..., :width]
+
+
+def latent_attention_reference(q_row, pool, tables, q_pos, *,
+                               block_size: int, rank: int, scale: float):
+    """The absorbed core of `_latent_attend`: q_row [B, T, H, width] over
+    the latent rows that `tables` [B, W] address in the pool, gathered a
+    block at a time — every entry, whatever a slot holds — causal from
+    the table's first row for queries at q_pos [B, T].
+    -> [B, H, T, rank] float32."""
+    held = _table_rows(pool, tables, block_size, q_row.shape[-1])
+    mask = q_pos[:, :, None] >= jnp.arange(held.shape[1])[None, None, :]
+    return attend_rows(q_row, held, mask, rank, scale)
+
+
 def _latent_attend(cfg, p, h, pool, addr, s):
     """Queries and this call's latent rows ([c after its norm | rotated
     key], one a token for all heads) at the cache's dtype; the rows
     written through the table; causal softmax over every cached row of
-    the slot, gathered through its table — the rows expanded through
-    W_kv_b where the call's queries share that product (a prefill
-    chunk), W_kv_b absorbed into query and output where they do not
-    (decode); output projection.  -> float32."""
+    the slot — the table's rows gathered and expanded through W_kv_b
+    where the call's queries share that product (a prefill chunk); W_kv_b
+    absorbed into query and output where they do not (decode), over the
+    rows as they lie (through the kernel registry: the walk of each
+    slot's live blocks on the chip at `q_len` <= 8, kernels/paged.py;
+    the gather of every table entry elsewhere); output projection.
+    -> float32."""
     B, T, _ = h.shape
     q_nope, q_rope, rows = latent_project(cfg, p, h, addr.q_pos, pool.dtype)
     pool = _kv_write(pool, addr.write_idx, rows.reshape(B * T, 1, -1),
                      "dense")
-    # the table's blocks as slabs of `block_size` rows, not row by row:
-    # a gather of 1,280-byte rows ran at an eighth of the chip's
-    # bandwidth (PERF.md, PR 37)
-    lanes = pool.shape[1]
-    held = pool.reshape(-1, s.block_size, lanes)[addr.tables].reshape(
-        B, -1, lanes)[..., :rows.shape[-1]]                # [B, L, width]
-    L = held.shape[1]
-    mask = addr.q_pos[:, :, None] >= jnp.arange(L)[None, None, :]
-    attend = attend_absorbed if absorb(cfg, T, L) else attend_expanded
-    out = attend(cfg, p["kv_b"], q_nope, q_rope, held, mask)
+    width, bs = rows.shape[-1], s.block_size
+    L = addr.tables.shape[1] * bs
+    if absorb(cfg, T, L):
+        from ..kernels import registry
+
+        out = absorbed_attention(
+            cfg, p["kv_b"], q_nope, q_rope, pool.dtype,
+            lambda q_row: registry.dispatch(
+                "latent_attention", q_row, pool, addr.tables, addr.q_pos,
+                info=latent_info(cfg, s, T, pool.dtype, width),
+                block_size=bs, rank=cfg.kv_lora_rank,
+                scale=softmax_scale(cfg.head_dim, cfg.yarn)))
+    else:
+        held = _table_rows(pool, addr.tables, bs, width)
+        mask = addr.q_pos[:, :, None] >= jnp.arange(L)[None, None, :]
+        out = attend_expanded(cfg, p["kv_b"], q_nope, q_rope, held, mask)
     return matmul32(out, p["o"]), pool
 
 
@@ -527,9 +567,8 @@ def grouped_attention_reference(q, ck, cv, tables, q_pos, *, kv_heads: int,
     holds the largest position <= newest that is j modulo the ring.
     -> [B, T, H * Dh] float32."""
     B, Dh = q.shape[0], q.shape[3]
-    lanes = ck.shape[1]
-    held = lambda c: c.reshape(-1, block_size, lanes)[tables].reshape(
-        B, -1, lanes)[..., :kv_heads * Dh].reshape(B, -1, kv_heads, Dh)
+    held = lambda c: _table_rows(c, tables, block_size, kv_heads * Dh
+                                 ).reshape(B, -1, kv_heads, Dh)
     keys, vals = held(ck), held(cv)
     L = keys.shape[1]
     at = jnp.arange(L)[None, :]

@@ -79,6 +79,7 @@ def test_kernels_phase_toy():
                                    eva_positions=(0, 31, 32, 100, -1),
                                    grouped_shapes=((6, 8, 2, 64, 4, 1 / 64),
                                                    (3, 4, 2, 16, 3, None)),
+                                   latent_shape=(3, 4, 32, 16, 3),
                                    routed_shape=(16, 16, 128, 256),
                                    routed_live=2, ssm_shape=(6, 4, 8, 16),
                                    ssm_live=3, on_chip=False)
@@ -90,6 +91,7 @@ def test_kernels_phase_toy():
         "paged_attention_dense_H2_Dh128", "eva_attention_bf16_H2_Dh64",
         "grouped_attention_bf16_H8_KV2_Dh64",
         "grouped_attention_bf16_H4_KV2_Dh16",
+        "latent_attention_bf16_H4_W48",
         "touched_experts_bf16_T16_E16", "ssm_step_B6_H4_P8_N16_live3"]
 
 

@@ -359,6 +359,42 @@ def test_a_request_decodes_the_same_alone_and_in_a_batch():
     assert outs[1] == alone
 
 
+@pytest.mark.parametrize("way", ["oracle", "kernel"])
+def test_rows_walked_is_what_a_decode_step_fetches(way, chip_rule):
+    """`serve.mla.rows_walked` beside `serve.mla.rows_read`: where the
+    registry answers the oracle for the decode program's shapes (every
+    backend but the TPU) a step gathers the table's whole width for
+    every running slot; where it answers the walk, a slot's cached
+    length rounded up to a block — in decode's layers and no other call
+    (a prefill chunk keeps the gather), and the tokens served are the
+    oracle's."""
+    import contextlib
+
+    model, params = _model()
+    lengths, bs = (8, 21), 8
+    prompts = [_prompt(n, i + 1) for i, n in enumerate(lengths)]
+    with chip_rule("latent_attention") if way == "kernel" \
+            else contextlib.nullcontext():
+        eng = ServeEngine(model, params, _serve())
+        before = COUNTERS.snapshot()
+        out = eng.generate(prompts, 6)
+    d = COUNTERS.delta_since(before)
+    assert eng._walks_live_blocks == (way == "kernel")
+    held = [n + i + 1 for n in lengths for i in range(5)]
+    assert d["serve.mla.rows_read"] == {"calls": 10, "bytes": sum(held)}
+    fetched = sum(-(-h // bs) * bs for h in held) if way == "kernel" \
+        else 10 * eng.kv.table_width * bs
+    assert d["serve.mla.rows_walked"] == {"calls": 10, "bytes": fetched}
+    if way == "kernel":
+        # within a block a query of the rows attended
+        assert sum(held) <= fetched <= sum(held) + 10 * (bs - 1)
+        assert d["kernel.dispatches"]["calls"] == model.config.num_layers
+        assert out == ServeEngine(model, params, _serve()).generate(prompts,
+                                                                   6)
+    else:
+        assert "kernel.dispatches" not in d
+
+
 def test_counters_of_a_decode_step():
     model, params = _model()
     eng = ServeEngine(model, params, _serve())

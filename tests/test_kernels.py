@@ -350,6 +350,190 @@ def test_grouped_attention_is_the_oracle_off_the_chip():
     assert resolve_impl("grouped_attention", info=_GRANITE_INFO) == "jnp"
 
 
+# -- latent rows: the walk at one K/V head a row, one operand -----------------
+
+# DeepSeek-V2-Lite's row in the chatgen cell: 16 heads, the latent c of
+# 512 beside a rotated key of 64, 576 values in a pool row of 640 lanes
+_LATENT = dict(H=16, rank=512, rope=64)
+
+
+def _latent_both(T=1, H=16, rank=512, rope=64, scale=0.1147, **kw):
+    """(kernel, oracle, q_pos) of one `latent_attention` call: queries as
+    rows-shaped [R, T, H, rank + rope] over a pool that is key and value
+    at once -> [R, H, T, rank] float32."""
+    from deepspeed_tpu.serving.layers import latent_attention_reference
+
+    q, pool, _, tables, q_pos, bs = _paged_inputs(
+        "dense", T=T, H=H, KV=1, Dh=rank + rope, **kw)
+    assert pool.shape[1] % 128 == 0 and pool.shape[1] >= rank + rope
+    args = dict(block_size=bs, rank=rank, scale=scale)
+    ref = latent_attention_reference(q, pool, tables, q_pos, **args)
+    with kernel_config(interpret=True):
+        out = registry.dispatch("latent_attention", q, pool, tables, q_pos,
+                                impl="pallas", **args)
+    assert out.shape == ref.shape == (q.shape[0], H, T, rank)
+    assert out.dtype == ref.dtype == jnp.float32
+    return np.asarray(out), np.asarray(ref), q_pos
+
+
+@pytest.mark.parametrize("T,shape", [
+    # the cell's tile, blocks of 16 in a table of 20 (two tiles of the
+    # walk): scattered slots on and beside the edges of blocks
+    (1, dict(_LATENT, R=7, bs=16, W=20, lengths=_EDGE_LENGTHS_16,
+             dead_to_trash=True)),
+    (1, dict(_LATENT, R=7, bs=16, W=20, lengths=_EDGE_LENGTHS_16)),
+    (2, dict(_LATENT, R=7, bs=16, W=20,
+             lengths=[2, 16, 17, 256, 257, 320, 0], dead_to_trash=True)),
+    # live lists of none, one and all of the slots
+    (1, dict(_LATENT, R=3, bs=16, W=20, lengths=[0, 0, 0])),
+    (1, dict(_LATENT, R=3, bs=16, W=20, lengths=[0, 37, 0],
+             dead_to_trash=True)),
+    (1, dict(_LATENT, R=3, bs=16, W=20, lengths=[320, 48, 101],
+             dead_to_trash=True)),
+    (2, dict(_LATENT, R=3, bs=16, W=20, lengths=[0, 0, 0])),
+    (2, dict(_LATENT, R=3, bs=16, W=20, lengths=[0, 33, 0])),
+    (2, dict(_LATENT, R=3, bs=16, W=20, lengths=[320, 48, 101])),
+    # rows narrower than a lane tile (tests/test_deepseek_v2.py's toy:
+    # 4 heads, rank 32 + rope 16)
+    (1, dict(H=4, rank=32, rope=16, R=5, bs=8, W=4,
+             lengths=[1, 8, 9, 32, 0])),
+    (2, dict(H=4, rank=32, rope=16, R=5, bs=8, W=4,
+             lengths=[2, 8, 9, 32, 0], dead_to_trash=True)),
+], ids=_paged_case_id)
+def test_latent_attention_parity(T, shape):
+    """The walk over latent rows — one array scored and summed over,
+    the value its first `rank` lanes — vs the gather of every table
+    entry under the causal mask.  An idle slot reads nothing and its
+    output, which the engine discards, is zeros.  (A score is a float32
+    sum of 576 products of unit normals, so its rounding is some 4e-6
+    and moves a probability by as much: against float64 the oracle
+    stands 2e-6 off and the kernel 6e-6.)"""
+    out, ref, q_pos = _latent_both(T=T, **shape)
+    live = np.asarray(q_pos)[:, -1] >= 0
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-5)
+    assert not out[~live].any()
+
+
+def test_latent_attention_slot_ignores_the_other_slots():
+    kw = dict(_LATENT, R=3, bs=16, W=20, dead_to_trash=True)
+    a, _, _ = _latent_both(lengths=[37, 320, 7], **kw)
+    b, _, _ = _latent_both(lengths=[37, 1, 0], **kw)
+    assert np.array_equal(a[0], b[0])
+
+
+def test_latent_walk_copies_a_block_once():
+    """Key and value are one array: the call hands the kernel one
+    operand, and its program starts half the copies a block that the
+    same walk over a K and a V array does."""
+    from deepspeed_tpu.kernels import paged
+
+    q, pool, cv, tables, q_pos, bs = _paged_inputs(
+        "dense", H=16, KV=1, Dh=576, R=2, bs=16, W=20)
+
+    def walk(fn):
+        text = str(jax.make_jaxpr(fn)())
+        call = next(ln for ln in text.splitlines() if "pallas_call" in ln)
+        return text.count("dma_start"), text.count("dma_wait"), call
+
+    with kernel_config(interpret=True):
+        one = walk(lambda: paged.latent_attention_pallas(
+            q, pool, tables, q_pos, block_size=bs, rank=512, scale=0.1))
+        two = walk(lambda: paged.grouped_attention_pallas(
+            q, pool, cv, tables, q_pos, kv_heads=1, block_size=bs,
+            scale=0.1))
+    assert one[0] > 0 and one[1] > 0
+    assert (2 * one[0], 2 * one[1]) == two[:2]
+
+
+def test_latent_oracle_is_the_expression_the_layer_ran():
+    """`latent_attention` through the registry off the chip IS the gather
+    of every table entry and `attend_absorbed`'s scores, softmax and
+    weighted sum under `q_pos >= arange(L)`, bit for bit — alone, and
+    inside the two W_kv_b products."""
+    from deepspeed_tpu.models import DeepSeekV2Config
+    from deepspeed_tpu.models import deepseek_v2 as dsv2
+
+    cfg = DeepSeekV2Config(
+        vocab_size=128, max_seq_len=128, num_layers=1, num_heads=4,
+        d_model=64, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=16, v_head_dim=16, d_ff=96, first_k_dense=1,
+        num_experts=8, top_k=3, num_shared_experts=1, d_expert=48,
+        yarn=dsv2.Yarn(40.0, 64, 32.0, 1.0, 0.707, 0.707))
+    q, pool, _, tables, q_pos, bs = _paged_inputs(
+        "dense", H=4, KV=1, Dh=48, R=3, bs=4, W=4, lengths=[16, 7, 0])
+    rng = np.random.RandomState(1)
+    kv_b = jnp.asarray(rng.randn(32, 4 * 32) * 0.2, jnp.float32)
+    q_nope = jnp.asarray(rng.randn(3, 1, 4, 16), jnp.float32)
+    q_rope = q[..., 32:]
+    lanes = pool.shape[1]
+    held = pool.reshape(-1, bs, lanes)[tables].reshape(
+        3, -1, lanes)[..., :48]
+    mask = q_pos[:, :, None] >= jnp.arange(16)[None, None, :]
+    scale = dsv2.softmax_scale(cfg.head_dim, cfg.yarn)
+    through = lambda q_row: registry.dispatch(
+        "latent_attention", q_row, pool, tables, q_pos,
+        info={"q_len": 1, "kv_heads": 1, "head_dim": 48}, block_size=bs,
+        rank=32, scale=scale)
+    assert np.array_equal(
+        np.asarray(through(q)),
+        np.asarray(dsv2.attend_rows(q, held, mask, 32, scale)))
+    want = dsv2.attend_absorbed(cfg, kv_b, q_nope, q_rope, held, mask)
+    got = dsv2.absorbed_attention(cfg, kv_b, q_nope, q_rope, jnp.float32,
+                                  through)
+    assert want.shape == (3, 1, 4 * 16)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+_LATENT_INFO = dict(block_size=16, table_width=256, q_len=1, num_heads=16,
+                    kv_heads=1, head_dim=576, kv_mode="dense",
+                    kv_itemsize=2)
+
+
+@pytest.mark.parametrize("change,why", [
+    ({}, None),
+    (dict(q_len=2), None),
+    # DeepSeek-V2's 128 heads over the same row
+    (dict(num_heads=128), None),
+    (dict(q_len=512), "q_len 512 is a prefill chunk"),
+    (dict(kv_mode="int8"), "int8 rows"),
+    (dict(block_size=8), "a block of 8 rows is not whole tiles"),
+    (dict(block_size=24), "a block of 24 rows is not whole tiles"),
+    (dict(q_len=8, num_heads=128, head_dim=1088),
+     "8 x 128 score rows of 1152 lanes"),
+], ids=lambda v: _paged_case_id(v) if isinstance(v, dict) else "")
+def test_latent_attention_shape_rule(change, why, native):
+    """What the call site can see decides (serving/layers.py::
+    latent_info): a decode or verify call over dense latent rows takes
+    the walk on the chip; a prefill chunk, quantized rows and every
+    shape the walk cannot copy take the gather and say what is missing
+    when the kernel is forced."""
+    info = dict(_LATENT_INFO, **change)
+    if why is None:
+        assert resolve_impl("latent_attention", info=info) == "pallas"
+        return
+    assert resolve_impl("latent_attention", info=info) == "jnp"
+    with pytest.raises(RuntimeError, match=why):
+        resolve_impl("latent_attention", impl="pallas", info=info)
+
+
+def test_latent_attention_is_the_oracle_off_the_chip():
+    assert resolve_impl("latent_attention", info=_LATENT_INFO) == "jnp"
+
+
+def test_latent_info_is_what_the_call_site_sees():
+    """`latent_info` of the chatgen cell's decode call: the shapes, no
+    model's name, no option."""
+    from deepspeed_tpu.models import DeepSeekV2Config
+    from deepspeed_tpu.serving import ServeSchedule
+    from deepspeed_tpu.serving.layers import latent_info
+
+    cfg = DeepSeekV2Config(num_layers=9, param_dtype=jnp.bfloat16)
+    sched = ServeSchedule(max_batch=32, prefill_chunk=512, block_size=16,
+                          num_blocks=8193, table_width=256)
+    assert latent_info(cfg, sched, 1, jnp.bfloat16,
+                       cfg.latent_width) == _LATENT_INFO
+
+
 def _eva_inputs(positions, T=1, H=2, Dh=64, bs=4, window=32, chunk=4,
                 summary_blocks=8, dtype=jnp.float32, seed=0):
     """A pool `[rows, pool_width(H, Dh)]`, tables `[window blocks |

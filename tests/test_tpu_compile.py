@@ -195,6 +195,31 @@ def _command_a_full(**kw):
                                 nblocks=16385, scale=None), **kw))
 
 
+def _latent(slots=32, heads=16, rank=512, rope=64, bs=16, width=256,
+            nblocks=8193, q_len=1, kv_mode="dense", dtype=jnp.bfloat16):
+    """`latent_attention` as a latent layer's serving block calls it in a
+    decode step: DeepSeek-V2-Lite's cell by default (32 slots, 16 heads'
+    absorbed queries over rows of 512 + 64 values in 640 lanes, 8,193
+    blocks of 16 rows, a table of 256 entries, bf16 rows)."""
+    from deepspeed_tpu.serving.kv_cache import pool_width
+
+    w = rank + rope
+    shapes = (_sds((slots, q_len, heads, w), dtype),
+              _sds((nblocks * bs, pool_width(1, w)), dtype),
+              _sds((slots, width), jnp.int32),
+              _sds((slots, q_len), jnp.int32))
+    info = {"block_size": bs, "table_width": width, "q_len": q_len,
+            "num_heads": heads, "head_dim": w, "kv_mode": kv_mode,
+            "kv_itemsize": jnp.dtype(dtype).itemsize, "kv_heads": 1}
+
+    def fn(q_row, pool, tables, q_pos):
+        return registry.dispatch("latent_attention", q_row, pool, tables,
+                                 q_pos, info=info, block_size=bs, rank=rank,
+                                 scale=0.1147)
+
+    return fn, shapes, info
+
+
 def _eva(slots=8, heads=32, dh=128, bs=16, window=2048, chunk=16,
          summary_blocks=64, nblocks=1537, q_len=1, dtype=jnp.bfloat16):
     """`eva_attention` as EvaByte's serving block calls it: the cell's
@@ -387,6 +412,19 @@ CASES = [
          lambda: _command_a_full(window=4096, ring=True, width=288,
                                  nblocks=16 * 288 + 1),
          op="grouped_attention", refused=r"rows are a ring .*D11"),
+    # latent rows: a decode call at the chatgen cell's tile (16 score
+    # rows of 640 lanes over one operand), a verify step, DeepSeek-V2's
+    # 128 heads, and what keeps the gather
+    Case("latent_H16_W576_chatgen", _latent, op="latent_attention"),
+    Case("latent_H16_W576_verify4", lambda: _latent(q_len=4),
+         op="latent_attention"),
+    Case("latent_H128_W576", lambda: _latent(heads=128),
+         op="latent_attention"),
+    Case("latent_H16_W576_prefill512",
+         lambda: _latent(slots=1, q_len=512),
+         op="latent_attention", refused=r"q_len 512 is a prefill chunk"),
+    Case("latent_H16_W576_block8", lambda: _latent(bs=8),
+         op="latent_attention", refused=r"block of 8 rows is not whole"),
     Case("codec_quantize_int8_4M_block256",
          lambda: _codec("quantize", "int8"),
          op="quant_codec", variant="quantize"),
@@ -496,6 +534,12 @@ def _serve_attention(q_len, slots):
     ("command-a-plus-d4.serve.mixedlen.decode.sliding",
      lambda: ("grouped_attention",
               _command_a_full(window=4096, ring=True, width=288)[2]), "jnp"),
+    # 32 slots, 8,193 blocks of 16 latent rows of 576 values, a table of
+    # 256 entries, bf16
+    ("deepseek-v2-lite-d9.serve.chatgen.decode",
+     lambda: ("latent_attention", _latent()[2]), "pallas"),
+    ("deepseek-v2-lite-d9.serve.chatgen.prefill",
+     lambda: ("latent_attention", _latent(slots=1, q_len=512)[2]), "jnp"),
 ], ids=lambda v: v if isinstance(v, str) and "." in v else "")
 def test_auto_choice_for_each_benchmark_cell(cell, call, expect, native):
     """The trace-time half of "the same numbers": at each cell's shapes
@@ -706,11 +750,15 @@ def test_deepseek_cell_programs_compile_inside_one_chip(program, one_chip,
     """`deepseek-v2-lite-d9.serve.chatgen.decode` / `.prefill` at the
     cell's shapes (9 layers at published widths in bf16, 32 slots, 8,193
     blocks of 16 latent rows, a table of 256 entries, chunk 512), as the
-    chip traces them: latent attention is `jax.numpy`, so `decode`'s only
-    custom calls are the 8 routed layers' `touched_experts` kernels (32
-    rows are under the ridge) and the only ones in `prefill` are XLA's
-    own grouped products (`lax.ragged_dot` over the 64 experts: 512 rows
-    are over it); each pool enters as `[rows, 640]`, one array a layer;
+    chip traces them: the registry answers the walk for a decode step's
+    latent attention and the gather for a prefill chunk's, so `decode`'s
+    custom calls are the 9 layers' walks of each slot's live blocks — no
+    slot's whole table is gathered as `[8192, 16, 640]` — beside the 8
+    routed layers' `touched_experts` kernels (32 rows are under the
+    ridge), and the only ones in `prefill` are XLA's own grouped
+    products (`lax.ragged_dot` over the 64 experts: 512 rows are over
+    it); each pool enters as `[rows, 640]`, one array a layer, and in
+    `decode` nothing of its size moves, converts or gathers it;
     and weights, pool and temporaries fit the chip's 15.75 GB with the
     room the check's 1.68 GB of reference logits needs."""
     from deepspeed_tpu.models import DeepSeekV2, DeepSeekV2Config
@@ -747,12 +795,17 @@ def test_deepseek_cell_programs_compile_inside_one_chip(program, one_chip,
     compiled = progs[program].lower(params, caches, *args).compile()
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    by_shape = _hlo_by_shape(text)
+    pools = by_shape[(nblocks * bs, 640)]
     if program == "decode":
-        assert len(calls) == layers - 1
-        assert all("touched_experts" in ln for ln in calls)
+        assert sum("touched_experts" in ln for ln in calls) == layers - 1
+        assert sum("paged_attention_walk" in ln for ln in calls) == layers
+        assert len(calls) == 2 * layers - 1
+        assert (slots * width, bs, 640) not in by_shape
+        moved = {"copy", "transpose", "convert", "gather", "reshape"}
+        assert not moved & {op for op, _ in pools}, pools
     else:
         assert calls and all("ragged" in ln for ln in calls)
-    pools = _hlo_by_shape(text)[(nblocks * bs, 640)]
     assert {layout for _, layout in pools} == {"1,0"}  # row-major
     m = compiled.memory_analysis()
     total = m.argument_size_in_bytes + m.temp_size_in_bytes + \
