@@ -446,6 +446,8 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                   eva_positions=(0, 100, 2047, 2048, 9000, 12345, 16383, -1),
                   grouped_shapes=((64, 32, 8, 64, 128, 1 / 64),
                                   (16, 128, 8, 128, 1024, None)),
+                  prefill_shape=(128, 8, 128, 1024, 512),
+                  prefill_starts=(0, 3003, 16084),
                   latent_shape=(32, 16, 512, 64, 256),
                   routed_shape=(32, 64, 2048, 1408), routed_live=12,
                   ssm_shape=(64, 64, 64, 128), ssm_live=37,
@@ -690,6 +692,43 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
             f"grouped_attention_bf16_H{g_heads}_KV{g_kv}_Dh{g_dim}",
             [slots, width * bs, g_heads, g_dim], got[held > 0],
             want[held > 0], rtol=0, atol=1e-2))
+
+    # and a prefill chunk of mixedlen's full layer (one request, 512
+    # queries of 128 heads on 8 K/V heads of 128, a table of 1,024
+    # blocks): a first chunk, one whose run ends inside a block, and a
+    # last chunk whose padded tail runs past the table — its valid rows
+    # agree; entries behind the run at the trash block.  bf16 rows, the
+    # default precision
+    p_heads, p_kv, p_dim, width, chunk = prefill_shape
+    bs, nblocks = 16, 1 + width
+    rows = (nblocks * bs, p_kv, p_dim)
+    ck = pool_rows(jax.random.normal(key[6], rows, jnp.bfloat16))
+    cv = pool_rows(jax.random.normal(key[7], rows, jnp.bfloat16))
+    pq = jax.random.normal(key[0], (1, chunk, p_heads, p_dim), jnp.bfloat16)
+    info = {"block_size": bs, "table_width": width, "q_len": chunk,
+            "num_heads": p_heads, "kv_heads": p_kv, "head_dim": p_dim,
+            "kv_mode": "dense", "kv_itemsize": 2, "window": 0,
+            "ring": False, "batch": 1}
+    chosen = registry.resolve_impl("grouped_attention", info=info)
+    if on_chip and chosen != "pallas":
+        raise RuntimeError(
+            f"auto resolved a prefill chunk of {chunk} queries at {p_heads} "
+            f"heads on {p_kv} of {p_dim} to {chosen!r} on this chip")
+    kw = dict(kv_heads=p_kv, block_size=bs, scale=None)
+    walk = jax.jit(lambda *a: registry.dispatch(
+        "grouped_attention", *a, info=info, **kw))
+    gather = jax.jit(lambda *a: grouped_attention_reference(*a, **kw))
+    entries = rs.permutation(np.arange(1, nblocks))
+    for start in prefill_starts:
+        valid = min(chunk, width * bs - start)
+        table = np.where(np.arange(width) < -(-(start + valid) // bs),
+                         entries, 0)
+        a = (pq, ck, cv, jnp.asarray(table[None], jnp.int32),
+             jnp.asarray(start + np.arange(chunk)[None], jnp.int32))
+        out.append(_close(
+            f"grouped_prefill_bf16_H{p_heads}_KV{p_kv}_Dh{p_dim}_at{start}",
+            [1, chunk, width * bs, p_heads, p_dim], walk(*a)[:, :valid],
+            gather(*a)[:, :valid], rtol=0, atol=1e-2))
 
     # attention over latent rows, one decode step at the chatgen cell's
     # shape (32 slots, 16 heads' absorbed queries over rows of 512 + 64

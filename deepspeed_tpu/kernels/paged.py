@@ -14,7 +14,8 @@ serving/layers.py's `_paged_attend` ran before there was a kernel:
 gather the rows of every table entry, dequantize if the cache is
 quantized, one fp32 einsum/softmax/einsum chain under the
 `q_pos >= k_idx` mask.  Its cost is the table's whole width whatever a
-slot holds; it stays for prefill (one request's rows), for every
+slot holds; it stays for prefill (one request's rows; grouped rows of
+whole-lane-tile heads walk there too, below), for every
 backend but the TPU (all of tier-1 on CPU, where serving output stays
 bit-identical to `generate()`), and as the kernel's correctness
 contract.
@@ -79,6 +80,29 @@ array a layer, key and value at once — [latent c | rotated key], one
 absorbed query over the row's lanes; the weighted sum runs over the SAME
 tile, so the call hands the kernel one operand and a block is copied
 once; the caller keeps the accumulator's first `rank` lanes, the value.
+
+A prefill chunk over grouped rows (`_prefill_walk`, what
+`grouped_attention_pallas` runs past `STEP_QUERIES` queries; a full
+layer's chunk of serving/layers.py `_grouped_attend`) is a kernel of its
+own beside `_walk_kernel`, with the same liveness and the same online
+softmax: ONE request, its table and `q_pos` in scalar prefetch, the grid
+over tiles of `tq` query positions.  A program copies the request's
+blocks from the table's first entry to the one its LAST position needs
+(tiles of blocks, double-buffered, as above) and nothing behind it — a
+padded tail's positions run past the table, and the run ends with it.
+What differs is the product: a chunk is compute-bound, and block-diagonal
+queries would multiply every K/V head's lanes for each query head —
+`kv_heads` times the MXU work.  So the program regroups its queries once
+to `[kv_heads, G * tq, Dh]` and multiplies K/V head n's `G * tq` rows
+with that head's 128-lane slice of the tile (`Dh` a whole number of lane
+tiles: narrower heads keep the gather); tiles every query of the program
+sees whole skip the mask; the float32 accumulator leaves normalised, as
+`[1, T, H * Dh]`, written once.  Not one body with the decode walk: that
+one unrolls over <= 8 queries whose score rows fill one MXU tile, this
+one tiles 512 of them over a grid and loops over K/V heads — the copies
+(`tile_copies`) and the softmax's update are the shared idiom, the tile
+and the loop nest are not.  The paged, latent and summarised rows keep
+the oracle in prefill (ROADMAP S11's later cases).
 """
 
 from __future__ import annotations
@@ -102,6 +126,17 @@ _ACC_BYTES = 6 << 20
 # rows a tile aims for: the scores of a tile are `[T * Hp, rows]`, so a
 # multiple of the 128 lanes keeps them dense
 _TILE_ROWS = 256
+# the most queries a slot has in a decode or verify step, over which
+# `_walk_kernel` unrolls; a call of more is a prefill chunk
+STEP_QUERIES = 8
+# a prefill chunk's walk (`_prefill_walk`): rows a K/V tile aims for,
+# query positions a program may take (the largest that divides the chunk
+# and fits), what its tiles may take of VMEM and what is left to the
+# compiler beside them (v5e: 128 MiB, of which 16 are the default)
+_PREFILL_TILE_ROWS = 512
+_PREFILL_QUERIES = (64, 32, 16, 8)
+_PREFILL_TILE_BYTES = 40 << 20
+_PREFILL_REST = 16 << 20
 
 
 def kv_read(c, rows, num_heads: int, head_dim: int,
@@ -223,6 +258,22 @@ def _tile_kv(buf, sbuf, slot, kv_mode, H, Dh, marker):
     return jnp.where(codes == marker, jnp.float32(jnp.nan), codes * spread)
 
 
+def _tile_copies(pairs, sem, entry, n_blocks, KB, tile, slot, go):
+    """`go` (start or wait) every live block of `tile`: one copy a block
+    and (pool, buffer) pair, a block being `bs` whole rows of the pool,
+    the one `entry(blk)` names."""
+    first = tile * KB
+
+    def one(blk, carry):
+        for n, (src, dst) in enumerate(pairs):
+            go(pltpu.make_async_copy(
+                src.at[entry(blk)], dst.at[slot, blk - first],
+                sem.at[n, slot]))
+        return carry
+
+    jax.lax.fori_loop(first, jnp.minimum(first + KB, n_blocks), one, 0)
+
+
 def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
                  kv_mode, marker, window=0, chunk=0, G=1):
     if kv_mode == "dense" and len(rest) == 7:
@@ -268,20 +319,9 @@ def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
         entry = lambda blk: jnp.where(blk < n_win, blk,
                                       blk - n_win + window // bs)
     n_tiles = (n_blocks + KB - 1) // KB
-
-    def tile_copies(tile, slot, go):
-        """`go` (start or wait) every live block of `tile`: one copy a
-        block and array, a block being `bs` whole rows of the pool."""
-        first = tile * KB
-
-        def one(blk, carry):
-            for n, (src, dst) in enumerate(pairs):
-                go(pltpu.make_async_copy(
-                    src.at[tbl[b, entry(blk)]], dst.at[slot, blk - first],
-                    sem.at[n, slot]))
-            return carry
-
-        jax.lax.fori_loop(first, jnp.minimum(first + KB, n_blocks), one, 0)
+    tile_copies = functools.partial(
+        _tile_copies, pairs, sem, lambda blk: tbl[b, entry(blk)], n_blocks,
+        KB)
 
     o_ref[...] = jnp.zeros_like(o_ref)
 
@@ -417,10 +457,209 @@ def grouped_attention_pallas(q, ck, cv, tables, q_pos, *, kv_heads: int,
             "table from its first entry; a window's lower bound and a "
             "ring's modular rows are liveness rules it does not have")
     B, T, H, Dh = q.shape
+    args = dict(block_size=int(block_size), kv_heads=int(kv_heads),
+                scale=None if scale is None else float(scale),
+                interpret=pallas_backend.interpret())
+    if T > STEP_QUERIES:
+        return _prefill_walk(q, ck, cv, tables, q_pos, **args)
     return _walk(q, ck, cv, tables, q_pos, kv_mode="dense",
-                 block_size=int(block_size), kv_heads=int(kv_heads),
-                 scale=None if scale is None else float(scale),
-                 interpret=pallas_backend.interpret()).reshape(B, T, H * Dh)
+                 **args).reshape(B, T, H * Dh)
+
+
+def prefill_tiles(q_len: int, num_heads: int, kv_heads: int, head_dim: int,
+                  block_size: int, table_width: int, itemsize: int):
+    """(query positions a program takes, blocks a K/V tile holds) of a
+    prefill chunk's walk, or None where no tile of whole sublanes divides
+    the chunk and fits `_PREFILL_TILE_BYTES`: the queries as they come
+    and regrouped, the float32 accumulator and output block, `m` and `l`
+    a lane tile wide, K and V double-buffered, and a K/V head's scores
+    three times (scores, probabilities, their cast)."""
+    lanes = num_heads * head_dim
+    kb = min(max(1, _PREFILL_TILE_ROWS // block_size), table_width)
+    rows = kb * block_size
+    for tq in _PREFILL_QUERIES:
+        if q_len % tq:
+            continue
+        held = (tq * lanes * (3 * itemsize + 3 * 4)
+                + 2 * tq * num_heads * 128 * 4
+                + 4 * rows * kv_heads * head_dim * itemsize
+                + 3 * tq * (num_heads // kv_heads) * rows * 4)
+        if held <= _PREFILL_TILE_BYTES:
+            return tq, kb
+    return None
+
+
+def _prefill_kernel(tbl, qp, q_ref, pos_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
+                    sem, q_s, acc, m_s, l_s, *, scale, bs, W, KB, tq, KV, G,
+                    Dh):
+    """One program a tile of `tq` query positions of the one request:
+    its blocks from the table's first entry to the one the tile's LAST
+    position needs, a tile of `KB` at a time; a K/V head's `G * tq` query
+    rows against that head's lanes of the tile."""
+    i = pl.program_id(0)
+    TK, R = KB * bs, G * tq
+
+    def span(t, c):
+        p = qp[0, i * tq + t]
+        return jnp.minimum(c[0], p), jnp.maximum(c[1], p)
+
+    first, last = jax.lax.fori_loop(
+        1, tq, span, (qp[0, i * tq], qp[0, i * tq]))
+    # a padded tail's positions run past the table: the run ends with it
+    n_blocks = jnp.clip((last + bs) // bs, 0, W)
+    n_tiles = (n_blocks + KB - 1) // KB
+    # tiles every query of the program sees whole need no mask
+    n_whole = jnp.clip((first + 1) // TK, 0, n_tiles)
+    tile_copies = functools.partial(
+        _tile_copies, ((k_hbm, kbuf), (v_hbm, vbuf)), sem,
+        lambda blk: tbl[0, blk], n_blocks, KB)
+
+    @pl.when(n_tiles == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    def head_lanes(h):
+        """Query head h's (or K/V head h's) `Dh` lanes of a row."""
+        return pl.ds(pl.multiple_of(h * Dh, 128), Dh)
+
+    def per_head(fn):
+        """`fn(n)` for every K/V head n, in a loop: unrolled, the body
+        is traced and lowered `KV` times (seconds of every start-up)."""
+        jax.lax.fori_loop(0, KV, lambda n, c: fn(n) or c, 0)
+
+    @pl.when(n_tiles > 0)
+    def _walk():
+        # a masked row's probability is 0 and must meet a finite value
+        vbuf[...] = jnp.zeros_like(vbuf)
+        tile_copies(0, 0, lambda cp: cp.start())
+
+        def regroup(n):
+            # query head (n, g)'s rows under K/V head n: [KV, G * tq, Dh]
+            for g in range(G):
+                q_s[n, g * tq:(g + 1) * tq, :] = \
+                    q_ref[0, :, head_lanes(n * G + g)]
+
+        per_head(regroup)
+        acc[...] = jnp.zeros_like(acc)
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        qrow = jnp.concatenate([pos_ref[...]] * G, axis=0)       # (R, 1)
+
+        def attend(tile, slot, masked):
+            if masked:
+                kidx = tile * TK + jax.lax.broadcasted_iota(
+                    jnp.int32, (R, TK), 1)
+                mask = qrow >= kidx
+
+            def head(n):
+                q = q_s[n]
+                k = kbuf[slot, :, :, head_lanes(n)].reshape(TK, Dh)
+                dt = jnp.promote_types(q.dtype, k.dtype)
+                s = jax.lax.dot_general(
+                    q.astype(dt), k.astype(dt), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale   # (R, TK)
+                if masked:
+                    s = jnp.where(mask, s, NEG_INF)
+                m_prev = m_s[n, :, :1]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                if masked:
+                    p = jnp.where(mask, p, 0.0)
+                alpha = jnp.exp(m_prev - m_new)
+                l_new = alpha * l_s[n, :, :1] + jnp.sum(p, axis=1,
+                                                        keepdims=True)
+                v = vbuf[slot, :, :, head_lanes(n)].reshape(TK, Dh)
+                acc[n] = acc[n] * alpha + jnp.dot(
+                    p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+                m_s[n] = jnp.broadcast_to(m_new, m_s.shape[1:])
+                l_s[n] = jnp.broadcast_to(l_new, l_s.shape[1:])
+
+            per_head(head)
+
+        def body(masked, j, carry):
+            slot = jax.lax.rem(j, 2)
+
+            @pl.when(j + 1 < n_tiles)
+            def _():
+                tile_copies(j + 1, 1 - slot, lambda cp: cp.start())
+
+            tile_copies(j, slot, lambda cp: cp.wait())
+            attend(j, slot, masked)
+            return carry
+
+        jax.lax.fori_loop(0, n_whole, functools.partial(body, False), 0)
+        jax.lax.fori_loop(n_whole, n_tiles, functools.partial(body, True), 0)
+
+        def leave(n):
+            for g in range(G):
+                rows = slice(g * tq, (g + 1) * tq)
+                l = l_s[n, rows, :1]
+                o_ref[0, :, head_lanes(n * G + g)] = \
+                    acc[n, rows, :] / jnp.where(l == 0.0, 1.0, l)
+
+        per_head(leave)
+
+
+@functools.partial(jax.jit, static_argnames=("kv_heads", "block_size",
+                                             "scale", "interpret"))
+def _prefill_walk(q, ck, cv, tables, q_pos, *, kv_heads, block_size, scale,
+                  interpret):
+    """A prefill chunk's call, a function of its own (a program lowers
+    it once): ONE request's queries q [1, T, H, Dh] over the dense pool
+    rows of `kv_heads` heads its table [1, W] addresses, causal from the
+    table's first row under `row <= q_pos[t]` -> [1, T, H * Dh] float32.
+    The grid runs over tiles of query positions; the MXU takes a K/V
+    head's `G * tq` query rows against that head's 128-lane slices of
+    the tile, not the decode walk's block-diagonal queries, which would
+    multiply every head's lanes for each."""
+    B, T, H, Dh = q.shape
+    W, bs, KV = tables.shape[1], block_size, kv_heads
+    G = H // KV
+    tiles = prefill_tiles(T, H, KV, Dh, bs, W, ck.dtype.itemsize)
+    if B != 1 or G * KV != H or Dh % 128 or ck.shape[1] != KV * Dh \
+            or tiles is None:
+        raise ValueError(
+            f"paged attention kernel: a prefill chunk's walk takes one "
+            f"request's queries ({B} given) on whole groups of K/V heads "
+            f"({H} on {KV}) of whole 128-lane tiles ({Dh} values, rows of "
+            f"{ck.shape[1]} lanes), in tiles of {_PREFILL_QUERIES} queries "
+            f"that divide the chunk ({T}) and fit VMEM")
+    tq, KB = tiles
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(T // tq,),
+        in_specs=[
+            pl.BlockSpec((1, tq, H * Dh), lambda i, t, s: (0, i, 0)),
+            pl.BlockSpec((tq, 1), lambda i, t, s: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, tq, H * Dh), lambda i, t, s: (0, i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, KB, bs, KV * Dh), ck.dtype),
+            pltpu.VMEM((2, KB, bs, KV * Dh), cv.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((KV, G * tq, Dh), q.dtype),
+            pltpu.VMEM((KV, G * tq, Dh), jnp.float32),
+            pltpu.VMEM((KV, G * tq, 128), jnp.float32),
+            pltpu.VMEM((KV, G * tq, 128), jnp.float32),
+        ],
+    )
+    q_pos = q_pos.astype(jnp.int32)
+    return pl.pallas_call(
+        functools.partial(_prefill_kernel,
+                          scale=Dh ** -0.5 if scale is None else scale,
+                          bs=bs, W=W, KB=KB, tq=tq, KV=KV, G=G, Dh=Dh),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((1, T, H * Dh), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.PARALLEL,),
+            vmem_limit_bytes=_PREFILL_TILE_BYTES + _PREFILL_REST),
+        interpret=interpret,
+        name="paged_attention_prefill_walk",
+    )(tables.astype(jnp.int32), q_pos, q.reshape(1, T, H * Dh),
+      q_pos.reshape(T, 1), ck.reshape(-1, bs, KV * Dh),
+      cv.reshape(-1, bs, KV * Dh))
 
 
 def latent_attention_pallas(q_row, pool, tables, q_pos, *, block_size: int,

@@ -255,23 +255,15 @@ class SparseAttentionOp(KernelOp):
             dropout_rate=dropout_rate, dropout_rng=dropout_rng)
 
 
-def _walk_supports(info: Mapping) -> Tuple[bool, str]:
-    """What kernels/paged.py's walk of live blocks takes, from what a
-    serving call site observes (serving/layers.py::paged_info): the
-    tile is `q_len` x `num_heads` score rows of a cache row's lanes —
-    the row's `kv_heads` heads where it has fewer than the queries."""
-    t = int(info.get("q_len", 1))
-    if t > 8:
-        return False, (f"q_len {t} is a prefill chunk: the kernel "
-                       f"unrolls over a decode or verify step's "
-                       f"queries (<= 8); prefill reads one request's "
-                       f"rows through the jnp oracle")
+def _copies_blocks(info: Mapping) -> Tuple[bool, str]:
+    """Whether a block of the call's rows is a slab kernels/paged.py's
+    walks can copy where it lies: dense rows, whole sublane tiles."""
     mode = info.get("kv_mode", "dense")
     bs = int(info.get("block_size", 0))
-    H = int(info.get("num_heads", 1))
     if mode != "dense":
         return False, (f"{mode} rows: the kernel would copy a "
-                       f"block's ({bs}, {H}) tile of scales, and "
+                       f"block's ({bs}, {int(info.get('num_heads', 1))}) "
+                       f"tile of scales, and "
                        f"\"Slice shape along dimension 2 must be "
                        f"aligned to tiling (128)\"; the oracle "
                        f"dequantises the gathered rows")
@@ -281,9 +273,29 @@ def _walk_supports(info: Mapping) -> Tuple[bool, str]:
         return False, (f"a block of {bs} rows is not whole tiles of "
                        f"{sublanes} rows at {item} bytes a value, so "
                        f"it is no slab the kernel can copy")
-    from ..serving.kv_cache import pool_width
-    from .paged import tile_blocks
+    return True, ""
 
+
+def _walk_supports(info: Mapping) -> Tuple[bool, str]:
+    """What kernels/paged.py's walk of live blocks takes, from what a
+    serving call site observes (serving/layers.py::paged_info): the
+    tile is `q_len` x `num_heads` score rows of a cache row's lanes —
+    the row's `kv_heads` heads where it has fewer than the queries."""
+    from .paged import STEP_QUERIES, tile_blocks
+
+    t = int(info.get("q_len", 1))
+    if t > STEP_QUERIES:
+        return False, (f"q_len {t} is a prefill chunk: the kernel "
+                       f"unrolls over a decode or verify step's "
+                       f"queries (<= {STEP_QUERIES}); prefill reads one "
+                       f"request's rows through the jnp oracle")
+    ok, why = _copies_blocks(info)
+    if not ok:
+        return ok, why
+    from ..serving.kv_cache import pool_width
+
+    bs, item = int(info["block_size"]), int(info.get("kv_itemsize", 2))
+    H = int(info.get("num_heads", 1))
     width = pool_width(int(info.get("kv_heads", H)),
                        int(info.get("head_dim", 128)))
     if not tile_blocks(bs, int(info.get("table_width", 1)),
@@ -326,18 +338,56 @@ class PagedAttentionOp(KernelOp):
         return paged.paged_attention_reference(*args, **kwargs)
 
 
+def _prefill_walk_supports(info: Mapping) -> Tuple[bool, str]:
+    """What kernels/paged.py's walk for a prefill chunk takes
+    (`_prefill_walk`): ONE request's queries, a K/V head's query rows
+    against that head's 128-lane slices of a tile of the row."""
+    from .paged import prefill_tiles
+
+    B, t = int(info.get("batch", 1)), int(info["q_len"])
+    if B != 1:
+        return False, (f"{B} sequences of {t} queries: the prefill walk "
+                       f"runs one request's table, its grid the tiles of "
+                       f"that request's query positions")
+    ok, why = _copies_blocks(info)
+    if not ok:
+        return ok, why
+    H = int(info.get("num_heads", 1))
+    KV, Dh = int(info.get("kv_heads", H)), int(info.get("head_dim", 128))
+    if Dh % 128 or H % KV:
+        return False, (f"{H} query heads on {KV} K/V heads of {Dh} values: "
+                       f"the prefill walk multiplies a K/V head's queries "
+                       f"with that head's lanes of a tile, which must be "
+                       f"whole 128-lane tiles of whole groups")
+    if not prefill_tiles(t, H, KV, Dh, int(info["block_size"]),
+                         int(info.get("table_width", 1)),
+                         int(info.get("kv_itemsize", 2))):
+        return False, (f"no tile of whole sublanes of query positions "
+                       f"divides the {t} of the chunk and fits the "
+                       f"kernel's VMEM at {H} heads of {Dh}")
+    return True, ""
+
+
 class GroupedAttentionOp(KernelOp):
     """Attention over paged rows of `kv_heads` heads that each serve
     `num_heads / kv_heads` query heads (serving/layers.py
-    `_grouped_attend`).  Pallas = the paged walk at a grouped tile: the
-    row's heads as they lie, `q_len` x `num_heads` score rows whose
-    queries sit in the lanes of the K/V head they read (kernels/paged.py
-    `grouped_attention_pallas`).  Oracle = the gather of every table
-    entry and `attend_grouped` under the layer's visibility mask, the
-    expression the layer ran before there was a kernel
-    (`grouped_attention_reference`).  The shape rule is the walk's at
-    that tile, and that the layer's rows are one causal run of the
-    table: a window or a ring (`grouped_info`) keeps the gather."""
+    `_grouped_attend`).  Pallas, a decode or verify step: the paged walk
+    at a grouped tile, the row's heads as they lie, `q_len` x
+    `num_heads` score rows whose queries sit in the lanes of the K/V
+    head they read.  Pallas, a prefill chunk (`q_len` over
+    `paged.STEP_QUERIES`): the one request's live blocks from the
+    table's first entry to the one each tile of query positions needs,
+    a K/V head's queries against that head's lanes (kernels/paged.py
+    `grouped_attention_pallas` for both).  Oracle = the gather of every
+    table entry and `attend_grouped` under the layer's visibility mask,
+    the expression the layer ran before there was a kernel
+    (`grouped_attention_reference`).  The shape rule is that the layer's
+    rows are one causal run of the table — a window or a ring
+    (`grouped_info`) keeps the gather — and then the walk's at its tile;
+    a prefill chunk's besides: one request, heads of whole 128-lane
+    tiles, tiles that fit VMEM.  The paged, latent and EVA ops keep
+    `q_len` <= `STEP_QUERIES`: their prefill is ROADMAP S11's later
+    cases."""
 
     NAME = "grouped_attention"
 
@@ -355,6 +405,10 @@ class GroupedAttentionOp(KernelOp):
                            f"liveness rule is causal from the table's first "
                            f"entry, with no lower bound until the runs are "
                            f"data (ROADMAP D11)")
+        from .paged import STEP_QUERIES
+
+        if int(info.get("q_len", 1)) > STEP_QUERIES:
+            return _prefill_walk_supports(info)
         return _walk_supports(info)
 
     def pallas(self, variant, *args, **kwargs):

@@ -154,8 +154,14 @@ Instrumented sites:
   cached length rounded up to a block in a layer whose decode call
   resolves to the walk of live blocks, the table's — or the ring's —
   whole width in a layer that gathers; `kernels/registry.py`, asked
-  once for each kind of layer at build), all three from positions on
-  the host; `kv.ring_wraps` — calls = requests that ended
+  once for each kind of layer at build);
+  `serve.attn.prefill_rows_walked` — calls = prefill chunks launched,
+  bytes = pool rows the chunk's attention FETCHES over the same layers
+  (its last position + 1 rounded up to a block, the table's width at
+  most, in a layer whose prefill call resolves to the walk of the
+  request's live blocks; its run's whole width in a layer that
+  gathers; asked once for each kind of layer at build), all four from
+  positions on the host; `kv.ring_wraps` — calls = requests that ended
   with more rows than a ring holds, bytes = blocks the ring saved them
   in the window group.  Behind a share of the experts
   `serve.moe.experts_touched` and `serve.moe.experts_streamed` count
@@ -175,8 +181,9 @@ Instrumented sites:
   prefill_tokens` — calls = prefill chunks, bytes = valid tokens
   scanned; `serve.ssm.state_resets` — calls = slots zeroed on the
   device as a request is seated (serving/kv_cache.py `reset_state`);
-  `serve.attn.rows_read` and `serve.attn.rows_walked` as above over
-  the attention layers alone.
+  `serve.attn.rows_read`, `serve.attn.rows_walked` and
+  `serve.attn.prefill_rows_walked` as above over the attention layers
+  alone.
   Paged attention (the GPT family): `serve.paged.rows_walked`
   — calls = slots decoded, bytes = pool rows their attention reads (a
   slot's live blocks where the paged kernel runs, the table's whole

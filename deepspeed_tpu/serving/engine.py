@@ -182,6 +182,13 @@ the pool rows the layers fetch for that: a slot's cached length rounded
 up to a block in a layer whose decode call the registry resolves to the
 walk of live blocks, its run's whole width — the table's, or the ring's
 — in a layer that gathers; asked once for each kind of layer at build),
+`serve.attn.prefill_rows_walked` (calls = prefill chunks launched, bytes
+= the pool rows the layers fetch for a chunk: its last position + 1
+rounded up to a block — its padded tail's, the table's width at most —
+in a layer whose prefill call the registry resolves to the walk of the
+request's live blocks, the whole width of its run in a layer that
+gathers; asked once for each kind of layer at build, for one request's
+`prefill_chunk` queries),
 `kv.ring_wraps` (calls = requests that ended with more rows
 than a ring, bytes = the blocks the ring saved each in the window
 group); behind a share of the experts `serve.moe.experts_touched`
@@ -216,8 +223,8 @@ slot's of both, twice), `serve.ssm.slots_live` (calls
 = decode steps, bytes = running slots x layers with a state),
 `serve.ssm.prefill_tokens` (calls = prefill chunks, bytes = valid
 tokens scanned), `serve.ssm.state_resets` (calls = slots zeroed) and
-`serve.attn.rows_read` and `serve.attn.rows_walked` over the attention
-layers.
+`serve.attn.rows_read`, `serve.attn.rows_walked` and
+`serve.attn.prefill_rows_walked` over the attention layers.
 
 What a paged step reads: `serve.paged.rows_walked` (calls = slots
 decoded, bytes = the pool rows attention reads for them: a slot's live
@@ -605,17 +612,26 @@ class ServeEngine:
             op, info=info) == "pallas"
         # (a learned selection gathers the rows it chose: no walk)
         self._walks_live_blocks = False
+        # (grouped rows alone count what a prefill chunk fetches)
+        self._prefill_walks = None
         if spec.attention == "latent" and not self._index_layers:
             self._walks_live_blocks = walks("latent_attention", latent_info(
                 cfg, schedule, q_len, pool.dtype, spec.latent_width))
         elif spec.attention == "grouped":
             # a full layer and a sliding one are asked apart: a sliding
-            # layer's rows are a ring, or the table under a window
-            ask = lambda window: walks("grouped_attention", grouped_info(
-                spec, cfg, schedule, q_len, pool.dtype, window,
-                bool(window and ring_blocks)))
-            self._walks_live_blocks = ask(0)
-            self._sliding_walks = bool(self._window) and ask(self._window)
+            # layer's rows are a ring, or the table under a window; and a
+            # decode step apart from a prefill chunk, one request's
+            ask = lambda window, q_len, batch: walks(
+                "grouped_attention", grouped_info(
+                    spec, cfg, schedule, q_len, pool.dtype, window,
+                    bool(window and ring_blocks), batch))
+            step, chunk = (q_len, c.max_batch), (c.prefill_chunk, 1)
+            self._walks_live_blocks = ask(0, *step)
+            self._sliding_walks = bool(self._window) and ask(
+                self._window, *step)
+            self._prefill_walks = ask(0, *chunk)
+            self._sliding_prefill_walks = bool(self._window) and ask(
+                self._window, *chunk)
         elif spec.attention == "paged":
             self._walks_live_blocks = walks("paged_attention", paged_info(
                 cfg, schedule, q_len, pool.dtype))
@@ -1020,6 +1036,13 @@ class ServeEngine:
         if self.kv.windowed:
             self._close_full_window(req)
         COUNTERS.add("serve.prefill_chunks", nbytes=n_valid)
+        if self._prefill_walks is not None:
+            # the chunk's last position is its padded tail's
+            COUNTERS.add(
+                "serve.attn.prefill_rows_walked",
+                nbytes=self._grouped_rows_fetched(
+                    np.array([pos0 + C]), self._prefill_walks,
+                    self._sliding_prefill_walks))
         if self._index_shared:
             COUNTERS.add("serve.sparse.selections_shared",
                          calls=self._index_shared)
@@ -1256,20 +1279,28 @@ class ServeEngine:
         COUNTERS.add("serve.attn.rows_read", calls=len(lanes),
                      nbytes=self._sliding_layers * in_window
                      + full_layers * int(held.sum()))
-        # what the layers FETCH for that: a slot's live blocks where the
-        # layer's decode is the walk, every entry of its run — the table,
-        # or the ring — where it gathers
+        COUNTERS.add("serve.attn.rows_walked", calls=len(lanes),
+                     nbytes=self._grouped_rows_fetched(
+                         held, self._walks_live_blocks, self._sliding_walks))
+
+    def _grouped_rows_fetched(self, held, full_walks: bool,
+                              sliding_walks: bool) -> int:
+        """The pool rows the layers with grouped rows FETCH for calls
+        that reach `held` [n] rows each — decoded slots, or the one
+        request of a prefill chunk, its padded tail counted: a call's
+        live blocks where the kind of layer (full, sliding) walks, every
+        entry of its run — the table, or the ring — where it gathers."""
+        bs, ring = self.kv.block_size, self.kv.ring_tokens
         table = self.kv.table_width * bs
+        full_layers = self.model.config.num_layers - self._sliding_layers \
+            - len(self._state_layers)
 
         def fetched(walks: bool, run: int) -> int:
             return int(np.minimum(-(-held // bs) * bs, run).sum()) if walks \
-                else run * len(lanes)
+                else run * len(held)
 
-        COUNTERS.add("serve.attn.rows_walked", calls=len(lanes),
-                     nbytes=full_layers * fetched(self._walks_live_blocks,
-                                                  table)
-                     + self._sliding_layers * fetched(self._sliding_walks,
-                                                      ring or table))
+        return full_layers * fetched(full_walks, table) \
+            + self._sliding_layers * fetched(sliding_walks, ring or table)
 
     def _count_rows_walked(self, running: List[Request], n_queries: int,
                            name: str = "serve.paged.rows_walked") -> None:

@@ -548,12 +548,13 @@ def _latent_attend(cfg, p, h, pool, addr, s):
 
 
 def grouped_info(spec, cfg, s, q_len: int, cache_dtype, window: int = 0,
-                 ringed: bool = False) -> dict:
-    """`paged_info` over rows of `kv_heads` heads, and what the layer's
-    rows are beside one causal run of the table: a `window` (0: none),
-    a `ring`."""
+                 ringed: bool = False, batch: int = 1) -> dict:
+    """`paged_info` over rows of `kv_heads` heads, what the layer's
+    rows are beside one causal run of the table — a `window` (0: none),
+    a `ring` — and how many sequences the call's queries belong to."""
     return dict(paged_info(cfg, s, q_len, cache_dtype),
-                kv_heads=spec.kv_heads, window=window, ring=ringed)
+                kv_heads=spec.kv_heads, window=window, ring=ringed,
+                batch=batch)
 
 
 def grouped_attention_reference(q, ck, cv, tables, q_pos, *, kv_heads: int,
@@ -585,9 +586,11 @@ def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
     rows written — into the request's ring where the layer has a window
     and the cache two groups, through the table else; softmax over the
     rows the layer lets a query see (through the kernel registry: where
-    the layer attends its whole table causally, the walk of each slot's
-    live blocks on the chip at `q_len` <= 8; the gather of every table
-    entry elsewhere, in prefill, under a window and over a ring); output
+    the layer attends its whole table causally, on the chip, the walk of
+    each slot's live blocks in a decode or verify step and of the one
+    request's in a prefill chunk of heads of whole 128-lane tiles,
+    kernels/paged.py; the gather of every table entry elsewhere, under a
+    window, over a ring and in a chunk of narrower heads); output
     projection.  -> float32."""
     B, T, _ = h.shape
     KV, Dh = spec.kv_heads, cfg.head_dim
@@ -603,7 +606,7 @@ def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
 
     out = registry.dispatch(
         "grouped_attention", q, ck, cv, tables, addr.q_pos,
-        info=grouped_info(spec, cfg, s, T, ck.dtype, window, ringed),
+        info=grouped_info(spec, cfg, s, T, ck.dtype, window, ringed, B),
         kv_heads=KV, block_size=s.block_size,
         scale=spec.attn_scale or None, window=window,
         newest=addr.ring_newest if ringed else None)

@@ -79,6 +79,8 @@ def test_kernels_phase_toy():
                                    eva_positions=(0, 31, 32, 100, -1),
                                    grouped_shapes=((6, 8, 2, 64, 4, 1 / 64),
                                                    (3, 4, 2, 16, 3, None)),
+                                   prefill_shape=(4, 2, 128, 6, 16),
+                                   prefill_starts=(0, 21, 88),
                                    latent_shape=(3, 4, 32, 16, 3),
                                    routed_shape=(16, 16, 128, 256),
                                    routed_live=2, ssm_shape=(6, 4, 8, 16),
@@ -91,6 +93,9 @@ def test_kernels_phase_toy():
         "paged_attention_dense_H2_Dh128", "eva_attention_bf16_H2_Dh64",
         "grouped_attention_bf16_H8_KV2_Dh64",
         "grouped_attention_bf16_H4_KV2_Dh16",
+        "grouped_prefill_bf16_H4_KV2_Dh128_at0",
+        "grouped_prefill_bf16_H4_KV2_Dh128_at21",
+        "grouped_prefill_bf16_H4_KV2_Dh128_at88",
         "latent_attention_bf16_H4_W48",
         "touched_experts_bf16_T16_E16", "ssm_step_B6_H4_P8_N16_live3"]
 

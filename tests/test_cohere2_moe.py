@@ -681,6 +681,42 @@ def test_the_registry_is_asked_for_each_kind_of_layer(native):
                                                  jnp.float32, *kind))
 
 
+@pytest.mark.parametrize("way,head_dim", [("oracle", 128), ("kernel", 128),
+                                          ("kernel", DH)])
+def test_prefill_rows_walked_is_what_each_kind_of_layer_fetches(
+        way, head_dim, chip_rule):
+    """`serve.attn.prefill_rows_walked`: a full layer fetches, for a
+    chunk, the request's rows up to the chunk's last position rounded up
+    to a block where its prefill call is the walk — on the chip, at
+    heads of whole lane tiles — and the table's whole width where it is
+    the gather; a sliding layer always gathers its ring.  The kernel
+    serves the oracle's tokens."""
+    import contextlib
+
+    model, params = _model(head_dim=head_dim)
+    serve = _serve()
+    lengths = (8, 2 * WINDOW + 1, CHUNK + 3)
+    prompts = [_prompt(n, i) for i, n in enumerate(lengths)]
+    with chip_rule("grouped_attention") if way == "kernel" \
+            else contextlib.nullcontext():
+        eng = ServeEngine(model, params, serve)
+        before = COUNTERS.snapshot()
+        out = eng.generate(prompts, 2)
+    d = COUNTERS.delta_since(before)
+    walks = way == "kernel" and head_dim == 128
+    assert eng._prefill_walks == walks and not eng._sliding_prefill_walks
+    # a chunk's last position is its padded tail's
+    ends = [start + CHUNK for n in lengths for start in range(0, n, CHUNK)]
+    table = eng.kv.table_width * BS
+    full = sum(min(-(-e // BS) * BS, table) for e in ends) if walks \
+        else len(ends) * table
+    assert d["serve.attn.prefill_rows_walked"] == {
+        "calls": len(ends), "bytes": 2 * full + 6 * len(ends) * RING}
+    assert d["serve.prefill_chunks"]["calls"] == len(ends)
+    if walks:
+        assert out == ServeEngine(model, params, serve).generate(prompts, 2)
+
+
 @pytest.mark.parametrize("way,ringed", [("oracle", True), ("kernel", True),
                                         ("kernel", False)])
 def test_rows_walked_is_what_each_kind_of_layer_fetches(way, ringed,

@@ -323,7 +323,8 @@ _GRANITE_INFO = dict(block_size=16, table_width=128, q_len=1, num_heads=32,
     (dict(q_len=4), None),
     # Command A+'s full layer: 128 score rows of 1,024 lanes
     (dict(num_heads=128, head_dim=128, table_width=1024), None),
-    (dict(q_len=512), "q_len 512 is a prefill chunk"),
+    # a prefill chunk of Granite's heads: half a lane tile each
+    (dict(q_len=512), "8 K/V heads of 64 values.*whole 128-lane tiles"),
     (dict(window=4096), "a window of 4096 rows.*ROADMAP D11"),
     (dict(window=4096, ring=True), "the rows are a ring.*ROADMAP D11"),
     (dict(block_size=8), "a block of 8 rows is not whole tiles"),
@@ -348,6 +349,131 @@ def test_grouped_attention_shape_rule(change, why, native):
 
 def test_grouped_attention_is_the_oracle_off_the_chip():
     assert resolve_impl("grouped_attention", info=_GRANITE_INFO) == "jnp"
+
+
+# -- a prefill chunk over grouped rows: the one request's live blocks ---------
+
+
+def _prefill_both(pos0, T=16, H=4, KV=2, Dh=128, bs=8, W=8, seed=0,
+                  nan_behind=False):
+    """(kernel, oracle) [T, H * Dh] of one request's chunk of T queries
+    at positions pos0 upward through a table of W entries.
+    `nan_behind`: every block the run does not reach — the table's
+    entries behind it and the blocks no entry names — holds NaN, which
+    the oracle's gather, reading zeros there, weighs by exactly 0."""
+    from deepspeed_tpu.serving.kv_cache import pool_rows
+    from deepspeed_tpu.serving.layers import grouped_attention_reference
+
+    rng = np.random.RandomState(seed)
+    nblocks = W + 4
+    table = rng.permutation(np.arange(1, nblocks))[:W].astype(np.int32)
+    q_pos = pos0 + np.arange(T)
+    live = -(-min(int(q_pos[-1]) + 1, W * bs) // bs)
+    pools, clean = [], []
+    for _ in range(2):
+        c = rng.randn(nblocks, bs, KV, Dh).astype(np.float32)
+        dead = np.setdiff1d(np.arange(nblocks), table[:live])
+        clean.append(np.where(np.isin(np.arange(nblocks), dead)[
+            :, None, None, None], 0.0, c))
+        if nan_behind:
+            c[dead] = np.nan
+        pools.append(c)
+    as_pool = lambda c: pool_rows(jnp.asarray(c).reshape(-1, KV, Dh))
+    q = jnp.asarray(rng.randn(1, T, H, Dh), jnp.float32)
+    tables, q_pos = jnp.asarray(table)[None], jnp.asarray(q_pos,
+                                                          jnp.int32)[None]
+    args = dict(kv_heads=KV, block_size=bs, scale=None)
+    with kernel_config(interpret=True):
+        out = registry.dispatch(
+            "grouped_attention", q, *map(as_pool, pools), tables, q_pos,
+            impl="pallas", **args)
+    ref = grouped_attention_reference(
+        q, *map(as_pool, clean if nan_behind else pools), tables, q_pos,
+        **args)
+    assert out.shape == ref.shape == (1, T, H * Dh)
+    assert out.dtype == ref.dtype == jnp.float32
+    return np.asarray(out)[0], np.asarray(ref)[0]
+
+
+@pytest.mark.parametrize("pos0,valid,shape", [
+    (0, 16, {}),                       # a first chunk, position 0 upward
+    (16, 16, {}),
+    (21, 16, {}),                      # the run ends inside a block
+    # a last chunk's padded tail: positions past `n_valid`, the last
+    # three past the table (64 rows): the valid rows agree
+    (51, 5, {}),
+    (56, 8, dict(nan_behind=True)),
+    # the run's end and everything behind it is never fetched
+    (21, 16, dict(nan_behind=True)),
+    # several tiles of the walk, whole ones unmasked, two tiles of query
+    # positions a program each; Command A+'s 16 query heads a K/V head
+    (600, 32, dict(T=32, W=80, nan_behind=True)),
+    (1040, 16, dict(T=64, W=160, H=2, KV=1, nan_behind=True)),
+    (40, 16, dict(H=32, KV=2, W=16, nan_behind=True)),
+], ids=_paged_case_id)
+def test_grouped_prefill_walk_parity(pos0, valid, shape):
+    """The prefill walk against `grouped_attention_reference`: a chunk's
+    queries read the request's blocks from the table's first entry to
+    the one the chunk's last position needs, and nothing behind it."""
+    out, ref = _prefill_both(pos0, **shape)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[:valid], ref[:valid], atol=2e-6)
+
+
+def test_grouped_prefill_walk_refuses_what_it_cannot_tile():
+    """Forced onto several sequences, or heads of half a lane tile, the
+    kernel says so and computes nothing."""
+    for kw in (dict(R=2, T=16, H=4, KV=2, Dh=128), dict(R=1, T=16, **{
+            k: v for k, v in _GRANITE.items() if k != "scale"})):
+        q, ck, cv, tables, q_pos, bs = _paged_inputs("dense", **kw)
+        with kernel_config(interpret=True), \
+                pytest.raises(ValueError, match="a prefill chunk's walk"):
+            registry.dispatch("grouped_attention", q, ck, cv, tables, q_pos,
+                              impl="pallas", kv_heads=kw["KV"], block_size=bs)
+
+
+# Command A+'s full layer's prefill call in its cell: one request's chunk
+# of 512 queries of 128 heads on 8 K/V heads of 128, a table of 1,024
+_COMMAND_A_CHUNK = dict(_GRANITE_INFO, q_len=512, num_heads=128,
+                        head_dim=128, table_width=1024, batch=1)
+
+
+@pytest.mark.parametrize("change,why", [
+    ({}, None),
+    (dict(q_len=1024), None),
+    (dict(kv_itemsize=4, block_size=8), None),
+    (dict(window=4096, ring=True), "the rows are a ring.*ROADMAP D11"),
+    (dict(window=4096), "a window of 4096 rows.*ROADMAP D11"),
+    (dict(head_dim=64), "8 K/V heads of 64 values.*whole 128-lane tiles"),
+    (dict(batch=2), "2 sequences of 512 queries.*one request's table"),
+    (dict(kv_mode="int8"), "int8 rows"),
+    (dict(block_size=8), "a block of 8 rows is not whole tiles"),
+    (dict(q_len=500), "no tile of whole sublanes.*divides the 500"),
+    (dict(num_heads=1024, kv_heads=64), "fits the kernel's VMEM"),
+], ids=lambda v: _paged_case_id(v) if isinstance(v, dict) else "")
+def test_grouped_prefill_shape_rule(change, why, native):
+    """A full layer's prefill call takes the walk on the chip where what
+    the call site sees allows it — one request, no ring, no window,
+    dense rows in whole tiles, heads of whole lane tiles, tiles that fit
+    VMEM — and everything else keeps the gather, with the reason."""
+    info = dict(_COMMAND_A_CHUNK, **change)
+    if why is None:
+        assert resolve_impl("grouped_attention", info=info) == "pallas"
+        return
+    assert resolve_impl("grouped_attention", info=info) == "jnp"
+    with pytest.raises(RuntimeError, match=why):
+        resolve_impl("grouped_attention", impl="pallas", info=info)
+
+
+@pytest.mark.parametrize("op", ["paged_attention", "latent_attention",
+                                "eva_attention"])
+def test_other_walks_keep_prefill_on_the_oracle(op, native):
+    """Only grouped rows have a prefill walk: the other families' chunks
+    are ROADMAP S11's later cases."""
+    info = dict(_COMMAND_A_CHUNK, kv_heads=1, window=2048, chunk=16)
+    assert resolve_impl(op, info=info) == "jnp"
+    with pytest.raises(RuntimeError, match="q_len 512 is a prefill chunk"):
+        resolve_impl(op, impl="pallas", info=info)
 
 
 # -- latent rows: the walk at one K/V head a row, one operand -----------------

@@ -175,7 +175,7 @@ def _grouped(slots=64, heads=32, kv=8, dh=64, bs=16, width=128,
     info = {"block_size": bs, "table_width": width, "q_len": q_len,
             "num_heads": heads, "head_dim": dh, "kv_mode": "dense",
             "kv_itemsize": jnp.dtype(dtype).itemsize, "kv_heads": kv,
-            "window": window, "ring": ring}
+            "window": window, "ring": ring, "batch": slots}
     if ring:
         shapes += (_sds((slots,), jnp.int32),)
 
@@ -401,9 +401,20 @@ CASES = [
          op="grouped_attention"),
     Case("grouped_H32_KV8_Dh64_verify4", lambda: _grouped(q_len=4),
          op="grouped_attention"),
+    # a prefill chunk: Command A+'s full layer takes the walk of the one
+    # request's live blocks, Granite's heads of half a lane tile and a
+    # chunk over two sequences keep the gather
+    Case("grouped_H128_KV8_Dh128_mixedlen_prefill512",
+         lambda: _command_a_full(slots=1, q_len=512),
+         op="grouped_attention"),
     Case("grouped_H32_KV8_Dh64_prefill512",
          lambda: _grouped(slots=1, q_len=512),
-         op="grouped_attention", refused=r"q_len 512 is a prefill chunk"),
+         op="grouped_attention",
+         refused=r"8 K/V heads of 64 values.*whole 128-lane tiles"),
+    Case("grouped_H128_KV8_Dh128_prefill512_two_sequences",
+         lambda: _command_a_full(slots=2, q_len=512),
+         op="grouped_attention",
+         refused=r"2 sequences of 512 queries.*one request's table"),
     Case("grouped_H128_KV8_Dh128_window_on_the_table",
          lambda: _command_a_full(window=4096),
          op="grouped_attention",
@@ -534,6 +545,14 @@ def _serve_attention(q_len, slots):
     ("command-a-plus-d4.serve.mixedlen.decode.sliding",
      lambda: ("grouped_attention",
               _command_a_full(window=4096, ring=True, width=288)[2]), "jnp"),
+    # one request's chunk of 512: the full layer walks, the rings gather
+    ("command-a-plus-d4.serve.mixedlen.prefill.full",
+     lambda: ("grouped_attention",
+              _command_a_full(slots=1, q_len=512)[2]), "pallas"),
+    ("command-a-plus-d4.serve.mixedlen.prefill.sliding",
+     lambda: ("grouped_attention",
+              _command_a_full(slots=1, q_len=512, window=4096, ring=True,
+                              width=288)[2]), "jnp"),
     # 32 slots, 8,193 blocks of 16 latent rows of 576 values, a table of
     # 256 entries, bf16
     ("deepseek-v2-lite-d9.serve.chatgen.decode",
@@ -892,12 +911,14 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     16 rows for the full layer and 16 x 288 + 1 for each sliding one, a
     table of 1,024 + 288 entries, chunk 512), as the chip traces them:
     the registry answers the walk for the full layer's decode call and
-    the gather for the three rings and for prefill, so `decode`'s custom
-    calls are the 4 layers' `touched_experts` kernels and the full
+    its prefill call and the gather for the three rings, so `decode`'s
+    custom calls are the 4 layers' `touched_experts` kernels and the full
     layer's one walk — no slot's whole table of 16,384 rows is laid out
-    as `[16, 16384, 8, 128]` — and the only ones in `prefill` are XLA's
-    own grouped products
-    (`lax.ragged_dot` over the 16 held experts); every pool enters as
+    as `[16, 16384, 8, 128]` — and `prefill`'s are XLA's own grouped
+    products (`lax.ragged_dot` over the 16 held experts) and the full
+    layer's one prefill walk — the request's whole table is not gathered
+    (`[1, 16384, 8, 128]`) nor scored (`[1, 1, 16, 512, 16384]`, a K/V
+    head's queries against 16,384 rows); every pool enters as
     `[rows, 1024]`, a K and a V a layer; and weights, pools and
     temporaries fit the chip's 15.75 GB with the room the check's 2.15 GB
     of reference logits needs."""
@@ -917,8 +938,8 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     ask = lambda q_len, *kind: registry.resolve_impl(
         "grouped_attention", info=grouped_info(
             spec, model.config, sched, q_len, jnp.bfloat16, *kind))
-    assert (ask(1), ask(1, 4096, True), ask(chunk)) == ("pallas", "jnp",
-                                                        "jnp")
+    assert (ask(1), ask(1, 4096, True)) == ("pallas", "jnp")
+    assert (ask(chunk), ask(chunk, 4096, True)) == ("pallas", "jnp")
     progs = ServeProgramBuilder(model, sched).build()
 
     def on(shape, dtype):
@@ -953,7 +974,12 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
         assert sum("paged_attention_walk" in ln for ln in calls) == 1
         assert (slots, 16384, 8, 128) not in _hlo_by_shape(text)
     else:
-        assert calls and all("ragged" in ln for ln in calls)
+        walks = [ln for ln in calls if "paged_attention_prefill_walk" in ln]
+        assert len(walks) == 1
+        assert all("ragged" in ln for ln in calls if ln not in walks)
+        wide = {(1, 16384, 8, 128), (8, 1, 16384, 128), (16, 512, 16384),
+                (1, 1, 16, 512, 16384)} & set(_hlo_by_shape(text))
+        assert not wide, wide
     for rows in (nblocks * bs, (slots * ring + 1) * bs):
         layouts = {layout for _, layout in _hlo_by_shape(text)[(rows, 1024)]}
         assert layouts == {"1,0"}  # row-major
