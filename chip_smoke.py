@@ -445,12 +445,14 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                   eva_summary_blocks=64,
                   eva_positions=(0, 100, 2047, 2048, 9000, 12345, 16383, -1),
                   grouped_shapes=((64, 32, 8, 64, 128, 1 / 64),
-                                  (16, 128, 8, 128, 1024, None)),
+                                  (16, 128, 8, 128, 1024, None),
+                                  (16, 16, 2, 256, 832, None)),
                   prefill_shape=(128, 8, 128, 1024, 512),
                   prefill_starts=(0, 3003, 16084),
                   latent_shape=(32, 16, 512, 64, 256),
                   routed_shape=(32, 64, 2048, 1408), routed_live=12,
                   ssm_shape=(64, 64, 64, 128), ssm_live=37,
+                  gdn_shape=(48, 32, 128), gdn_live=29,
                   on_chip=True) -> dict:
     """Each kernel `auto` selects on this chip, once, natively, at the
     main path's shapes, against its jnp oracle at tier-1's tolerance
@@ -826,8 +828,55 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                                np.array_equal(np.asarray(got[1])[rest],
                                               np.asarray(state)[rest])):
         raise RuntimeError("the recurrence touched a slot that does not run")
+
+    # the gated delta rule of a decode step at the longchat cell's shape:
+    # 48 slots of which 29 run, scattered; the others' state comes back
+    # bit for bit and their output is zeros
+    from deepspeed_tpu.kernels.gdn import gdn_step_info
+    from deepspeed_tpu.models.qwen3_next import delta_step
+
+    B, H, d = gdn_shape
+    runs = jnp.zeros((B,), bool).at[
+        jax.random.permutation(key[6], B)[:gdn_live]].set(True)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    gq, gk, gv = (jax.random.normal(k, (B, H, d), jnp.float32)
+                  for k in key[:3])
+    gq, gk = unit(gq) * d ** -0.5, unit(gk)
+    g = -jax.random.uniform(key[3], (B, H), maxval=1.0) * runs[:, None]
+    beta = jax.random.uniform(key[4], (B, H)) * runs[:, None]
+    state = jax.random.normal(key[5], (B, H, d, d), jnp.float32)
+    info = gdn_step_info(state)
+    chosen = registry.resolve_impl("gdn_step", info=info)
+    if on_chip and chosen != "pallas":
+        raise RuntimeError(f"auto resolved the delta rule over a state of "
+                           f"{gdn_shape} to {chosen!r} on this chip")
+    got = jax.jit(lambda *a: registry.dispatch("gdn_step", *a, info=info))(
+        gq, gk, gv, g, beta, state, *live_slots(runs))
+    want = jax.jit(delta_step)(gq, gk, gv, g, beta, state)
+    out.append(_close(f"gdn_step_B{B}_H{H}_D{d}_live{gdn_live}",
+                      list(gdn_shape), jax.tree_util.tree_map(
+                          lambda a: a[runs], got),
+                      jax.tree_util.tree_map(lambda a: a[runs], want),
+                      rtol=1e-5, atol=1e-4))
+    rest = ~np.asarray(runs)
+    if chosen == "pallas" and (np.asarray(got[0])[rest].any() or not
+                               np.array_equal(np.asarray(got[1])[rest],
+                                              np.asarray(state)[rest])):
+        raise RuntimeError("the delta rule touched a slot that does not run")
+
+    # not a failure but an answer: does this chip's compiler keep
+    # `_own_lanes`' two slices right (16 rows of 2 K/V heads of 256)?
+    # Once it reads true here, `_own_lanes_of_two` may go
+    from deepspeed_tpu.kernels import paged
+
+    raw = jax.random.normal(key[0], (8, 16, 512), jnp.float32)
+    slices, masked = (np.asarray(jax.jit(
+        lambda o, keep=keep: keep(o, 1, 16, 8, 256))(raw))
+        for keep in (paged._own_lanes, paged._own_lanes_of_two))
     return {"phase": "kernels", "native": not pallas_backend.interpret(),
-            "kernels": out}
+            "kernels": out,
+            "own_lanes_two_slices_right": bool(
+                np.array_equal(slices, masked))}
 
 
 # ---------------------------------------------------------------------------

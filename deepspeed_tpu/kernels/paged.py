@@ -763,5 +763,31 @@ def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret,
     )(tables.astype(jnp.int32), q_pos.astype(jnp.int32),
       _block_diagonal(q, Hp, width, G), *operands)
     if G > 1:
-        return _own_lanes(out, T, H, G, Dh)
+        keep = _own_lanes_of_two if H // G == 2 else _own_lanes
+        return keep(out, T, H, G, Dh)
     return out[..., :HD].reshape(B, T, H, Dh)
+
+
+def _own_lanes_of_two(out, T: int, H: int, G: int, Dh: int):
+    """`_own_lanes` for rows of TWO K/V heads, under a mask and a sum over
+    the heads: the chip's compiler gets the concatenation of exactly two
+    such slices wrong — rows G .. 2 G come back as neither head's
+    (PERF.md section 6, PR 57: `bench_artifacts/pr57/walk_probe2.py`, the
+    same expression on a plain array; one, four and eight heads are
+    right and keep the slices).  What fails, under `jax.jit` on a TPU
+    v5e (jax 0.9.0, libtpu 0.0.34), for float32 `rows` [B, 1, 16, 512]
+    with G = 8 and Dh = 256, by 3.2 where the values' deviation is 0.22:
+
+        jnp.concatenate([rows[:, :, 0:G, 0:Dh],
+                         rows[:, :, G:2 * G, Dh:2 * Dh]], axis=2)
+
+    the CPU and the interpreter are right.  Delete this function, and
+    the fork in `_walk`, when `chip_smoke.py`'s kernels phase reads
+    `own_lanes_two_slices_right: true` on the chip.  It stands behind
+    `_walk` so that no line above the walk's call moves: a Mosaic
+    kernel's cache entry is keyed on its source locations too."""
+    B, C, width = out.shape
+    rows = out.reshape(B, T, C // T, width)[:, :, :H, :2 * Dh]
+    own = (jnp.arange(H)[:, None] // G) == jnp.arange(2)[None, :]
+    return jnp.sum(jnp.where(own[None, None, :, :, None],
+                             rows.reshape(B, T, H, 2, Dh), 0.0), axis=3)

@@ -20,7 +20,8 @@ Every hot inner loop that has a Pallas implementation registers a
 
     call site (ops/transformer/attention.py, serving/layers.py,
                runtime/comm/quant.py, moe/dispatch.py, moe/dropless.py,
-               models/granite_hybrid.py, ops/sparse_attention/)
+               models/granite_hybrid.py, models/qwen3_next.py,
+               ops/sparse_attention/)
        └─> dispatch(op, *args, info=<shape facts of this call>)
               └─> pallas  iff  TPU backend  and  op.auto_supports(info)
                                and  partitionable here
@@ -639,12 +640,52 @@ class SsmStepOp(KernelOp):
         return ssm_step(x, Bm, Cm, dt, A, state)
 
 
+class GdnStepOp(KernelOp):
+    """The gated delta rule of a decode step (models/qwen3_next.py
+    `gdn_mix`, one token a slot).  Pallas = kernels/ssm.py's walk of the
+    step's live slots over this recurrence: only their state is read and
+    written, in place (kernels/gdn.py); oracle = `delta_step`, every
+    slot's state through the same expression, a slot that is not live
+    under g = 0 and beta = 0.  The shape rule looks at the state's four
+    sizes (`kernels/gdn.py::gdn_step_info`)."""
+
+    NAME = "gdn_step"
+
+    def auto_supports(self, variant, info):
+        if not info:
+            return True, ""
+        from .ssm import head_tile
+
+        dk, dv, item = (int(info[k]) for k in ("key_dim", "value_dim",
+                                               "itemsize"))
+        if item != 4 or dk % 128 or dv != dk:
+            return False, (f"a head's state of {dk} x {dv} values of {item} "
+                           f"bytes is not square whole 128-lane tiles of "
+                           f"float32")
+        th = head_tile(int(info["heads"]), dk, dv)
+        if not th or th % 8:
+            return False, (f"the {th} heads of {dk} x {dv} float32 a block "
+                           f"that fit the kernel's VMEM, read and written "
+                           f"and double-buffered, are not whole tiles of 8 "
+                           f"rows")
+        return True, ""
+
+    def pallas(self, variant, *args, **kwargs):
+        from . import gdn
+        return gdn.gdn_step_pallas(*args, **kwargs)
+
+    def oracle(self, variant, q, k, v, g, beta, state, ids, n):
+        from ..models.qwen3_next import delta_step
+        return delta_step(q, k, v, g, beta, state)
+
+
 KERNEL_OPS: Dict[str, KernelOp] = {
     op.NAME: op for op in (FlashAttentionOp(), SparseAttentionOp(),
                            PagedAttentionOp(), GroupedAttentionOp(),
                            LatentAttentionOp(), EvaAttentionOp(),
                            QuantCodecOp(), MoEDispatchOp(),
-                           TouchedExpertsOp(), SsmStepOp())
+                           TouchedExpertsOp(), SsmStepOp(),
+                           GdnStepOp())
 }
 
 

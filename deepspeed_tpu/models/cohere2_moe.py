@@ -206,6 +206,8 @@ def routed_ffn(spec, cfg, p, flat, live=None):
                            total=cfg.num_experts, held=held, live=live)
     with jax.named_scope("moe_shared"):
         shared = silu_gated_ffn(p["shared"], flat)
+        if spec.shared == "gated":
+            shared = shared * jax.nn.sigmoid(matmul32(flat, p["shared_gate"]))
         y = y + (shared / cfg.num_shared if spec.shared == "average"
                  else shared)
     return y, idx, count, held

@@ -23,8 +23,8 @@ FFNS = ("gelu_mlp", "silu_gated", "routed_experts")
 HEADS = ("tied", "untied")
 RESIDUALS = ("sequential", "parallel")
 SCORINGS = ("softmax", "sigmoid")
-SHARED = ("sum", "average")
-MIXERS = ("attention", "ssm")
+SHARED = ("sum", "average", "gated")
+MIXERS = ("attention", "ssm", "gdn")
 
 
 class LayerSpec(NamedTuple):
@@ -32,7 +32,8 @@ class LayerSpec(NamedTuple):
     "grouped" attention, a pattern of layers repeated: `layer_windows`
     and `layer_positions` give layer l its window and its positions at
     index l mod the pattern's length, and `layer_mixers` says which of
-    them mix tokens by attention and which by a state-space recurrence.
+    them mix tokens by attention and which by a recurrence over a state
+    a request keeps (state-space, or the gated delta rule).
     The first `dense_layers` layers may keep a plain gated FFN where the
     others route.
 
@@ -44,7 +45,9 @@ class LayerSpec(NamedTuple):
                attention, over the rotary part of it, with the model's
                YaRN frequencies) | "per_layer" (`layer_positions` says
                of each layer "rope" — over the whole head, interleaved
-               pairing — or "none": no positions at all)
+               pairing, or, with `rope_halves`, dims i and i + half
+               of the first `rotary_dim` values of it — or "none": no
+               positions at all)
     attention  "paged": causal softmax over every cached position; the
                cache holds one exact K/V row a token for the request's
                whole life.  "eva": exact rows for the open window of
@@ -63,7 +66,16 @@ class LayerSpec(NamedTuple):
                only (the query's own among them) and its rows may live
                in a ring of `window + prefill_chunk` rows a request
                (serving/kv_cache.py's group "window"); 0 is every
-               cached position (models/cohere2_moe.py).
+               cached position (models/cohere2_moe.py).  Three
+               additions, each off unless the spec says so
+               (models/qwen3_next.py): `attn_gate` — the query
+               projection is twice as wide, [q | gate] a head, and the
+               attended values are multiplied by sigmoid(gate) before
+               the output projection; `qk_norm` — q and k are
+               RMS-normed over the head (the gain 1 + g, `q_norm` and
+               `k_norm`) before they are rotated; `rotary_dim` — a
+               rotating layer turns the first `rotary_dim` values of a
+               head only (0: the whole head).
     indexers   `layer_indexers` (latent attention only; empty: every
                cached row is attended) says of layer l, at index l mod
                its length, "full" — the layer computes a learned
@@ -88,6 +100,17 @@ class LayerSpec(NamedTuple):
                `ssm_conv - 1` inputs — a prefill chunk scans from the
                state and leaves it behind, `ssm_chunk` positions at a
                time; a decode step moves it on by one token.
+               "gdn": a gated delta-rule mixer (models/qwen3_next.py)
+               of `gdn_value_heads` heads of `gdn_value_dim` values on
+               `gdn_key_heads` keys and queries of `gdn_key_dim` (value
+               head j on key head j // (value heads / key heads)),
+               behind a causal depthwise convolution of `gdn_conv` taps
+               over [q | k | v].  It keeps, a request, one float32 state
+               [gdn_value_heads, gdn_key_dim, gdn_value_dim] and the
+               convolution's last `gdn_conv - 1` inputs; a prefill chunk
+               takes `gdn_chunk` positions at a time.  A pattern has
+               state layers of one kind (`state_shapes` says what ONE
+               slot keeps for a layer of it).
     ffn        "gelu_mlp" (fc1, tanh GELU, fc2, biases) | "silu_gated"
                | "routed_experts" (a float32 router — `scoring`
                "softmax" over all experts or "sigmoid" of each — the top
@@ -99,8 +122,10 @@ class LayerSpec(NamedTuple):
                the `experts_held` experts from
                `first_expert` on that this chip holds (0: all); plus
                shared experts, their outputs summed or, with `shared`
-               "average", their mean; the first `dense_layers` layers
-               are "silu_gated")
+               "average", their mean, or, with "gated", times the
+               sigmoid of the token's product with `shared_gate`
+               [D, 1]; the first `dense_layers` layers are
+               "silu_gated")
     head       "tied" (wte transposed) | "untied" (`lm_head`)
     residual   "sequential" (x + attn(norm1 x), then + ffn(norm2 of
                that)) | "parallel" (one norm: x + attn(h) + ffn(h))
@@ -138,7 +163,7 @@ class LayerSpec(NamedTuple):
     shared: str = "sum"          # routed_experts: the shared experts
     experts_held: int = 0        # routed_experts: experts held here (0: all)
     first_expert: int = 0        # routed_experts: the first one held
-    layer_mixers: tuple = ()     # the pattern's "attention" | "ssm"
+    layer_mixers: tuple = ()     # the pattern's "attention" | "ssm" | "gdn"
     ssm_heads: int = 0           # ssm: heads of the recurrence
     ssm_head_dim: int = 0        # ssm: values a head
     ssm_state: int = 0           # ssm: state values (B and C's width)
@@ -154,6 +179,16 @@ class LayerSpec(NamedTuple):
     index_width: int = 0         # indexers: values of a cached index key
     select_bias: bool = False    # routed_experts: a bias chooses the top k
     route_scale: float = 1.0     # routed_experts: times the weights
+    gdn_key_heads: int = 0       # gdn: heads of keys and queries
+    gdn_value_heads: int = 0     # gdn: heads of values (and of the state)
+    gdn_key_dim: int = 0         # gdn: values of a key
+    gdn_value_dim: int = 0       # gdn: values of a value
+    gdn_conv: int = 0            # gdn: taps of the causal convolution
+    gdn_chunk: int = 0           # gdn: positions the scan takes at once
+    attn_gate: bool = False      # grouped: [q | gate] a head, sigmoid gate
+    qk_norm: bool = False        # grouped: q and k RMS-normed over the head
+    rotary_dim: int = 0          # grouped: values of a head that rotate (0: all)
+    rope_halves: bool = False    # grouped: pairs i, i + half (not 2i, 2i + 1)
 
     def window_of(self, layer: int) -> int:
         """The window of layer `layer` (0: every cached position)."""
@@ -167,7 +202,7 @@ class LayerSpec(NamedTuple):
             layer % len(self.layer_positions)] == "rope"
 
     def mixer_of(self, layer: int) -> str:
-        """"attention" or "ssm": how layer `layer` mixes tokens."""
+        """"attention", "ssm" or "gdn": how layer `layer` mixes tokens."""
         if not self.layer_mixers:
             return "attention"
         return self.layer_mixers[layer % len(self.layer_mixers)]
@@ -189,13 +224,39 @@ class LayerSpec(NamedTuple):
     def has_state(self) -> bool:
         """Whether some layer keeps a state a request beside (or in
         place of) cache rows."""
-        return "ssm" in self.layer_mixers
+        return any(m != "attention" for m in self.layer_mixers)
 
     def state_layers(self, num_layers: int) -> tuple:
         """The layers of `num_layers` that keep a state a request and
         no cache rows."""
         return tuple(i for i in range(num_layers)
-                     if self.mixer_of(i) == "ssm")
+                     if self.mixer_of(i) != "attention")
+
+    @property
+    def state_shapes(self) -> tuple:
+        """What ONE slot keeps for one layer with a state: ((shape,
+        dtype or None: the cache's), ...) — the float32 state and the
+        convolution's last inputs; () where no layer has one."""
+        if "ssm" in self.layer_mixers:
+            return (((self.ssm_heads, self.ssm_head_dim, self.ssm_state),
+                     "float32"),
+                    ((self.ssm_conv - 1, self.ssm_conv_width), None))
+        if "gdn" in self.layer_mixers:
+            return (((self.gdn_value_heads, self.gdn_key_dim,
+                      self.gdn_value_dim), "float32"),
+                    ((self.gdn_conv - 1, self.gdn_conv_width), None))
+        return ()
+
+    @property
+    def state_chunk(self) -> int:
+        """Positions the state layers' scan takes at once (0: none)."""
+        return self.ssm_chunk or self.gdn_chunk
+
+    @property
+    def gdn_conv_width(self) -> int:
+        """Channels of the delta mixer's convolution: [q | k | v]."""
+        return 2 * self.gdn_key_heads * self.gdn_key_dim + \
+            self.gdn_value_heads * self.gdn_value_dim
 
     @property
     def ssm_conv_width(self) -> int:
@@ -289,11 +350,33 @@ class LayerSpec(NamedTuple):
                 or ("ssm" not in self.layer_mixers and any(sizes)) \
                 or self.ssm_conv == 1:
             raise ValueError(
-                f"layer spec: layer_mixers says \"attention\" or \"ssm\" "
-                f"of each layer of the pattern, and a pattern with ssm "
-                f"layers, and nothing else, names ssm_heads, ssm_head_dim, "
-                f"ssm_state, ssm_conv >= 2 and ssm_chunk (got "
+                f"layer spec: layer_mixers says \"attention\", \"ssm\" or "
+                f"\"gdn\" of each layer of the pattern, and a pattern with "
+                f"ssm layers, and nothing else, names ssm_heads, "
+                f"ssm_head_dim, ssm_state, ssm_conv >= 2 and ssm_chunk (got "
                 f"{self.layer_mixers}, {sizes})")
+        sizes = (self.gdn_key_heads, self.gdn_value_heads, self.gdn_key_dim,
+                 self.gdn_value_dim, self.gdn_conv, self.gdn_chunk)
+        if ("gdn" in self.layer_mixers) != all(n > 0 for n in sizes) \
+                or ("gdn" not in self.layer_mixers and any(sizes)) \
+                or self.gdn_conv == 1 or (
+                    self.gdn_key_heads
+                    and self.gdn_value_heads % self.gdn_key_heads) \
+                or {"ssm", "gdn"} <= set(self.layer_mixers):
+            raise ValueError(
+                f"layer spec: a pattern with gdn layers, and nothing else, "
+                f"names gdn_key_heads, gdn_value_heads (a multiple of "
+                f"them), gdn_key_dim, gdn_value_dim, gdn_conv >= 2 and "
+                f"gdn_chunk, and its state layers are of one kind (got "
+                f"{self.layer_mixers}, {sizes})")
+        if (self.attn_gate or self.qk_norm or self.rotary_dim
+                or self.rope_halves) and self.attention != "grouped" \
+                or self.rotary_dim < 0 or self.rotary_dim % 2:
+            raise ValueError(
+                f"layer spec: attn_gate, qk_norm, an even rotary_dim and "
+                f"rope_halves describe grouped attention (got "
+                f"{self.attn_gate}, {self.qk_norm}, {self.rotary_dim}, "
+                f"{self.rope_halves} with {self.attention!r} attention)")
         if min(self.embed_scale, self.residual_scale,
                self.logit_divisor) <= 0 or self.attn_scale < 0 or (
                 self.attn_scale and self.attention != "grouped"):

@@ -180,7 +180,12 @@ Instrumented sites:
   layer's bytes a slot: what the live slots need); `serve.ssm.
   prefill_tokens` — calls = prefill chunks, bytes = valid tokens
   scanned; `serve.ssm.state_resets` — calls = slots zeroed on the
-  device as a request is seated (serving/kv_cache.py `reset_state`);
+  device as a request is seated (serving/kv_cache.py `reset_state`) —
+  and `serve.gdn.state_bytes`, `serve.gdn.slots_live`, `serve.gdn.
+  prefill_tokens`, `serve.gdn.state_resets`, the same four name for
+  name, where the layers with a state are gated delta-rule mixers
+  (models/qwen3_next.py; the kernel asked about is `gdn_step`), which
+  emit no `serve.ssm.*`;
   `serve.attn.rows_read`, `serve.attn.rows_walked` and
   `serve.attn.prefill_rows_walked` as above over the attention layers
   alone.

@@ -196,8 +196,8 @@ counts among those held, and `serve.moe.assignments` is not emitted
 (only the program knows how many of a call's assignments it held).
 
 A state beside rows (a layer spec some of whose layers mix tokens by a
-state-space recurrence, grouped attention without positions in the
-others): those layers own no cache rows — `num_blocks`, the tables and
+state-space recurrence or by the gated delta rule, grouped attention in
+the others): those layers own no cache rows — `num_blocks`, the tables and
 admission count the attention layers alone — but a float32 state and
 the convolution's last inputs a SLOT, held for all `max_batch` slots
 whatever is seated: the state, not the rows, sizes `max_batch`.  When a
@@ -222,7 +222,10 @@ every slot's convolution inputs twice; where it is the oracle, every
 slot's of both, twice), `serve.ssm.slots_live` (calls
 = decode steps, bytes = running slots x layers with a state),
 `serve.ssm.prefill_tokens` (calls = prefill chunks, bytes = valid
-tokens scanned), `serve.ssm.state_resets` (calls = slots zeroed) and
+tokens scanned), `serve.ssm.state_resets` (calls = slots zeroed) —
+under `serve.gdn.*` in place of `serve.ssm.*`, name for name, where the
+layers with a state are gated delta-rule mixers (`gdn_step` the kernel
+asked about) — and
 `serve.attn.rows_read`, `serve.attn.rows_walked` and
 `serve.attn.prefill_rows_walked` over the attention layers.
 
@@ -552,6 +555,9 @@ class ServeEngine:
         # storage mode is folded in by the cache itself)
         prefix_salt = (f"{cfg.num_layers}|{cfg.num_heads}|{cfg.head_dim}|"
                        f"{cfg.vocab_size}|{cfg.max_seq_len}|{c.quant_mode}")
+        # the state mixers' family of counters: serve.ssm.* | serve.gdn.*
+        gdn = "gdn" in spec.layer_mixers
+        self._state_counters = "serve.gdn" if gdn else "serve.ssm"
         self.kv = PagedKVCache(
             num_layers=cfg.num_layers,
             num_heads=spec.kv_heads or cfg.num_heads,
@@ -567,10 +573,8 @@ class ServeEngine:
             ring_layers=ring_layers, max_requests=c.max_batch,
             index_layers=self._index_layers, index_width=spec.index_width,
             state_layers=self._state_layers,
-            state_shapes=(((spec.ssm_heads, spec.ssm_head_dim,
-                            spec.ssm_state), jnp.float32),
-                          ((spec.ssm_conv - 1, spec.ssm_conv_width), None))
-            if self._state_layers else ())
+            state_shapes=spec.state_shapes,
+            state_counters=self._state_counters)
         # what a decode step reads and writes of it: every slot's — less,
         # where the registry answers that the recurrence walks the live
         # slots (asked once, for the decode program's shapes), the
@@ -580,11 +584,17 @@ class ServeEngine:
         self._state_dead_bytes = 0
         if self._state_layers:
             from ..kernels import registry
-            from ..kernels.ssm import ssm_step_info
 
             states = [self.kv.caches[i][0] for i in self._state_layers]
-            if registry.resolve_impl(
-                    "ssm_step", info=ssm_step_info(states[0])) == "pallas":
+            if gdn:
+                from ..kernels.gdn import gdn_step_info
+
+                op, info = "gdn_step", gdn_step_info(states[0])
+            else:
+                from ..kernels.ssm import ssm_step_info
+
+                op, info = "ssm_step", ssm_step_info(states[0])
+            if registry.resolve_impl(op, info=info) == "pallas":
                 self._state_dead_bytes = 2 * sum(
                     a.nbytes for a in states) // c.max_batch
         self.scheduler = Scheduler(self.kv, c.max_batch,
@@ -1024,7 +1034,8 @@ class ServeEngine:
                     self.kv.reset_state(req.slot)
             # behind the table's entries: where the request's state lies
             table = np.append(table, np.int32(req.slot))
-            COUNTERS.add("serve.ssm.prefill_tokens", nbytes=n_valid)
+            COUNTERS.add(f"{self._state_counters}.prefill_tokens",
+                         nbytes=n_valid)
         tok, _logits, caches = self.programs["prefill"](
             self.params, self.kv.caches, jnp.asarray(tokens),
             np.int32(req.prefill_pos), np.int32(n_valid),
@@ -1138,10 +1149,10 @@ class ServeEngine:
         if self._state_layers:
             # what the program as built streams: every slot's state,
             # running or not, or the running slots' alone
-            COUNTERS.add("serve.ssm.state_bytes",
+            COUNTERS.add(f"{self._state_counters}.state_bytes",
                          nbytes=self._state_step_bytes - self._state_dead_bytes
                          * (self.config.max_batch - len(lanes)))
-            COUNTERS.add("serve.ssm.slots_live",
+            COUNTERS.add(f"{self._state_counters}.slots_live",
                          nbytes=len(lanes) * len(self._state_layers))
         COUNTERS.add("serve.decode_ahead", nbytes=int(
             any(isinstance(u, _Step) for u in self._unread)))

@@ -158,7 +158,9 @@ it as it lies — the loop runs a step ahead, and a step launched for the
 slot's last tenant may still be to run; `reset_state(slot)` zeroes a
 slot's entries on the device, in launch order behind whatever was
 launched before it, and the engine calls it when it seats a request,
-before its first prefill chunk.  The prefix cache, session pins,
+before its first prefill chunk (counted as `<state_counters>.
+state_resets`: the engine names its mixers' family, `serve.ssm` or
+`serve.gdn`).  The prefix cache, session pins,
 quantized rows and a mesh are not offered for such a cache (the engine
 refuses them by name).
 
@@ -296,6 +298,7 @@ class PagedKVCache:
                  ring_layers: Sequence[int] = (), max_requests: int = 0,
                  state_layers: Sequence[int] = (),
                  state_shapes: Sequence[tuple] = (),
+                 state_counters: str = "serve.ssm",
                  index_layers: Sequence[int] = (), index_width: int = 0):
         if num_blocks < 2:
             raise ValueError(
@@ -375,6 +378,8 @@ class PagedKVCache:
         self.state_layers = frozenset(int(i) for i in state_layers)
         self._state_order = tuple(sorted(self.state_layers))
         self.state_shapes = tuple(state_shapes)
+        # the mixers' own family of counters: serve.ssm | serve.gdn
+        self.reset_counter = f"{state_counters}.state_resets"
         self.max_requests = int(max_requests)
         if bool(self.state_layers) != bool(self.state_shapes) or (
                 self.state_layers and self.max_requests < 1):
@@ -546,7 +551,7 @@ class PagedKVCache:
     def reset_state(self, slot: int) -> None:
         """Zero slot `slot`'s entries of every layer with a state, on
         the device and in place: behind every program already launched,
-        before any launched after."""
+        before any launched after; counted under `reset_counter`."""
         if self._reset_fn is None:
             self._reset_fn = jax.jit(
                 lambda states, slot: jax.tree_util.tree_map(
@@ -556,7 +561,7 @@ class PagedKVCache:
                                 np.int32(slot))
         for i, entry in zip(self._state_order, states):
             self.caches[i] = entry
-        COUNTERS.add("serve.ssm.state_resets")
+        COUNTERS.add(self.reset_counter)
 
     # -- allocator ----------------------------------------------------
 

@@ -22,13 +22,21 @@ sequential, both branches scaled; `spec.layer_mixers` says which layer
 has which; below), and latent attention over a learned selection
 of the cached rows (the GLM-5.2 block: `spec.layer_indexers` says which
 layers score and choose — they keep a second row a token, an index key
-— and which take the choice of the layer before them; below).  The norm,
+— and which take the choice of the layer before them; below), and
+positions given layer by layer with grouped attention in some layers
+and a gated delta-rule mixer in the others (the Qwen3-Next block:
+sequential, a routed FFN in every layer; the attention layers gate their
+output, norm q and k and rotate part of a head — `spec.attn_gate`,
+`qk_norm`, `rotary_dim` — and the delta layers keep a matrix state a
+slot as the state-space ones do; models/qwen3_next.py, imported when
+such a block is first built).  The norm,
 the FFN and the head are free of that choice.  A routed-experts FFN,
 behind leading dense layers, is one function in either block and reads
 the spec (models/cohere2_moe.py `routed_ffn`): the router's scoring,
 whether the chosen weights are renormalised, a bias that chooses and a
-factor on the weights, which experts this chip holds, and — in the
-parallel block alone — how the shared experts combine.
+factor on the weights, which experts this chip holds, and how the
+shared experts combine (their mean in the parallel block alone, behind
+a sigmoid gate in the sequential one alone).
 
 The paged pieces mirror models/generation.py `_block_with_cache` op for
 op (fp32 scores, the same einsum strings, NEG_INF masking, probs cast to
@@ -65,11 +73,13 @@ key) where the registry picks the kernel; a ring and a window are
 liveness rules the walk does not have, say so in `grouped_info`, and are
 gathered in `jax.numpy` (`grouped_attention_reference`).
 
-Layers with a state and no rows (`spec.mixer_of(layer) == "ssm"`,
-models/granite_hybrid.py): such a layer's entry of the cache is not rows
+Layers with a state and no rows (`spec.mixer_of(layer)` "ssm",
+models/granite_hybrid.py, or "gdn", models/qwen3_next.py): such a
+layer's entry of the cache is not rows
 of a pool but two arrays BY SLOT — a float32 state `[slots, heads,
-head_dim, state]` and the convolution's last inputs `[slots, taps - 1,
-conv_width]` — and its block reads and writes the entries of the call's
+head_dim, state]` (`[slots, value heads, key_dim, value_dim]` for the
+delta rule) and the convolution's last inputs `[slots, taps - 1,
+conv_width]` — `spec.state_shapes` — and its block reads and writes the entries of the call's
 sequences: a prefill chunk its request's one (`Addr.slot`, which rides
 behind the request's table), a decode step every slot's.  The
 block moves both on by the call's valid positions (`Addr.n_valid`: a
@@ -77,7 +87,8 @@ chunk's valid tokens; 1 for a running slot, 0 for any other, whose
 entries a step therefore hands back as it found them) and no term
 crosses slots.  A decode step also lists its running slots once
 (`Addr.live`, kernels/ssm.py `live_slots`), for every such layer: where
-the registry picks the `ssm_step` kernel the recurrence walks that list
+the registry picks the `ssm_step` (or `gdn_step`) kernel the recurrence
+walks that list
 and a slot that is not on it has its state neither read nor written.
 Nothing here zeroes a state: the engine does, when a request is seated
 (serving/kv_cache.py `reset_state`).
@@ -136,39 +147,64 @@ def check_spec(spec: LayerSpec) -> LayerSpec:
             f"serving has no block with {spec.positions!r} positions and "
             f"{spec.attention!r} attention yet; built: {sorted(BUILT)}")
     # grouped rows and the parallel block are built for each other; a
-    # routed FFN reads the spec in either block
+    # routed FFN reads the spec in either block.  Elsewhere grouped rows
+    # serve the attention layers of a sequential hybrid: a model without
+    # positions, or one whose state layers have none and whose attention
+    # layers rotate
     parallel = spec.residual == "parallel"
-    hybrid = spec.positions == "none"
+    hybrid = spec.positions == "none" or (
+        spec.positions == "per_layer" and not parallel and spec.has_state)
     if (spec.attention == "grouped") != (parallel or hybrid) or (
             parallel and spec.ffn != "routed_experts"):
         raise NotImplementedError(
             f"serving builds the parallel block over grouped attention "
             f"and a routed_experts FFN, and grouped attention elsewhere "
-            f"only in a model without positions; got a {spec.residual!r} "
+            f"only in a sequential block without positions or with layers "
+            f"that keep a state; got a {spec.residual!r} "
             f"residual with {spec.attention!r} attention, "
-            f"{spec.positions!r} positions and a {spec.ffn!r} FFN")
-    if hybrid and (parallel or spec.ffn != "silu_gated" or spec.layer_windows
-                   or spec.norm != "rmsnorm"):
+            f"{spec.positions!r} positions, layer_mixers "
+            f"{spec.layer_mixers} and a {spec.ffn!r} FFN")
+    if hybrid and (parallel or spec.layer_windows
+                   or spec.ffn not in ("silu_gated", "routed_experts")
+                   or spec.norm not in ("rmsnorm", "rmsnorm_unit_offset")):
         raise NotImplementedError(
-            f"a model without positions is built as the sequential block "
-            f"with RMSNorm, a silu_gated FFN and no window; got a "
+            f"a hybrid of state layers and grouped attention is built as "
+            f"the sequential block with RMSNorm (a gain, or 1 + g), a "
+            f"silu_gated or routed_experts FFN and no window; got a "
             f"{spec.residual!r} residual, a {spec.norm!r} norm, a "
             f"{spec.ffn!r} FFN and layer_windows {spec.layer_windows}")
+    if spec.has_state and any(
+            spec.mixer_of(i) != "attention" and spec.rotates(i)
+            for i in range(len(spec.layer_mixers))):
+        raise NotImplementedError(
+            f"a layer that keeps a state has no positions: layer_positions "
+            f"{spec.layer_positions} rotates a layer of layer_mixers "
+            f"{spec.layer_mixers} that does not attend")
     scaled = (spec.embed_scale, spec.residual_scale, spec.logit_divisor,
               spec.attn_scale) != (1.0, 1.0, 1.0, 0.0)
     if (spec.has_state or scaled) and not hybrid:
         raise NotImplementedError(
-            f"layers with a state-space mixer and the stream's scalars "
-            f"are built in the sequential block without positions; got "
-            f"{spec.positions!r} positions with layer_mixers "
+            f"layers with a state (a state-space or a gated delta-rule "
+            f"mixer) and the stream's scalars are built in the sequential "
+            f"block without positions, or with positions layer by layer; "
+            f"got {spec.positions!r} positions and a {spec.residual!r} "
+            f"residual with layer_mixers "
             f"{spec.layer_mixers} and scalars {spec.embed_scale}, "
             f"{spec.residual_scale}, {spec.attn_scale}, "
             f"{spec.logit_divisor}")
-    if not parallel and spec.shared != "sum":
+    if spec.shared != "sum" and parallel != (spec.shared == "average"):
         raise NotImplementedError(
-            f"the sequential block's routed FFN sums its shared experts "
-            f"(its models' configurations do not count them); got shared "
-            f"{spec.shared!r}: the parallel block reads it")
+            f"the parallel block's routed FFN averages or sums its shared "
+            f"experts, the sequential block's sums them or weighs them by "
+            f"a sigmoid gate; got shared {spec.shared!r} with a "
+            f"{spec.residual!r} residual")
+    if (spec.attn_gate or spec.qk_norm or spec.rotary_dim
+            or spec.rope_halves) and parallel:
+        raise NotImplementedError(
+            f"grouped attention's output gate, q/k norm and partial or "
+            f"half-split rotary are built in the sequential block; got "
+            f"them ({spec.attn_gate}, {spec.qk_norm}, {spec.rotary_dim}, "
+            f"{spec.rope_halves}) with a 'parallel' residual")
     if spec.layer_indexers and (spec.positions != "rope"
                                 or spec.ffn != "routed_experts"):
         raise NotImplementedError(
@@ -585,7 +621,9 @@ def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
     values at the cache's dtype, rotated where the layer rotates; the
     rows written — into the request's ring where the layer has a window
     and the cache two groups, through the table else; softmax over the
-    rows the layer lets a query see (through the kernel registry: where
+    rows the layer lets a query see — q and k normed, part of the head
+    rotated and the attended values gated where the spec says so
+    (models/qwen3_next.py `project_gated`) — (through the kernel registry: where
     the layer attends its whole table causally, on the chip, the walk of
     each slot's live blocks in a decode or verify step and of the one
     request's in a prefill chunk of heads of whole 128-lane tiles,
@@ -595,8 +633,15 @@ def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
     B, T, _ = h.shape
     KV, Dh = spec.kv_heads, cfg.head_dim
     window = spec.window_of(layer)
-    q, k, v = cohere2_moe.project_grouped(
-        cfg, p, h, addr.q_pos, spec.rotates(layer), ck.dtype)
+    gate = None
+    if spec.attn_gate or spec.qk_norm or spec.rotary_dim or spec.rope_halves:
+        from ..models.qwen3_next import project_gated
+
+        q, k, v, gate = project_gated(
+            cfg, spec, p, h, addr.q_pos, spec.rotates(layer), ck.dtype)
+    else:
+        q, k, v = cohere2_moe.project_grouped(
+            cfg, p, h, addr.q_pos, spec.rotates(layer), ck.dtype)
     ringed = window > 0 and addr.ring_idx is not None
     idx, tables = (addr.ring_idx, addr.ring_tables) if ringed \
         else (addr.write_idx, addr.tables)
@@ -610,22 +655,25 @@ def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
         kv_heads=KV, block_size=s.block_size,
         scale=spec.attn_scale or None, window=window,
         newest=addr.ring_newest if ringed else None)
+    if gate is not None:
+        out = out * jax.nn.sigmoid(gate)
     return matmul32(out, p["o"]), ck, cv
 
 
-def _ssm_mix(spec, p, h, state, conv, addr):
-    """The state-space mixer over the call's sequences: their entries of
+def _ssm_mix(spec, p, h, state, conv, addr, mix=ssm_mix):
+    """The state-space mixer (or, as `mix`, another mixer with a state)
+    over the call's sequences: their entries of
     the layer's state and convolution inputs — slot `addr.slot`'s for
     the one sequence of a prefill chunk, every slot's in a decode step —
     moved on by `addr.n_valid` positions each and written back in place.
     -> float32."""
     if addr.slot is None:
-        return ssm_mix(spec, p, h, state, conv, addr.n_valid, addr.live)
+        return mix(spec, p, h, state, conv, addr.n_valid, addr.live)
     take = lambda a: jax.lax.dynamic_slice_in_dim(a, addr.slot, 1)
     put = lambda a, new: jax.lax.dynamic_update_slice_in_dim(
         a, new, addr.slot, 0)
-    out, new_state, new_conv = ssm_mix(spec, p, h, take(state), take(conv),
-                                       addr.n_valid)
+    out, new_state, new_conv = mix(spec, p, h, take(state), take(conv),
+                                   addr.n_valid)
     return out, put(state, new_state), put(conv, new_conv)
 
 
@@ -705,8 +753,14 @@ def block(spec, cfg, p, x, kv, addr, s, layer: int = 0, sel=None):
     elif spec.mixer_of(layer) == "ssm":
         with jax.named_scope("ssm.step" if h.shape[1] == 1 else "ssm.scan"):
             attn, *kv = _ssm_mix(spec, p["ssm"], h, *kv, addr)
+    elif spec.mixer_of(layer) == "gdn":
+        from ..models.qwen3_next import gdn_mix
+
+        with jax.named_scope("gdn.step" if h.shape[1] == 1 else "gdn.scan"):
+            attn, *kv = _ssm_mix(spec, p["gdn"], h, *kv, addr, gdn_mix)
     elif spec.attention == "grouped":
-        with jax.named_scope("full_attend"):
+        with jax.named_scope("gated_attend" if spec.attn_gate
+                             else "full_attend"):
             attn, *kv = _grouped_attend(spec, cfg, p["attn"], h, *kv, addr,
                                         s, layer)
     elif spec.attention == "paged":

@@ -65,9 +65,9 @@ group]` and the sliding layers' blocks address their ring by position
 appends to its tokens counts the experts touched among those held.
 `verify`, quantized weights and quantized rows are not built for it.
 
-A spec some of whose layers mix tokens by a state-space recurrence
-(`layer_mixers`; grouped attention without positions in the others)
-runs the same two programs: such a layer's entry of `caches` is its
+A spec some of whose layers mix tokens by a state-space recurrence or
+by the gated delta rule (`layer_mixers`; grouped attention in the
+others) runs the same two programs: such a layer's entry of `caches` is its
 state and its convolution's last inputs BY SLOT, donated and written in
 place with the rows; `prefill` is told its request's slot by one more
 entry behind the table's, `decode` steps every slot's state and leaves a
@@ -431,7 +431,7 @@ class ServeProgramBuilder:
                 f"row codecs are read by the paged gather, and a state "
                 f"kept in fewer bits accumulates its rounding at every "
                 f"token; neither is built")
-        chunk = self.spec.ssm_chunk
+        chunk = self.spec.state_chunk
         if s.prefill_chunk > chunk and s.prefill_chunk % chunk:
             raise ValueError(
                 f"prefill_chunk must be at most the model's scan chunk "

@@ -706,12 +706,12 @@ def test_layer_spec_validate_refuses(change, match):
 
 
 @pytest.mark.parametrize("change,match", [
-    (dict(ffn="gelu_mlp"), "model without positions"),
-    (dict(norm="layernorm"), "model without positions"),
-    (dict(layer_windows=(0, 8, 0)), "model without positions"),
+    (dict(ffn="gelu_mlp"), "a hybrid of state layers and grouped"),
+    (dict(norm="layernorm"), "a hybrid of state layers and grouped"),
+    (dict(layer_windows=(0, 8, 0)), "a hybrid of state layers and grouped"),
     (dict(positions="per_layer", layer_positions=("none",) * 3,
           residual="parallel", ffn="routed_experts", top_k=2),
-     "state-space mixer and the stream's scalars"),
+     "mixer\\) and the stream's scalars"),
     (dict(positions="learned", attention="paged", kv_heads=0, attn_scale=0,
           layer_mixers=(), ssm_heads=0, ssm_head_dim=0, ssm_state=0,
           ssm_conv=0, ssm_chunk=0), "the stream's scalars"),

@@ -321,6 +321,24 @@ def _ssm_step(slots=64, heads=64, p=64, n=128, dtype=jnp.float32):
     return fn, shapes, info
 
 
+def _gdn_step(slots=48, heads=32, d=128, dtype=jnp.float32):
+    """`gdn_step` as `gdn_mix` calls it in a decode step: the Qwen3-Next
+    cell's shapes by default (48 slots, a float32 state of 32 heads x 128
+    x 128 a slot)."""
+    from deepspeed_tpu.kernels.gdn import gdn_step_info
+
+    state = _sds((slots, heads, d, d), dtype)
+    info = gdn_step_info(state)
+    f32 = lambda *shape: _sds(shape, jnp.float32)
+    shapes = (f32(slots, heads, d), f32(slots, heads, d),
+              f32(slots, heads, d), f32(slots, heads), f32(slots, heads),
+              state, _sds((slots,), jnp.int32), _sds((), jnp.int32))
+
+    def fn(*args):
+        return registry.dispatch("gdn_step", *args, info=info)
+    return fn, shapes, info
+
+
 @dataclasses.dataclass
 class Case:
     """`build()` -> (fn, shapes[, info]).  `op` None: the kernel is
@@ -472,6 +490,17 @@ CASES = [
     Case("ssm_step_B64_H64_P64_N128_bf16_state",
          lambda: _ssm_step(dtype=jnp.bfloat16),
          op="ssm_step", refused=r"64 x 128 values of 2 bytes is not whole"),
+    # the delta rule of a decode step at the Qwen3-Next cell's shapes (a
+    # slot's 2 MB of state as one block of 32 heads), a state in tiles of
+    # 32 of 64 heads, and what the shape rule sends to the oracle
+    Case("gdn_step_B48_H32_D128_longchat", _gdn_step, op="gdn_step"),
+    Case("gdn_step_B8_H64_D128_head_tiles", lambda: _gdn_step(8, 64),
+         op="gdn_step"),
+    Case("gdn_step_B48_H4_D16_toy", lambda: _gdn_step(48, 4, 16),
+         op="gdn_step", refused=r"16 x 16 values of 4 bytes is not square"),
+    Case("gdn_step_B48_H32_D128_bf16_state",
+         lambda: _gdn_step(dtype=jnp.bfloat16), op="gdn_step",
+         refused=r"128 x 128 values of 2 bytes is not square"),
     Case("moe_dispatch_N8192_D768_E8", lambda: _moe("dispatch"),
          op="moe_dispatch", variant="dispatch", refused=r"one-row block"),
     Case("moe_combine_N8192_D768_E8", lambda: _moe("combine"),
@@ -553,6 +582,16 @@ def _serve_attention(q_len, slots):
      lambda: ("grouped_attention",
               _command_a_full(slots=1, q_len=512, window=4096, ring=True,
                               width=288)[2]), "jnp"),
+    # 48 slots, 39,937 blocks of 16, a table of 832 entries, 16 query
+    # heads on 2 K/V heads of 256, bf16: both calls walk
+    ("qwen3-next-80b-a3b-d12.serve.longchat.decode",
+     lambda: ("grouped_attention", _grouped(
+         slots=48, heads=16, kv=2, dh=256, width=832, nblocks=39937,
+         scale=None)[2]), "pallas"),
+    ("qwen3-next-80b-a3b-d12.serve.longchat.prefill",
+     lambda: ("grouped_attention", _grouped(
+         slots=1, heads=16, kv=2, dh=256, width=832, nblocks=39937,
+         q_len=512, scale=None)[2]), "pallas"),
     # 32 slots, 8,193 blocks of 16 latent rows of 576 values, a table of
     # 256 entries, bf16
     ("deepseek-v2-lite-d9.serve.chatgen.decode",
@@ -1076,6 +1115,91 @@ def test_granite_hybrid_cell_programs_compile_inside_one_chip(program,
     print(program, "temp", m.temp_size_in_bytes / 1e9, "total", total / 1e9)
     assert m.temp_size_in_bytes < 1024 << 20
     assert total < 15.75e9 - 0.82e9 - 1.0e9, total
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_qwen3_next_cell_programs_compile_inside_one_chip(program, one_chip,
+                                                          native):
+    """`qwen3-next-80b-a3b-d12.serve.longchat.decode` / `.prefill` at the
+    cell's shapes (layers 0-11 at published widths in bf16, 64 of 512
+    experts, 18,992 rows of the vocabulary, 48 slots, 39,937 blocks of 16
+    rows for the 3 full layers, a float32 state `[48, 32, 128, 128]` and
+    `[48, 3, 8192]` convolution inputs for each of the 9 delta layers,
+    chunk 512): `decode`'s custom calls are the 9 delta layers'
+    `gdn_step_live` kernels, the 12 layers' walks of the touched experts
+    and the 3 full layers' walks of the live blocks — no slot's whole
+    table is re-laid as `[48, 13312, 2, 256]` — `prefill`'s the 3 full
+    layers' walks of the request's live blocks (the chunked rule and the
+    grouped products are `jax.numpy`); every state enters and leaves
+    under its own shape, updated in place; and weights, rows, state and
+    temporaries fit the chip's 15.75 GB with room for the check's 1.0 GB
+    of reference logits and its float32 layer."""
+    from deepspeed_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
+    from deepspeed_tpu.serving import ServeProgramBuilder, ServeSchedule
+
+    slots, bs, nblocks, chunk, seq = 48, 16, 39937, 512, 13312
+    model = Qwen3Next(Qwen3NextConfig(
+        vocab_size=18992, max_seq_len=seq, num_layers=12, experts_held=64,
+        param_dtype=jnp.bfloat16))
+    spec, cfg = model.layer_spec(), model.config
+    width = seq // bs
+    sched = ServeSchedule(max_batch=slots, prefill_chunk=chunk,
+                          block_size=bs, num_blocks=nblocks,
+                          table_width=width)
+    progs = ServeProgramBuilder(model, sched).build()
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda s: on(s.shape, s.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    held = sum(s.size * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    assert abs(held - 5.859e9) < 0.001e9
+    rows = on((nblocks * bs, 512), jnp.bfloat16)
+    state = (on((slots, 32, 128, 128), jnp.float32),
+             on((slots, 3, 8192), jnp.bfloat16))
+    caches = [state if spec.mixer_of(i) == "gdn" else (rows, rows)
+              for i in range(cfg.num_layers)]
+    nbytes = lambda c: sum(a.size * a.dtype.itemsize for a in c)
+    assert abs(sum(nbytes(c) for c in caches if c is state) - 0.927e9) < 1e7
+    assert abs(sum(nbytes(c) for c in caches if c is not state)
+               - 3.926e9) < 1e7
+    if program == "decode":
+        args = (on((slots,), jnp.int32), on((slots,), jnp.int32),
+                on((slots,), jnp.bool_), on((slots, width), jnp.int32),
+                on((slots,), jnp.float32), on((slots,), jnp.int32),
+                on((slots,), jnp.uint32))
+    else:   # behind the table's entries: the slot
+        args = (on((1, chunk), jnp.int32), on((), jnp.int32),
+                on((), jnp.int32), on((width + 1,), jnp.int32),
+                on((), jnp.float32), on((), jnp.int32), on((), jnp.uint32))
+    compiled = progs[program].lower(params, caches, *args).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    if program == "decode":
+        assert sum("gdn_step_live" in ln for ln in calls) == 9
+        assert sum("paged_attention_walk" in ln for ln in calls) == 3
+        assert sum("touched_experts" in ln for ln in calls) == 12
+        assert (slots, seq, 2, 256) not in _hlo_by_shape(text)
+        state_ops = {op for op, _ in _hlo_by_shape(text)[
+            (slots, 32, 128, 128)]}
+        assert state_ops <= {"parameter", "custom-call", "get-tuple-element",
+                             "bitcast"}, state_ops
+    else:
+        # (beside them only the grouped products' own: ragged_dot)
+        assert sum("paged_attention_prefill_walk" in ln for ln in calls) == 3
+        assert not any("gdn_step" in ln or "touched_experts" in ln
+                       for ln in calls)
+    m = compiled.memory_analysis()
+    # all 18 state arrays and 6 pools are donated and aliased
+    assert m.alias_size_in_bytes > 4.8e9
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + \
+        m.output_size_in_bytes - m.alias_size_in_bytes
+    print(program, "temp", m.temp_size_in_bytes / 1e9, "total", total / 1e9)
+    assert m.temp_size_in_bytes < 1024 << 20
+    assert total < 15.75e9 - 1.0e9 - 1.0e9, total
 
 
 def test_evabyte_phase_after_the_described_compiles(topo):
