@@ -451,6 +451,8 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                   prefill_starts=(0, 3003, 16084),
                   latent_shape=(32, 16, 512, 64, 256),
                   routed_shape=(32, 64, 2048, 1408), routed_live=12,
+                  slab_shapes=((512, 10, 64, 512, 2048, 512),
+                               (512, 8, 16, 128, 4096, 4096)),
                   ssm_shape=(64, 64, 64, 128), ssm_live=37,
                   gdn_shape=(48, 32, 128), gdn_live=29,
                   on_chip=True) -> dict:
@@ -794,6 +796,37 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
     if int(dropless.experts_touched(idx, alive, E)) in (0, E) or \
             np.asarray(got)[routed_live:].any():
         raise RuntimeError("the routed check's dead slots touched experts")
+
+    # the routed product of a prefill chunk at the longchat and mixedlen
+    # cells' shapes: 512 tokens, the last 100 a padded tail that is not
+    # live, an eighth of the experts held; the held rows walked a slab at
+    # a time against XLA's grouped products over every assignment
+    for T, top_k, E, total, D, F in slab_shapes:
+        mk = lambda k, shape: (jax.random.normal(k, shape, jnp.float32)
+                               * D ** -0.5).astype(jnp.bfloat16)
+        experts = {"gate": mk(key[1], (E, D, F)),
+                   "up": mk(key[2], (E, D, F)),
+                   "down": mk(key[3], (E, F, D))}
+        x = jax.random.normal(key[4], (T, D), jnp.float32)
+        weights, idx, held = dropless.held_assignments(*dropless.route(
+            x, jax.random.normal(key[5], (D, total), jnp.float32)
+            * D ** -0.5, top_k, renormalize=True), total // 4, E)
+        alive = jnp.arange(T) < T - 100
+        way = dropless.routed_way(T, top_k, experts, total)
+        if on_chip and way != "slabs":
+            raise RuntimeError(
+                f"a chunk of {T} rows over {E} of {total} experts of {D} x "
+                f"{F} takes the {way} way on this chip")
+        got = jax.jit(lambda *a: dropless.experts_slabs(
+            *a, total, held, alive))(x, experts, weights, idx)
+        want = jax.jit(dropless.experts_grouped)(x, experts, weights, idx,
+                                                 held)
+        out.append(_close(
+            f"grouped_experts_bf16_T{T}_E{E}of{total}", [T, E, D, F],
+            got[alive], want[alive], rtol=0,
+            atol=1e-2 * float(jnp.abs(want).max())))
+        if np.asarray(got)[T - 100:].any() or not bool(held.any()):
+            raise RuntimeError("the slab check's padded tail was multiplied")
 
     # the state-space recurrence of a decode step at the chatrate cell's
     # shape: 64 slots of which 37 run, scattered; the others' state

@@ -258,3 +258,166 @@ def _touched(x, gate, up, down, w, ids, n, *, interpret):
         interpret=interpret,
     )(ids, n, x, w, gate, up, down)
     return out[:T]
+
+
+# ---------------------------------------------------------------------------
+# grouped experts: the routed product of a slab of rows sorted by expert
+# ---------------------------------------------------------------------------
+
+# rows of the slab one product of the walk takes: a window that starts at
+# an expert's first row, rounded down to whole bf16 tiles, and covers its
+# range in one step wherever the range is under the ridge (the weights
+# pass through the MXU once a window, whatever rows it holds)
+GROUPED_WINDOW = 128
+# what the kernel may ask of a v5e's 128 MiB of VMEM: the slab and its
+# float32 result, held once, the three weight tiles in both pipeline
+# buffers and a window's temporaries
+_GROUPED_VMEM = 100 << 20
+_WINDOW_ALIGN = 16                  # whole tiles of bf16 rows
+
+
+def _whole_tiles(rows: int) -> int:
+    return -(-rows // _WINDOW_ALIGN) * _WINDOW_ALIGN
+
+
+def grouped_vmem(rows: int, model_dim: int, tile: int, itemsize: int) -> int:
+    """Bytes of VMEM the walk over a slab of `rows` rows needs at a
+    column tile of `tile`: what `grouped_tile` fits and the call asks
+    the compiler for."""
+    # a window of room behind the slab's rows for the last expert's
+    slab = (_whole_tiles(rows) + GROUPED_WINDOW) * model_dim * (itemsize + 4)
+    weights = 6 * model_dim * tile * itemsize
+    window = GROUPED_WINDOW * (2 * model_dim + 3 * tile) * 4
+    return slab + weights + window + (4 << 20)
+
+
+def grouped_tile(rows: int, model_dim: int, expert_dim: int,
+                 itemsize: int) -> int:
+    """The columns of an expert's `gate` and `up` (rows of its `down`) a
+    grid step of the walk over a slab of `rows` rows takes: the largest
+    divisor of `expert_dim` in whole 128-lane tiles — or all of it —
+    that `grouped_vmem` fits into `_GROUPED_VMEM`; 0 where none does."""
+    fits = lambda tf: grouped_vmem(rows, model_dim, tf,
+                                   itemsize) <= _GROUPED_VMEM
+    if fits(expert_dim):
+        return expert_dim
+    return max((tf for tf in range(128, expert_dim, 128)
+                if expert_dim % tf == 0 and fits(tf)), default=0)
+
+
+def _grouped_kernel(off_ref, src_ref, col_ref, x_hbm, gate_ref, up_ref,
+                    down_ref, o_hbm, x_ref, acc_ref, sem, *, rows):
+    e, f = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((e == 0) & (f == 0))
+    def _load():
+        copy = pltpu.make_async_copy(x_hbm, x_ref.at[pl.ds(0, rows)], sem)
+        copy.start()
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        copy.wait()
+
+    lo, hi = off_ref[e], off_ref[e + 1]
+    start = lo // _WINDOW_ALIGN * _WINDOW_ALIGN
+
+    def window(i, carry):
+        r0 = pl.multiple_of(start + i * GROUPED_WINDOW, _WINDOW_ALIGN)
+        at = pl.ds(r0, GROUPED_WINDOW)
+        # experts_grouped's roundings: operands at the weights' dtype,
+        # float32 sums, the gated product rounded before `down`
+        x = x_ref[at, :]
+        g = jnp.dot(x, gate_ref[...], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, up_ref[...], preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        out = jnp.dot(h, down_ref[...], preferred_element_type=jnp.float32)
+        row = r0 + jax.lax.broadcasted_iota(
+            jnp.int32, (GROUPED_WINDOW, 1), 0)
+        # a row of another expert, or of none, is never written: what
+        # it holds (anything, past the slab's end) stays out of the sum
+        acc_ref[at, :] += jnp.where((row >= lo) & (row < hi), out, 0.0)
+        return carry
+
+    jax.lax.fori_loop(
+        0, jnp.where(hi > lo, -(-(hi - start) // GROUPED_WINDOW), 0),
+        window, 0)
+
+    @pl.when((e == pl.num_programs(0) - 1) & (f == pl.num_programs(1) - 1))
+    def _store():
+        copy = pltpu.make_async_copy(acc_ref.at[pl.ds(0, rows)], o_hbm, sem)
+        copy.start()
+        copy.wait()
+
+
+def grouped_experts_pallas(xs, experts, offsets):
+    """Drop-in for `moe/dropless.py::grouped_ffn`: xs [C, D], the rows of
+    a slab sorted by expert, expert e's at `offsets[e]` ..
+    `offsets[e + 1]` (offsets [E + 1] int32, ascending, within 0 .. C)
+    -> [C, D] float32, each row through its expert's FFN and 0 from
+    `offsets[E]` on; tolerance parity (an expert's column tiles are
+    summed in another order)."""
+    return _grouped(xs.astype(experts["gate"].dtype), experts["gate"],
+                    experts["up"], experts["down"], offsets,
+                    interpret=pallas_backend.interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _grouped(xs, gate, up, down, offsets, *, interpret):
+    """The call, as a function of its own (a program that makes it in
+    every layer lowers the kernel once).  Grid (expert e, column tile
+    f), the offsets scalar-prefetched: a step multiplies the windows of
+    the slab that cover the expert's range with its tile of `gate`, `up`
+    and `down` and adds the rows of the range to the float32 result;
+    slab and result lie in VMEM once, copied in at the first step and
+    out at the last.  An expert with no row stays on the tile read last
+    (the first one to come, before any is), so the pipeline copies
+    nothing for it."""
+    C, D = xs.shape
+    E, _, F = gate.shape
+    item = gate.dtype.itemsize
+    tf = grouped_tile(C, D, F, item)
+    nf = F // tf
+    rows = _whole_tiles(C)
+    if rows != C:
+        xs = jnp.pad(xs, ((0, rows - C), (0, 0)))
+    offsets = offsets.astype(jnp.int32)
+    at = jnp.arange(E, dtype=jnp.int32)
+    full = offsets[1:] > offsets[:-1]
+    before = jax.lax.cummax(jnp.where(full, at, -1))    # the last read
+    ahead = jnp.min(jnp.where(full, at, E - 1))         # the first to come
+    src = jnp.where(before >= 0, before, ahead)
+    col = jnp.where(before >= 0, nf - 1, 0)
+
+    def tile(e, f, off, src, col):   # (expert, column tile) of a grid step
+        own = off[e + 1] > off[e]
+        return jnp.where(own, e, src[e]), jnp.where(own, f, col[e])
+
+    def cols_of(*step):
+        expert, t = tile(*step)
+        return expert, 0, t
+
+    cols = pl.BlockSpec((None, D, tf), cols_of)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(E, nf),
+        in_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),
+            cols, cols,
+            pl.BlockSpec((None, tf, D), lambda *step: (*tile(*step), 0)),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((rows + GROUPED_WINDOW, D), xs.dtype),
+            pltpu.VMEM((rows + GROUPED_WINDOW, D), jnp.float32),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_grouped_kernel, rows=rows),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY),
+            vmem_limit_bytes=grouped_vmem(C, D, tf, item)),
+        name="grouped_experts",
+        interpret=interpret,
+    )(offsets, src, col, xs, gate, up, down)
+    return out[:C]

@@ -599,6 +599,51 @@ class TouchedExpertsOp(KernelOp):
         return dropless.experts_weighted(x, experts, w)
 
 
+class GroupedExpertsOp(KernelOp):
+    """The routed FFN of a slab of rows sorted by expert (moe/dropless.py
+    `experts_slabs`, a prefill chunk): each row through the FFN of the
+    expert whose range of the slab holds it.  Pallas = a walk of the
+    held experts over their ranges that reads every expert's matrices
+    once, slab and result in VMEM (kernels/moe_kernels.py); oracle =
+    `grouped_ffn`, XLA's grouped products (`lax.ragged_dot`) over the
+    same rows.  The shape rule looks at the call's rows, the slab's and
+    the experts' three sizes (`moe/dropless.py::grouped_info`)."""
+
+    NAME = "grouped_experts"
+
+    def auto_supports(self, variant, info):
+        if not info:
+            return True, ""
+        from ..moe.dropless import RIDGE_TOKENS
+        from .moe_kernels import grouped_tile
+
+        t = int(info["tokens"])
+        if t <= RIDGE_TOKENS:
+            return False, (f"{t} rows are under the ridge "
+                           f"({RIDGE_TOKENS}): a call bound by the "
+                           f"experts' bytes reads the touched ones under "
+                           f"a mask, and sorts nothing")
+        D, F = int(info["model_dim"]), int(info["expert_dim"])
+        if D % 128:
+            return False, (f"rows of {D} values are not whole 128-lane "
+                           f"tiles")
+        rows = int(info["rows"])
+        if not grouped_tile(rows, D, F, int(info["itemsize"])):
+            return False, (f"a slab of {rows} rows of {D} values, its "
+                           f"float32 result and a whole-tile share of an "
+                           f"expert's {F} columns do not fit the kernel's "
+                           f"VMEM together")
+        return True, ""
+
+    def pallas(self, variant, *args, **kwargs):
+        from . import moe_kernels
+        return moe_kernels.grouped_experts_pallas(*args, **kwargs)
+
+    def oracle(self, variant, *args, **kwargs):
+        from ..moe import dropless
+        return dropless.grouped_ffn(*args, **kwargs)
+
+
 class SsmStepOp(KernelOp):
     """The Mamba-2 recurrence of a decode step (models/granite_hybrid.py
     `ssm_mix`, one token a slot).  Pallas = a walk of the step's live
@@ -684,8 +729,8 @@ KERNEL_OPS: Dict[str, KernelOp] = {
                            PagedAttentionOp(), GroupedAttentionOp(),
                            LatentAttentionOp(), EvaAttentionOp(),
                            QuantCodecOp(), MoEDispatchOp(),
-                           TouchedExpertsOp(), SsmStepOp(),
-                           GdnStepOp())
+                           TouchedExpertsOp(), GroupedExpertsOp(),
+                           SsmStepOp(), GdnStepOp())
 }
 
 

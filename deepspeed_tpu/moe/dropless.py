@@ -19,7 +19,7 @@ renumbers the chosen experts to the ones this chip holds and gives the
 others weight 0, so an assignment to an expert that lies elsewhere adds
 nothing here — what the other chips would add is theirs to add.
 
-Three ways to the same sum y_t = sum_i w_ti E_i(x_t), chosen by
+Four ways to the same sum y_t = sum_i w_ti E_i(x_t), chosen by
 `routed_experts` from what the call can see (its static shapes and its
 backend):
 
@@ -42,10 +42,22 @@ backend):
   the slots that are not live (`live` [T] False: their hidden rows are
   whatever the slot last held) touch nothing.
 * `experts_grouped`: assignments sorted by expert, one grouped product
-  (`lax.ragged_dot`) a matrix over the experts held, the results put
-  back in token order.  top_k products a token: a prefill chunk (any
-  call over the ridge), or off a TPU a decode step too small to touch
-  every expert.
+  (`lax.ragged_dot`) a matrix over the experts held and over all
+  T * top_k rows, the results put back in token order.  top_k products
+  a token: off a TPU a prefill chunk (any call over the ridge) and a
+  decode step too small to touch every expert.  It is the oracle of the
+  fourth way.
+* `experts_slabs` (on a TPU): the same sort, and of its order only the
+  rows this chip holds — a compact SLAB of the first `slab_rows` rows
+  (twice the rows expected of the share held, in whole `SLAB_ROWS`;
+  every row where all experts are held), each through its expert's FFN
+  by a kernel that walks the held experts over their ranges of the slab
+  and reads every expert's matrices once (the registry's
+  `grouped_experts` op, kernels/moe_kernels.py), the results summed
+  into token order under their weights.  A chunk that holds more rows
+  than a slab walks the next slab too (`slabs_walked`: a loop of one
+  body, usually one trip): nothing is dropped at any number of rows
+  held.  A prefill chunk: any call over the ridge.
 
 An expert is a SiLU-gated FFN; `experts` holds `gate`, `up` [E, D, F]
 and `down` [E, F, D].
@@ -60,6 +72,10 @@ import jax.numpy as jnp
 # cost more than the bytes: ~240 on a v5e (197 TFLOP/s over 819 GB/s);
 # half of it leaves the masked path bound by bytes with room
 RIDGE_TOKENS = 128
+# a slab of `experts_slabs` is whole multiples of this many rows, and
+# holds this many times the rows expected of the share held
+SLAB_ROWS = 256
+SLAB_ROOM = 2
 
 
 def route(h, router, top_k: int, scoring: str = "softmax",
@@ -150,20 +166,29 @@ def experts_masked(x, experts, weights, idx):
         x, experts, combine_weights(weights, idx, experts["gate"].shape[0]))
 
 
-def experts_grouped(x, experts, weights, idx, held=None):
-    """Assignments sorted by expert, grouped products over the experts
-    held; x [T, D] -> [T, D] float32.  With `held` [T, top_k] the
-    assignments that lie elsewhere sort behind every group, belong to
-    none and add nothing."""
-    T, k = idx.shape
-    E = experts["gate"].shape[0]
-    flat = idx.reshape(T * k)
-    if held is not None:
-        flat = jnp.where(held.reshape(T * k), flat, E)
-    order = jnp.argsort(flat, stable=True)
-    sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
+def _by_expert(idx, num_experts: int, keep=None):
+    """The assignments sorted by expert, as the grouped ways walk them:
+    (order [T * top_k]: the flat assignments in the order of their
+    experts, those where `keep` [T, top_k] is False behind every group;
+    offsets [num_experts + 1]: expert e's rows of that order lie at
+    `offsets[e]` .. `offsets[e + 1]`, the rows of no group from
+    `offsets[-1]` on)."""
+    flat = idx.reshape(-1)
+    if keep is not None:
+        flat = jnp.where(keep.reshape(-1), flat, num_experts)
+    sizes = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1, mode="drop")
+    return jnp.argsort(flat, stable=True), jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
+
+
+def grouped_ffn(xs, experts, offsets):
+    """Rows sorted by expert through their experts' FFN by grouped
+    products (`lax.ragged_dot`): xs [C, D], expert e's rows at
+    `offsets[e]` .. `offsets[e + 1]` (offsets [E + 1]) -> [C, D]
+    float32, 0 from `offsets[E]` on: rows of no group hold nothing to
+    rely on."""
     dt = experts["gate"].dtype
-    xs = x.astype(dt)[order // k]                              # [T*k, D]
+    xs, sizes = xs.astype(dt), offsets[1:] - offsets[:-1]
 
     def grouped(a, w):
         return jax.lax.ragged_dot(a, w, sizes,
@@ -171,11 +196,94 @@ def experts_grouped(x, experts, weights, idx, held=None):
 
     h = jax.nn.silu(grouped(xs, experts["gate"])) * \
         grouped(xs, experts["up"])
-    out = grouped(h.astype(dt), experts["down"])               # [T*k, D]
-    if held is not None:     # rows of no group hold nothing to rely on
-        out = jnp.where((flat[order] < E)[:, None], out, 0.0)
+    out = grouped(h.astype(dt), experts["down"])
+    return jnp.where(jnp.arange(xs.shape[0])[:, None] < offsets[-1],
+                     out, 0.0)
+
+
+def experts_grouped(x, experts, weights, idx, held=None):
+    """Assignments sorted by expert, grouped products over the experts
+    held; x [T, D] -> [T, D] float32.  With `held` [T, top_k] the
+    assignments that lie elsewhere sort behind every group, belong to
+    none and add nothing."""
+    T, k = idx.shape
+    order, offsets = _by_expert(idx, experts["gate"].shape[0], held)
+    out = grouped_ffn(x.astype(experts["gate"].dtype)[order // k], experts,
+                      offsets)                                 # [T*k, D]
     back = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
     return jnp.einsum("tkd,tk->td", out[back].reshape(T, k, -1), weights)
+
+
+def slab_rows(tokens: int, top_k: int, count: int, total: int) -> int:
+    """Rows of a slab of `experts_slabs` for a call of `tokens` rows of
+    `top_k` assignments of which a share `count / total` is held:
+    `SLAB_ROOM` times the rows expected, in whole `SLAB_ROWS`, and never
+    more than there are."""
+    rows = tokens * top_k
+    expected = -(-rows * count // total)
+    return min(rows, -(-SLAB_ROOM * expected // SLAB_ROWS) * SLAB_ROWS)
+
+
+def _kept(held, live, top_k: int):
+    """The assignments a slab may hold, [T, top_k] bool (None: all):
+    those `held`, of a token that is `live`."""
+    if live is None:
+        return held
+    live = jnp.broadcast_to(live[:, None], (live.shape[0], top_k))
+    return live if held is None else held & live
+
+
+def slabs_walked(idx, experts, total=None, held=None, live=None):
+    """(slabs `experts_slabs` walks for this call — int32, or the int 1
+    where one slab holds every row —, rows of a slab)."""
+    T, k = idx.shape
+    E = experts["gate"].shape[0]
+    C = slab_rows(T, k, E, E if held is None or total is None else total)
+    if C == T * k:
+        return 1, C
+    return -(-jnp.sum(_kept(held, live, k), dtype=jnp.int32) // C), C
+
+
+def grouped_info(tokens: int, rows: int, experts) -> dict:
+    """What the kernel registry may look at to choose the product over
+    a slab of `rows` rows, of a call of `tokens` rows over `experts`."""
+    return dict(touched_info(tokens, experts), rows=rows)
+
+
+def experts_slabs(x, experts, weights, idx, total=None, held=None,
+                  live=None):
+    """Assignments sorted by expert and the held ones walked a slab at a
+    time through the registry's `grouped_experts` op; x [T, D] -> [T, D]
+    float32.  An assignment that lies elsewhere, and one of a token
+    that is not `live`, sorts behind every group: it enters no slab's
+    sum, and the token's sum is 0."""
+    from ..kernels import registry
+
+    T, k = idx.shape
+    slabs, C = slabs_walked(idx, experts, total, held, live)
+    order, offsets = _by_expert(idx, experts["gate"].shape[0],
+                                _kept(held, live, k))
+    order = jnp.pad(order, (0, -(T * k) % C))      # whole slabs
+    xd, w = x.astype(experts["gate"].dtype), weights.reshape(T * k)
+    info = grouped_info(T, C, experts)
+
+    def slab(s, y):
+        rows = jax.lax.dynamic_slice(order, (s * C,), (C,))
+        out = registry.dispatch(
+            "grouped_experts", xd[rows // k], experts,
+            jnp.clip(offsets - s * C, 0, C), info=info)
+        # back to token order: a 0/1 [T, C] product with the weighted
+        # rows, float32 throughout
+        mine = (rows // k)[None, :] == jnp.arange(T)[:, None]
+        return y + jnp.dot(mine.astype(jnp.float32), out * w[rows][:, None],
+                           precision=jax.lax.Precision.HIGHEST)
+
+    y = jnp.zeros((T, x.shape[1]), jnp.float32)
+    if isinstance(slabs, int):
+        return slab(0, y)
+    return jax.lax.while_loop(
+        lambda c: c[0] < slabs, lambda c: (c[0] + 1, slab(*c)),
+        (jnp.int32(0), y))[1]
 
 
 def touched_info(tokens: int, experts) -> dict:
@@ -203,12 +311,14 @@ def experts_touched_only(x, experts, weights, idx, live=None, held=None):
 
 
 def routed_way(tokens: int, top_k: int, experts, total=None) -> str:
-    """Which of the three ways `routed_experts` takes for a call of
+    """Which of the four ways `routed_experts` takes for a call of
     `tokens` rows of `top_k` assignments over `experts` (arrays or their
-    shapes), a share of `total`: "touched", "masked" or "grouped"."""
+    shapes), a share of `total`: "touched", "masked", "slabs" or
+    "grouped"."""
     from ..kernels import registry
 
-    total = experts["gate"].shape[0] if total is None else total
+    E = experts["gate"].shape[0]
+    total = E if total is None else total
     # on a TPU, under the ridge: never more bytes than the masked way
     if registry.resolve_impl("touched_experts",
                              info=touched_info(tokens, experts)) == "pallas":
@@ -216,12 +326,37 @@ def routed_way(tokens: int, top_k: int, experts, total=None) -> str:
     # the assignments expected here, T * k * E / total, cover the E held
     if tokens * top_k >= total and tokens <= RIDGE_TOKENS:
         return "masked"
+    # on a TPU, over the ridge: the held rows alone, each expert read once
+    if registry.resolve_impl(
+            "grouped_experts", info=grouped_info(
+                tokens, slab_rows(tokens, top_k, E, total),
+                experts)) == "pallas":
+        return "slabs"
     return "grouped"
+
+
+def rows_multiplied(idx, experts, total=None, held=None, live=None):
+    """Assignment rows the routed product of this call multiplies with
+    an expert's matrices, int32: the slabs walked times a slab's rows,
+    every assignment where they are grouped whole, every row times the
+    experts held (or touched) where each expert runs on every row."""
+    T, k = idx.shape
+    E = experts["gate"].shape[0]
+    way = routed_way(T, k, experts, total)
+    if way == "slabs":
+        slabs, C = slabs_walked(idx, experts, total, held, live)
+        return jnp.int32(slabs * C)
+    if way == "grouped":
+        return jnp.int32(T * k)
+    if way == "masked":
+        return jnp.int32(T * E)
+    return T * experts_touched(
+        idx, jnp.ones((T,), bool) if live is None else live, E, held)
 
 
 def routed_experts(x, experts, weights, idx, total=None, held=None,
                    live=None):
-    """sum_i w_ti E_i(x_t) for x [T, D], by the cheapest of the three
+    """sum_i w_ti E_i(x_t) for x [T, D], by the cheapest of the four
     ways at this call's shapes and backend (`routed_way`).  `total` is
     the number of experts the router chose among where `experts` is a
     share of them, `held` [T, top_k] the assignments of the share
@@ -232,4 +367,6 @@ def routed_experts(x, experts, weights, idx, total=None, held=None,
         return experts_touched_only(x, experts, weights, idx, live, held)
     if way == "masked":
         return experts_masked(x, experts, weights, idx)
+    if way == "slabs":
+        return experts_slabs(x, experts, weights, idx, total, held, live)
     return experts_grouped(x, experts, weights, idx, held)

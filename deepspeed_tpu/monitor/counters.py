@@ -143,7 +143,14 @@ Instrumented sites:
   `serve.moe.experts_streamed` — the same calls, bytes = experts whose
   weights the step's routed product read: the touched ones where it
   follows the touched list or sorts by expert, every expert held where
-  it masks (`moe/dropless.py::routed_way`, asked once at build).
+  it masks (`moe/dropless.py::routed_way`, asked once at build);
+  `serve.moe.prefill_rows_multiplied` — calls = prefill chunks x routed
+  layers, bytes = assignment rows the chunks' routed products
+  multiplied with an expert's matrices (slabs walked x a slab's rows
+  where the product walks compact slabs of the rows held, tokens x
+  top_k where it groups every assignment; counted in the program,
+  returned behind each chunk's sample and read with the request's
+  first token).
   Grouped rows over two groups of layers (a served model with
   "grouped" attention and sliding layers): `serve.window.rows_read` —
   calls = queries decoded, bytes = rows one attends in ONE sliding

@@ -143,7 +143,15 @@ launch) and `serve.moe.experts_streamed` (the same calls, bytes =
 experts whose weights the step's routed product read: the touched ones
 where it follows the touched list — a TPU — or sorts by expert, every
 one held where it masks; `moe/dropless.py::routed_way`, asked once at
-build, and no read of its own).
+build, and no read of its own) and `serve.moe.prefill_rows_multiplied`
+(calls = prefill chunks x routed layers, bytes = assignment rows the
+chunks' routed products multiplied with an expert's matrices: the slabs
+walked times a slab's rows where the product walks compact slabs of the
+rows held — a TPU —, tokens x top_k where it groups every assignment;
+counted in the program, `moe/dropless.py::rows_multiplied`, and
+returned behind each chunk's sample: the arrays are kept with the
+request and read when its first token is, never by a read of their
+own).
 
 A learned selection of the latent rows (a layer spec whose
 `layer_indexers` mark layers "full" or "shared", models/glm_moe_dsa.py):
@@ -362,7 +370,8 @@ class _First:
     """A prompt's first token: sampled by `prefill`, not yet read."""
 
     req: Request
-    tok: Any                          # device scalar
+    tok: Any                          # device scalar (behind routed
+    #                                   FFNs [2]: the rows multiplied too)
 
 
 @dataclasses.dataclass
@@ -1058,6 +1067,11 @@ class ServeEngine:
             COUNTERS.add("serve.sparse.selections_shared",
                          calls=self._index_shared)
         self._count_assignments(n_valid)
+        if self._routed_layers and req.prefill_pos < len(req.prompt):
+            # behind the sample nobody reads: the rows the chunk's routed
+            # products multiplied, read when the last chunk's sample is
+            tok.copy_to_host_async()
+            req.chunk_counts.append(tok)
         if tr is not None:
             # cached/computed: the prefix-cache outcome per request —
             # how many prompt tokens this request never prefilled
@@ -1191,8 +1205,20 @@ class ServeEngine:
             return
         phases = self._step_tracer()
         with phase("serve.read", phases):
-            first = int(item.tok)
+            if self._routed_layers:
+                # every chunk's [sample, rows multiplied], the last last
+                chunks = [np.asarray(t) for t in (*req.chunk_counts,
+                                                  item.tok)]
+                first = int(chunks[-1][0])
+            else:
+                first = int(item.tok)
         with phase("serve.bookkeep", phases):
+            if self._routed_layers:
+                COUNTERS.add(
+                    "serve.moe.prefill_rows_multiplied",
+                    calls=len(chunks) * self._routed_layers,
+                    nbytes=sum(int(c[1]) for c in chunks))
+                req.chunk_counts = []
             tr = self._req_tracer(req)
             now = self.clock()
             stamp_us = tr.now_us() if tr is not None else 0

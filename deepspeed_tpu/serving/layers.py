@@ -133,7 +133,7 @@ from ..models.evabyte import (chunk_summaries, matmul32, project_qkv,
                               rms_norm, silu_gated_ffn)
 from ..models.gpt import layer_norm
 from ..models.layer_spec import LayerSpec
-from ..moe.dropless import experts_touched
+from ..moe.dropless import experts_touched, rows_multiplied
 from .kv_cache import pool_rows
 
 BUILT = {("learned", "paged"), ("rope", "eva"), ("rope", "latent"),
@@ -688,17 +688,24 @@ def _visible(at, q_pos, window: int):
     return mask
 
 
-def _parallel_block(spec, cfg, p, x, kv, addr, s, layer: int):
+def _parallel_block(spec, cfg, p, x, kv, addr, s, layer: int, count: str):
     """One norm, attention and the routed FFN the spec describes on the
-    same h, both added to x: -> (x, kv, touched)."""
+    same h, both added to x: -> (x, kv, the layer's `count`)."""
     h = _norm(spec, x, p["ln1"])
     with jax.named_scope("swa_attend" if spec.window_of(layer)
                          else "full_attend"):
         attn, *kv = _grouped_attend(spec, cfg, p["attn"], h, *kv, addr, s,
                                     layer)
-    y, touched = cohere2_moe.expert_ffn(
-        spec, cfg, p["mlp"], h, live=addr.q_pos.reshape(-1) >= 0)
-    return x + attn + y, tuple(kv), touched
+    live = addr.q_pos.reshape(-1) >= 0
+    if count == "touched":   # as a decode step's program has had it
+        y, n = cohere2_moe.expert_ffn(spec, cfg, p["mlp"], h, live=live)
+    else:
+        y, idx, _, held = cohere2_moe.routed_ffn(
+            spec, cfg, p["mlp"], h.reshape(-1, h.shape[-1]), live)
+        y = y.reshape(h.shape)
+        n = rows_multiplied(idx, p["mlp"]["experts"], cfg.num_experts, held,
+                            live)
+    return x + attn + y, tuple(kv), n
 
 
 def _norm(spec, x, p):
@@ -721,29 +728,35 @@ def _ffn(spec, p, h):
         p["fc2"]["b"].astype(h.dtype)
 
 
-def blocks(spec, cfg, params, x, caches, addr, s):
+def blocks(spec, cfg, params, x, caches, addr, s, count: str = "touched"):
     """Every layer's `block` in turn over x -> (x, the new cache
-    entries, `touched` of the layers that have one), a selection that a
-    layer makes handed to the layers behind it."""
+    entries, the `count` of the layers that have one), a selection that
+    a layer makes handed to the layers behind it."""
     new_caches, touched, sel = [], [], None
     for i, (bp, kv) in enumerate(zip(params["blocks"], caches)):
-        x, kv, n, sel = block(spec, cfg, bp, x, kv, addr, s, i, sel)
+        x, kv, n, sel = block(spec, cfg, bp, x, kv, addr, s, i, sel, count)
         new_caches.append(kv)
         if n is not None:
             touched.append(n)
     return x, new_caches, touched
 
 
-def block(spec, cfg, p, x, kv, addr, s, layer: int = 0, sel=None):
+def block(spec, cfg, p, x, kv, addr, s, layer: int = 0, sel=None,
+          count: str = "touched"):
     """Pre-norm decoder block number `layer` over x [B, T, D] through
     its entry `kv` of the cache of a program of schedule `s` -> (x, kv,
-    touched, sel): `touched` is None, or, behind a routed FFN, how many
-    of its experts the call's live tokens (`addr.q_pos` >= 0) chose
-    (behind a share of the experts: of those held); `sel` is the
-    selection of rows the layer attended where the spec has layers that
-    choose (None elsewhere), for the layers behind it."""
+    touched, sel): `touched` is None, or, behind a routed FFN, what the
+    program asked the layer to `count`, int32: "touched", how many of
+    its experts the call's live tokens (`addr.q_pos` >= 0) chose (behind
+    a share of the experts: of those held; a decode step's), or "rows",
+    how many assignment rows its routed product multiplied with an
+    expert's matrices (`moe/dropless.py::rows_multiplied`; a prefill
+    chunk's); `sel` is the selection of rows the layer attended where
+    the spec has layers that choose (None elsewhere), for the layers
+    behind it."""
     if spec.residual == "parallel":
-        return _parallel_block(spec, cfg, p, x, kv, addr, s, layer) + (None,)
+        return _parallel_block(spec, cfg, p, x, kv, addr, s, layer,
+                               count) + (None,)
     h = _norm(spec, x, p["ln1"])
     if spec.layer_indexers:
         from .sparse import sparse_latent_attend
@@ -776,12 +789,14 @@ def block(spec, cfg, p, x, kv, addr, s, layer: int = 0, sel=None):
         return (x + _scaled(_ffn(spec, p["mlp"], h), spec.residual_scale),
                 tuple(kv), None, sel)
     live = addr.q_pos.reshape(-1) >= 0
-    y, idx, count, held = cohere2_moe.routed_ffn(
+    y, idx, held_of, held = cohere2_moe.routed_ffn(
         spec, cfg, p["mlp"], h.reshape(-1, h.shape[-1]), live)
     # reshaped before the count, as this block's programs have it (the
     # order of independent operations is part of their StableHLO)
     y = y.reshape(h.shape)
-    touched = experts_touched(idx, live, count, held)
+    touched = experts_touched(idx, live, held_of, held) \
+        if count == "touched" else rows_multiplied(
+            idx, p["mlp"]["experts"], cfg.num_experts, held, live)
     return x + y, tuple(kv), touched, sel
 
 

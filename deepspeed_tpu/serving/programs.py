@@ -248,6 +248,13 @@ def seat(tokens, slot, tok):
     return tokens.at[slot].set(tok)
 
 
+@jax.jit
+def seat_counted(tokens, slot, tok):
+    """`seat` behind routed FFNs, where `prefill`'s sample is the first
+    of two entries."""
+    return tokens.at[slot].set(tok[0])
+
+
 # -- qwZ weight store -------------------------------------------------------
 
 
@@ -465,9 +472,15 @@ class ServeProgramBuilder:
         return {"schedule": self.schedule,
                 "prefill": self._build_prefill(),
                 "decode": self._build_decode(),
-                "seat": seat,
+                "seat": seat_counted if self._routed() else seat,
                 "verify": self._build_verify(),
                 "prepare_params": self._prepare_params}
+
+    def _routed(self) -> bool:
+        """Whether some layer has a routed FFN: `prefill` and `decode`
+        then return their counts behind their tokens."""
+        return self.spec.ffn == "routed_experts" and \
+            self.model.config.num_layers > self.spec.dense_layers
 
     def _prepare_params(self, params):
         """Engine-side one-time weight prep for the schedule's quant
@@ -501,14 +514,18 @@ class ServeProgramBuilder:
             position `pos`; writes the chunk's K/V through `table`
             [W] and returns (first-token sample, last-valid-row
             logits, caches).  The sample is only meaningful on the
-            FINAL chunk (the engine ignores it otherwise)."""
+            FINAL chunk (the engine ignores it otherwise).  Behind
+            routed FFNs the sample is followed by one more entry, the
+            assignment rows the chunk's routed products multiplied
+            summed over those layers (int32 [2]): it rides the one
+            transfer the engine makes a request."""
             params = self._maybe_dequant(params)
             abs_pos = pos + jnp.arange(C)
             x = layers.embed_chunk(spec, params, tokens, abs_pos)
             addr = layers.address_chunk(spec, s, table, pos, abs_pos,
                                         n_valid)
-            x, new_caches, _ = layers.blocks(spec, cfg, params, x, caches,
-                                             addr, s)
+            x, new_caches, rows = layers.blocks(spec, cfg, params, x, caches,
+                                                addr, s, count="rows")
             x = layers.final_norm(spec, params, x)
             last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
             logits = layers.logits(spec, params, last[:, 0, :])  # [1, V]
@@ -516,6 +533,8 @@ class ServeProgramBuilder:
                 layers.sampled(spec, logits), temperature[None],
                 top_k[None], jnp.ones((1,), bool),
                 lambda: _row_key(seed, pos + n_valid)[None])[0]
+            if rows:
+                tok = jnp.stack([tok, sum(rows)])
             return tok, logits[0], new_caches
 
         return prefill

@@ -96,6 +96,9 @@ class Request:
     #                                   written by a launched program
     prefix_cached_tokens: int = 0     # prompt tokens skipped at admit
     block_hashes: List[bytes] = field(default_factory=list)
+    # behind routed FFNs: what `prefill` returned for the chunks before
+    # the last, on the device, read with the first token
+    chunk_counts: List[Any] = field(default_factory=list)
     # timestamps (engine clock)
     t_submit: float = 0.0
     t_first_token: Optional[float] = None

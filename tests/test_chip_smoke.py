@@ -83,7 +83,9 @@ def test_kernels_phase_toy():
                                    prefill_starts=(0, 21, 88),
                                    latent_shape=(3, 4, 32, 16, 3),
                                    routed_shape=(16, 16, 128, 256),
-                                   routed_live=2, ssm_shape=(6, 4, 8, 16),
+                                   routed_live=2,
+                                   slab_shapes=((160, 4, 4, 16, 128, 256),),
+                                   ssm_shape=(6, 4, 8, 16),
                                    ssm_live=3, gdn_shape=(6, 4, 16),
                                    gdn_live=3, on_chip=False)
     assert [k["kernel"] for k in out["kernels"]] == [
@@ -98,7 +100,8 @@ def test_kernels_phase_toy():
         "grouped_prefill_bf16_H4_KV2_Dh128_at21",
         "grouped_prefill_bf16_H4_KV2_Dh128_at88",
         "latent_attention_bf16_H4_W48",
-        "touched_experts_bf16_T16_E16", "ssm_step_B6_H4_P8_N16_live3",
+        "touched_experts_bf16_T16_E16", "grouped_experts_bf16_T160_E4of16",
+        "ssm_step_B6_H4_P8_N16_live3",
         "gdn_step_B6_H4_D16_live3"]
     # the CPU keeps the two slices right; the chip's answer is the phase's
     assert out["own_lanes_two_slices_right"] is True

@@ -293,11 +293,11 @@ def test_on_a_tpu_a_share_under_the_ridge_follows_the_touched_list(native):
     128 held, 8 a token): any call of up to 128 rows reads the held
     experts its live rows touched — one token's 8 assignments hold one
     expert on average, and none is read for the other 15 — and a
-    prefill chunk keeps the grouped products."""
+    prefill chunk walks the held rows a slab at a time (PR 58)."""
     ex = {"gate": jax.ShapeDtypeStruct((16, 4096, 4096), jnp.bfloat16)}
     assert [dropless.routed_way(t, 8, ex, 128)
             for t in (1, 15, 16, 128, 129, 512)] == \
-        ["touched"] * 4 + ["grouped"] * 2
+        ["touched"] * 4 + ["slabs"] * 2
     # widths the kernel cannot tile: the choice off a TPU
     small = {"gate": jax.ShapeDtypeStruct((16, 64, 32), jnp.float32)}
     assert [dropless.routed_way(t, 8, small, 128) for t in (15, 16, 129)] \
@@ -436,7 +436,7 @@ def _drive(model, params, sched, kv, prompt, n_decode):
             params, caches, jnp.asarray(toks), np.int32(pos),
             np.int32(len(chunk)), jnp.asarray(table), *zero)
     rows.append(np.asarray(lg))
-    tok = int(tok)
+    tok = int(tok[0])       # behind routed FFNs [sample, rows multiplied]
     for p in range(len(prompt), len(prompt) + n_decode):
         if kv.ring_blocks:
             table = kv.extend("r", p, p + 1)
@@ -538,6 +538,30 @@ def test_counters_of_a_decode_step():
         "calls": steps * LAYERS, "bytes": steps * LAYERS * 4}
     assert "serve.moe.assignments" not in d
     assert "serve.paged.rows_walked" not in d
+
+
+def test_counters_of_a_prefill_chunk():
+    """`serve.moe.prefill_rows_multiplied`: every chunk's routed layers
+    report the assignment rows their product multiplied — counted in
+    the program, returned behind the chunk's sample and read with the
+    request's first token.  Off a TPU a chunk of 16 tokens runs each of
+    the 4 experts held on every row under a mask: 16 x 4 rows a layer,
+    the padded tail's too."""
+    model, params = _model(first_expert=0, experts_held=4)
+    eng = ServeEngine(model, params, _serve())
+    chunk = eng.config.prefill_chunk
+    assert dropless.routed_way(
+        chunk, TOPK, params["blocks"][0]["mlp"]["experts"],
+        EXPERTS) == "masked"
+    before = COUNTERS.snapshot()
+    lengths = (8, 2 * WINDOW + 1)
+    eng.generate([_prompt(n, i) for i, n in enumerate(lengths)], 3)
+    d = COUNTERS.delta_since(before)
+    chunks = d["serve.prefill_chunks"]["calls"]
+    assert chunks == sum(-(-n // chunk) for n in lengths) > 2
+    assert d["serve.moe.prefill_rows_multiplied"] == {
+        "calls": chunks * LAYERS, "bytes": chunks * LAYERS * chunk * 4}
+    assert not any(r.chunk_counts for r in eng.scheduler.requests)
 
 
 def test_decode_appends_the_held_experts_touched_to_its_tokens():

@@ -234,13 +234,13 @@ def test_on_a_tpu_every_call_under_the_ridge_follows_the_touched_list(
         native):
     """The chip-side half of the chooser, at the `chatgen` cell's expert
     shapes: whatever its assignments cover, a call of up to 128 rows
-    reads only the experts its live rows touched; a prefill chunk keeps
-    the grouped products; `DS_KERNEL_TOUCHED_EXPERTS=0` gives the two
-    old ways back."""
+    reads only the experts its live rows touched; a prefill chunk walks
+    its rows as one slab (PR 58); `DS_KERNEL_TOUCHED_EXPERTS=0` gives
+    the two old ways back under the ridge."""
     ex = {"gate": jax.ShapeDtypeStruct((64, 2048, 1408), jnp.bfloat16)}
     ways = lambda: [dropless.routed_way(t, 6, ex)
                     for t in (1, 10, 11, 32, 128, 129, 512)]
-    assert ways() == ["touched"] * 5 + ["grouped"] * 2
+    assert ways() == ["touched"] * 5 + ["slabs"] * 2
     picked = []
     real = dropless.experts_touched_only
     try:
@@ -256,9 +256,12 @@ def test_on_a_tpu_every_call_under_the_ridge_follows_the_touched_list(
     os.environ["DS_KERNEL_TOUCHED_EXPERTS"] = "0"
     try:
         assert ways() == ["grouped", "grouped", "masked", "masked",
-                          "masked", "grouped", "grouped"]
+                          "masked", "slabs", "slabs"]
+        os.environ["DS_KERNEL_GROUPED_EXPERTS"] = "0"
+        assert ways()[-2:] == ["grouped", "grouped"]
     finally:
         del os.environ["DS_KERNEL_TOUCHED_EXPERTS"]
+        os.environ.pop("DS_KERNEL_GROUPED_EXPERTS", None)
 
 
 def test_routed_ffn_equals_the_all_experts_masked_reference():

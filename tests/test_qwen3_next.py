@@ -507,7 +507,7 @@ def _drive(model, params, prompt, n_decode, chunk, slot=1, slots=3):
             params, caches, jnp.asarray(toks), np.int32(pos),
             np.int32(len(part)), jnp.asarray(np.append(table, slot)), *zero)
     rows.append(np.asarray(lg))
-    tok = int(tok)
+    tok = int(tok[0])       # behind routed FFNs [sample, rows multiplied]
     active = np.arange(slots) == slot
     tables = np.zeros((slots, W), np.int32)
     tables[slot] = table

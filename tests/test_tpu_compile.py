@@ -303,6 +303,22 @@ def _touched(tokens, e, d, f, dtype=jnp.bfloat16):
     return fn, shapes, info
 
 
+def _slab(tokens, rows, e, d, f, dtype=jnp.bfloat16):
+    """The product over a slab of `rows` rows as `experts_slabs` calls
+    it in a prefill chunk of `tokens` tokens."""
+    from deepspeed_tpu.moe.dropless import grouped_info
+
+    experts = {"gate": _sds((e, d, f), dtype), "up": _sds((e, d, f), dtype),
+               "down": _sds((e, f, d), dtype)}
+    info = grouped_info(tokens, rows, experts)
+    shapes = (_sds((rows, d), dtype), experts, _sds((e + 1,), jnp.int32))
+
+    def fn(xs, experts, offsets):
+        return registry.dispatch("grouped_experts", xs, experts, offsets,
+                                 info=info)
+    return fn, shapes, info
+
+
 def _ssm_step(slots=64, heads=64, p=64, n=128, dtype=jnp.float32):
     """`ssm_step` as `ssm_mix` calls it in a decode step: the Granite
     cell's shapes by default (64 slots, a float32 state of 64 heads x 64
@@ -479,6 +495,30 @@ CASES = [
     Case("touched_experts_T512_prefill",
          lambda: _touched(512, 16, 1024, 512),
          op="touched_experts", refused=r"512 rows are over the ridge"),
+    # the routed product of a prefill chunk's slab at the four MoE cells'
+    # shapes (longchat and chatgen: an expert's columns as one tile;
+    # mixedlen: tiles of 1,024; longctx: of 512), and what the shape
+    # rule sends to XLA's grouped products
+    Case("grouped_experts_C1280_E64_D2048_F512_longchat",
+         lambda: _slab(512, 1280, 64, 2048, 512), op="grouped_experts"),
+    Case("grouped_experts_C1024_E16_D4096_F4096_mixedlen",
+         lambda: _slab(512, 1024, 16, 4096, 4096), op="grouped_experts"),
+    Case("grouped_experts_C512_E16_D6144_F2048_longctx",
+         lambda: _slab(512, 512, 16, 6144, 2048), op="grouped_experts"),
+    Case("grouped_experts_C3072_E64_D2048_F1408_chatgen",
+         lambda: _slab(512, 3072, 64, 2048, 1408), op="grouped_experts"),
+    Case("grouped_experts_C256_E8_D1024_F512_fp32",
+         lambda: _slab(256, 256, 8, 1024, 512, jnp.float32),
+         op="grouped_experts"),
+    Case("grouped_experts_T64_decode",
+         lambda: _slab(64, 256, 16, 1024, 512), op="grouped_experts",
+         refused=r"64 rows are under the ridge"),
+    Case("grouped_experts_D1000_lanes",
+         lambda: _slab(512, 1024, 16, 1000, 512), op="grouped_experts",
+         refused=r"rows of 1000 values are not whole 128-lane"),
+    Case("grouped_experts_C16384_D8192_vmem",
+         lambda: _slab(2048, 16384, 16, 8192, 2048),
+         op="grouped_experts", refused=r"do not fit the kernel's\s+VMEM"),
     # the recurrence of a decode step at the Granite cell's shapes (a
     # slot's 2 MB of state as one block), a state of 16 MB a slot in
     # tiles of 16 heads, and what the shape rule sends to the oracle
@@ -521,7 +561,11 @@ def test_kernel_compiles_or_is_refused_by_name(case, one_chip, native):
             registry.resolve_impl(case.op, case.variant, impl="pallas",
                                   info=info)
         text = _compile_text(fn, shapes, one_chip)  # auto: the oracle
-        assert "tpu_custom_call" not in text
+        if case.op == "grouped_experts":
+            # the oracle's `lax.ragged_dot` is a custom call of XLA's own
+            assert "grouped_experts" not in text and "ragged-dot" in text
+        else:
+            assert "tpu_custom_call" not in text
         return
     assert "tpu_custom_call" in _compile_text(fn, shapes, one_chip)
 
@@ -598,6 +642,22 @@ def _serve_attention(q_len, slots):
      lambda: ("latent_attention", _latent()[2]), "pallas"),
     ("deepseek-v2-lite-d9.serve.chatgen.prefill",
      lambda: ("latent_attention", _latent(slots=1, q_len=512)[2]), "jnp"),
+    # PR 58: a prefill chunk's routed product, a slab of the rows held —
+    # 3,072 of 3,072, 1,024 of 4,096, 512 of 4,096, 1,280 of 5,120
+    ("deepseek-v2-lite-d9.serve.chatgen.prefill.routed",
+     lambda: ("grouped_experts", _slab(512, 3072, 64, 2048, 1408)[2]),
+     "pallas"),
+    ("command-a-plus-d4.serve.mixedlen.prefill.routed",
+     lambda: ("grouped_experts", _slab(512, 1024, 16, 4096, 4096)[2]),
+     "pallas"),
+    ("glm-5.2-d5.serve.longctx.prefill.routed",
+     lambda: ("grouped_experts", _slab(512, 512, 16, 6144, 2048)[2]),
+     "pallas"),
+    ("qwen3-next-80b-a3b-d12.serve.longchat.prefill.routed",
+     lambda: ("grouped_experts", _slab(512, 1280, 64, 2048, 512)[2]),
+     "pallas"),
+    ("deepseek-v2-lite-d9.serve.chatgen.decode.routed",
+     lambda: ("grouped_experts", _slab(32, 192, 64, 2048, 1408)[2]), "jnp"),
 ], ids=lambda v: v if isinstance(v, str) and "." in v else "")
 def test_auto_choice_for_each_benchmark_cell(cell, call, expect, native):
     """The trace-time half of "the same numbers": at each cell's shapes
@@ -863,11 +923,15 @@ def test_deepseek_cell_programs_compile_inside_one_chip(program, one_chip,
         moved = {"copy", "transpose", "convert", "gather", "reshape"}
         assert not moved & {op for op, _ in pools}, pools
     else:
-        assert calls and all("ragged" in ln for ln in calls)
+        # PR 58: every routed layer's product walks one slab of the
+        # chunk's 3,072 rows; XLA's own grouped products are gone
+        assert sum("grouped_experts" in ln for ln in calls) == layers - 1
+        assert len(calls) == layers - 1 and "ragged" not in text
     assert {layout for _, layout in pools} == {"1,0"}  # row-major
     m = compiled.memory_analysis()
     total = m.argument_size_in_bytes + m.temp_size_in_bytes + \
         m.output_size_in_bytes - m.alias_size_in_bytes
+    print(program, "temp", m.temp_size_in_bytes / 1e9, "total", total / 1e9)
     assert m.temp_size_in_bytes < 512 << 20
     assert total < 15.75e9 - 1.68e9 - 1.0e9, total
 
@@ -929,7 +993,10 @@ def test_glm_dsa_cell_programs_compile_inside_one_chip(program, one_chip,
     if program == "decode":
         assert sum("touched_experts" in ln for ln in calls) == layers - 1
     else:
-        assert calls and all("ragged" in ln for ln in calls)
+        # PR 58: the 4 routed layers' products walk slabs of 512 of the
+        # chunk's 4,096 rows; XLA's own grouped products are gone
+        assert sum("grouped_experts" in ln for ln in calls) == layers - 1
+        assert len(calls) == layers - 1 and "ragged" not in text
     pools = _hlo_by_shape(text)[(nblocks * bs, 640)]
     assert {layout for _, layout in pools} == {"1,0"}  # row-major
     m = compiled.memory_analysis()
@@ -1015,7 +1082,10 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     else:
         walks = [ln for ln in calls if "paged_attention_prefill_walk" in ln]
         assert len(walks) == 1
-        assert all("ragged" in ln for ln in calls if ln not in walks)
+        # PR 58: the 4 layers' routed products walk slabs of 1,024 of
+        # the chunk's 4,096 rows; XLA's own grouped products are gone
+        assert sum("grouped_experts" in ln for ln in calls) == layers
+        assert len(calls) == layers + 1 and "ragged" not in text
         wide = {(1, 16384, 8, 128), (8, 1, 16384, 128), (16, 512, 16384),
                 (1, 1, 16, 512, 16384)} & set(_hlo_by_shape(text))
         assert not wide, wide
@@ -1025,6 +1095,7 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     m = compiled.memory_analysis()
     total = m.argument_size_in_bytes + m.temp_size_in_bytes + \
         m.output_size_in_bytes - m.alias_size_in_bytes
+    print(program, "temp", m.temp_size_in_bytes / 1e9, "total", total / 1e9)
     assert m.temp_size_in_bytes < 1536 << 20
     assert total < 15.75e9 - 2.15e9 - 0.5e9, total
 
@@ -1188,10 +1259,11 @@ def test_qwen3_next_cell_programs_compile_inside_one_chip(program, one_chip,
         assert state_ops <= {"parameter", "custom-call", "get-tuple-element",
                              "bitcast"}, state_ops
     else:
-        # (beside them only the grouped products' own: ragged_dot)
+        # beside them the 12 layers' routed products over slabs of
+        # 1,280 of the chunk's 5,120 rows (PR 58), and none of XLA's own
         assert sum("paged_attention_prefill_walk" in ln for ln in calls) == 3
-        assert not any("gdn_step" in ln or "touched_experts" in ln
-                       for ln in calls)
+        assert sum("grouped_experts" in ln for ln in calls) == 12
+        assert len(calls) == 15 and "ragged" not in text
     m = compiled.memory_analysis()
     # all 18 state arrays and 6 pools are donated and aliased
     assert m.alias_size_in_bytes > 4.8e9
