@@ -855,15 +855,20 @@ def dispatch(name: str, *args, variant: str = "default",
     """Run op `name` through the registry's selection contract.
 
     Bumps `kernel.dispatches` / `kernel.fallbacks` at trace time (the
-    `dist.*` once-per-compiled-program convention)."""
+    `dist.*` once-per-compiled-program convention).  What it calls is
+    traced under the scope `kernel.<name>` (Pallas) or `oracle.<name>`:
+    the one place that names every Mosaic call of a compiled program,
+    which a device trace otherwise shows as `tpu_custom_call` all alike
+    (monitor/tracing.py `program_scopes`)."""
     op = get_kernel(name)
     chosen = resolve_impl(name, variant, impl=impl,
                           interpret_ok=interpret_ok, info=info)
     COUNTERS.add("kernel.dispatches" if chosen == "pallas"
                  else "kernel.fallbacks")
-    if chosen == "pallas":
-        return op.pallas(variant, *args, **kwargs)
-    return op.oracle(variant, *args, **kwargs)
+    scope, run = ("kernel", op.pallas) if chosen == "pallas" else \
+        ("oracle", op.oracle)
+    with jax.named_scope(f"{scope}.{name}"):
+        return run(variant, *args, **kwargs)
 
 
 def probe_report():

@@ -184,12 +184,11 @@ Instrumented sites:
   state twice and every slot's kept inputs twice; where it is the
   oracle, every slot's of both, twice; `serve.ssm.slots_live` — calls =
   decode steps, bytes = running slots x layers with a state (times a
-  layer's bytes a slot: what the live slots need); `serve.ssm.
-  prefill_tokens` — calls = prefill chunks, bytes = valid tokens
-  scanned; `serve.ssm.state_resets` — calls = slots zeroed on the
+  layer's bytes a slot: what the live slots need);
+  `serve.ssm.state_resets` — calls = slots zeroed on the
   device as a request is seated (serving/kv_cache.py `reset_state`) —
-  and `serve.gdn.state_bytes`, `serve.gdn.slots_live`, `serve.gdn.
-  prefill_tokens`, `serve.gdn.state_resets`, the same four name for
+  and `serve.gdn.state_bytes`, `serve.gdn.slots_live`,
+  `serve.gdn.state_resets`, the same three name for
   name, where the layers with a state are gated delta-rule mixers
   (models/qwen3_next.py; the kernel asked about is `gdn_step`), which
   emit no `serve.ssm.*`;

@@ -39,6 +39,19 @@ operations, and hands the same interval to a recorder when one is given.
 The serving loop's `serve.*` and the training dispatch's `train.*` phases
 go through it (docs/tutorials/tracing.md lists them).
 
+`program_scopes` is how a `jax.named_scope` reaches a reader of the
+DEVICE trace.  The profiler's `XLA Ops` events carry an instruction's HLO
+text and its device time and nothing of the scope it was written under;
+the compiled program does (`metadata={op_name="jit(decode)/attn/..."}`),
+and an event's text begins with the instruction's name, unique in its
+module.  So the program says the map — `ServeEngine.attach_tracing`
+records one `program_scopes` instant a compiled program, packed by
+`pack_scopes` — and `device_scope_times` joins it with a profile:
+device time by scope path, each nanosecond counted once
+(`tools/trace_report.py --xplane`; the benchmark's
+`readers/trace_scope_time.py` is the same join over its own reduction
+of the trace and imports nothing from here).
+
 `ServingSLO` rides the same clock: a sliding window over request
 lifecycle observations (TTFT, emitted tokens, queue depth, speculative
 accepts, sheds) emitting periodic `slo` monitor events; the p50/p99
@@ -49,8 +62,10 @@ when the window covers the lane.
 
 from __future__ import annotations
 
+import bisect
 import collections
 import json
+import re
 import threading
 import time
 import zlib
@@ -66,6 +81,277 @@ TRACE_FILE_PREFIX = "trace.rank"
 # subsystem categories (the merged trace's tid lanes)
 TRACE_CATEGORIES = ("train", "input", "wire", "ckpt", "autotune",
                     "watchdog", "serve", "slo")
+
+
+_INSTRUCTION = re.compile(
+    r'^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+) = (?P<rest>.*)$')
+_OP_NAME = re.compile(r'metadata=\{[^{}]*?op_name="(?P<op>[^"]*)"')
+_OPCODE = re.compile(r'\s*([\w\-]+)\(')
+_OPERAND = re.compile(r'%([\w.\-]+)')
+_WRAPPER = re.compile(r'^p?jit\(.*\)$')
+_EVENT_NAME = re.compile(r'^%?([\w.\-]+) = ')
+_DEVICE_PLANE = re.compile(r'^/device:TPU:\d+$')
+# instructions that never run as an operation of their own
+_NO_EVENT = {"parameter", "constant", "tuple", "get-tuple-element",
+             "bitcast"}
+
+
+def _opcode(rest: str) -> str:
+    """`fusion` from `f32[8]{0} fusion(...)`: what follows the result's
+    shape, which is one word or, for a tuple, a parenthesis."""
+    end, depth = rest.find(" "), 0
+    if rest.startswith("("):
+        for end, c in enumerate(rest):
+            depth += (c == "(") - (c == ")")
+            if not depth:
+                break
+        end += 1
+    m = _OPCODE.match(rest, end)
+    return m.group(1) if m else ""
+
+
+def _own_path(rest: str) -> Optional[str]:
+    """The scope path an instruction's own `op_name` gives, or None."""
+    op = _OP_NAME.search(rest)
+    if op is None:
+        return None
+    # (a merged instruction carries its parts' names joined by ";": the
+    # first is its own)
+    parts = op.group("op").split(";", 1)[0].split("/")
+    if not _WRAPPER.match(parts[0]):
+        return None
+    return "/".join(c for c in parts[1:-1] if not _WRAPPER.match(c))
+
+
+def _moved_paths(insts, paths) -> Dict[str, str]:
+    """Paths for one computation's instructions that have none of their
+    own, `insts` [(name, opcode, operands)] in the schedule's order: the
+    path of the first instruction, by that order, that consumes what the
+    instruction made (through any others without a path), else of the
+    last operand that has one; with `xla.<opcode>` appended."""
+    at = {name: i for i, (name, _, _) in enumerate(insts)}
+    users = [[] for _ in insts]
+    for i, (_, _, operands) in enumerate(insts):
+        for o in operands:
+            if o in at and at[o] < i:
+                users[at[o]].append(i)
+    first = [None] * len(insts)    # index of the first consumer with a path
+    for i in range(len(insts) - 1, -1, -1):
+        if insts[i][0] in paths:
+            first[i] = i
+        else:
+            first[i] = min((first[u] for u in users[i]
+                            if first[u] is not None), default=None)
+    made_by, out = {}, {}    # made_by: the path of what produced a value
+    for i, (name, opcode, operands) in enumerate(insts):
+        if name in paths:
+            made_by[name] = paths[name]
+            continue
+        made_by[name] = next((made_by[o] for o in reversed(operands)
+                              if made_by.get(o)), "")
+        path = paths[insts[first[i]][0]] if first[i] is not None \
+            else made_by[name]
+        if path and opcode not in _NO_EVENT:
+            out[name] = f"{path}/xla.{opcode}"
+    return out
+
+
+def program_scopes(compiled_text: str) -> Dict[str, str]:
+    """{instruction name: scope path} of a compiled program's text
+    (`jitted.lower(...).compile().as_text()`), over every instruction of
+    every computation of the module: its `op_name` without the `jit(...)`
+    / `pjit(...)` wrappers and without the trailing primitive —
+    `jit(decode)/attn/full_attend/kernel.grouped_attention/jit(_walk)/
+    pallas_call` is `attn/full_attend/kernel.grouped_attention`, and
+    `jit(decode)/add`, written under no scope, is "".  An `op_name` that
+    does not begin with the program's own `jit(...)` is no path (a
+    parameter carries its argument's name, the body of a reduction the
+    bare `reduce_sum`).
+
+    An instruction the COMPILER made has no `op_name`: the copies and
+    slices that move a weight into fast memory ahead of its use
+    (`copy-start` / `copy-done`, `slice-start` / `slice-done`, a
+    `ConcatBitcast`), whose `-done` halves are where a program waits for
+    its data — a fifth of a decode step of GPT-2 xl.  Such an
+    instruction is counted with what it serves: the path of the first
+    instruction, in the schedule's order, that consumes what it made,
+    else of what it consumed, with one component `xla.<opcode>` appended
+    (`attn/paged_attend/xla.slice-done`); left out where neither has a
+    path."""
+    out, insts = {}, []
+
+    def close():
+        out.update(_moved_paths(insts, out))
+        insts.clear()
+
+    for line in compiled_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            if line.startswith("}"):
+                close()
+            continue
+        name, rest = m.group("name"), m.group("rest")
+        path = _own_path(rest)
+        if path is not None:
+            out[name] = path
+        insts.append((name, _opcode(rest), _OPERAND.findall(rest)))
+    close()
+    return out
+
+
+def program_name(compiled_text: str) -> str:
+    """`jit_decode` from `HloModule jit_decode, ...`: what the trace's
+    `XLA Modules` line prints before the parenthesis."""
+    m = re.match(r"\s*HloModule\s+([\w.\-]+)", compiled_text)
+    return m.group(1) if m else ""
+
+
+def pack_scopes(scopes: Dict[str, str]) -> Dict[str, Any]:
+    """The map as an event carries it: the distinct paths in a table and
+    each instruction as an index into it."""
+    paths = sorted(set(scopes.values()))
+    at = {p: i for i, p in enumerate(paths)}
+    return {"paths": paths,
+            "instructions": {k: at[v] for k, v in scopes.items()}}
+
+
+def unpack_scopes(args: Dict[str, Any]) -> Dict[str, str]:
+    paths = args["paths"]
+    return {k: paths[i] for k, i in args["instructions"].items()}
+
+
+def scope_maps(events) -> Dict[str, Dict[str, str]]:
+    """{program: {instruction: path}} from a run's `program_scopes`
+    events (the newest of a program wins)."""
+    return {e["args"]["program"]: unpack_scopes(e["args"])
+            for e in events if e.get("name") == "program_scopes"}
+
+
+def stage_of(path: str, stages) -> str:
+    """The one of `stages` (a program's top-level scopes: `serving/
+    programs.py::STAGES`) a scope path lies under; "" for a path under
+    none."""
+    head = path.split("/", 1)[0]
+    return head if head in stages else ""
+
+
+def load_profile(path: str):
+    """`jax.profiler.ProfileData` of an `.xplane.pb` or `.xplane.pb.gz`."""
+    from jax.profiler import ProfileData
+
+    if path.endswith(".gz"):
+        import gzip
+
+        with gzip.open(path, "rb") as f:
+            return ProfileData.from_serialized_xspace(f.read())
+    return ProfileData.from_file(path)
+
+
+def device_scope_times(profile, events, window=None) -> Dict[str, Any]:
+    """Device time by scope path for every program that has a
+    `program_scopes` event among `events`, from a profile
+    (`jax.profiler.ProfileData` of an `.xplane.pb`).  -> {program:
+    {"runs": n, "run_ns": mean device duration of a run, "paths": {path:
+    ns a run}, "unscoped": {instruction: ns a run}}}.  A run is an `XLA
+    Modules` event of the program that lies wholly inside `window` ((lo,
+    hi) ns; None: the whole profile); its operations are the `XLA Ops`
+    events of the same device that start inside it.  Each nanosecond is
+    counted once: where one event lies inside another (a `while` and its
+    body) the innermost event whose instruction has a path takes the
+    time and the outer one keeps the rest; time under no path — an
+    instruction the map lacks, or one written under no scope — is path
+    "", and `unscoped` says whose it was."""
+    maps = scope_maps(events)
+    acc = {p: {"runs": 0, "run_ns": 0, "paths": collections.Counter(),
+               "unscoped": collections.Counter()} for p in maps}
+    lo, hi = window or (float("-inf"), float("inf"))
+    for plane in profile.planes:
+        if not _DEVICE_PLANE.match(plane.name):
+            continue
+        lines = {ln.name: [(int(e.start_ns), int(e.start_ns + e.duration_ns),
+                            e.name) for e in ln.events]
+                 for ln in plane.lines
+                 if ln.name in ("XLA Ops", "XLA Modules")}
+        runs = sorted((a, b, name.split("(", 1)[0])
+                      for a, b, name in lines.get("XLA Modules", ())
+                      if a >= lo and b <= hi)
+        starts = [r[0] for r in runs]
+        for a, b, program in runs:
+            if program in acc:
+                acc[program]["runs"] += 1
+                acc[program]["run_ns"] += b - a
+        stack, t = [], 0    # (end, program, path, instruction), open events
+
+        def credit(until):
+            if stack and until > t and stack[-1][1] in acc:
+                _, program, path, name = stack[-1]
+                acc[program]["paths"][path] += until - t
+                if not path:
+                    acc[program]["unscoped"][name] += until - t
+
+        for a, b, text in sorted(lines.get("XLA Ops", ()),
+                                 key=lambda e: (e[0], -e[1])):
+            while stack and stack[-1][0] <= a:
+                credit(stack[-1][0])
+                t = max(t, stack.pop()[0])
+            credit(a)
+            t = a
+            i = bisect.bisect_right(starts, a) - 1
+            program = runs[i][2] if i >= 0 and a < runs[i][1] else None
+            m = _EVENT_NAME.match(text)
+            name = m.group(1) if m else text[:40]
+            path = maps.get(program, {}).get(name, "")
+            if stack:   # an inner event without a path is its outer one's
+                b = min(b, stack[-1][0])
+                if not path:
+                    _, program, path, name = stack[-1]
+            stack.append((b, program, path, name))
+        while stack:
+            credit(stack[-1][0])
+            t = max(t, stack.pop()[0])
+    for prog in acc.values():
+        n = max(prog["runs"], 1)
+        prog["run_ns"] /= n
+        for key in ("paths", "unscoped"):
+            prog[key] = {k: v / n for k, v in prog[key].items()}
+    return acc
+
+
+def scope_table(times: Dict[str, Any], stages) -> List[str]:
+    """`device_scope_times` as the lines `tools/trace_report.py --xplane`
+    prints: for each program its runs and their mean device time, then
+    ms a run by stage (`stages`, in their order) and by every scope
+    beneath one that holds a thousandth of the run or more — the
+    layers' own scopes, the registry's `kernel.<op>`, the compiler's
+    `xla.<opcode>` — what no stage owns last, with the instructions that
+    make up most of it."""
+    out = []
+    for program, got in times.items():
+        if not got["runs"]:
+            out.append(f"{program}: no run in the profile")
+            continue
+        out.append(f"{program}: {got['runs']} runs, "
+                   f"{got['run_ns'] / 1e6:.3f} ms a run on the device")
+        by = collections.Counter()
+        for path, ns in got["paths"].items():
+            parts = tuple(path.split("/")) if stage_of(path, stages) else ()
+            for d in range(min(len(parts), 1), len(parts) + 1):
+                by[parts[:d]] += ns
+        for key in sorted(by, key=lambda k: (
+                not k, stages.index(k[0]) if k else 0, k)):
+            if len(key) > 1 and by[key] < 1e-3 * got["run_ns"]:
+                continue    # a scope under a thousandth of the run
+            label = "  " * max(len(key) - 1, 0) + (
+                key[-1] if key else "(no stage)")
+            out.append(f"  {label:<44}{by[key] / 1e6:>10.3f} ms "
+                       f"{100 * by[key] / got['run_ns']:>6.1f} %")
+        worst = sorted(got["unscoped"].items(), key=lambda kv: -kv[1])[:5]
+        for name, ns in worst:
+            out.append(f"      {name:<40}{ns / 1e6:>10.3f} ms")
+        idle = got["run_ns"] - sum(got["paths"].values())
+        out.append(f"  {'(no operation)':<44}{idle / 1e6:>10.3f} ms "
+                   f"{100 * idle / got['run_ns']:>6.1f} %")
+    return out
 
 
 def percentile_nearest_rank(sorted_vals: List[float], q: float) -> float:

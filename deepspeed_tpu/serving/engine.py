@@ -229,8 +229,7 @@ answers for the decode program's shapes: where the recurrence is the
 every slot's convolution inputs twice; where it is the oracle, every
 slot's of both, twice), `serve.ssm.slots_live` (calls
 = decode steps, bytes = running slots x layers with a state),
-`serve.ssm.prefill_tokens` (calls = prefill chunks, bytes = valid
-tokens scanned), `serve.ssm.state_resets` (calls = slots zeroed) —
+`serve.ssm.state_resets` (calls = slots zeroed) —
 under `serve.gdn.*` in place of `serve.ssm.*`, name for name, where the
 layers with a state are gated delta-rule mixers (`gdn_step` the kernel
 asked about) — and
@@ -865,14 +864,90 @@ class ServeEngine:
         (TTFT, tokens, queue depth, accept rate, sheds) and ticked at
         every step boundary so its windows never have sampling holes.
         When a watchdog is attached (before or after this call) the
-        tracer's tail doubles as its trip-snapshot flight recorder."""
+        tracer's tail doubles as its trip-snapshot flight recorder.
+
+        A tracer is also told, once and here, which instruction of each
+        compiled program was written under which scope: one instant
+        `program_scopes` a program (`_record_program_scopes`), never
+        sampled out, for whoever reads a device trace of the run beside
+        the recorder's file.  That compiles each program once more (a
+        load where the persistent compile cache holds it); without a
+        tracer nothing is lowered, compiled or parsed."""
         self._tracer = tracer
         self._slo = slo
         self.scheduler.tracer = tracer
         if slo is not None and getattr(slo, "tracer", None) is None:
             slo.tracer = tracer
         if tracer is not None and self._watchdog is not None:
-            self._watchdog.set_flight_recorder(tracer.last_events)
+            self._watchdog.set_flight_recorder(self._flight_tail)
+        if tracer is not None:
+            self._record_program_scopes(tracer)
+
+    def _flight_tail(self) -> list:
+        """The tracer's newest events as a watchdog's trip snapshot
+        ships them: what the wedged step was doing, without the
+        programs' maps (tens to hundreds of KB each, and no timeline)."""
+        return [e for e in self._tracer.last_events()
+                if e.get("name") != "program_scopes"]
+
+    def _program_calls(self) -> dict:
+        """{name: (jitted program, its arguments as the engine hands
+        them over)} for the programs this engine runs — `prefill`, and
+        `decode` and `seat`, or `verify` where it drafts — from what it
+        holds: the weights and the cache as they lie, the slot state's
+        rows, the schedule's sizes.  A description, so it can drift
+        from what `_prefill_chunk`, the decode launch and `seat` pass:
+        tests/test_program_scopes.py holds every argument's shape,
+        dtype and weak type to a real call's, family by family."""
+        c = self.config
+        of = jax.ShapeDtypeStruct
+        scalar = lambda dtype: of((), dtype)
+        host = self._slots.host
+        rows = {name: of(a.shape, a.dtype) for name, a in host.items()}
+        tokens = of((c.max_batch,), jnp.int32)
+        held = (self.params, self.kv.caches)
+        # a request's table as `prefill` takes it: behind the entries of
+        # layers with a state, the slot
+        table = of((host["tables"].shape[1] + bool(self._state_layers),),
+                   jnp.int32)
+        calls = {"prefill": (self.programs["prefill"], held + (
+            of((1, c.prefill_chunk), jnp.int32), scalar(jnp.int32),
+            scalar(jnp.int32), table, scalar(jnp.float32),
+            scalar(jnp.int32), scalar(jnp.uint32)))}
+        if self._serial:
+            k = int(c.draft_len)
+            calls["verify"] = (self.programs["verify"], held + (
+                of((c.max_batch, k + 1), jnp.int32), rows["positions"],
+                of((c.max_batch,), jnp.int32), rows["active"],
+                rows["tables"], rows["temperatures"], rows["top_ks"],
+                rows["seeds"]))
+            return calls
+        calls["decode"] = (self.programs["decode"],
+                           held + (tokens,) + tuple(rows.values()))
+        # `prefill`'s sample as it lies: two entries behind routed FFNs
+        calls["seat"] = (self.programs["seat"], (
+            tokens, scalar(jnp.int32),
+            of((2,) if self._routed_layers else (), jnp.int32)))
+        return calls
+
+    def _record_program_scopes(self, tracer) -> None:
+        """One `program_scopes` instant a program: `program`, the name
+        the device trace's `XLA Modules` line gives its runs
+        (`jit_decode`); `paths` and `instructions`, every instruction of
+        the compiled module that was written under a scope as an index
+        into the table of scope paths (monitor/tracing.py
+        `program_scopes`, `pack_scopes`); `seconds`, what lowering,
+        compiling (or loading) and parsing it took."""
+        from ..monitor import tracing
+
+        for name, (program, args) in self._program_calls().items():
+            t0 = time.perf_counter()
+            text = program.lower(*args).compile().as_text()
+            packed = tracing.pack_scopes(tracing.program_scopes(text))
+            tracer.instant(
+                "program_scopes", "serve",
+                program=tracing.program_name(text) or f"jit_{name}",
+                seconds=round(time.perf_counter() - t0, 3), **packed)
 
     def _req_tracer(self, req: Request):
         """The tracer, iff this request's rid is sampled in."""
@@ -1043,8 +1118,6 @@ class ServeEngine:
                     self.kv.reset_state(req.slot)
             # behind the table's entries: where the request's state lies
             table = np.append(table, np.int32(req.slot))
-            COUNTERS.add(f"{self._state_counters}.prefill_tokens",
-                         nbytes=n_valid)
         tok, _logits, caches = self.programs["prefill"](
             self.params, self.kv.caches, jnp.asarray(tokens),
             np.int32(req.prefill_pos), np.int32(n_valid),
@@ -1570,7 +1643,7 @@ class ServeEngine:
         flight."""
         self._watchdog = watchdog
         if self._tracer is not None:
-            watchdog.set_flight_recorder(self._tracer.last_events)
+            watchdog.set_flight_recorder(self._flight_tail)
         watchdog.register_threads(
             "serving",
             lambda: [t for t in (self._worker,)

@@ -691,21 +691,28 @@ def _visible(at, q_pos, window: int):
 def _parallel_block(spec, cfg, p, x, kv, addr, s, layer: int, count: str):
     """One norm, attention and the routed FFN the spec describes on the
     same h, both added to x: -> (x, kv, the layer's `count`)."""
-    h = _norm(spec, x, p["ln1"])
-    with jax.named_scope("swa_attend" if spec.window_of(layer)
-                         else "full_attend"):
-        attn, *kv = _grouped_attend(spec, cfg, p["attn"], h, *kv, addr, s,
-                                    layer)
-    live = addr.q_pos.reshape(-1) >= 0
-    if count == "touched":   # as a decode step's program has had it
-        y, n = cohere2_moe.expert_ffn(spec, cfg, p["mlp"], h, live=live)
-    else:
-        y, idx, _, held = cohere2_moe.routed_ffn(
-            spec, cfg, p["mlp"], h.reshape(-1, h.shape[-1]), live)
-        y = y.reshape(h.shape)
-        n = rows_multiplied(idx, p["mlp"]["experts"], cfg.num_experts, held,
-                            live)
-    return x + attn + y, tuple(kv), n
+    with jax.named_scope("attn"):    # the shared norm goes with it
+        h = _norm(spec, x, p["ln1"])
+        with jax.named_scope("swa_attend" if spec.window_of(layer)
+                             else "full_attend"):
+            attn, *kv = _grouped_attend(spec, cfg, p["attn"], h, *kv, addr,
+                                        s, layer)
+    with jax.named_scope("ffn"):
+        live = addr.q_pos.reshape(-1) >= 0
+        if count == "touched":   # as a decode step's program has had it
+            y, n = cohere2_moe.expert_ffn(spec, cfg, p["mlp"], h, live=live)
+        else:
+            y, idx, _, held = cohere2_moe.routed_ffn(
+                spec, cfg, p["mlp"], h.reshape(-1, h.shape[-1]), live)
+            y = y.reshape(h.shape)
+            n = rows_multiplied(idx, p["mlp"]["experts"], cfg.num_experts,
+                                held, live)
+    # each branch's residual add under its own stage, where the program
+    # has had them: behind both branches
+    with jax.named_scope("attn"):
+        x = x + attn
+    with jax.named_scope("ffn"):
+        return x + y, tuple(kv), n
 
 
 def _norm(spec, x, p):
@@ -757,47 +764,56 @@ def block(spec, cfg, p, x, kv, addr, s, layer: int = 0, sel=None,
     if spec.residual == "parallel":
         return _parallel_block(spec, cfg, p, x, kv, addr, s, layer,
                                count) + (None,)
-    h = _norm(spec, x, p["ln1"])
-    if spec.layer_indexers:
-        from .sparse import sparse_latent_attend
+    mixer = spec.mixer_of(layer)
+    with jax.named_scope("attn" if mixer == "attention" else "state"):
+        h = _norm(spec, x, p["ln1"])
+        if spec.layer_indexers:
+            from .sparse import sparse_latent_attend
 
-        attn, kv, sel = sparse_latent_attend(
-            spec, cfg, p["attn"], h, kv, addr, s, layer, sel, _kv_write)
-    elif spec.mixer_of(layer) == "ssm":
-        with jax.named_scope("ssm.step" if h.shape[1] == 1 else "ssm.scan"):
-            attn, *kv = _ssm_mix(spec, p["ssm"], h, *kv, addr)
-    elif spec.mixer_of(layer) == "gdn":
-        from ..models.qwen3_next import gdn_mix
+            attn, kv, sel = sparse_latent_attend(
+                spec, cfg, p["attn"], h, kv, addr, s, layer, sel, _kv_write)
+        elif mixer == "ssm":
+            with jax.named_scope("ssm.step" if h.shape[1] == 1
+                                 else "ssm.scan"):
+                attn, *kv = _ssm_mix(spec, p["ssm"], h, *kv, addr)
+        elif mixer == "gdn":
+            from ..models.qwen3_next import gdn_mix
 
-        with jax.named_scope("gdn.step" if h.shape[1] == 1 else "gdn.scan"):
-            attn, *kv = _ssm_mix(spec, p["gdn"], h, *kv, addr, gdn_mix)
-    elif spec.attention == "grouped":
-        with jax.named_scope("gated_attend" if spec.attn_gate
-                             else "full_attend"):
-            attn, *kv = _grouped_attend(spec, cfg, p["attn"], h, *kv, addr,
-                                        s, layer)
-    elif spec.attention == "paged":
-        attn, *kv = _paged_attend(cfg, p["attn"], h, *kv, addr, s)
-    elif spec.attention == "eva":
-        attn, *kv = _eva_attend(spec, cfg, p["attn"], h, *kv, addr, s)
-    else:
-        with jax.named_scope("mla_attend"):
-            attn, *kv = _latent_attend(cfg, p["attn"], h, *kv, addr, s)
-    x = x + _scaled(attn, spec.residual_scale)
-    h = _norm(spec, x, p["ln2"])
-    if spec.ffn != "routed_experts" or layer < spec.dense_layers:
-        return (x + _scaled(_ffn(spec, p["mlp"], h), spec.residual_scale),
-                tuple(kv), None, sel)
-    live = addr.q_pos.reshape(-1) >= 0
-    y, idx, held_of, held = cohere2_moe.routed_ffn(
-        spec, cfg, p["mlp"], h.reshape(-1, h.shape[-1]), live)
-    # reshaped before the count, as this block's programs have it (the
-    # order of independent operations is part of their StableHLO)
-    y = y.reshape(h.shape)
-    touched = experts_touched(idx, live, held_of, held) \
-        if count == "touched" else rows_multiplied(
-            idx, p["mlp"]["experts"], cfg.num_experts, held, live)
-    return x + y, tuple(kv), touched, sel
+            with jax.named_scope("gdn.step" if h.shape[1] == 1
+                                 else "gdn.scan"):
+                attn, *kv = _ssm_mix(spec, p["gdn"], h, *kv, addr, gdn_mix)
+        elif spec.attention == "grouped":
+            with jax.named_scope("gated_attend" if spec.attn_gate
+                                 else "full_attend"):
+                attn, *kv = _grouped_attend(spec, cfg, p["attn"], h, *kv,
+                                            addr, s, layer)
+        elif spec.attention == "paged":
+            with jax.named_scope("paged_attend"):
+                attn, *kv = _paged_attend(cfg, p["attn"], h, *kv, addr, s)
+        elif spec.attention == "eva":
+            with jax.named_scope("eva_attend"):
+                attn, *kv = _eva_attend(spec, cfg, p["attn"], h, *kv, addr,
+                                        s)
+        else:
+            with jax.named_scope("mla_attend"):
+                attn, *kv = _latent_attend(cfg, p["attn"], h, *kv, addr, s)
+        x = x + _scaled(attn, spec.residual_scale)
+    with jax.named_scope("ffn"):
+        h = _norm(spec, x, p["ln2"])
+        if spec.ffn != "routed_experts" or layer < spec.dense_layers:
+            return (x + _scaled(_ffn(spec, p["mlp"], h),
+                                spec.residual_scale),
+                    tuple(kv), None, sel)
+        live = addr.q_pos.reshape(-1) >= 0
+        y, idx, held_of, held = cohere2_moe.routed_ffn(
+            spec, cfg, p["mlp"], h.reshape(-1, h.shape[-1]), live)
+        # reshaped before the count, as this block's programs have it
+        # (the order of independent operations is part of their StableHLO)
+        y = y.reshape(h.shape)
+        touched = experts_touched(idx, live, held_of, held) \
+            if count == "touched" else rows_multiplied(
+                idx, p["mlp"]["experts"], cfg.num_experts, held, live)
+        return x + y, tuple(kv), touched, sel
 
 
 # -- head -------------------------------------------------------------------

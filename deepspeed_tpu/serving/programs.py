@@ -84,6 +84,18 @@ of its own — it is host book-keeping in the engine.  Its `verify`
 program is not built (`draft_len` must be 0), nor are quantized weights
 or quantized rows for it.
 
+Stages: every operation of the four programs is written under exactly
+one of six `jax.named_scope`s, the same in every family — `embed` (the
+weights' dequantisation, the embedding, the call's addressing), `attn`
+or `state` and `ffn` (a block's two halves, serving/layers.py `block`),
+`head` (final norm and logits) and `sample` (the sampling tail and
+whatever a program appends to its tokens; `seat`) — with the layers' own
+scopes (`full_attend`, `moe_experts`, `kernel.<op>` ...) beneath them.  A
+scope is metadata on the instructions and nothing else; `ServeEngine.
+attach_tracing` reads it back from the compiled programs
+(`monitor/tracing.py::program_scopes`) so that a reader of the device
+trace can name each instruction's stage.
+
 The paged attention math deliberately mirrors models/generation.py
 `_block_with_cache` op for op (serving/layers.py) so greedy serving
 output of a GPT is bit-identical to `generate()` when the cache lengths
@@ -120,6 +132,10 @@ from ..utils.logging import logger
 from . import layers
 
 QUANT_MODES = ("none", "int8", "int4")
+
+# the top-level scopes every operation of a program lies under (the
+# module's docstring, "Stages"; serving/layers.py `block` writes three)
+STAGES = ("embed", "attn", "state", "ffn", "head", "sample")
 
 # how the cache stores K/V: "dense" = at the cache arrays' own dtype
 # (the dtype is a runtime property of the arrays, not program
@@ -245,14 +261,16 @@ def seat(tokens, slot, tok):
     """A request joins the decode batch: its first token, sampled by
     `prefill` and still on the device, becomes its slot's entry of the
     vector the next `decode` takes as `tokens`."""
-    return tokens.at[slot].set(tok)
+    with jax.named_scope("sample"):
+        return tokens.at[slot].set(tok)
 
 
 @jax.jit
 def seat_counted(tokens, slot, tok):
     """`seat` behind routed FFNs, where `prefill`'s sample is the first
     of two entries."""
-    return tokens.at[slot].set(tok[0])
+    with jax.named_scope("sample"):
+        return tokens.at[slot].set(tok[0])
 
 
 # -- qwZ weight store -------------------------------------------------------
@@ -519,23 +537,27 @@ class ServeProgramBuilder:
             assignment rows the chunk's routed products multiplied
             summed over those layers (int32 [2]): it rides the one
             transfer the engine makes a request."""
-            params = self._maybe_dequant(params)
-            abs_pos = pos + jnp.arange(C)
-            x = layers.embed_chunk(spec, params, tokens, abs_pos)
-            addr = layers.address_chunk(spec, s, table, pos, abs_pos,
-                                        n_valid)
+            with jax.named_scope("embed"):
+                params = self._maybe_dequant(params)
+                abs_pos = pos + jnp.arange(C)
+                x = layers.embed_chunk(spec, params, tokens, abs_pos)
+                addr = layers.address_chunk(spec, s, table, pos, abs_pos,
+                                            n_valid)
             x, new_caches, rows = layers.blocks(spec, cfg, params, x, caches,
                                                 addr, s, count="rows")
-            x = layers.final_norm(spec, params, x)
-            last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
-            logits = layers.logits(spec, params, last[:, 0, :])  # [1, V]
-            tok = sample_rows(
-                layers.sampled(spec, logits), temperature[None],
-                top_k[None], jnp.ones((1,), bool),
-                lambda: _row_key(seed, pos + n_valid)[None])[0]
-            if rows:
-                tok = jnp.stack([tok, sum(rows)])
-            return tok, logits[0], new_caches
+            with jax.named_scope("head"):
+                x = layers.final_norm(spec, params, x)
+                last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1,
+                                                    axis=1)
+                logits = layers.logits(spec, params, last[:, 0, :])  # [1, V]
+            with jax.named_scope("sample"):
+                tok = sample_rows(
+                    layers.sampled(spec, logits), temperature[None],
+                    top_k[None], jnp.ones((1,), bool),
+                    lambda: _row_key(seed, pos + n_valid)[None])[0]
+                if rows:
+                    tok = jnp.stack([tok, sum(rows)])
+                return tok, logits[0], new_caches
 
         return prefill
 
@@ -547,12 +569,15 @@ class ServeProgramBuilder:
         `touched` holds, for each layer with a routed FFN, how many of
         its experts the active slots chose."""
         cfg, spec, s = self.model.config, self.spec, self.schedule
-        x = layers.embed_step(spec, params, tokens, positions)
-        addr = layers.address_step(spec, s, tables, positions, active)
+        with jax.named_scope("embed"):
+            x = layers.embed_step(spec, params, tokens, positions)
+            addr = layers.address_step(spec, s, tables, positions, active)
         x, new_caches, touched = layers.blocks(spec, cfg, params, x, caches,
                                                addr, s)
-        x = layers.final_norm(spec, params, x)
-        return layers.logits(spec, params, x[:, -1, :]), new_caches, touched
+        with jax.named_scope("head"):
+            x = layers.final_norm(spec, params, x)
+            return (layers.logits(spec, params, x[:, -1, :]), new_caches,
+                    touched)
 
     def _build_decode(self):
         spec = self.spec
@@ -573,16 +598,18 @@ class ServeProgramBuilder:
             step; the next step's (tokens [R], positions [R]) are the
             samples as they lie and the positions moved on by one for
             the active slots."""
-            params = self._maybe_dequant(params)
+            with jax.named_scope("embed"):
+                params = self._maybe_dequant(params)
             logits, new_caches, touched = self.step_logits(
                 params, caches, tokens, positions, active, tables)
-            toks = sample_rows(
-                layers.sampled(spec, logits), temperatures, top_ks, active,
-                lambda: jax.vmap(_row_key)(seeds, positions + 1))
-            ahead = (toks, positions + active.astype(positions.dtype))
-            if touched:
-                toks = jnp.concatenate([toks, sum(touched)[None]])
-            return toks, new_caches, ahead
+            with jax.named_scope("sample"):
+                toks = sample_rows(
+                    layers.sampled(spec, logits), temperatures, top_ks,
+                    active, lambda: jax.vmap(_row_key)(seeds, positions + 1))
+                ahead = (toks, positions + active.astype(positions.dtype))
+                if touched:
+                    toks = jnp.concatenate([toks, sum(touched)[None]])
+                return toks, new_caches, ahead
 
         return decode
 
@@ -614,27 +641,32 @@ class ServeProgramBuilder:
             caches): toks[r, i] is the token the target emits at
             absolute position positions[r] + 1 + i given the prefix
             through column i."""
-            params = self._maybe_dequant(params)
             R = tokens.shape[0]
-            abs_pos = positions[:, None] + jnp.arange(T)[None, :]
-            # pad rows past the position table clamp (their writes land
-            # in trash and their samples are discarded by the engine)
-            x = layers.embed_chunk(spec, params, tokens, abs_pos)  # [R, T, D]
-            addr = layers.address_grid(spec, s, tables, abs_pos, active,
-                                       n_draft)
+            with jax.named_scope("embed"):
+                params = self._maybe_dequant(params)
+                abs_pos = positions[:, None] + jnp.arange(T)[None, :]
+                # pad rows past the position table clamp (their writes
+                # land in trash and their samples are discarded by the
+                # engine)
+                x = layers.embed_chunk(spec, params, tokens,
+                                       abs_pos)              # [R, T, D]
+                addr = layers.address_grid(spec, s, tables, abs_pos, active,
+                                           n_draft)
             x, new_caches, _ = layers.blocks(spec, cfg, params, x, caches,
                                              addr, s)
-            x = layers.final_norm(spec, params, x)
-            logits = layers.logits(
-                spec, params, x.reshape(R * T, -1))       # [R * T, V]
+            with jax.named_scope("head"):
+                x = layers.final_norm(spec, params, x)
+                logits = layers.logits(
+                    spec, params, x.reshape(R * T, -1))       # [R * T, V]
 
             def rows(a):             # a slot's value at each of its T rows
                 return jnp.repeat(a, T)
 
-            toks = sample_rows(
-                logits, rows(temperatures), rows(top_ks), rows(active),
-                lambda: jax.vmap(_row_key)(rows(seeds),
-                                           abs_pos.reshape(-1) + 1))
-            return toks.reshape(R, T), new_caches
+            with jax.named_scope("sample"):
+                toks = sample_rows(
+                    logits, rows(temperatures), rows(top_ks), rows(active),
+                    lambda: jax.vmap(_row_key)(rows(seeds),
+                                               abs_pos.reshape(-1) + 1))
+                return toks.reshape(R, T), new_caches
 
         return verify

@@ -568,7 +568,7 @@ def test_counters_of_the_state_space_layers(way):
     steps = d["serve.decode_steps"]["calls"]
     assert d["serve.ssm.state_resets"] == {"calls": 2, "bytes": 0}
     # chunks of 8: one, and three
-    assert d["serve.ssm.prefill_tokens"] == {"calls": 4, "bytes": 27}
+    assert d["serve.prefill_chunks"] == {"calls": 4, "bytes": 27}
     # as the program is built.  The oracle: every step streams every
     # slot's state, in and out, live or not.  The kernel: the float32
     # state of the running slots (10 over the steps, of 3 a step) and

@@ -666,7 +666,7 @@ def test_counters_of_the_delta_layers():
     assert not [k for k in d if k.startswith("serve.ssm.")]
     assert d["serve.gdn.state_resets"] == {"calls": 2, "bytes": 0}
     # chunks of 8: 9 -> 8 + 1, 18 -> 8 + 8 + 2
-    assert d["serve.gdn.prefill_tokens"] == {"calls": 5, "bytes": 27}
+    assert d["serve.prefill_chunks"] == {"calls": 5, "bytes": 27}
     steps = d["serve.decode_steps"]["calls"]
     a_slot = HV * DK * DV * 4 + (TAPS - 1) * CONV * 4
     # the oracle steps all four slots' state in six layers, in and out

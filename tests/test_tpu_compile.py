@@ -562,8 +562,12 @@ def test_kernel_compiles_or_is_refused_by_name(case, one_chip, native):
                                   info=info)
         text = _compile_text(fn, shapes, one_chip)  # auto: the oracle
         if case.op == "grouped_experts":
-            # the oracle's `lax.ragged_dot` is a custom call of XLA's own
-            assert "grouped_experts" not in text and "ragged-dot" in text
+            # the oracle's `lax.ragged_dot` is a custom call of XLA's own;
+            # the op's name stands in the text as the registry's scope
+            # alone (PR 59: `oracle.grouped_experts`), never as a kernel
+            assert "kernel.grouped_experts" not in text
+            assert "grouped_experts" not in text.replace(
+                "oracle.grouped_experts", "") and "ragged-dot" in text
         else:
             assert "tpu_custom_call" not in text
         return
@@ -764,6 +768,34 @@ def _hlo_by_shape(text):
     return by_shape
 
 
+def _kernels_by_scope(text):
+    """{registry op: its Mosaic calls} of an optimised program's text,
+    from the scope each `tpu_custom_call` was written under (PR 59:
+    `kernels/registry.py::dispatch` wraps what it calls in
+    `kernel.<op>`, and `monitor/tracing.py::program_scopes` reads the
+    compiled instruction's path back): every one has such a path, under
+    one of the serving programs' stages, and none lies under an
+    `oracle.`."""
+    import collections
+    import re
+
+    from deepspeed_tpu.monitor import tracing
+
+    scopes = tracing.program_scopes(text)
+    ops = collections.Counter()
+    for ln in text.splitlines():
+        if "tpu_custom_call" not in ln:
+            continue
+        name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", ln).group(1)
+        parts = scopes[name].split("/")
+        assert parts[0] in ("attn", "state", "ffn"), (name, scopes[name])
+        assert not any(c.startswith("oracle.") for c in parts), scopes[name]
+        op, = [c[len("kernel."):] for c in parts if c.startswith("kernel.")]
+        assert op in registry.KERNEL_OPS, scopes[name]
+        ops[op] += 1
+    return dict(ops)
+
+
 def test_decode_layer_uses_the_pool_as_it_lies(one_chip, native):
     """One layer of GPT-2 xl's `decode` at the chat cell's shapes (16
     slots, 513 blocks of 16, a table 64 wide, bf16): the pool enters
@@ -796,6 +828,7 @@ def test_decode_layer_uses_the_pool_as_it_lies(one_chip, native):
         on((slots,), jnp.int32), on((slots,), jnp.uint32),
     ).compile().as_text()
     assert text.count("tpu_custom_call") == 1
+    assert _kernels_by_scope(text) == {"paged_attention": 1}
     by_shape = _hlo_by_shape(text)
     pool_ops = by_shape[(rows, pool_width(H, DH))]
     assert {"parameter", "scatter"} <= {op for op, _ in pool_ops}
@@ -845,6 +878,7 @@ def test_evabyte_decode_walks_the_pool_as_it_lies(one_chip, native):
     ).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == layers
+    assert _kernels_by_scope(text) == {"eva_attention": layers}
     by_shape = _hlo_by_shape(text)
     pool_ops = by_shape[(rows, 4096)]
     assert {"parameter", "scatter"} <= {op for op, _ in pool_ops}
@@ -919,6 +953,8 @@ def test_deepseek_cell_programs_compile_inside_one_chip(program, one_chip,
         assert sum("touched_experts" in ln for ln in calls) == layers - 1
         assert sum("paged_attention_walk" in ln for ln in calls) == layers
         assert len(calls) == 2 * layers - 1
+        assert _kernels_by_scope(text) == {
+            "touched_experts": layers - 1, "latent_attention": layers}
         assert (slots * width, bs, 640) not in by_shape
         moved = {"copy", "transpose", "convert", "gather", "reshape"}
         assert not moved & {op for op, _ in pools}, pools
@@ -927,6 +963,7 @@ def test_deepseek_cell_programs_compile_inside_one_chip(program, one_chip,
         # chunk's 3,072 rows; XLA's own grouped products are gone
         assert sum("grouped_experts" in ln for ln in calls) == layers - 1
         assert len(calls) == layers - 1 and "ragged" not in text
+        assert _kernels_by_scope(text) == {"grouped_experts": layers - 1}
     assert {layout for _, layout in pools} == {"1,0"}  # row-major
     m = compiled.memory_analysis()
     total = m.argument_size_in_bytes + m.temp_size_in_bytes + \
@@ -992,11 +1029,13 @@ def test_glm_dsa_cell_programs_compile_inside_one_chip(program, one_chip,
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     if program == "decode":
         assert sum("touched_experts" in ln for ln in calls) == layers - 1
+        assert _kernels_by_scope(text) == {"touched_experts": layers - 1}
     else:
         # PR 58: the 4 routed layers' products walk slabs of 512 of the
         # chunk's 4,096 rows; XLA's own grouped products are gone
         assert sum("grouped_experts" in ln for ln in calls) == layers - 1
         assert len(calls) == layers - 1 and "ragged" not in text
+        assert _kernels_by_scope(text) == {"grouped_experts": layers - 1}
     pools = _hlo_by_shape(text)[(nblocks * bs, 640)]
     assert {layout for _, layout in pools} == {"1,0"}  # row-major
     m = compiled.memory_analysis()
@@ -1079,6 +1118,8 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
         assert sum("touched_experts" in ln for ln in calls) == layers
         assert sum("paged_attention_walk" in ln for ln in calls) == 1
         assert (slots, 16384, 8, 128) not in _hlo_by_shape(text)
+        assert _kernels_by_scope(text) == {"touched_experts": layers,
+                                           "grouped_attention": 1}
     else:
         walks = [ln for ln in calls if "paged_attention_prefill_walk" in ln]
         assert len(walks) == 1
@@ -1086,6 +1127,8 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
         # the chunk's 4,096 rows; XLA's own grouped products are gone
         assert sum("grouped_experts" in ln for ln in calls) == layers
         assert len(calls) == layers + 1 and "ragged" not in text
+        assert _kernels_by_scope(text) == {"grouped_experts": layers,
+                                           "grouped_attention": 1}
         wide = {(1, 16384, 8, 128), (8, 1, 16384, 128), (16, 512, 16384),
                 (1, 1, 16, 512, 16384)} & set(_hlo_by_shape(text))
         assert not wide, wide
@@ -1167,6 +1210,8 @@ def test_granite_hybrid_cell_programs_compile_inside_one_chip(program,
         assert sum("ssm_step_live" in ln for ln in calls) == 36
         assert sum("paged_attention_walk" in ln for ln in calls) == 4
         assert (slots, seq, 8, 64) not in _hlo_by_shape(text)
+        assert _kernels_by_scope(text) == {"ssm_step": 36,
+                                           "grouped_attention": 4}
         # a layer's state enters, goes through the kernel and leaves:
         # nothing copies it, nothing else of its size is built
         # (the kernel takes it as [slots, 32, 128, 128]: the same bytes)
@@ -1254,6 +1299,8 @@ def test_qwen3_next_cell_programs_compile_inside_one_chip(program, one_chip,
         assert sum("paged_attention_walk" in ln for ln in calls) == 3
         assert sum("touched_experts" in ln for ln in calls) == 12
         assert (slots, seq, 2, 256) not in _hlo_by_shape(text)
+        assert _kernels_by_scope(text) == {
+            "gdn_step": 9, "grouped_attention": 3, "touched_experts": 12}
         state_ops = {op for op, _ in _hlo_by_shape(text)[
             (slots, 32, 128, 128)]}
         assert state_ops <= {"parameter", "custom-call", "get-tuple-element",
@@ -1264,6 +1311,8 @@ def test_qwen3_next_cell_programs_compile_inside_one_chip(program, one_chip,
         assert sum("paged_attention_prefill_walk" in ln for ln in calls) == 3
         assert sum("grouped_experts" in ln for ln in calls) == 12
         assert len(calls) == 15 and "ragged" not in text
+        assert _kernels_by_scope(text) == {"grouped_attention": 3,
+                                           "grouped_experts": 12}
     m = compiled.memory_analysis()
     # all 18 state arrays and 6 pools are donated and aliased
     assert m.alias_size_in_bytes > 4.8e9

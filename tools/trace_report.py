@@ -20,8 +20,16 @@ first segment — same host, same wall.  Lanes from DIFFERENT run dirs
 (e.g. a training run beside a serving run) are each shifted to start
 at 0 and stacked by pid block.
 
+With `--xplane FILE` (a profiler capture of the same run, `.xplane.pb`
+or `.xplane.pb.gz`) it prints instead, for each program that left a
+`program_scopes` event in the run dir's trace file, device ms a run by
+stage and by every scope beneath one: the join of the program's own map
+with the device's own events (`monitor/tracing.py::device_scope_times`;
+docs/tutorials/tracing.md, "Device time by stage").
+
 Usage:
     python tools/trace_report.py RUN_DIR [RUN_DIR2 ...] [-o out.json]
+    python tools/trace_report.py RUN_DIR --xplane FILE
     python tools/trace_report.py --selftest
     python tools/trace_report.py --campaign   # the committed 2-lane
         # artifact: a 2-process training lane (overlapped wire -> real
@@ -165,6 +173,24 @@ def prefill_skips(merged):
             "cached": int(args.get("cached", 0)),
             "computed": int(args.get("computed", 0))}
     return out
+
+
+def scope_report(run_dir, xplane):
+    """The lines `--xplane` prints: device time by the serving
+    programs' stages for every program whose `program_scopes` event any
+    rank's newest segment in `run_dir` holds, over the profile at
+    `xplane`."""
+    from deepspeed_tpu.monitor.tracing import (device_scope_times,
+                                               load_profile, scope_table)
+    from deepspeed_tpu.serving.programs import STAGES
+
+    events = [e for segments, _ in load_rank_traces(run_dir).values()
+              for e in segments[-1][1]]
+    times = device_scope_times(load_profile(xplane), events)
+    if not times:
+        return [f"no program_scopes event under {run_dir!r}: attach the "
+                f"recorder with engine.attach_tracing(tracer=...)"]
+    return scope_table(times, STAGES)
 
 
 def write_merged(run_dirs, out_path, labels=None):
@@ -494,6 +520,10 @@ def main() -> int:
     ap.add_argument("--campaign", action="store_true",
                     help="record the 2-lane (training x serving) "
                     "trace artifact")
+    ap.add_argument("--xplane",
+                    help="a profiler capture of the run: print device "
+                    "time by stage for each program of the run dir's "
+                    "program_scopes events instead of merging")
     ap.add_argument("--no-record", action="store_true")
     ap.add_argument("--steps", type=int, default=4)
     # train-worker plumbing (run_training_lane spawns these)
@@ -513,6 +543,9 @@ def main() -> int:
         return 0
     if not args.run_dirs:
         ap.error("run_dirs required (or --selftest / --campaign)")
+    if args.xplane:
+        print("\n".join(scope_report(args.run_dirs[0], args.xplane)))
+        return 0
     out = args.output or os.path.join(args.run_dirs[0],
                                       "trace.merged.json")
     write_merged(args.run_dirs, out)
