@@ -455,6 +455,8 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                                (512, 8, 16, 128, 4096, 4096)),
                   ssm_shape=(64, 64, 64, 128), ssm_live=37,
                   gdn_shape=(48, 32, 128), gdn_live=29,
+                  masked_shape=(512, 64, 192, 64, 256, 512),
+                  masked_table=(16, 1536, 1024, 2048), masked_start=4608,
                   on_chip=True) -> dict:
     """Each kernel `auto` selects on this chip, once, natively, at the
     main path's shapes, against its jnp oracle at tier-1's tolerance
@@ -896,6 +898,57 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                                np.array_equal(np.asarray(got[1])[rest],
                                               np.asarray(state)[rest])):
         raise RuntimeError("the delta rule touched a slot that does not run")
+
+    # a prefill chunk's attention over a learned selection at the longctx
+    # cell's shape: one request's 512 queries from position 4,608 on (five
+    # tiles of 1,024 of a table that holds 24), each over the 2,048 rows
+    # `select_mask` chooses among those it sees; the table's entries behind
+    # the tiles walked are the trash block, which holds NaN
+    import types
+
+    from deepspeed_tpu.models.glm_moe_dsa import select_mask
+    from deepspeed_tpu.serving import sparse
+
+    T, H, nope, rope, v, rank = masked_shape
+    bs, width, tile, topk = masked_table
+    cfg = types.SimpleNamespace(kv_lora_rank=rank, v_head_dim=v,
+                                qk_rope_head_dim=rope, head_dim=nope + rope,
+                                yarn=None)
+    sched = types.SimpleNamespace(block_size=bs)
+    q_pos = masked_start + jnp.arange(T)
+    n_tiles = sparse._tiles_needed(q_pos, tile, width * bs // tile)
+    at = jnp.arange(width)
+    tables = jnp.where(at < n_tiles * (tile // bs), jax.random.permutation(
+        key[0], width) + 1, 0)[None].astype(jnp.int32)
+    lanes = -(-(rank + rope) // 128) * 128
+    pool = jax.random.normal(key[1], ((width + 1) * bs, lanes),
+                             jnp.bfloat16).at[:bs].set(jnp.nan)
+    kv_b = (jax.random.normal(key[2], (rank, H * (nope + v)), jnp.float32)
+            * rank ** -0.5).astype(jnp.bfloat16)
+    q_nope = jax.random.normal(key[3], (1, T, H, nope), jnp.bfloat16)
+    q_rope = jax.random.normal(key[4], (1, T, H, rope), jnp.bfloat16)
+    mask = select_mask(
+        jax.random.normal(key[5], (1, T, width * bs), jnp.float32),
+        jnp.arange(width * bs)[None, None, :] <= q_pos[None, :, None], topk)
+    info = sparse.masked_info(cfg, q_nope, q_rope, pool, kv_b, tile)
+    chosen = registry.resolve_impl("masked_latent_attention", info=info)
+    if on_chip and chosen != "pallas":
+        raise RuntimeError(
+            f"auto resolved a chunk's attention over its selection at "
+            f"{masked_shape} to {chosen!r} on this chip")
+    operands = (kv_b, q_nope, q_rope, pool, tables, mask, n_tiles)
+    got = jax.jit(lambda *a: registry.dispatch(
+        "masked_latent_attention", cfg, *a, sched, tile, info=info))(
+        *operands)
+    want = jax.jit(lambda *a: sparse.attend_tiles(cfg, *a, sched, tile))(
+        *operands)
+    out.append(_close(
+        f"masked_latent_attention_bf16_T{T}_H{H}_tiles{int(n_tiles)}",
+        [T, H, nope + rope, v, int(n_tiles) * tile], got, want, rtol=0,
+        atol=2e-3 * float(jnp.abs(want).max())))
+    if int(mask.sum(-1).min()) != min(topk, masked_start + 1):
+        raise RuntimeError("the selection check's queries chose other than "
+                           "`topk` rows")
 
     # not a failure but an answer: does this chip's compiler keep
     # `_own_lanes`' two slices right (16 rows of 2 K/V heads of 256)?

@@ -19,7 +19,8 @@ Every hot inner loop that has a Pallas implementation registers a
   described v5e) and where the kernel beats the oracle on the chip.
 
     call site (ops/transformer/attention.py, serving/layers.py,
-               runtime/comm/quant.py, moe/dispatch.py, moe/dropless.py,
+               serving/sparse.py, runtime/comm/quant.py,
+               moe/dispatch.py, moe/dropless.py,
                models/granite_hybrid.py, models/qwen3_next.py,
                ops/sparse_attention/)
        └─> dispatch(op, *args, info=<shape facts of this call>)
@@ -448,6 +449,59 @@ class LatentAttentionOp(KernelOp):
         return layers.latent_attention_reference(*args, **kwargs)
 
 
+class MaskedLatentAttentionOp(KernelOp):
+    """A prefill chunk's attention over a learned selection of latent
+    rows (serving/sparse.py `sparse_latent_attend`: a mask [queries,
+    table positions] over tiles of the one request's rows, each expanded
+    through W_kv_b).  Pallas = one program a head that walks the tiles
+    with that head's scores, probabilities and running softmax in VMEM
+    (kernels/masked_latent.py); oracle = `attend_tiles`, the same walk
+    in `jax.numpy`, every tile's `[heads, queries, tile]` scores through
+    HBM.  The shape rule (`serving/sparse.py::masked_info`): one
+    request, rows and weights of two bytes a value, queries in whole
+    sublane tiles of the int8 mask, every width the kernel slices a
+    whole number of lane tiles, tiles that fit its VMEM."""
+
+    NAME = "masked_latent_attention"
+
+    def auto_supports(self, variant, info):
+        if not info:
+            return True, ""
+        from .masked_latent import _VMEM, masked_vmem
+
+        B, t, tile = (int(info[k]) for k in ("batch", "q_len", "tile"))
+        if B != 1:
+            return False, (f"{B} sequences of {t} queries: the kernel walks "
+                           f"one request's table")
+        item = int(info["kv_itemsize"])
+        if item != 2 or int(info["w_itemsize"]) != 2:
+            return False, (f"rows of {item} and weights of "
+                           f"{int(info['w_itemsize'])} bytes a value: the "
+                           f"kernel's products take both at two")
+        rank, qk, v = int(info["rank"]), \
+            int(info["nope"]) + int(info["rope"]), int(info["v"])
+        if t % 32 or tile % 128 or rank % 128 or qk % 128 or v % 128:
+            return False, (f"{t} queries over tiles of {tile} positions, "
+                           f"latent rows of {rank}, keys of {qk} and values "
+                           f"of {v} a head: the mask's tiles are (32, 128) "
+                           f"and every slice of the kernel whole 128-lane "
+                           f"tiles")
+        need = masked_vmem(t, tile, rank, qk, v, item)
+        if need > _VMEM:
+            return False, (f"a tile of {tile} rows expanded for one head and "
+                           f"{t} x {tile} scores need {need >> 20} MiB of "
+                           f"VMEM, over the kernel's {_VMEM >> 20}")
+        return True, ""
+
+    def pallas(self, variant, *args, **kwargs):
+        from . import masked_latent
+        return masked_latent.masked_latent_attention_pallas(*args, **kwargs)
+
+    def oracle(self, variant, *args, **kwargs):
+        from ..serving import sparse
+        return sparse.attend_tiles(*args, **kwargs)
+
+
 class EvaAttentionOp(KernelOp):
     """Chunk-summarised attention over the paged cache (kernels/eva.py):
     a window of exact rows and the summary rows of closed windows in one
@@ -727,7 +781,8 @@ class GdnStepOp(KernelOp):
 KERNEL_OPS: Dict[str, KernelOp] = {
     op.NAME: op for op in (FlashAttentionOp(), SparseAttentionOp(),
                            PagedAttentionOp(), GroupedAttentionOp(),
-                           LatentAttentionOp(), EvaAttentionOp(),
+                           LatentAttentionOp(), MaskedLatentAttentionOp(),
+                           EvaAttentionOp(),
                            QuantCodecOp(), MoEDispatchOp(),
                            TouchedExpertsOp(), GroupedExpertsOp(),
                            SsmStepOp(), GdnStepOp())

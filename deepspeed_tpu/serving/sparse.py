@@ -31,7 +31,10 @@ sequence, as latent attention's expanded and absorbed products are:
   position; the selection is a MASK [queries, table positions].
   Attention expands a tile's rows through W_kv_b for all the chunk's
   queries and keeps a running softmax over the tiles, the mask applied
-  to each.
+  to each — through the kernel registry (`masked_latent_attention`):
+  where it picks the kernel (kernels/masked_latent.py) a head's scores
+  of a tile stay in VMEM; `attend_tiles` below is the oracle every
+  other backend runs.
 
 A slot that is not running (`q_pos` < 0) sees no row, chooses none and
 attends nothing.
@@ -134,6 +137,18 @@ def attend_tiles(cfg, kv_b, q_nope, q_rope, pool, tables, mask, n_tiles, s,
     return jnp.moveaxis(out, 1, 2).reshape(B, T, H * v)
 
 
+def masked_info(cfg, q_nope, q_rope, pool, kv_b, tile: int) -> dict:
+    """What the kernel registry may look at to choose how a prefill
+    chunk attends its selection: the call's sequences and queries, a
+    head's sizes, the tile, the rows' and the weights' dtype."""
+    B, T, _, nope = q_nope.shape
+    return {"batch": B, "q_len": T, "tile": tile,
+            "rank": cfg.kv_lora_rank, "nope": nope,
+            "rope": q_rope.shape[-1], "v": cfg.v_head_dim,
+            "kv_itemsize": jnp.dtype(pool.dtype).itemsize,
+            "w_itemsize": jnp.dtype(kv_b.dtype).itemsize}
+
+
 def select_step(scores, q_pos, tables, topk: int, block_size: int):
     """A decode step's selection: scores [B, L] of each slot's one query
     at q_pos [B] -> (pool rows [B, K], chosen [B, K] bool), K =
@@ -202,6 +217,10 @@ def sparse_latent_attend(spec, cfg, p, h, kv, addr, s, layer: int, sel,
             out = attend_absorbed(cfg, p["kv_b"], q_nope, q_rope, held,
                                   chosen[:, None, :])
         else:
-            out = attend_tiles(cfg, p["kv_b"], q_nope, q_rope, pool,
-                               addr.tables, sel, n_tiles, s, tile)
+            from ..kernels import registry
+
+            out = registry.dispatch(
+                "masked_latent_attention", cfg, p["kv_b"], q_nope, q_rope,
+                pool, addr.tables, sel, n_tiles, s, tile,
+                info=masked_info(cfg, q_nope, q_rope, pool, p["kv_b"], tile))
     return matmul32(out, p["o"]), kv, sel

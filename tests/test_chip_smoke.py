@@ -87,7 +87,10 @@ def test_kernels_phase_toy():
                                    slab_shapes=((160, 4, 4, 16, 128, 256),),
                                    ssm_shape=(6, 4, 8, 16),
                                    ssm_live=3, gdn_shape=(6, 4, 16),
-                                   gdn_live=3, on_chip=False)
+                                   gdn_live=3,
+                                   masked_shape=(16, 2, 24, 8, 16, 32),
+                                   masked_table=(4, 16, 16, 12),
+                                   masked_start=20, on_chip=False)
     assert [k["kernel"] for k in out["kernels"]] == [
         "flash_attention_fwd", "flash_attention_bwd",
         "flash_attention_full_bias_dropout_fwd",
@@ -102,7 +105,8 @@ def test_kernels_phase_toy():
         "latent_attention_bf16_H4_W48",
         "touched_experts_bf16_T16_E16", "grouped_experts_bf16_T160_E4of16",
         "ssm_step_B6_H4_P8_N16_live3",
-        "gdn_step_B6_H4_D16_live3"]
+        "gdn_step_B6_H4_D16_live3",
+        "masked_latent_attention_bf16_T16_H2_tiles3"]
     # the CPU keeps the two slices right; the chip's answer is the phase's
     assert out["own_lanes_two_slices_right"] is True
 

@@ -220,6 +220,38 @@ def _latent(slots=32, heads=16, rank=512, rope=64, bs=16, width=256,
     return fn, shapes, info
 
 
+def _masked(batch=1, q_len=512, heads=64, nope=192, rope=64, v=256, rank=512,
+            bs=16, width=1536, nblocks=12289, tile=1024,
+            dtype=jnp.bfloat16):
+    """A prefill chunk's attention over its selection as
+    `sparse_latent_attend` calls it: the GLM cell's shapes by default
+    (one request's 512 queries of 64 heads, latent rows of 512 + 64 in a
+    pool of 640 lanes, a table of 1,536 blocks of 16, tiles of 1,024)."""
+    import types
+
+    from deepspeed_tpu.serving import sparse
+
+    cfg = types.SimpleNamespace(kv_lora_rank=rank, v_head_dim=v,
+                                qk_rope_head_dim=rope, head_dim=nope + rope,
+                                yarn=None)
+    s = types.SimpleNamespace(block_size=bs)
+    shapes = (_sds((rank, heads * (nope + v)), dtype),
+              _sds((batch, q_len, heads, nope), dtype),
+              _sds((batch, q_len, heads, rope), dtype),
+              _sds((nblocks * bs, 640), dtype),
+              _sds((batch, width), jnp.int32),
+              _sds((batch, q_len, width * bs), jnp.bool_),
+              _sds((), jnp.int32))
+    info = sparse.masked_info(cfg, shapes[1], shapes[2], shapes[3], shapes[0],
+                              tile)
+
+    def fn(kv_b, q_nope, q_rope, pool, tables, mask, n_tiles):
+        return registry.dispatch(
+            "masked_latent_attention", cfg, kv_b, q_nope, q_rope, pool,
+            tables, mask, n_tiles, s, tile, info=info)
+    return fn, shapes, info
+
+
 def _eva(slots=8, heads=32, dh=128, bs=16, window=2048, chunk=16,
          summary_blocks=64, nblocks=1537, q_len=1, dtype=jnp.bfloat16):
     """`eva_attention` as EvaByte's serving block calls it: the cell's
@@ -470,6 +502,19 @@ CASES = [
          op="latent_attention", refused=r"q_len 512 is a prefill chunk"),
     Case("latent_H16_W576_block8", lambda: _latent(bs=8),
          op="latent_attention", refused=r"block of 8 rows is not whole"),
+    # a prefill chunk over a learned selection (PR 60): the GLM cell's
+    # call, and what the shape rule keeps from the compiler
+    Case("masked_latent_T512_H64_tile1024_longctx", _masked,
+         op="masked_latent_attention"),
+    Case("masked_latent_T256_H16_tile512", lambda: _masked(
+        q_len=256, heads=16, width=256, nblocks=1025, tile=512),
+        op="masked_latent_attention"),
+    Case("masked_latent_two_sequences", lambda: _masked(batch=2),
+         op="masked_latent_attention", refused="2 sequences of 512"),
+    Case("masked_latent_fp32_rows", lambda: _masked(dtype=jnp.float32),
+         op="masked_latent_attention", refused="bytes a value"),
+    Case("masked_latent_heads_of_96_values", lambda: _masked(nope=32, v=96),
+         op="masked_latent_attention", refused="whole 128-lane"),
     Case("codec_quantize_int8_4M_block256",
          lambda: _codec("quantize", "int8"),
          op="quant_codec", variant="quantize"),
@@ -660,6 +705,9 @@ def _serve_attention(q_len, slots):
     ("qwen3-next-80b-a3b-d12.serve.longchat.prefill.routed",
      lambda: ("grouped_experts", _slab(512, 1280, 64, 2048, 512)[2]),
      "pallas"),
+    # PR 60: a chunk's attention over its selection, one request
+    ("glm-5.2-d5.serve.longctx.prefill.selection",
+     lambda: ("masked_latent_attention", _masked()[2]), "pallas"),
     ("deepseek-v2-lite-d9.serve.chatgen.decode.routed",
      lambda: ("grouped_experts", _slab(32, 192, 64, 2048, 1408)[2]), "jnp"),
 ], ids=lambda v: v if isinstance(v, str) and "." in v else "")
@@ -979,11 +1027,15 @@ def test_glm_dsa_cell_programs_compile_inside_one_chip(program, one_chip,
     """`glm-5.2-d5.serve.longctx.decode` / `.prefill` at the cell's
     shapes (5 layers at published widths in bf16, 16 of 256 experts,
     8 slots, 12,289 blocks of 16 rows, a table of 1,536 entries, chunk
-    512), as the chip traces them: the selection and the attention over
-    it are `jax.numpy` (a stable sort over the table's width in decode, a
-    walk of 1,024-position tiles in prefill), so `decode`'s only
-    custom calls are the 4 routed layers' `touched_experts` kernels,
-    `prefill`'s XLA's grouped products; a "full"
+    512), as the chip traces them: the selection is `jax.numpy` (a
+    stable sort over the table's width in decode, a threshold over
+    1,024-position tiles in prefill) and so is a decode step's attention
+    over the 2,048 rows it lists, so `decode`'s only custom calls are
+    the 4 routed layers' `touched_experts` kernels; a prefill chunk's
+    attention over its mask is the `masked_latent_attention` kernel in
+    each of the 5 layers (PR 60: a head's scores of a tile stay in VMEM,
+    and no `[64, 512, 1024]` float32 is left in the program), beside the
+    4 routed layers' `grouped_experts` kernels; a "full"
     layer's entry is (latent rows [rows, 640], index keys [rows, 128]),
     a "shared" layer's the latent rows alone; and weights, pools and
     temporaries leave the room the check's 1.9 GB of reference logits
@@ -1034,8 +1086,14 @@ def test_glm_dsa_cell_programs_compile_inside_one_chip(program, one_chip,
         # PR 58: the 4 routed layers' products walk slabs of 512 of the
         # chunk's 4,096 rows; XLA's own grouped products are gone
         assert sum("grouped_experts" in ln for ln in calls) == layers - 1
-        assert len(calls) == layers - 1 and "ragged" not in text
-        assert _kernels_by_scope(text) == {"grouped_experts": layers - 1}
+        assert len(calls) == 2 * layers - 1 and "ragged" not in text
+        # PR 60: the walk of the mask's tiles is one kernel a layer, and
+        # a tile's scores for all heads are no buffer of the program
+        assert _kernels_by_scope(text) == {
+            "grouped_experts": layers - 1,
+            "masked_latent_attention": layers}
+        assert "f32[1,64,512,1024]" not in text
+        assert "f32[64,512,1024]" not in text
     pools = _hlo_by_shape(text)[(nblocks * bs, 640)]
     assert {layout for _, layout in pools} == {"1,0"}  # row-major
     m = compiled.memory_analysis()
