@@ -455,6 +455,9 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                                (512, 8, 16, 128, 4096, 4096)),
                   ssm_shape=(64, 64, 64, 128), ssm_live=37,
                   gdn_shape=(48, 32, 128), gdn_live=29,
+                  relu2_shape=(40, 16, 2688, 1856), relu2_live=2,
+                  relu2_slab=(512, 6, 16, 128, 2688, 1856),
+                  ssm_groups_shape=(40, 64, 64, 128, 8), ssm_groups_live=24,
                   masked_shape=(512, 64, 192, 64, 256, 512),
                   masked_table=(16, 1536, 1024, 2048), masked_start=4608,
                   on_chip=True) -> dict:
@@ -770,45 +773,54 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
     # the routed product of a decode step at the chatgen cell's shape:
     # 32 slots of which 12 are live, 6 of 64 bf16 experts a slot, the
     # dead slots' rows left as they are.  At the default precision too
+    # ... and at the reasoning cell's: 40 slots of which 2 are live,
+    # two matrices an expert of the whole width 1,856 (no whole-tile
+    # share divides it)
     from deepspeed_tpu.moe import dropless
 
-    T, E, D, F = routed_shape
-    mk = lambda k, shape: (jax.random.normal(k, shape, jnp.float32)
-                           * D ** -0.5).astype(jnp.bfloat16)
-    experts = {"gate": mk(key[1], (E, D, F)), "up": mk(key[2], (E, D, F)),
-               "down": mk(key[3], (E, F, D))}
-    x = jax.random.normal(key[4], (T, D), jnp.float32)
-    weights, idx = dropless.route(
-        x, jax.random.normal(key[5], (D, E), jnp.float32) * D ** -0.5, 6)
-    alive = jnp.arange(T) < routed_live
-    way = dropless.routed_way(T, 6, experts)
-    if on_chip and way != "touched":
-        raise RuntimeError(
-            f"a call of {T} rows over {E} experts of {D} x {F} takes the "
-            f"{way} way on this chip")
-    got = jax.jit(dropless.experts_touched_only)(x, experts, weights, idx,
-                                                 alive)
-    want = jax.jit(dropless.experts_masked)(
-        x, experts, jnp.where(alive[:, None], weights, 0.0), idx)
-    # the kernel's weighted sum is float32 on the VPU; where XLA makes
-    # the oracle's an MXU product it enters as bf16 at this precision
-    out.append(_close(
-        f"touched_experts_bf16_T{T}_E{E}", [T, E, D, F], got, want, rtol=0,
-        atol=1e-2 * float(jnp.abs(want).max())))
-    if int(dropless.experts_touched(idx, alive, E)) in (0, E) or \
-            np.asarray(got)[routed_live:].any():
-        raise RuntimeError("the routed check's dead slots touched experts")
+    for (T, E, D, F), live_rows, gated in ((routed_shape, routed_live, True),
+                                           (relu2_shape, relu2_live, False)):
+        mk = lambda k, shape: (jax.random.normal(k, shape, jnp.float32)
+                               * D ** -0.5).astype(jnp.bfloat16)
+        experts = {"up": mk(key[2], (E, D, F)), "down": mk(key[3], (E, F, D))}
+        if gated:
+            experts["gate"] = mk(key[1], (E, D, F))
+        x = jax.random.normal(key[4], (T, D), jnp.float32)
+        weights, idx = dropless.route(
+            x, jax.random.normal(key[5], (D, E), jnp.float32) * D ** -0.5, 6)
+        alive = jnp.arange(T) < live_rows
+        way = dropless.routed_way(T, 6, experts)
+        if on_chip and way != "touched":
+            raise RuntimeError(
+                f"a call of {T} rows over {E} experts of {D} x {F} takes the "
+                f"{way} way on this chip")
+        got = jax.jit(dropless.experts_touched_only)(x, experts, weights, idx,
+                                                     alive)
+        want = jax.jit(dropless.experts_masked)(
+            x, experts, jnp.where(alive[:, None], weights, 0.0), idx)
+        # the kernel's weighted sum is float32 on the VPU; where XLA makes
+        # the oracle's an MXU product it enters as bf16 at this precision
+        out.append(_close(
+            f"touched_experts_bf16_T{T}_E{E}" + ("" if gated else "_relu2"),
+            [T, E, D, F], got, want, rtol=0,
+            atol=1e-2 * float(jnp.abs(want).max())))
+        if int(dropless.experts_touched(idx, alive, E)) in (0, E) or \
+                np.asarray(got)[live_rows:].any():
+            raise RuntimeError(
+                "the routed check's dead slots touched experts")
 
     # the routed product of a prefill chunk at the longchat and mixedlen
     # cells' shapes: 512 tokens, the last 100 a padded tail that is not
     # live, an eighth of the experts held; the held rows walked a slab at
-    # a time against XLA's grouped products over every assignment
-    for T, top_k, E, total, D, F in slab_shapes:
+    # a time against XLA's grouped products over every assignment; and
+    # at the reasoning cell's, two matrices an expert
+    for (T, top_k, E, total, D, F), gated in (
+            *((shape, True) for shape in slab_shapes), (relu2_slab, False)):
         mk = lambda k, shape: (jax.random.normal(k, shape, jnp.float32)
                                * D ** -0.5).astype(jnp.bfloat16)
-        experts = {"gate": mk(key[1], (E, D, F)),
-                   "up": mk(key[2], (E, D, F)),
-                   "down": mk(key[3], (E, F, D))}
+        experts = {"up": mk(key[2], (E, D, F)), "down": mk(key[3], (E, F, D))}
+        if gated:
+            experts["gate"] = mk(key[1], (E, D, F))
         x = jax.random.normal(key[4], (T, D), jnp.float32)
         weights, idx, held = dropless.held_assignments(*dropless.route(
             x, jax.random.normal(key[5], (D, total), jnp.float32)
@@ -824,7 +836,8 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
         want = jax.jit(dropless.experts_grouped)(x, experts, weights, idx,
                                                  held)
         out.append(_close(
-            f"grouped_experts_bf16_T{T}_E{E}of{total}", [T, E, D, F],
+            f"grouped_experts_bf16_T{T}_E{E}of{total}"
+            + ("" if gated else "_relu2"), [T, E, D, F],
             got[alive], want[alive], rtol=0,
             atol=1e-2 * float(jnp.abs(want).max())))
         if np.asarray(got)[T - 100:].any() or not bool(held.any()):
@@ -836,33 +849,43 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
     from deepspeed_tpu.kernels.ssm import live_slots, ssm_step_info
     from deepspeed_tpu.models.granite_hybrid import ssm_step
 
-    B, H, P, N = ssm_shape
-    runs = jnp.zeros((B,), bool).at[
-        jax.random.permutation(key[6], B)[:ssm_live]].set(True)
-    sx, sB, sC = (jax.random.normal(k, shape, jnp.float32)
-                  for k, shape in zip(key[:3], ((B, H, P), (B, N), (B, N))))
-    dt = jax.random.uniform(key[3], (B, H), minval=0.001, maxval=0.1) * runs[
-        :, None]
-    A = -jax.random.uniform(key[4], (H,), minval=1.0, maxval=16.0)
-    state = jax.random.normal(key[5], (B, H, P, N), jnp.float32)
-    info = ssm_step_info(state)
-    chosen = registry.resolve_impl("ssm_step", info=info)
-    if on_chip and chosen != "pallas":
-        raise RuntimeError(f"auto resolved the recurrence over a state of "
-                           f"{ssm_shape} to {chosen!r} on this chip")
-    got = jax.jit(lambda *a: registry.dispatch("ssm_step", *a, info=info))(
-        sx, sB, sC, dt, A, state, *live_slots(runs))
-    want = jax.jit(ssm_step)(sx, sB, sC, dt, A, state)
-    out.append(_close(f"ssm_step_B{B}_H{H}_P{P}_N{N}_live{ssm_live}",
-                      list(ssm_shape), jax.tree_util.tree_map(
-                          lambda a: a[runs], got),
-                      jax.tree_util.tree_map(lambda a: a[runs], want),
-                      rtol=1e-5, atol=1e-4))
-    rest = ~np.asarray(runs)
-    if chosen == "pallas" and (np.asarray(got[0])[rest].any() or not
-                               np.array_equal(np.asarray(got[1])[rest],
-                                              np.asarray(state)[rest])):
-        raise RuntimeError("the recurrence touched a slot that does not run")
+    from deepspeed_tpu.models.granite_hybrid import by_group
+
+    # ... and at the reasoning cell's: 40 slots of which 24 run, a B and
+    # a C for each of 8 groups of 8 heads
+    for (B, H, P, N, G), n_live in (((*ssm_shape, 1), ssm_live),
+                                    (ssm_groups_shape, ssm_groups_live)):
+        runs = jnp.zeros((B,), bool).at[
+            jax.random.permutation(key[6], B)[:n_live]].set(True)
+        sx, sB, sC = (jax.random.normal(k, shape, jnp.float32)
+                      for k, shape in zip(key[:3], (
+                          (B, H, P), *[(B, G, N) if G > 1 else (B, N)] * 2)))
+        dt = jax.random.uniform(key[3], (B, H), minval=0.001,
+                                maxval=0.1) * runs[:, None]
+        A = -jax.random.uniform(key[4], (H,), minval=1.0, maxval=16.0)
+        state = jax.random.normal(key[5], (B, H, P, N), jnp.float32)
+        info = ssm_step_info(state, G)
+        chosen = registry.resolve_impl("ssm_step", info=info)
+        if on_chip and chosen != "pallas":
+            raise RuntimeError(
+                f"auto resolved the recurrence over a state of "
+                f"{(B, H, P, N)} in {G} group(s) to {chosen!r} on this chip")
+        got = jax.jit(lambda *a: registry.dispatch("ssm_step", *a, info=info))(
+            sx, sB, sC, dt, A, state, *live_slots(runs))
+        want = jax.jit(ssm_step if G == 1 else by_group(ssm_step, G))(
+            sx, sB, sC, dt, A, state)
+        out.append(_close(f"ssm_step_B{B}_H{H}_P{P}_N{N}_live{n_live}"
+                          + (f"_G{G}" if G > 1 else ""),
+                          [B, H, P, N], jax.tree_util.tree_map(
+                              lambda a: a[runs], got),
+                          jax.tree_util.tree_map(lambda a: a[runs], want),
+                          rtol=1e-5, atol=1e-4))
+        rest = ~np.asarray(runs)
+        if chosen == "pallas" and (np.asarray(got[0])[rest].any() or not
+                                   np.array_equal(np.asarray(got[1])[rest],
+                                                  np.asarray(state)[rest])):
+            raise RuntimeError(
+                "the recurrence touched a slot that does not run")
 
     # the gated delta rule of a decode step at the longchat cell's shape:
     # 48 slots of which 29 run, scattered; the others' state comes back

@@ -44,6 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..ops import pallas_backend
+from .expert_form import expert_hidden
 
 
 def _clamp(i):
@@ -157,28 +158,66 @@ def sorted_combine_pallas(expert_out, eidx, gate, pos, keep):
 # touched experts: the routed product of a call of few rows
 # ---------------------------------------------------------------------------
 
-# what the three weight tiles a grid step reads may take of VMEM, both
-# pipeline buffers counted; the kernel asks the compiler for this much
-# and `_TOUCHED_REST` for x, the weights, the accumulator and its own
-# temporaries (a v5e's VMEM is 128 MiB, its scoped default 16)
+# what the weight tiles a grid step reads — one of each of an expert's
+# `matrices` (expert_form.py `expert_matrices`: gate, up and down, or
+# up and down) — may take of VMEM, both pipeline buffers counted; the
+# kernel asks the compiler for this much and `_TOUCHED_REST` for x, the
+# weights, the accumulator and its own temporaries (a v5e's VMEM is
+# 128 MiB, its scoped default 16)
 _TOUCHED_TILE_BYTES = 48 << 20
 _TOUCHED_REST = 16 << 20
+# the matrices in front of an expert's `down`, in the order both kernels
+# take them: those of these its form has
+_FRONT = ("gate", "up")
 
 
-def touched_tile(model_dim: int, expert_dim: int, itemsize: int) -> int:
-    """The columns of an expert's `gate` and `up` (rows of its `down`)
-    a grid step takes: the largest divisor of `expert_dim` in whole
-    128-lane tiles — or all of it — whose three tiles, double-buffered,
-    fit `_TOUCHED_TILE_BYTES`; 0 where none does."""
-    fits = lambda tf: 6 * model_dim * tf * itemsize <= _TOUCHED_TILE_BYTES
+def _front(experts) -> dict:
+    """The matrices of `experts` that multiply the rows."""
+    return {k: experts[k] for k in _FRONT if k in experts}
+
+
+def _turned(expert_dim: int) -> bool:
+    """Whether the front matrices go to the kernel turned, `[E, F, D]`:
+    where an expert's F columns are not whole 128-lane tiles the chip
+    holds a `[E, D, F]` array with D on the lanes (its layout {1,2,0}),
+    and a kernel that asked for it as it is written would be handed a
+    copy of every expert's matrix, every call (PERF.md section 6,
+    PR 61); turned, the same bytes are the array the kernel asks for."""
+    return expert_dim % 128 != 0
+
+
+def _hidden(x, front_refs, turned: bool):
+    """`expert_hidden` of the rows x over a grid step's tiles of the
+    front matrices — `[D, tf]`, or `[tf, D]` where they come turned — at
+    x's dtype (the roundings of `experts_weighted` and `grouped_ffn`:
+    operands at the weights' dtype, float32 sums, the hidden values
+    rounded before `down`)."""
+    refs = dict(zip(_FRONT[-len(front_refs):], front_refs))
+    dims = (((1,), (1 if turned else 0,)), ((), ()))
+    return expert_hidden(
+        lambda ref: jax.lax.dot_general(
+            x, ref[...], dims, preferred_element_type=jnp.float32),
+        refs).astype(x.dtype)
+
+
+def touched_tile(model_dim: int, expert_dim: int, itemsize: int,
+                 matrices: int = 3) -> int:
+    """The columns of an expert's front matrices (rows of its `down`) a
+    grid step takes: the largest divisor of `expert_dim` in whole
+    128-lane tiles — or all of it — whose `matrices` tiles (one of each
+    of the expert's operands: 3 of a SiLU-gated expert, 2 of a relu2
+    one), double-buffered, fit `_TOUCHED_TILE_BYTES`; 0 where none
+    does."""
+    fits = lambda tf: 2 * matrices * model_dim * tf * itemsize \
+        <= _TOUCHED_TILE_BYTES
     if fits(expert_dim):
         return expert_dim
     return max((tf for tf in range(128, expert_dim, 128)
                 if expert_dim % tf == 0 and fits(tf)), default=0)
 
 
-def _touched_kernel(ids_ref, n_ref, x_ref, w_ref, gate_ref, up_ref,
-                    down_ref, o_ref):
+def _touched_kernel(ids_ref, n_ref, x_ref, w_ref, *refs, turned):
+    *front, down_ref, o_ref = refs
     j, f = pl.program_id(0), pl.program_id(1)
 
     @pl.when((j == 0) & (f == 0))
@@ -187,13 +226,8 @@ def _touched_kernel(ids_ref, n_ref, x_ref, w_ref, gate_ref, up_ref,
 
     @pl.when(j < n_ref[0])
     def _expert():
-        # experts_weighted's roundings: operands at the weights' dtype,
-        # float32 sums, the gated product rounded before `down`
-        x = x_ref[...]
-        g = jnp.dot(x, gate_ref[...], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, up_ref[...], preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(g) * u).astype(x.dtype)
-        out = jnp.dot(h, down_ref[...], preferred_element_type=jnp.float32)
+        out = jnp.dot(_hidden(x_ref[...], front, turned), down_ref[...],
+                      preferred_element_type=jnp.float32)
         w = w_ref[...]                                    # (T, E)
         mine = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1) == ids_ref[j]
         o_ref[...] += out * jnp.sum(jnp.where(mine, w, 0.0), axis=1,
@@ -205,22 +239,21 @@ def touched_experts_pallas(x, experts, w, ids, n):
     lists the `n` experts with a weight in w [T, E] (`touched_list`):
     x [T, D] -> [T, D] float32, tolerance parity (the sum over experts
     and over an expert's column tiles is taken in another order)."""
-    return _touched(x.astype(experts["gate"].dtype), experts["gate"],
-                    experts["up"], experts["down"], w, ids,
-                    jnp.reshape(n, (1,)),
+    return _touched(x.astype(experts["up"].dtype), _front(experts),
+                    experts["down"], w, ids, jnp.reshape(n, (1,)),
                     interpret=pallas_backend.interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _touched(x, gate, up, down, w, ids, n, *, interpret):
+def _touched(x, front, down, w, ids, n, *, interpret):
     """The call, as a function of its own (a program that makes it in
     every layer lowers the kernel once).  Grid (place j in the list,
     column tile f); the weight tiles' index maps read `ids[j]`, and
     from place `n` on stay on the last tile read, so that the pipeline
     copies nothing more; the output block is the float32 accumulator."""
     T, D = x.shape
-    E, _, F = gate.shape
-    tf = touched_tile(D, F, gate.dtype.itemsize)
+    E, F, _ = down.shape
+    tf = touched_tile(D, F, down.dtype.itemsize, len(front) + 1)
     nf = F // tf
     rows = -(-T // 16) * 16            # whole tiles of bf16 rows
     if rows != T:
@@ -235,20 +268,24 @@ def _touched(x, gate, up, down, w, ids, n, *, interpret):
         return expert, 0, t
 
     whole = lambda j, f, ids, n: (0, 0)
-    cols = pl.BlockSpec((None, D, tf), cols_of)
+    rows_of = pl.BlockSpec((None, tf, D), lambda *step: (*tile(*step), 0))
+    turned = _turned(F)
+    if turned:
+        front = {k: jnp.swapaxes(w_, 1, 2) for k, w_ in front.items()}
+    cols = rows_of if turned else pl.BlockSpec((None, D, tf), cols_of)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(E, nf),
         in_specs=[
             pl.BlockSpec((rows, D), whole),
             pl.BlockSpec((rows, E), whole),
-            cols, cols,
-            pl.BlockSpec((None, tf, D), lambda *step: (*tile(*step), 0)),
+            *[cols] * len(front),
+            rows_of,
         ],
         out_specs=pl.BlockSpec((rows, D), whole),
     )
     out = pl.pallas_call(
-        _touched_kernel,
+        functools.partial(_touched_kernel, turned=turned),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -256,7 +293,7 @@ def _touched(x, gate, up, down, w, ids, n, *, interpret):
             vmem_limit_bytes=_TOUCHED_TILE_BYTES + _TOUCHED_REST),
         name="touched_experts",
         interpret=interpret,
-    )(ids, n, x, w, gate, up, down)
+    )(ids, n, x, w, *front.values(), down)
     return out[:T]
 
 
@@ -270,8 +307,8 @@ def _touched(x, gate, up, down, w, ids, n, *, interpret):
 # pass through the MXU once a window, whatever rows it holds)
 GROUPED_WINDOW = 128
 # what the kernel may ask of a v5e's 128 MiB of VMEM: the slab and its
-# float32 result, held once, the three weight tiles in both pipeline
-# buffers and a window's temporaries
+# float32 result, held once, the weight tiles (one of each of an
+# expert's matrices) in both pipeline buffers and a window's temporaries
 _GROUPED_VMEM = 100 << 20
 _WINDOW_ALIGN = 16                  # whole tiles of bf16 rows
 
@@ -280,33 +317,34 @@ def _whole_tiles(rows: int) -> int:
     return -(-rows // _WINDOW_ALIGN) * _WINDOW_ALIGN
 
 
-def grouped_vmem(rows: int, model_dim: int, tile: int, itemsize: int) -> int:
+def grouped_vmem(rows: int, model_dim: int, tile: int, itemsize: int,
+                 matrices: int = 3) -> int:
     """Bytes of VMEM the walk over a slab of `rows` rows needs at a
-    column tile of `tile`: what `grouped_tile` fits and the call asks
-    the compiler for."""
+    column tile of `tile` of an expert's `matrices` operands: what
+    `grouped_tile` fits and the call asks the compiler for."""
     # a window of room behind the slab's rows for the last expert's
     slab = (_whole_tiles(rows) + GROUPED_WINDOW) * model_dim * (itemsize + 4)
-    weights = 6 * model_dim * tile * itemsize
-    window = GROUPED_WINDOW * (2 * model_dim + 3 * tile) * 4
+    weights = 2 * matrices * model_dim * tile * itemsize
+    window = GROUPED_WINDOW * (2 * model_dim + matrices * tile) * 4
     return slab + weights + window + (4 << 20)
 
 
 def grouped_tile(rows: int, model_dim: int, expert_dim: int,
-                 itemsize: int) -> int:
-    """The columns of an expert's `gate` and `up` (rows of its `down`) a
+                 itemsize: int, matrices: int = 3) -> int:
+    """The columns of an expert's front matrices (rows of its `down`) a
     grid step of the walk over a slab of `rows` rows takes: the largest
     divisor of `expert_dim` in whole 128-lane tiles — or all of it —
     that `grouped_vmem` fits into `_GROUPED_VMEM`; 0 where none does."""
-    fits = lambda tf: grouped_vmem(rows, model_dim, tf,
-                                   itemsize) <= _GROUPED_VMEM
+    fits = lambda tf: grouped_vmem(rows, model_dim, tf, itemsize,
+                                   matrices) <= _GROUPED_VMEM
     if fits(expert_dim):
         return expert_dim
     return max((tf for tf in range(128, expert_dim, 128)
                 if expert_dim % tf == 0 and fits(tf)), default=0)
 
 
-def _grouped_kernel(off_ref, src_ref, col_ref, x_hbm, gate_ref, up_ref,
-                    down_ref, o_hbm, x_ref, acc_ref, sem, *, rows):
+def _grouped_kernel(off_ref, src_ref, col_ref, x_hbm, *refs, rows, turned):
+    *front, down_ref, o_hbm, x_ref, acc_ref, sem = refs
     e, f = pl.program_id(0), pl.program_id(1)
 
     @pl.when((e == 0) & (f == 0))
@@ -322,13 +360,8 @@ def _grouped_kernel(off_ref, src_ref, col_ref, x_hbm, gate_ref, up_ref,
     def window(i, carry):
         r0 = pl.multiple_of(start + i * GROUPED_WINDOW, _WINDOW_ALIGN)
         at = pl.ds(r0, GROUPED_WINDOW)
-        # experts_grouped's roundings: operands at the weights' dtype,
-        # float32 sums, the gated product rounded before `down`
-        x = x_ref[at, :]
-        g = jnp.dot(x, gate_ref[...], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, up_ref[...], preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(g) * u).astype(x.dtype)
-        out = jnp.dot(h, down_ref[...], preferred_element_type=jnp.float32)
+        out = jnp.dot(_hidden(x_ref[at, :], front, turned), down_ref[...],
+                      preferred_element_type=jnp.float32)
         row = r0 + jax.lax.broadcasted_iota(
             jnp.int32, (GROUPED_WINDOW, 1), 0)
         # a row of another expert, or of none, is never written: what
@@ -354,26 +387,26 @@ def grouped_experts_pallas(xs, experts, offsets):
     -> [C, D] float32, each row through its expert's FFN and 0 from
     `offsets[E]` on; tolerance parity (an expert's column tiles are
     summed in another order)."""
-    return _grouped(xs.astype(experts["gate"].dtype), experts["gate"],
-                    experts["up"], experts["down"], offsets,
+    return _grouped(xs.astype(experts["up"].dtype), _front(experts),
+                    experts["down"], offsets,
                     interpret=pallas_backend.interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _grouped(xs, gate, up, down, offsets, *, interpret):
+def _grouped(xs, front, down, offsets, *, interpret):
     """The call, as a function of its own (a program that makes it in
     every layer lowers the kernel once).  Grid (expert e, column tile
     f), the offsets scalar-prefetched: a step multiplies the windows of
-    the slab that cover the expert's range with its tile of `gate`, `up`
-    and `down` and adds the rows of the range to the float32 result;
-    slab and result lie in VMEM once, copied in at the first step and
+    the slab that cover the expert's range with its tile of the front
+    matrices and of `down` and adds the rows of the range to the float32
+    result; slab and result lie in VMEM once, copied in at the first step and
     out at the last.  An expert with no row stays on the tile read last
     (the first one to come, before any is), so the pipeline copies
     nothing for it."""
     C, D = xs.shape
-    E, _, F = gate.shape
-    item = gate.dtype.itemsize
-    tf = grouped_tile(C, D, F, item)
+    E, F, _ = down.shape
+    item, matrices = down.dtype.itemsize, len(front) + 1
+    tf = grouped_tile(C, D, F, item, matrices)
     nf = F // tf
     rows = _whole_tiles(C)
     if rows != C:
@@ -394,14 +427,18 @@ def _grouped(xs, gate, up, down, offsets, *, interpret):
         expert, t = tile(*step)
         return expert, 0, t
 
-    cols = pl.BlockSpec((None, D, tf), cols_of)
+    rows_of = pl.BlockSpec((None, tf, D), lambda *step: (*tile(*step), 0))
+    turned = _turned(F)
+    if turned:
+        front = {k: jnp.swapaxes(w_, 1, 2) for k, w_ in front.items()}
+    cols = rows_of if turned else pl.BlockSpec((None, D, tf), cols_of)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(E, nf),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
-            cols, cols,
-            pl.BlockSpec((None, tf, D), lambda *step: (*tile(*step), 0)),
+            *[cols] * len(front),
+            rows_of,
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
@@ -411,13 +448,13 @@ def _grouped(xs, gate, up, down, offsets, *, interpret):
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_grouped_kernel, rows=rows),
+        functools.partial(_grouped_kernel, rows=rows, turned=turned),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY),
-            vmem_limit_bytes=grouped_vmem(C, D, tf, item)),
+            vmem_limit_bytes=grouped_vmem(C, D, tf, item, matrices)),
         name="grouped_experts",
         interpret=interpret,
-    )(offsets, src, col, xs, gate, up, down)
+    )(offsets, src, col, xs, *front.values(), down)
     return out[:C]

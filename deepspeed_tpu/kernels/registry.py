@@ -616,8 +616,8 @@ class TouchedExpertsOp(KernelOp):
     call's combine weights.  Pallas = a walk of the touched list that
     reads only those experts' matrices (kernels/moe_kernels.py); oracle
     = `experts_weighted`, every expert held under the same weights.  The
-    shape rule looks at the call's rows and the experts' three sizes
-    (`moe/dropless.py::touched_info`)."""
+    shape rule looks at the call's rows, the experts' three sizes and
+    how many matrices an expert has (`moe/dropless.py::touched_info`)."""
 
     NAME = "touched_experts"
 
@@ -638,10 +638,11 @@ class TouchedExpertsOp(KernelOp):
         if D % 128:
             return False, (f"rows of {D} values are not whole 128-lane "
                            f"tiles")
-        if not touched_tile(D, F, int(info["itemsize"])):
+        m = int(info.get("matrices", 3))
+        if not touched_tile(D, F, int(info["itemsize"]), m):
             return False, (f"no whole-tile share of an expert's {F} "
                            f"columns divides them and fits the kernel's "
-                           f"VMEM at {D} rows")
+                           f"VMEM at {D} rows and {m} matrices an expert")
         return True, ""
 
     def pallas(self, variant, *args, **kwargs):
@@ -682,11 +683,12 @@ class GroupedExpertsOp(KernelOp):
             return False, (f"rows of {D} values are not whole 128-lane "
                            f"tiles")
         rows = int(info["rows"])
-        if not grouped_tile(rows, D, F, int(info["itemsize"])):
+        m = int(info.get("matrices", 3))
+        if not grouped_tile(rows, D, F, int(info["itemsize"]), m):
             return False, (f"a slab of {rows} rows of {D} values, its "
                            f"float32 result and a whole-tile share of an "
-                           f"expert's {F} columns do not fit the kernel's "
-                           f"VMEM together")
+                           f"expert's {F} columns, {m} matrices an expert, "
+                           f"do not fit the kernel's VMEM together")
         return True, ""
 
     def pallas(self, variant, *args, **kwargs):
@@ -712,7 +714,7 @@ class SsmStepOp(KernelOp):
     def auto_supports(self, variant, info):
         if not info:
             return True, ""
-        from .ssm import head_tile
+        from .ssm import groups_fit, head_tile
 
         P, N, item = (int(info[k]) for k in ("head_dim", "state",
                                              "itemsize"))
@@ -728,6 +730,11 @@ class SsmStepOp(KernelOp):
             return False, (f"the {th} heads of {P} rows a block that fit "
                            f"the kernel's VMEM are not whole tiles of 128 "
                            f"rows")
+        G = int(info.get("groups", 1))
+        if not groups_fit(int(info["heads"]), P, G, th):
+            return False, (f"{G} groups of {int(info['heads']) // G} heads "
+                           f"of {P} rows do not fall on blocks of 128 rows "
+                           f"and tiles of {th} heads")
         return True, ""
 
     def pallas(self, variant, *args, **kwargs):
@@ -735,7 +742,9 @@ class SsmStepOp(KernelOp):
         return ssm.ssm_step_pallas(*args, **kwargs)
 
     def oracle(self, variant, x, Bm, Cm, dt, A, state, ids, n):
-        from ..models.granite_hybrid import ssm_step
+        from ..models.granite_hybrid import by_group, ssm_step
+        if Bm.ndim == 3:                 # a B and a C a group
+            return by_group(ssm_step, Bm.shape[1])(x, Bm, Cm, dt, A, state)
         return ssm_step(x, Bm, Cm, dt, A, state)
 
 

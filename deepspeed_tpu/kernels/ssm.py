@@ -20,6 +20,12 @@ head_dim on the sublanes and N on the lanes, so the sum over N of a
 (head, head_dim) row is a reduction along the lanes: the kernel takes
 the rows 128 at a time, turns the [128, N] tile and adds its sublanes —
 y then leaves with the rows on the lanes, as the caller holds it.
+
+B and C may come a group of heads (`[B, G, N]`, head h reading group
+h // (H / G); `[B, N]` is one group): a grid step takes the B and C of
+the groups its tile of heads lies in, and each block of 128 rows reads
+its group's — the registry's rule (`groups_fit`) holds a group's rows to
+whole blocks and a tile to whole groups, or a group to whole tiles.
 """
 
 from __future__ import annotations
@@ -70,22 +76,36 @@ def head_tile(heads: int, head_dim: int, state: int) -> int:
                default=0)
 
 
-def ssm_step_info(state) -> dict:
+def groups_fit(heads: int, head_dim: int, groups: int, tile: int) -> bool:
+    """Whether a tile of `tile` heads a grid step and blocks of 128
+    (head, head_dim) rows fall on the bounds of `groups` groups of heads:
+    a group's rows are whole blocks, and a tile is whole groups or a
+    group whole tiles.  One group always fits."""
+    per = heads // groups
+    return groups == 1 or (per * head_dim % 128 == 0
+                           and (tile % per == 0 or per % tile == 0))
+
+
+def ssm_step_info(state, groups: int = 1) -> dict:
     """What the kernel registry may look at to choose the recurrence of
     a decode step over `state` [slots, heads, head_dim, N] (an array or
-    its shape and dtype)."""
+    its shape and dtype) with B and C in `groups` groups of heads."""
     B, H, P, N = state.shape
     return {"slots": B, "heads": H, "head_dim": P, "state": N,
+            "groups": groups,
             "itemsize": jnp.dtype(state.dtype).itemsize}
 
 
 def _step_kernel(ids_ref, n_ref, rows_ref, bc_ref, s_ref, so_ref, y_ref):
-    Bm, Cm = bc_ref[0:1, :], bc_ref[1:2, :]                   # [1, N]
+    # blocks of rows that read one group's B and C: all of the tile's
+    # where it lies in one group
+    per = s_ref.shape[0] // bc_ref.shape[0]
     # what multiplies a (head, head_dim) row of the state comes with the
     # rows on the lanes; the state has them on the sublanes: turned once
     # a block
     dtx, a = rows_ref[0].T, rows_ref[1].T                     # [128, R]
     for r in range(s_ref.shape[0]):
+        Bm, Cm = bc_ref[r // per, 0:1, :], bc_ref[r // per, 1:2, :]  # [1, N]
         s = s_ref[r] * a[:, r:r + 1] + dtx[:, r:r + 1] * Bm
         so_ref[r] = s
         # the sum over N as a sum over the sublanes of the turned tile:
@@ -97,11 +117,12 @@ def _step_kernel(ids_ref, n_ref, rows_ref, bc_ref, s_ref, so_ref, y_ref):
 
 
 def ssm_step_pallas(x, Bm, Cm, dt, A, state, ids, n):
-    """Drop-in for `ssm_step` where `ids` [B] lists the `n` slots with
-    dt != 0 (`live_slots`): -> (y [B, H, P], state).  A listed slot's
-    state and y to float32 tolerance (the sum over N in another order);
-    any other slot's state is the input's, bit for bit, and its y
-    zeros."""
+    """Drop-in for `ssm_step` (Bm, Cm [B, N]; or [B, G, N], a B and a C
+    a group of heads: `by_group(ssm_step, G)`) where `ids` [B] lists the
+    `n` slots with dt != 0 (`live_slots`): -> (y [B, H, P], state).  A
+    listed slot's state and y to float32 tolerance (the sum over N in
+    another order); any other slot's state is the input's, bit for bit,
+    and its y zeros."""
     y, state = _step_live(jnp.exp(dt * A), dt[:, :, None] * x, Bm, Cm,
                           state, ids, jnp.reshape(n, (1,)),
                           interpret=pallas_backend.interpret())
@@ -120,12 +141,17 @@ def _step_live(a, dtx, Bm, Cm, state, ids, n, *, interpret):
     `[.., H * P / 128, 128]`."""
     B, H, P, N = state.shape
     th = head_tile(H, P, N)
-    # (the interpreter takes a tile of any number of rows as one)
-    Q = 128 if th * P % 128 == 0 else th * P
+    G = Bm.shape[1] if Bm.ndim == 3 else 1
+    gt = max(1, th * G // H)       # groups a tile of heads lies in
+    # (the interpreter takes any number of rows as a block: a tile's, or
+    # those of each of its groups)
+    Q = 128 if th * P % 128 == 0 else th * P // gt
     nt, R = H // th, th * P // Q
     rows = jnp.stack([dtx, jnp.broadcast_to(a[:, :, None], dtx.shape)], 1)
     rows = rows.reshape(B, 2, nt, R, Q).swapaxes(1, 2)
-    bc = jnp.stack([Bm, Cm], axis=1)                          # [B, 2, N]
+    # [B, G, 2, N]: a group's B and C side by side; a grid step takes
+    # the gt groups its tile of heads lies in
+    bc = jnp.stack([Bm.reshape(B, G, N), Cm.reshape(B, G, N)], axis=2)
 
     slot = lambda *zeros: lambda j, t, ids, n: (ids[j], t, *zeros)
     block = pl.BlockSpec((None, R, Q, N), slot(0, 0))
@@ -134,7 +160,8 @@ def _step_live(a, dtx, Bm, Cm, state, ids, n, *, interpret):
         grid=(n[0], nt),
         in_specs=[
             pl.BlockSpec((None, None, 2, R, Q), slot(0, 0, 0)),
-            pl.BlockSpec((None, 2, N), lambda j, t, ids, n: (ids[j], 0, 0)),
+            pl.BlockSpec((None, gt, 2, N), lambda j, t, ids, n: (
+                ids[j], t * th * G // (H * gt), 0, 0)),
             block,
         ],
         out_specs=[block, pl.BlockSpec((None, None, R, Q), slot(0, 0))],

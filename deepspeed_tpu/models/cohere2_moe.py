@@ -46,9 +46,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..moe.dropless import (experts_touched, held_assignments, route,
+from ..moe.dropless import (dense_expert, experts_touched,
+                            held_assignments, route,
                             routed_experts)
-from .evabyte import NEG_INF, matmul32, silu_gated_ffn
+from .evabyte import NEG_INF, matmul32
 from .layer_spec import LayerSpec
 
 # scores of a call's heads formed at once, at most: beyond it a K/V
@@ -185,7 +186,8 @@ def routed_ffn(spec, cfg, p, flat, live=None):
     `spec.renormalize` (chosen by the scores plus the layer's
     `select_bias` where `spec.select_bias`, times `spec.route_scale`),
     the held experts' weighted sum (`spec.held`: a share of
-    `cfg.num_experts`, or all) plus the shared experts' sum or, with
+    `cfg.num_experts`, or all; an expert's form is what its tree holds,
+    kernels/expert_form.py `expert_hidden`) plus the shared experts' sum or, with
     `spec.shared` "average", their mean — and what `experts_touched`
     counts the touched experts from: the experts chosen, numbered among
     the `count` held, and which assignments are `held` (None: all).
@@ -205,7 +207,7 @@ def routed_ffn(spec, cfg, p, flat, live=None):
         y = routed_experts(flat, p["experts"], weights, idx,
                            total=cfg.num_experts, held=held, live=live)
     with jax.named_scope("moe_shared"):
-        shared = silu_gated_ffn(p["shared"], flat)
+        shared = dense_expert(p["shared"], flat)   # of the experts' form
         if spec.shared == "gated":
             shared = shared * jax.nn.sigmoid(matmul32(flat, p["shared_gate"]))
         y = y + (shared / cfg.num_shared if spec.shared == "average"

@@ -46,8 +46,10 @@ The serving engine runs the model through `layer_spec()`
 (`serving/layers.py` holds the cached block, built from the pieces
 here); `apply` is the uncached forward the tests compare with the plain
 reference (`benchmarks/reference/granite_hybrid.py`, which knows the
-recurrence only).  Training it, routed experts (`num_local_experts` > 0),
-more than one group of B and C, and a mesh are not built.
+recurrence only).  Training it, routed experts (`num_local_experts` > 0)
+and a mesh are not built; more than one group of B and C is
+(`spec.ssm_groups`: `by_group`, `gated_norm`; models/nemotron_h.py has
+eight).
 """
 
 from __future__ import annotations
@@ -168,6 +170,38 @@ def ssm_scan(x, Bm, Cm, dt, A, state, chunk: int):
     return jnp.moveaxis(y, 0, 1).reshape(B, T, H, P), state
 
 
+def by_group(fn, groups: int):
+    """`ssm_step` or `ssm_scan` where the heads lie in `groups` groups,
+    each with a B and a C of its own (Bm, Cm [..., groups, N], head h
+    reading group h // (H / groups)): the one-group function mapped
+    over the groups, each a recurrence over its own heads — same
+    arguments and results."""
+
+    def split(t, axis):            # the heads' axis -> (groups, heads of one)
+        return t.reshape(t.shape[:axis] + (groups, -1) + t.shape[axis + 1:])
+
+    def grouped(x, Bm, Cm, dt, A, state, *rest):
+        a = x.ndim - 2             # the heads' axis of x (and of dt)
+        y, new = jax.vmap(lambda *g: fn(*g, *rest),
+                          in_axes=(a, a, a, a, 0, 1), out_axes=(a, 1))(
+            split(x, a), Bm, Cm, split(dt, a), split(A, 0), split(state, 1))
+        return y.reshape(x.shape), new.reshape(state.shape)
+
+    return grouped
+
+
+def gated_norm(spec, g, p):
+    """The mixer's RMS norm of the gated values g [..., d_in]: over all
+    of them, or, with `spec.ssm_groups` > 1, over each group's apart
+    (one gain of d_in either way)."""
+    G = spec.ssm_groups
+    if G == 1:
+        return rms_norm_plain(g, p, spec.eps)
+    return rms_norm_plain(g.reshape(g.shape[:-1] + (G, -1)),
+                          {"scale": p["scale"].reshape(G, -1)},
+                          spec.eps).reshape(g.shape)
+
+
 def ssm_mix(spec, p, h, state, conv, n_valid, live=None):
     """The Mamba-2 mixer over h [B, T, D] (normed) from a request's
     `state` [B, H, P, N] float32 and the convolution's last inputs
@@ -180,7 +214,8 @@ def ssm_mix(spec, p, h, state, conv, n_valid, live=None):
     other sequence's state where it lies."""
     B, T, _ = h.shape
     H, P, N = spec.ssm_heads, spec.ssm_head_dim, spec.ssm_state
-    K, d_in = spec.ssm_conv, spec.ssm_heads * spec.ssm_head_dim
+    K, d_in, G = spec.ssm_conv, spec.ssm_heads * spec.ssm_head_dim, \
+        spec.ssm_groups
     z, xBC, dt = jnp.split(matmul32(h, p["in"]),
                            [d_in, d_in + spec.ssm_conv_width], axis=-1)
     # the convolution's inputs at the dtype they are kept in between
@@ -193,8 +228,10 @@ def ssm_mix(spec, p, h, state, conv, n_valid, live=None):
     # the last K - 1 VALID inputs: rows n_valid .. of [kept | call]
     conv = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(
         s, n, K - 1, axis=0))(seq, n_valid).astype(conv.dtype)
-    x, Bm, Cm = jnp.split(c, [d_in, d_in + N], axis=-1)
+    x, Bm, Cm = jnp.split(c, [d_in, d_in + G * N], axis=-1)
     x = x.reshape(B, T, H, P)
+    if G > 1:                      # a B and a C a group: [B, T, G, N]
+        Bm, Cm = Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N)
     valid = jnp.arange(T)[None, :] < n_valid[:, None]
     dt = jnp.where(valid[..., None], jax.nn.softplus(
         dt + p["dt_bias"].astype(jnp.float32)), 0.0)
@@ -206,13 +243,13 @@ def ssm_mix(spec, p, h, state, conv, n_valid, live=None):
         y, state = registry.dispatch(
             "ssm_step", x[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], A, state,
             *(live_slots(n_valid) if live is None else live),
-            info=ssm_step_info(state))
+            info=ssm_step_info(state, G))
         y = y[:, None]
     else:
-        y, state = ssm_scan(x, Bm, Cm, dt, A, state, min(spec.ssm_chunk, T))
+        scan = ssm_scan if G == 1 else by_group(ssm_scan, G)
+        y, state = scan(x, Bm, Cm, dt, A, state, min(spec.ssm_chunk, T))
     y = y + p["D"].astype(jnp.float32)[:, None] * x
-    g = y.reshape(B, T, d_in) * jax.nn.silu(z)
-    g = rms_norm_plain(g, p["norm"], spec.eps)
+    g = gated_norm(spec, y.reshape(B, T, d_in) * jax.nn.silu(z), p["norm"])
     return matmul32(g, p["out"]), state, conv
 
 

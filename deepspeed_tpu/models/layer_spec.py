@@ -21,10 +21,10 @@ POSITIONS = ("learned", "rope", "per_layer", "none")
 ATTENTIONS = ("paged", "eva", "latent", "grouped")
 FFNS = ("gelu_mlp", "silu_gated", "routed_experts")
 HEADS = ("tied", "untied")
-RESIDUALS = ("sequential", "parallel")
+RESIDUALS = ("sequential", "parallel", "single")
 SCORINGS = ("softmax", "sigmoid")
 SHARED = ("sum", "average", "gated")
-MIXERS = ("attention", "ssm", "gdn")
+MIXERS = ("attention", "ssm", "gdn", "none")
 
 
 class LayerSpec(NamedTuple):
@@ -92,8 +92,11 @@ class LayerSpec(NamedTuple):
                l, at index l mod its length, "attention" (the kind
                above) or "ssm": a Mamba-2 mixer (models/
                granite_hybrid.py) of `ssm_heads` heads of
-               `ssm_head_dim` over one group of `ssm_state` state
-               values, behind a causal depthwise convolution of
+               `ssm_head_dim` over `ssm_groups` groups of `ssm_state`
+               state values (head h reads the B and C of group
+               h // (ssm_heads / ssm_groups), and the gated norm is
+               taken over each group's values apart; one group: over
+               all of them), behind a causal depthwise convolution of
                `ssm_conv` taps.  Such a layer owns no cache rows: it
                keeps, a request, one float32 state [ssm_heads,
                ssm_head_dim, ssm_state] and the convolution's last
@@ -110,7 +113,10 @@ class LayerSpec(NamedTuple):
                convolution's last `gdn_conv - 1` inputs; a prefill chunk
                takes `gdn_chunk` positions at a time.  A pattern has
                state layers of one kind (`state_shapes` says what ONE
-               slot keeps for a layer of it).
+               slot keeps for a layer of it).  "none" (with the
+               "single" residual alone): the layer has no mixer — it is
+               its FFN and nothing else, and owns neither rows nor a
+               state.
     ffn        "gelu_mlp" (fc1, tanh GELU, fc2, biases) | "silu_gated"
                | "routed_experts" (a float32 router — `scoring`
                "softmax" over all experts or "sigmoid" of each — the top
@@ -125,10 +131,16 @@ class LayerSpec(NamedTuple):
                "average", their mean, or, with "gated", times the
                sigmoid of the token's product with `shared_gate`
                [D, 1]; the first `dense_layers` layers are
-               "silu_gated")
+               "silu_gated").  What an expert, and the shared
+               expert with it, is — three matrices, down(silu(gate h) *
+               up h), or two, down(relu(up h) ** 2) — is what the tree
+               holds (kernels/expert_form.py), not a field here
     head       "tied" (wte transposed) | "untied" (`lm_head`)
     residual   "sequential" (x + attn(norm1 x), then + ffn(norm2 of
-               that)) | "parallel" (one norm: x + attn(h) + ffn(h))
+               that)) | "parallel" (one norm: x + attn(h) + ffn(h)) |
+               "single" (one norm and ONE part a layer, x + part(norm1
+               x): the mixer `layer_mixers` names, or, where it says
+               "none", the FFN; models/nemotron_h.py)
     eps        the norm's epsilon
     scalars    `embed_scale` multiplies the embedding, `residual_scale`
                every branch before it is added to the stream,
@@ -164,11 +176,13 @@ class LayerSpec(NamedTuple):
     experts_held: int = 0        # routed_experts: experts held here (0: all)
     first_expert: int = 0        # routed_experts: the first one held
     layer_mixers: tuple = ()     # the pattern's "attention" | "ssm" | "gdn"
+    #                              | "none" (single: the layer is its FFN)
     ssm_heads: int = 0           # ssm: heads of the recurrence
     ssm_head_dim: int = 0        # ssm: values a head
     ssm_state: int = 0           # ssm: state values (B and C's width)
     ssm_conv: int = 0            # ssm: taps of the causal convolution
     ssm_chunk: int = 0           # ssm: positions the scan takes at once
+    ssm_groups: int = 1          # ssm: groups of heads, each with a B and C
     embed_scale: float = 1.0
     residual_scale: float = 1.0
     attn_scale: float = 0.0      # grouped: 0 is head_dim ** -0.5
@@ -220,17 +234,35 @@ class LayerSpec(NamedTuple):
         return tuple(i for i in range(num_layers)
                      if self.indexer_of(i) == "full")
 
+    def has_ffn(self, layer: int) -> bool:
+        """Whether layer `layer` has an FFN: every layer, but under the
+        "single" residual only those without a mixer."""
+        return self.residual != "single" or self.mixer_of(layer) == "none"
+
+    def routed_layers(self, num_layers: int) -> tuple:
+        """The layers of `num_layers` whose FFN routes."""
+        if self.ffn != "routed_experts":
+            return ()
+        return tuple(i for i in range(self.dense_layers, num_layers)
+                     if self.has_ffn(i))
+
     @property
     def has_state(self) -> bool:
         """Whether some layer keeps a state a request beside (or in
         place of) cache rows."""
-        return any(m != "attention" for m in self.layer_mixers)
+        return any(m in ("ssm", "gdn") for m in self.layer_mixers)
 
     def state_layers(self, num_layers: int) -> tuple:
         """The layers of `num_layers` that keep a state a request and
         no cache rows."""
         return tuple(i for i in range(num_layers)
-                     if self.mixer_of(i) != "attention")
+                     if self.mixer_of(i) in ("ssm", "gdn"))
+
+    def row_layers(self, num_layers: int) -> tuple:
+        """The layers of `num_layers` that attend, and so own cache
+        rows; a layer in neither this nor `state_layers` owns nothing."""
+        return tuple(i for i in range(num_layers)
+                     if self.mixer_of(i) == "attention")
 
     @property
     def state_shapes(self) -> tuple:
@@ -260,8 +292,10 @@ class LayerSpec(NamedTuple):
 
     @property
     def ssm_conv_width(self) -> int:
-        """Channels of the convolution: the heads' values, B and C."""
-        return self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_state
+        """Channels of the convolution: the heads' values, and the B
+        and C of every group."""
+        return self.ssm_heads * self.ssm_head_dim + \
+            2 * self.ssm_groups * self.ssm_state
 
     @property
     def held(self):
@@ -348,13 +382,26 @@ class LayerSpec(NamedTuple):
         if any(m not in MIXERS for m in self.layer_mixers) or (
                 "ssm" in self.layer_mixers) != all(n > 0 for n in sizes) \
                 or ("ssm" not in self.layer_mixers and any(sizes)) \
-                or self.ssm_conv == 1:
+                or self.ssm_conv == 1 or self.ssm_groups < 1 or (
+                    self.ssm_groups > 1 and (
+                        "ssm" not in self.layer_mixers
+                        or self.ssm_heads % self.ssm_groups)):
             raise ValueError(
-                f"layer spec: layer_mixers says \"attention\", \"ssm\" or "
-                f"\"gdn\" of each layer of the pattern, and a pattern with "
-                f"ssm layers, and nothing else, names ssm_heads, "
-                f"ssm_head_dim, ssm_state, ssm_conv >= 2 and ssm_chunk (got "
-                f"{self.layer_mixers}, {sizes})")
+                f"layer spec: layer_mixers says \"attention\", \"ssm\", "
+                f"\"gdn\" or \"none\" of each layer of the pattern, and a "
+                f"pattern with ssm layers, and nothing else, names "
+                f"ssm_heads, ssm_head_dim, ssm_state, ssm_conv >= 2, "
+                f"ssm_chunk and may name ssm_groups that divide the heads "
+                f"(got {self.layer_mixers}, {sizes}, {self.ssm_groups})")
+        single = self.residual == "single"
+        if ("none" in self.layer_mixers) != single or (
+                single and not any(m != "none" for m in self.layer_mixers)):
+            raise ValueError(
+                f"layer spec: the \"single\" residual, and nothing else, "
+                f"has layers that are their FFN alone (\"none\" in "
+                f"layer_mixers) beside layers that are their mixer alone "
+                f"(got a {self.residual!r} residual with layer_mixers "
+                f"{self.layer_mixers})")
         sizes = (self.gdn_key_heads, self.gdn_value_heads, self.gdn_key_dim,
                  self.gdn_value_dim, self.gdn_conv, self.gdn_chunk)
         if ("gdn" in self.layer_mixers) != all(n > 0 for n in sizes) \

@@ -59,14 +59,22 @@ backend):
   body, usually one trip): nothing is dropped at any number of rows
   held.  A prefill chunk: any call over the ridge.
 
-An expert is a SiLU-gated FFN; `experts` holds `gate`, `up` [E, D, F]
-and `down` [E, F, D].
+An expert has one of two forms, and `experts` says which by what it
+holds: `gate`, `up` [E, D, F] and `down` [E, F, D] — a SiLU-gated FFN,
+down(silu(gate x) * up x) — or `up` and `down` alone — down(relu(up x)
+** 2).  `expert_hidden` (kernels/expert_form.py) is the one place that
+knows: the four ways, both kernels (kernels/moe_kernels.py) and a shared
+expert of the same form (`dense_expert`) call it; E, D and F are read
+off `up`, which every form has, and `expert_matrices` counts what an
+expert streams.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from ..kernels.expert_form import expert_hidden, expert_matrices
 
 # tokens a call multiplies each streamed weight with before the products
 # cost more than the bytes: ~240 on a v5e (197 TFLOP/s over 819 GB/s);
@@ -76,6 +84,14 @@ RIDGE_TOKENS = 128
 # holds this many times the rows expected of the share held
 SLAB_ROWS = 256
 SLAB_ROOM = 2
+
+
+def dense_expert(p, x):
+    """One expert of either form on every row (a shared expert): x
+    [T, D] -> [T, D] float32, products at the weights' dtype."""
+    dot = lambda a, w: jnp.dot(a.astype(w.dtype), w,
+                               preferred_element_type=jnp.float32)
+    return dot(expert_hidden(lambda w: dot(x, w), p), p["down"])
 
 
 def route(h, router, top_k: int, scoring: str = "softmax",
@@ -153,9 +169,8 @@ def combine_weights(weights, idx, num_experts: int):
 def experts_weighted(x, experts, w):
     """Every expert on every token under the weights w [T, E] of
     `combine_weights`; x [T, D] -> [T, D] float32."""
-    g = _dot32(x, experts["gate"], "td,edf->etf")
-    u = _dot32(x, experts["up"], "td,edf->etf")
-    out = _dot32(jax.nn.silu(g) * u, experts["down"], "etf,efd->etd")
+    h = expert_hidden(lambda w: _dot32(x, w, "td,edf->etf"), experts)
+    out = _dot32(h, experts["down"], "etf,efd->etd")
     return jnp.einsum("etd,te->td", out, w)
 
 
@@ -163,7 +178,7 @@ def experts_masked(x, experts, weights, idx):
     """Every expert on every token, weighted 0 where the token did not
     choose it; x [T, D] -> [T, D] float32."""
     return experts_weighted(
-        x, experts, combine_weights(weights, idx, experts["gate"].shape[0]))
+        x, experts, combine_weights(weights, idx, experts["up"].shape[0]))
 
 
 def _by_expert(idx, num_experts: int, keep=None):
@@ -187,15 +202,14 @@ def grouped_ffn(xs, experts, offsets):
     `offsets[e]` .. `offsets[e + 1]` (offsets [E + 1]) -> [C, D]
     float32, 0 from `offsets[E]` on: rows of no group hold nothing to
     rely on."""
-    dt = experts["gate"].dtype
+    dt = experts["up"].dtype
     xs, sizes = xs.astype(dt), offsets[1:] - offsets[:-1]
 
     def grouped(a, w):
         return jax.lax.ragged_dot(a, w, sizes,
                                   preferred_element_type=jnp.float32)
 
-    h = jax.nn.silu(grouped(xs, experts["gate"])) * \
-        grouped(xs, experts["up"])
+    h = expert_hidden(lambda w: grouped(xs, w), experts)
     out = grouped(h.astype(dt), experts["down"])
     return jnp.where(jnp.arange(xs.shape[0])[:, None] < offsets[-1],
                      out, 0.0)
@@ -207,8 +221,8 @@ def experts_grouped(x, experts, weights, idx, held=None):
     assignments that lie elsewhere sort behind every group, belong to
     none and add nothing."""
     T, k = idx.shape
-    order, offsets = _by_expert(idx, experts["gate"].shape[0], held)
-    out = grouped_ffn(x.astype(experts["gate"].dtype)[order // k], experts,
+    order, offsets = _by_expert(idx, experts["up"].shape[0], held)
+    out = grouped_ffn(x.astype(experts["up"].dtype)[order // k], experts,
                       offsets)                                 # [T*k, D]
     back = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
     return jnp.einsum("tkd,tk->td", out[back].reshape(T, k, -1), weights)
@@ -237,7 +251,7 @@ def slabs_walked(idx, experts, total=None, held=None, live=None):
     """(slabs `experts_slabs` walks for this call — int32, or the int 1
     where one slab holds every row —, rows of a slab)."""
     T, k = idx.shape
-    E = experts["gate"].shape[0]
+    E = experts["up"].shape[0]
     C = slab_rows(T, k, E, E if held is None or total is None else total)
     if C == T * k:
         return 1, C
@@ -261,10 +275,10 @@ def experts_slabs(x, experts, weights, idx, total=None, held=None,
 
     T, k = idx.shape
     slabs, C = slabs_walked(idx, experts, total, held, live)
-    order, offsets = _by_expert(idx, experts["gate"].shape[0],
+    order, offsets = _by_expert(idx, experts["up"].shape[0],
                                 _kept(held, live, k))
     order = jnp.pad(order, (0, -(T * k) % C))      # whole slabs
-    xd, w = x.astype(experts["gate"].dtype), weights.reshape(T * k)
+    xd, w = x.astype(experts["up"].dtype), weights.reshape(T * k)
     info = grouped_info(T, C, experts)
 
     def slab(s, y):
@@ -288,11 +302,11 @@ def experts_slabs(x, experts, weights, idx, total=None, held=None,
 
 def touched_info(tokens: int, experts) -> dict:
     """What the kernel registry may look at to choose the routed product
-    of a call of `tokens` rows over `experts`."""
-    E, D, F = experts["gate"].shape
+    of a call of `tokens` rows over `experts` (arrays or their shapes)."""
+    E, D, F = experts["up"].shape
     return {"tokens": tokens, "num_experts": E, "model_dim": D,
-            "expert_dim": F,
-            "itemsize": jnp.dtype(experts["gate"].dtype).itemsize}
+            "expert_dim": F, "matrices": expert_matrices(experts),
+            "itemsize": jnp.dtype(experts["up"].dtype).itemsize}
 
 
 def experts_touched_only(x, experts, weights, idx, live=None, held=None):
@@ -301,7 +315,7 @@ def experts_touched_only(x, experts, weights, idx, live=None, held=None):
     a token that is not `live` [T] weighs 0 and touches nothing."""
     from ..kernels import registry
 
-    E = experts["gate"].shape[0]
+    E = experts["up"].shape[0]
     if live is None:
         live = jnp.ones((x.shape[0],), bool)
     ids, n = touched_list(idx, live, E, held)
@@ -317,7 +331,7 @@ def routed_way(tokens: int, top_k: int, experts, total=None) -> str:
     "grouped"."""
     from ..kernels import registry
 
-    E = experts["gate"].shape[0]
+    E = experts["up"].shape[0]
     total = E if total is None else total
     # on a TPU, under the ridge: never more bytes than the masked way
     if registry.resolve_impl("touched_experts",
@@ -335,13 +349,32 @@ def routed_way(tokens: int, top_k: int, experts, total=None) -> str:
     return "grouped"
 
 
+def routed_words(tokens: int, top_k: int, experts, total=None) -> str:
+    """`routed_way` in words, for a log line at build time: the way a
+    call of `tokens` rows takes here, and, where that is not the kernel's
+    walk, the registry's own reason for refusing it."""
+    from ..kernels import registry
+
+    way = routed_way(tokens, top_k, experts, total)
+    if way in ("touched", "slabs"):
+        return f"{tokens} rows take the {way!r} way (the kernel's walk)"
+    E = experts["up"].shape[0]
+    op, info = ("touched_experts", touched_info(tokens, experts)) \
+        if tokens <= RIDGE_TOKENS else ("grouped_experts", grouped_info(
+            tokens, slab_rows(tokens, top_k, E, total or E), experts))
+    kernel = registry.get_kernel(op)
+    why = kernel.auto_supports("default", info)[1] or \
+        kernel.compatibility_message()
+    return f"{tokens} rows take the {way!r} way ({op}: {why})"
+
+
 def rows_multiplied(idx, experts, total=None, held=None, live=None):
     """Assignment rows the routed product of this call multiplies with
     an expert's matrices, int32: the slabs walked times a slab's rows,
     every assignment where they are grouped whole, every row times the
     experts held (or touched) where each expert runs on every row."""
     T, k = idx.shape
-    E = experts["gate"].shape[0]
+    E = experts["up"].shape[0]
     way = routed_way(T, k, experts, total)
     if way == "slabs":
         slabs, C = slabs_walked(idx, experts, total, held, live)

@@ -143,7 +143,9 @@ Instrumented sites:
   `serve.moe.experts_streamed` — the same calls, bytes = experts whose
   weights the step's routed product read: the touched ones where it
   follows the touched list or sorts by expert, every expert held where
-  it masks (`moe/dropless.py::routed_way`, asked once at build);
+  it masks (`moe/dropless.py::routed_way`, asked once at build) — an
+  expert streamed is the matrices of its form, `expert_matrices` x
+  [D, F] values: three of a SiLU-gated expert, two of a relu2 one;
   `serve.moe.prefill_rows_multiplied` — calls = prefill chunks x routed
   layers, bytes = assignment rows the chunks' routed products
   multiplied with an expert's matrices (slabs walked x a slab's rows

@@ -465,6 +465,9 @@ class ServeEngine:
         self._window = max(spec.layer_windows, default=0)
         # layers that keep a state a slot and no rows
         self._state_layers = spec.state_layers(cfg.num_layers)
+        # layers that attend, and so own rows; a layer in neither (its
+        # FFN alone) owns nothing
+        self._row_layers = spec.row_layers(cfg.num_layers)
         if self._state_layers:
             if c.prefix_cache:
                 raise NotImplementedError(
@@ -524,8 +527,8 @@ class ServeEngine:
         self._index_topk = min(spec.index_topk,
                                table_width * c.block_size)
         # routed-FFN layers, for serve.moe.*
-        self._routed_layers = (cfg.num_layers - spec.dense_layers
-                               if spec.ffn == "routed_experts" else 0)
+        routed = spec.routed_layers(cfg.num_layers)
+        self._routed_layers = len(routed)
         self._top_k = spec.top_k
         self._held_share = spec.held is not None
         # what a decode step's routed product streams of a layer's
@@ -533,12 +536,21 @@ class ServeEngine:
         # the touched list, the experts held where every one is read
         self._streams_all = None
         if self._routed_layers:
-            from ..moe.dropless import routed_way
+            from ..moe.dropless import (expert_matrices, routed_way,
+                                        routed_words)
 
-            experts = params["blocks"][spec.dense_layers]["mlp"]["experts"]
-            if routed_way(c.max_batch * (int(c.draft_len) + 1), spec.top_k,
-                          experts, cfg.num_experts) == "masked":
-                self._streams_all = experts["gate"].shape[0]
+            experts = params["blocks"][routed[0]]["mlp"]["experts"]
+            rows = c.max_batch * (int(c.draft_len) + 1)
+            if routed_way(rows, spec.top_k, experts,
+                          cfg.num_experts) == "masked":
+                self._streams_all = experts["up"].shape[0]
+            words = lambda n: routed_words(n, spec.top_k, experts,
+                                           cfg.num_experts)
+            logger.info(
+                f"routed FFN in {len(routed)} layer(s), "
+                f"{experts['up'].shape[0]} of {cfg.num_experts} experts of "
+                f"{expert_matrices(experts)} matrices held: a decode step's "
+                f"{words(rows)}; a prefill chunk's {words(c.prefill_chunk)}")
         self._sliding_layers = sum(
             1 for i in range(cfg.num_layers) if spec.window_of(i))
         kv_dtype = cfg.param_dtype if c.kv_dtype is None else c.kv_dtype
@@ -582,6 +594,9 @@ class ServeEngine:
             index_layers=self._index_layers, index_width=spec.index_width,
             state_layers=self._state_layers,
             state_shapes=spec.state_shapes,
+            bare_layers=[i for i in range(cfg.num_layers)
+                         if i not in self._state_layers
+                         and i not in self._row_layers],
             state_counters=self._state_counters)
         # what a decode step reads and writes of it: every slot's — less,
         # where the registry answers that the recurrence walks the live
@@ -601,7 +616,8 @@ class ServeEngine:
             else:
                 from ..kernels.ssm import ssm_step_info
 
-                op, info = "ssm_step", ssm_step_info(states[0])
+                op, info = "ssm_step", ssm_step_info(states[0],
+                                                     spec.ssm_groups)
             if registry.resolve_impl(op, info=info) == "pallas":
                 self._state_dead_bytes = 2 * sum(
                     a.nbytes for a in states) // c.max_batch
@@ -622,8 +638,7 @@ class ServeEngine:
         from ..kernels import registry
         from .layers import eva_info, grouped_info, latent_info, paged_info
 
-        with_rows = next(i for i in range(cfg.num_layers)
-                         if i not in self._state_layers)
+        with_rows = self._row_layers[0]
         pool = jax.tree_util.tree_leaves(self.kv.caches[with_rows][0])[0]
         q_len = int(c.draft_len) + 1
         walks = lambda op, info: registry.resolve_impl(
@@ -1381,8 +1396,7 @@ class ServeEngine:
                 state.set(req.slot, tables=req.table)
         held = positions[[r.slot for r in lanes]].astype(np.int64) + 1
         in_window = int(np.minimum(held, self._window).sum())
-        full_layers = self.model.config.num_layers - self._sliding_layers \
-            - len(self._state_layers)
+        full_layers = len(self._row_layers) - self._sliding_layers
         if self._window:
             COUNTERS.add("serve.window.rows_read", calls=len(lanes),
                          nbytes=in_window)
@@ -1402,8 +1416,7 @@ class ServeEngine:
         entry of its run — the table, or the ring — where it gathers."""
         bs, ring = self.kv.block_size, self.kv.ring_tokens
         table = self.kv.table_width * bs
-        full_layers = self.model.config.num_layers - self._sliding_layers \
-            - len(self._state_layers)
+        full_layers = len(self._row_layers) - self._sliding_layers
 
         def fetched(walks: bool, run: int) -> int:
             return int(np.minimum(-(-held // bs) * bs, run).sum()) if walks \

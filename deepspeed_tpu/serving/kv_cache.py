@@ -158,6 +158,9 @@ it as it lies — the loop runs a step ahead, and a step launched for the
 slot's last tenant may still be to run; `reset_state(slot)` zeroes a
 slot's entries on the device, in launch order behind whatever was
 launched before it, and the engine calls it when it seats a request,
+(beside them there may be layers that own NOTHING, `bare_layers` — a
+layer that is its FFN alone, models/nemotron_h.py: an entry `()` that
+costs no byte, so the rows are laid out for the attention layers only)
 before its first prefill chunk (counted as `<state_counters>.
 state_resets`: the engine names its mixers' family, `serve.ssm` or
 `serve.gdn`).  The prefix cache, session pins,
@@ -299,7 +302,8 @@ class PagedKVCache:
                  state_layers: Sequence[int] = (),
                  state_shapes: Sequence[tuple] = (),
                  state_counters: str = "serve.ssm",
-                 index_layers: Sequence[int] = (), index_width: int = 0):
+                 index_layers: Sequence[int] = (), index_width: int = 0,
+                 bare_layers: Sequence[int] = ()):
         if num_blocks < 2:
             raise ValueError(
                 f"num_blocks must be >= 2 (block 0 is the reserved trash "
@@ -378,6 +382,15 @@ class PagedKVCache:
         self.state_layers = frozenset(int(i) for i in state_layers)
         self._state_order = tuple(sorted(self.state_layers))
         self.state_shapes = tuple(state_shapes)
+        # the layers `bare_layers` neither attend nor keep a state (a
+        # layer that is its FFN alone): an entry of nothing
+        self.bare_layers = frozenset(int(i) for i in bare_layers)
+        if self.bare_layers & self.state_layers or (
+                self.bare_layers and not self.state_layers):
+            raise ValueError(
+                f"layers that own nothing ({sorted(self.bare_layers)}) "
+                f"stand beside layers with a state, and are none of them "
+                f"({sorted(self.state_layers)})")
         # the mixers' own family of counters: serve.ssm | serve.gdn
         self.reset_counter = f"{state_counters}.state_resets"
         self.max_requests = int(max_requests)
@@ -475,6 +488,8 @@ class PagedKVCache:
             shape = (rows, pool_width(self.num_heads, self.head_dim))
 
             def entry(i):
+                if i in self.bare_layers:
+                    return ()
                 if i not in self.state_layers:
                     return (jnp.zeros(shape, self.dense_dtype),
                             jnp.zeros(shape, self.dense_dtype))
@@ -1003,10 +1018,14 @@ class PagedKVCache:
                      f"request ({self.ring_pool_blocks} blocks of their "
                      f"own)")
         if self.state_layers:
-            rows += (f" in {self.num_layers - len(self.state_layers)} "
+            with_rows = self.num_layers - len(self.state_layers) \
+                - len(self.bare_layers)
+            rows += (f" in {with_rows} "
                      f"layer(s); {len(self.state_layers)} layer(s) with no "
                      f"rows and a state a slot, {self.max_requests} slots "
                      f"({self.state_nbytes() / (1 << 20):.2f} MiB)")
+            if self.bare_layers:
+                rows += (f"; {len(self.bare_layers)} layer(s) with neither")
         return (f"PagedKVCache(layers={self.num_layers}, "
                 f"blocks={self.num_blocks} x {self.block_size} rows, {rows}, "
                 f"table_width={self.table_width}, " + (

@@ -29,12 +29,18 @@ sequential, a routed FFN in every layer; the attention layers gate their
 output, norm q and k and rotate part of a head — `spec.attn_gate`,
 `qk_norm`, `rotary_dim` — and the delta layers keep a matrix state a
 slot as the state-space ones do; models/qwen3_next.py, imported when
-such a block is first built).  The norm,
+such a block is first built), and no positions with layers that are
+each ONE part under one norm — a state-space mixer whose B and C come in
+`spec.ssm_groups` groups of heads, grouped attention, or the FFN alone —
+(the Nemotron-H block: `spec.residual` "single", a layer whose
+`spec.mixer_of` is "none" is its FFN and owns neither rows nor a
+state).  The norm,
 the FFN and the head are free of that choice.  A routed-experts FFN,
 behind leading dense layers, is one function in either block and reads
 the spec (models/cohere2_moe.py `routed_ffn`): the router's scoring,
 whether the chosen weights are renormalised, a bias that chooses and a
-factor on the weights, which experts this chip holds, and how the
+factor on the weights, which experts this chip holds, what an expert is
+(what the tree holds says: kernels/expert_form.py), and how the
 shared experts combine (their mean in the parallel block alone, behind
 a sigmoid gate in the sequential one alone).
 
@@ -180,6 +186,13 @@ def check_spec(spec: LayerSpec) -> LayerSpec:
             f"a layer that keeps a state has no positions: layer_positions "
             f"{spec.layer_positions} rotates a layer of layer_mixers "
             f"{spec.layer_mixers} that does not attend")
+    if spec.residual == "single" and (
+            spec.positions != "none" or "gdn" in spec.layer_mixers):
+        raise NotImplementedError(
+            f"one part a layer (the \"single\" residual) is built without "
+            f"positions, over state-space, grouped-attention and FFN "
+            f"layers; got {spec.positions!r} positions and layer_mixers "
+            f"{spec.layer_mixers}")
     scaled = (spec.embed_scale, spec.residual_scale, spec.logit_divisor,
               spec.attn_scale) != (1.0, 1.0, 1.0, 0.0)
     if (spec.has_state or scaled) and not hybrid:
@@ -760,46 +773,24 @@ def block(spec, cfg, p, x, kv, addr, s, layer: int = 0, sel=None,
     expert's matrices (`moe/dropless.py::rows_multiplied`; a prefill
     chunk's); `sel` is the selection of rows the layer attended where
     the spec has layers that choose (None elsewhere), for the layers
-    behind it."""
+    behind it.  Under the "single" residual the layer is ONE part under
+    its one norm (models/nemotron_h.py): its mixer, under the mixer's
+    stage, or — `spec.mixer_of(layer)` "none", a cache entry of nothing
+    — its FFN under `ffn`."""
     if spec.residual == "parallel":
         return _parallel_block(spec, cfg, p, x, kv, addr, s, layer,
                                count) + (None,)
-    mixer = spec.mixer_of(layer)
-    with jax.named_scope("attn" if mixer == "attention" else "state"):
-        h = _norm(spec, x, p["ln1"])
-        if spec.layer_indexers:
-            from .sparse import sparse_latent_attend
-
-            attn, kv, sel = sparse_latent_attend(
-                spec, cfg, p["attn"], h, kv, addr, s, layer, sel, _kv_write)
-        elif mixer == "ssm":
-            with jax.named_scope("ssm.step" if h.shape[1] == 1
-                                 else "ssm.scan"):
-                attn, *kv = _ssm_mix(spec, p["ssm"], h, *kv, addr)
-        elif mixer == "gdn":
-            from ..models.qwen3_next import gdn_mix
-
-            with jax.named_scope("gdn.step" if h.shape[1] == 1
-                                 else "gdn.scan"):
-                attn, *kv = _ssm_mix(spec, p["gdn"], h, *kv, addr, gdn_mix)
-        elif spec.attention == "grouped":
-            with jax.named_scope("gated_attend" if spec.attn_gate
-                                 else "full_attend"):
-                attn, *kv = _grouped_attend(spec, cfg, p["attn"], h, *kv,
-                                            addr, s, layer)
-        elif spec.attention == "paged":
-            with jax.named_scope("paged_attend"):
-                attn, *kv = _paged_attend(cfg, p["attn"], h, *kv, addr, s)
-        elif spec.attention == "eva":
-            with jax.named_scope("eva_attend"):
-                attn, *kv = _eva_attend(spec, cfg, p["attn"], h, *kv, addr,
-                                        s)
-        else:
-            with jax.named_scope("mla_attend"):
-                attn, *kv = _latent_attend(cfg, p["attn"], h, *kv, addr, s)
-        x = x + _scaled(attn, spec.residual_scale)
-    with jax.named_scope("ffn"):
-        h = _norm(spec, x, p["ln2"])
+    mixer, single = spec.mixer_of(layer), spec.residual == "single"
+    if mixer != "none":
+        with jax.named_scope("attn" if mixer == "attention" else "state"):
+            h = _norm(spec, x, p["ln1"])
+            attn, kv, sel = _mix(spec, cfg, p, h, kv, addr, s, layer, sel,
+                                 mixer)
+            x = x + _scaled(attn, spec.residual_scale)
+        if single:               # the layer is its mixer and nothing else
+            return x, tuple(kv), None, sel
+    with jax.named_scope("ffn"):  # (a single layer has the one norm)
+        h = _norm(spec, x, p["ln1" if single else "ln2"])
         if spec.ffn != "routed_experts" or layer < spec.dense_layers:
             return (x + _scaled(_ffn(spec, p["mlp"], h),
                                 spec.residual_scale),
@@ -814,6 +805,42 @@ def block(spec, cfg, p, x, kv, addr, s, layer: int = 0, sel=None,
             if count == "touched" else rows_multiplied(
                 idx, p["mlp"]["experts"], cfg.num_experts, held, live)
         return x + y, tuple(kv), touched, sel
+
+
+def _mix(spec, cfg, p, h, kv, addr, s, layer: int, sel, mixer: str):
+    """Layer `layer`'s mixer — attention of the spec's kind, or the
+    recurrence `mixer` names — over the normed h through its cache entry
+    -> (mixed, kv, sel)."""
+    if spec.layer_indexers:
+        from .sparse import sparse_latent_attend
+
+        return sparse_latent_attend(
+            spec, cfg, p["attn"], h, kv, addr, s, layer, sel, _kv_write)
+    if mixer == "ssm":
+        with jax.named_scope("ssm.step" if h.shape[1] == 1
+                             else "ssm.scan"):
+            attn, *kv = _ssm_mix(spec, p["ssm"], h, *kv, addr)
+    elif mixer == "gdn":
+        from ..models.qwen3_next import gdn_mix
+
+        with jax.named_scope("gdn.step" if h.shape[1] == 1
+                             else "gdn.scan"):
+            attn, *kv = _ssm_mix(spec, p["gdn"], h, *kv, addr, gdn_mix)
+    elif spec.attention == "grouped":
+        with jax.named_scope("gated_attend" if spec.attn_gate
+                             else "full_attend"):
+            attn, *kv = _grouped_attend(spec, cfg, p["attn"], h, *kv,
+                                        addr, s, layer)
+    elif spec.attention == "paged":
+        with jax.named_scope("paged_attend"):
+            attn, *kv = _paged_attend(cfg, p["attn"], h, *kv, addr, s)
+    elif spec.attention == "eva":
+        with jax.named_scope("eva_attend"):
+            attn, *kv = _eva_attend(spec, cfg, p["attn"], h, *kv, addr, s)
+    else:
+        with jax.named_scope("mla_attend"):
+            attn, *kv = _latent_attend(cfg, p["attn"], h, *kv, addr, s)
+    return attn, kv, sel
 
 
 # -- head -------------------------------------------------------------------

@@ -497,8 +497,7 @@ class ServeProgramBuilder:
     def _routed(self) -> bool:
         """Whether some layer has a routed FFN: `prefill` and `decode`
         then return their counts behind their tokens."""
-        return self.spec.ffn == "routed_experts" and \
-            self.model.config.num_layers > self.spec.dense_layers
+        return bool(self.spec.routed_layers(self.model.config.num_layers))
 
     def _prepare_params(self, params):
         """Engine-side one-time weight prep for the schedule's quant

@@ -88,6 +88,11 @@ def test_kernels_phase_toy():
                                    ssm_shape=(6, 4, 8, 16),
                                    ssm_live=3, gdn_shape=(6, 4, 16),
                                    gdn_live=3,
+                                   relu2_shape=(16, 16, 128, 256),
+                                   relu2_live=2,
+                                   relu2_slab=(160, 4, 4, 16, 128, 128),
+                                   ssm_groups_shape=(6, 4, 8, 16, 2),
+                                   ssm_groups_live=3,
                                    masked_shape=(16, 2, 24, 8, 16, 32),
                                    masked_table=(4, 16, 16, 12),
                                    masked_start=20, on_chip=False)
@@ -103,8 +108,10 @@ def test_kernels_phase_toy():
         "grouped_prefill_bf16_H4_KV2_Dh128_at21",
         "grouped_prefill_bf16_H4_KV2_Dh128_at88",
         "latent_attention_bf16_H4_W48",
-        "touched_experts_bf16_T16_E16", "grouped_experts_bf16_T160_E4of16",
-        "ssm_step_B6_H4_P8_N16_live3",
+        "touched_experts_bf16_T16_E16", "touched_experts_bf16_T16_E16_relu2",
+        "grouped_experts_bf16_T160_E4of16",
+        "grouped_experts_bf16_T160_E4of16_relu2",
+        "ssm_step_B6_H4_P8_N16_live3", "ssm_step_B6_H4_P8_N16_live3_G2",
         "gdn_step_B6_H4_D16_live3",
         "masked_latent_attention_bf16_T16_H2_tiles3"]
     # the CPU keeps the two slices right; the chip's answer is the phase's

@@ -274,7 +274,7 @@ def test_the_way_is_chosen_from_the_assignments_held():
     try:
         dropless.experts_masked = lambda *a: picked.append("masked")
         dropless.experts_grouped = lambda *a: picked.append("grouped")
-        ex = {"gate": jnp.zeros((16, 8, 4))}     # 16 of 128 held, top 8
+        ex = dict.fromkeys(("gate", "up"), jnp.zeros((16, 8, 4)))     # 16 of 128 held, top 8
         for tokens in (1, 15, 16, 128, 129, 512):
             dropless.routed_experts(None, ex, None,
                                     jnp.zeros((tokens, 8), jnp.int32),
@@ -294,12 +294,12 @@ def test_on_a_tpu_a_share_under_the_ridge_follows_the_touched_list(native):
     experts its live rows touched — one token's 8 assignments hold one
     expert on average, and none is read for the other 15 — and a
     prefill chunk walks the held rows a slab at a time (PR 58)."""
-    ex = {"gate": jax.ShapeDtypeStruct((16, 4096, 4096), jnp.bfloat16)}
+    ex = dict.fromkeys(("gate", "up"), jax.ShapeDtypeStruct((16, 4096, 4096), jnp.bfloat16))
     assert [dropless.routed_way(t, 8, ex, 128)
             for t in (1, 15, 16, 128, 129, 512)] == \
         ["touched"] * 4 + ["slabs"] * 2
     # widths the kernel cannot tile: the choice off a TPU
-    small = {"gate": jax.ShapeDtypeStruct((16, 64, 32), jnp.float32)}
+    small = dict.fromkeys(("gate", "up"), jax.ShapeDtypeStruct((16, 64, 32), jnp.float32))
     assert [dropless.routed_way(t, 8, small, 128) for t in (15, 16, 129)] \
         == ["grouped", "masked", "grouped"]
 

@@ -218,7 +218,7 @@ def test_the_way_is_chosen_from_the_calls_shapes():
     try:
         dropless.experts_masked = lambda *a: picked.append("masked")
         dropless.experts_grouped = lambda *a: picked.append("grouped")
-        ex = {"gate": jnp.zeros((64, 8, 4))}
+        ex = dict.fromkeys(("gate", "up"), jnp.zeros((64, 8, 4)))
         for tokens in (1, 10, 11, 32, 128, 129, 512):
             dropless.routed_experts(None, ex, None,
                                     jnp.zeros((tokens, 6), jnp.int32))
@@ -237,7 +237,7 @@ def test_on_a_tpu_every_call_under_the_ridge_follows_the_touched_list(
     reads only the experts its live rows touched; a prefill chunk walks
     its rows as one slab (PR 58); `DS_KERNEL_TOUCHED_EXPERTS=0` gives
     the two old ways back under the ridge."""
-    ex = {"gate": jax.ShapeDtypeStruct((64, 2048, 1408), jnp.bfloat16)}
+    ex = dict.fromkeys(("gate", "up"), jax.ShapeDtypeStruct((64, 2048, 1408), jnp.bfloat16))
     ways = lambda: [dropless.routed_way(t, 6, ex)
                     for t in (1, 10, 11, 32, 128, 129, 512)]
     assert ways() == ["touched"] * 5 + ["slabs"] * 2

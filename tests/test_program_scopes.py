@@ -3,7 +3,7 @@
 `monitor/tracing.py::program_scopes` reads a compiled program's text into
 {instruction: scope path}; `ServeEngine.attach_tracing` hands a recorder
 that map for every program the engine runs, and nothing where there is no
-recorder; every operation of the seven served families' programs lies
+recorder; every operation of the eight served families' programs lies
 under exactly one of the six stages; and a scope is metadata and nothing
 else: the compiled text without its `metadata={...}` is the text compiled
 with `jax.named_scope` doing nothing, for the serving programs and for
@@ -263,9 +263,22 @@ def _qwen3_next():
         max_seq_len=64, prefix_cache=False)
 
 
+def _nemotron_h():
+    from deepspeed_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+
+    return NemotronH(NemotronHConfig(
+        vocab_size=97, max_seq_len=64, pattern="MEM*E", d_model=32,
+        num_heads=4, kv_heads=2, head_dim=16, ssm_heads=8, ssm_head_dim=4,
+        ssm_state=8, ssm_groups=4, ssm_conv=4, ssm_chunk=4, d_expert=16,
+        d_shared=24, num_experts=16, top_k=3, init_std=0.2)), dict(
+        block_size=4, num_blocks=64, max_batch=3, prefill_chunk=8,
+        max_seq_len=64, prefix_cache=False)
+
+
 FAMILIES = {"gpt": _gpt, "evabyte": _evabyte, "deepseek_v2": _deepseek,
             "command_a": _command_a, "granite_hybrid": _granite,
             "glm_moe_dsa": _glm, "qwen3_next": _qwen3_next,
+            "nemotron_h": _nemotron_h,
             "gpt_drafting": lambda: _gpt(draft_len=2)}
 # the scopes each family's programs are known by, beneath their stages
 # (PERF.md §3 names the metric that reads each)
@@ -283,8 +296,11 @@ EXPECT = {
                     "ffn/moe_experts"},
     "qwen3_next": {"attn/gated_attend/oracle.grouped_attention",
                    "ffn/moe_route", "ffn/moe_experts", "ffn/moe_shared"},
+    "nemotron_h": {"attn/full_attend/oracle.grouped_attention",
+                   "ffn/moe_route", "ffn/moe_experts", "ffn/moe_shared"},
 }
 STATE = {"granite_hybrid": ("state/ssm.scan", "state/ssm.step"),
+         "nemotron_h": ("state/ssm.scan", "state/ssm.step"),
          "qwen3_next": ("state/gdn.scan", "state/gdn.step")}
 _TEXTS = {}
 

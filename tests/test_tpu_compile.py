@@ -1381,6 +1381,132 @@ def test_qwen3_next_cell_programs_compile_inside_one_chip(program, one_chip,
     assert total < 15.75e9 - 1.0e9 - 1.0e9, total
 
 
+_NEMOTRON_CELL = {}
+
+
+def _nemotron_h_cell_compiled(program, one_chip):
+    """The reasoning cell's `decode` or `prefill` program compiled for a
+    described v5e at the cell's shapes, once a process: (compiled, slots,
+    seq)."""
+    if program in _NEMOTRON_CELL:
+        return _NEMOTRON_CELL[program]
+    from deepspeed_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+    from deepspeed_tpu.serving import ServeProgramBuilder, ServeSchedule
+
+    slots, bs, nblocks, chunk, seq = 40, 16, 6401, 512, 2560
+    model = NemotronH(NemotronHConfig(
+        vocab_size=16384, max_seq_len=seq, experts_held=16,
+        param_dtype=jnp.bfloat16))
+    spec, cfg = model.layer_spec(), model.config
+    width = seq // bs
+    sched = ServeSchedule(max_batch=slots, prefill_chunk=chunk,
+                          block_size=bs, num_blocks=nblocks,
+                          table_width=width)
+    progs = ServeProgramBuilder(model, sched).build()
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda s: on(s.shape, s.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    held = sum(s.size * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    # (A, D, the step's bias and the choosing bias are float32)
+    assert held == 2 * 5_258_420_544 + 23 * (3 * 64 + 128) * 2
+    rows = on((nblocks * bs, 256), jnp.bfloat16)
+    state = (on((slots, 64, 64, 128), jnp.float32),
+             on((slots, 3, 6144), jnp.bfloat16))
+    caches = [{"ssm": state, "attention": (rows, rows), "none": ()}[
+        spec.mixer_of(i)] for i in range(cfg.num_layers)]
+    nbytes = lambda c: sum(a.size * a.dtype.itemsize for a in c)
+    assert sum(nbytes(c) for c in caches if c is state) == \
+        slots * 23 * 2_134_016
+    assert abs(sum(nbytes(c) for c in caches if c is not state)
+               - 0.629e9) < 1e6
+    if program == "decode":
+        args = (on((slots,), jnp.int32), on((slots,), jnp.int32),
+                on((slots,), jnp.bool_), on((slots, width), jnp.int32),
+                on((slots,), jnp.float32), on((slots,), jnp.int32),
+                on((slots,), jnp.uint32))
+    else:   # behind the table's entries: the slot
+        args = (on((1, chunk), jnp.int32), on((), jnp.int32),
+                on((), jnp.int32), on((width + 1,), jnp.int32),
+                on((), jnp.float32), on((), jnp.int32), on((), jnp.uint32))
+    compiled = progs[program].lower(params, caches, *args).compile()
+    _NEMOTRON_CELL[program] = compiled, slots, seq
+    return _NEMOTRON_CELL[program]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_nemotron_h_cell_programs_compile_inside_one_chip(program, one_chip,
+                                                          native):
+    """`nemotron-3-nano-30b-a3b-e16.serve.reasoning.decode` / `.prefill`
+    at the cell's shapes (all 52 layers at published widths in bf16, 16
+    of 128 experts, 16,384 rows of the vocabulary, 40 slots, 6,401 blocks
+    of 16 rows for the 6 attention layers, a float32 state
+    `[40, 64, 64, 128]` and `[40, 3, 6144]` convolution inputs for each
+    of the 23 Mamba-2 layers, NOTHING for the 23 expert layers, chunk
+    512): `decode`'s custom calls are the 23 mixers' `ssm_step_live`
+    kernels (8 groups of B and C), the 23 expert layers' walks of the
+    touched experts (two matrices of the whole width 1,856) and the 6
+    attention layers' walks of the live blocks; `prefill`'s the 6 walks
+    of the request's live blocks and the 23 slab products, none of
+    XLA's own grouped products; every state enters and leaves under its
+    own shape, updated in place; and weights, rows, state and
+    temporaries fit the chip's 15.75 GB with room for the check's
+    0.17 GB of reference logits and its float32 layer."""
+    compiled, slots, seq = _nemotron_h_cell_compiled(program, one_chip)
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    if program == "decode":
+        assert sum("ssm_step_live" in ln for ln in calls) == 23
+        assert sum("paged_attention_walk" in ln for ln in calls) == 6
+        assert sum("touched_experts" in ln for ln in calls) == 23
+        assert (slots, seq, 2, 128) not in _hlo_by_shape(text)
+        assert _kernels_by_scope(text) == {
+            "ssm_step": 23, "grouped_attention": 6, "touched_experts": 23}
+        by_shape = _hlo_by_shape(text)
+        state_ops = {op for shape in ((slots, 64, 64, 128),
+                                      (slots, 32, 128, 128))
+                     for op, _ in by_shape[shape]}
+        assert state_ops <= {"parameter", "custom-call", "get-tuple-element",
+                             "bitcast"}, state_ops
+    else:
+        assert sum("paged_attention_prefill_walk" in ln for ln in calls) == 6
+        assert sum("grouped_experts" in ln for ln in calls) == 23
+        assert len(calls) == 29 and "ragged" not in text
+        assert _kernels_by_scope(text) == {"grouped_attention": 6,
+                                           "grouped_experts": 23}
+    m = compiled.memory_analysis()
+    # all 46 state arrays and 12 pools are donated and aliased
+    assert m.alias_size_in_bytes > 2.5e9
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + \
+        m.output_size_in_bytes - m.alias_size_in_bytes
+    print(program, "temp", m.temp_size_in_bytes / 1e9, "total", total / 1e9)
+    assert m.temp_size_in_bytes < 1024 << 20
+    assert total < 15.75e9 - 0.17e9 - 1.0e9, total
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_nemotron_h_cell_programs_copy_no_experts_matrix(program, one_chip,
+                                                         native):
+    """Nothing in the cell's programs makes a second array the size of a
+    layer's held experts' matrix: an expert's 1,856 columns are not whole
+    128-lane tiles, so the chip keeps `up` `[16, 2688, 1856]` with D on
+    the lanes, and a Mosaic operand asked for as written would be handed
+    a copy of all 16 experts' matrix in every expert layer of every call
+    (12 ms a decode step on the chip: PERF.md section 6, PR 61).  Both
+    kernels take `up` turned (`moe_kernels._turned`), which is a bitcast
+    of the same bytes; this holds the compiler to that, whatever layout
+    it picks."""
+    compiled, _, _ = _nemotron_h_cell_compiled(program, one_chip)
+    by_shape = _hlo_by_shape(compiled.as_text())
+    ops = {op for shape in ((16, 2688, 1856), (16, 1856, 2688))
+           for op, _ in by_shape[shape]}
+    assert ops and ops <= {"parameter", "bitcast", "get-tuple-element"}, ops
+
+
 def test_evabyte_phase_after_the_described_compiles(topo):
     """This file, then tests/test_chip_smoke.py::test_evabyte_phase_toy,
     in one process: the order in which the toy EvaByte run chose bytes
