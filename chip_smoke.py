@@ -447,6 +447,9 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                   grouped_shapes=((64, 32, 8, 64, 128, 1 / 64),
                                   (16, 128, 8, 128, 1024, None),
                                   (16, 16, 2, 256, 832, None)),
+                  sliding_shape=(128, 8, 128, 288, 4096),
+                  sliding_positions=(20000, 300, 4095, 4096, 4607, 4608,
+                                     9215, -1),
                   prefill_shape=(128, 8, 128, 1024, 512),
                   prefill_starts=(0, 3003, 16084),
                   latent_shape=(32, 16, 512, 64, 256),
@@ -701,6 +704,46 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
             f"grouped_attention_bf16_H{g_heads}_KV{g_kv}_Dh{g_dim}",
             [slots, width * bs, g_heads, g_dim], got[held > 0],
             want[held > 0], rtol=0, atol=1e-2))
+
+    # a sliding layer's decode step at mixedlen's tile (128 query heads
+    # on 8 K/V heads of 128, a ring of 288 blocks under a window of
+    # 4,096): slots several laps in, short of the window, on both sides
+    # of the window's and the ring's ends, one idle; the walk of the
+    # window's blocks modulo the ring against the gather of every ring
+    # under `newest`.  bf16 rows, the default precision
+    s_heads, s_kv, s_dim, ring, window = sliding_shape
+    bs, pos = 16, np.asarray(sliding_positions)
+    slots = len(pos)
+    nblocks = 1 + slots * ring
+    tables = jnp.asarray(rs.permutation(np.arange(1, nblocks)).reshape(
+        slots, ring), jnp.int32)
+    rows = (nblocks * bs, s_kv, s_dim)
+    ck = pool_rows(jax.random.normal(key[6], rows, jnp.bfloat16))
+    cv = pool_rows(jax.random.normal(key[7], rows, jnp.bfloat16))
+    sq = jax.random.normal(key[0], (slots, 1, s_heads, s_dim), jnp.bfloat16)
+    info = {"block_size": bs, "table_width": ring, "q_len": 1,
+            "num_heads": s_heads, "kv_heads": s_kv, "head_dim": s_dim,
+            "kv_mode": "dense", "kv_itemsize": 2, "window": window,
+            "ring": True}
+    chosen = registry.resolve_impl("grouped_attention", info=info)
+    if on_chip and chosen != "pallas":
+        raise RuntimeError(
+            f"auto resolved a sliding layer's decode step at {s_heads} "
+            f"heads on {s_kv} of {s_dim}, a ring of {ring} blocks under a "
+            f"window of {window}, to {chosen!r} on this chip")
+    kw = dict(kv_heads=s_kv, block_size=bs, scale=None, window=window)
+    a = (sq, ck, cv, tables, jnp.asarray(pos[:, None], jnp.int32),
+         jnp.asarray(np.maximum(pos, 0), jnp.int32))
+    got = jax.jit(lambda q, k, v, tbl, q_pos, newest: registry.dispatch(
+        "grouped_attention", q, k, v, tbl, q_pos, info=info, newest=newest,
+        **kw))(*a)
+    want = jax.jit(lambda q, k, v, tbl, q_pos, newest:
+                   grouped_attention_reference(q, k, v, tbl, q_pos,
+                                               newest=newest, **kw))(*a)
+    out.append(_close(
+        f"sliding_attention_bf16_H{s_heads}_KV{s_kv}_Dh{s_dim}_ring{ring}",
+        [slots, ring * bs, s_heads, s_dim], got[pos >= 0], want[pos >= 0],
+        rtol=0, atol=1e-2))
 
     # and a prefill chunk of mixedlen's full layer (one request, 512
     # queries of 128 heads on 8 K/V heads of 128, a table of 1,024

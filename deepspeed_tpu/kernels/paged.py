@@ -67,10 +67,18 @@ lanes cannot sum into one output row: the program writes its `[T * Hp,
 lanes]` accumulator out, normalised, in float32, and the caller keeps
 each score row's own `Dh` lanes (`_own_lanes`) -> `[B, T, H, Dh]`.  The
 softmax scale is the caller's (Granite's `attention_multiplier` is not
-`Dh ** -0.5`).  Liveness is the paged walk's own — one causal run from
-the table's first entry; a window's lower bound and a ring's modular
-rows are not cases of it (kernels/registry.py refuses them by what
-`grouped_info` says).  At `G` = 1 nothing of this is traced.
+`Dh ** -0.5`).  Liveness is the paged walk's own in a full layer — one
+causal run from the table's first entry — and in a sliding layer's
+decode or verify step a third rule (`_live_run`, `sliding` the layer's
+window): position p lies in row p modulo the run the table names — the
+slot's ring, or the table itself — so a query's window is consecutive
+blocks modulo the table's width from the block of its lower bound, and
+the walk copies those and no other (every slot's whole ring was
+gathered before); walked row k holds position `base + k`, so the mask
+stays linear.  That needs a run of `window + q_len - 1 + block_size`
+rows (no row at or below the newest overwritten by a newer lap:
+kernels/registry.py refuses a shorter ring by what `grouped_info`
+says).  At `G` = 1 nothing of this is traced.
 
 And latent rows (`latent_attention_pallas`; serving/layers.py
 `_latent_attend`, a decode step's absorbed products): a cache row is ONE
@@ -274,33 +282,53 @@ def _tile_copies(pairs, sem, entry, n_blocks, KB, tile, slot, go):
     jax.lax.fori_loop(first, jnp.minimum(first + KB, n_blocks), one, 0)
 
 
-def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
-                 kv_mode, marker, window=0, chunk=0, G=1):
-    if kv_mode == "dense" and len(rest) == 7:
-        # one array is key and value (a latent row): the one tile is
-        # multiplied twice and a block copied once
-        k_hbm, o_ref, kbuf, sem, acc, m_s, l_s = rest
-        vbuf, pairs = kbuf, ((k_hbm, kbuf),)
-        ksbuf = vsbuf = None
-    elif kv_mode == "dense":
-        k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc, m_s, l_s = rest
-        pairs = ((k_hbm, kbuf), (v_hbm, vbuf))
-        ksbuf = vsbuf = None
-    else:
-        (k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref,
-         kbuf, ksbuf, vbuf, vsbuf, sem, acc, m_s, l_s) = rest
-        pairs = ((k_hbm, kbuf), (v_hbm, vbuf),
-                 (ks_hbm, ksbuf), (vs_hbm, vsbuf))
-    b = pl.program_id(0)
-    C, TK = T * Hp, KB * bs
+def _live_run(qp, b, *, T, bs, W, window, chunk, sliding):
+    """A slot's liveness, from its query positions and what the call says
+    of its rows: which entries of its table the walk copies and which of
+    their rows a query sees.  Three rules — one causal run from the
+    table's first entry; summarised windows' two runs (`window`, `chunk`:
+    kernels/eva.py); a sliding layer's modular run (`sliding`: the
+    layer's window) — and all the loop below knows of them (ROADMAP D11
+    would lift this to the caller, the runs as data).
+    -> (n_blocks, entry, visible): the walk is `n_blocks` long, its
+    block `blk` is table entry `entry(blk)`, and `visible(by_query)` is
+    the mask of a tile's walked row indices, `by_query(value)` laying a
+    scalar a query out over that query's score rows."""
     # T is tiny (1 decode, draft + 1 verify): the scalar position reads
     # unroll.  The slot's length is its last query's position + 1
     last = qp[b, 0]
     for t in range(1, T):
         last = jnp.maximum(last, qp[b, t])
-    if not window:
+    if sliding:
+        # a sliding layer's rows (serving/layers.py `_grouped_attend`):
+        # position p lies in row p % (W * bs) of the run the table names
+        # — a ring, or the table itself, which no position laps — and a
+        # query at p sees positions max(0, p - sliding + 1) .. p.  Those
+        # are consecutive blocks modulo W from the one that holds the
+        # oldest query's lower bound, and walked row k holds position
+        # `base + k`: the run is long enough that no row at or below
+        # `last` was overwritten by a newer lap (kernels/registry.py
+        # holds it to that), so the mask is linear, each query's own
+        # bounds; rows past `last` are masked as the causal run's are
+        lows = [jnp.maximum(jnp.where(qp[b, t] >= 0, qp[b, t], last)
+                            - sliding + 1, 0) for t in range(T)]
+        first = functools.reduce(jnp.minimum, lows) // bs
+        base = first * bs
+        n_blocks = jnp.where(last >= 0,
+                             jnp.minimum(last // bs - first + 1, W), 0)
+        entry = lambda blk: jax.lax.rem(first + blk, W)
+
+        def visible(by_query):
+            qlow = by_query(lambda t: lows[t] - base)
+            qrow = by_query(lambda t: qp[b, t] - base)
+            return lambda kidx: (kidx >= qlow) & (kidx <= qrow)
+    elif not window:
         n_blocks = jnp.clip((last + bs) // bs, 0, W)
         entry = lambda blk: blk
+
+        def visible(by_query):
+            qrow = by_query(lambda t: qp[b, t])
+            return lambda kidx: qrow >= kidx
     else:
         # summarised windows (kernels/eva.py): the live blocks are two
         # runs of the table, walked as one — the open window's, entries
@@ -318,6 +346,38 @@ def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
         n_blocks = jnp.where(last >= 0, n_win + n_sum, 0)
         entry = lambda blk: jnp.where(blk < n_win, blk,
                                       blk - n_win + window // bs)
+
+        def visible(by_query):
+            qoff = by_query(lambda t: offs[t])
+            qsum = by_query(lambda t: sums[t])
+            # (no select between masks: Mosaic has no i1 `select_n`)
+            return lambda kidx: (
+                ((kidx < n_win * bs) & (kidx <= qoff)) |
+                ((kidx >= n_win * bs) & (kidx - n_win * bs < qsum)))
+    return n_blocks, entry, visible
+
+
+def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
+                 kv_mode, marker, window=0, chunk=0, G=1, sliding=0):
+    if kv_mode == "dense" and len(rest) == 7:
+        # one array is key and value (a latent row): the one tile is
+        # multiplied twice and a block copied once
+        k_hbm, o_ref, kbuf, sem, acc, m_s, l_s = rest
+        vbuf, pairs = kbuf, ((k_hbm, kbuf),)
+        ksbuf = vsbuf = None
+    elif kv_mode == "dense":
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc, m_s, l_s = rest
+        pairs = ((k_hbm, kbuf), (v_hbm, vbuf))
+        ksbuf = vsbuf = None
+    else:
+        (k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref,
+         kbuf, ksbuf, vbuf, vsbuf, sem, acc, m_s, l_s) = rest
+        pairs = ((k_hbm, kbuf), (v_hbm, vbuf),
+                 (ks_hbm, ksbuf), (vs_hbm, vsbuf))
+    b = pl.program_id(0)
+    C, TK = T * Hp, KB * bs
+    n_blocks, entry, visible_of = _live_run(
+        qp, b, T=T, bs=bs, W=W, window=window, chunk=chunk, sliding=sliding)
     n_tiles = (n_blocks + KB - 1) // KB
     tile_copies = functools.partial(
         _tile_copies, pairs, sem, lambda blk: tbl[b, entry(blk)], n_blocks,
@@ -345,16 +405,7 @@ def _walk_kernel(tbl, qp, q_ref, *rest, scale, bs, W, KB, T, H, Hp, Dh,
                                 value(t), out)
             return out
 
-        if not window:
-            qrow = by_query(lambda t: qp[b, t])
-            visible = lambda kidx: qrow >= kidx
-        else:
-            qoff = by_query(lambda t: offs[t])
-            qsum = by_query(lambda t: sums[t])
-            # (no select between masks: Mosaic has no i1 `select_n`)
-            visible = lambda kidx: (
-                ((kidx < n_win * bs) & (kidx <= qoff)) |
-                ((kidx >= n_win * bs) & (kidx - n_win * bs < qsum)))
+        visible = visible_of(by_query)
         q = q_ref[0]                                       # (C, H * Dh)
 
         def body(i, carry):
@@ -449,21 +500,28 @@ def grouped_attention_pallas(q, ck, cv, tables, q_pos, *, kv_heads: int,
                              block_size: int, scale=None, window: int = 0,
                              newest=None):
     """Drop-in for serving/layers.py's `grouped_attention_reference`
-    where a layer attends its whole table causally (tolerance parity):
-    the walk at `G` = q's heads / `kv_heads` -> [B, T, H * Dh] float32."""
-    if window or newest is not None:
-        raise ValueError(
-            "paged attention kernel: the walk reads one causal run of a "
-            "table from its first entry; a window's lower bound and a "
-            "ring's modular rows are liveness rules it does not have")
+    (tolerance parity): the walk at `G` = q's heads / `kv_heads` ->
+    [B, T, H * Dh] float32.  A decode or verify step under a `window`
+    walks the window's live blocks modulo the table it was handed — the
+    slot's ring where `newest` is given, which is then the largest of
+    the slot's `q_pos` (serving/layers.py `_address_grouped`) and read
+    from them.  A prefill chunk walks one causal run of the request's
+    table: under a window or over a ring it has no walk (ROADMAP S14's
+    prefill half)."""
     B, T, H, Dh = q.shape
     args = dict(block_size=int(block_size), kv_heads=int(kv_heads),
                 scale=None if scale is None else float(scale),
                 interpret=pallas_backend.interpret())
     if T > STEP_QUERIES:
+        if window or newest is not None:
+            raise ValueError(
+                f"paged attention kernel: a prefill chunk of {T} queries "
+                f"walks one causal run of the request's table from its "
+                f"first entry; the chunk has no walk of a sliding run — a "
+                f"window's lower bound, a ring's modular rows")
         return _prefill_walk(q, ck, cv, tables, q_pos, **args)
     return _walk(q, ck, cv, tables, q_pos, kv_mode="dense",
-                 **args).reshape(B, T, H * Dh)
+                 sliding=int(window), **args).reshape(B, T, H * Dh)
 
 
 def prefill_tiles(q_len: int, num_heads: int, kv_heads: int, head_dim: int,
@@ -676,9 +734,10 @@ def latent_attention_pallas(q_row, pool, tables, q_pos, *, block_size: int,
 
 @functools.partial(jax.jit,
                    static_argnames=("kv_mode", "block_size", "interpret",
-                                    "window", "chunk", "kv_heads", "scale"))
+                                    "window", "chunk", "kv_heads", "scale",
+                                    "sliding"))
 def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret,
-          window=0, chunk=0, kv_heads=None, scale=None):
+          window=0, chunk=0, kv_heads=None, scale=None, sliding=0):
     """The call, as a function of its own: a program that makes it in
     every layer traces and lowers the kernel once and calls it.
     `window` > 0: the table is `[window blocks | summary blocks]` and
@@ -686,7 +745,9 @@ def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret,
     fewer than q's heads: grouped rows (dense, float32 out), a tile of
     the row's heads serving `G` = H / kv_heads query heads a key.
     `cv` None: `ck` is key and value at once (a latent row), one operand.
-    `scale`: the softmax's, `Dh ** -0.5` unless given."""
+    `scale`: the softmax's, `Dh ** -0.5` unless given.  `sliding` > 0:
+    a sliding layer's window — the table is the run its rows lie in by
+    position modulo its length, and the walk is that window's blocks."""
     B, T, H, Dh = q.shape
     W = tables.shape[1]
     bs = block_size
@@ -752,7 +813,7 @@ def _walk(q, ck, cv, tables, q_pos, *, kv_mode, block_size, interpret,
                           scale=Dh ** -0.5 if scale is None else scale,
                           bs=bs, W=W, KB=KB, T=T, H=H, Hp=Hp, Dh=Dh,
                           kv_mode=kv_mode, marker=marker, window=window,
-                          chunk=chunk, G=G),
+                          chunk=chunk, G=G, sliding=sliding),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, C if G > 1 else T, width),
                                        out_dtype),

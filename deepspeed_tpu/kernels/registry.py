@@ -383,35 +383,49 @@ class GroupedAttentionOp(KernelOp):
     `grouped_attention_pallas` for both).  Oracle = the gather of every
     table entry and `attend_grouped` under the layer's visibility mask,
     the expression the layer ran before there was a kernel
-    (`grouped_attention_reference`).  The shape rule is that the layer's
-    rows are one causal run of the table — a window or a ring
-    (`grouped_info`) keeps the gather — and then the walk's at its tile;
-    a prefill chunk's besides: one request, heads of whole 128-lane
-    tiles, tiles that fit VMEM.  The paged, latent and EVA ops keep
-    `q_len` <= `STEP_QUERIES`: their prefill is ROADMAP S11's later
-    cases."""
+    (`grouped_attention_reference`).  A decode or verify step of a
+    sliding layer (`grouped_info`'s `window`, and `ring` where its rows
+    lie in a ring) takes the same walk over the window's live blocks,
+    modulo the run — where the run is long enough that no row the walk
+    masks by position was overwritten by a newer lap: `window + q_len -
+    1 + block_size` rows.  The shape rule is then the walk's at its
+    tile; a prefill chunk's besides: one causal run of one request's
+    table (under a window or over a ring the CHUNK has no walk: ROADMAP
+    S14's prefill half), heads of whole 128-lane tiles, tiles that fit
+    VMEM.  The paged, latent and EVA ops keep `q_len` <=
+    `STEP_QUERIES`: their prefill is ROADMAP S11's later cases."""
 
     NAME = "grouped_attention"
 
     def auto_supports(self, variant, info):
         if not info:
             return True, ""
-        if info.get("ring"):
-            return False, ("the rows are a ring (position p in row p "
-                           "modulo the ring): the walk reads one run of a "
-                           "table from its first entry and has no modular "
-                           "run until the runs are data (ROADMAP D11)")
-        window = int(info.get("window", 0))
-        if window:
-            return False, (f"a window of {window} rows: the walk's one "
-                           f"liveness rule is causal from the table's first "
-                           f"entry, with no lower bound until the runs are "
-                           f"data (ROADMAP D11)")
         from .paged import STEP_QUERIES
 
-        if int(info.get("q_len", 1)) > STEP_QUERIES:
+        t, window = int(info.get("q_len", 1)), int(info.get("window", 0))
+        ring = bool(info.get("ring"))
+        if t > STEP_QUERIES:
+            if window or ring:
+                return False, (
+                    f"q_len {t} is a prefill chunk over "
+                    f"{'a ring' if ring else 'the table'} under a window "
+                    f"of {window} rows: the chunk's walk is one causal run "
+                    f"from the table's first entry and has no sliding run "
+                    f"(ROADMAP S14's prefill half); the decode step's has")
             return _prefill_walk_supports(info)
-        return _walk_supports(info)
+        ok, why = _walk_supports(info)
+        if ok and ring:
+            bs = int(info["block_size"])
+            run = int(info.get("table_width", 1)) * bs
+            need = window + t - 1 + bs
+            if run < need:
+                return False, (
+                    f"a ring of {run} rows under a window of {window}: the "
+                    f"walk masks a block's rows by position, which holds "
+                    f"where no row at or below the newest was overwritten "
+                    f"by a newer lap — a run of {need} rows or more; a "
+                    f"shorter one keeps the gather")
+        return ok, why
 
     def pallas(self, variant, *args, **kwargs):
         from . import paged

@@ -186,10 +186,12 @@ positions on the host: `serve.window.rows_read` (calls = queries
 decoded, bytes = rows one of them attends in ONE sliding layer:
 min(cached, window)), `serve.attn.rows_read` (the same summed over all
 the layers), `serve.attn.rows_walked` (calls = slots decoded, bytes =
-the pool rows the layers fetch for that: a slot's cached length rounded
-up to a block in a layer whose decode call the registry resolves to the
-walk of live blocks, its run's whole width — the table's, or the ring's
-— in a layer that gathers; asked once for each kind of layer at build),
+the pool rows the layers fetch for that: in a layer whose decode call
+the registry resolves to the walk of live blocks, a slot's cached length
+rounded up to a block — less, in a sliding layer, the blocks below the
+one its window begins in — and its run's whole width — the table's, or
+the ring's — in a layer that gathers; asked once for each kind of layer
+at build),
 `serve.attn.prefill_rows_walked` (calls = prefill chunks launched, bytes
 = the pool rows the layers fetch for a chunk: its last position + 1
 rounded up to a block — its padded tail's, the table's width at most —
@@ -1412,18 +1414,24 @@ class ServeEngine:
         """The pool rows the layers with grouped rows FETCH for calls
         that reach `held` [n] rows each — decoded slots, or the one
         request of a prefill chunk, its padded tail counted: a call's
-        live blocks where the kind of layer (full, sliding) walks, every
-        entry of its run — the table, or the ring — where it gathers."""
+        live blocks where the kind of layer (full, sliding) walks —
+        from the table's first entry in a full layer, from the block of
+        the query's lower bound `held - window` in a sliding one (a
+        decode step's walk: kernels/paged.py `_live_run`) — every entry
+        of its run — the table, or the ring — where it gathers."""
         bs, ring = self.kv.block_size, self.kv.ring_tokens
         table = self.kv.table_width * bs
         full_layers = len(self._row_layers) - self._sliding_layers
 
-        def fetched(walks: bool, run: int) -> int:
-            return int(np.minimum(-(-held // bs) * bs, run).sum()) if walks \
-                else run * len(held)
+        def fetched(walks: bool, run: int, window: int = 0) -> int:
+            if not walks:
+                return run * len(held)
+            first = np.maximum(held - window, 0) // bs if window else 0
+            return int(np.minimum((-(-held // bs) - first) * bs, run).sum())
 
         return full_layers * fetched(full_walks, table) \
-            + self._sliding_layers * fetched(sliding_walks, ring or table)
+            + self._sliding_layers * fetched(sliding_walks, ring or table,
+                                             self._window)
 
     def _count_rows_walked(self, running: List[Request], n_queries: int,
                            name: str = "serve.paged.rows_walked") -> None:

@@ -72,12 +72,14 @@ a ring row j holds follows from the newest position written: the
 largest p <= newest with p % ring = j; a query at p_q attends row j iff
 that position is >= 0, <= p_q and > p_q - window.  With `ring_blocks`
 0 (the ring would be no shorter than the table) sliding layers lie in
-the one group and mask the window on the whole table.  A full layer's
-decode step walks each slot's live blocks (kernels/paged.py, a tile of
-the row's `kv_heads` heads serving `num_heads / kv_heads` query heads a
-key) where the registry picks the kernel; a ring and a window are
-liveness rules the walk does not have, say so in `grouped_info`, and are
-gathered in `jax.numpy` (`grouped_attention_reference`).
+the one group and mask the window on the whole table.  A decode step
+walks each slot's live blocks (kernels/paged.py, a tile of the row's
+`kv_heads` heads serving `num_heads / kv_heads` query heads a key) where
+the registry picks the kernel: a full layer's from the table's first
+entry, a sliding layer's the blocks its window lies in, modulo the run
+(`grouped_info` says `window` and `ring`).  A prefill chunk under a
+window has no walk and gathers its run in `jax.numpy`
+(`grouped_attention_reference`), as every call does off the chip.
 
 Layers with a state and no rows (`spec.mixer_of(layer)` "ssm",
 models/granite_hybrid.py, or "gdn", models/qwen3_next.py): such a
@@ -600,10 +602,14 @@ def grouped_info(spec, cfg, s, q_len: int, cache_dtype, window: int = 0,
                  ringed: bool = False, batch: int = 1) -> dict:
     """`paged_info` over rows of `kv_heads` heads, what the layer's
     rows are beside one causal run of the table — a `window` (0: none),
-    a `ring` — and how many sequences the call's queries belong to."""
-    return dict(paged_info(cfg, s, q_len, cache_dtype),
+    a `ring`, whose entries are then the table the call hands over — and
+    how many sequences the call's queries belong to."""
+    info = dict(paged_info(cfg, s, q_len, cache_dtype),
                 kv_heads=spec.kv_heads, window=window, ring=ringed,
                 batch=batch)
+    if ringed:
+        info["table_width"] = s.ring_blocks
+    return info
 
 
 def grouped_attention_reference(q, ck, cv, tables, q_pos, *, kv_heads: int,
@@ -636,12 +642,13 @@ def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
     and the cache two groups, through the table else; softmax over the
     rows the layer lets a query see — q and k normed, part of the head
     rotated and the attended values gated where the spec says so
-    (models/qwen3_next.py `project_gated`) — (through the kernel registry: where
-    the layer attends its whole table causally, on the chip, the walk of
-    each slot's live blocks in a decode or verify step and of the one
-    request's in a prefill chunk of heads of whole 128-lane tiles,
-    kernels/paged.py; the gather of every table entry elsewhere, under a
-    window, over a ring and in a chunk of narrower heads); output
+    (models/qwen3_next.py `project_gated`) — (through the kernel registry: on
+    the chip, the walk of each slot's live blocks in a decode or verify
+    step — the whole table's, or under a window the window's, modulo the
+    ring — and, where the layer attends its whole table causally, of the
+    one request's in a prefill chunk of heads of whole 128-lane tiles,
+    kernels/paged.py; the gather of every table entry elsewhere, in a
+    chunk under a window and in a chunk of narrower heads); output
     projection.  -> float32."""
     B, T, _ = h.shape
     KV, Dh = spec.kv_heads, cfg.head_dim

@@ -79,6 +79,9 @@ def test_kernels_phase_toy():
                                    eva_positions=(0, 31, 32, 100, -1),
                                    grouped_shapes=((6, 8, 2, 64, 4, 1 / 64),
                                                    (3, 4, 2, 16, 3, None)),
+                                   sliding_shape=(4, 2, 16, 6, 32),
+                                   sliding_positions=(200, 5, 31, 32, 95, 96,
+                                                      -1),
                                    prefill_shape=(4, 2, 128, 6, 16),
                                    prefill_starts=(0, 21, 88),
                                    latent_shape=(3, 4, 32, 16, 3),
@@ -104,6 +107,7 @@ def test_kernels_phase_toy():
         "paged_attention_dense_H2_Dh128", "eva_attention_bf16_H2_Dh64",
         "grouped_attention_bf16_H8_KV2_Dh64",
         "grouped_attention_bf16_H4_KV2_Dh16",
+        "sliding_attention_bf16_H4_KV2_Dh16_ring6",
         "grouped_prefill_bf16_H4_KV2_Dh128_at0",
         "grouped_prefill_bf16_H4_KV2_Dh128_at21",
         "grouped_prefill_bf16_H4_KV2_Dh128_at88",

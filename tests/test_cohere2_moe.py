@@ -677,9 +677,10 @@ def test_engine_refuses_sessions_and_a_mesh_by_name():
 
 def test_the_registry_is_asked_for_each_kind_of_layer(native):
     """What `_grouped_attend` hands the registry says what the layer's
-    rows are: on the chip a full layer's decode call takes the walk, a
-    ring and a window on the table keep the gather and name what the walk
-    lacks, and so does a prefill chunk."""
+    rows are: on the chip a decode call takes the walk — a full layer's,
+    and a sliding layer's in its ring or on the table — and a prefill
+    chunk of these narrow heads keeps the gather; under a window it is
+    the chunk, not the run, that has no walk."""
     model, params = _model()
     spec, cfg = model.layer_spec(), model.config
     sched = ServeSchedule(max_batch=3, prefill_chunk=CHUNK, block_size=BS,
@@ -688,20 +689,28 @@ def test_the_registry_is_asked_for_each_kind_of_layer(native):
     info = serving_layers.grouped_info(spec, cfg, sched, 1, jnp.float32)
     assert info["kv_heads"] == KV and info["num_heads"] == HEADS
     assert (info["window"], info["ring"]) == (0, False)
+    assert info["table_width"] == 256 // BS
+    # over a ring the table the call hands over is the ring's entries
+    assert serving_layers.grouped_info(
+        spec, cfg, sched, 1, jnp.float32, WINDOW, True)["table_width"] == \
+        RING // BS
     ask = lambda *a: registry.resolve_impl(
         "grouped_attention", info=serving_layers.grouped_info(
             spec, cfg, sched, *a))
     assert ask(1, jnp.float32) == "pallas"
-    assert ask(1, jnp.float32, WINDOW, True) == "jnp"
-    assert ask(1, jnp.float32, WINDOW, False) == "jnp"
+    assert ask(1, jnp.float32, WINDOW, True) == "pallas"
+    assert ask(1, jnp.float32, WINDOW, False) == "pallas"
     assert ask(CHUNK, jnp.float32) == "jnp"
+    assert ask(CHUNK, jnp.float32, WINDOW, True) == "jnp"
     assert ask(1, jnp.bfloat16) == "jnp"     # blocks of 8 rows of bf16
     for kind, why in (((WINDOW, True), "a ring"), ((WINDOW, False),
-                                                   "a window of 32 rows")):
-        with pytest.raises(RuntimeError, match=f"{why}.*ROADMAP D11"):
+                                                   "the table")):
+        with pytest.raises(RuntimeError, match=(
+                f"q_len {CHUNK} is a prefill chunk over {why} under a "
+                f"window of 32 rows.*S14's prefill half")):
             registry.resolve_impl(
                 "grouped_attention", impl="pallas",
-                info=serving_layers.grouped_info(spec, cfg, sched, 1,
+                info=serving_layers.grouped_info(spec, cfg, sched, CHUNK,
                                                  jnp.float32, *kind))
 
 
@@ -747,10 +756,12 @@ def test_rows_walked_is_what_each_kind_of_layer_fetches(way, ringed,
                                                         chip_rule):
     """`serve.attn.rows_walked`: a full layer fetches a slot's cached
     length rounded up to a block where its decode is the walk and the
-    table's whole width where it is the gather; a sliding layer always
-    gathers — its ring, or (one group: the ring would be no shorter than
-    the table) the table under the window.  The kernel serves the
-    oracle's tokens."""
+    table's whole width where it is the gather; a sliding layer the
+    blocks its window lies in — counted here position by position, for
+    slots before and after the ring's wrap — where its decode is the
+    walk, and its whole run — its ring, or (one group: the ring would be
+    no shorter than the table) the table under the window — where it is
+    the gather.  The kernel serves the oracle's tokens."""
     import contextlib
 
     model, params = _model()
@@ -764,20 +775,26 @@ def test_rows_walked_is_what_each_kind_of_layer_fetches(way, ringed,
         out = eng.generate(prompts, 6)
     d = COUNTERS.delta_since(before)
     assert bool(eng.kv.ring_blocks) == ringed
-    assert eng._walks_live_blocks == (way == "kernel")
-    assert not eng._sliding_walks
+    assert eng._walks_live_blocks == eng._sliding_walks == (way == "kernel")
     held = [n + i + 1 for n in lengths for i in range(5)]
     table = eng.kv.table_width * BS
-    full = sum(-(-h // BS) * BS for h in held) if way == "kernel" \
-        else 10 * table
+    run = RING if ringed else table
+    if way == "kernel":
+        full = sum(-(-h // BS) * BS for h in held)
+        # the blocks that hold positions max(0, h - window) .. h - 1
+        sliding = sum(BS * len({p // BS for p in range(
+            max(0, h - WINDOW), h)}) for h in held)
+        assert sliding < 10 * run
+        assert not ringed or max(held) > RING > min(held)   # the wrap
+    else:
+        full, sliding = 10 * table, 10 * run
     assert d["serve.attn.rows_walked"] == {
-        "calls": 10,
-        "bytes": 2 * full + 6 * 10 * (RING if ringed else table)}
+        "calls": 10, "bytes": 2 * full + 6 * sliding}
     assert d["serve.attn.rows_read"]["bytes"] <= \
         d["serve.attn.rows_walked"]["bytes"]
     if way == "kernel":
-        # decode's two full layers, and no other call, took the kernel
-        assert d["kernel.dispatches"]["calls"] == 2
+        # decode's eight layers, and no other call, took the kernel
+        assert d["kernel.dispatches"]["calls"] == LAYERS
         assert out == ServeEngine(model, params, serve).generate(prompts, 6)
     else:
         assert "kernel.dispatches" not in d

@@ -275,16 +275,126 @@ def test_paged_attention_slot_ignores_the_other_slots(shape):
     assert np.array_equal(a[0], b[0])
 
 
-def test_grouped_attention_kernel_refuses_a_window_and_a_ring():
-    """Forced onto rows it has no liveness rule for, the kernel says so
-    and computes nothing."""
-    q, ck, cv, tables, q_pos, bs = _paged_inputs("dense", **{
-        k: v for k, v in _GRANITE.items() if k != "scale"})
-    for kw in (dict(window=8), dict(newest=q_pos[:, 0])):
+def test_grouped_attention_kernel_refuses_a_chunk_under_a_window_or_a_ring():
+    """Forced onto a prefill chunk of rows it has no liveness rule for,
+    the kernel says so and computes nothing."""
+    q, ck, cv, tables, q_pos, bs = _paged_inputs(
+        "dense", T=16, H=4, KV=2, Dh=128, bs=8, W=8, lengths=[40, 16])
+    for kw in (dict(window=8), dict(newest=q_pos[:, -1])):
         with kernel_config(interpret=True), \
-                pytest.raises(ValueError, match="one causal run"):
+                pytest.raises(ValueError, match="no walk of a sliding run"):
             registry.dispatch("grouped_attention", q, ck, cv, tables, q_pos,
-                              impl="pallas", kv_heads=8, block_size=bs, **kw)
+                              impl="pallas", kv_heads=2, block_size=bs, **kw)
+
+
+def _sliding_both(p, T=1, H=8, KV=2, Dh=16, bs=8, M=6, window=32, ring=True,
+                  scale=0.3, seed=0):
+    """(kernel, oracle, live) of one decode or verify call of a sliding
+    layer: slots whose newest positions are `p` (negative: idle), each
+    with its own run of `M` blocks of `bs` rows — a ring, the rows by
+    position modulo `M * bs`, or (`ring` false) the table, which no
+    position laps — and its last `T` positions as queries."""
+    from deepspeed_tpu.serving.kv_cache import pool_rows
+    from deepspeed_tpu.serving.layers import grouped_attention_reference
+
+    rng = np.random.RandomState(seed)
+    p = np.asarray(p)
+    R = len(p)
+    ck, cv = (pool_rows(jnp.asarray(
+        rng.randn((R * M + 1) * bs, KV, Dh), jnp.float32)) for _ in range(2))
+    tables = jnp.asarray(
+        1 + rng.permutation(R * M).reshape(R, M), jnp.int32)
+    q_pos = p[:, None] - T + 1 + np.arange(T)[None, :]
+    q_pos = jnp.asarray(np.where(q_pos < 0, -1, q_pos), jnp.int32)
+    q = jnp.asarray(rng.randn(R, T, H, Dh), jnp.float32)
+    args = dict(kv_heads=KV, block_size=bs, scale=scale, window=window,
+                newest=jnp.asarray(np.maximum(p, 0), jnp.int32)
+                if ring else None)
+    ref = grouped_attention_reference(q, ck, cv, tables, q_pos, **args)
+    with kernel_config(interpret=True):
+        out = registry.dispatch("grouped_attention", q, ck, cv, tables, q_pos,
+                                impl="pallas", **args)
+    assert out.shape == ref.shape and out.dtype == ref.dtype == jnp.float32
+    return np.asarray(out), np.asarray(ref), p >= 0
+
+
+# a ring of L = 48 rows (6 blocks of 8) under a window of 32
+_L = 48
+
+
+@pytest.mark.parametrize("case", [
+    # a slot shorter than the window, on and off a block's edge
+    dict(p=[0, 5, 7, 8, 30]),
+    # the newest position at window - 1, window, L - 1, L, L + 1
+    dict(p=[31, 32, _L - 1, _L, _L + 1]),
+    # several laps; the lower bound on a block's edge (p - 31 = 8 k) and
+    # off it; the run wrapping the ring's last block into its first
+    dict(p=[3 * _L + 7, 5 * _L, 39, 40, _L + 7, 2 * _L + 15]),
+    # an idle slot beside live ones
+    dict(p=[-1, _L + 3, -1, 12]),
+    # a verify step's four queries: each its own lower bound, the oldest
+    # query's the walk's; a slot that holds the step alone
+    dict(p=[5, 31, 32, 34, _L - 1, _L, _L + 2, 3 * _L + 7, -1, 3], T=4),
+    dict(p=[2 * _L + 1, 40, -1], T=4, M=7),
+    # the window on the table (`ring_blocks` 0): the same rule, no wrap
+    dict(p=[5, 31, 32, 40, 63, 0], M=8, ring=False),
+    dict(p=[34, 63, 9, -1], M=8, ring=False, T=4),
+    # the window a whole number of blocks short of the run by one block
+    # (the least the registry lets through at q_len 1: 32 + 8 = 40)
+    dict(p=[39, 40, 41, 200, 7], M=5),
+    # Command A+'s heads and Granite's: G = 16 and 4, lanes of whole
+    # tiles; blocks of 16 over several tiles of the walk
+    dict(p=[5, 300, 4 * 320 + 17, -1], H=128, KV=8, Dh=128, bs=16, M=20,
+         window=288, scale=None),
+    dict(p=[287, 288, 319, 320, 321], H=32, KV=8, Dh=64, bs=16, M=20,
+         window=288, scale=1 / 64),
+    dict(p=[700, 3, -1], H=16, KV=1, Dh=128, bs=16, M=4, window=40),
+], ids=_paged_case_id)
+def test_sliding_walk_parity(case):
+    """A sliding layer's decode or verify call: the walk of the window's
+    live blocks modulo the run — its mask linear in the walked row, each
+    query's own bounds — against the gather of the whole run under
+    `_visible` over `newest - (newest - j) % L`.  An idle slot reads
+    nothing and leaves zeros; the engine discards them."""
+    out, ref, live = _sliding_both(**case)
+    np.testing.assert_allclose(out[live], ref[live], atol=5e-6)
+    assert not out[~live].any()
+
+
+def test_sliding_walk_reads_only_the_windows_blocks():
+    """The walk copies the blocks the window lies in and no other: with
+    NaN in every other block of a wrapped slot's ring and of a short
+    slot's, the output is finite and the oracle's (whose gather weighs
+    those rows by exactly 0 only where they are finite: compared on a
+    clean copy)."""
+    from deepspeed_tpu.kernels.paged import grouped_attention_pallas
+    from deepspeed_tpu.serving.kv_cache import pool_rows
+    from deepspeed_tpu.serving.layers import grouped_attention_reference
+
+    rng = np.random.RandomState(0)
+    bs, M, KV, Dh, H, window = 8, 8, 2, 16, 8, 24
+    p = np.array([2 * M * bs + 3, 13])           # wrapped; short
+    tables = 1 + rng.permutation(2 * M).reshape(2, M).astype(np.int32)
+    clean = [rng.randn((2 * M + 1) * bs, KV, Dh).astype(np.float32)
+             for _ in range(2)]
+    dirty = [c.copy() for c in clean]
+    for slot, newest in enumerate(p):
+        lo = max(0, newest - window + 1)
+        held = {b % M for b in range(lo // bs, newest // bs + 1)}
+        for entry in set(range(M)) - held:
+            for c in dirty:
+                c[tables[slot, entry] * bs:][:bs] = np.nan
+    q = jnp.asarray(rng.randn(2, 1, H, Dh), jnp.float32)
+    args = dict(kv_heads=KV, block_size=bs, window=window,
+                newest=jnp.asarray(p, jnp.int32))
+    pools = lambda cs: [pool_rows(jnp.asarray(c)) for c in cs]
+    q_pos = jnp.asarray(p[:, None], jnp.int32)
+    ref = grouped_attention_reference(q, *pools(clean), jnp.asarray(tables),
+                                      q_pos, **args)
+    with kernel_config(interpret=True):
+        out = grouped_attention_pallas(q, *pools(dirty), jnp.asarray(tables),
+                                       q_pos, **args)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
 
 
 def test_grouped_oracle_is_the_expression_the_layer_ran():
@@ -325,8 +435,16 @@ _GRANITE_INFO = dict(block_size=16, table_width=128, q_len=1, num_heads=32,
     (dict(num_heads=128, head_dim=128, table_width=1024), None),
     # a prefill chunk of Granite's heads: half a lane tile each
     (dict(q_len=512), "8 K/V heads of 64 values.*whole 128-lane tiles"),
-    (dict(window=4096), "a window of 4096 rows.*ROADMAP D11"),
-    (dict(window=4096, ring=True), "the rows are a ring.*ROADMAP D11"),
+    # a sliding layer's decode call: the window on the table, and in a
+    # ring long enough that the walk's linear mask holds (Command A+'s
+    # 288 blocks); a shorter ring keeps the gather, and so does the chunk
+    (dict(window=4096), None),
+    (dict(window=4096, ring=True, table_width=288), None),
+    (dict(window=4096, ring=True, table_width=257), None),
+    (dict(window=4096, ring=True, table_width=257, q_len=4),
+     "a ring of 4112 rows under a window of 4096.*a run of 4115 rows"),
+    (dict(window=4096, ring=True, table_width=256),
+     "a ring of 4096 rows under a window of 4096.*a run of 4112 rows"),
     (dict(block_size=8), "a block of 8 rows is not whole tiles"),
     (dict(kv_mode="int8"), "int8 rows"),
     (dict(q_len=8, num_heads=128, head_dim=128),
@@ -334,10 +452,11 @@ _GRANITE_INFO = dict(block_size=16, table_width=128, q_len=1, num_heads=32,
 ], ids=lambda v: _paged_case_id(v) if isinstance(v, dict) else "")
 def test_grouped_attention_shape_rule(change, why, native):
     """What the call site can see decides (serving/layers.py::
-    grouped_info): a full layer's decode call takes the walk on the chip
-    at the grouped tile; prefill, a window, a ring and every shape the
-    walk cannot copy take the gather and say what is missing when the
-    kernel is forced."""
+    grouped_info): a decode call takes the walk on the chip at the
+    grouped tile — a full layer's one causal run, a sliding layer's
+    window modulo its run; prefill, a ring too short for the walk's mask
+    and every shape the walk cannot copy take the gather and say what is
+    missing when the kernel is forced."""
     info = dict(_GRANITE_INFO, **change)
     if why is None:
         assert resolve_impl("grouped_attention", info=info) == "pallas"
@@ -442,8 +561,13 @@ _COMMAND_A_CHUNK = dict(_GRANITE_INFO, q_len=512, num_heads=128,
     ({}, None),
     (dict(q_len=1024), None),
     (dict(kv_itemsize=4, block_size=8), None),
-    (dict(window=4096, ring=True), "the rows are a ring.*ROADMAP D11"),
-    (dict(window=4096), "a window of 4096 rows.*ROADMAP D11"),
+    # the CHUNK has no walk of a sliding run, whatever the decode step has
+    (dict(window=4096, ring=True, table_width=288),
+     "q_len 512 is a prefill chunk over a ring under a window of 4096 "
+     "rows.*no sliding run.*S14's prefill half"),
+    (dict(window=4096),
+     "q_len 512 is a prefill chunk over the table under a window of 4096 "
+     "rows.*no sliding run.*S14's prefill half"),
     (dict(head_dim=64), "8 K/V heads of 64 values.*whole 128-lane tiles"),
     (dict(batch=2), "2 sequences of 512 queries.*one request's table"),
     (dict(kv_mode="int8"), "int8 rows"),

@@ -461,7 +461,7 @@ CASES = [
          op="eva_attention", refused=r"8 summary rows are not whole"),
     # grouped rows: a full layer's decode call at the two cells' tiles
     # (32 x 512 and 128 x 1,024), a verify step, and what keeps the
-    # gather — prefill, a window on the table, a ring
+    # gather
     Case("grouped_H32_KV8_Dh64_chatrate", _grouped, op="grouped_attention"),
     Case("grouped_H128_KV8_Dh128_mixedlen_full", _command_a_full,
          op="grouped_attention"),
@@ -481,14 +481,27 @@ CASES = [
          lambda: _command_a_full(slots=2, q_len=512),
          op="grouped_attention",
          refused=r"2 sequences of 512 queries.*one request's table"),
+    # a sliding layer's decode call walks its window's live blocks modulo
+    # the run: on the table, and in the cell's ring (16 slots, 288 blocks
+    # of 16 rows); the CHUNK under a window keeps the gather
     Case("grouped_H128_KV8_Dh128_window_on_the_table",
          lambda: _command_a_full(window=4096),
-         op="grouped_attention",
-         refused=r"a window of 4096 rows: .*no lower bound.*D11"),
+         op="grouped_attention"),
     Case("grouped_H128_KV8_Dh128_mixedlen_ring",
          lambda: _command_a_full(window=4096, ring=True, width=288,
                                  nblocks=16 * 288 + 1),
-         op="grouped_attention", refused=r"rows are a ring .*D11"),
+         op="grouped_attention"),
+    Case("grouped_H128_KV8_Dh128_prefill512_window_on_the_table",
+         lambda: _command_a_full(slots=1, q_len=512, window=4096),
+         op="grouped_attention",
+         refused=r"q_len 512 is a prefill chunk over the table under a "
+                 r"window of 4096 rows: .*no sliding run.*S14"),
+    Case("grouped_H128_KV8_Dh128_prefill512_mixedlen_ring",
+         lambda: _command_a_full(slots=1, q_len=512, window=4096, ring=True,
+                                 width=288, nblocks=16 * 288 + 1),
+         op="grouped_attention",
+         refused=r"q_len 512 is a prefill chunk over a ring under a "
+                 r"window of 4096 rows: .*no sliding run.*S14"),
     # latent rows: a decode call at the chatgen cell's tile (16 score
     # rows of 640 lanes over one operand), a verify step, DeepSeek-V2's
     # 128 heads, and what keeps the gather
@@ -666,7 +679,8 @@ def _serve_attention(q_len, slots):
      lambda: ("grouped_attention", _command_a_full()[2]), "pallas"),
     ("command-a-plus-d4.serve.mixedlen.decode.sliding",
      lambda: ("grouped_attention",
-              _command_a_full(window=4096, ring=True, width=288)[2]), "jnp"),
+              _command_a_full(window=4096, ring=True, width=288)[2]),
+     "pallas"),
     # one request's chunk of 512: the full layer walks, the rings gather
     ("command-a-plus-d4.serve.mixedlen.prefill.full",
      lambda: ("grouped_attention",
@@ -1113,11 +1127,13 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     experts and an eighth of the vocabulary, 16 slots, 16,385 blocks of
     16 rows for the full layer and 16 x 288 + 1 for each sliding one, a
     table of 1,024 + 288 entries, chunk 512), as the chip traces them:
-    the registry answers the walk for the full layer's decode call and
-    its prefill call and the gather for the three rings, so `decode`'s
-    custom calls are the 4 layers' `touched_experts` kernels and the full
-    layer's one walk — no slot's whole table of 16,384 rows is laid out
-    as `[16, 16384, 8, 128]` — and `prefill`'s are XLA's own grouped
+    the registry answers the walk for every layer's decode call (PR 62:
+    the three rings' too, the window's live blocks modulo the ring) and
+    for the full layer's prefill call, and the gather for the three
+    rings' prefill call, so `decode`'s custom calls are the 4 layers'
+    `touched_experts` kernels and the 4 layers' walks — no slot's whole
+    table of 16,384 rows is laid out as `[16, 16384, 8, 128]` and no
+    slot's whole ring as `[16, 4608, 8, 128]` — and `prefill`'s are XLA's own grouped
     products (`lax.ragged_dot` over the 16 held experts) and the full
     layer's one prefill walk — the request's whole table is not gathered
     (`[1, 16384, 8, 128]`) nor scored (`[1, 1, 16, 512, 16384]`, a K/V
@@ -1141,7 +1157,7 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     ask = lambda q_len, *kind: registry.resolve_impl(
         "grouped_attention", info=grouped_info(
             spec, model.config, sched, q_len, jnp.bfloat16, *kind))
-    assert (ask(1), ask(1, 4096, True)) == ("pallas", "jnp")
+    assert (ask(1), ask(1, 4096, True)) == ("pallas", "pallas")
     assert (ask(chunk), ask(chunk, 4096, True)) == ("pallas", "jnp")
     progs = ServeProgramBuilder(model, sched).build()
 
@@ -1172,12 +1188,14 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     if program == "decode":
-        assert len(calls) == layers + 1
+        assert len(calls) == 2 * layers
         assert sum("touched_experts" in ln for ln in calls) == layers
-        assert sum("paged_attention_walk" in ln for ln in calls) == 1
-        assert (slots, 16384, 8, 128) not in _hlo_by_shape(text)
+        assert sum("paged_attention_walk" in ln for ln in calls) == layers
+        assert not {(slots, 16384, 8, 128), (slots, 4608, 8, 128),
+                    (slots * 4608 // 8, 8, 8, 128)} & set(
+                        _hlo_by_shape(text))
         assert _kernels_by_scope(text) == {"touched_experts": layers,
-                                           "grouped_attention": 1}
+                                           "grouped_attention": layers}
     else:
         walks = [ln for ln in calls if "paged_attention_prefill_walk" in ln]
         assert len(walks) == 1
