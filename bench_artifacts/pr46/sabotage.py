@@ -34,16 +34,19 @@ def sabotages():
         return lambda x, w, *a, **kw: orig(low(x), w, *a, **kw)
 
     def in_prefill(before=None, after=None):
-        """`layers.ssm_mix` for a prefill chunk (T > 1) with
+        """`granite_hybrid.ssm_mix` (which serving/layers.py loads through
+        models/layer_spec.py STATE_MIXERS since PR 63, when a block is
+        built) for a prefill chunk (T > 1) with
         `before(state, conv, n_valid, T)` applied to what it is handed
         and `after(state, conv)` to what it hands on."""
         def wrap(orig):
-            def mix(spec, p, h, state, conv, n_valid):
+            def mix(spec, p, h, state, conv, n_valid, live=None):
                 chunk = h.shape[1] > 1
                 if chunk and before:
                     state, conv, n_valid = before(state, conv, n_valid,
                                                   h.shape[1])
-                out, state, conv = orig(spec, p, h, state, conv, n_valid)
+                out, state, conv = orig(spec, p, h, state, conv, n_valid,
+                                        live)
                 if chunk and after:
                     state, conv = after(state, conv)
                 return out, state, conv
@@ -59,21 +62,21 @@ def sabotages():
         "none": [],
         # a chunk starts from zeros: lost between one chunk and the next
         "a1_state_not_carried_from_chunk_to_chunk": [
-            (layers, "ssm_mix", in_prefill(
+            (granite_hybrid, "ssm_mix", in_prefill(
                 before=lambda s, c, n, T: (jnp.zeros_like(s), c, n)))],
         # a chunk hands zeros on: lost before the next chunk AND before
         # the first decode step
         "a2_state_not_carried_past_any_chunk": [
-            (layers, "ssm_mix", in_prefill(
+            (granite_hybrid, "ssm_mix", in_prefill(
                 after=lambda s, c: (jnp.zeros_like(s), c)))],
         "b1_conv_rows_dropped_from_chunk_to_chunk": [
-            (layers, "ssm_mix", in_prefill(
+            (granite_hybrid, "ssm_mix", in_prefill(
                 before=lambda s, c, n, T: (s, jnp.zeros_like(c), n)))],
         "b2_conv_rows_dropped_past_any_chunk": [
-            (layers, "ssm_mix", in_prefill(
+            (granite_hybrid, "ssm_mix", in_prefill(
                 after=lambda s, c: (s, jnp.zeros_like(c))))],
         "c_padded_tail_moves_state_and_rows": [
-            (layers, "ssm_mix", in_prefill(
+            (granite_hybrid, "ssm_mix", in_prefill(
                 before=lambda s, c, n, T: (s, c, jnp.full_like(n, T))))],
         "d_seated_slot_keeps_its_last_tenants_state": [
             (PagedKVCache, "reset_state", lambda o: (

@@ -28,6 +28,7 @@ import re
 import sys
 import tempfile
 import time
+import types
 import traceback
 
 import numpy as np
@@ -463,6 +464,9 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                   ssm_groups_shape=(40, 64, 64, 128, 8), ssm_groups_live=24,
                   masked_shape=(512, 64, 192, 64, 256, 512),
                   masked_table=(16, 1536, 1024, 2048), masked_start=4608,
+                  conv_shape=(96, 2048, 3, 512), conv_live=64,
+                  held_shape=(96, 4, 8, 64, 2048, 1536), held_live=64,
+                  held_slab=(512, 4, 8, 64, 2048, 1536),
                   on_chip=True) -> dict:
     """Each kernel `auto` selects on this chip, once, natively, at the
     main path's shapes, against its jnp oracle at tier-1's tolerance
@@ -1015,6 +1019,109 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
     if int(mask.sum(-1).min()) != min(topk, masked_start + 1):
         raise RuntimeError("the selection check's queries chose other than "
                            "`topk` rows")
+
+    # the gated short convolution at the assist cell's shape (PR 63; no
+    # kernel: `jax.numpy` at the chip's default precision against float32
+    # at the highest): a decode step of 96 slots of which 64 run,
+    # scattered, whose rows move on by one while the others' come back
+    # to the bit; and a prefill chunk of 512 of which the last 100 are a
+    # padded tail, which leaves its last two VALID inputs
+    from deepspeed_tpu.models.lfm2_moe import conv_mix
+
+    B, D, K, chunk = conv_shape
+    cspec = types.SimpleNamespace(conv_taps=K)
+    mk = lambda k, shape, scale: (jax.random.normal(
+        k, shape, jnp.float32) * scale).astype(jnp.bfloat16)
+    cp = {"in": mk(key[1], (D, 3 * D), D ** -0.5),
+          "conv_w": mk(key[2], (D, K), K ** -0.5),
+          "out": mk(key[3], (D, D), D ** -0.5)}
+
+    def conv_by_hand(h, rows, n_valid):
+        with jax.default_matmul_precision("highest"):
+            f32 = lambda a: a.astype(jnp.float32)
+            # (the layer's products are at the weights' dtype)
+            b, c, u = jnp.split(f32(h.astype(jnp.bfloat16)) @ f32(cp["in"]),
+                                3, axis=-1)
+            seq = jnp.concatenate(
+                [f32(rows), f32((b * u).astype(rows.dtype))], axis=1)
+            conv = sum(seq[:, j:j + h.shape[1]] * f32(cp["conv_w"])[:, j]
+                       for j in range(K))
+            kept = jnp.stack([jax.lax.dynamic_slice_in_dim(
+                seq[i], n_valid[i], K - 1) for i in range(h.shape[0])])
+            return (c * conv) @ f32(cp["out"]), kept.astype(rows.dtype)
+
+    mix = jax.jit(lambda h, rows, n: conv_mix(cspec, cp, h, rows, n))
+    for label, h, rows, n_valid in (
+            (f"step_B{B}_live{conv_live}",
+             jax.random.normal(key[4], (B, 1, D), jnp.float32),
+             mk(key[5], (B, K - 1, D), 1.0),
+             jnp.zeros((B,), jnp.int32).at[jax.random.permutation(
+                 key[6], B)[:conv_live]].set(1)),
+            (f"chunk_T{chunk}_valid{chunk - 100}",
+             jax.random.normal(key[4], (1, chunk, D), jnp.float32),
+             mk(key[5], (1, K - 1, D), 1.0),
+             jnp.full((1,), chunk - 100, jnp.int32))):
+        got, want = mix(h, rows, n_valid), conv_by_hand(h, rows, n_valid)
+        real = np.arange(h.shape[1])[None, :] < np.asarray(n_valid)[:, None]
+        out.append(_close(
+            f"conv_mix_bf16_D{D}_{label}", [h.shape[0], h.shape[1], D, K],
+            (got[0][real], got[1].astype(jnp.float32)),
+            (want[0][real], want[1].astype(jnp.float32)), rtol=0,
+            atol=2e-2 * float(jnp.abs(want[0]).max())))
+        rest = np.asarray(n_valid) == 0
+        if not np.array_equal(np.asarray(got[1])[rest],
+                              np.asarray(rows)[rest]):
+            raise RuntimeError(
+                "the convolution moved the rows of a slot that does not run")
+
+    # ... and the routed products at its shape, behind a share: 96 slots
+    # of which 64 are live choose 4 of 64 experts and the 8 held are ALL
+    # touched, each multiplying a few rows (the regime no other cell
+    # has); a chunk of 512 over slabs of the rows held
+    T, top_k, E, total, D, F = held_shape
+    experts = {name: mk(k, shape, D ** -0.5) for name, k, shape in (
+        ("gate", key[1], (E, D, F)), ("up", key[2], (E, D, F)),
+        ("down", key[3], (E, F, D)))}
+    x = jax.random.normal(key[4], (T, D), jnp.float32)
+    weights, idx, held = dropless.held_assignments(*dropless.route(
+        x, jax.random.normal(key[5], (D, total), jnp.float32) * D ** -0.5,
+        top_k, scoring="sigmoid", renormalize=True, renorm_eps=1e-6), 0, E)
+    alive = jnp.arange(T) < held_live
+    way = dropless.routed_way(T, top_k, experts, total)
+    if on_chip and way != "touched":
+        raise RuntimeError(
+            f"a call of {T} rows over {E} of {total} experts of {D} x {F} "
+            f"takes the {way} way on this chip")
+    got = jax.jit(dropless.experts_touched_only)(x, experts, weights, idx,
+                                                 alive, held)
+    want = jax.jit(dropless.experts_masked)(
+        x, experts, jnp.where(alive[:, None], weights, 0.0), idx)
+    out.append(_close(
+        f"touched_experts_bf16_T{T}_E{E}of{total}", [T, E, D, F], got, want,
+        rtol=0, atol=1e-2 * float(jnp.abs(want).max())))
+    touched = int(dropless.experts_touched(idx, alive, E, held))
+    if (on_chip and touched != E) or np.asarray(got)[held_live:].any():
+        raise RuntimeError(
+            f"the share's check touched {touched} of {E} held experts, or "
+            f"its dead slots touched some")
+    T, top_k, E, total, D, F = held_slab
+    x = jax.random.normal(key[4], (T, D), jnp.float32)
+    weights, idx, held = dropless.held_assignments(*dropless.route(
+        x, jax.random.normal(key[5], (D, total), jnp.float32) * D ** -0.5,
+        top_k, scoring="sigmoid", renormalize=True), 0, E)
+    alive = jnp.arange(T) < T - 100
+    way = dropless.routed_way(T, top_k, experts, total)
+    if on_chip and way != "slabs":
+        raise RuntimeError(
+            f"a chunk of {T} rows over {E} of {total} experts of {D} x {F} "
+            f"takes the {way} way on this chip")
+    got = jax.jit(lambda *a: dropless.experts_slabs(*a, total, held, alive))(
+        x, experts, weights, idx)
+    want = jax.jit(dropless.experts_grouped)(x, experts, weights, idx, held)
+    out.append(_close(
+        f"grouped_experts_bf16_T{T}_E{E}of{total}_sigmoid", [T, E, D, F],
+        got[alive], want[alive], rtol=0,
+        atol=1e-2 * float(jnp.abs(want).max())))
 
     # not a failure but an answer: does this chip's compiler keep
     # `_own_lanes`' two slices right (16 rows of 2 K/V heads of 256)?

@@ -184,10 +184,12 @@ def routed_ffn(spec, cfg, p, flat, live=None):
     count, held): the routed FFN the layer spec describes — the router's
     `spec.scoring`, the chosen weights over their sum where
     `spec.renormalize` (chosen by the scores plus the layer's
-    `select_bias` where `spec.select_bias`, times `spec.route_scale`),
+    `select_bias` where `spec.select_bias`, the sum plus
+    `spec.renorm_eps`, times `spec.route_scale`),
     the held experts' weighted sum (`spec.held`: a share of
     `cfg.num_experts`, or all; an expert's form is what its tree holds,
-    kernels/expert_form.py `expert_hidden`) plus the shared experts' sum or, with
+    kernels/expert_form.py `expert_hidden`) plus, where the tree holds
+    `shared` experts, their sum or, with
     `spec.shared` "average", their mean — and what `experts_touched`
     counts the touched experts from: the experts chosen, numbered among
     the `count` held, and which assignments are `held` (None: all).
@@ -198,7 +200,8 @@ def routed_ffn(spec, cfg, p, flat, live=None):
                              renormalize=spec.renormalize,
                              select_bias=p["select_bias"]
                              if spec.select_bias else None,
-                             scale=spec.route_scale)
+                             scale=spec.route_scale,
+                             renorm_eps=spec.renorm_eps)
         held, count = None, cfg.num_experts
         if spec.held is not None:
             weights, idx, held = held_assignments(weights, idx, *spec.held)
@@ -206,6 +209,8 @@ def routed_ffn(spec, cfg, p, flat, live=None):
     with jax.named_scope("moe_experts"):
         y = routed_experts(flat, p["experts"], weights, idx,
                            total=cfg.num_experts, held=held, live=live)
+    if "shared" not in p:          # a model without shared experts
+        return y, idx, count, held
     with jax.named_scope("moe_shared"):
         shared = dense_expert(p["shared"], flat)   # of the experts' form
         if spec.shared == "gated":

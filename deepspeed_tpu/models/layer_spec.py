@@ -24,7 +24,7 @@ HEADS = ("tied", "untied")
 RESIDUALS = ("sequential", "parallel", "single")
 SCORINGS = ("softmax", "sigmoid")
 SHARED = ("sum", "average", "gated")
-MIXERS = ("attention", "ssm", "gdn", "none")
+MIXERS = ("attention", "ssm", "gdn", "conv", "none")
 
 
 class LayerSpec(NamedTuple):
@@ -113,7 +113,16 @@ class LayerSpec(NamedTuple):
                convolution's last `gdn_conv - 1` inputs; a prefill chunk
                takes `gdn_chunk` positions at a time.  A pattern has
                state layers of one kind (`state_shapes` says what ONE
-               slot keeps for a layer of it).  "none" (with the
+               slot keeps for a layer of it).
+               "conv": a gated short convolution (models/lfm2_moe.py):
+               a causal depthwise convolution of `conv_taps` taps over
+               `conv_channels` channels, gated on both sides, with no
+               activation and no recurrence.  It keeps, a request, the
+               convolution's last `conv_taps - 1` inputs and NOTHING
+               else — one array, no float32 state, no chunk size.
+               `STATE_MIXERS` below is the one table of these kinds:
+               what a slot keeps, the mix function, the counters'
+               family and the step kernel of each.  "none" (with the
                "single" residual alone): the layer has no mixer — it is
                its FFN and nothing else, and owns neither rows nor a
                state.
@@ -127,7 +136,8 @@ class LayerSpec(NamedTuple):
                multiplies the weights last) — moe/dropless.py — among
                the `experts_held` experts from
                `first_expert` on that this chip holds (0: all); plus
-               shared experts, their outputs summed or, with `shared`
+               shared experts (where the tree holds any: `shared`),
+               their outputs summed or, with `shared`
                "average", their mean, or, with "gated", times the
                sigmoid of the token's product with `shared_gate`
                [D, 1]; the first `dense_layers` layers are
@@ -176,7 +186,8 @@ class LayerSpec(NamedTuple):
     experts_held: int = 0        # routed_experts: experts held here (0: all)
     first_expert: int = 0        # routed_experts: the first one held
     layer_mixers: tuple = ()     # the pattern's "attention" | "ssm" | "gdn"
-    #                              | "none" (single: the layer is its FFN)
+    #                              | "conv" | "none" (single: the layer is
+    #                              its FFN)
     ssm_heads: int = 0           # ssm: heads of the recurrence
     ssm_head_dim: int = 0        # ssm: values a head
     ssm_state: int = 0           # ssm: state values (B and C's width)
@@ -203,6 +214,10 @@ class LayerSpec(NamedTuple):
     qk_norm: bool = False        # grouped: q and k RMS-normed over the head
     rotary_dim: int = 0          # grouped: values of a head that rotate (0: all)
     rope_halves: bool = False    # grouped: pairs i, i + half (not 2i, 2i + 1)
+    conv_taps: int = 0           # conv: taps of the causal convolution
+    conv_channels: int = 0       # conv: its channels (the model's width)
+    renorm_eps: float = 0.0      # routed_experts: added to the chosen
+    #                              weights' sum before it divides them
 
     def window_of(self, layer: int) -> int:
         """The window of layer `layer` (0: every cached position)."""
@@ -216,7 +231,8 @@ class LayerSpec(NamedTuple):
             layer % len(self.layer_positions)] == "rope"
 
     def mixer_of(self, layer: int) -> str:
-        """"attention", "ssm" or "gdn": how layer `layer` mixes tokens."""
+        """"attention", a kind of `STATE_MIXERS`, or "none": how layer
+        `layer` mixes tokens."""
         if not self.layer_mixers:
             return "attention"
         return self.layer_mixers[layer % len(self.layer_mixers)]
@@ -247,16 +263,23 @@ class LayerSpec(NamedTuple):
                      if self.has_ffn(i))
 
     @property
+    def state_mixer(self):
+        """None, or the pattern's one kind of `STATE_MIXERS`: how its
+        layers with a state mix tokens."""
+        return next((m for m in self.layer_mixers if m in STATE_MIXERS),
+                    None)
+
+    @property
     def has_state(self) -> bool:
         """Whether some layer keeps a state a request beside (or in
         place of) cache rows."""
-        return any(m in ("ssm", "gdn") for m in self.layer_mixers)
+        return self.state_mixer is not None
 
     def state_layers(self, num_layers: int) -> tuple:
         """The layers of `num_layers` that keep a state a request and
         no cache rows."""
         return tuple(i for i in range(num_layers)
-                     if self.mixer_of(i) in ("ssm", "gdn"))
+                     if self.mixer_of(i) in STATE_MIXERS)
 
     def row_layers(self, num_layers: int) -> tuple:
         """The layers of `num_layers` that attend, and so own cache
@@ -267,22 +290,17 @@ class LayerSpec(NamedTuple):
     @property
     def state_shapes(self) -> tuple:
         """What ONE slot keeps for one layer with a state: ((shape,
-        dtype or None: the cache's), ...) — the float32 state and the
-        convolution's last inputs; () where no layer has one."""
-        if "ssm" in self.layer_mixers:
-            return (((self.ssm_heads, self.ssm_head_dim, self.ssm_state),
-                     "float32"),
-                    ((self.ssm_conv - 1, self.ssm_conv_width), None))
-        if "gdn" in self.layer_mixers:
-            return (((self.gdn_value_heads, self.gdn_key_dim,
-                      self.gdn_value_dim), "float32"),
-                    ((self.gdn_conv - 1, self.gdn_conv_width), None))
-        return ()
+        dtype or None: the cache's), ...) — a float32 state and the
+        convolution's last inputs, or those inputs alone; () where no
+        layer has one."""
+        kind = self.state_mixer
+        return STATE_MIXERS[kind].keeps(self) if kind else ()
 
     @property
     def state_chunk(self) -> int:
         """Positions the state layers' scan takes at once (0: none)."""
-        return self.ssm_chunk or self.gdn_chunk
+        kind = self.state_mixer
+        return STATE_MIXERS[kind].chunk(self) if kind else 0
 
     @property
     def gdn_conv_width(self) -> int:
@@ -357,6 +375,13 @@ class LayerSpec(NamedTuple):
                 f"layer spec: scoring, renormalize, shared, experts_held, "
                 f"first_expert, select_bias and route_scale describe a "
                 f"routed_experts FFN (got {self.ffn!r})")
+        if self.renorm_eps < 0 or (self.renorm_eps
+                                   and not self.renormalize):
+            raise ValueError(
+                f"layer spec: renorm_eps >= 0 is added to the sum that "
+                f"renormalize divides the chosen weights by (got "
+                f"renorm_eps {self.renorm_eps}, renormalize "
+                f"{self.renormalize})")
         sizes = (self.index_topk, self.index_heads, self.index_width)
         if any(k not in ("full", "shared") for k in self.layer_indexers) \
                 or bool(self.layer_indexers) != all(n > 0 for n in sizes) \
@@ -387,12 +412,17 @@ class LayerSpec(NamedTuple):
                         "ssm" not in self.layer_mixers
                         or self.ssm_heads % self.ssm_groups)):
             raise ValueError(
-                f"layer spec: layer_mixers says \"attention\", \"ssm\", "
-                f"\"gdn\" or \"none\" of each layer of the pattern, and a "
+                f"layer spec: layer_mixers says one of {MIXERS} of each "
+                f"layer of the pattern, and a "
                 f"pattern with ssm layers, and nothing else, names "
                 f"ssm_heads, ssm_head_dim, ssm_state, ssm_conv >= 2, "
                 f"ssm_chunk and may name ssm_groups that divide the heads "
                 f"(got {self.layer_mixers}, {sizes}, {self.ssm_groups})")
+        if len(set(self.layer_mixers) & set(STATE_MIXERS)) > 1:
+            raise ValueError(
+                f"layer spec: a pattern's layers with a state are of one "
+                f"kind of {tuple(STATE_MIXERS)} (got layer_mixers "
+                f"{self.layer_mixers})")
         single = self.residual == "single"
         if ("none" in self.layer_mixers) != single or (
                 single and not any(m != "none" for m in self.layer_mixers)):
@@ -408,14 +438,20 @@ class LayerSpec(NamedTuple):
                 or ("gdn" not in self.layer_mixers and any(sizes)) \
                 or self.gdn_conv == 1 or (
                     self.gdn_key_heads
-                    and self.gdn_value_heads % self.gdn_key_heads) \
-                or {"ssm", "gdn"} <= set(self.layer_mixers):
+                    and self.gdn_value_heads % self.gdn_key_heads):
             raise ValueError(
                 f"layer spec: a pattern with gdn layers, and nothing else, "
                 f"names gdn_key_heads, gdn_value_heads (a multiple of "
                 f"them), gdn_key_dim, gdn_value_dim, gdn_conv >= 2 and "
-                f"gdn_chunk, and its state layers are of one kind (got "
-                f"{self.layer_mixers}, {sizes})")
+                f"gdn_chunk (got {self.layer_mixers}, {sizes})")
+        sizes = (self.conv_taps, self.conv_channels)
+        if ("conv" in self.layer_mixers) != all(n > 0 for n in sizes) \
+                or ("conv" not in self.layer_mixers and any(sizes)) \
+                or self.conv_taps == 1:
+            raise ValueError(
+                f"layer spec: a pattern with conv layers, and nothing "
+                f"else, names conv_taps >= 2 and the conv_channels they "
+                f"run over (got {self.layer_mixers}, {sizes})")
         if (self.attn_gate or self.qk_norm or self.rotary_dim
                 or self.rope_halves) and self.attention != "grouped" \
                 or self.rotary_dim < 0 or self.rotary_dim % 2:
@@ -435,3 +471,67 @@ class LayerSpec(NamedTuple):
                 f"{self.logit_divisor}, {self.attn_scale} with "
                 f"{self.attention!r} attention)")
         return self
+
+
+class StateMixer(NamedTuple):
+    """One kind of mixer whose layers keep arrays BY SLOT and no cache
+    rows.  `keeps(spec)` is what ONE slot keeps for one such layer,
+    ((shape, dtype or None: the cache's), ...); `chunk(spec)` the
+    positions its scan takes at once (0: it has no scan); `mix` names the
+    function that mixes, `module:function` of this package,
+    `mix(spec, p, h, *kept, n_valid, live=None) -> (out, *kept)`, loaded
+    when a block of the kind is first built (`mix_fn`; its parameters
+    lie in a block's tree under the kind's name); `counters` is the
+    family its counters go by (`<counters>.state_bytes`, `.slots_live`,
+    `.state_resets`); `step_kernel(spec, kept)` answers (registry op,
+    info) of the kernel that walks a decode step's live slots, where
+    the kind has one — the engine asks the registry once whether a slot
+    that is not running costs the step its first array.  The scopes are
+    `<kind>.step` and `<kind>.scan`, under the `state` stage."""
+
+    keeps: object
+    chunk: object
+    mix: str
+    counters: str
+    step_kernel: object = None
+
+    def mix_fn(self):
+        import importlib
+
+        module, name = self.mix.split(":")
+        return getattr(importlib.import_module("." + module, __package__),
+                       name)
+
+
+def _ssm_step_kernel(spec, kept):
+    from ..kernels.ssm import ssm_step_info
+
+    return "ssm_step", ssm_step_info(kept[0], spec.ssm_groups)
+
+
+def _gdn_step_kernel(spec, kept):
+    from ..kernels.gdn import gdn_step_info
+
+    return "gdn_step", gdn_step_info(kept[0])
+
+
+STATE_MIXERS = {
+    "ssm": StateMixer(
+        keeps=lambda s: (((s.ssm_heads, s.ssm_head_dim, s.ssm_state),
+                          "float32"),
+                         ((s.ssm_conv - 1, s.ssm_conv_width), None)),
+        chunk=lambda s: s.ssm_chunk,
+        mix="granite_hybrid:ssm_mix", counters="serve.ssm",
+        step_kernel=_ssm_step_kernel),
+    "gdn": StateMixer(
+        keeps=lambda s: (((s.gdn_value_heads, s.gdn_key_dim,
+                           s.gdn_value_dim), "float32"),
+                         ((s.gdn_conv - 1, s.gdn_conv_width), None)),
+        chunk=lambda s: s.gdn_chunk,
+        mix="qwen3_next:gdn_mix", counters="serve.gdn",
+        step_kernel=_gdn_step_kernel),
+    "conv": StateMixer(
+        keeps=lambda s: (((s.conv_taps - 1, s.conv_channels), None),),
+        chunk=lambda s: 0,
+        mix="lfm2_moe:conv_mix", counters="serve.conv"),
+}

@@ -325,7 +325,8 @@ def project_gated(cfg, spec, p, h, positions, rotate: bool, dtype):
     [B, T, KV, Dh] at `dtype`, gate [B, T, H * Dh] float32 or None):
     `cohere2_moe.project_grouped` with what the spec adds — with
     `attn_gate` the query projection is [q | gate] a head; with
-    `qk_norm` q and k are RMS-normed over the head first; a rotating
+    `qk_norm` q and k are RMS-normed over the head first (the gain as
+    `spec.norm` has it: 1 + g, or under "rmsnorm" the plain w); a rotating
     layer turns `rotary_dim` values of a head, pairing by
     `rope_halves`."""
     B, T, _ = h.shape
@@ -338,8 +339,9 @@ def project_gated(cfg, spec, p, h, positions, rotate: bool, dtype):
     k = matmul32(h, p["k"]).reshape(B, T, KV, Dh)
     v = matmul32(h, p["v"]).reshape(B, T, KV, Dh)
     if spec.qk_norm:
-        q = rms_norm(q, p["q_norm"], spec.eps)
-        k = rms_norm(k, p["k_norm"], spec.eps)
+        norm = rms_norm_plain if spec.norm == "rmsnorm" else rms_norm
+        q = norm(q, p["q_norm"], spec.eps)
+        k = norm(k, p["k_norm"], spec.eps)
     if rotate:
         q = rope_partial(q, positions, spec.rope_theta, spec.rotary_dim,
                          spec.rope_halves)

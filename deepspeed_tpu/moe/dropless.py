@@ -95,12 +95,13 @@ def dense_expert(p, x):
 
 
 def route(h, router, top_k: int, scoring: str = "softmax",
-          renormalize: bool = False, select_bias=None, scale: float = 1.0):
+          renormalize: bool = False, select_bias=None, scale: float = 1.0,
+          renorm_eps: float = 0.0):
     """h [T, D], router [D, E] -> (weights [T, top_k] float32, experts
     [T, top_k] int32): the scores over E in float32, the top_k largest —
     of the scores plus `select_bias` [E] where one is given, which
-    chooses and does not weigh — as they are or over their sum, times
-    `scale`."""
+    chooses and does not weigh — as they are or over their sum (plus
+    `renorm_eps`, where a model adds one), times `scale`."""
     scores = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.softmax(scores, axis=-1) if scoring == "softmax" \
@@ -112,7 +113,8 @@ def route(h, router, top_k: int, scoring: str = "softmax",
                                top_k)
         weights = jnp.take_along_axis(scores, idx, axis=-1)
     if renormalize:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (total + renorm_eps if renorm_eps else total)
     if scale != 1.0:
         weights = weights * scale
     return weights, idx.astype(jnp.int32)

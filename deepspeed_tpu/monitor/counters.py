@@ -193,7 +193,13 @@ Instrumented sites:
   `serve.gdn.state_resets`, the same three name for
   name, where the layers with a state are gated delta-rule mixers
   (models/qwen3_next.py; the kernel asked about is `gdn_step`), which
-  emit no `serve.ssm.*`;
+  emit no `serve.ssm.*`; and `serve.conv.state_bytes`,
+  `serve.conv.slots_live`, `serve.conv.state_resets`, name for name
+  again, where they are gated short convolutions (models/lfm2_moe.py:
+  what a slot keeps is the convolution's last `taps - 1` inputs and
+  nothing else; no kernel is asked about, so `state_bytes` is every
+  slot's rows twice, whatever is running) — the family is the kind's
+  `counters` in models/layer_spec.py `STATE_MIXERS`;
   `serve.attn.rows_read`, `serve.attn.rows_walked` and
   `serve.attn.prefill_rows_walked` as above over the attention layers
   alone.

@@ -206,10 +206,13 @@ counts among those held, and `serve.moe.assignments` is not emitted
 (only the program knows how many of a call's assignments it held).
 
 A state beside rows (a layer spec some of whose layers mix tokens by a
-state-space recurrence or by the gated delta rule, grouped attention in
+state-space recurrence, by the gated delta rule or by a gated short
+convolution — the kinds of models/layer_spec.py `STATE_MIXERS` —
+grouped attention in
 the others): those layers own no cache rows — `num_blocks`, the tables and
 admission count the attention layers alone — but a float32 state and
-the convolution's last inputs a SLOT, held for all `max_batch` slots
+the convolution's last inputs (the convolution kind: those inputs alone)
+a SLOT, held for all `max_batch` slots
 whatever is seated: the state, not the rows, sizes `max_batch`.  When a
 request is seated, before its first prefill chunk, the engine zeroes its
 slot's entries on the device (`kv.reset_state`, phase
@@ -234,7 +237,9 @@ slot's of both, twice), `serve.ssm.slots_live` (calls
 `serve.ssm.state_resets` (calls = slots zeroed) —
 under `serve.gdn.*` in place of `serve.ssm.*`, name for name, where the
 layers with a state are gated delta-rule mixers (`gdn_step` the kernel
-asked about) — and
+asked about), under `serve.conv.*` where they are gated short
+convolutions (no kernel to ask about: every slot's kept rows, twice) —
+the kind's entry of `STATE_MIXERS` says which — and
 `serve.attn.rows_read`, `serve.attn.rows_walked` and
 `serve.attn.prefill_rows_walked` over the attention layers.
 
@@ -269,6 +274,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.eva import live_blocks
+from ..models.layer_spec import STATE_MIXERS
 from ..monitor.counters import COUNTERS
 from ..monitor.tracing import phase
 from ..runtime.resilience import fault_point
@@ -577,9 +583,11 @@ class ServeEngine:
         # storage mode is folded in by the cache itself)
         prefix_salt = (f"{cfg.num_layers}|{cfg.num_heads}|{cfg.head_dim}|"
                        f"{cfg.vocab_size}|{cfg.max_seq_len}|{c.quant_mode}")
-        # the state mixers' family of counters: serve.ssm.* | serve.gdn.*
-        gdn = "gdn" in spec.layer_mixers
-        self._state_counters = "serve.gdn" if gdn else "serve.ssm"
+        # the state mixers' kind (models/layer_spec.py STATE_MIXERS) and
+        # its family of counters: serve.ssm.* | serve.gdn.* | serve.conv.*
+        # ("": no layer keeps a state)
+        state_kind = STATE_MIXERS.get(spec.state_mixer)
+        self._state_counters = state_kind.counters if state_kind else ""
         self.kv = PagedKVCache(
             num_layers=cfg.num_layers,
             num_heads=spec.kv_heads or cfg.num_heads,
@@ -601,28 +609,22 @@ class ServeEngine:
                          and i not in self._row_layers],
             state_counters=self._state_counters)
         # what a decode step reads and writes of it: every slot's — less,
-        # where the registry answers that the recurrence walks the live
-        # slots (asked once, for the decode program's shapes), the
-        # float32 state `_state_dead_bytes` of each slot that is not
-        # running; the convolution's inputs stay every slot's
+        # where the kind has a kernel that walks the live slots and the
+        # registry answers that it runs (asked once, for the decode
+        # program's shapes), the first array (the float32 state)
+        # `_state_dead_bytes` of each slot that is not running; the
+        # convolution's inputs stay every slot's
         self._state_step_bytes = 2 * self.kv.state_nbytes()
         self._state_dead_bytes = 0
-        if self._state_layers:
+        if self._state_layers and state_kind.step_kernel is not None:
             from ..kernels import registry
 
-            states = [self.kv.caches[i][0] for i in self._state_layers]
-            if gdn:
-                from ..kernels.gdn import gdn_step_info
-
-                op, info = "gdn_step", gdn_step_info(states[0])
-            else:
-                from ..kernels.ssm import ssm_step_info
-
-                op, info = "ssm_step", ssm_step_info(states[0],
-                                                     spec.ssm_groups)
+            op, info = state_kind.step_kernel(
+                spec, self.kv.caches[self._state_layers[0]])
             if registry.resolve_impl(op, info=info) == "pallas":
                 self._state_dead_bytes = 2 * sum(
-                    a.nbytes for a in states) // c.max_batch
+                    self.kv.caches[i][0].nbytes
+                    for i in self._state_layers) // c.max_batch
         self.scheduler = Scheduler(self.kv, c.max_batch,
                                    admission=c.admission, clock=clock,
                                    draft_len=int(c.draft_len))

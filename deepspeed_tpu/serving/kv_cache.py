@@ -149,7 +149,8 @@ spec some of whose layers mix tokens by a state-space recurrence): such
 a layer's entry of `caches` is not rows of a pool but arrays BY SLOT,
 `state_shapes` says which — for a Mamba-2 layer a float32 state
 `[max_requests, heads, head_dim, state]` and the convolution's last
-inputs `[max_requests, taps - 1, conv_width]` at the cache's dtype.  No
+inputs `[max_requests, taps - 1, conv_width]` at the cache's dtype, for a
+gated short convolution (models/lfm2_moe.py) those inputs alone.  No
 blocks, no table entries, no free list: a request's share is its slot's,
 fixed whatever its length, so the pool, `num_blocks`, `bytes_per_block`
 and admission count the other layers only, and with `max_batch` slots
@@ -162,8 +163,8 @@ launched before it, and the engine calls it when it seats a request,
 layer that is its FFN alone, models/nemotron_h.py: an entry `()` that
 costs no byte, so the rows are laid out for the attention layers only)
 before its first prefill chunk (counted as `<state_counters>.
-state_resets`: the engine names its mixers' family, `serve.ssm` or
-`serve.gdn`).  The prefix cache, session pins,
+state_resets`: the engine names its mixers' family, `serve.ssm`,
+`serve.gdn` or `serve.conv`).  The prefix cache, session pins,
 quantized rows and a mesh are not offered for such a cache (the engine
 refuses them by name).
 
@@ -391,7 +392,8 @@ class PagedKVCache:
                 f"layers that own nothing ({sorted(self.bare_layers)}) "
                 f"stand beside layers with a state, and are none of them "
                 f"({sorted(self.state_layers)})")
-        # the mixers' own family of counters: serve.ssm | serve.gdn
+        # the mixers' own family of counters: serve.ssm | serve.gdn |
+        # serve.conv
         self.reset_counter = f"{state_counters}.state_resets"
         self.max_requests = int(max_requests)
         if bool(self.state_layers) != bool(self.state_shapes) or (

@@ -34,7 +34,13 @@ each ONE part under one norm — a state-space mixer whose B and C come in
 `spec.ssm_groups` groups of heads, grouped attention, or the FFN alone —
 (the Nemotron-H block: `spec.residual` "single", a layer whose
 `spec.mixer_of` is "none" is its FFN and owns neither rows nor a
-state).  The norm,
+state), and positions given layer by layer with grouped attention in
+some layers — q and k normed over the head with the spec's own kind of
+gain, then rotated whole, by halves — and a gated short convolution in
+the others (the LFM2-MoE block: sequential, a routed FFN without a
+shared expert behind two dense layers; the convolution layers keep their
+last two inputs a slot and no other state; models/lfm2_moe.py, imported
+when such a block is first built).  The norm,
 the FFN and the head are free of that choice.  A routed-experts FFN,
 behind leading dense layers, is one function in either block and reads
 the spec (models/cohere2_moe.py `routed_ffn`): the router's scoring,
@@ -81,13 +87,17 @@ entry, a sliding layer's the blocks its window lies in, modulo the run
 window has no walk and gathers its run in `jax.numpy`
 (`grouped_attention_reference`), as every call does off the chip.
 
-Layers with a state and no rows (`spec.mixer_of(layer)` "ssm",
-models/granite_hybrid.py, or "gdn", models/qwen3_next.py): such a
+Layers with a state and no rows (`spec.mixer_of(layer)` one of
+models/layer_spec.py `STATE_MIXERS`, the one table that says of each
+kind what a slot keeps, which function mixes, its counters' family and
+its step kernel: "ssm", models/granite_hybrid.py, "gdn",
+models/qwen3_next.py, or "conv", models/lfm2_moe.py): such a
 layer's entry of the cache is not rows
-of a pool but two arrays BY SLOT — a float32 state `[slots, heads,
+of a pool but arrays BY SLOT — a float32 state `[slots, heads,
 head_dim, state]` (`[slots, value heads, key_dim, value_dim]` for the
 delta rule) and the convolution's last inputs `[slots, taps - 1,
-conv_width]` — `spec.state_shapes` — and its block reads and writes the entries of the call's
+conv_width]`, or, for the gated short convolution, those inputs alone
+— `spec.state_shapes` — and its block reads and writes the entries of the call's
 sequences: a prefill chunk its request's one (`Addr.slot`, which rides
 behind the request's table), a decode step every slot's.  The
 block moves both on by the call's valid positions (`Addr.n_valid`: a
@@ -97,7 +107,9 @@ crosses slots.  A decode step also lists its running slots once
 (`Addr.live`, kernels/ssm.py `live_slots`), for every such layer: where
 the registry picks the `ssm_step` (or `gdn_step`) kernel the recurrence
 walks that list
-and a slot that is not on it has its state neither read nor written.
+and a slot that is not on it has its state neither read nor written
+(the convolution kind has no kernel and no list to walk: two rows a
+slot).
 Nothing here zeroes a state: the engine does, when a request is seated
 (serving/kv_cache.py `reset_state`).
 
@@ -132,7 +144,6 @@ import jax.numpy as jnp
 
 from ..models import cohere2_moe
 from ..kernels.ssm import live_slots
-from ..models.granite_hybrid import ssm_mix
 from ..models.deepseek_v2 import (absorb, absorbed_attention,
                                   attend_expanded, attend_rows,
                                   latent_project, rms_norm_plain,
@@ -140,7 +151,7 @@ from ..models.deepseek_v2 import (absorb, absorbed_attention,
 from ..models.evabyte import (chunk_summaries, matmul32, project_qkv,
                               rms_norm, silu_gated_ffn)
 from ..models.gpt import layer_norm
-from ..models.layer_spec import LayerSpec
+from ..models.layer_spec import STATE_MIXERS, LayerSpec
 from ..moe.dropless import experts_touched, rows_multiplied
 from .kv_cache import pool_rows
 
@@ -680,21 +691,20 @@ def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
     return matmul32(out, p["o"]), ck, cv
 
 
-def _ssm_mix(spec, p, h, state, conv, addr, mix=ssm_mix):
-    """The state-space mixer (or, as `mix`, another mixer with a state)
-    over the call's sequences: their entries of
-    the layer's state and convolution inputs — slot `addr.slot`'s for
-    the one sequence of a prefill chunk, every slot's in a decode step —
-    moved on by `addr.n_valid` positions each and written back in place.
-    -> float32."""
+def _state_mix(mix, spec, p, h, kept, addr):
+    """`mix`, a mixer with a state (`STATE_MIXERS`), over the call's
+    sequences: their entries of the arrays `kept` that the layer keeps
+    by slot (a state and the convolution's inputs, or those inputs
+    alone) — slot `addr.slot`'s for the one sequence of a prefill chunk,
+    every slot's in a decode step — moved on by `addr.n_valid`
+    positions each and written back in place.  -> (float32, *kept)."""
     if addr.slot is None:
-        return mix(spec, p, h, state, conv, addr.n_valid, addr.live)
+        return mix(spec, p, h, *kept, addr.n_valid, addr.live)
     take = lambda a: jax.lax.dynamic_slice_in_dim(a, addr.slot, 1)
     put = lambda a, new: jax.lax.dynamic_update_slice_in_dim(
         a, new, addr.slot, 0)
-    out, new_state, new_conv = mix(spec, p, h, take(state), take(conv),
-                                   addr.n_valid)
-    return out, put(state, new_state), put(conv, new_conv)
+    out, *new = mix(spec, p, h, *map(take, kept), addr.n_valid)
+    return (out, *map(put, kept, new))
 
 
 def _visible(at, q_pos, window: int):
@@ -816,23 +826,18 @@ def block(spec, cfg, p, x, kv, addr, s, layer: int = 0, sel=None,
 
 def _mix(spec, cfg, p, h, kv, addr, s, layer: int, sel, mixer: str):
     """Layer `layer`'s mixer — attention of the spec's kind, or the
-    recurrence `mixer` names — over the normed h through its cache entry
-    -> (mixed, kv, sel)."""
+    kind of `STATE_MIXERS` that `mixer` names — over the normed h
+    through its cache entry -> (mixed, kv, sel)."""
     if spec.layer_indexers:
         from .sparse import sparse_latent_attend
 
         return sparse_latent_attend(
             spec, cfg, p["attn"], h, kv, addr, s, layer, sel, _kv_write)
-    if mixer == "ssm":
-        with jax.named_scope("ssm.step" if h.shape[1] == 1
-                             else "ssm.scan"):
-            attn, *kv = _ssm_mix(spec, p["ssm"], h, *kv, addr)
-    elif mixer == "gdn":
-        from ..models.qwen3_next import gdn_mix
-
-        with jax.named_scope("gdn.step" if h.shape[1] == 1
-                             else "gdn.scan"):
-            attn, *kv = _ssm_mix(spec, p["gdn"], h, *kv, addr, gdn_mix)
+    if mixer in STATE_MIXERS:
+        with jax.named_scope(f"{mixer}.step" if h.shape[1] == 1
+                             else f"{mixer}.scan"):
+            attn, *kv = _state_mix(STATE_MIXERS[mixer].mix_fn(), spec,
+                                   p[mixer], h, kv, addr)
     elif spec.attention == "grouped":
         with jax.named_scope("gated_attend" if spec.attn_gate
                              else "full_attend"):

@@ -456,8 +456,8 @@ class ServeProgramBuilder:
                 f"row codecs are read by the paged gather, and a state "
                 f"kept in fewer bits accumulates its rounding at every "
                 f"token; neither is built")
-        chunk = self.spec.state_chunk
-        if s.prefill_chunk > chunk and s.prefill_chunk % chunk:
+        chunk = self.spec.state_chunk       # 0: the kind has no scan
+        if chunk and s.prefill_chunk > chunk and s.prefill_chunk % chunk:
             raise ValueError(
                 f"prefill_chunk must be at most the model's scan chunk "
                 f"({chunk}) or whole chunks of it (the scan takes a prefill "

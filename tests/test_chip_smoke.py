@@ -98,7 +98,12 @@ def test_kernels_phase_toy():
                                    ssm_groups_live=3,
                                    masked_shape=(16, 2, 24, 8, 16, 32),
                                    masked_table=(4, 16, 16, 12),
-                                   masked_start=20, on_chip=False)
+                                   masked_start=20,
+                                   conv_shape=(6, 32, 3, 160), conv_live=4,
+                                   held_shape=(16, 4, 4, 16, 128, 256),
+                                   held_live=12,
+                                   held_slab=(160, 4, 4, 16, 128, 256),
+                                   on_chip=False)
     assert [k["kernel"] for k in out["kernels"]] == [
         "flash_attention_fwd", "flash_attention_bwd",
         "flash_attention_full_bias_dropout_fwd",
@@ -117,7 +122,11 @@ def test_kernels_phase_toy():
         "grouped_experts_bf16_T160_E4of16_relu2",
         "ssm_step_B6_H4_P8_N16_live3", "ssm_step_B6_H4_P8_N16_live3_G2",
         "gdn_step_B6_H4_D16_live3",
-        "masked_latent_attention_bf16_T16_H2_tiles3"]
+        "masked_latent_attention_bf16_T16_H2_tiles3",
+        "conv_mix_bf16_D32_step_B6_live4",
+        "conv_mix_bf16_D32_chunk_T160_valid60",
+        "touched_experts_bf16_T16_E4of16",
+        "grouped_experts_bf16_T160_E4of16_sigmoid"]
     # the CPU keeps the two slices right; the chip's answer is the phase's
     assert out["own_lanes_two_slices_right"] is True
 

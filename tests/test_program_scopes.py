@@ -275,10 +275,23 @@ def _nemotron_h():
         max_seq_len=64, prefix_cache=False)
 
 
+def _lfm2_moe():
+    from deepspeed_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
+
+    return Lfm2Moe(Lfm2MoeConfig(
+        vocab_size=97, max_seq_len=64,
+        layer_types=("conv", "conv", "full_attention", "conv"), d_model=32,
+        d_ffn=48, dense_layers=2, num_heads=4, kv_heads=2, head_dim=16,
+        rope_theta=1e4, d_expert=16, num_experts=16, top_k=3,
+        init_std=0.2)), dict(
+        block_size=4, num_blocks=64, max_batch=3, prefill_chunk=8,
+        max_seq_len=64, prefix_cache=False)
+
+
 FAMILIES = {"gpt": _gpt, "evabyte": _evabyte, "deepseek_v2": _deepseek,
             "command_a": _command_a, "granite_hybrid": _granite,
             "glm_moe_dsa": _glm, "qwen3_next": _qwen3_next,
-            "nemotron_h": _nemotron_h,
+            "nemotron_h": _nemotron_h, "lfm2_moe": _lfm2_moe,
             "gpt_drafting": lambda: _gpt(draft_len=2)}
 # the scopes each family's programs are known by, beneath their stages
 # (PERF.md §3 names the metric that reads each)
@@ -298,10 +311,13 @@ EXPECT = {
                    "ffn/moe_route", "ffn/moe_experts", "ffn/moe_shared"},
     "nemotron_h": {"attn/full_attend/oracle.grouped_attention",
                    "ffn/moe_route", "ffn/moe_experts", "ffn/moe_shared"},
+    "lfm2_moe": {"attn/full_attend/oracle.grouped_attention",
+                 "ffn/moe_route", "ffn/moe_experts"},
 }
 STATE = {"granite_hybrid": ("state/ssm.scan", "state/ssm.step"),
          "nemotron_h": ("state/ssm.scan", "state/ssm.step"),
-         "qwen3_next": ("state/gdn.scan", "state/gdn.step")}
+         "qwen3_next": ("state/gdn.scan", "state/gdn.step"),
+         "lfm2_moe": ("state/conv.scan", "state/conv.step")}
 _TEXTS = {}
 
 

@@ -1525,6 +1525,101 @@ def test_nemotron_h_cell_programs_copy_no_experts_matrix(program, one_chip,
     assert ops and ops <= {"parameter", "bitcast", "get-tuple-element"}, ops
 
 
+_LFM2_CELL = {}
+
+
+def _lfm2_moe_cell_compiled(program, one_chip):
+    """The assist cell's `decode` or `prefill` program compiled for a
+    described v5e at the cell's shapes, once a process: (compiled, slots,
+    seq)."""
+    if program in _LFM2_CELL:
+        return _LFM2_CELL[program]
+    from deepspeed_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
+    from deepspeed_tpu.serving import ServeProgramBuilder, ServeSchedule
+
+    slots, bs, nblocks, chunk, seq = 96, 16, 18433, 512, 3072
+    model = Lfm2Moe(Lfm2MoeConfig(
+        vocab_size=8192, max_seq_len=seq, experts_held=8,
+        param_dtype=jnp.bfloat16))
+    spec, cfg = model.layer_spec(), model.config
+    width = seq // bs
+    sched = ServeSchedule(max_batch=slots, prefill_chunk=chunk,
+                          block_size=bs, num_blocks=nblocks,
+                          table_width=width)
+    progs = ServeProgramBuilder(model, sched).build()
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda s: on(s.shape, s.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    held = sum(s.size * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(params))
+    # (the choosing bias is float32)
+    assert held == 2 * 3_643_893_376 + 38 * 64 * 2
+    rows = on((nblocks * bs, 512), jnp.bfloat16)
+    kept = (on((slots, 2, 2048), jnp.bfloat16),)
+    caches = [{"conv": kept, "attention": (rows, rows)}[spec.mixer_of(i)]
+              for i in range(cfg.num_layers)]
+    nbytes = lambda c: sum(a.size * a.dtype.itemsize for a in c)
+    assert sum(nbytes(c) for c in caches if c is kept) == slots * 245_760
+    assert abs(sum(nbytes(c) for c in caches if c is not kept)
+               - 6.04e9) < 1e7
+    if program == "decode":
+        args = (on((slots,), jnp.int32), on((slots,), jnp.int32),
+                on((slots,), jnp.bool_), on((slots, width), jnp.int32),
+                on((slots,), jnp.float32), on((slots,), jnp.int32),
+                on((slots,), jnp.uint32))
+    else:   # behind the table's entries: the slot
+        args = (on((1, chunk), jnp.int32), on((), jnp.int32),
+                on((), jnp.int32), on((width + 1,), jnp.int32),
+                on((), jnp.float32), on((), jnp.int32), on((), jnp.uint32))
+    compiled = progs[program].lower(params, caches, *args).compile()
+    _LFM2_CELL[program] = compiled, slots, seq
+    return _LFM2_CELL[program]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_lfm2_moe_cell_programs_compile_inside_one_chip(program, one_chip,
+                                                        native):
+    """`lfm2-24b-a2b-e8.serve.assist.decode` / `.prefill` at the cell's
+    shapes (all 40 layers at published widths in bf16, 8 of 64 experts,
+    8,192 rows of the vocabulary, 96 slots, 18,433 blocks of 16 rows for
+    the 10 attention layers, two kept rows `[96, 2, 2048]` for each of
+    the 30 convolution layers, chunk 512): `decode`'s custom calls are
+    the 38 routed layers' walks of the touched experts (three matrices,
+    tiles of 1,536) and the 10 attention layers' walks of the live
+    blocks — the convolution is `jax.numpy` and has no kernel;
+    `prefill`'s the 38 slab products, none of XLA's own grouped products
+    (a chunk's attention over heads of 64, narrower than a 128-lane
+    tile, gathers); every kept array enters and leaves under its own
+    shape, updated in place; and weights, rows and temporaries fit the
+    chip's 15.75 GB with room for the check's 0.10 GB of reference
+    logits and its float32 layer."""
+    compiled, slots, seq = _lfm2_moe_cell_compiled(program, one_chip)
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    if program == "decode":
+        assert sum("paged_attention_walk" in ln for ln in calls) == 10
+        assert sum("touched_experts" in ln for ln in calls) == 38
+        assert (slots, seq, 8, 64) not in _hlo_by_shape(text)
+        assert _kernels_by_scope(text) == {
+            "grouped_attention": 10, "touched_experts": 38}
+    else:
+        assert sum("grouped_experts" in ln for ln in calls) == 38
+        assert "ragged" not in text
+        assert _kernels_by_scope(text).get("grouped_experts") == 38
+    m = compiled.memory_analysis()
+    # all 30 kept arrays and 20 pools are donated and aliased
+    assert m.alias_size_in_bytes > 6.0e9
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + \
+        m.output_size_in_bytes - m.alias_size_in_bytes
+    print(program, "temp", m.temp_size_in_bytes / 1e9, "total", total / 1e9)
+    assert m.temp_size_in_bytes < 1024 << 20
+    assert total < 15.75e9 - 0.10e9 - 1.0e9, total
+
+
 def test_evabyte_phase_after_the_described_compiles(topo):
     """This file, then tests/test_chip_smoke.py::test_evabyte_phase_toy,
     in one process: the order in which the toy EvaByte run chose bytes
