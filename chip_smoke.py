@@ -453,6 +453,8 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
                                      9215, -1),
                   prefill_shape=(128, 8, 128, 1024, 512),
                   prefill_starts=(0, 3003, 16084),
+                  sliding_chunk_shape=(128, 8, 128, 288, 4096, 512),
+                  sliding_chunk_starts=(0, 3584, 4096, 4608, 7003, 20480),
                   latent_shape=(32, 16, 512, 64, 256),
                   routed_shape=(32, 64, 2048, 1408), routed_live=12,
                   slab_shapes=((512, 10, 64, 512, 2048, 512),
@@ -785,6 +787,46 @@ def kernels_phase(batch=TRAIN_MICRO, seq=SEQ, heads=25, head_dim=64,
             f"grouped_prefill_bf16_H{p_heads}_KV{p_kv}_Dh{p_dim}_at{start}",
             [1, chunk, width * bs, p_heads, p_dim], walk(*a)[:, :valid],
             gather(*a)[:, :valid], rtol=0, atol=1e-2))
+
+    # and a prefill chunk of mixedlen's sliding layers (one request, 512
+    # queries, a ring of 288 blocks = window + chunk under a window of
+    # 4,096): a first chunk, the one that fills the window, the one that
+    # fills the ring, the first past the wrap, one off a block's edge
+    # and one several laps in; each tile of 64 queries walks the blocks
+    # from its oldest lower bound modulo the ring, against the gather of
+    # the whole ring under `newest`.  bf16 rows, the default precision
+    c_heads, c_kv, c_dim, ring, window, chunk = sliding_chunk_shape
+    bs, nblocks = 16, 1 + ring
+    rows = (nblocks * bs, c_kv, c_dim)
+    ck = pool_rows(jax.random.normal(key[6], rows, jnp.bfloat16))
+    cv = pool_rows(jax.random.normal(key[7], rows, jnp.bfloat16))
+    cq = jax.random.normal(key[0], (1, chunk, c_heads, c_dim), jnp.bfloat16)
+    info = {"block_size": bs, "table_width": ring, "q_len": chunk,
+            "num_heads": c_heads, "kv_heads": c_kv, "head_dim": c_dim,
+            "kv_mode": "dense", "kv_itemsize": 2, "window": window,
+            "ring": True, "batch": 1}
+    chosen = registry.resolve_impl("grouped_attention", info=info)
+    if on_chip and chosen != "pallas":
+        raise RuntimeError(
+            f"auto resolved a sliding layer's prefill chunk of {chunk} "
+            f"queries at {c_heads} heads on {c_kv} of {c_dim}, a ring of "
+            f"{ring} blocks under a window of {window}, to {chosen!r} on "
+            f"this chip")
+    kw = dict(kv_heads=c_kv, block_size=bs, scale=None, window=window)
+    walk = jax.jit(lambda q, k, v, tbl, q_pos: registry.dispatch(
+        "grouped_attention", q, k, v, tbl, q_pos, info=info,
+        newest=q_pos[:, -1], **kw))
+    gather = jax.jit(lambda q, k, v, tbl, q_pos: grouped_attention_reference(
+        q, k, v, tbl, q_pos, newest=q_pos[:, -1], **kw))
+    table = jnp.asarray(rs.permutation(np.arange(1, nblocks))[None],
+                        jnp.int32)
+    for start in sliding_chunk_starts:
+        a = (cq, ck, cv, table,
+             jnp.asarray(start + np.arange(chunk)[None], jnp.int32))
+        out.append(_close(
+            f"sliding_prefill_bf16_H{c_heads}_KV{c_kv}_Dh{c_dim}_ring{ring}"
+            f"_at{start}", [1, chunk, ring * bs, c_heads, c_dim], walk(*a),
+            gather(*a), rtol=0, atol=1e-2))
 
     # attention over latent rows, one decode step at the chatgen cell's
     # shape (32 slots, 16 heads' absorbed queries over rows of 512 + 64

@@ -75,9 +75,10 @@ slot's ring, or the table itself — so a query's window is consecutive
 blocks modulo the table's width from the block of its lower bound, and
 the walk copies those and no other (every slot's whole ring was
 gathered before); walked row k holds position `base + k`, so the mask
-stays linear.  That needs a run of `window + q_len - 1 + block_size`
-rows (no row at or below the newest overwritten by a newer lap:
-kernels/registry.py refuses a shorter ring by what `grouped_info`
+stays linear.  That needs a run of `window + q_len - 1` rows (no row a
+query sees overwritten by a newer lap) and, this walk being held to the
+run's own count of blocks from a block's first row, `block_size` more
+(kernels/registry.py refuses a shorter ring by what `grouped_info`
 says).  At `G` = 1 nothing of this is traced.
 
 And latent rows (`latent_attention_pallas`; serving/layers.py
@@ -90,14 +91,27 @@ tile, so the call hands the kernel one operand and a block is copied
 once; the caller keeps the accumulator's first `rank` lanes, the value.
 
 A prefill chunk over grouped rows (`_prefill_walk`, what
-`grouped_attention_pallas` runs past `STEP_QUERIES` queries; a full
-layer's chunk of serving/layers.py `_grouped_attend`) is a kernel of its
+`grouped_attention_pallas` runs past `STEP_QUERIES` queries; a chunk of
+serving/layers.py `_grouped_attend`) is a kernel of its
 own beside `_walk_kernel`, with the same liveness and the same online
 softmax: ONE request, its table and `q_pos` in scalar prefetch, the grid
 over tiles of `tq` query positions.  A program copies the request's
 blocks from the table's first entry to the one its LAST position needs
 (tiles of blocks, double-buffered, as above) and nothing behind it — a
 padded tail's positions run past the table, and the run ends with it.
+Under a window (`sliding`, the layer's) its liveness is the decode
+walk's third rule at a tile of queries (`_sliding_run`, shared): from
+the block of the tile's OLDEST lower bound, modulo the table — the
+request's ring, or the table itself — to the block of its last
+position.  A tile is copied block by block, so it lies in VMEM in
+walked order whatever the ring's wrap: walked row k holds position
+`base + k` and the mask is two bounds a query; the tiles every query
+sees whole are now a run in the middle (masked / whole / masked).  A
+ring of `window + T - 1` rows is enough — no row a query sees was
+overwritten by the chunk's newest position — and one that short may
+have ONE block that is its oldest and its newest at once, which the
+walk copies at both ends and masks by position at each (the request's
+whole ring was gathered before, whatever the position).
 What differs is the product: a chunk is compute-bound, and block-diagonal
 queries would multiply every K/V head's lanes for each query head —
 `kv_heads` times the MXU work.  So the program regroups its queries once
@@ -282,6 +296,20 @@ def _tile_copies(pairs, sem, entry, n_blocks, KB, tile, slot, go):
     jax.lax.fori_loop(first, jnp.minimum(first + KB, n_blocks), one, 0)
 
 
+def _sliding_run(low, last, *, bs, W, most):
+    """A sliding run's walk, from the oldest lower bound `low` among its
+    queries and their newest position `last` (negative: nothing to walk):
+    consecutive blocks modulo the run's `W` entries from the one that
+    holds `low`, `most` of them at most.  -> (base, n_blocks, entry):
+    walked row k holds position `base + k`, walked block `blk` is entry
+    `entry(blk)` of the table."""
+    first = low // bs
+    base = first * bs
+    n_blocks = jnp.where(last >= 0,
+                         jnp.minimum(last // bs - first + 1, most), 0)
+    return base, n_blocks, lambda blk: jax.lax.rem(first + blk, W)
+
+
 def _live_run(qp, b, *, T, bs, W, window, chunk, sliding):
     """A slot's liveness, from its query positions and what the call says
     of its rows: which entries of its table the walk copies and which of
@@ -312,11 +340,8 @@ def _live_run(qp, b, *, T, bs, W, window, chunk, sliding):
         # bounds; rows past `last` are masked as the causal run's are
         lows = [jnp.maximum(jnp.where(qp[b, t] >= 0, qp[b, t], last)
                             - sliding + 1, 0) for t in range(T)]
-        first = functools.reduce(jnp.minimum, lows) // bs
-        base = first * bs
-        n_blocks = jnp.where(last >= 0,
-                             jnp.minimum(last // bs - first + 1, W), 0)
-        entry = lambda blk: jax.lax.rem(first + blk, W)
+        base, n_blocks, entry = _sliding_run(
+            functools.reduce(jnp.minimum, lows), last, bs=bs, W=W, most=W)
 
         def visible(by_query):
             qlow = by_query(lambda t: lows[t] - base)
@@ -505,21 +530,23 @@ def grouped_attention_pallas(q, ck, cv, tables, q_pos, *, kv_heads: int,
     walks the window's live blocks modulo the table it was handed — the
     slot's ring where `newest` is given, which is then the largest of
     the slot's `q_pos` (serving/layers.py `_address_grouped`) and read
-    from them.  A prefill chunk walks one causal run of the request's
-    table: under a window or over a ring it has no walk (ROADMAP S14's
-    prefill half)."""
+    from them.  A prefill chunk (`T` past `STEP_QUERIES`, one request)
+    walks the same two runs a tile of its queries a program: one causal
+    run of the request's table, or under a `window` the blocks from the
+    tile's oldest lower bound, modulo the table."""
     B, T, H, Dh = q.shape
     args = dict(block_size=int(block_size), kv_heads=int(kv_heads),
                 scale=None if scale is None else float(scale),
                 interpret=pallas_backend.interpret())
+    if newest is not None and not window:
+        raise ValueError(
+            f"paged attention kernel: the rows of {T} queries' table are "
+            f"a ring (`newest` given) under no window; the walk takes a "
+            f"ring's rows by position behind a window's lower bound, and "
+            f"one causal run from a table's first entry")
     if T > STEP_QUERIES:
-        if window or newest is not None:
-            raise ValueError(
-                f"paged attention kernel: a prefill chunk of {T} queries "
-                f"walks one causal run of the request's table from its "
-                f"first entry; the chunk has no walk of a sliding run — a "
-                f"window's lower bound, a ring's modular rows")
-        return _prefill_walk(q, ck, cv, tables, q_pos, **args)
+        return _prefill_walk(q, ck, cv, tables, q_pos, sliding=int(window),
+                             **args)
     return _walk(q, ck, cv, tables, q_pos, kv_mode="dense",
                  sliding=int(window), **args).reshape(B, T, H * Dh)
 
@@ -549,11 +576,13 @@ def prefill_tiles(q_len: int, num_heads: int, kv_heads: int, head_dim: int,
 
 def _prefill_kernel(tbl, qp, q_ref, pos_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
                     sem, q_s, acc, m_s, l_s, *, scale, bs, W, KB, tq, KV, G,
-                    Dh):
+                    Dh, sliding=0):
     """One program a tile of `tq` query positions of the one request:
-    its blocks from the table's first entry to the one the tile's LAST
-    position needs, a tile of `KB` at a time; a K/V head's `G * tq` query
-    rows against that head's lanes of the tile."""
+    its blocks up to the one the tile's LAST position needs — from the
+    table's first entry, or under a window (`sliding`) from the block of
+    the tile's oldest lower bound, modulo the table — a tile of `KB` at a
+    time; a K/V head's `G * tq` query rows against that head's lanes of
+    the tile."""
     i = pl.program_id(0)
     TK, R = KB * bs, G * tq
 
@@ -563,14 +592,41 @@ def _prefill_kernel(tbl, qp, q_ref, pos_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
 
     first, last = jax.lax.fori_loop(
         1, tq, span, (qp[0, i * tq], qp[0, i * tq]))
-    # a padded tail's positions run past the table: the run ends with it
-    n_blocks = jnp.clip((last + bs) // bs, 0, W)
+    if sliding:
+        # the decode walk's third rule (`_live_run`) for a tile of
+        # queries: a query at p sees positions max(0, p - sliding + 1) ..
+        # p, which lie in consecutive blocks modulo W; the tile is copied
+        # block by block, so walked row k holds position `base + k`
+        # whatever the ring's wrap, and the mask is two bounds a query.
+        # One block more than the run has may be walked: a run of just
+        # `sliding + T - 1` rows whose oldest block is also its newest
+        # (its first rows a lap ahead of its last) is copied at both ends
+        # of the walk and masked by position at each
+        base, n_blocks, entry = _sliding_run(
+            jnp.maximum(first - sliding + 1, 0), last, bs=bs, W=W,
+            most=W + 1)
+    else:
+        # a padded tail's positions run past the table: the run ends
+        # with it
+        n_blocks = jnp.clip((last + bs) // bs, 0, W)
+        entry = lambda blk: blk
     n_tiles = (n_blocks + KB - 1) // KB
     # tiles every query of the program sees whole need no mask
-    n_whole = jnp.clip((first + 1) // TK, 0, n_tiles)
+    if sliding:
+        # they lie between the newest query's lower bound and the oldest
+        # query's position, where every block of theirs was copied:
+        # masked / whole / masked
+        n_cut = jnp.clip(
+            (jnp.maximum(last - sliding + 1, 0) - base + TK - 1) // TK,
+            0, n_tiles)
+        n_whole = jnp.clip(
+            jnp.minimum((first + 1 - base) // TK, n_blocks // KB),
+            n_cut, n_tiles)
+    else:
+        n_whole = jnp.clip((first + 1) // TK, 0, n_tiles)
     tile_copies = functools.partial(
         _tile_copies, ((k_hbm, kbuf), (v_hbm, vbuf)), sem,
-        lambda blk: tbl[0, blk], n_blocks, KB)
+        lambda blk: tbl[0, entry(blk)], n_blocks, KB)
 
     @pl.when(n_tiles == 0)
     def _idle():
@@ -602,12 +658,17 @@ def _prefill_kernel(tbl, qp, q_ref, pos_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         qrow = jnp.concatenate([pos_ref[...]] * G, axis=0)       # (R, 1)
+        if sliding:
+            qlow = jnp.maximum(qrow - sliding + 1, 0) - base
+            qrow = qrow - base
 
         def attend(tile, slot, masked):
             if masked:
                 kidx = tile * TK + jax.lax.broadcasted_iota(
                     jnp.int32, (R, TK), 1)
                 mask = qrow >= kidx
+                if sliding:
+                    mask = mask & (kidx >= qlow)
 
             def head(n):
                 q = q_s[n]
@@ -645,7 +706,12 @@ def _prefill_kernel(tbl, qp, q_ref, pos_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
             attend(j, slot, masked)
             return carry
 
-        jax.lax.fori_loop(0, n_whole, functools.partial(body, False), 0)
+        if sliding:
+            jax.lax.fori_loop(0, n_cut, functools.partial(body, True), 0)
+            jax.lax.fori_loop(n_cut, n_whole,
+                              functools.partial(body, False), 0)
+        else:
+            jax.lax.fori_loop(0, n_whole, functools.partial(body, False), 0)
         jax.lax.fori_loop(n_whole, n_tiles, functools.partial(body, True), 0)
 
         def leave(n):
@@ -659,13 +725,16 @@ def _prefill_kernel(tbl, qp, q_ref, pos_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
 
 
 @functools.partial(jax.jit, static_argnames=("kv_heads", "block_size",
-                                             "scale", "interpret"))
+                                             "scale", "interpret", "sliding"))
 def _prefill_walk(q, ck, cv, tables, q_pos, *, kv_heads, block_size, scale,
-                  interpret):
+                  interpret, sliding=0):
     """A prefill chunk's call, a function of its own (a program lowers
     it once): ONE request's queries q [1, T, H, Dh] over the dense pool
     rows of `kv_heads` heads its table [1, W] addresses, causal from the
-    table's first row under `row <= q_pos[t]` -> [1, T, H * Dh] float32.
+    table's first row under `row <= q_pos[t]` — or, `sliding` > 0, the
+    layer's window: the table is the run its rows lie in by position
+    modulo its length, and a query sees its last `sliding` positions —
+    -> [1, T, H * Dh] float32.
     The grid runs over tiles of query positions; the MXU takes a K/V
     head's `G * tq` query rows against that head's 128-lane slices of
     the tile, not the decode walk's block-diagonal queries, which would
@@ -707,7 +776,8 @@ def _prefill_walk(q, ck, cv, tables, q_pos, *, kv_heads, block_size, scale,
     return pl.pallas_call(
         functools.partial(_prefill_kernel,
                           scale=Dh ** -0.5 if scale is None else scale,
-                          bs=bs, W=W, KB=KB, tq=tq, KV=KV, G=G, Dh=Dh),
+                          bs=bs, W=W, KB=KB, tq=tq, KV=KV, G=G, Dh=Dh,
+                          sliding=sliding),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, T, H * Dh), jnp.float32),
         compiler_params=pltpu.CompilerParams(

@@ -383,15 +383,22 @@ class GroupedAttentionOp(KernelOp):
     `grouped_attention_pallas` for both).  Oracle = the gather of every
     table entry and `attend_grouped` under the layer's visibility mask,
     the expression the layer ran before there was a kernel
-    (`grouped_attention_reference`).  A decode or verify step of a
-    sliding layer (`grouped_info`'s `window`, and `ring` where its rows
-    lie in a ring) takes the same walk over the window's live blocks,
-    modulo the run — where the run is long enough that no row the walk
-    masks by position was overwritten by a newer lap: `window + q_len -
-    1 + block_size` rows.  The shape rule is then the walk's at its
-    tile; a prefill chunk's besides: one causal run of one request's
-    table (under a window or over a ring the CHUNK has no walk: ROADMAP
-    S14's prefill half), heads of whole 128-lane tiles, tiles that fit
+    (`grouped_attention_reference`).  A sliding layer's call
+    (`grouped_info`'s `window`, and `ring` where its rows lie in a ring)
+    takes the same walks over the window's live blocks, modulo the run:
+    consecutive blocks from the one that holds the oldest query's lower
+    bound, walked row k at position `base + k`, two bounds a query.  On
+    the table itself no position laps another and nothing more is asked.
+    Over a ring the mask by position holds where no row a query sees was
+    overwritten by the call's newest position: a run of `window + q_len
+    - 1` rows — the engine's `window + prefill_chunk` passes for its
+    chunk.  That is all a prefill chunk needs (its walk may copy the
+    block that is the run's oldest and newest at once at both of its
+    ends); a decode or verify step's walk is held to the run's own
+    count of blocks and starts at a block's first row, so it needs
+    `block_size` rows more.  A shorter ring keeps the gather.  The
+    shape rule is then the walk's at its tile; a prefill chunk's
+    besides: one request, heads of whole 128-lane tiles, tiles that fit
     VMEM.  The paged, latent and EVA ops keep `q_len` <=
     `STEP_QUERIES`: their prefill is ROADMAP S11's later cases."""
 
@@ -403,28 +410,22 @@ class GroupedAttentionOp(KernelOp):
         from .paged import STEP_QUERIES
 
         t, window = int(info.get("q_len", 1)), int(info.get("window", 0))
-        ring = bool(info.get("ring"))
-        if t > STEP_QUERIES:
-            if window or ring:
-                return False, (
-                    f"q_len {t} is a prefill chunk over "
-                    f"{'a ring' if ring else 'the table'} under a window "
-                    f"of {window} rows: the chunk's walk is one causal run "
-                    f"from the table's first entry and has no sliding run "
-                    f"(ROADMAP S14's prefill half); the decode step's has")
-            return _prefill_walk_supports(info)
-        ok, why = _walk_supports(info)
-        if ok and ring:
+        chunk = t > STEP_QUERIES
+        ok, why = _prefill_walk_supports(info) if chunk \
+            else _walk_supports(info)
+        if ok and info.get("ring"):
             bs = int(info["block_size"])
             run = int(info.get("table_width", 1)) * bs
-            need = window + t - 1 + bs
+            # (a step's walk, held to the run's own count of blocks from
+            # a block's first row, needs a block more)
+            need = window + t - 1 + (0 if chunk else bs)
             if run < need:
                 return False, (
                     f"a ring of {run} rows under a window of {window}: the "
                     f"walk masks a block's rows by position, which holds "
-                    f"where no row at or below the newest was overwritten "
-                    f"by a newer lap — a run of {need} rows or more; a "
-                    f"shorter one keeps the gather")
+                    f"where no row a query of the call's {t} sees was "
+                    f"overwritten by a newer lap — a run of {need} rows or "
+                    f"more; a shorter one keeps the gather")
         return ok, why
 
     def pallas(self, variant, *args, **kwargs):

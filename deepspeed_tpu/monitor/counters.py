@@ -168,8 +168,10 @@ Instrumented sites:
   bytes = pool rows the chunk's attention FETCHES over the same layers
   (its last position + 1 rounded up to a block, the table's width at
   most, in a layer whose prefill call resolves to the walk of the
-  request's live blocks; its run's whole width in a layer that
-  gathers; asked once for each kind of layer at build), all four from
+  request's live blocks — in a sliding layer from the block that holds
+  the chunk's first query's lower bound, the ring at most; its run's
+  whole width in a layer that gathers; asked once for each kind of
+  layer at build), all four from
   positions on the host; `kv.ring_wraps` — calls = requests that ended
   with more rows than a ring holds, bytes = blocks the ring saved them
   in the window group.  Behind a share of the experts

@@ -196,9 +196,10 @@ at build),
 = the pool rows the layers fetch for a chunk: its last position + 1
 rounded up to a block — its padded tail's, the table's width at most —
 in a layer whose prefill call the registry resolves to the walk of the
-request's live blocks, the whole width of its run in a layer that
-gathers; asked once for each kind of layer at build, for one request's
-`prefill_chunk` queries),
+request's live blocks — less, in a sliding layer, the blocks below the
+one its FIRST query's window begins in, its run at most — the whole
+width of its run in a layer that gathers; asked once for each kind of
+layer at build, for one request's `prefill_chunk` queries),
 `kv.ring_wraps` (calls = requests that ended with more rows
 than a ring, bytes = the blocks the ring saved each in the window
 group); behind a share of the experts `serve.moe.experts_touched`
@@ -1154,7 +1155,7 @@ class ServeEngine:
                 "serve.attn.prefill_rows_walked",
                 nbytes=self._grouped_rows_fetched(
                     np.array([pos0 + C]), self._prefill_walks,
-                    self._sliding_prefill_walks))
+                    self._sliding_prefill_walks, n_queries=C))
         if self._index_shared:
             COUNTERS.add("serve.sparse.selections_shared",
                          calls=self._index_shared)
@@ -1412,15 +1413,17 @@ class ServeEngine:
                          held, self._walks_live_blocks, self._sliding_walks))
 
     def _grouped_rows_fetched(self, held, full_walks: bool,
-                              sliding_walks: bool) -> int:
+                              sliding_walks: bool, n_queries: int = 1) -> int:
         """The pool rows the layers with grouped rows FETCH for calls
-        that reach `held` [n] rows each — decoded slots, or the one
-        request of a prefill chunk, its padded tail counted: a call's
-        live blocks where the kind of layer (full, sliding) walks —
-        from the table's first entry in a full layer, from the block of
-        the query's lower bound `held - window` in a sliding one (a
-        decode step's walk: kernels/paged.py `_live_run`) — every entry
-        of its run — the table, or the ring — where it gathers."""
+        that reach `held` [n] rows each with their last `n_queries`
+        positions as queries — decoded slots, or the one request of a
+        prefill chunk, its padded tail counted: a call's live blocks
+        where the kind of layer (full, sliding) walks — from the table's
+        first entry in a full layer, from the block of the OLDEST
+        query's lower bound `held - n_queries - window + 1` in a sliding
+        one (kernels/paged.py `_sliding_run`: a decode step's one query,
+        a chunk's first), the run at most — every entry of its run — the
+        table, or the ring — where it gathers."""
         bs, ring = self.kv.block_size, self.kv.ring_tokens
         table = self.kv.table_width * bs
         full_layers = len(self._row_layers) - self._sliding_layers
@@ -1428,7 +1431,8 @@ class ServeEngine:
         def fetched(walks: bool, run: int, window: int = 0) -> int:
             if not walks:
                 return run * len(held)
-            first = np.maximum(held - window, 0) // bs if window else 0
+            first = np.maximum(held - n_queries - window + 1, 0) // bs \
+                if window else 0
             return int(np.minimum((-(-held // bs) - first) * bs, run).sum())
 
         return full_layers * fetched(full_walks, table) \

@@ -83,9 +83,12 @@ walks each slot's live blocks (kernels/paged.py, a tile of the row's
 `kv_heads` heads serving `num_heads / kv_heads` query heads a key) where
 the registry picks the kernel: a full layer's from the table's first
 entry, a sliding layer's the blocks its window lies in, modulo the run
-(`grouped_info` says `window` and `ring`).  A prefill chunk under a
-window has no walk and gathers its run in `jax.numpy`
-(`grouped_attention_reference`), as every call does off the chip.
+(`grouped_info` says `window` and `ring`).  A prefill chunk of heads of
+whole lane tiles walks the same runs, a tile of its query positions a
+program: a sliding layer's from the block of the tile's oldest lower
+bound, where the ring holds `window + q_len - 1` rows (the engine's
+does).  Every call gathers its run in `jax.numpy`
+(`grouped_attention_reference`) off the chip.
 
 Layers with a state and no rows (`spec.mixer_of(layer)` one of
 models/layer_spec.py `STATE_MIXERS`, the one table that says of each
@@ -656,11 +659,11 @@ def _grouped_attend(spec, cfg, p, h, ck, cv, addr, s, layer: int):
     (models/qwen3_next.py `project_gated`) — (through the kernel registry: on
     the chip, the walk of each slot's live blocks in a decode or verify
     step — the whole table's, or under a window the window's, modulo the
-    ring — and, where the layer attends its whole table causally, of the
-    one request's in a prefill chunk of heads of whole 128-lane tiles,
-    kernels/paged.py; the gather of every table entry elsewhere, in a
-    chunk under a window and in a chunk of narrower heads); output
-    projection.  -> float32."""
+    ring — and of the one request's, by the same two rules, in a prefill
+    chunk of heads of whole 128-lane tiles, kernels/paged.py; the gather
+    of every table entry elsewhere, over a ring too short for the walk's
+    mask and in a chunk of narrower heads); output projection.
+    -> float32."""
     B, T, _ = h.shape
     KV, Dh = spec.kv_heads, cfg.head_dim
     window = spec.window_of(layer)

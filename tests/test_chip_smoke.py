@@ -84,6 +84,9 @@ def test_kernels_phase_toy():
                                                       -1),
                                    prefill_shape=(4, 2, 128, 6, 16),
                                    prefill_starts=(0, 21, 88),
+                                   sliding_chunk_shape=(4, 2, 128, 6, 32,
+                                                        16),
+                                   sliding_chunk_starts=(0, 32, 48, 53, 200),
                                    latent_shape=(3, 4, 32, 16, 3),
                                    routed_shape=(16, 16, 128, 256),
                                    routed_live=2,
@@ -116,6 +119,8 @@ def test_kernels_phase_toy():
         "grouped_prefill_bf16_H4_KV2_Dh128_at0",
         "grouped_prefill_bf16_H4_KV2_Dh128_at21",
         "grouped_prefill_bf16_H4_KV2_Dh128_at88",
+        *(f"sliding_prefill_bf16_H4_KV2_Dh128_ring6_at{start}"
+          for start in (0, 32, 48, 53, 200)),
         "latent_attention_bf16_H4_W48",
         "touched_experts_bf16_T16_E16", "touched_experts_bf16_T16_E16_relu2",
         "grouped_experts_bf16_T160_E4of16",

@@ -679,8 +679,9 @@ def test_the_registry_is_asked_for_each_kind_of_layer(native):
     """What `_grouped_attend` hands the registry says what the layer's
     rows are: on the chip a decode call takes the walk — a full layer's,
     and a sliding layer's in its ring or on the table — and a prefill
-    chunk of these narrow heads keeps the gather; under a window it is
-    the chunk, not the run, that has no walk."""
+    chunk of these narrow heads keeps the gather, whatever its rows; at
+    heads of whole lane tiles the chunk walks too, under a window where
+    its ring holds `window + chunk - 1` rows (the engine's does)."""
     model, params = _model()
     spec, cfg = model.layer_spec(), model.config
     sched = ServeSchedule(max_batch=3, prefill_chunk=CHUNK, block_size=BS,
@@ -694,24 +695,34 @@ def test_the_registry_is_asked_for_each_kind_of_layer(native):
     assert serving_layers.grouped_info(
         spec, cfg, sched, 1, jnp.float32, WINDOW, True)["table_width"] == \
         RING // BS
-    ask = lambda *a: registry.resolve_impl(
-        "grouped_attention", info=serving_layers.grouped_info(
-            spec, cfg, sched, *a))
+    ask = lambda *a, spec=spec, cfg=cfg, sched=sched, **kw: \
+        registry.resolve_impl(
+            "grouped_attention", info=serving_layers.grouped_info(
+                spec, cfg, sched, *a), **kw)
     assert ask(1, jnp.float32) == "pallas"
     assert ask(1, jnp.float32, WINDOW, True) == "pallas"
     assert ask(1, jnp.float32, WINDOW, False) == "pallas"
     assert ask(CHUNK, jnp.float32) == "jnp"
     assert ask(CHUNK, jnp.float32, WINDOW, True) == "jnp"
     assert ask(1, jnp.bfloat16) == "jnp"     # blocks of 8 rows of bf16
-    for kind, why in (((WINDOW, True), "a ring"), ((WINDOW, False),
-                                                   "the table")):
+    for kind in ((WINDOW, True), (WINDOW, False)):
         with pytest.raises(RuntimeError, match=(
-                f"q_len {CHUNK} is a prefill chunk over {why} under a "
-                f"window of 32 rows.*S14's prefill half")):
-            registry.resolve_impl(
-                "grouped_attention", impl="pallas",
-                info=serving_layers.grouped_info(spec, cfg, sched, CHUNK,
-                                                 jnp.float32, *kind))
+                f"{KV} K/V heads of {DH} values.*whole 128-lane tiles")):
+            ask(CHUNK, jnp.float32, *kind, impl="pallas")
+    # heads of whole lane tiles: the chunk walks each kind of layer's run
+    wide = _model(head_dim=128)[0]
+    wide = dict(spec=wide.layer_spec(), cfg=wide.config)
+    assert ask(CHUNK, jnp.float32, **wide) == "pallas"
+    assert ask(CHUNK, jnp.float32, WINDOW, True, **wide) == "pallas"
+    assert ask(CHUNK, jnp.float32, WINDOW, False, **wide) == "pallas"
+    # a ring a block short of `window + chunk - 1` rows keeps the gather
+    short = sched._replace(ring_blocks=RING // BS - 1)
+    assert ask(CHUNK, jnp.float32, WINDOW, True, sched=short, **wide) == "jnp"
+    with pytest.raises(RuntimeError, match=(
+            f"a ring of {RING - BS} rows under a window of {WINDOW}.*"
+            f"a run of {WINDOW + CHUNK - 1} rows")):
+        ask(CHUNK, jnp.float32, WINDOW, True, sched=short, impl="pallas",
+            **wide)
 
 
 @pytest.mark.parametrize("way,head_dim", [("oracle", 128), ("kernel", 128),
@@ -722,8 +733,10 @@ def test_prefill_rows_walked_is_what_each_kind_of_layer_fetches(
     chunk, the request's rows up to the chunk's last position rounded up
     to a block where its prefill call is the walk — on the chip, at
     heads of whole lane tiles — and the table's whole width where it is
-    the gather; a sliding layer always gathers its ring.  The kernel
-    serves the oracle's tokens."""
+    the gather; a sliding layer there the blocks from the one that holds
+    its FIRST query's lower bound — counted here position by position,
+    for chunks before and after the ring's wrap — and its whole ring
+    where it gathers.  The kernel serves the oracle's tokens."""
     import contextlib
 
     model, params = _model(head_dim=head_dim)
@@ -737,14 +750,21 @@ def test_prefill_rows_walked_is_what_each_kind_of_layer_fetches(
         out = eng.generate(prompts, 2)
     d = COUNTERS.delta_since(before)
     walks = way == "kernel" and head_dim == 128
-    assert eng._prefill_walks == walks and not eng._sliding_prefill_walks
+    assert eng._prefill_walks == eng._sliding_prefill_walks == walks
     # a chunk's last position is its padded tail's
     ends = [start + CHUNK for n in lengths for start in range(0, n, CHUNK)]
     table = eng.kv.table_width * BS
-    full = sum(min(-(-e // BS) * BS, table) for e in ends) if walks \
-        else len(ends) * table
+    if walks:
+        full = sum(min(-(-e // BS) * BS, table) for e in ends)
+        # the blocks that hold positions max(0, e - chunk - window + 1)
+        # .. e - 1, what the chunk's first query and its last see
+        sliding = sum(BS * len({p // BS for p in range(
+            max(0, e - CHUNK - WINDOW + 1), e)}) for e in ends)
+        assert sliding < len(ends) * RING and max(ends) > RING   # the wrap
+    else:
+        full, sliding = len(ends) * table, len(ends) * RING
     assert d["serve.attn.prefill_rows_walked"] == {
-        "calls": len(ends), "bytes": 2 * full + 6 * len(ends) * RING}
+        "calls": len(ends), "bytes": 2 * full + 6 * sliding}
     assert d["serve.prefill_chunks"]["calls"] == len(ends)
     if walks:
         assert out == ServeEngine(model, params, serve).generate(prompts, 2)

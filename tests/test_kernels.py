@@ -275,16 +275,18 @@ def test_paged_attention_slot_ignores_the_other_slots(shape):
     assert np.array_equal(a[0], b[0])
 
 
-def test_grouped_attention_kernel_refuses_a_chunk_under_a_window_or_a_ring():
-    """Forced onto a prefill chunk of rows it has no liveness rule for,
-    the kernel says so and computes nothing."""
-    q, ck, cv, tables, q_pos, bs = _paged_inputs(
-        "dense", T=16, H=4, KV=2, Dh=128, bs=8, W=8, lengths=[40, 16])
-    for kw in (dict(window=8), dict(newest=q_pos[:, -1])):
+def test_grouped_attention_kernel_refuses_a_ring_under_no_window():
+    """Handed a ring's newest position and no window — rows that lie by
+    position with nothing to say which of them a query sees — the kernel
+    says so and computes nothing, for a chunk and for a decode step."""
+    for T in (16, 1):
+        q, ck, cv, tables, q_pos, bs = _paged_inputs(
+            "dense", T=T, H=4, KV=2, Dh=128, bs=8, W=8, lengths=[40])
         with kernel_config(interpret=True), \
-                pytest.raises(ValueError, match="no walk of a sliding run"):
+                pytest.raises(ValueError, match="a ring .* under no window"):
             registry.dispatch("grouped_attention", q, ck, cv, tables, q_pos,
-                              impl="pallas", kv_heads=2, block_size=bs, **kw)
+                              impl="pallas", kv_heads=2, block_size=bs,
+                              newest=q_pos[:, -1])
 
 
 def _sliding_both(p, T=1, H=8, KV=2, Dh=16, bs=8, M=6, window=32, ring=True,
@@ -397,6 +399,93 @@ def test_sliding_walk_reads_only_the_windows_blocks():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
 
 
+def _sliding_chunk_both(pos0, T=16, H=4, KV=2, Dh=128, bs=8, M=6, window=32,
+                        ring=True, dtype=jnp.float32, nan_elsewhere=False,
+                        seed=0):
+    """(kernel, oracle) [T, H * Dh] of one request's prefill chunk of a
+    sliding layer: T queries at positions pos0 upward over its run of `M`
+    blocks of `bs` rows — a ring, the rows by position modulo `M * bs`
+    with the chunk's last position the newest written, or (`ring` false)
+    the table.  `nan_elsewhere`: every block no query's window reaches
+    holds NaN in the kernel's pool (the oracle reads a clean copy: its
+    gather weighs those rows by exactly 0 only where they are finite)."""
+    from deepspeed_tpu.serving.kv_cache import pool_rows
+    from deepspeed_tpu.serving.layers import grouped_attention_reference
+
+    rng = np.random.RandomState(seed)
+    table = 1 + rng.permutation(M).astype(np.int32)
+    clean = [rng.randn(M + 1, bs, KV, Dh).astype(np.float32)
+             for _ in range(2)]
+    dirty = [c.copy() for c in clean]
+    if nan_elsewhere:
+        held = {b % M for b in range(max(0, pos0 - window + 1) // bs,
+                                     (pos0 + T - 1) // bs + 1)}
+        for c in dirty:
+            c[np.setdiff1d(np.arange(M + 1), table[sorted(held)])] = np.nan
+    pools = lambda cs: [pool_rows(jnp.asarray(c.reshape(-1, KV, Dh), dtype))
+                        for c in cs]
+    q_pos = jnp.asarray(pos0 + np.arange(T), jnp.int32)[None]
+    q = jnp.asarray(rng.randn(1, T, H, Dh), dtype)
+    args = dict(kv_heads=KV, block_size=bs, scale=None, window=window,
+                newest=q_pos[:, -1] if ring else None)
+    tables = jnp.asarray(table)[None]
+    ref = grouped_attention_reference(q, *pools(clean), tables, q_pos, **args)
+    with kernel_config(interpret=True):
+        out = registry.dispatch("grouped_attention", q, *pools(dirty), tables,
+                                q_pos, impl="pallas", **args)
+    assert out.shape == ref.shape == (1, T, H * Dh)
+    assert out.dtype == ref.dtype == jnp.float32
+    return np.asarray(out)[0], np.asarray(ref)[0]
+
+
+@pytest.mark.parametrize("case", [
+    # a ring of 48 rows (6 blocks of 8) under a window of 32, the
+    # engine's `window + chunk`: a first chunk, and one before the
+    # window fills
+    dict(pos0=0),
+    dict(pos0=16, nan_elsewhere=True),
+    # the chunk that fills the ring, the first to wrap it, one several
+    # laps in
+    dict(pos0=32),
+    dict(pos0=48, nan_elsewhere=True),
+    dict(pos0=5 * _L + 16, nan_elsewhere=True),
+    # chunks off a block's edge: the walk starts inside a block, and a
+    # run of exactly `window + T - 1` rows (33 + 16 - 1 = 48) whose
+    # oldest block is its newest too, copied at both ends of the walk
+    dict(pos0=37),
+    dict(pos0=3 * _L + 5),
+    dict(pos0=41, window=33),
+    dict(pos0=2 * _L + 9, window=33),
+    # a tile of 64 query positions on both sides of the ring's wrap
+    # (1,056 .. 1,119 over a ring of 1,088 rows), its walk three tiles
+    # of 512 rows: masked / whole / masked; and two such tiles, a
+    # program each, three laps in
+    dict(pos0=1088 - 32, T=64, H=2, KV=1, M=136, window=1024),
+    dict(pos0=3 * 1152 - 40, T=128, H=2, KV=1, M=144, window=1000,
+         nan_elsewhere=True),
+    # the window on the table (`ring_blocks` 0): the same rule, no wrap
+    dict(pos0=5, ring=False, M=8),
+    dict(pos0=40, ring=False, M=8, nan_elsewhere=True),
+    # a last chunk's padded tail past the table (64 rows): 5 valid rows
+    dict(pos0=51, ring=False, M=8, valid=5),
+    # Command A+'s 16 query heads a K/V head; a bf16 pool
+    dict(pos0=2 * _L + 16, H=32, KV=2, nan_elsewhere=True),
+    dict(pos0=16, dtype=jnp.bfloat16, atol=2e-2),
+    dict(pos0=3 * _L + 5, dtype=jnp.bfloat16, atol=2e-2),
+], ids=_paged_case_id)
+def test_sliding_chunk_walk_parity(case):
+    """A sliding layer's prefill chunk: each tile of query positions
+    walks the blocks from its oldest lower bound to its last position,
+    modulo the run, and no other — its mask linear in the walked row,
+    two bounds a query — against the gather of the whole run under
+    `_visible` over `newest - (newest - j) % L`."""
+    case = dict(case)
+    valid, atol = case.pop("valid", None), case.pop("atol", 3e-6)
+    out, ref = _sliding_chunk_both(**case)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[:valid], ref[:valid], atol=atol)
+
+
 def test_grouped_oracle_is_the_expression_the_layer_ran():
     """`grouped_attention` through the registry off the chip IS
     `attend_grouped` over the gathered table under `_visible`, bit for
@@ -437,7 +526,7 @@ _GRANITE_INFO = dict(block_size=16, table_width=128, q_len=1, num_heads=32,
     (dict(q_len=512), "8 K/V heads of 64 values.*whole 128-lane tiles"),
     # a sliding layer's decode call: the window on the table, and in a
     # ring long enough that the walk's linear mask holds (Command A+'s
-    # 288 blocks); a shorter ring keeps the gather, and so does the chunk
+    # 288 blocks); a shorter ring keeps the gather
     (dict(window=4096), None),
     (dict(window=4096, ring=True, table_width=288), None),
     (dict(window=4096, ring=True, table_width=257), None),
@@ -561,13 +650,14 @@ _COMMAND_A_CHUNK = dict(_GRANITE_INFO, q_len=512, num_heads=128,
     ({}, None),
     (dict(q_len=1024), None),
     (dict(kv_itemsize=4, block_size=8), None),
-    # the CHUNK has no walk of a sliding run, whatever the decode step has
-    (dict(window=4096, ring=True, table_width=288),
-     "q_len 512 is a prefill chunk over a ring under a window of 4096 "
-     "rows.*no sliding run.*S14's prefill half"),
-    (dict(window=4096),
-     "q_len 512 is a prefill chunk over the table under a window of 4096 "
-     "rows.*no sliding run.*S14's prefill half"),
+    # a sliding layer's chunk: the window on the table, and in a ring
+    # that holds `window + q_len - 1` rows (Command A+'s 288 blocks, the
+    # engine's window + chunk); a shorter ring keeps the gather
+    (dict(window=4096), None),
+    (dict(window=4096, ring=True, table_width=288), None),
+    (dict(window=4096, ring=True, table_width=287),
+     "a ring of 4592 rows under a window of 4096.*no row a query of the "
+     "call's 512 sees.*a run of 4607 rows"),
     (dict(head_dim=64), "8 K/V heads of 64 values.*whole 128-lane tiles"),
     (dict(batch=2), "2 sequences of 512 queries.*one request's table"),
     (dict(kv_mode="int8"), "int8 rows"),
@@ -576,10 +666,11 @@ _COMMAND_A_CHUNK = dict(_GRANITE_INFO, q_len=512, num_heads=128,
     (dict(num_heads=1024, kv_heads=64), "fits the kernel's VMEM"),
 ], ids=lambda v: _paged_case_id(v) if isinstance(v, dict) else "")
 def test_grouped_prefill_shape_rule(change, why, native):
-    """A full layer's prefill call takes the walk on the chip where what
-    the call site sees allows it — one request, no ring, no window,
-    dense rows in whole tiles, heads of whole lane tiles, tiles that fit
-    VMEM — and everything else keeps the gather, with the reason."""
+    """A prefill call takes the walk on the chip where what the call
+    site sees allows it — one request, dense rows in whole tiles, heads
+    of whole lane tiles, tiles that fit VMEM, and under a window a ring
+    no query's rows were lapped in — and everything else keeps the
+    gather, with the reason."""
     info = dict(_COMMAND_A_CHUNK, **change)
     if why is None:
         assert resolve_impl("grouped_attention", info=info) == "pallas"

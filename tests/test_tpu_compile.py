@@ -483,7 +483,8 @@ CASES = [
          refused=r"2 sequences of 512 queries.*one request's table"),
     # a sliding layer's decode call walks its window's live blocks modulo
     # the run: on the table, and in the cell's ring (16 slots, 288 blocks
-    # of 16 rows); the CHUNK under a window keeps the gather
+    # of 16 rows); and so does the CHUNK, a tile of its queries a
+    # program, at the cell's shapes (one request, 288 blocks of 16)
     Case("grouped_H128_KV8_Dh128_window_on_the_table",
          lambda: _command_a_full(window=4096),
          op="grouped_attention"),
@@ -493,15 +494,11 @@ CASES = [
          op="grouped_attention"),
     Case("grouped_H128_KV8_Dh128_prefill512_window_on_the_table",
          lambda: _command_a_full(slots=1, q_len=512, window=4096),
-         op="grouped_attention",
-         refused=r"q_len 512 is a prefill chunk over the table under a "
-                 r"window of 4096 rows: .*no sliding run.*S14"),
+         op="grouped_attention"),
     Case("grouped_H128_KV8_Dh128_prefill512_mixedlen_ring",
          lambda: _command_a_full(slots=1, q_len=512, window=4096, ring=True,
                                  width=288, nblocks=16 * 288 + 1),
-         op="grouped_attention",
-         refused=r"q_len 512 is a prefill chunk over a ring under a "
-                 r"window of 4096 rows: .*no sliding run.*S14"),
+         op="grouped_attention"),
     # latent rows: a decode call at the chatgen cell's tile (16 score
     # rows of 640 lanes over one operand), a verify step, DeepSeek-V2's
     # 128 heads, and what keeps the gather
@@ -681,14 +678,15 @@ def _serve_attention(q_len, slots):
      lambda: ("grouped_attention",
               _command_a_full(window=4096, ring=True, width=288)[2]),
      "pallas"),
-    # one request's chunk of 512: the full layer walks, the rings gather
+    # one request's chunk of 512: the full layer walks, and the rings
+    # (window + chunk = 4,608 rows, one more than the mask needs)
     ("command-a-plus-d4.serve.mixedlen.prefill.full",
      lambda: ("grouped_attention",
               _command_a_full(slots=1, q_len=512)[2]), "pallas"),
     ("command-a-plus-d4.serve.mixedlen.prefill.sliding",
      lambda: ("grouped_attention",
               _command_a_full(slots=1, q_len=512, window=4096, ring=True,
-                              width=288)[2]), "jnp"),
+                              width=288)[2]), "pallas"),
     # 48 slots, 39,937 blocks of 16, a table of 832 entries, 16 query
     # heads on 2 K/V heads of 256, bf16: both calls walk
     ("qwen3-next-80b-a3b-d12.serve.longchat.decode",
@@ -1129,15 +1127,16 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
     table of 1,024 + 288 entries, chunk 512), as the chip traces them:
     the registry answers the walk for every layer's decode call (PR 62:
     the three rings' too, the window's live blocks modulo the ring) and
-    for the full layer's prefill call, and the gather for the three
-    rings' prefill call, so `decode`'s custom calls are the 4 layers'
-    `touched_experts` kernels and the 4 layers' walks — no slot's whole
-    table of 16,384 rows is laid out as `[16, 16384, 8, 128]` and no
-    slot's whole ring as `[16, 4608, 8, 128]` — and `prefill`'s are XLA's own grouped
-    products (`lax.ragged_dot` over the 16 held experts) and the full
-    layer's one prefill walk — the request's whole table is not gathered
+    for every layer's prefill call (PR 64: the three rings' too, a tile
+    of the chunk's queries a program), so `decode`'s custom calls are
+    the 4 layers' `touched_experts` kernels and the 4 layers' walks — no
+    slot's whole table of 16,384 rows is laid out as `[16, 16384, 8,
+    128]` and no slot's whole ring as `[16, 4608, 8, 128]` — and
+    `prefill`'s are the 4 layers' `grouped_experts` kernels and the 4
+    layers' prefill walks — the request's whole table is not gathered
     (`[1, 16384, 8, 128]`) nor scored (`[1, 1, 16, 512, 16384]`, a K/V
-    head's queries against 16,384 rows); every pool enters as
+    head's queries against 16,384 rows), and neither is its whole ring
+    (`[1, 4608, 8, 128]`, `[1, 1, 16, 512, 4608]`); every pool enters as
     `[rows, 1024]`, a K and a V a layer; and weights, pools and
     temporaries fit the chip's 15.75 GB with the room the check's 2.15 GB
     of reference logits needs."""
@@ -1158,7 +1157,7 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
         "grouped_attention", info=grouped_info(
             spec, model.config, sched, q_len, jnp.bfloat16, *kind))
     assert (ask(1), ask(1, 4096, True)) == ("pallas", "pallas")
-    assert (ask(chunk), ask(chunk, 4096, True)) == ("pallas", "jnp")
+    assert (ask(chunk), ask(chunk, 4096, True)) == ("pallas", "pallas")
     progs = ServeProgramBuilder(model, sched).build()
 
     def on(shape, dtype):
@@ -1198,15 +1197,17 @@ def test_command_a_plus_cell_programs_compile_inside_one_chip(program,
                                            "grouped_attention": layers}
     else:
         walks = [ln for ln in calls if "paged_attention_prefill_walk" in ln]
-        assert len(walks) == 1
+        assert len(walks) == layers
         # PR 58: the 4 layers' routed products walk slabs of 1,024 of
         # the chunk's 4,096 rows; XLA's own grouped products are gone
         assert sum("grouped_experts" in ln for ln in calls) == layers
-        assert len(calls) == layers + 1 and "ragged" not in text
+        assert len(calls) == 2 * layers and "ragged" not in text
         assert _kernels_by_scope(text) == {"grouped_experts": layers,
-                                           "grouped_attention": 1}
+                                           "grouped_attention": layers}
         wide = {(1, 16384, 8, 128), (8, 1, 16384, 128), (16, 512, 16384),
-                (1, 1, 16, 512, 16384)} & set(_hlo_by_shape(text))
+                (1, 1, 16, 512, 16384), (1, 4608, 8, 128),
+                (8, 1, 4608, 128), (16, 512, 4608),
+                (1, 1, 16, 512, 4608)} & set(_hlo_by_shape(text))
         assert not wide, wide
     for rows in (nblocks * bs, (slots * ring + 1) * bs):
         layouts = {layout for _, layout in _hlo_by_shape(text)[(rows, 1024)]}
