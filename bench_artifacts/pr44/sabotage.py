@@ -27,7 +27,7 @@ def sabotages():
     from deepspeed_tpu.models import evabyte
     from deepspeed_tpu.moe import dropless
     from deepspeed_tpu.models.layer_spec import LayerSpec
-    from deepspeed_tpu.serving import engine, layers, programs
+    from deepspeed_tpu.serving import kv_cache, layers, programs
 
     def heads_mod(orig):
         def attend(q, k, v, mask):
@@ -66,7 +66,7 @@ def sabotages():
             lambda self, layer: o(self, layer) and layer != 1))],
         "c_head_n_reads_kv_n_mod_8": [(c2, "attend_grouped", heads_mod)],
         "d_ring_without_the_chunks_margin": [
-            (engine, "ring_blocks_for", lambda o: (
+            (kv_cache, "ring_blocks_for", lambda o: (
                 lambda window, chunk, bs: window // bs)),
             (programs.ServeProgramBuilder, "_check_grouped", lambda o: (
                 lambda self, s: None))],

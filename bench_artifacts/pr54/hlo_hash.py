@@ -19,8 +19,7 @@ import jax.numpy as jnp
 from benchmarks.harness import load_json, plugin
 from deepspeed_tpu.serving import (PagedKVCache, ServeConfig,
                                    ServeProgramBuilder, ServeSchedule)
-from deepspeed_tpu.serving.engine import ring_blocks_for
-from deepspeed_tpu.serving.kv_cache import resolve_kv_dtype
+from deepspeed_tpu.serving.kv_cache import cache_plan, resolve_kv_dtype
 
 CELLS = ["gpt2-xl.serve.chat", "deepseek-v2-lite-d9.serve.chatgen",
          "command-a-plus-d4.serve.mixedlen"]
@@ -33,11 +32,8 @@ for name in CELLS:
     model = plugin("models", config["family"]).build(
         config, seq_len=c.max_seq_len, n_dev=1, **w.get("model", {}))
     cfg, spec = model.config, model.layer_spec()
-    width = -(-c.max_seq_len // c.block_size)
-    window = max(spec.layer_windows, default=0)
-    ring = ring_blocks_for(window, c.prefill_chunk, c.block_size) \
-        if window else 0
-    ring = ring if ring < width else 0
+    plan = cache_plan(spec, cfg, c)
+    width, ring = plan.table_width, plan.ring_blocks
     kv_dtype = cfg.param_dtype if c.kv_dtype is None else c.kv_dtype
     sched = ServeSchedule(
         max_batch=c.max_batch, prefill_chunk=c.prefill_chunk,
@@ -46,13 +42,7 @@ for name in CELLS:
         draft_len=int(c.draft_len), ring_blocks=ring)
     progs = ServeProgramBuilder(model, sched).build()
     caches = jax.eval_shape(lambda: PagedKVCache(
-        num_layers=cfg.num_layers, num_heads=spec.kv_heads or cfg.num_heads,
-        head_dim=cfg.head_dim, num_blocks=c.num_blocks,
-        block_size=c.block_size, table_width=width, dtype=kv_dtype,
-        prefix_cache=False, latent_width=spec.latent_width,
-        ring_tokens=ring * c.block_size,
-        ring_layers=[i for i in range(cfg.num_layers) if spec.window_of(i)]
-        if ring else (), max_requests=c.max_batch).caches)
+        plan, c.num_blocks, dtype=kv_dtype, prefix_cache=False).caches)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     on = jax.ShapeDtypeStruct
     R, C, W = c.max_batch, c.prefill_chunk, width + ring

@@ -38,8 +38,9 @@ def main():
     from deepspeed_tpu.kernels.gdn import gdn_step_info
     from deepspeed_tpu.kernels.ssm import live_slots
     from deepspeed_tpu.models import qwen3_next as qn
-    from deepspeed_tpu.serving import (PagedKVCache, ServeProgramBuilder,
-                                       ServeSchedule)
+    from deepspeed_tpu.serving import (PagedKVCache, ServeConfig,
+                                       ServeProgramBuilder, ServeSchedule)
+    from deepspeed_tpu.serving.kv_cache import cache_plan
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
@@ -97,12 +98,10 @@ def main():
             progs = builder.build()
             step = jax.jit(builder.step_logits, donate_argnums=(1,))
             kv = PagedKVCache(
-                num_layers=cfg.num_layers, num_heads=cfg.kv_heads,
-                head_dim=cfg.head_dim, num_blocks=nblocks, block_size=bs,
-                table_width=W, dtype=jnp.bfloat16, prefix_cache=False,
-                max_requests=args.slots,
-                state_layers=spec.state_layers(cfg.num_layers),
-                state_shapes=spec.state_shapes)
+                cache_plan(spec, cfg, ServeConfig(
+                    block_size=bs, max_batch=args.slots, prefill_chunk=chunk,
+                    max_seq_len=args.seq)),
+                nblocks, dtype=jnp.bfloat16, prefix_cache=False)
             table = kv.alloc("r", -(-(len(prompt) + args.steps) // bs))
             table = np.pad(table, (0, W - len(table)))
             rows, caches = [], kv.caches

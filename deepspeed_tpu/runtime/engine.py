@@ -2875,41 +2875,17 @@ class DeepSpeedEngine:
     def zero_offload_param(self):
         return self._config.zero_config.offload_param
 
-    def zero_sub_group_size(self):
-        return self._config.zero_config.sub_group_size
-
     def zero_optimization_stage(self):
         return self._config.zero_optimization_stage
 
     def zero_reduce_bucket_size(self):
         return self._config.zero_config.reduce_bucket_size
 
-    def zero_allgather_bucket_size(self):
-        return self._config.zero_config.allgather_bucket_size
-
-    def zero_allgather_partitions(self):
-        return self._config.zero_config.allgather_partitions
-
-    def zero_contiguous_gradients(self):
-        return self._config.zero_config.contiguous_gradients
-
     def zero_elastic_checkpoint(self):
         return self._config.zero_config.elastic_checkpoint
 
     def zero_load_from_fp32_weights(self):
         return self._config.zero_config.load_from_fp32_weights
-
-    def zero_max_live_parameters(self):
-        return self._config.zero_config.max_live_parameters
-
-    def zero_max_reuse_distance(self):
-        return self._config.zero_config.max_reuse_distance
-
-    def zero_prefetch_bucket_size(self):
-        return self._config.zero_config.prefetch_bucket_size
-
-    def zero_param_persistence_threshold(self):
-        return self._config.zero_config.param_persistence_threshold
 
     def zero_gather_fp16_weights_on_model_save(self):
         return self._config.zero_config.gather_fp16_weights_on_model_save

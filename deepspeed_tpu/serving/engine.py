@@ -100,155 +100,108 @@ output bitwise-identical with the cache on or off.  Counters:
 `kv.prefix_hits`, `kv.prefix_hit_tokens`, `kv.cow_copies`,
 `kv.session_pins`, `kv.prefix_evictions`.
 
-Summarised windows (a layer spec with "eva" attention): the cache
-keeps exact rows only for a request's open window and one summary row
-per chunk behind it.  The programs write both; the engine does the
-book-keeping at step boundaries: before a call it takes the blocks the
-call will write (`kv.extend`), after it, when a request's cached length
-reaches a multiple of the window, it closes the window (`kv.close_window`:
-the window's exact blocks go back to the free list mid-request, its
-summary rows become visible — the programs read that off the position).
-No program of its own, so nothing compiles after warm-up.  For such a
-model the engine refuses, by name, `prefix_cache=True`, sessions,
-`draft_len > 0`, quantized weights and int8/int4 rows.  Counters:
-`kv.summary_rows`, `kv.window_closes`, `serve.eva.rows_read`,
-`serve.eva.context_tokens`, `serve.eva.rows_walked` (bytes = the rows
-attention fetches for the queries `rows_read` counts: the live window
-and summary blocks where the registry picks the kernel for the decode
-program's shapes, the table's whole width where it picks the oracle);
-host span `eva.window_close`.
+What the cache keeps, family by family, is the cache plan's
+(serving/kv_cache.py `cache_plan`: a list of layer groups); the engine
+builds the schedule and the cache from it, refuses in a group's own
+words what is not offered for it (`prefix_cache=True`, a mesh of more
+than one device, sessions; the program builder refuses `draft_len > 0`,
+quantized weights and int8/int4 rows), and at step boundaries does the
+same three things for every plan: before a call it takes the blocks the
+call will write where a run's blocks are not handed out at admission
+(`kv.extend`: a window's exact and summary blocks, a ring's), before a
+request's first prefill chunk it zeroes its slot's arrays where layers
+keep arrays by slot (`kv.reset_state`, phase `serve.state.reset`:
+launched behind whatever step still decodes for the slot's last tenant;
+`prefill` learns the slot from one more entry behind the request's
+table, and a decode step hands a slot that is not running its arrays
+back as it found them), and after a call it closes a window the call
+filled (`kv.close_window`: the exact blocks go back mid-request, the
+summary rows become visible — the programs read that off the position;
+host span `eva.window_close`).  No program of its own, so nothing
+compiles after warm-up.  What a step and a chunk read is counted kind by
+kind (`kv_cache.KINDS`), from positions on the host, with "walks" — a
+call fetches the live blocks, or the running slots' state, and not
+everything — being what the kernel registry answers for the programs'
+shapes, asked once at build:
 
-Latent rows and routed experts (a layer spec with "latent" attention
-and a "routed_experts" FFN): the cache holds one row a token for all
-heads, under the same allocator and tables as the paged one, and the
-programs pick their attention products from the call's query count.
-For such a model the engine refuses, by name, `prefix_cache=True`,
-sessions, `draft_len > 0`, quantized weights, int8/int4 rows and a mesh
-of more than one device.  A decode step's absorbed products read each
-running slot's live blocks where they lie (kernels/paged.py, the walk at
-one K/V head a row and one operand) where the registry picks the kernel
-for the decode program's shapes; a prefill chunk, and every backend but
-the TPU, gathers the table's rows.  Counters: `serve.mla.rows_read`
-(calls = queries decoded, bytes = latent rows they attend: every cached
-row), `serve.mla.rows_walked` (the same calls, bytes = latent rows the
-step FETCHES for them: a slot's cached length rounded up to a block
-where its decode call is the walk, the table's whole width where it
-gathers),
-`serve.moe.assignments` (calls = routed-layer calls, bytes =
-token-expert pairs they computed: tokens x top_k, nothing dropped) and
-`serve.moe.experts_touched` (calls = decode steps x routed layers,
-bytes = experts with at least one active slot's token, counted in the
-program and read back with the step's tokens, one step after the
-launch) and `serve.moe.experts_streamed` (the same calls, bytes =
+* paged: `serve.paged.rows_walked` (calls = slots decoded, bytes = the
+  pool rows attention reads for them: a slot's live blocks where the
+  paged kernel runs, the table's whole width where the jnp oracle does).
+* eva (exact rows for an open window, a summary row a chunk behind it):
+  `kv.summary_rows`, `kv.window_closes`, `serve.eva.rows_read`,
+  `serve.eva.context_tokens`, `serve.eva.rows_walked` (bytes = the rows
+  attention fetches for the queries `rows_read` counts: the live window
+  and summary blocks where it walks, the table's whole width where not).
+* latent (one row a token for all heads; the programs pick expanded or
+  absorbed products from the call's query count): `serve.mla.rows_read`
+  (calls = queries decoded, bytes = latent rows they attend: every
+  cached row), `serve.mla.rows_walked` (the same calls, bytes = latent
+  rows the step FETCHES: a slot's cached length rounded up to a block
+  where its decode call is the walk — kernels/paged.py, one K/V head a
+  row and one operand —, the table's whole width where it gathers; a
+  prefill chunk, and every backend but the TPU, gathers).
+* sparse (latent rows of which a learned selection is attended: the
+  "full" layers keep an index key a token, the "shared" ones take the
+  choice over; `serve.mla.*` is not emitted): `serve.sparse.keys_scored`
+  (calls = queries decoded x "full" layers, bytes = index keys they
+  score: every cached one), `serve.sparse.rows_selected` (calls = queries
+  decoded x layers, bytes = rows the chosen sets hold: min(cached,
+  index_topk) a layer), `serve.sparse.rows_fetched` (the same calls,
+  bytes = latent rows the decode program gathers for them: `index_topk`
+  a slot a layer, chosen or not), `serve.sparse.selections_shared`
+  (calls = layer-calls that attended a selection made by an earlier
+  layer of the same call: the "shared" layers of every step and chunk).
+* grouped (rows of `kv_heads` keys and values; sliding layers in a ring
+  of `window + prefill_chunk` rows a request, sized for `max_batch`
+  requests so that it never runs dry — no option — or, where the ring
+  would be no shorter than `max_seq_len` or layers with a state stand
+  beside them, in the table under a mask): `serve.window.rows_read`
+  (calls = queries decoded, bytes = rows one of them attends in ONE
+  sliding layer: min(cached, window)), `serve.attn.rows_read` (the same
+  summed over all the layers), `serve.attn.rows_walked` (calls = slots
+  decoded, bytes = the pool rows the layers fetch for that: in a group
+  that walks, a slot's cached length rounded up to a block — less, in a
+  sliding layer, the blocks below the one its window begins in — and its
+  run's whole width — the table's, or the ring's — in a group that
+  gathers), `serve.attn.prefill_rows_walked` (calls = prefill chunks
+  launched, bytes = the pool rows the layers fetch for a chunk: its last
+  position + 1 rounded up to a block — its padded tail's, the table's
+  width at most — less, in a sliding layer, the blocks below the one its
+  FIRST query's window begins in, its run at most; the whole width of
+  its run in a group that gathers), `kv.ring_wraps` (calls = requests
+  that ended with more rows than a ring, bytes = the blocks the ring
+  saved each).
+* ssm | gdn | conv (models/layer_spec.py `STATE_MIXERS`: a float32 state
+  and the convolution's last inputs a SLOT — the convolution kind those
+  inputs alone —, held for all `max_batch` slots whatever is seated, so
+  the state and not the rows sizes `max_batch`; grouped attention in the
+  other layers): `serve.ssm.state_bytes` (calls = decode steps, bytes =
+  what the step's program reads and writes: where the recurrence is the
+  kind's kernel, the float32 state of the RUNNING slots twice and every
+  slot's convolution inputs twice; where it is the oracle, or the kind
+  has no kernel, every slot's of both, twice), `serve.ssm.slots_live`
+  (bytes = running slots x layers with a state), `serve.ssm.state_resets`
+  (calls = slots zeroed) — under `serve.gdn.*` or `serve.conv.*`, name
+  for name, as the kind's entry of `STATE_MIXERS` says.
+
+Behind routed FFNs, whatever the cache: `serve.moe.assignments` (calls =
+routed-layer calls, bytes = token-expert pairs they computed: tokens x
+top_k, nothing dropped; not emitted behind a share of the experts, where
+only the program knows how many of a call's assignments it held),
+`serve.moe.experts_touched` (calls = decode steps x routed layers, bytes
+= experts — of those held — with at least one active slot's token,
+counted in the program and read back with the step's tokens, one step
+after the launch), `serve.moe.experts_streamed` (the same calls, bytes =
 experts whose weights the step's routed product read: the touched ones
 where it follows the touched list — a TPU — or sorts by expert, every
 one held where it masks; `moe/dropless.py::routed_way`, asked once at
-build, and no read of its own) and `serve.moe.prefill_rows_multiplied`
-(calls = prefill chunks x routed layers, bytes = assignment rows the
-chunks' routed products multiplied with an expert's matrices: the slabs
-walked times a slab's rows where the product walks compact slabs of the
-rows held — a TPU —, tokens x top_k where it groups every assignment;
-counted in the program, `moe/dropless.py::rows_multiplied`, and
-returned behind each chunk's sample: the arrays are kept with the
-request and read when its first token is, never by a read of their
-own).
-
-A learned selection of the latent rows (a layer spec whose
-`layer_indexers` mark layers "full" or "shared", models/glm_moe_dsa.py):
-the "full" layers keep one index key a token beside the latent row,
-under the same tables and allocator (`kv.index_layers`), and every
-refusal above holds.  Counters, from positions on the host like
-`serve.mla.rows_read`, which this family does not emit (its queries do
-not attend every cached row): `serve.sparse.keys_scored` (calls =
-queries decoded x "full" layers, bytes = index keys they score: every
-cached one), `serve.sparse.rows_selected` (calls = queries decoded x
-layers, bytes = rows the chosen sets hold: min(cached, index_topk) a
-layer), `serve.sparse.rows_fetched` (the same calls, bytes = latent rows
-the decode program gathers for them: its list's `index_topk` rows a
-slot a layer, chosen or not) and `serve.sparse.selections_shared`
-(calls = layer-calls that attended a selection made by an earlier layer
-of the same program call: the "shared" layers of every decode step and
-prefill chunk).
-
-Grouped rows over two groups of layers (a layer spec with "grouped"
-attention some of whose layers have a window): the full layers' rows are
-handed out at admission as the paged ones are, and `num_blocks` is
-theirs; the sliding layers' rows live in a group of their own, a ring of
-`window + prefill_chunk` rows a request (whole blocks), which the engine
-sizes for `max_batch` requests so that it never runs dry — no option —
-and takes from as positions are first written (`kv.extend`, before a
-prefill chunk and before a decode step that enters a block).  Where the
-ring would be no shorter than `max_seq_len` the cache is one group and
-the window is a mask.  For such a model the engine refuses, by name,
-`prefix_cache=True`, sessions, `draft_len > 0`, quantized weights,
-int8/int4 rows and a mesh of more than one device.  Counters, from
-positions on the host: `serve.window.rows_read` (calls = queries
-decoded, bytes = rows one of them attends in ONE sliding layer:
-min(cached, window)), `serve.attn.rows_read` (the same summed over all
-the layers), `serve.attn.rows_walked` (calls = slots decoded, bytes =
-the pool rows the layers fetch for that: in a layer whose decode call
-the registry resolves to the walk of live blocks, a slot's cached length
-rounded up to a block — less, in a sliding layer, the blocks below the
-one its window begins in — and its run's whole width — the table's, or
-the ring's — in a layer that gathers; asked once for each kind of layer
-at build),
-`serve.attn.prefill_rows_walked` (calls = prefill chunks launched, bytes
-= the pool rows the layers fetch for a chunk: its last position + 1
-rounded up to a block — its padded tail's, the table's width at most —
-in a layer whose prefill call the registry resolves to the walk of the
-request's live blocks — less, in a sliding layer, the blocks below the
-one its FIRST query's window begins in, its run at most — the whole
-width of its run in a layer that gathers; asked once for each kind of
-layer at build, for one request's `prefill_chunk` queries),
-`kv.ring_wraps` (calls = requests that ended with more rows
-than a ring, bytes = the blocks the ring saved each in the window
-group); behind a share of the experts `serve.moe.experts_touched`
-counts among those held, and `serve.moe.assignments` is not emitted
-(only the program knows how many of a call's assignments it held).
-
-A state beside rows (a layer spec some of whose layers mix tokens by a
-state-space recurrence, by the gated delta rule or by a gated short
-convolution — the kinds of models/layer_spec.py `STATE_MIXERS` —
-grouped attention in
-the others): those layers own no cache rows — `num_blocks`, the tables and
-admission count the attention layers alone — but a float32 state and
-the convolution's last inputs (the convolution kind: those inputs alone)
-a SLOT, held for all `max_batch` slots
-whatever is seated: the state, not the rows, sizes `max_batch`.  When a
-request is seated, before its first prefill chunk, the engine zeroes its
-slot's entries on the device (`kv.reset_state`, phase
-`serve.state.reset`): launched behind whatever step still decodes for
-the slot's last tenant, so it holds under the loop that runs ahead; a
-decode step hands a slot that is not running its state back as it found
-it, so a request between its prefill chunks is safe from the steps that
-run meanwhile.  `prefill` learns the slot from one more entry behind the
-request's table.  For such a model the engine refuses, by name,
-`prefix_cache=True` and sessions (a shared or resumed prefix would need
-the state as it stood at the prefix's end, and nothing stores one),
-`draft_len > 0` (a rejected draft would have to rewind the state),
-quantized weights, int8/int4 rows and a mesh of more than one device.
-Counters, from what the host knows: `serve.ssm.state_bytes` (calls =
-decode steps, bytes = state the step's program reads and writes, as
-the program is built — decided once, from what `kernels/registry.py`
-answers for the decode program's shapes: where the recurrence is the
-`ssm_step` kernel, the float32 state of the RUNNING slots twice and
-every slot's convolution inputs twice; where it is the oracle, every
-slot's of both, twice), `serve.ssm.slots_live` (calls
-= decode steps, bytes = running slots x layers with a state),
-`serve.ssm.state_resets` (calls = slots zeroed) —
-under `serve.gdn.*` in place of `serve.ssm.*`, name for name, where the
-layers with a state are gated delta-rule mixers (`gdn_step` the kernel
-asked about), under `serve.conv.*` where they are gated short
-convolutions (no kernel to ask about: every slot's kept rows, twice) —
-the kind's entry of `STATE_MIXERS` says which — and
-`serve.attn.rows_read`, `serve.attn.rows_walked` and
-`serve.attn.prefill_rows_walked` over the attention layers.
-
-What a paged step reads: `serve.paged.rows_walked` (calls = slots
-decoded, bytes = the pool rows attention reads for them: a slot's live
-blocks where the paged kernel runs, the table's whole width where the
-jnp oracle does — what the kernel registry answers for the decode
-program's shapes, asked once at build).
+build) and `serve.moe.prefill_rows_multiplied` (calls = prefill chunks x
+routed layers, bytes = assignment rows the chunks' routed products
+multiplied with an expert's matrices: the slabs walked times a slab's
+rows where the product walks compact slabs — a TPU —, tokens x top_k
+where it groups every assignment; counted in the program,
+`moe/dropless.py::rows_multiplied`, returned behind each chunk's sample
+and read when the request's first token is, never by a read of its own).
 
 Speculative decoding (`draft_len > 0`): each decode step becomes a
 verify step — a host-side n-gram drafter proposes up to `draft_len`
@@ -274,13 +227,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..kernels.eva import live_blocks
-from ..models.layer_spec import STATE_MIXERS
 from ..monitor.counters import COUNTERS
 from ..monitor.tracing import phase
 from ..runtime.resilience import fault_point
 from ..utils.logging import logger
-from .kv_cache import PagedKVCache, TRASH_BLOCK, resolve_kv_dtype
+from .kv_cache import (KINDS, TRASH_BLOCK, Counted, PagedKVCache, cache_plan,
+                       resolve_kv_dtype)
 from .programs import ServeProgramBuilder, ServeSchedule
 from .scheduler import (ADMISSION_POLICIES, ERROR, FINISHED, RUNNING,
                         Request, Scheduler)
@@ -347,13 +299,6 @@ class ServeConfig:
     @property
     def quant_mode(self) -> str:
         return self.quantized_weights if self.quantized_weights else "none"
-
-
-def ring_blocks_for(window: int, prefill_chunk: int, block_size: int) -> int:
-    """Blocks of a sliding layer's ring: the window and one prefill
-    chunk — the chunk's rows are written before its oldest query has
-    attended the window behind it — in whole blocks."""
-    return -(-(window + prefill_chunk) // block_size)
 
 
 @dataclasses.dataclass
@@ -451,90 +396,20 @@ class ServeEngine:
             raise ValueError(
                 f"serving max_seq_len {self.max_seq_len} exceeds the "
                 f"model's positional table ({cfg.max_seq_len})")
-        table_width = -(-self.max_seq_len // c.block_size)
-        window_blocks = 0
-        if spec.attention == "eva":
-            if c.prefix_cache:
-                raise NotImplementedError(
-                    "prefix_cache=True over summarised windows: a shared "
-                    "prefix would have to keep a closed window's summary "
-                    "rows AND the open window's exact blocks alive for "
-                    "its followers, and only whole blocks of exact rows "
-                    "are hashed today; pass prefix_cache=False")
-            # the window's exact blocks, then one summary row per chunk
-            window_blocks = -(-spec.window // c.block_size)
-            table_width = window_blocks + -(-table_width // c.block_size)
         if mesh_info is None:
             from ..comm.mesh import peek_mesh
 
             mesh_info = peek_mesh()
         self.mesh_info = mesh_info
-        ring_blocks, ring_layers = 0, ()
-        # grouped rows: the rows ONE sliding layer reads at most a query
-        self._window = max(spec.layer_windows, default=0)
-        # layers that keep a state a slot and no rows
-        self._state_layers = spec.state_layers(cfg.num_layers)
-        # layers that attend, and so own rows; a layer in neither (its
-        # FFN alone) owns nothing
-        self._row_layers = spec.row_layers(cfg.num_layers)
-        if self._state_layers:
-            if c.prefix_cache:
-                raise NotImplementedError(
-                    "prefix_cache=True over layers with a state: a request "
-                    "that shares a prefix's blocks would also need the "
-                    "state as it stood at the prefix's last block "
-                    "boundary, and nothing stores such a snapshot; pass "
-                    "prefix_cache=False")
-            if mesh_info is not None and mesh_info.size > 1:
-                raise NotImplementedError(
-                    f"a mesh of {mesh_info.size} devices over layers with "
-                    f"a state: the split of the state's heads and of the "
-                    f"convolution's channels over the model axis is not "
-                    f"built; serve it on one device")
-        elif spec.attention == "grouped":
-            if c.prefix_cache:
-                raise NotImplementedError(
-                    "prefix_cache=True over grouped rows with sliding "
-                    "layers: a shared prefix's rows in a ring are "
-                    "overwritten as its first holder goes on, so only the "
-                    "full layers' blocks could be shared; pass "
-                    "prefix_cache=False")
-            if mesh_info is not None and mesh_info.size > 1:
-                raise NotImplementedError(
-                    f"a mesh of {mesh_info.size} devices over grouped rows "
-                    f"and a share of the experts: the all-to-all between "
-                    f"the chips that share a layer is not built; serve "
-                    f"one chip's share on one device")
-            # the sliding layers' ring: the window and one prefill chunk,
-            # whole blocks; one group where that is no less than the table
-            ring = ring_blocks_for(self._window, c.prefill_chunk,
-                                   c.block_size)
-            if self._window and ring < table_width:
-                ring_blocks = ring
-                ring_layers = [i for i in range(cfg.num_layers)
-                               if spec.window_of(i)]
-        if spec.attention == "latent":
-            if c.prefix_cache:
-                raise NotImplementedError(
-                    "prefix_cache=True over latent rows: a shared block "
-                    "would be read by the expanded path in one request's "
-                    "prefill and by the absorbed path in another's decode, "
-                    "and the prefix cache's bitwise pins are not proven "
-                    "across the two; pass prefix_cache=False")
-            if mesh_info is not None and mesh_info.size > 1:
-                raise NotImplementedError(
-                    f"a mesh of {mesh_info.size} devices over latent rows "
-                    f"and routed experts: one row serves every head, so "
-                    f"the head split of the pool does not apply, and an "
-                    f"expert layer that holds a share of the experts is "
-                    f"not built; serve it on one device")
-        # layers that choose the rows their queries attend, and those
-        # that take the choice over, for serve.sparse.*
-        self._index_layers = spec.index_layers(cfg.num_layers)
-        self._index_shared = (cfg.num_layers - len(self._index_layers)
-                              if self._index_layers else 0)
-        self._index_topk = min(spec.index_topk,
-                               table_width * c.block_size)
+        # what each layer keeps, and the table that addresses it: the
+        # schedule and the cache are built from it, and what is not offered
+        # for a group is refused in the group's own words
+        self.plan = plan = cache_plan(spec, cfg, c)
+        for g in plan.groups:
+            if c.prefix_cache and g.no_prefix_cache:
+                raise NotImplementedError(g.no_prefix_cache)
+            if g.no_mesh and mesh_info is not None and mesh_info.size > 1:
+                raise NotImplementedError(g.no_mesh.format(n=mesh_info.size))
         # routed-FFN layers, for serve.moe.*
         routed = spec.routed_layers(cfg.num_layers)
         self._routed_layers = len(routed)
@@ -560,16 +435,14 @@ class ServeEngine:
                 f"{experts['up'].shape[0]} of {cfg.num_experts} experts of "
                 f"{expert_matrices(experts)} matrices held: a decode step's "
                 f"{words(rows)}; a prefill chunk's {words(c.prefill_chunk)}")
-        self._sliding_layers = sum(
-            1 for i in range(cfg.num_layers) if spec.window_of(i))
         kv_dtype = cfg.param_dtype if c.kv_dtype is None else c.kv_dtype
         kv_mode = resolve_kv_dtype(kv_dtype)[0]
         schedule = ServeSchedule(
             max_batch=c.max_batch, prefill_chunk=c.prefill_chunk,
             block_size=c.block_size, num_blocks=c.num_blocks,
-            table_width=table_width, quantized=c.quant_mode,
+            table_width=plan.table_width, quantized=c.quant_mode,
             kv_dtype=kv_mode, draft_len=int(c.draft_len),
-            window_blocks=window_blocks, ring_blocks=ring_blocks)
+            window_blocks=plan.window_blocks, ring_blocks=plan.ring_blocks)
         if programs is None:
             # the builder refuses what a family's programs cannot do
             # before the pool is laid out
@@ -584,48 +457,11 @@ class ServeEngine:
         # storage mode is folded in by the cache itself)
         prefix_salt = (f"{cfg.num_layers}|{cfg.num_heads}|{cfg.head_dim}|"
                        f"{cfg.vocab_size}|{cfg.max_seq_len}|{c.quant_mode}")
-        # the state mixers' kind (models/layer_spec.py STATE_MIXERS) and
-        # its family of counters: serve.ssm.* | serve.gdn.* | serve.conv.*
-        # ("": no layer keeps a state)
-        state_kind = STATE_MIXERS.get(spec.state_mixer)
-        self._state_counters = state_kind.counters if state_kind else ""
         self.kv = PagedKVCache(
-            num_layers=cfg.num_layers,
-            num_heads=spec.kv_heads or cfg.num_heads,
-            head_dim=cfg.head_dim, num_blocks=c.num_blocks,
-            block_size=c.block_size, table_width=table_width,
-            dtype=kv_dtype, mesh_info=mesh_info,
+            plan, c.num_blocks, dtype=kv_dtype, mesh_info=mesh_info,
             prefix_cache=c.prefix_cache,
             min_match_blocks=c.prefix_min_match_blocks,
-            prefix_salt=prefix_salt,
-            window_tokens=window_blocks * c.block_size,
-            latent_width=spec.latent_width,
-            ring_tokens=ring_blocks * c.block_size,
-            ring_layers=ring_layers, max_requests=c.max_batch,
-            index_layers=self._index_layers, index_width=spec.index_width,
-            state_layers=self._state_layers,
-            state_shapes=spec.state_shapes,
-            bare_layers=[i for i in range(cfg.num_layers)
-                         if i not in self._state_layers
-                         and i not in self._row_layers],
-            state_counters=self._state_counters)
-        # what a decode step reads and writes of it: every slot's — less,
-        # where the kind has a kernel that walks the live slots and the
-        # registry answers that it runs (asked once, for the decode
-        # program's shapes), the first array (the float32 state)
-        # `_state_dead_bytes` of each slot that is not running; the
-        # convolution's inputs stay every slot's
-        self._state_step_bytes = 2 * self.kv.state_nbytes()
-        self._state_dead_bytes = 0
-        if self._state_layers and state_kind.step_kernel is not None:
-            from ..kernels import registry
-
-            op, info = state_kind.step_kernel(
-                spec, self.kv.caches[self._state_layers[0]])
-            if registry.resolve_impl(op, info=info) == "pallas":
-                self._state_dead_bytes = 2 * sum(
-                    self.kv.caches[i][0].nbytes
-                    for i in self._state_layers) // c.max_batch
+            prefix_salt=prefix_salt)
         self.scheduler = Scheduler(self.kv, c.max_batch,
                                    admission=c.admission, clock=clock,
                                    draft_len=int(c.draft_len))
@@ -636,46 +472,31 @@ class ServeEngine:
             self.scheduler.session_lookup = self._session_lookup
             self.scheduler.session_consumed = self._session_consumed
         self.programs = programs
-        # what a decoded slot's attention reads, for
-        # serve.{paged,eva,attn,mla}.rows_walked: its live blocks where the
-        # registry picks the kernel for the decode program's shapes,
-        # else the table's whole width
+        # kind by kind, what a step's and a chunk's counting reads: the
+        # plan's groups of the kind, their bytes, and whether the call over
+        # a group walks what is live or reads everything — what the
+        # registry answers for the programs' shapes, asked once here (a
+        # decode step's `max_batch` slots; a prefill chunk's one request,
+        # only where the kind counts chunks)
         from ..kernels import registry
-        from .layers import eva_info, grouped_info, latent_info, paged_info
 
-        with_rows = self._row_layers[0]
-        pool = jax.tree_util.tree_leaves(self.kv.caches[with_rows][0])[0]
-        q_len = int(c.draft_len) + 1
-        walks = lambda op, info: registry.resolve_impl(
-            op, info=info) == "pallas"
-        # (a learned selection gathers the rows it chose: no walk)
-        self._walks_live_blocks = False
-        # (grouped rows alone count what a prefill chunk fetches)
-        self._prefill_walks = None
-        if spec.attention == "latent" and not self._index_layers:
-            self._walks_live_blocks = walks("latent_attention", latent_info(
-                cfg, schedule, q_len, pool.dtype, spec.latent_width))
-        elif spec.attention == "grouped":
-            # a full layer and a sliding one are asked apart: a sliding
-            # layer's rows are a ring, or the table under a window; and a
-            # decode step apart from a prefill chunk, one request's
-            ask = lambda window, q_len, batch: walks(
-                "grouped_attention", grouped_info(
-                    spec, cfg, schedule, q_len, pool.dtype, window,
-                    bool(window and ring_blocks), batch))
-            step, chunk = (q_len, c.max_batch), (c.prefill_chunk, 1)
-            self._walks_live_blocks = ask(0, *step)
-            self._sliding_walks = bool(self._window) and ask(
-                self._window, *step)
-            self._prefill_walks = ask(0, *chunk)
-            self._sliding_prefill_walks = bool(self._window) and ask(
-                self._window, *chunk)
-        elif spec.attention == "paged":
-            self._walks_live_blocks = walks("paged_attention", paged_info(
-                cfg, schedule, q_len, pool.dtype))
-        elif spec.attention == "eva":
-            self._walks_live_blocks = walks("eva_attention", eva_info(
-                spec, cfg, schedule, q_len, pool.dtype))
+        def walks(kind, g, q_len, batch):
+            asked = kind.asks and kind.asks(
+                g, spec, cfg, schedule, self.kv.caches[g.layers[0]], q_len,
+                batch)
+            return bool(asked) and registry.resolve_impl(
+                asked[0], info=asked[1]) == "pallas"
+
+        self._counted = {}              # kind -> Counted
+        for name in dict.fromkeys(g.kind for g in plan.groups):
+            kind = KINDS[name]
+            groups = tuple(g for g in plan.groups if g.kind == name)
+            self._counted[name] = Counted(
+                plan, groups,
+                tuple((walks(kind, g, int(c.draft_len) + 1, c.max_batch),
+                       walks(kind, g, c.prefill_chunk, 1)
+                       if kind.chunk else None) for g in groups),
+                tuple(self.kv.group_nbytes(g) for g in groups))
         self.params = programs["prepare_params"](
             self._place_params(params))
         logger.info(f"serving engine up: {schedule.describe()}; "
@@ -683,7 +504,8 @@ class ServeEngine:
         # packed decode-batch state (one row per slot).  Each slot's
         # last token lies on the device where decode runs ahead of what
         # the host has read, on the host where drafting reads it
-        self._slots = _SlotState(c.max_batch, table_width + ring_blocks)
+        self._slots = _SlotState(c.max_batch,
+                                 plan.table_width + plan.ring_blocks)
         self._serial = int(c.draft_len) > 0
         self._tokens = (np.zeros if self._serial else jnp.zeros)(
             (c.max_batch,), np.int32)
@@ -747,30 +569,9 @@ class ServeEngine:
             raise ValueError(
                 f"top_k must be >= 0 and temperature >= 0, got "
                 f"{top_k}, {temperature}")
-        if session_id is not None and self.kv.latent_width:
-            raise NotImplementedError(
-                "sessions over latent rows: a pin keeps rows that decode "
-                "wrote, and the next turn's prefill would expand them "
-                "beside rows of its own; not proven, so not offered")
-        if session_id is not None and self._state_layers:
-            raise NotImplementedError(
-                "sessions over layers with a state: a pin would have to "
-                "keep the state as the last turn left it beside the rows, "
-                "and a slot's state is zeroed when the next request is "
-                "seated; nothing stores a snapshot")
-        if session_id is not None and self.kv.ring_blocks:
-            raise NotImplementedError(
-                "sessions over grouped rows with sliding layers: a pin "
-                "would have to keep the ring's rows as the last turn left "
-                "them and the next turn resume inside it; only whole "
-                "tables of exact rows are pinned today")
-        if session_id is not None and self.kv.windowed:
-            raise NotImplementedError(
-                "sessions over summarised windows: a pin would have to "
-                "hold the open window's exact blocks and every summary "
-                "row of the conversation, and the next turn resume inside "
-                "a window; only whole tables of exact rows are pinned "
-                "today")
+        for g in self.plan.groups:
+            if session_id is not None and g.no_sessions:
+                raise NotImplementedError(g.no_sessions)
         req = Request(prompt=prompt, max_new_tokens=int(max_new_tokens),
                       temperature=float(temperature), top_k=int(top_k),
                       seed=int(seed), eos_token=eos_token,
@@ -928,7 +729,7 @@ class ServeEngine:
         held = (self.params, self.kv.caches)
         # a request's table as `prefill` takes it: behind the entries of
         # layers with a state, the slot
-        table = of((host["tables"].shape[1] + bool(self._state_layers),),
+        table = of((host["tables"].shape[1] + bool(self.kv.by_slot),),
                    jnp.int32)
         calls = {"prefill": (self.programs["prefill"], held + (
             of((1, c.prefill_chunk), jnp.int32), scalar(jnp.int32),
@@ -1014,11 +815,7 @@ class ServeEngine:
         """Terminal transition of a request that holds a slot: slot and
         blocks go back now, and no later step decodes for the slot."""
         slot = req.slot
-        if self.kv.ring_blocks:
-            saved = -(-req.cached_len // self.kv.block_size) \
-                - self.kv.ring_blocks
-            if saved > 0:
-                COUNTERS.add("kv.ring_wraps", nbytes=saved)
+        self.kv.count_wraps(req.cached_len)
         self.scheduler.finish(req, state, error=error)
         if slot is not None:
             self._slots.set(slot, active=False, tables=TRASH_BLOCK)
@@ -1127,16 +924,13 @@ class ServeEngine:
         tus0 = tr.now_us() if tr is not None else 0
         tokens = np.zeros((1, C), np.int32)
         tokens[0, :n_valid] = chunk
-        if self.kv.windowed:
-            self._take_blocks(req, pos0, pos0 + n_valid)
-        elif self.kv.ring_blocks:
-            req.table = self.kv.extend(req.rid, pos0, pos0 + n_valid)
+        self._take_blocks(req, pos0, pos0 + n_valid)
         table = req.table
-        if self._state_layers:
-            if pos0 == 0:   # seated: the slot's last tenant's state goes
+        if self.kv.by_slot:
+            if pos0 == 0:   # seated: the slot's last tenant's arrays go
                 with phase("serve.state.reset", self._step_tracer()):
                     self.kv.reset_state(req.slot)
-            # behind the table's entries: where the request's state lies
+            # behind the table's entries: where the request's slot lies
             table = np.append(table, np.int32(req.slot))
         tok, _logits, caches = self.programs["prefill"](
             self.params, self.kv.caches, jnp.asarray(tokens),
@@ -1146,19 +940,12 @@ class ServeEngine:
         self.kv.caches = caches
         req.prefill_pos += n_valid
         req.cached_len = req.prefill_pos
-        if self.kv.windowed:
-            self._close_full_window(req)
+        self._close_full_window(req)
         COUNTERS.add("serve.prefill_chunks", nbytes=n_valid)
-        if self._prefill_walks is not None:
-            # the chunk's last position is its padded tail's
-            COUNTERS.add(
-                "serve.attn.prefill_rows_walked",
-                nbytes=self._grouped_rows_fetched(
-                    np.array([pos0 + C]), self._prefill_walks,
-                    self._sliding_prefill_walks, n_queries=C))
-        if self._index_shared:
-            COUNTERS.add("serve.sparse.selections_shared",
-                         calls=self._index_shared)
+        for kind, counted in self._counted.items():
+            if KINDS[kind].chunk:
+                # the chunk's last position is its padded tail's
+                KINDS[kind].chunk(counted, np.array([pos0 + C]), C)
         self._count_assignments(n_valid)
         if self._routed_layers and req.prefill_pos < len(req.prompt):
             # behind the sample nobody reads: the rows the chunk's routed
@@ -1213,54 +1000,11 @@ class ServeEngine:
         state = self._slots
         positions = state.host["positions"]
         slots = [r.slot for r in lanes]
-        if self.kv.windowed:
-            W, C = self.kv.window_tokens, self.kv.block_size
-            tables = state.host["tables"]
-            for req in lanes:
-                p = int(positions[req.slot])
-                self._take_blocks(req, p, p + 1)
-                if not np.array_equal(tables[req.slot], req.table):
-                    state.set(req.slot, tables=req.table)
-                # what this query reads: its window up to itself and
-                # the summaries of the windows closed before it; what
-                # its attention fetches for that: the blocks those rows
-                # lie in, or every entry of the table
-                COUNTERS.add("serve.eva.rows_read",
-                             nbytes=p % W + 1 + p // W * (W // C))
-                COUNTERS.add("serve.eva.context_tokens", nbytes=p + 1)
-                COUNTERS.add("serve.eva.rows_walked", nbytes=C * (
-                    sum(live_blocks(p, W, C, C)) if self._walks_live_blocks
-                    else self.kv.table_width))
-        elif self._index_layers:
-            held = positions[slots].astype(np.int64) + 1
-            full, every = len(self._index_layers), self.kv.num_layers
-            COUNTERS.add("serve.sparse.keys_scored", calls=len(lanes) * full,
-                         nbytes=int(held.sum()) * full)
-            COUNTERS.add("serve.sparse.rows_selected",
-                         calls=len(lanes) * every, nbytes=every * int(
-                             np.minimum(held, self._index_topk).sum()))
-            COUNTERS.add("serve.sparse.rows_fetched",
-                         calls=len(lanes) * every,
-                         nbytes=len(lanes) * every * self._index_topk)
-            COUNTERS.add("serve.sparse.selections_shared",
-                         calls=self._index_shared)
-        elif self.kv.latent_width:
-            held = positions[slots].astype(np.int64) + 1
-            COUNTERS.add("serve.mla.rows_read", calls=len(lanes),
-                         nbytes=int(held.sum()))
-            self._count_rows_walked(lanes, 1, "serve.mla.rows_walked")
-        elif self._window or self._state_layers:
-            self._count_grouped_rows(lanes)
-        else:
-            self._count_rows_walked(lanes, 1)
-        if self._state_layers:
-            # what the program as built streams: every slot's state,
-            # running or not, or the running slots' alone
-            COUNTERS.add(f"{self._state_counters}.state_bytes",
-                         nbytes=self._state_step_bytes - self._state_dead_bytes
-                         * (self.config.max_batch - len(lanes)))
-            COUNTERS.add(f"{self._state_counters}.slots_live",
-                         nbytes=len(lanes) * len(self._state_layers))
+        for req in lanes:
+            p = int(positions[req.slot])
+            if self._take_blocks(req, p, p + 1):
+                state.set(req.slot, tables=req.table)
+        self._count_step(lanes, 1)
         COUNTERS.add("serve.decode_ahead", nbytes=int(
             any(isinstance(u, _Step) for u in self._unread)))
         # the predicate `decode` picks its sampling tail by, from the
@@ -1277,7 +1021,7 @@ class ServeEngine:
             req.cached_len += 1
             if req.cached_len >= len(req.prompt) + req.max_new_tokens - 1:
                 state.set(req.slot, active=False)
-            elif self.kv.windowed:
+            else:
                 self._close_full_window(req)
         self._unread.append(step)
         return step
@@ -1386,81 +1130,25 @@ class ServeEngine:
                 "serve.moe.assignments", calls=self._routed_layers,
                 nbytes=n_tokens * self._top_k * self._routed_layers)
 
-    def _count_grouped_rows(self, lanes: List[Request]) -> None:
-        """Before a decode step over grouped rows: the blocks of the
-        window group a slot's position enters, and what its query
-        attends — the window's rows in a sliding layer, every cached row
-        in a full one."""
-        state = self._slots
-        positions = state.host["positions"]
-        bs, ring = self.kv.block_size, self.kv.ring_tokens
-        for req in lanes:
-            p = int(positions[req.slot])
-            if p < ring and p % bs == 0:
-                req.table = self.kv.extend(req.rid, p, p + 1)
-                state.set(req.slot, tables=req.table)
-        held = positions[[r.slot for r in lanes]].astype(np.int64) + 1
-        in_window = int(np.minimum(held, self._window).sum())
-        full_layers = len(self._row_layers) - self._sliding_layers
-        if self._window:
-            COUNTERS.add("serve.window.rows_read", calls=len(lanes),
-                         nbytes=in_window)
-        COUNTERS.add("serve.attn.rows_read", calls=len(lanes),
-                     nbytes=self._sliding_layers * in_window
-                     + full_layers * int(held.sum()))
-        COUNTERS.add("serve.attn.rows_walked", calls=len(lanes),
-                     nbytes=self._grouped_rows_fetched(
-                         held, self._walks_live_blocks, self._sliding_walks))
-
-    def _grouped_rows_fetched(self, held, full_walks: bool,
-                              sliding_walks: bool, n_queries: int = 1) -> int:
-        """The pool rows the layers with grouped rows FETCH for calls
-        that reach `held` [n] rows each with their last `n_queries`
-        positions as queries — decoded slots, or the one request of a
-        prefill chunk, its padded tail counted: a call's live blocks
-        where the kind of layer (full, sliding) walks — from the table's
-        first entry in a full layer, from the block of the OLDEST
-        query's lower bound `held - n_queries - window + 1` in a sliding
-        one (kernels/paged.py `_sliding_run`: a decode step's one query,
-        a chunk's first), the run at most — every entry of its run — the
-        table, or the ring — where it gathers."""
-        bs, ring = self.kv.block_size, self.kv.ring_tokens
-        table = self.kv.table_width * bs
-        full_layers = len(self._row_layers) - self._sliding_layers
-
-        def fetched(walks: bool, run: int, window: int = 0) -> int:
-            if not walks:
-                return run * len(held)
-            first = np.maximum(held - n_queries - window + 1, 0) // bs \
-                if window else 0
-            return int(np.minimum((-(-held // bs) - first) * bs, run).sum())
-
-        return full_layers * fetched(full_walks, table) \
-            + self._sliding_layers * fetched(sliding_walks, ring or table,
-                                             self._window)
-
-    def _count_rows_walked(self, running: List[Request], n_queries: int,
-                           name: str = "serve.paged.rows_walked") -> None:
-        """The pool rows this step's attention FETCHES for the running
-        slots: their live blocks where the decode call is the walk, the
-        table's whole width where it gathers."""
+    def _count_step(self, lanes: List[Request], n_queries: int) -> None:
+        """Count, kind by kind of the plan's groups, what a step reads
+        whose lanes reach their position + `n_queries` rows each."""
         held = self._slots.host["positions"][
-            [r.slot for r in running]].astype(np.int64) + n_queries
-        bs = self.kv.block_size
-        walked = (-(-held // bs) * bs if self._walks_live_blocks
-                  else np.full_like(held, self.kv.table_width * bs))
-        COUNTERS.add(name, calls=len(running), nbytes=int(walked.sum()))
+            [r.slot for r in lanes]].astype(np.int64) + n_queries
+        for kind, counted in self._counted.items():
+            if KINDS[kind].step:
+                KINDS[kind].step(counted, held)
 
-    # -- summarised windows: host book-keeping at step boundaries --------
+    # -- host book-keeping at step boundaries ----------------------------
 
-    def _take_blocks(self, req: Request, start: int, stop: int) -> None:
+    def _take_blocks(self, req: Request, start: int, stop: int) -> bool:
         """Before a program writes positions [start, stop): take the
-        exact blocks of those window offsets and the summary blocks of
-        the chunks the call completes (booked at admission)."""
-        req.table = self.kv.extend(req.rid, start, stop)
-        rows = stop // self.kv.block_size - start // self.kv.block_size
-        if rows:
-            COUNTERS.add("kv.summary_rows", nbytes=rows)
+        blocks they need of the runs that are not handed out at
+        admission.  -> whether the request's table changed."""
+        table = self.kv.extend(req.rid, start, stop)
+        if table is not None:
+            req.table = table
+        return table is not None
 
     def _close_full_window(self, req: Request) -> None:
         """After a program that writes up to `req.cached_len` was
@@ -1470,7 +1158,7 @@ class ServeEngine:
         blocks next is launched behind the program that last read them.
         The slot's row of the decode state keeps them until the
         request's next step takes its table anew, as every step does."""
-        if req.cached_len % self.kv.window_tokens:
+        if not self.kv.window_full(req.cached_len):
             return
         tr = self._req_tracer(req)
         tus0 = tr.now_us() if tr is not None else 0
@@ -1572,7 +1260,7 @@ class ServeEngine:
                     COUNTERS.add("serve.draft_tokens", calls=len(d))
         with phase("serve.decode.launch", tr):
             tokens = np.concatenate([self._tokens[:, None], drafts], axis=1)
-            self._count_rows_walked(running, k + 1)
+            self._count_step(running, k + 1)
             t0 = time.perf_counter()
             toks, caches = self.programs["verify"](
                 self.params, self.kv.caches, jnp.asarray(tokens),

@@ -130,6 +130,7 @@ import jax.numpy as jnp
 from ..models.generation import kth_largest
 from ..utils.logging import logger
 from . import layers
+from .kv_cache import ring_blocks_for
 
 QUANT_MODES = ("none", "int8", "int4")
 
@@ -409,10 +410,11 @@ class ServeProgramBuilder:
     def _check_grouped(self, s: ServeSchedule) -> None:
         """What the programs over grouped rows need of a schedule, and
         what is not built for them."""
+        # (the plan's own arithmetic: a schedule built by hand is held to it)
         window = max(self.spec.layer_windows, default=0)
         ring = s.ring_blocks * s.block_size
-        if s.ring_blocks and (not window
-                              or ring < window + s.prefill_chunk):
+        if s.ring_blocks and (not window or s.ring_blocks < ring_blocks_for(
+                window, s.prefill_chunk, s.block_size)):
             raise ValueError(
                 f"a ring of {ring} rows needs layers with a window and "
                 f"must hold it ({window}) and one prefill chunk "

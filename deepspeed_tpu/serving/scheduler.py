@@ -10,18 +10,11 @@ The scheduling loop the engine drives once per `step()`:
    chain hash is already registered are aliased (one refcount, zero
    fresh blocks) and a request arriving with a live session pin adopts
    the pin's blocks outright — admission charges only what is actually
-   new.  Prefill then starts at the first non-cached position.  Over a
-   windowed cache (`kv.windowed`: exact rows for the open window,
-   summary rows behind it) the budget is the request's BOUNDED
-   footprint — at most a window of exact blocks plus its summary
-   blocks, `kv.blocks_needed` — and admission books it (`kv.reserve`)
-   without taking a block: the engine takes blocks as positions are
-   written and gives a window's blocks back when it closes.  Over two
-   groups of layers (`kv.ring_blocks`: sliding layers in a ring of
-   their own) admission books the full layers' footprint as ever —
-   `num_blocks` is theirs — and never asks the window group, which holds
-   a ring for every slot; the table it gets is `[full | ring]` entries
-   wide.  The
+   new.  Prefill then starts at the first non-cached position.  What
+   the budget is and whether `kv.alloc` hands it out or books it is the
+   cache plan's (serving/kv_cache.py: `blocks_needed`, a "window" run's
+   bounded footprint, a ring that admission never asks); the table it
+   gets is as wide as the plan says.  The
    `draft_len` tail matters under speculative decoding: a verify step
    writes up to `draft_len` candidate K/V rows PAST the committed
    length, and without the reservation those rows would spill into the
@@ -197,8 +190,6 @@ class Scheduler:
         block table or None; on success the request's cached offsets
         and registration hashes are set."""
         needed = self.blocks_reserved(req)
-        if self.kv.windowed:
-            return self.kv.reserve(req.rid, needed)
         pin = None
         if req.session_id is not None and self.session_lookup is not None:
             pin = self.session_lookup(req)

@@ -22,7 +22,9 @@ from deepspeed_tpu.monitor.counters import COUNTERS
 from deepspeed_tpu.serving import (PagedKVCache, ServeConfig, ServeEngine,
                                    ServeProgramBuilder, ServeSchedule)
 from deepspeed_tpu.serving import layers as serving_layers
-from deepspeed_tpu.serving.kv_cache import TRASH_BLOCK, pool_width
+from deepspeed_tpu.serving.kv_cache import (TRASH_BLOCK, cache_plan,
+                                            pool_width)
+from toy_plans import toy_plan
 
 VOCAB, LAYERS, HEADS, KV, DH, WINDOW, EXPERTS, TOPK, SHARED = \
     128, 8, 8, 2, 16, 32, 8, 4, 2
@@ -416,6 +418,13 @@ def test_prefill_then_decode_matches_the_reference_forward(dtype):
                                                   else TOL[dtype])
 
 
+def _extended(kv, table, start, stop):
+    """Request "r"'s table before positions [start, stop) are written:
+    `extend` answers None where it took nothing."""
+    taken = kv.extend("r", start, stop)
+    return table if taken is None else taken
+
+
 def _drive(model, params, sched, kv, prompt, n_decode):
     """One request by hand through a builder's programs over `kv`:
     prefill chunk by chunk, then decode steps -> every logits row."""
@@ -430,16 +439,14 @@ def _drive(model, params, sched, kv, prompt, n_decode):
         chunk = prompt[pos:pos + C]
         toks = np.zeros((1, C), np.int32)
         toks[0, :len(chunk)] = chunk
-        if kv.ring_blocks:
-            table = kv.extend("r", pos, pos + len(chunk))
+        table = _extended(kv, table, pos, pos + len(chunk))
         tok, lg, caches = progs["prefill"](
             params, caches, jnp.asarray(toks), np.int32(pos),
             np.int32(len(chunk)), jnp.asarray(table), *zero)
     rows.append(np.asarray(lg))
     tok = int(tok[0])       # behind routed FFNs [sample, rows multiplied]
     for p in range(len(prompt), len(prompt) + n_decode):
-        if kv.ring_blocks:
-            table = kv.extend("r", p, p + 1)
+        table = _extended(kv, table, p, p + 1)
         lg, caches, _ = step(params, caches, jnp.asarray([tok], jnp.int32),
                              jnp.asarray([p], jnp.int32),
                              jnp.asarray([True]), jnp.asarray(table[None]))
@@ -458,16 +465,17 @@ def test_one_group_with_a_mask_equals_two_groups_with_a_ring():
     W = 256 // BS
     base = dict(max_batch=1, prefill_chunk=CHUNK, block_size=BS,
                 num_blocks=40, table_width=W)
-    cache = dict(num_layers=LAYERS, num_heads=KV, head_dim=DH, num_blocks=40,
-                 block_size=BS, table_width=W, prefix_cache=False)
     prompt = _prompt(3 * WINDOW + 5, 11)
+    # one group: what the same layers keep where none has a window
     one = _drive(model, params, ServeSchedule(**base),
-                 PagedKVCache(**cache), prompt, 2 * CHUNK)
-    sliding = [i for i in range(LAYERS) if model.config.window_of(i)]
+                 PagedKVCache(toy_plan(LAYERS, KV, DH, BS, 256), 40,
+                              prefix_cache=False), prompt, 2 * CHUNK)
+    plan = cache_plan(model.layer_spec(), model.config, _serve(max_batch=1))
+    assert plan.ring_blocks == RING // BS and plan.table_width == W
     two = _drive(model, params,
                  ServeSchedule(ring_blocks=RING // BS, **base),
-                 PagedKVCache(ring_tokens=RING, ring_layers=sliding,
-                              max_requests=1, **cache), prompt, 2 * CHUNK)
+                 PagedKVCache(plan, 40, prefix_cache=False), prompt,
+                 2 * CHUNK)
     assert one.std() > 1.0
     np.testing.assert_allclose(two, one, atol=4e-6 * np.abs(one).max())
     want = np.asarray(ref.logits(
@@ -581,24 +589,31 @@ def test_decode_appends_the_held_experts_touched_to_its_tokens():
 # -- two groups of layers under the allocator ------------------------------------
 
 
+def _ring_plan(layers, windows):
+    """`layers` layers of the pattern `windows` under blocks of BS, a
+    table of 48 positions, chunks of 8 and two slots."""
+    return toy_plan(layers, KV, DH, BS, 48, slots=2, prefill_chunk=8,
+                    attention="grouped", kv_heads=KV, layer_windows=windows)
+
+
 def test_two_groups_under_the_allocator():
-    kv = PagedKVCache(num_layers=4, num_heads=KV, head_dim=DH, num_blocks=9,
-                      block_size=BS, table_width=6, dtype=jnp.bfloat16,
-                      prefix_cache=False, ring_tokens=24,
-                      ring_layers=[0, 1, 2], max_requests=2)
+    # a ring of window 16 + a chunk of 8 = 24 rows, under a table of 48
+    kv = PagedKVCache(_ring_plan(4, (16, 16, 16, 0)), 9, dtype=jnp.bfloat16,
+                      prefix_cache=False)
+    assert kv.ring_tokens == 24 and kv.table_width == 6
     assert [c[0].shape for c in kv.caches] == [(56, 128)] * 3 + [(72, 128)]
     a = kv.alloc("a", 5)
     assert a.shape == (9,) and (a[5:] == TRASH_BLOCK).all()
     assert TRASH_BLOCK not in a[:5] and kv.ring_blocks_in_use == 0
     a = kv.extend("a", 0, 10)              # positions 0..9: two ring blocks
     assert (a[6:8] != TRASH_BLOCK).all() and a[8] == TRASH_BLOCK
-    assert np.array_equal(kv.extend("a", 3, 12), a)     # nothing new
+    assert kv.extend("a", 3, 12) is None                # nothing new
     a = kv.extend("a", 16, 17)
     assert sorted(kv.ring_blocks_of("a")) == sorted(a[6:].tolist())
     held = set(kv.ring_blocks_of("a"))
     for p in range(17, 40):                # round and round: never more
         assert set(kv.ring_blocks_of("a")) == held
-        assert np.array_equal(kv.extend("a", p, p + 1), a)
+        assert kv.extend("a", p, p + 1) is None
     assert kv.alloc("b", 4) is None        # group full: 8 blocks, 5 held
     b = kv.extend("b", 0, 24) if kv.alloc("b", 3) is not None else None
     assert kv.ring_blocks_in_use == 6 and not held & set(b[6:].tolist())
@@ -613,17 +628,18 @@ def test_two_groups_under_the_allocator():
 
 
 def test_two_groups_refuse_what_they_cannot_hold():
-    base = dict(num_layers=2, num_heads=KV, head_dim=DH, num_blocks=9,
-                block_size=BS, table_width=6, dtype=jnp.bfloat16,
-                prefix_cache=False, ring_tokens=24, ring_layers=[0],
-                max_requests=2)
-    for kw, match in (({"dtype": "int8"}, "two groups"),
-                      ({"prefix_cache": True}, "two groups"),
-                      ({"ring_tokens": 20}, "whole blocks"),
-                      ({"ring_layers": []}, "for some layers"),
-                      ({"max_requests": 0}, "max_requests")):
-        with pytest.raises(ValueError, match=match):
-            PagedKVCache(**dict(base, **kw))
+    plan = _ring_plan(2, (16, 0))
+    PagedKVCache(plan, 9, dtype=jnp.bfloat16, prefix_cache=False)
+    for kw in ({"dtype": "int8"}, {"prefix_cache": True}):
+        with pytest.raises(ValueError, match="two groups"):
+            PagedKVCache(plan, 9, **dict(
+                dict(dtype=jnp.bfloat16, prefix_cache=False), **kw))
+    # a pattern whose windows fall on none of the layers: a ring for none
+    with pytest.raises(ValueError, match="for some layers"):
+        _ring_plan(2, (0, 0, 16))
+    # (a ring is whole blocks, for `max_batch` >= 1 slots, by construction:
+    # `cache_plan` rounds it up and ServeConfig refuses max_batch < 1)
+    assert _ring_plan(2, (13, 0)).ring_blocks == 3
 
 
 def test_engine_never_holds_more_than_the_ring_and_frees_both_groups():
@@ -750,7 +766,8 @@ def test_prefill_rows_walked_is_what_each_kind_of_layer_fetches(
         out = eng.generate(prompts, 2)
     d = COUNTERS.delta_since(before)
     walks = way == "kernel" and head_dim == 128
-    assert eng._prefill_walks == eng._sliding_prefill_walks == walks
+    # (full layers, sliding layers) x (a decode step, a prefill chunk)
+    assert [w[1] for w in eng._counted["grouped"].walks] == [walks, walks]
     # a chunk's last position is its padded tail's
     ends = [start + CHUNK for n in lengths for start in range(0, n, CHUNK)]
     table = eng.kv.table_width * BS
@@ -795,7 +812,8 @@ def test_rows_walked_is_what_each_kind_of_layer_fetches(way, ringed,
         out = eng.generate(prompts, 6)
     d = COUNTERS.delta_since(before)
     assert bool(eng.kv.ring_blocks) == ringed
-    assert eng._walks_live_blocks == eng._sliding_walks == (way == "kernel")
+    assert [w[0] for w in eng._counted["grouped"].walks] == \
+        [way == "kernel"] * 2
     held = [n + i + 1 for n in lengths for i in range(5)]
     table = eng.kv.table_width * BS
     run = RING if ringed else table

@@ -5,6 +5,7 @@ refused for them — against the plain reference
 """
 
 import math
+import types
 
 import numpy as np
 import pytest
@@ -20,6 +21,7 @@ from deepspeed_tpu.monitor.counters import COUNTERS
 from deepspeed_tpu.serving import (PagedKVCache, ServeConfig, ServeEngine,
                                    ServeProgramBuilder)
 from deepspeed_tpu.serving.kv_cache import TRASH_BLOCK, pool_width
+from toy_plans import toy_plan
 
 VOCAB, HEADS, NOPE, ROPE, VDIM, RANK, TOPK, EXPERTS = 128, 4, 16, 16, 16, 32, \
     3, 8
@@ -382,7 +384,7 @@ def test_rows_walked_is_what_a_decode_step_fetches(way, chip_rule):
         before = COUNTERS.snapshot()
         out = eng.generate(prompts, 6)
     d = COUNTERS.delta_since(before)
-    assert eng._walks_live_blocks == (way == "kernel")
+    assert eng._counted["latent"].walks == ((way == "kernel", None),)
     held = [n + i + 1 for n in lengths for i in range(5)]
     assert d["serve.mla.rows_read"] == {"calls": 10, "bytes": sum(held)}
     fetched = sum(-(-h // bs) * bs for h in held) if way == "kernel" \
@@ -496,9 +498,10 @@ def test_decode_appends_experts_touched_to_its_tokens():
 
 def test_latent_rows_under_the_one_allocator():
     assert pool_width(1, 576) == 640 and pool_width(1, 48) == 128
-    kv = PagedKVCache(num_layers=3, num_heads=4, head_dim=32, num_blocks=9,
-                      block_size=8, table_width=4, dtype=jnp.bfloat16,
-                      prefix_cache=False, latent_width=576)
+    kv = PagedKVCache(toy_plan(3, 4, 32, 8, 32, attention="latent",
+                               latent_width=576), 9, dtype=jnp.bfloat16,
+                      prefix_cache=False)
+    assert kv.table_width == 4
     assert len(kv.caches) == 3 and all(len(e) == 1 for e in kv.caches)
     assert kv.caches[0][0].shape == (72, 640)
     assert kv.nbytes() == 3 * 72 * 640 * 2
@@ -515,14 +518,14 @@ def test_latent_rows_under_the_one_allocator():
 
 
 def test_latent_cache_refuses_what_it_cannot_hold():
+    plan = toy_plan(1, 4, 32, 8, 32, attention="latent", latent_width=48)
+    mesh = types.SimpleNamespace(size=2, axis_size=lambda axis: 1)
     for kw in ({"dtype": "int8"}, {"prefix_cache": True},
-               {"window_tokens": 16}):
-        base = dict(num_layers=1, num_heads=4, head_dim=32, num_blocks=9,
-                    block_size=8, table_width=4, dtype=jnp.bfloat16,
-                    prefix_cache=False, latent_width=48)
+               {"mesh_info": mesh}):
+        base = dict(dtype=jnp.bfloat16, prefix_cache=False)
         base.update(kw)
         with pytest.raises(ValueError, match="latent rows"):
-            PagedKVCache(**base)
+            PagedKVCache(plan, 9, **base)
 
 
 def test_engine_leaves_the_trash_block_and_freed_blocks_alone():

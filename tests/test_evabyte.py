@@ -30,6 +30,7 @@ from deepspeed_tpu.serving import (PagedKVCache, ServeConfig, ServeEngine,
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmarks.reference import evabyte as ref  # noqa: E402
+from toy_plans import toy_plan  # noqa: E402
 
 W, C, VOCAB = 32, 4, 320
 KW = dict(heads=4, eps=1e-5, theta=1e5, window=W, chunk=C, vocab=VOCAB)
@@ -306,16 +307,17 @@ def test_the_check_sees_the_remote_term(model_and_params, monkeypatch):
 
 
 def _kv(**kw):
-    base = dict(num_layers=1, num_heads=2, head_dim=8, num_blocks=40,
-                block_size=4, table_width=8 + 4, prefix_cache=False,
-                window_tokens=32)
+    # a window of 32 = 8 blocks, and 4 blocks of summary rows for 64 tokens
+    plan = toy_plan(1, 2, 8, 4, 64, attention="eva", window=32, chunk=4)
+    base = dict(num_blocks=40, prefix_cache=False)
     base.update(kw)
-    return PagedKVCache(**base)
+    return PagedKVCache(plan, **base)
 
 
 def test_two_kinds_of_row_under_one_allocator():
     kv = _kv()
-    assert kv.windowed and kv.window_blocks == 8 and kv.token_capacity == 64
+    assert kv.window_blocks == 8 and kv.token_capacity == 64
+    assert kv.table_width == 8 + 4 and kv.plan.groups[0].run == "window"
     # bounded footprint: a window of exact blocks + one summary row / block
     assert kv.blocks_needed(10) == 3 + 1
     assert kv.blocks_needed(64) == 8 + 4
@@ -374,13 +376,11 @@ def test_reserve_refuses_what_the_pool_cannot_promise():
 def test_windowed_cache_refuses_prefix_cache_and_describes_both_rows():
     with pytest.raises(ValueError, match="prefix cache"):
         _kv(prefix_cache=True)
-    with pytest.raises(ValueError, match="multiple of"):
-        _kv(window_tokens=30)
     text = _kv().describe()
     assert "exact rows for a window of 32 tok (8 blocks)" in text
     assert "summary rows 1 per 4 tok (4 blocks)" in text
     assert "exact rows" in PagedKVCache(
-        1, 2, 8, 8, 4, 4, prefix_cache=False).describe()
+        toy_plan(1, 2, 8, 4, 16), 8, prefix_cache=False).describe()
 
 
 @IMPLS
@@ -535,8 +535,9 @@ def test_rows_walked_counts_live_blocks_where_the_kernel_runs(monkeypatch):
     with monkeypatch.context() as chip:
         chip.setattr(pallas_backend, "interpret", lambda: False)
         eng = ServeEngine(model, params, serve)
-    assert eng._walks_live_blocks
-    assert not ServeEngine(model, params, serve)._walks_live_blocks
+    assert eng._counted["eva"].walks == ((True, None),)
+    assert ServeEngine(model, params, serve)._counted["eva"].walks == (
+        (False, None),)
     before = COUNTERS.snapshot()
     with _attention("pallas"):
         out = eng.generate([_prompt(60, 0)], 81)[0]   # positions 60..139

@@ -21,6 +21,7 @@ from deepspeed_tpu.monitor.counters import COUNTERS
 from deepspeed_tpu.serving import PagedKVCache, ServeConfig, ServeEngine
 from deepspeed_tpu.serving import layers as serving_layers
 from deepspeed_tpu.serving import sparse
+from toy_plans import toy_plan
 
 VOCAB, TOPK, EXPERTS, HELD, INDEX_TOPK = 97, 4, 16, 4, 8
 KINDS = ("full", "shared", "shared", "shared", "full")
@@ -274,10 +275,12 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 
 def test_index_keys_lie_in_the_full_layers_only():
-    kv = PagedKVCache(num_layers=5, num_heads=4, head_dim=32, num_blocks=9,
-                      block_size=8, table_width=4, dtype=jnp.bfloat16,
-                      prefix_cache=False, latent_width=576,
-                      index_layers=(0, 4), index_width=128)
+    full = ("full", "shared", "shared", "shared")
+    kv = PagedKVCache(toy_plan(5, 4, 32, 8, 32, attention="latent",
+                               latent_width=576, layer_indexers=full,
+                               index_width=128, index_topk=16), 9,
+                      dtype=jnp.bfloat16, prefix_cache=False)
+    assert [g.layers for g in kv.plan.groups] == [(0, 4), (1, 2, 3)]
     assert [len(e) for e in kv.caches] == [2, 1, 1, 1, 2]
     assert kv.caches[0][0].shape == (72, 640)
     assert kv.caches[0][1].shape == kv.caches[4][1].shape == (72, 128)
@@ -288,20 +291,20 @@ def test_index_keys_lie_in_the_full_layers_only():
     a = kv.alloc("a", 3)
     assert kv.blocks_in_use == 3 and (a[3:] == 0).all()
     kv.free("a")
-    plain = PagedKVCache(num_layers=5, num_heads=4, head_dim=32, num_blocks=9,
-                         block_size=8, table_width=4, dtype=jnp.bfloat16,
-                         prefix_cache=False, latent_width=576)
+    plain = PagedKVCache(toy_plan(5, 4, 32, 8, 32, attention="latent",
+                                  latent_width=576), 9, dtype=jnp.bfloat16,
+                         prefix_cache=False)
     assert plain.index_nbytes() == 0 and "index keys" not in plain.describe()
     with pytest.raises(ValueError, match="beside latent rows"):
-        PagedKVCache(num_layers=2, num_heads=4, head_dim=32, num_blocks=9,
-                     block_size=8, table_width=4, prefix_cache=False,
-                     index_layers=(0,), index_width=16)
+        toy_plan(2, 4, 32, 8, 32, layer_indexers=("full",), index_width=16)
 
 
 def test_the_engine_lays_out_what_the_spec_says():
     model, params = _model()
     eng = ServeEngine(model, params, _serve())
-    assert eng.kv.index_layers == {0, 4} and eng.kv.index_width == 8
+    keyed, shared = eng.plan.groups
+    assert keyed.layers == (0, 4) and keyed.arrays[1] == ("key", 1, 8)
+    assert shared.layers == (1, 2, 3) and len(shared.arrays) == 1
     assert [len(e) for e in eng.kv.caches] == [2, 1, 1, 1, 2]
     assert "indexer" in params["blocks"][0]["attn"]
     assert all("indexer" not in params["blocks"][i]["attn"]

@@ -8,6 +8,7 @@ over a state of 16, convolution 4, scan chunk 4, vocabulary 97.
 """
 
 import contextlib
+import types
 
 import numpy as np
 import pytest
@@ -23,6 +24,8 @@ from deepspeed_tpu.monitor.counters import COUNTERS
 from deepspeed_tpu.serving import (PagedKVCache, ServeConfig, ServeEngine,
                                    ServeProgramBuilder, ServeSchedule)
 from deepspeed_tpu.serving import layers as serving_layers
+from deepspeed_tpu.serving.kv_cache import cache_plan
+from toy_plans import toy_plan
 
 VOCAB, LAYERS, PERIOD, AT = 97, 6, 3, (1,)
 HEADS, KV, DH = 4, 2, 8
@@ -343,12 +346,11 @@ def _drive(model, params, prompt, n_decode, chunk, slot=1, slots=3):
     sched = ServeSchedule(max_batch=slots, prefill_chunk=chunk,
                           block_size=BS, num_blocks=64, table_width=W)
     spec = model.layer_spec()
-    kv = PagedKVCache(
-        num_layers=LAYERS, num_heads=KV, head_dim=DH, num_blocks=64,
-        block_size=BS, table_width=W, prefix_cache=False,
-        max_requests=slots, state_layers=spec.state_layers(LAYERS),
-        state_shapes=(((SH, SP, SN), jnp.float32),
-                      ((TAPS - 1, spec.ssm_conv_width), None)))
+    plan = cache_plan(spec, model.config,
+                      _serve(max_batch=slots, prefill_chunk=chunk))
+    assert plan.table_width == W and plan.groups[0].arrays == (
+        ((SH, SP, SN), "float32"), ((TAPS - 1, spec.ssm_conv_width), None))
+    kv = PagedKVCache(plan, 64, prefix_cache=False)
     key = (repr(model.config), sched)
     if key not in _BUILT:
         builder = ServeProgramBuilder(model, sched)
@@ -543,16 +545,24 @@ def test_the_cache_holds_a_state_a_slot_beside_rows():
 
 
 def test_a_cache_with_a_state_refuses_what_it_cannot_hold():
-    base = dict(num_layers=3, num_heads=2, head_dim=8, num_blocks=8,
-                block_size=4, table_width=4, prefix_cache=False,
-                max_requests=2, state_layers=(0, 2),
-                state_shapes=(((2, 2, 4), jnp.float32), ((3, 12), None)))
-    PagedKVCache(**base)
+    plan = toy_plan(3, 2, 8, 4, 16, slots=2, attention="grouped", kv_heads=2,
+                    layer_mixers=("ssm", "attention", "ssm"), ssm_heads=2,
+                    ssm_head_dim=2, ssm_state=4, ssm_conv=4)
+    assert [(g.keeps, g.layers) for g in plan.groups] == [
+        ("slots", (0, 2)), ("blocks", (1,))]
+    PagedKVCache(plan, 8, prefix_cache=False)
+    mesh = types.SimpleNamespace(size=2, axis_size=lambda axis: 1)
     for change in (dict(prefix_cache=True), dict(dtype="int8"),
-                   dict(max_requests=0), dict(state_shapes=()),
-                   dict(ring_tokens=8, ring_layers=(1,))):
-        with pytest.raises(ValueError):
-            PagedKVCache(**dict(base, **change))
+                   dict(mesh_info=mesh)):
+        with pytest.raises(ValueError, match="keep a state a slot"):
+            PagedKVCache(plan, 8, **dict(dict(prefix_cache=False), **change))
+    # (what a slot keeps and how many slots are the spec's and the
+    # ServeConfig's: a plan has both or no such group; and windows beside
+    # a state are a mask on the table, never a ring)
+    assert toy_plan(3, 2, 8, 4, 64, slots=2, attention="grouped", kv_heads=2,
+                    layer_mixers=("ssm", "attention", "ssm"), ssm_heads=2,
+                    ssm_head_dim=2, ssm_state=4, ssm_conv=4,
+                    layer_windows=(0, 8, 0)).ring_blocks == 0
 
 
 @pytest.mark.parametrize("way", ["oracle", "kernel"])
@@ -608,7 +618,7 @@ def test_rows_walked_is_what_the_attention_layers_fetch(way, chip_rule):
         before = COUNTERS.snapshot()
         out = eng.generate(prompts, 6)
     d = COUNTERS.delta_since(before)
-    assert eng._walks_live_blocks == (way == "kernel")
+    assert [w[0] for w in eng._counted["grouped"].walks] == [way == "kernel"]
     attention = LAYERS - len(STATE_LAYERS)
     held = [n + i + 1 for n in lengths for i in range(5)]
     assert d["serve.attn.rows_read"] == {

@@ -520,8 +520,8 @@ def test_counters_of_the_convolutions_and_the_experts():
     model, params = _model()
     eng = ServeEngine(model, params, _serve())
     assert eng._routed_layers == len(TYPES) - DENSE
-    assert eng._state_counters == "serve.conv"
-    assert eng._state_dead_bytes == 0
+    assert STATE_MIXERS[eng.plan.groups[0].kind].counters == "serve.conv"
+    assert eng._counted["conv"].walks == ((False, None),)  # no slot's is dead
     before = COUNTERS.snapshot()
     eng.generate([_prompt(9, 1), _prompt(5, 2)], 6)
     d = COUNTERS.delta_since(before)

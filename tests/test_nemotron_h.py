@@ -26,6 +26,7 @@ from deepspeed_tpu.monitor.counters import COUNTERS
 from deepspeed_tpu.serving import (PagedKVCache, ServeConfig, ServeEngine,
                                    ServeProgramBuilder, ServeSchedule)
 from deepspeed_tpu.serving import layers as serving_layers
+from toy_plans import toy_plan
 
 VOCAB, PATTERN = 97, "MEM*EMEM*E"
 HEADS, KV, DH = 4, 2, 16
@@ -596,9 +597,8 @@ def test_rows_for_the_attention_layers_and_nothing_for_an_expert_layer():
     assert kv.bytes_per_block() == rows // 64
     assert "4 layer(s) with neither" in kv.describe()
     with pytest.raises(ValueError, match="layers that own nothing"):
-        PagedKVCache(num_layers=3, num_heads=KV, head_dim=DH, num_blocks=8,
-                     block_size=BS, table_width=4, prefix_cache=False,
-                     bare_layers=(1,))
+        toy_plan(3, KV, DH, BS, 16, attention="grouped", kv_heads=KV,
+                 layer_mixers=("attention", "none", "attention"))
 
 
 @pytest.mark.parametrize("way", ["oracle", "kernel"])

@@ -27,6 +27,7 @@ from deepspeed_tpu.serving import (ERROR, FINISHED, FleetRouter,
                                    PagedKVCache, ServeConfig, ServeEngine,
                                    ServeProgramBuilder, ServeSchedule,
                                    build_fleet)
+from toy_plans import toy_plan
 
 TOOLS = os.path.join(os.path.dirname(__file__), "..", "tools")
 sys.path.insert(0, TOOLS)
@@ -78,10 +79,9 @@ def _engine(model_and_params, **over):
 
 
 def _kv(**over):
-    base = dict(num_layers=1, num_heads=2, head_dim=4, num_blocks=6,
-                block_size=BS, table_width=8)
+    base = dict(num_blocks=6)
     base.update(over)
-    return PagedKVCache(**base)
+    return PagedKVCache(toy_plan(1, 2, 4, BS, 8 * BS), **base)
 
 
 class _Clock:

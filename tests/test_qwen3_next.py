@@ -22,6 +22,7 @@ from deepspeed_tpu.monitor.counters import COUNTERS
 from deepspeed_tpu.serving import (PagedKVCache, ServeConfig, ServeEngine,
                                    ServeProgramBuilder, ServeSchedule)
 from deepspeed_tpu.serving import layers as serving_layers
+from deepspeed_tpu.serving.kv_cache import cache_plan
 
 VOCAB, LAYERS, PERIOD = 97, 8, 4
 HEADS, KV, DH, ROT = 4, 2, 16, 8
@@ -486,11 +487,10 @@ def _drive(model, params, prompt, n_decode, chunk, slot=1, slots=3):
     sched = ServeSchedule(max_batch=slots, prefill_chunk=chunk,
                           block_size=BS, num_blocks=64, table_width=W)
     spec = model.layer_spec()
-    kv = PagedKVCache(
-        num_layers=LAYERS, num_heads=KV, head_dim=DH, num_blocks=64,
-        block_size=BS, table_width=W, prefix_cache=False,
-        max_requests=slots, state_layers=spec.state_layers(LAYERS),
-        state_shapes=spec.state_shapes)
+    plan = cache_plan(spec, model.config,
+                      _serve(max_batch=slots, prefill_chunk=chunk))
+    assert plan.table_width == W
+    kv = PagedKVCache(plan, 64, prefix_cache=False)
     key = (repr(model.config), sched)
     if key not in _PROGRAMS:
         builder = ServeProgramBuilder(model, sched)
@@ -692,11 +692,9 @@ def test_the_scopes_name_the_mixers_in_both_programs():
     sched = ServeSchedule(max_batch=2, prefill_chunk=8, block_size=BS,
                           num_blocks=64, table_width=SEQ // BS)
     builder = ServeProgramBuilder(model, sched)
-    kv = PagedKVCache(
-        num_layers=LAYERS, num_heads=KV, head_dim=DH, num_blocks=64,
-        block_size=BS, table_width=SEQ // BS, prefix_cache=False,
-        max_requests=2, state_layers=builder.spec.state_layers(LAYERS),
-        state_shapes=builder.spec.state_shapes)
+    kv = PagedKVCache(cache_plan(builder.spec, model.config,
+                                 _serve(max_batch=2)), 64,
+                      prefix_cache=False)
     step = jax.jit(builder.step_logits).lower(
         params, kv.caches, jnp.zeros((2,), jnp.int32),
         jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool),

@@ -29,6 +29,7 @@ from deepspeed_tpu.serving import (FINISHED, PagedKVCache, ServeConfig,
                                    ServeSchedule, kv_block_bytes,
                                    resolve_kv_dtype)
 from deepspeed_tpu.serving.scheduler import Request, Scheduler
+from toy_plans import toy_plan
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 
@@ -169,17 +170,13 @@ def test_kv_block_bytes_formula(heads, dh, kv, per_row):
 
 @pytest.mark.parametrize("kv", ["bf16", "int8", "int4"])
 def test_cache_nbytes_matches_block_accounting(kv):
-    cache = PagedKVCache(num_layers=2, num_heads=4, head_dim=8,
-                         num_blocks=10, block_size=BS, table_width=WIDTH,
-                         dtype=kv)
+    cache = PagedKVCache(toy_plan(2, 4, 8, BS, WIDTH * BS), 10, dtype=kv)
     assert cache.nbytes() == 10 * cache.bytes_per_block()
     assert cache.bytes_per_block() == kv_block_bytes(2, 4, 8, BS, kv)
 
 
 def test_quant_cache_zero_init_dequantizes_to_zero():
-    cache = PagedKVCache(num_layers=1, num_heads=2, head_dim=8,
-                         num_blocks=3, block_size=BS, table_width=WIDTH,
-                         dtype="int8")
+    cache = PagedKVCache(toy_plan(1, 2, 8, BS, WIDTH * BS), 3, dtype="int8")
     payload, scales = cache.caches[0][0]
     assert payload.shape == (3 * BS, 128) and scales.shape == (3 * BS, 2)
     y = dequantize_rows(payload[:, :2 * 8].reshape(-1, 2, 8), scales,
@@ -189,9 +186,7 @@ def test_quant_cache_zero_init_dequantizes_to_zero():
 
 def test_int4_cache_needs_even_head_dim():
     with pytest.raises(ValueError, match="even"):
-        PagedKVCache(num_layers=1, num_heads=2, head_dim=7,
-                     num_blocks=3, block_size=BS, table_width=WIDTH,
-                     dtype="int4")
+        PagedKVCache(toy_plan(1, 2, 7, BS, WIDTH * BS), 3, dtype="int4")
 
 
 # -- THE parity matrix ------------------------------------------------------
@@ -293,9 +288,7 @@ def test_scheduler_reserves_speculative_tail():
     blocks: verify writes up to draft_len candidate rows PAST the
     committed length, and those rows need real blocks, never the
     trash-padded table tail."""
-    kv = PagedKVCache(num_layers=1, num_heads=2, head_dim=8,
-                      num_blocks=20, block_size=BS, table_width=WIDTH,
-                      dtype="int8")
+    kv = PagedKVCache(toy_plan(1, 2, 8, BS, WIDTH * BS), 20, dtype="int8")
     plain = Scheduler(kv, max_batch=2, draft_len=0)
     spec = Scheduler(kv, max_batch=2, draft_len=4)
     # prompt 5 + max_new 3 = 8 tokens = exactly 2 blocks; +4 draft

@@ -15,7 +15,7 @@ engine offers (runtime/comm/bucketing.py):
   zero2 / zero2_bucketed   the ZeRO-2 lane: implicit vs the bucketed
                  reduce-scatter lowering
 
-Two fabrics, following tools/onebit_bench_mp.py:
+Two fabrics:
 
   --nproc 1  (default) single-process CPU mesh — collectives are memory
              movement; shows the bucketing overhead floor.
